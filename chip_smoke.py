@@ -1,0 +1,1031 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that ray_tpu still starts on the chip.
+
+    python3 chip_smoke.py            # all phases, one after another
+
+Drives both hot paths once through the entry points a user calls, at the
+full width of the models the benchmark uses (depth cut where stated,
+weights random from a seed), and checks what comes out against the
+float32 ``jax.numpy`` references the repository already holds:
+
+    kernels        every Pallas kernel, compiled by Mosaic (never the
+                   interpreter), against its reference
+    train          ``JaxTrainer.fit`` on bench.BENCH_CFG (319M), B=8 S=2048,
+                   5 steps on one fixed batch: loss near ln(V), falling
+    serve          Llama-3-8B widths at full depth, int8 weights + int8 KV,
+                   ragged step, through ``serve.run`` and a handle: 8 of 8
+                   requests answered, the replica on the chip and the
+                   caller off it, prefill logits against ``llama.forward``
+    engine_legacy  ``LLMEngine`` in-process on the two-program path (319M)
+    multichip      only with four or more devices: train at fsdp=4, serve
+                   at tp=4 with a 512-token prompt, one-chip workers
+
+A chip belongs to one process at a time, so this parent never imports
+JAX and runs each phase as a child in turn; the chip is free between
+phases.  Each child pins ``jax_platforms`` to ``tpu`` before first use,
+so a missing or busy chip raises instead of landing on the CPU.  Any
+child's non-zero exit is this script's non-zero exit and no result is
+printed.  On success the last line of stdout is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+Numbers printed on the way are smoke timings, not measurements: they go
+in no table.  tests/test_chip_smoke.py runs the same phase functions at
+toy widths on the CPU, with the platform stated explicitly.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import functools
+import json
+import math
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+PHASES = ("kernels", "train", "serve", "engine_legacy", "multichip")
+REPORT_TAG = "CHIP_SMOKE_REPORT "
+BUDGET_S = 1150.0          # the contract allows 1200, compilation included
+PAGE = 64
+
+
+def log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# shared by the phases (children only: everything below imports JAX lazily)
+# ---------------------------------------------------------------------------
+
+
+class CompileClock:
+    """Seconds this process spent in the XLA backend compiler (or reading
+    the persistent cache in its place), and how often the cache hit."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.seconds = 0.0
+        self.hits = 0
+        self.misses = 0
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += duration
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def report(self) -> dict:
+        return {"compile_s": round(self.seconds, 1),
+                "cache_hits": self.hits, "cache_misses": self.misses}
+
+
+def require_platform(platform: str) -> list:
+    """The devices a phase runs on; a phase never reports success from
+    a platform other than the one its caller stated."""
+    import jax
+
+    from ray_tpu.ops import platform as ops_platform
+
+    devices = jax.devices()
+    if devices[0].platform != platform:
+        raise RuntimeError(
+            f"phase was told platform={platform!r} but JAX computes on "
+            f"{devices[0].platform!r}")
+    if platform == "tpu" and ops_platform.interpret_mode():
+        raise RuntimeError("on a TPU the Pallas kernels must be compiled "
+                           "by Mosaic, but interpret_mode() is True")
+    return devices
+
+
+def check_close(name: str, got, want, tol: float, *, what="kernel") -> float:
+    """Print ``got`` against the float32 reference ``want`` and fail
+    past ``tol``, which is relative to the reference's largest value."""
+    import numpy as np
+
+    from ray_tpu.ops import platform as ops_platform
+
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    if got.shape != want.shape:
+        raise AssertionError(f"{name}: shape {got.shape} != {want.shape}")
+    if not np.isfinite(got).all():
+        raise AssertionError(f"{name}: non-finite values")
+    scale = max(float(np.max(np.abs(want))), 1e-6)
+    err = float(np.max(np.abs(got - want)))
+    how = "interpreted" if ops_platform.interpret_mode() else "compiled"
+    log(f"  {what}={name} {how} max_abs_err={err:.3e} ref_max={scale:.3e} "
+        f"rel={err / scale:.2e} tol={tol:.0e}")
+    if err > tol * scale:
+        raise AssertionError(
+            f"{name}: error {err:.3e} exceeds {tol:.0e} of {scale:.3e}")
+    return err / scale
+
+
+def peak_hbm(devices) -> list:
+    """peak_bytes_in_use per device (None where the backend has no
+    memory_stats, i.e. the CPU)."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
+
+
+def llama3_8b_int8():
+    """bench.py's 8B serving cell (published Llama-3-8B widths, full
+    depth, int8 KV pages; the weights are load_int8_params') on the
+    unfused path: the fused megakernel has its own check in
+    ``kernels``."""
+    import bench
+
+    return dataclasses.replace(bench.BENCH_8B_CFG, fused_decode=False)
+
+
+def load_int8_params(cfg, seed: int):
+    """Random int8 weights from a seed, built where they are used (in
+    the replica): no checkpoint, no network."""
+    import jax
+
+    from ray_tpu.models import quant
+
+    return quant.fuse_for_decode(
+        quant.init_quantized_llama(jax.random.key(seed), cfg), cfg)
+
+
+def load_params(cfg, seed: int):
+    import jax
+
+    from ray_tpu.models import llama
+
+    return llama.init_params(jax.random.key(seed), cfg)
+
+
+def prefill_logits_check(params, cfg, prompt, *, path: str,
+                         n_layers=None, tol: float = 5e-2) -> float:
+    """Last-prompt-token logits from the program the engine jits for
+    prefill (``path`` "ragged": adapter.ragged_step; "batch":
+    adapter.prefill_batch) against ``llama.forward`` in float32 on the
+    same weights (int8 leaves dequantized).  ``n_layers`` cuts both to
+    the first layers, for a model whose float32 copy would not fit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import llama, quant
+    from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
+    from ray_tpu.serve.llm_engine import llama_paged_adapter
+
+    if n_layers is not None and n_layers < cfg.n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+        params = dict(params, layers=jax.tree.map(
+            lambda a: a[:n_layers], params["layers"]))
+    adapter = llama_paged_adapter(cfg)
+    n = len(prompt)
+    maxp = -(-n // PAGE)
+    cache = adapter.init_cache(maxp, PAGE)
+    if path == "ragged":
+        T = -(-n // 8) * 8
+        (toks, _mask, _slot, pos, r_slot, r_start, r_len,
+         r_off) = pack_ragged_batch(
+            [{"slot": 0, "start": 0, "tokens": list(prompt)}], T, 1)
+        logits, _ = jax.jit(adapter.ragged_step)(
+            params, toks, pos, r_slot, r_start, r_len, r_off,
+            np.arange(maxp, dtype=np.int32)[None], cache)
+    else:
+        toks = np.zeros((1, maxp * PAGE), np.int32)
+        toks[0, :n] = prompt
+        logits, _ = jax.jit(adapter.prefill_batch)(
+            params, toks, np.asarray([n], np.int32),
+            np.arange(maxp, dtype=np.int32)[None], cache)
+    ref_cfg = dataclasses.replace(cfg, dtype=jnp.float32, kv_int8=False,
+                                  remat=False)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda p, t: llama.forward(
+            quant.dequantize_params(p, jnp.float32), t, ref_cfg)[0, -1])(
+                params, jnp.asarray(prompt, jnp.int32)[None])
+    return check_close(f"prefill_logits[{path},L={cfg.n_layers}]",
+                       logits[0], want, tol, what="logits")
+
+
+def fixed_prompts(cfg, n: int, length: int, seed: int = 1) -> list:
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, cfg.vocab_size, length).tolist()
+            for _ in range(n)]
+
+
+def check_answers(outs, new_tokens: int, vocab: int) -> None:
+    for i, toks in enumerate(outs):
+        if len(toks) != new_tokens:
+            raise AssertionError(
+                f"request {i}: {len(toks)} tokens, wanted {new_tokens}")
+        if not all(0 <= t < vocab for t in toks):
+            raise AssertionError(f"request {i}: token outside the vocab")
+
+
+# ---------------------------------------------------------------------------
+# phase: kernels
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelDims:
+    """Kernel widths: the defaults are the 8B serving cell's (32 query
+    heads over 8 KV heads of 128, 64-token pages) and the train cell's
+    flash shape; tests shrink them."""
+
+    heads: int = 32
+    kv_heads: int = 8
+    head_dim: int = 128
+    page: int = PAGE
+    slots: int = 16
+    maxp: int = 4
+    layers: int = 2
+    flash_batch: int = 2
+    flash_seq: int = 1024
+    flash_heads: int = 8
+    flash_kv_heads: int = 4
+    ssd: tuple = (2, 1024, 8, 64, 128, 128)     # B, S, H, P, N, chunk
+    fused_cfg: object = None     # None: llama3_8b_int8 cut to 2 layers
+
+
+def _rand(key, shape, dtype):
+    import jax
+
+    return jax.random.normal(key, shape, "float32").astype(dtype)
+
+
+def _kernels_flash(d: KernelDims, platform: str) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    ks = jax.random.split(jax.random.key(0), 4)
+    B, S, D = d.flash_batch, d.flash_seq, d.head_dim
+    q = _rand(ks[0], (B, S, d.flash_heads, D), jnp.bfloat16)
+    k = _rand(ks[1], (B, S, d.flash_kv_heads, D), jnp.bfloat16)
+    v = _rand(ks[2], (B, S, d.flash_kv_heads, D), jnp.bfloat16)
+    w = _rand(ks[3], q.shape, jnp.float32)
+    # One all-zero segment id masks nothing, and routes
+    # dot_product_attention to its einsum path: the reference.
+    seg = jnp.zeros((B, S), jnp.int32)
+
+    def ref(q, k, v):
+        return attention.dot_product_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), causal=True, segment_ids=seg)
+
+    def loss(f):
+        return lambda q, k, v: (f(q, k, v).astype(jnp.float32) * w).sum()
+
+    if platform == "tpu" and not attention._flash_eligible(
+            q, k, True, None, None):
+        raise AssertionError("the train shape must take the flash kernel")
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(ref)(q, k, v)
+        want_g = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
+    check_close("flash_attention.forward",
+                jax.jit(flash_attention)(q, k, v), want, 2e-2)
+    got_g = jax.jit(jax.grad(loss(flash_attention),
+                             argnums=(0, 1, 2)))(q, k, v)
+    for name, g, wg in zip(("dq", "dk", "dv"), got_g, want_g):
+        check_close(f"flash_attention.backward.{name}", g, wg, 3e-2)
+
+
+def _page_setup(d: KernelDims, seed: int):
+    """Random pools [L, KVH, P+1, page, D] (last page = scratch), a
+    shuffled block table and per-slot lengths that leave room for one
+    more token."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.key(seed), 2)
+    P = d.slots * d.maxp
+    shape = (d.layers, d.kv_heads, P + 1, d.page, d.head_dim)
+    k_pools = _rand(ks[0], shape, jnp.bfloat16)
+    v_pools = _rand(ks[1], shape, jnp.bfloat16)
+    bt = rng.permutation(P).astype(np.int32).reshape(d.slots, d.maxp)
+    lengths = rng.integers(1, d.maxp * d.page - 1, d.slots).astype(np.int32)
+    lengths[0] = d.page            # next append opens a fresh page
+    return k_pools, v_pools, bt, lengths
+
+
+def _quantize_pools(pools):
+    """int8 pools + page-major scales [L, P, KVH, 1], and the float32
+    values they stand for."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    q8, scale = llama._quant_pages(pools)          # scale [L, KVH, P]
+    deq = q8.astype(jnp.float32) * scale[..., None, None]
+    return q8, scale.transpose(0, 2, 1)[..., None], deq
+
+
+def _kernels_paged(d: KernelDims) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import paged_attention as pa
+
+    k_pools, v_pools, bt, lengths = _page_setup(d, 1)
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = _rand(ks[0], (d.slots, d.heads, d.head_dim), jnp.bfloat16)
+    layer = d.layers - 1
+    f32 = jnp.float32
+
+    def reference(kp, vp):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(pa.paged_decode_attention_reference)(
+                q.astype(f32), kp[layer].astype(f32),
+                vp[layer].astype(f32), bt, lengths)
+
+    want = reference(k_pools, v_pools)
+    check_close("paged_decode_attention", jax.jit(pa.paged_decode_attention)(
+        q, k_pools[layer], v_pools[layer], bt, lengths), want, 2e-2)
+    acc, _m, l = jax.jit(pa.paged_decode_attention_partial)(
+        q, k_pools, v_pools, jnp.int32(layer), bt, lengths)
+    check_close("paged_decode_attention_partial", acc / l, want, 2e-2)
+
+    k8, k_sc, k_deq = _quantize_pools(k_pools)
+    v8, v_sc, v_deq = _quantize_pools(v_pools)
+    acc, _m, l = jax.jit(
+        lambda q, k, v, ks, vs, ly, bt, ln: pa.paged_decode_attention_partial(
+            q, k, v, ly, bt, ln, k_scales=ks, v_scales=vs))(
+        q, k8, v8, k_sc, v_sc, jnp.int32(layer), bt, lengths)
+    check_close("paged_decode_attention_partial[int8]", acc / l,
+                reference(k_deq, v_deq), 2e-2)
+
+    # append: one new row per slot at (page of lengths, lengths % page)
+    k_new = _rand(ks[1], (d.layers, d.slots, d.kv_heads, d.head_dim),
+                  jnp.bfloat16)
+    v_new = _rand(ks[2], k_new.shape, jnp.bfloat16)
+    pids = bt[np.arange(d.slots), lengths // d.page]
+    offs = lengths % d.page
+    got_k, got_v = jax.jit(pa.paged_append)(
+        k_pools, v_pools, k_new, v_new, pids, offs)
+    for name, got, pool, new in (("k", got_k, k_pools, k_new),
+                                 ("v", got_v, v_pools, v_new)):
+        want_pool = np.array(pool.astype(f32))
+        want_pool[:, :, pids, offs] = np.asarray(
+            new.astype(f32)).transpose(0, 2, 1, 3)
+        check_close(f"paged_append.{name}", got, want_pool, 0.0)
+    got = jax.jit(pa.paged_append_quantized)(
+        k8, v8, k_sc, v_sc, k_new, v_new, pids, offs)
+    _check_int8_rows("paged_append_quantized", got, (k_new, v_new),
+                     [(np.arange(d.slots), pids, offs)])
+
+
+def _check_int8_rows(name, pools_and_scales, news, places) -> None:
+    """Appended rows of int8 pools, dequantized with their page's scale,
+    against the float32 rows that were appended: within one quantization
+    step.  ``places``: (index into the new rows, page id, offset)."""
+    import numpy as np
+
+    from ray_tpu.ops import platform as ops_platform
+
+    how = "interpreted" if ops_platform.interpret_mode() else "compiled"
+    k8, v8, k_sc, v_sc = (np.asarray(a) for a in pools_and_scales)
+    for which, q8, sc, new in (("k", k8, k_sc, news[0]),
+                               ("v", v8, v_sc, news[1])):
+        new = np.asarray(new.astype("float32"))       # [L, T|B, KVH, D]
+        worst = 0.0
+        for idx, pid, off in places:
+            rows = q8[:, :, pid, off].astype(np.float32)   # [L,KVH,n,D]
+            scale = sc[:, pid][..., 0].transpose(0, 2, 1)[..., None]
+            err = np.abs(rows * scale - new[:, idx].transpose(0, 2, 1, 3))
+            worst = max(worst, float(np.max(err / scale)))
+        log(f"  kernel={name}.{which} {how} max_err={worst:.3f} "
+            f"quantization steps (tol 1.0)")
+        if not worst <= 1.0:
+            raise AssertionError(f"{name}.{which}: {worst} steps off")
+
+
+def _kernels_ragged(d: KernelDims) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import ragged_paged_attention as rpa
+
+    k_pools, v_pools, bt, _ = _page_setup(d, 2)
+    page = d.page
+    # A mixed batch: decode rows, a prompt's first chunk, a later chunk
+    # that crosses a page boundary, and padding rows.
+    rows = [{"slot": 1, "start": page + 3, "tokens": None},
+            {"slot": 2, "start": 0, "tokens": [1] * (page // 2 + 3)},
+            {"slot": 3, "start": page - 5, "tokens": [1] * 11},
+            {"slot": 0, "start": 2 * page, "tokens": None}]
+    T = -(-(sum(len(r["tokens"] or [0]) for r in rows) + 5) // 8) * 8
+    R = d.slots
+    (_toks, _mask, _slot, _pos, r_slot, r_start, r_len,
+     r_off) = rpa.pack_ragged_batch(rows, T, R)
+    ks = jax.random.split(jax.random.key(12), 5)
+    q = _rand(ks[0], (T, d.heads, d.head_dim), jnp.bfloat16)
+    k_new = _rand(ks[1], (d.layers, T, d.kv_heads, d.head_dim),
+                  jnp.bfloat16)
+    v_new = _rand(ks[2], k_new.shape, jnp.bfloat16)
+    layer = d.layers - 1
+    f32 = jnp.float32
+    meta = (r_slot, r_start, r_len, r_off, bt)
+
+    def reference(kp, vp, **scales):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(functools.partial(
+                rpa.ragged_attention_reference, **scales))(
+                    q.astype(f32), k_new[layer].astype(f32),
+                    v_new[layer].astype(f32), kp[layer], vp[layer], *meta)
+
+    check_close("ragged_paged_attention", jax.jit(rpa.ragged_paged_attention)(
+        q, k_new[layer], v_new[layer], k_pools, v_pools, jnp.int32(layer),
+        *meta), reference(k_pools.astype(f32), v_pools.astype(f32)), 2e-2)
+    k8, k_sc, _ = _quantize_pools(k_pools)
+    v8, v_sc, _ = _quantize_pools(v_pools)
+    check_close("ragged_paged_attention[int8]", jax.jit(
+        lambda q, k, v, kp, vp, ks, vs, ly, *m: rpa.ragged_paged_attention(
+            q, k, v, kp, vp, ly, *m, k_scales=ks, v_scales=vs))(
+        q, k_new[layer], v_new[layer], k8, v8, k_sc, v_sc,
+        jnp.int32(layer), *meta),
+        reference(k8, v8, k_scales=k_sc[layer], v_scales=v_sc[layer]), 2e-2)
+
+    got_k, got_v = jax.jit(rpa.ragged_paged_append)(
+        k_pools, v_pools, k_new, v_new, *meta)
+    for li in range(d.layers):
+        want_k, want_v = jax.jit(rpa.ragged_append_reference)(
+            k_pools[li], v_pools[li], k_new[li], v_new[li], *meta)
+        # the last physical page is scratch: padding writes land there
+        check_close(f"ragged_paged_append.k[layer {li}]",
+                    got_k[li][:, :-1], want_k[:, :-1], 0.0)
+        check_close(f"ragged_paged_append.v[layer {li}]",
+                    got_v[li][:, :-1], want_v[:, :-1], 0.0)
+    got = jax.jit(rpa.ragged_paged_append_quantized)(
+        k8, v8, k_sc, v_sc, k_new, v_new, *meta)
+    places = []
+    for i in range(len(rows)):
+        pos = r_start[i] + np.arange(r_len[i])
+        places.append((r_off[i] + np.arange(r_len[i]),
+                       bt[r_slot[i], pos // page], pos % page))
+    _check_int8_rows("ragged_paged_append_quantized", got, (k_new, v_new),
+                     places)
+
+
+def _kernels_ssd(d: KernelDims) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.mamba2 import ssd_chunked
+    from ray_tpu.ops.mamba_ssd import ssd_pallas
+
+    B, S, H, P, N, chunk = d.ssd
+    ks = jax.random.split(jax.random.key(3), 4)
+    x = _rand(ks[0], (B, S, H, P), jnp.float32)
+    la = -jax.nn.softplus(_rand(ks[1], (B, S, H), jnp.float32))
+    Bm = _rand(ks[2], (B, S, N), jnp.float32) * 0.3
+    Cm = _rand(ks[3], (B, S, N), jnp.float32) * 0.3
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: ssd_chunked(*a, chunk=chunk))(
+            x, la, Bm, Cm)
+    check_close("ssd_pallas",
+                jax.jit(lambda *a: ssd_pallas(*a, chunk))(x, la, Bm, Cm),
+                want, 2e-2)
+
+
+def _kernels_fused(d: KernelDims) -> None:
+    """The fused per-layer megakernels against the unfused path: one
+    decode step and one ragged step over the same random int8 cache."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
+
+    cfg = d.fused_cfg or dataclasses.replace(
+        llama3_8b_int8(), n_layers=2, max_seq_len=d.maxp * d.page)
+    fused = dataclasses.replace(cfg, fused_decode=True)
+    params = load_int8_params(cfg, 0)
+    dims = dataclasses.replace(d, heads=cfg.n_heads,
+                               kv_heads=cfg.n_kv_heads,
+                               head_dim=cfg.head_dim, layers=cfg.n_layers)
+    k_pools, v_pools, bt, lengths = _page_setup(dims, 4)
+    k8, k_sc, _ = _quantize_pools(k_pools * 0.3)
+    v8, v_sc, _ = _quantize_pools(v_pools * 0.3)
+    cache = {"k": k8, "v": v8, "k_scale": k_sc, "v_scale": v_sc}
+    toks = np.random.default_rng(5).integers(
+        1, cfg.vocab_size, d.slots).astype(np.int32)
+    active = np.ones((d.slots,), bool)
+
+    def decode(c):
+        return jax.jit(lambda p, t, a, b, ln, ch: llama.decode_slots_paged(
+            p, t, a, b, ln, c, ch)[0])(params, toks, active, bt, lengths,
+                                       cache)
+
+    check_close("fused_decode_layer (decode_slots_paged fused vs unfused)",
+                decode(fused), decode(cfg), 5e-2)
+
+    rows = [{"slot": 1, "start": int(lengths[1]), "tokens": None},
+            {"slot": 2, "start": 0, "tokens": toks[:9].tolist()},
+            {"slot": 3, "start": int(lengths[3]), "tokens": None}]
+    (t_host, _mask, _slot, pos, r_slot, r_start, r_len,
+     r_off) = pack_ragged_batch(rows, 16, d.slots)
+    t_host[r_off[0]] = toks[1]
+    t_host[r_off[2]] = toks[3]
+
+    def ragged(c):
+        return jax.jit(lambda p, ch: llama.ragged_step_paged(
+            p, t_host, pos, r_slot, r_start, r_len, r_off, bt, c, ch)[0])(
+                params, cache)[:len(rows)]
+
+    check_close("fused_ragged_layer (ragged_step_paged fused vs unfused)",
+                ragged(fused), ragged(cfg), 5e-2)
+
+
+def phase_kernels(platform: str, *, dims: KernelDims = KernelDims()) -> dict:
+    require_platform(platform)
+    _kernels_flash(dims, platform)
+    _kernels_paged(dims)
+    _kernels_ragged(dims)
+    _kernels_ssd(dims)
+    _kernels_fused(dims)
+    return {}
+
+
+# ---------------------------------------------------------------------------
+# phase: train
+# ---------------------------------------------------------------------------
+
+
+def phase_train(platform: str, *, cfg=None, batch: int = 8,
+                seq: int = 2048, steps: int = 5, mesh_spec=None,
+                devices=None, expect_loss0=None) -> dict:
+    """``JaxTrainer.fit`` for ``steps`` steps on ONE fixed batch: the
+    loss starts near ln(vocab) (random weights) and falls."""
+    import itertools
+
+    import numpy as np
+
+    import bench
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.train import (
+        JaxTrainer,
+        RunConfig,
+        ScalingConfig,
+        default_optimizer,
+    )
+
+    all_devices = require_platform(platform)
+    cfg = cfg or bench.BENCH_CFG
+    devices = devices or all_devices[:1]
+    trainer = JaxTrainer(
+        init_params=lambda r: llama.init_params(r, cfg),
+        loss_fn=lambda p, b: llama.loss_fn(p, b, cfg),
+        params_axes=llama.logical_axes(cfg),
+        batch_axes={"tokens": ("batch", None)},
+        # lr 0 at step 0, full from step 2: five steps on one batch move
+        optimizer=default_optimizer(1e-3, warmup_steps=2),
+        scaling_config=ScalingConfig(
+            mesh_spec=mesh_spec or MeshSpec(dp=1), devices=devices),
+        run_config=RunConfig(report_every=1),
+    )
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (batch, seq), dtype=np.int64).astype(np.int32)
+    t0 = time.perf_counter()
+    stamps = []
+    result = trainer.fit(itertools.repeat({"tokens": tokens}),
+                         num_steps=steps,
+                         report=lambda m: stamps.append(time.perf_counter()))
+    if result.error is not None:
+        raise result.error
+    losses = [m["loss"] for m in result.metrics_history]
+    log(f"  train {cfg.num_params() / 1e6:.0f}M B={batch} S={seq} on "
+        f"{len(devices)} device(s) mesh={dict(trainer.mesh.shape)}: "
+        f"losses={[round(x, 4) for x in losses]}")
+    ln_v = math.log(cfg.vocab_size)
+    if len(losses) != steps or not all(math.isfinite(x) for x in losses):
+        raise AssertionError(f"want {steps} finite losses, got {losses}")
+    if abs(losses[0] - ln_v) > 1.0:
+        raise AssertionError(
+            f"step-0 loss {losses[0]:.3f} is not near ln(V)={ln_v:.3f}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"loss did not fall: {losses}")
+    if expect_loss0 is not None and abs(losses[0] - expect_loss0) > 2e-2:
+        raise AssertionError(
+            f"step-0 loss {losses[0]:.4f} differs from the one-device "
+            f"value {expect_loss0:.4f} by more than 0.02")
+    peaks = peak_hbm(devices)
+    steady = (stamps[-1] - stamps[1]) / (steps - 2) if steps > 2 else None
+    log(f"  train first step (compile included) "
+        f"{stamps[0] - t0:.1f}s, later steps "
+        f"{'n/a' if steady is None else f'{steady:.3f}s'} each "
+        f"(smoke timing); peak HBM per device: "
+        f"{[None if p is None else round(p / 2**30, 2) for p in peaks]} GiB")
+    return {"loss0": losses[0], "loss_last": losses[-1],
+            "peak_hbm_bytes": peaks}
+
+
+# ---------------------------------------------------------------------------
+# phase: serve
+# ---------------------------------------------------------------------------
+
+
+def smoke_server_class():
+    """LLMServer plus what the smoke asks of a replica: where it runs,
+    what it compiled, and whether its prefill agrees with the reference.
+    Built in a function so that importing this file imports no JAX."""
+    from ray_tpu.serve.llm_engine import LLMServer
+
+    class SmokeLLMServer(LLMServer):
+        def __init__(self, model_cfg, engine_cfg, param_loader, **kw):
+            self._clock = CompileClock()
+            self._model_cfg = model_cfg
+            super().__init__(model_cfg, engine_cfg, param_loader, **kw)
+
+        def device_report(self) -> dict:
+            import jax
+
+            devices = jax.devices()
+            return {"pid": os.getpid(),
+                    "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS"),
+                    "platform": devices[0].platform,
+                    "kind": devices[0].device_kind,
+                    "count": len(devices),
+                    "peak_hbm_bytes": peak_hbm(devices),
+                    **self._clock.report()}
+
+        def prefill_check(self, prompt, n_layers) -> float:
+            return prefill_logits_check(
+                self.engine._params, self._model_cfg, prompt,
+                path="ragged", n_layers=n_layers)
+
+    return SmokeLLMServer
+
+
+def phase_serve(platform: str, *, cfg=None, slots: int = 48,
+                n_requests: int = 8, prompt_len: int = 128,
+                new_tokens: int = 16, ref_layers: int = 2,
+                ready_timeout_s: float = 900.0) -> dict:
+    """The serving entry points, end to end: ``serve.run`` of an
+    ``LLMServer`` deployment, requests through the handle.  This process
+    is the caller and stays off the chip; the replica asks for the
+    host's chips (``num_tpus``) and must report ``platform``."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.models import quant
+    from ray_tpu.serve.llm_engine import EngineConfig
+    from ray_tpu.utils import accelerator
+
+    cfg = cfg or llama3_8b_int8()
+    ray_tpu.init(ignore_reinit_error=True)
+    try:
+        chips = int(ray_tpu.cluster_resources().get("TPU", 0))
+        if platform == "tpu" and chips < 1:
+            raise RuntimeError(
+                "ray_tpu.init() found no TPU chip on this host "
+                "(no /dev/accel* or /dev/vfio/<N>)")
+        log(f"  caller pid={os.getpid()}: ray_tpu.init() sees TPU={chips} "
+            f"without JAX; backend initialised: "
+            f"{accelerator.backend_initialised()}")
+        app = serve.deployment(
+            ray_actor_options=({"num_tpus": chips} if platform == "tpu"
+                               else {}),
+            max_ongoing_requests=2 * n_requests,
+        )(smoke_server_class()).bind(
+            cfg,
+            EngineConfig(max_slots=slots, max_seq_len=cfg.max_seq_len,
+                         page_size=PAGE, ragged_batching=True,
+                         max_new_tokens_default=new_tokens),
+            functools.partial(load_int8_params, cfg, 0),
+            adapter_factory=quant.llama_paged_adapter_quant,
+        )
+        t0 = time.perf_counter()
+        handle = serve.run(app, name="chip_smoke", route_prefix=None,
+                           timeout_s=ready_timeout_s)
+        log(f"  replica ready after {time.perf_counter() - t0:.1f}s "
+            f"(weights built in the replica)")
+        prompts = fixed_prompts(cfg, n_requests, prompt_len)
+        t0 = time.perf_counter()
+        pending = [handle.remote({"tokens": p, "max_new_tokens": new_tokens,
+                                  "temperature": 0.0}) for p in prompts]
+        outs = [r.result(timeout_s=ready_timeout_s)["tokens"]
+                for r in pending]
+        check_answers(outs, new_tokens, cfg.vocab_size)
+        log(f"  {len(outs)} of {n_requests} requests answered "
+            f"({prompt_len} prompt + {new_tokens} new tokens each) in "
+            f"{time.perf_counter() - t0:.1f}s, first compile included "
+            f"(smoke timing)")
+        rel = handle.prefill_check.remote(
+            prompts[0], ref_layers).result(timeout_s=ready_timeout_s)
+        rep = handle.device_report.remote().result(timeout_s=60)
+        log(f"  replica pid={rep['pid']} JAX_PLATFORMS="
+            f"{rep['JAX_PLATFORMS']} platform={rep['platform']} "
+            f"device_kind={rep['kind']!r} count={rep['count']} "
+            f"compile_s={rep['compile_s']} cache_hits={rep['cache_hits']} "
+            f"cache_misses={rep['cache_misses']}")
+        if rep["platform"] != platform:
+            raise AssertionError(
+                f"replica computes on {rep['platform']!r}, not "
+                f"{platform!r}")
+        if rep["pid"] == os.getpid():
+            raise AssertionError("the replica must be its own process")
+        if accelerator.backend_initialised():
+            raise AssertionError(
+                "the process that called serve.run initialised a JAX "
+                "backend: it would hold the chip")
+        log("  caller never initialised a JAX backend")
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    return {"device": {k: rep[k] for k in ("platform", "kind", "count")},
+            "prefill_rel_err": rel, "compile_s": rep["compile_s"],
+            "cache_hits": rep["cache_hits"],
+            "cache_misses": rep["cache_misses"]}
+
+
+# ---------------------------------------------------------------------------
+# phase: engine_legacy
+# ---------------------------------------------------------------------------
+
+
+def phase_engine_legacy(platform: str, *, cfg=None, slots: int = 64,
+                        n_requests: int = 8, prompt_len: int = 128,
+                        new_tokens: int = 16, mesh=None,
+                        long_prompt: int = 0) -> dict:
+    """``LLMEngine`` in-process on the two-program path (batched prefill
+    + decode chunks, ``ragged_batching=False``): the only path with chip
+    history (BENCH_r05's serving shape).  With ``mesh`` it is the
+    tensor-parallel engine, and ``long_prompt`` adds a prompt long
+    enough to enter the flash kernel under shard_map."""
+    import bench
+    from ray_tpu.serve.llm_engine import (
+        EngineConfig,
+        LLMEngine,
+        llama_paged_adapter,
+    )
+
+    require_platform(platform)
+    cfg = cfg or dataclasses.replace(bench.BENCH_CFG, max_seq_len=512)
+    params = load_params(cfg, 0)
+    eng = LLMEngine(
+        params, llama_paged_adapter(cfg),
+        EngineConfig(max_slots=slots, max_seq_len=cfg.max_seq_len,
+                     decode_chunk=8, page_size=PAGE,
+                     max_new_tokens_default=new_tokens,
+                     ragged_batching=False),
+        mesh=mesh)
+    try:
+        prompts = fixed_prompts(cfg, n_requests, prompt_len)
+        if long_prompt:
+            prompts[0] = fixed_prompts(cfg, 1, long_prompt, seed=2)[0]
+        t0 = time.perf_counter()
+        streams = [eng.submit(p, max_new_tokens=new_tokens, temperature=0.0)
+                   for p in prompts]
+        outs = [s.result(timeout_s=900) for s in streams]
+        check_answers(outs, new_tokens, cfg.vocab_size)
+        log(f"  two-program engine"
+            f"{'' if mesh is None else ' on mesh ' + str(dict(mesh.shape))}"
+            f": {len(outs)} of {n_requests} requests answered (prompts "
+            f"{sorted({len(p) for p in prompts})}, {new_tokens} new tokens)"
+            f" in {time.perf_counter() - t0:.1f}s, compile included "
+            f"(smoke timing)")
+    finally:
+        eng.shutdown()
+        eng._thread.join(timeout=60)
+    rel = None
+    if mesh is None:
+        rel = prefill_logits_check(params, cfg, prompts[0], path="batch")
+    return {"prefill_rel_err": rel}
+
+
+# ---------------------------------------------------------------------------
+# phase: multichip
+# ---------------------------------------------------------------------------
+
+
+def phase_multichip(platform: str, *, expect_loss0=None, cfg=None,
+                    batch: int = 8, seq: int = 2048, serve_cfg=None,
+                    prompt_len: int = 128, long_prompt: int = 512,
+                    n: int = 4) -> dict:
+    """Four chips in one process: the train phase at fsdp=n (step-0 loss
+    equal to the one-device value, memory in use on every device), then
+    the tensor-parallel engine answering a prompt long enough for the
+    flash kernel."""
+    import bench
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.parallel.mesh import create_serving_mesh
+
+    devices = require_platform(platform)[:n]
+    if len(devices) < n:
+        raise RuntimeError(f"multichip wants {n} devices, found "
+                           f"{len(devices)}")
+    out = phase_train(platform, cfg=cfg, batch=batch, seq=seq,
+                      mesh_spec=MeshSpec(dp=1, fsdp=n), devices=devices,
+                      expect_loss0=expect_loss0)
+    peaks = out["peak_hbm_bytes"]
+    if platform == "tpu" and not all(p and p > 2**28 for p in peaks):
+        raise AssertionError(f"fsdp={n} left a device idle: {peaks}")
+    serve_cfg = serve_cfg or dataclasses.replace(
+        bench.BENCH_CFG, max_seq_len=1024)
+    phase_engine_legacy(
+        platform, cfg=dataclasses.replace(serve_cfg, tensor_parallel=True),
+        slots=16, mesh=create_serving_mesh(1, n, devices=devices),
+        prompt_len=prompt_len, long_prompt=long_prompt)
+    return out
+
+
+def _chip_owner_report():
+    import jax
+
+    return {"pid": os.getpid(), "platform": jax.devices()[0].platform,
+            "count": len(jax.devices()),
+            "ids": [d.id for d in jax.devices()],
+            "visible": os.environ.get("TPU_VISIBLE_CHIPS")}
+
+
+def phase_owners(platform: str, *, n: int = 2) -> dict:
+    """One owner per chip on a host with several: ``n`` tasks that each
+    ask for one chip run at once, each in its own process, each seeing
+    exactly one device.  The caller stays off the chip."""
+    import ray_tpu
+    from ray_tpu.utils import accelerator
+
+    ray_tpu.init(ignore_reinit_error=True)
+    try:
+        probe = ray_tpu.remote(num_tpus=1, num_cpus=0)(_chip_owner_report)
+        reps = ray_tpu.get([probe.remote() for _ in range(n)], timeout=300)
+        for rep in reps:
+            log(f"  chip owner {rep}")
+        if any(r["platform"] != platform or r["count"] != 1 for r in reps):
+            raise AssertionError(f"want one {platform} device each: {reps}")
+        if len({r["pid"] for r in reps} | {os.getpid()}) != n + 1:
+            raise AssertionError(f"owners must be separate processes: "
+                                 f"{reps}")
+        if accelerator.backend_initialised():
+            raise AssertionError("the caller initialised a JAX backend")
+    finally:
+        ray_tpu.shutdown()
+    return {}
+
+
+# ---------------------------------------------------------------------------
+# child: one phase in its own process
+# ---------------------------------------------------------------------------
+
+
+def run_child(phase: str, expect_loss0) -> int:
+    t0 = time.perf_counter()
+    import jax
+
+    from ray_tpu.utils import accelerator
+
+    report: dict = {"phase": phase}
+    if phase in ("serve", "owners"):
+        # The caller of serve.run / ray_tpu.init must stay off the chip.
+        # Pinned all the same: if it ever touches JAX it takes the chip,
+        # the replica cannot get it, and the phase fails.
+        jax.config.update("jax_platforms", "tpu")
+        fn = phase_serve if phase == "serve" else phase_owners
+        report.update(fn("tpu"))
+    else:
+        clock = CompileClock()
+        try:
+            held = accelerator.claim_tpu()
+        except RuntimeError as e:
+            jax.config.update("jax_platforms", "cpu")
+            found = jax.devices()[0]
+            log(f"chip_smoke[{phase}]: no TPU for this process; JAX finds "
+                f"platform={found.platform!r} device_kind="
+                f"{found.device_kind!r} (JAX_PLATFORMS="
+                f"{os.environ.get('JAX_PLATFORMS')!r}): {e}")
+            return 3
+        log(f"  {phase} pid={os.getpid()} "
+            + " ".join(f"{k}={v}" for k, v in held.items()))
+        report["device"] = {"platform": held["platform"],
+                            "kind": held["device_kind"],
+                            "count": held["count"]}
+        fn = {"kernels": phase_kernels, "train": phase_train,
+              "engine_legacy": phase_engine_legacy,
+              "multichip": functools.partial(
+                  phase_multichip, expect_loss0=expect_loss0)}[phase]
+        report.update(fn("tpu"))
+        report.update(clock.report())
+    report["wall_s"] = round(time.perf_counter() - t0, 1)
+    log(REPORT_TAG + json.dumps(report))
+    return 0
+
+
+# ---------------------------------------------------------------------------
+# parent: no JAX, one child at a time
+# ---------------------------------------------------------------------------
+
+
+def run_phase(phase: str, deadline: float, extra=()) -> dict:
+    """Run one phase as a child in its own process group, pass its
+    output through, and leave no process behind.  Returns the child's
+    report; raises SystemExit(child's code) when it fails."""
+    log(f"== phase {phase}")
+    proc = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), "--phase", phase,
+         *extra],
+        stdout=subprocess.PIPE, text=True, start_new_session=True)
+    report = None
+    timer = None
+    try:
+        timer = threading.Timer(max(1.0, deadline - time.monotonic()),
+                                lambda: os.killpg(proc.pid, signal.SIGKILL))
+        timer.start()
+        for line in proc.stdout:
+            if line.startswith(REPORT_TAG):
+                report = json.loads(line[len(REPORT_TAG):])
+            else:
+                sys.stdout.write(line)
+                sys.stdout.flush()
+        rc = proc.wait()
+    finally:
+        if timer is not None:
+            timer.cancel()
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)   # workers it left behind
+        except ProcessLookupError:
+            pass
+    if rc != 0 or report is None:
+        log(f"chip_smoke: phase {phase} FAILED (exit code {rc})")
+        raise SystemExit(rc or 1)
+    log(f"== phase {phase} ok: {timing(report)}")
+    return report
+
+
+def timing(report: dict) -> str:
+    """One phase's smoke timing; compile seconds where the phase has a
+    process that compiles (``owners`` does not report any)."""
+    if "compile_s" not in report:
+        return f"wall {report['wall_s']}s"
+    return (f"wall {report['wall_s']}s, compile {report['compile_s']}s, "
+            f"persistent-cache hits {report['cache_hits']} misses "
+            f"{report['cache_misses']}")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--phase", choices=PHASES + ("owners",),
+                    help="run one phase in THIS process (what the parent "
+                         "starts; also useful alone)")
+    ap.add_argument("--expect-loss0", type=float, default=None,
+                    help="multichip: the one-device step-0 loss")
+    args = ap.parse_args(argv)
+    if args.phase:
+        return run_child(args.phase, args.expect_loss0)
+
+    if "jax" in sys.modules:
+        raise RuntimeError("the chip_smoke parent must not import JAX: it "
+                           "would hold the chip its children need")
+    t0 = time.monotonic()
+    deadline = t0 + BUDGET_S
+    reports = {}
+    for phase in PHASES:
+        if phase == "multichip":
+            count = reports["kernels"]["device"]["count"]
+            if count < 4:
+                log(f"== multichip: not run ({count} devices)")
+                continue
+            reports["owners"] = run_phase("owners", deadline)
+            reports[phase] = run_phase(
+                phase, deadline,
+                ("--expect-loss0", repr(reports["train"]["loss0"])))
+        else:
+            reports[phase] = run_phase(phase, deadline)
+    log("== smoke timings (not measurements): "
+        + "; ".join(f"{p}: {timing(r)}" for p, r in reports.items())
+        + f"; total {time.monotonic() - t0:.0f}s")
+    devices = {json.dumps(r["device"], sort_keys=True)
+               for r in reports.values() if "device" in r}
+    if len(devices) != 1:
+        log(f"chip_smoke: phases disagree about the device: {devices}")
+        return 1
+    print(json.dumps({"ok": True, "device": reports["kernels"]["device"]}),
+          flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    # Re-enter as the importable module ``chip_smoke``: what the serve
+    # phase ships to its replica is then pickled by reference to a module
+    # the worker can import, not by value out of ``__main__``.
+    import chip_smoke
+
+    sys.exit(chip_smoke.main())

@@ -1,6 +1,6 @@
 """User-facing error types.
 
-Parity with the reference's exception taxonomy
+Parity with the reference's exception hierarchy
 (ray: python/ray/exceptions.py): task failures are captured where they
 happen, serialized, and re-raised at every ``get`` of the poisoned ref,
 with the remote traceback attached.

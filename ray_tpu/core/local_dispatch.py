@@ -163,6 +163,8 @@ class LocalDispatcher:
         if not self.view_fresh():
             return None
         demand = opts.resource_demand()
+        if demand.get("TPU"):
+            return None  # chip leases are the head path's (one owner)
         with self._view_lock:
             mine = (self._view or {}).get(self.d.node_hex)
         if mine is None:

@@ -93,6 +93,7 @@ class RemoteWorkerHandle:
         self.node_hex = agent.node_hex
         self.dead = False
         self.dedicated = False
+        self.tpu_chips = 0  # chips bound to the process (0 = off them)
         self.on_death = None
         self._direct: Optional[MsgChannel] = None
         self._direct_retry_at = 0.0
@@ -222,14 +223,23 @@ class RemoteNodeAgent:
 
     # -- worker leasing (same surface as WorkerPool) -----------------------
 
-    def lease(self, dedicated: bool = False) -> RemoteWorkerHandle:
+    def lease(self, dedicated: bool = False,
+              tpu_chips: int = 0) -> RemoteWorkerHandle:
         """Free-listed lease with bounded in-flight lease RPCs: a burst
         of N tasks must not turn into N concurrent lease requests (and
         N spawn attempts) at the daemon — excess requesters park in a
         FIFO and are handed a freed worker directly (parity: bounded
         pending lease requests + OnWorkerIdle pushing onto released
-        workers, direct_task_transport.cc:191)."""
+        workers, direct_task_transport.cc:191).  A ``tpu_chips`` lease
+        goes straight to the daemon: free-listed workers were started
+        off the chip (WorkerPool.lease)."""
         from ray_tpu.utils.config import get_config
+
+        if tpu_chips:
+            return self._adopt(
+                self.chan.call("lease", dedicated=dedicated,
+                               tpu_chips=tpu_chips),
+                dedicated, tpu_chips)
 
         cfg = get_config()
         max_inflight = max(
@@ -290,15 +300,22 @@ class RemoteNodeAgent:
                 with self._lock:
                     self._busy_until = time.monotonic() + 0.5
                 continue
-            wh = RemoteWorkerHandle(self, rep["wid"], rep["key"],
-                                    rep["pid"], wport=rep.get("wport"))
-            wh.dedicated = dedicated
-            with self._lock:
-                self._leased[wh.wid] = wh
-            return wh
+            return self._adopt(rep, dedicated)
+
+    def _adopt(self, rep: Dict[str, Any], dedicated: bool,
+               tpu_chips: int = 0) -> RemoteWorkerHandle:
+        wh = RemoteWorkerHandle(self, rep["wid"], rep["key"], rep["pid"],
+                                wport=rep.get("wport"))
+        wh.dedicated = dedicated
+        wh.tpu_chips = tpu_chips
+        with self._lock:
+            self._leased[wh.wid] = wh
+        return wh
 
     def release(self, wh: RemoteWorkerHandle) -> None:
-        if not wh.dead and not wh.dedicated:
+        # A chip lease is never cached head-side: it goes back to the
+        # daemon's pool, whose release ends the process (one owner).
+        if not wh.dead and not wh.dedicated and not wh.tpu_chips:
             with self._lock:
                 if not self._closed:
                     # Hand the worker straight to the oldest parked
@@ -962,7 +979,8 @@ class NodeDaemon:
         op = msg["op"]
         if op == "lease":
             wh = self.pool.lease(dedicated=msg.get("dedicated", False),
-                                 block=msg.get("block", True))
+                                 block=msg.get("block", True),
+                                 tpu_chips=msg.get("tpu_chips", 0))
             if wh is None:
                 return {"busy": True}
             self._hook_death(wh)

@@ -25,6 +25,7 @@ import contextlib
 import dataclasses
 import inspect as _inspect
 import itertools
+import math
 import threading
 import time
 import queue as _queue
@@ -76,6 +77,12 @@ def _tracing():
 
         _tracing_mod = tracing
     return _tracing_mod
+
+
+def _tpu_chips(demand: Dict[str, float]) -> int:
+    """Chips a worker must own for this demand: a chip has one owner,
+    so any share of one is the whole chip."""
+    return math.ceil(demand.get("TPU", 0))
 
 
 @dataclasses.dataclass
@@ -926,7 +933,8 @@ class _ProcessActorShell(_ActorShell):
         import cloudpickle as _cp
 
         pool = self.runtime._pool_for(self.allocation)
-        wh = pool.lease(dedicated=True)
+        wh = pool.lease(dedicated=True,
+                        tpu_chips=_tpu_chips(self.allocation.demand))
         try:
             # Init args ship raw — ObjectRefs stay refs, matching the
             # thread shell (the instance resolves them itself if/when
@@ -2431,7 +2439,9 @@ class LocalRuntime:
         wire_args, wire_kwargs = self._wire_args(pt.args, pt.kwargs)
         spec = cloudpickle.dumps((wire_args, wire_kwargs))
         fhash, fblob = self._export_fn(pt.fn)
-        wh = pool.lease()
+        wh = pool.lease(tpu_chips=_tpu_chips(
+            pt.demand if pt.demand is not None
+            else pt.options.resource_demand()))
         with self._lock:
             entry = self._running_tasks.get(pt.task_id)
             if entry is not None:

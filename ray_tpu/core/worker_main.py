@@ -500,6 +500,7 @@ class _WorkerServer:
         # sidesteps the entire class of bug.
         self._task_exec = _ActorExecutor(1)
         self._exit = threading.Event()
+        self._chips_claimed = False
         # In-flight pushed work: the 1s ref sweep only flushes when
         # idle, so a sweep-sent del can't overtake a reply-attached add.
         self._busy = 0
@@ -580,6 +581,21 @@ class _WorkerServer:
         return tracing.activate(ctx)
 
     # -- request handling --------------------------------------------------
+
+    def _claim_chips(self) -> None:
+        """A worker spawned for a ``TPU`` lease (worker_pool.spawn pinned
+        JAX_PLATFORMS=tpu) owns its chips before it runs anything: the
+        backend is initialised here, so a chip it cannot get fails the
+        task or the actor's construction instead of a CPU run."""
+        if os.environ.get("JAX_PLATFORMS") != "tpu" or self._chips_claimed:
+            return
+        from ray_tpu.utils import accelerator
+
+        report = accelerator.claim_tpu()
+        self._chips_claimed = True
+        print(f"[ray_tpu worker {os.getpid()}] holds "
+              + " ".join(f"{k}={v}" for k, v in report.items()),
+              file=sys.stderr)
 
     def handle(self, chan: MsgChannel, msg: Dict[str, Any]) -> Any:
         op = msg["op"]
@@ -731,6 +747,7 @@ class _WorkerServer:
             self._wr.refs.flush()
 
     def _run_task(self, msg: Dict[str, Any]) -> Any:
+        self._claim_chips()
         fhash = msg.get("fn_hash")
         if fhash is not None:
             # Ship-once function protocol: the blob rides the first
@@ -845,6 +862,7 @@ class _WorkerServer:
         )
 
     def _actor_create(self, msg: Dict[str, Any]) -> None:
+        self._claim_chips()
         cls, args, kwargs = cloudpickle.loads(msg["spec"])
         args, kwargs = self._decode_args(args, kwargs)
         self._actor_env = msg.get("env")

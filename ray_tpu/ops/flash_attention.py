@@ -28,6 +28,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import platform
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_KV = 512
 NEG_INF = -1e30
@@ -133,7 +135,7 @@ def _flash_forward(q, k, v, *, scale, causal, block_q, block_kv):
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         out_shape=out_shape,
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(q, k, v)
 
 
@@ -269,7 +271,7 @@ def _flash_backward(q, k_exp, v_exp, o, lse, do, *, scale, causal,
                                lambda b, h, i, j: (b, h, i, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(q, k_exp, v_exp, do, lse, delta)
 
     # dk/dv: swap loop order — kv blocks outer, q blocks inner
@@ -298,7 +300,7 @@ def _flash_backward(q, k_exp, v_exp, o, lse, do, *, scale, causal,
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         ],
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(q, k_exp, v_exp, do, lse, delta)
     return dq, dk, dv
 
@@ -306,13 +308,6 @@ def _flash_backward(q, k_exp, v_exp, o, lse, do, *, scale, causal,
 # --------------------------------------------------------------------------
 # custom-vjp wrapper
 # --------------------------------------------------------------------------
-
-_INTERPRET = False
-
-
-def _interpret_mode() -> bool:
-    return _INTERPRET or jax.devices()[0].platform == "cpu"
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, block_q, block_kv):

@@ -40,11 +40,15 @@ decode_slots_paged):
     apply to matmul RESULTS inside the kernel, so HBM moves int8.
 
 Numerics are tolerance-gated against the unfused path in interpret
-mode on CPU (tests/test_fused_decode.py).  Some scratch access
-patterns (static middle-dim indexing of 4-D VMEM scratch, dynamic
-leading-dim indexing by the in-phase cell id) are interpret-clean and
-believed Mosaic-lowerable, but per-pattern tile tuning on hardware is
-expected follow-up; tile sizes are keyword-tunable for that reason.
+mode on CPU (tests/test_fused_decode.py), the kernel is compiled by
+Mosaic for a v5e at the 319M and 8B-int8 shapes in
+tests/test_mosaic_aot.py, and chip_smoke.py's ``kernels`` phase checks
+it against the unfused path on the chip.  Its scratch (activations,
+flash state, residual stream for every slot) plus double-buffered
+weight tiles pass the 16 MiB scoped-VMEM default at 48 slots of 8B
+width, hence ``vmem_limit_bytes`` below.  Whether it is FASTER than the
+unfused path has not been measured on this tree (ROADMAP Queue 1
+item 5); tile sizes are keyword-tunable for that work.
 """
 
 from __future__ import annotations
@@ -58,7 +62,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.paged_attention import _MIN_QPG, NEG_INF, _interpret_mode
+from ray_tpu.ops import platform
+from ray_tpu.ops.paged_attention import _MIN_QPG, NEG_INF
 
 
 def _qdict(node) -> bool:
@@ -261,11 +266,11 @@ def _fused_kernel(*refs, B: int, D: int, H: int, KVH: int, qpg: int,
     # ---- grid end: down-proj scale + second residual ------------------
     @pl.when(t == S4 - 1)
     def _final():
-        sdv = sd_ref[...].astype(jnp.float32)
         for j in range(To):
             sl = slice(j * to, (j + 1) * to)
-            xo_ref[:, sl] = (h_s[j] + y_s[:, sl] * sdv[:, sl]).astype(
-                xo_ref.dtype)
+            xo_ref[:, sl] = (
+                h_s[j] + y_s[:, sl] * sd_ref[:, sl].astype(jnp.float32)
+            ).astype(xo_ref.dtype)
 
 
 def _weight_pair(leaf, cols_of_hd: Optional[int] = None):
@@ -482,7 +487,9 @@ def fused_decode_layer(
             jax.ShapeDtypeStruct((B_p, KVH * hd), dt),
             jax.ShapeDtypeStruct((B_p, KVH * hd), dt),
         ],
-        interpret=_interpret_mode(),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=48 * 2**20),
+        interpret=platform.interpret_mode(),
     )(*prefetch, x, x, ln_a, ln_m, sin.astype(jnp.float32),
       cos.astype(jnp.float32), wqkv, sqkv, k_pools, v_pools, wo, so,
       wg, wg, sg, sg, wd, sd)

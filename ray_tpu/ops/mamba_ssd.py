@@ -25,13 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across jax releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def _interpret_mode() -> bool:
-    return jax.devices()[0].platform == "cpu"
+from ray_tpu.ops import platform
 
 
 def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, o_ref, state_scr, *,
@@ -131,9 +125,9 @@ def _ssd_pallas_fwd_impl(x, log_a, Bm, Cm, chunk: int):
         scratch_shapes=[pltpu.VMEM((H * N, P), jnp.float32)],
         # Only the chunk walk is stateful; batches are independent so
         # Mosaic may split them across TensorCores.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(xc, la, Bc, Cc)
     return out.reshape(B, S, H, P)
 

@@ -38,6 +38,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import platform
+
 NEG_INF = -1e30
 _MIN_QPG = 8  # sublane floor: pad the per-kv-head q group to 8 rows
 
@@ -159,7 +161,7 @@ def paged_decode_attention(
                           soft_cap=soft_cap, kvh=KVH, qpg_p=qpg_p),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, qpg_p, D), q.dtype),
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pages, v_pages)
     return out[:, :, :qpg, :].reshape(B, H, D)
@@ -354,7 +356,7 @@ def paged_decode_attention_partial(
             jax.ShapeDtypeStruct((B, KVH, qpg_p, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, KVH, qpg_p, 1), jnp.float32),
         ],
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(*prefetch, qg, *([k_pools] * G), *([v_pools] * G))
     acc = acc[:, :, :qpg, :].reshape(B, H, D)
     m = m[:, :, :qpg, :].reshape(B, H, 1)
@@ -507,7 +509,7 @@ def paged_append_quantized(k_pools, v_pools, k_scales, v_scales,
         # Scalar-prefetch args first: pids=0, offs=1, knew=2, vnew=3,
         # k_pools=4, v_pools=5, k_scales=6, v_scales=7.
         input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3},
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(pids.astype(jnp.int32), offs.astype(jnp.int32), knew, vnew,
       k_pools, v_pools, k_scales, v_scales)
 
@@ -571,7 +573,7 @@ def paged_append(k_pools: jax.Array, v_pools: jax.Array,
         # Inputs count scalar-prefetch args first: pids=0, offs=1,
         # knew=2, vnew=3, k_pools=4, v_pools=5.
         input_output_aliases={4: 0, 5: 1},
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(pids.astype(jnp.int32), offs.astype(jnp.int32), knew, vnew,
       k_pools, v_pools)
 
@@ -713,10 +715,6 @@ def paged_decode_attention_reference(
     probs = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhk,bkhd->bhd", probs, vx.astype(jnp.float32))
     return out.astype(q.dtype)
-
-
-def _interpret_mode() -> bool:
-    return jax.devices()[0].platform == "cpu"
 
 
 def paged_decode_attention_tp(

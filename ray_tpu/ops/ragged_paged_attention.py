@@ -48,8 +48,10 @@ so the fused path serves ragged batches too.
 
 Interpret-mode (CPU) numerics are tier-1 tested against the unfused
 paged reference for fp32 / int8-weight / int8-KV
-(tests/test_ragged_paged_attention.py); per-pattern tile tuning on
-hardware is expected follow-up, as for ops/fused_decode.py.
+(tests/test_ragged_paged_attention.py); tests/test_mosaic_aot.py
+compiles every kernel here for a v5e and chip_smoke.py checks them
+against the references on the chip.  Tile tuning is follow-up, as for
+ops/fused_decode.py.
 """
 
 from __future__ import annotations
@@ -64,7 +66,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.paged_attention import NEG_INF, _interpret_mode
+from ray_tpu.ops import platform
+from ray_tpu.ops.paged_attention import NEG_INF
 
 
 def _round8(n: int) -> int:
@@ -380,7 +383,7 @@ def ragged_paged_attention(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T_p, H, hd), jnp.float32),
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(*prefetch, q, k_new, v_new, k_pools, v_pools)
     return out[:T]
 
@@ -447,21 +450,22 @@ def _ragged_append_kernel(*refs, T: int, Cq: int, KVH: int, page: int,
         # row writes from offset 0 this step is FRESH (reset); a page
         # extended past existing rows keeps old int8 values bit-stable
         # unless the scale must grow (no cumulative requant error).
-        wrote = jnp.max(mask_w.astype(jnp.float32), axis=(0, 1),
-                        keepdims=True)                     # [1, 1]
+        # Every per-page quantity below is a SCALAR (full reductions),
+        # not a [1, 1] vector: Mosaic splats a scalar over a
+        # [page, hd] tile but refuses to broadcast a [1, 1] vector in
+        # both sublanes and lanes.
+        wrote = jnp.max(mask_w.astype(jnp.float32)) > 0.0
         fresh = (base >= start)
         for (new, cur, sc_in, sc_out) in (
                 (newk, curk, ks_ref, ks_out),
                 (newv, curv, vs_ref, vs_out)):
-            s_old = sc_in[0, 0, h:h + 1, 0:1].astype(jnp.float32)
-            amax = jnp.max(jnp.where(mask_w, jnp.abs(new), 0.0),
-                           axis=(0, 1), keepdims=True)
+            s_old = jnp.sum(sc_in[0, 0, h:h + 1, 0:1].astype(jnp.float32))
+            amax = jnp.max(jnp.where(mask_w, jnp.abs(new), 0.0))
             needed = jnp.maximum(amax / 127.0, 1e-8)
             grown = jnp.where(fresh, needed,
                               jnp.maximum(s_old, needed))
-            s_new = jnp.where(wrote > 0.0, grown,
-                              jnp.maximum(s_old, 1e-8))
-            factor = jnp.where(fresh & (wrote > 0.0), 0.0,
+            s_new = jnp.where(wrote, grown, jnp.maximum(s_old, 1e-8))
+            factor = jnp.where(fresh & wrote, 0.0,
                                jnp.where(s_new > s_old,
                                          s_old / s_new, 1.0))
             requant = jnp.round(cur.astype(jnp.float32) * factor)
@@ -473,8 +477,8 @@ def _ragged_append_kernel(*refs, T: int, Cq: int, KVH: int, page: int,
             else:
                 vp_out[0, h, 0] = jnp.clip(outp, -127, 127).astype(
                     vp_out.dtype)
-            sc_out[0, 0, h:h + 1, 0:1] = jnp.where(
-                wrote > 0.0, s_new, s_old).astype(sc_out.dtype)
+            sc_out[0, 0, h:h + 1, 0:1] = jnp.full(
+                (1, 1), jnp.where(wrote, s_new, s_old), sc_out.dtype)
 
 
 def _append_maps(page: int, Pt: int, maxp: int, NPR: int):
@@ -553,7 +557,7 @@ def ragged_paged_append(
         # prefetch: slot=0 start=1 len=2 off=3 bt=4, then kn=5 vn=6
         # k_pools=7 v_pools=8
         input_output_aliases={7: 0, 8: 1},
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
       row_len.astype(jnp.int32), row_off.astype(jnp.int32),
       block_tables.astype(jnp.int32), k_new, v_new, k_pools, v_pools)
@@ -619,7 +623,7 @@ def ragged_paged_append_quantized(
         ],
         # prefetch 0-4, kn=5 vn=6 kp=7 vp=8 ks=9 vs=10
         input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3},
-        interpret=_interpret_mode(),
+        interpret=platform.interpret_mode(),
     )(row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
       row_len.astype(jnp.int32), row_off.astype(jnp.int32),
       block_tables.astype(jnp.int32), k_new, v_new, k_pools, v_pools,
@@ -827,11 +831,11 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
 
     @pl.when(t == S4 - 1)
     def _final():
-        sdv = sd_ref[...].astype(jnp.float32)
         for j in range(To):
             sl = slice(j * to, (j + 1) * to)
-            xo_ref[:, sl] = (h_s[j] + y_s[:, sl] * sdv[:, sl]).astype(
-                xo_ref.dtype)
+            xo_ref[:, sl] = (
+                h_s[j] + y_s[:, sl] * sd_ref[:, sl].astype(jnp.float32)
+            ).astype(xo_ref.dtype)
 
 
 def fused_ragged_layer(
@@ -1000,7 +1004,9 @@ def fused_ragged_layer(
             jax.ShapeDtypeStruct((T_p, KVH * hd), dt),
             jax.ShapeDtypeStruct((T_p, KVH * hd), dt),
         ],
-        interpret=_interpret_mode(),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=48 * 2**20),
+        interpret=platform.interpret_mode(),
     )(*prefetch, x, x, ln_a, ln_m, sin.astype(jnp.float32),
       cos.astype(jnp.float32), wqkv, sqkv, k_pools, v_pools, wo, so,
       wg, wg, sg, sg, wd, sd)

@@ -218,14 +218,10 @@ def ring_attention(
 
 
 def _ambient_mesh() -> Mesh:
-    mesh = None
-    try:
-        env = jax.interpreters.pxla.thread_resources.env
-        if env.physical_mesh and not env.physical_mesh.empty:
-            mesh = env.physical_mesh
-    except Exception:
-        pass
-    if mesh is None:
+    from jax._src import mesh as _mesh_lib
+
+    mesh = _mesh_lib.thread_resources.env.physical_mesh
+    if mesh.empty:
         raise ValueError(
             "ring_attention needs a mesh — pass one explicitly or call "
             "inside `with mesh:`"

@@ -94,18 +94,8 @@ def axis_index(axis: AxisName) -> jax.Array:
 
 
 def axis_size(axis: AxisName) -> int:
-    """Concrete size of a named mesh axis inside shard_map.  Falls back
-    to ``core.axis_frame`` (which returns the concrete int the
-    enclosing shard_map bound) on jax builds without ``lax.axis_size``."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    from jax import core
-
-    names = (axis,) if isinstance(axis, str) else tuple(axis)
-    n = 1
-    for name in names:
-        n *= core.axis_frame(name)
-    return n
+    """Concrete size of a named mesh axis inside shard_map."""
+    return lax.axis_size(axis)
 
 
 # --- quantized DCN collectives ---------------------------------------------

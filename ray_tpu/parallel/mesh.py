@@ -199,18 +199,9 @@ def create_mesh(
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
     """``jax.shard_map`` with replication checking disabled (our mapped
-    bodies produce per-device values by construction), papering over the
-    jax 0.8 rename of ``check_rep`` → ``check_vma``."""
-    try:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except (TypeError, AttributeError):  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    bodies produce per-device values by construction)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def create_serving_mesh(shards: int, tp: int, *,
@@ -279,20 +270,15 @@ class TpuTopology:
 
 
 def detect_topology() -> TpuTopology:
+    """The slice this process computes on.  Raises LookupError on a
+    device utils/accelerator has no entry for (a CPU backend): a
+    topology of an unknown chip is not a TPU topology."""
+    from ray_tpu.utils.accelerator import chip_spec
+
     devs = jax.devices()
     n = len(devs)
-    kind = (devs[0].device_kind or "cpu").lower() if devs else "cpu"
-    if "v6" in kind or "trillium" in kind:
-        gen = "v6e"
-    elif "lite" in kind or "v5e" in kind:
-        gen = "v5e"
-    elif "v5p" in kind or "v5" in kind:
-        gen = "v5p"
-    elif "v4" in kind:
-        gen = "v4"
-    else:
-        gen = "cpu"
-    num_hosts = max(1, getattr(jax, "process_count", lambda: 1)())
+    gen = chip_spec(devs[0].device_kind)["chip"].removeprefix("TPU-")
+    num_hosts = jax.process_count()
     return TpuTopology(
         generation=gen,
         chips=n,

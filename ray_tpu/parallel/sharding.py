@@ -134,8 +134,7 @@ def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]],
     thread-resources mesh is populated — the abstract mesh stays empty —
     so a bare-PartitionSpec constraint would either raise or be
     dropped; bind the spec to the concrete mesh instead.
-    ``current_mesh`` resolves either kind (with a fallback for jax
-    builds without ``jax.sharding.get_abstract_mesh``)."""
+    ``current_mesh`` resolves either kind."""
     mesh = current_mesh()
     if mesh is None:
         return x
@@ -154,14 +153,9 @@ def current_mesh():
     (see train/optim8.py's ZeRO block constraints)."""
     from jax._src import mesh as _mesh_lib
 
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None) \
-        or getattr(_mesh_lib, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        abstract = get_abstract()
-        # Older jax returns the raw context value — ``()`` when no
-        # abstract mesh is set — instead of an empty AbstractMesh.
-        if getattr(abstract, "empty", True) is False:
-            return abstract
+    abstract = jax.sharding.get_abstract_mesh()
+    if not abstract.empty:
+        return abstract
     physical = _mesh_lib.thread_resources.env.physical_mesh
     return None if physical.empty else physical
 

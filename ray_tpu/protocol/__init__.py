@@ -1,10 +1,11 @@
 """Wire schema (protobuf) for the control plane.
 
-``raytpu.proto`` is the source of truth; ``raytpu_pb2.py`` is checked
-in so no toolchain is needed at runtime.  When protoc is available and
-the .proto is newer (a dev edited it), the module regenerates on
-import — same convention as the native layer's compile-on-first-use
-(`ray_tpu/_native/__init__.py`).
+``raytpu.proto`` is the source of truth and ``raytpu_pb2.py``, checked
+in beside it, is the product: nothing is generated at import, because a
+copied or checked-out tree has arbitrary file times and an import must
+not depend on them.  After editing the .proto, regenerate by hand:
+
+    cd ray_tpu/protocol && protoc --python_out=. raytpu.proto
 
 Parity: src/ray/protobuf/*.proto compiled into ray._raylet /
 ray.core.generated at build time.
@@ -12,64 +13,7 @@ ray.core.generated at build time.
 
 from __future__ import annotations
 
-import os
-import shutil
-import subprocess
-
-_HERE = os.path.dirname(__file__)
-_PROTO = os.path.join(_HERE, "raytpu.proto")
-_GEN = os.path.join(_HERE, "raytpu_pb2.py")
-
-
-def _maybe_regen() -> None:
-    try:
-        stale = (not os.path.exists(_GEN)
-                 or os.path.getmtime(_PROTO) > os.path.getmtime(_GEN))
-    except OSError:
-        return
-    if not stale:
-        return
-    protoc = shutil.which("protoc")
-    if protoc is None:
-        if not os.path.exists(_GEN):
-            raise RuntimeError(
-                "ray_tpu/protocol/raytpu_pb2.py is missing and protoc is "
-                "not installed to regenerate it from raytpu.proto")
-        return  # stale but unregenerable: trust the checked-in module
-    # Generate into a private dir and os.replace() into place: many
-    # processes (daemon + its workers) can hit a stale checkout at
-    # once, and a peer importing a half-written module would crash in
-    # the middle of its first frame.  Failures fall back to the
-    # checked-in module when one exists.
-    import sys
-    import tempfile
-
-    tmpdir = None
-    try:
-        tmpdir = tempfile.mkdtemp(dir=_HERE, prefix=".protoc-")
-        subprocess.run(
-            [protoc, f"--python_out={tmpdir}", "raytpu.proto"],
-            cwd=_HERE, check=True, capture_output=True)
-        # Prove the output imports against the INSTALLED runtime before
-        # replacing the known-good module (an old protoc can emit
-        # gencode the runtime rejects).  Subprocess: importing here
-        # would register descriptors the real import then collides with.
-        subprocess.run(
-            [sys.executable, "-c", "import raytpu_pb2"],
-            cwd=tmpdir, check=True, capture_output=True,
-            env={**os.environ, "PYTHONPATH": tmpdir})
-        os.replace(os.path.join(tmpdir, "raytpu_pb2.py"), _GEN)
-    except (subprocess.CalledProcessError, OSError):
-        if not os.path.exists(_GEN):
-            raise
-    finally:
-        if tmpdir is not None:
-            shutil.rmtree(tmpdir, ignore_errors=True)
-
-
-_maybe_regen()
-
-from ray_tpu.protocol import raytpu_pb2 as pb  # noqa: E402
+from ray_tpu.protocol import raytpu_pb2 as pb
 
 Frame = pb.Frame
 ObjectMeta = pb.ObjectMeta

@@ -30,8 +30,9 @@ within float tolerance instead of hoping two clocks agree.
 Device estimates come from ``xprof.ProgramRecord.cost_steps`` (the
 token count the recorded cost covers): per-token device seconds =
 ``max(flops/peak_flops, bytes/peak_bw) / cost_steps`` against
-``accelerator.chip_spec()`` peaks.  When a backend reports no cost
-numbers the device components are 0 and the residuals stay honest.
+``accelerator.local_chip_spec()`` peaks.  When a backend reports no
+cost numbers, or the device has no published peaks (CPU), the device
+components are 0 and the residuals stay honest.
 
 Terminal requests feed the tier-1-pinned families
 ``raytpu_serve_request_overhead_seconds{component=...}`` and
@@ -117,12 +118,17 @@ def clear() -> None:
 # -- device-cost + compile-window helpers -----------------------------------
 
 def _chip_peaks() -> Tuple[Optional[float], Optional[float]]:
+    """Peaks of the device this process computes on; (None, None) where
+    it has no published peaks (a CPU backend): the device components
+    are then 0 — not measured — rather than priced against a made-up
+    chip."""
+    from ray_tpu.utils.accelerator import local_chip_spec
+
     try:
-        from ray_tpu.utils.accelerator import chip_spec
-        spec = chip_spec()
-        return spec.get("peak_flops"), spec.get("peak_hbm_bytes_per_s")
-    except Exception:
+        spec = local_chip_spec()
+    except LookupError:
         return None, None
+    return spec["peak_flops"], spec["peak_hbm_bytes_per_s"]
 
 
 def _per_token_device_s(program_names) -> float:
@@ -130,12 +136,16 @@ def _per_token_device_s(program_names) -> float:
     program in ``program_names`` with cost numbers: the roofline lower
     bound max(flops/peak_flops, bytes/peak_bw) over the tokens the
     recorded cost covers.  0.0 = no estimate (absent cost analysis)."""
-    peak_flops, peak_bw = _chip_peaks()
     progs = xprof.programs()
     for name in program_names:
         rec = progs.get(name)
         if rec is None or not rec.cost_steps:
             continue
+        # Only past this point: a registered program means THIS process
+        # compiled it, so it already holds the backend the peaks
+        # describe.  A driver joining federated rows never gets here
+        # and never initialises one.
+        peak_flops, peak_bw = _chip_peaks()
         bounds = []
         if rec.flops is not None and peak_flops:
             bounds.append(rec.flops / peak_flops)

@@ -13,8 +13,8 @@ walls and queues; this module is its device-side half:
   * ``roofline()`` — joins the registered cost numbers against the
     span walls the producers already emit (train.compute, llm.decode)
     and the chip's peak flops / HBM bandwidth
-    (utils/accelerator.chip_spec, nominal CPU fallback) into achieved
-    -vs-peak utilization gauges.
+    (utils/accelerator.chip_spec; a device without published peaks is
+    an error) into achieved-vs-peak utilization gauges.
   * ``sample_device_memory()`` — per-device HBM watermarks, shared by
     every plane (the trainer's private gauges moved here).
   * ``capture()`` / ``distributed_capture()`` — a bounded
@@ -29,7 +29,10 @@ walls and queues; this module is its device-side half:
 
 Everything degrades to ABSENT on CPU or partial backends: missing
 ``cost_analysis`` keys, ``memory_stats() -> None`` and an unavailable
-profiler yield no samples — never zeros, never raises.
+profiler yield no samples — never zeros, never raises.  Nothing here
+initialises a JAX backend: a process that holds none (the driver, the
+dashboard) reports no devices, because looking would take the chip
+from the process that owns it.
 """
 
 from __future__ import annotations
@@ -230,7 +233,20 @@ def _program_walls() -> Dict[str, List[float]]:
     return walls
 
 
-def roofline() -> Dict[str, Dict[str, Any]]:
+def _local_devices() -> list:
+    """This process's devices, or [] when it has initialised no backend
+    (asking JAX would initialise one)."""
+    from ray_tpu.utils.accelerator import backend_initialised
+
+    if not backend_initialised():
+        return []
+    import jax
+
+    return jax.local_devices()
+
+
+def roofline(spec: Optional[Dict[str, Any]] = None
+             ) -> Dict[str, Dict[str, Any]]:
     """Per-program achieved-vs-peak attribution.
 
     For each registered program with a measured span wall:
@@ -240,12 +256,19 @@ def roofline() -> Dict[str, Dict[str, Any]]:
         achieved_bytes/s = cost bytes accessed / median per-step wall
         hbm_util         = achieved_bytes/s / chip peak HBM bandwidth
 
-    Peaks come from utils/accelerator.chip_spec() (nominal fallback on
-    CPU, so the math still runs end to end in tests).  Results land in
-    the ``raytpu_xla_roofline_*`` gauges and come back as a dict."""
-    from ray_tpu.utils.accelerator import chip_spec
+    ``spec`` is a utils/accelerator.chip_spec() row; by default the one
+    of the device this process computes on, which raises LookupError
+    where that device has no published peaks (a CPU: tests pass a spec).
+    A process that computes on nothing has nothing to attribute: {}.
+    Results land in the ``raytpu_xla_roofline_*`` gauges and come back
+    as a dict."""
+    if spec is None:
+        devices = _local_devices()
+        if not devices:
+            return {}
+        from ray_tpu.utils.accelerator import chip_spec
 
-    spec = chip_spec()
+        spec = chip_spec(devices[0].device_kind)
     peak_flops = spec.get("peak_flops")
     peak_bw = spec.get("peak_hbm_bytes_per_s")
     walls = _program_walls()
@@ -282,14 +305,8 @@ def sample_device_memory() -> None:
     """Per-device HBM watermarks → shared gauges.  TPU/GPU backends
     expose memory_stats(); CPU returns None/raises — then the gauges
     simply never appear."""
-    try:
-        import jax
-
-        devices = jax.local_devices()
-    except Exception:
-        return
     tm = _telemetry()
-    for d in devices:
+    for d in _local_devices():
         try:
             stats = d.memory_stats()
         except Exception:
@@ -310,12 +327,7 @@ def device_timeline_events() -> List[Dict[str, Any]]:
     per-program events (a registered program's span walls replayed on
     the device row with its cost numbers in args).  Mergeable with
     core/events.chrome_tracing_dump()."""
-    try:
-        import jax
-
-        devices = jax.local_devices()
-    except Exception:
-        return []
+    devices = _local_devices()
     from ray_tpu.util import tracing
 
     by_span: Dict[str, List[ProgramRecord]] = {}

@@ -1,16 +1,19 @@
-"""TPU accelerator detection + topology labels.
+"""TPU accelerator detection, chip peaks, and chip ownership.
 
 Parity: ray: python/ray/_private/accelerator.py:20-191 — TPU chip
-count (/dev/accel* or env), version (GCE metadata), per-pod head
+count (device nodes or env), version (accelerator type), per-pod head
 resources (``TPU-{version}-{pod}-head``), visibility isolation via
 ``TPU_VISIBLE_CHIPS``; constants in
 python/ray/util/accelerators/accelerators.py (GOOGLE_TPU_V2/V3/V4).
 
-Here detection prefers the live jax backend (authoritative on TPU VMs);
-the env/metadata paths mirror the reference for worker processes that
-must not initialize jax.  Topology labels feed ICI-aware placement
-(SURVEY.md §7 phase 3: nodes carry slice/ICI coordinates; bundle
-policies pack along them — see runtime._reserve_bundles 'ici_index').
+A chip belongs to one process at a time, so detection never touches
+JAX: ``ray_tpu.init()`` and every control process count chips from
+device nodes and read the version from the environment or the PCI bus.
+Only a process that was leased chips calls ``claim_tpu()``, which pins
+JAX to the TPU backend and initialises it.  Topology labels feed
+ICI-aware placement (SURVEY.md §7 phase 3: nodes carry slice/ICI
+coordinates; bundle policies pack along them — see
+runtime._reserve_bundles 'ici_index').
 """
 
 from __future__ import annotations
@@ -24,88 +27,112 @@ GOOGLE_TPU_V5E = "TPU-v5e"
 GOOGLE_TPU_V5P = "TPU-v5p"
 GOOGLE_TPU_V6E = "TPU-v6e"
 
-_JAX_PLATFORM_VERSIONS = {
-    "tpu v4": GOOGLE_TPU_V4,
-    "tpu v5e": GOOGLE_TPU_V5E,
-    "tpu v5 lite": GOOGLE_TPU_V5E,
-    "tpu v5p": GOOGLE_TPU_V5P,
-    "tpu v5": GOOGLE_TPU_V5P,
-    "tpu v6e": GOOGLE_TPU_V6E,
-}
-
-# Per-chip peak dense bf16 flops and HBM bandwidth (public spec
-# sheets) — the denominators of the device-plane roofline
-# (util/xprof.roofline).
+# Published per-chip peaks (Google Cloud TPU documentation, the page of
+# each version): dense bf16 FLOP/s, int8 OP/s, HBM bytes/s.  The one
+# table every utilization figure in the repository divides by.
 _CHIP_SPECS = {
-    GOOGLE_TPU_V4: {"peak_flops": 275e12,
+    GOOGLE_TPU_V4: {"peak_flops": 275e12, "peak_int8_ops": 275e12,
                     "peak_hbm_bytes_per_s": 1228e9},
-    GOOGLE_TPU_V5E: {"peak_flops": 197e12,
+    GOOGLE_TPU_V5E: {"peak_flops": 197e12, "peak_int8_ops": 393e12,
                      "peak_hbm_bytes_per_s": 819e9},
-    GOOGLE_TPU_V5P: {"peak_flops": 459e12,
+    GOOGLE_TPU_V5P: {"peak_flops": 459e12, "peak_int8_ops": 918e12,
                      "peak_hbm_bytes_per_s": 2765e9},
-    GOOGLE_TPU_V6E: {"peak_flops": 918e12,
+    GOOGLE_TPU_V6E: {"peak_flops": 918e12, "peak_int8_ops": 1836e12,
                      "peak_hbm_bytes_per_s": 1640e9},
 }
 
-# Nominal one-core CPU envelope so roofline math still runs end to end
-# off-TPU (utilization numbers against it are order-of-magnitude only;
-# the point is exercising the same code path tier-1 tests cover).
-_CPU_FALLBACK_SPEC = {"peak_flops": 100e9,
-                      "peak_hbm_bytes_per_s": 50e9}
+# jax ``device_kind`` (lower-cased) -> version.
+_DEVICE_KINDS = {
+    "tpu v4": GOOGLE_TPU_V4,
+    "tpu v5 lite": GOOGLE_TPU_V5E,
+    "tpu v5e": GOOGLE_TPU_V5E,
+    "tpu v5": GOOGLE_TPU_V5P,
+    "tpu v5p": GOOGLE_TPU_V5P,
+    "tpu v6 lite": GOOGLE_TPU_V6E,
+    "tpu v6e": GOOGLE_TPU_V6E,
+}
+
+# ``TPU_ACCELERATOR_TYPE`` prefix (before the "-<chips>") -> version.
+_ACCELERATOR_TYPES = {
+    "v4": GOOGLE_TPU_V4,
+    "v5litepod": GOOGLE_TPU_V5E,
+    "v5e": GOOGLE_TPU_V5E,
+    "v5p": GOOGLE_TPU_V5P,
+    "v6e": GOOGLE_TPU_V6E,
+}
+
+# PCI device id of a Google (vendor 0x1ae0) TPU -> version.
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_PCI_DEVICE_IDS = {
+    "0x005e": GOOGLE_TPU_V4,
+    "0x0062": GOOGLE_TPU_V5P,
+    "0x0063": GOOGLE_TPU_V5E,
+    "0x006f": GOOGLE_TPU_V6E,
+}
 
 
-def chip_spec(version: Optional[str] = None) -> Dict[str, float]:
-    """Peak flops + HBM bandwidth for one chip: ``{"chip", "peak_flops",
-    "peak_hbm_bytes_per_s"}``.  ``version`` defaults to the detected
-    TPU version; unknown/absent hardware gets the nominal CPU fallback
-    so callers never branch on None."""
-    version = version or tpu_version()
+def chip_spec(device_kind: str) -> Dict[str, float]:
+    """Peaks of one chip: ``{"chip", "peak_flops", "peak_int8_ops",
+    "peak_hbm_bytes_per_s"}`` for a jax ``device_kind`` ("TPU v5 lite")
+    or a version string ("TPU-v5e").  A device that is not in the table
+    is an error: a utilization against a made-up peak is worse than
+    none."""
+    version = _DEVICE_KINDS.get(device_kind.lower(), device_kind)
     spec = _CHIP_SPECS.get(version)
     if spec is None:
-        return {"chip": version or "cpu", **_CPU_FALLBACK_SPEC}
+        raise LookupError(
+            f"no peak rates for device {device_kind!r}; known: "
+            f"{sorted(_DEVICE_KINDS)} / {sorted(_CHIP_SPECS)}")
     return {"chip": version, **spec}
 
 
+def local_chip_spec() -> Dict[str, float]:
+    """``chip_spec`` of the device THIS process computes on.  For
+    processes that already hold a backend (an engine, a trainer, the
+    benchmark); raises LookupError on a CPU backend."""
+    import jax
+
+    return chip_spec(jax.devices()[0].device_kind)
+
+
 def num_tpu_chips() -> int:
-    """Chips visible to this host (parity: accelerator.py chip count —
-    TPU_VISIBLE_CHIPS > /dev/accel* > jax)."""
+    """Chips this host exposes (parity: accelerator.py chip count):
+    ``TPU_VISIBLE_CHIPS`` when set, else the device nodes — one
+    ``/dev/accel<N>`` per chip on v4 and older hosts, one
+    ``/dev/vfio/<N>`` per chip on v5e and newer."""
     visible = os.environ.get("TPU_VISIBLE_CHIPS")
     if visible is not None:
         # An empty value means "no chips visible" — isolation, not
         # unset; falling through would leak the host's full chip count.
         return len([c for c in visible.split(",") if c.strip()])
-    accels = glob.glob("/dev/accel*")
-    if accels:
-        return len(accels)
-    try:
-        import jax
-
-        devs = jax.devices()
-        if devs and devs[0].platform not in ("cpu", "gpu"):
-            return len(devs)
-    except Exception:
-        pass
-    return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def tpu_version() -> Optional[str]:
     """Resource-string TPU version (parity: GCE metadata
-    accelerator-type; jax device_kind preferred when live)."""
+    accelerator-type): ``RAYTPU_TPU_VERSION``, else the TPU VM's
+    ``TPU_ACCELERATOR_TYPE`` ("v5litepod-4"), else the PCI device id of
+    the chips on the bus.  None when the host has no TPU."""
     env = os.environ.get("RAYTPU_TPU_VERSION")
     if env:
         return env
-    try:
-        import jax
-
-        devs = jax.devices()
-        if devs and devs[0].platform not in ("cpu", "gpu"):
-            kind = getattr(devs[0], "device_kind", "").lower()
-            for prefix, version in _JAX_PLATFORM_VERSIONS.items():
-                if kind.startswith(prefix):
-                    return version
-            return f"TPU-{kind.replace(' ', '-')}" if kind else None
-    except Exception:
-        pass
+    acc_type = os.environ.get("TPU_ACCELERATOR_TYPE", "")
+    version = _ACCELERATOR_TYPES.get(acc_type.split("-")[0].lower())
+    if version:
+        return version
+    for vendor_path in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor_path) as f:
+                if f.read().strip() != _GOOGLE_PCI_VENDOR:
+                    continue
+            with open(os.path.join(os.path.dirname(vendor_path),
+                                   "device")) as f:
+                version = _PCI_DEVICE_IDS.get(f.read().strip())
+        except OSError:
+            continue
+        if version:
+            return version
     return None
 
 
@@ -165,10 +192,102 @@ def node_resources_and_labels() -> (Dict[str, float], Dict[str, str]):
     return resources, labels
 
 
-def visible_chip_env(chip_ids: List[int]) -> Dict[str, str]:
-    """Env pinning a worker to specific chips (parity: the reference
-    sets TPU_VISIBLE_CHIPS the way it sets CUDA_VISIBLE_DEVICES)."""
+# -- chip ownership ----------------------------------------------------------
+
+# Chips one process may hold, as the bounds libtpu wants for them.
+_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def chip_worker_env(chip_ids: Optional[List[int]],
+                    host_chips: int) -> Dict[str, str]:
+    """Environment of a worker process.  ``chip_ids`` None keeps the
+    worker off the chips (every control process and CPU task worker).
+    A list pins JAX to the TPU backend, so a chip the worker cannot get
+    is an error and never a quiet CPU run; a list shorter than the
+    host's ``host_chips`` also binds the process to exactly those chips
+    (parity: the reference sets TPU_VISIBLE_CHIPS the way it sets
+    CUDA_VISIBLE_DEVICES), while a worker given every chip sees the
+    host as the host's own environment describes it."""
+    if chip_ids is None:
+        return {"JAX_PLATFORMS": "cpu"}
+    env = {"JAX_PLATFORMS": "tpu"}
+    if len(chip_ids) < host_chips:
+        if len(chip_ids) not in _PROCESS_BOUNDS:
+            raise ValueError(
+                f"a process can be bound to {sorted(_PROCESS_BOUNDS)} "
+                f"chips, not {len(chip_ids)}")
+        env.update({
+            "TPU_VISIBLE_CHIPS": ",".join(str(i) for i in chip_ids),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": _PROCESS_BOUNDS[len(chip_ids)],
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        })
+    return env
+
+
+def compile_cache_dir() -> str:
+    """Where this checkout keeps JAX's persistent compilation cache when
+    ``JAX_COMPILATION_CACHE_DIR`` does not place it: a fixed path inside
+    the checkout, because the path is part of what makes a later run
+    find the cache again."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(root, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache for this process and
+    return its directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set
+    JAX already reads it and nothing is set here."""
+    import jax
+
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = compile_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def backend_initialised() -> bool:
+    """Whether this process ever initialised a JAX backend (and so may
+    hold a chip).  Asking JAX for its devices would initialise one, so
+    anything that only wants to LOOK asks this first."""
+    import sys
+
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def claim_tpu() -> Dict[str, str]:
+    """Make this process the owner of its chips: pin JAX to the TPU
+    backend before first use, so a missing or busy chip raises instead
+    of landing on the CPU, and initialise it.  Returns a report
+    (platform, device_kind, count, library versions, cache directory)
+    for the caller to print."""
+    import jax
+    import jaxlib
+
+    jax.config.update("jax_platforms", "tpu")
+    cache = enable_compile_cache()
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        raise RuntimeError(
+            f"asked for the TPU backend, got {devices[0].platform!r}")
+    try:
+        from importlib.metadata import version as _pkg_version
+
+        libtpu = _pkg_version("libtpu")
+    except Exception:  # a libtpu that ships outside pip metadata
+        libtpu = "unknown"
     return {
-        "TPU_VISIBLE_CHIPS": ",".join(str(i) for i in chip_ids),
-        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu,
+        "compile_cache": cache,
     }

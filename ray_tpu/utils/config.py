@@ -129,10 +129,6 @@ D("workers", str, "process",
   "driver: ray src/ray/raylet/worker_pool.h:156) or 'thread' "
   "(in-process, fast start, GIL-bound — the annotated exception for "
   "latency-critical embedded uses and tests).  Env: RAYTPU_WORKERS.")
-D("worker_tpu_access", bool, False,
-  "Give spawned worker processes the TPU runtime preload (slower start; "
-  "only one process can hold a chip — leave off for pure-CPU workers and "
-  "run device work from the driver or a dedicated TPU actor).")
 D("worker_prestart", int, 0,
   "Spawn this many workers in the background at init (hides cold-start).")
 D("num_workers_soft_limit", int, 0, "0 = num_cpus workers per node.")

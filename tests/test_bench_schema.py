@@ -700,24 +700,79 @@ def test_bench_out_if_present(schema):
     assert schema.validate_record(rec) == []
 
 
-def test_bench_main_emits_file_and_stdout_line(schema, tmp_path,
-                                               monkeypatch, capsys):
-    """bench.main() end-to-end (measurement stubbed): the record lands
-    in BENCH_OUT.json AND as the final stdout line, the two copies are
-    byte-identical, the line is COMPACT (the driver wrapper keeps only
-    a bounded stdout tail — padding is what truncated BENCH_r05's line
-    into parsed:null), and the record satisfies the schema."""
+def _stubbed_bench(monkeypatch):
+    """bench.py with every leg stubbed and the device check satisfied:
+    what is left is main()'s own record assembly and exit code."""
+    import jax
+
+    from ray_tpu.utils import accelerator
+
     spec = importlib.util.spec_from_file_location("bench",
                                                   REPO / "bench.py")
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
+    monkeypatch.setattr(bench, "_require_tpu", jax.devices)
+    monkeypatch.setattr(accelerator, "local_chip_spec",
+                        lambda: accelerator.chip_spec("TPU v5 lite"))
+    monkeypatch.setattr(accelerator, "enable_compile_cache", lambda: "")
     monkeypatch.setattr(bench, "_measure", lambda *a, **k: 1000.0)
-    monkeypatch.setattr(bench, "_measure_serving_multihost",
-                        lambda *a, **k: _multihost_block())
-    monkeypatch.setattr(bench, "_measure_serving_disagg",
-                        lambda *a, **k: _disagg_block())
-    monkeypatch.setattr(bench, "_measure_serving_chaos",
-                        lambda *a, **k: _chaos_block())
+    for leg, block in [
+            ("_measure_serving", _serving),
+            ("_measure_serving_mixed",
+             lambda: _mixed_record()["extra"]["serving_mixed"]),
+            ("_measure_ssd", lambda: {"speedup": 1.0}),
+            ("_measure_8b",
+             lambda: {"params_b": 8.03,
+                      "train": {**_zero_train(), "optimizer": "adamw8bit"}}),
+            ("_measure_serving_multihost", _multihost_block),
+            ("_measure_serving_disagg", _disagg_block),
+            ("_measure_serving_adapters", _adapters_block),
+            ("_measure_serving_chaos", _chaos_block)]:
+        monkeypatch.setattr(bench, leg,
+                            lambda *a, _block=block, **k: _block())
+    return bench
+
+
+def test_bench_main_refuses_to_run_off_a_tpu():
+    """No CPU fallback: on this machine (JAX pinned to the CPU) main()
+    exits non-zero and names the platform it found."""
+    spec = importlib.util.spec_from_file_location("bench",
+                                                  REPO / "bench.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert "platform='cpu'" in str(exc.value.code)
+
+
+def test_bench_main_exits_nonzero_when_a_leg_errors(tmp_path, monkeypatch,
+                                                    capsys):
+    """An errored leg still reports beside the others, and the exit
+    code says so."""
+    bench = _stubbed_bench(monkeypatch)
+
+    def boom(*a, **k):
+        raise RuntimeError("Mosaic refused")
+
+    monkeypatch.setattr(bench, "_measure_ssd", boom)
+    monkeypatch.chdir(tmp_path)
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert "Mosaic refused" in rec["extra"]["mamba_ssd"]["error"]
+    assert rec["extra"]["serving"]["knee_req_s"] == 2.0
+
+
+def test_bench_main_emits_file_and_stdout_line(schema, tmp_path,
+                                               monkeypatch, capsys):
+    """bench.main() end-to-end (device check and measurements stubbed:
+    main() itself refuses to run off a TPU): the record lands in
+    BENCH_OUT.json AND as the final stdout line, the two copies are
+    byte-identical, the line is COMPACT (the driver wrapper keeps only
+    a bounded stdout tail — padding is what truncated BENCH_r05's line
+    into parsed:null), and the record satisfies the schema."""
+    bench = _stubbed_bench(monkeypatch)
     monkeypatch.chdir(tmp_path)
     bench.main()
     lines = capsys.readouterr().out.strip().splitlines()

@@ -75,10 +75,10 @@ def test_unequal_blocks_causal():
 
 
 def test_eligibility_matches_kernel(monkeypatch):
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import attention, platform
 
     # pretend we're on TPU so the shape logic is actually exercised
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(platform, "interpret_mode", lambda: False)
 
     mk = lambda s, kl=None: (
         jax.ShapeDtypeStruct((1, s, 4, 64), jnp.bfloat16),
@@ -95,3 +95,41 @@ def test_eligibility_matches_kernel(monkeypatch):
     # packed sequences fall back
     q, k = mk(1024)
     assert not attention._flash_eligible(q, k, True, "segs", None)
+
+
+def test_flash_partition_specs():
+    """Batch over the data axes, heads over the tensor axes, by the
+    rule table; a dimension its axes do not divide stays whole."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops.attention import flash_partition_specs
+
+    train = {"pp": 1, "dp": 2, "fsdp": 2, "ep": 1, "sp": 1, "tp": 2}
+    q, kv = flash_partition_specs(train, 8, 8, 4)
+    assert q == kv == P(("dp", "fsdp"), None, "tp", None)
+    assert flash_partition_specs(train, 2, 8, 4)[0] == P(
+        None, None, "tp", None)           # 2 rows over 4 data shards
+    assert flash_partition_specs(train, 8, 8, 1)[0] == P(
+        ("dp", "fsdp"), None, None, None)  # one KV head over tp=2
+    serving = {"dcn_tp": 2, "dp": 1, "fsdp": 1, "tp": 2}
+    assert flash_partition_specs(serving, 3, 8, 4)[0][2] == ("dcn_tp", "tp")
+
+
+def test_flash_runs_per_shard_under_a_mesh(cpu_devices):
+    """Mosaic kernels cannot be partitioned by GSPMD: under a mesh the
+    kernel is entered through shard_map (the AOT test proves that for a
+    TPU; this proves the sharded result is the same attention)."""
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import MeshSpec, create_mesh
+
+    mesh = create_mesh(MeshSpec(dp=2, fsdp=2, tp=2), devices=cpu_devices)
+    q, k, v = _rand_qkv(jax.random.key(7), B=4, S=256, H=4, KVH=2)
+    with mesh:
+        out = jax.jit(attention._flash_over_mesh)(q, k, v)
+        g = jax.jit(jax.grad(
+            lambda q: attention._flash_over_mesh(q, k, v).sum()))(q)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    g_ref = jax.grad(lambda q: _ref(q, k, v).sum())(q)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                               atol=2e-4, rtol=2e-4)

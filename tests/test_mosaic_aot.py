@@ -1,0 +1,411 @@
+"""Compile every Pallas kernel, and the model steps that carry them,
+for a TPU v5e WITHOUT a chip.
+
+libtpu can describe a topology it does not have
+(``jax.experimental.topologies``), and ``jit(...).trace(...).lower(
+lowering_platforms=("tpu",)).compile()`` then runs the real Mosaic and
+XLA:TPU compilers against abstract arguments placed on that topology's
+devices.  Every other test runs the kernels through the Pallas
+interpreter, which accepts programs Mosaic refuses; this file is the
+off-chip guard that the programs ``chip_smoke.py`` runs still compile,
+at the widths the benchmark uses, on one device and on four.  It costs
+no chip time.  What it cannot see is a wrong answer: that is the smoke's
+``kernels`` phase.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import importlib.util
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ray_tpu.models import llama, quant
+from ray_tpu.ops import platform
+from ray_tpu.parallel.mesh import MeshSpec, create_mesh, create_serving_mesh
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+PAGE = 64
+
+
+@pytest.fixture(scope="module")
+def bench():
+    spec = importlib.util.spec_from_file_location("bench", REPO / "bench.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    return topologies.get_topology_desc(
+        topology_name="v5e:2x2", platform="tpu").devices
+
+
+@pytest.fixture(autouse=True)
+def mosaic_not_interpreter(monkeypatch):
+    monkeypatch.setattr(platform, "interpret_mode", lambda: False)
+
+
+def _on(mesh, tree, spec=P()):
+    """Abstract arguments placed on ``mesh`` (replicated unless told)."""
+    sh = NamedSharding(mesh, spec)
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+
+
+def _compile(fn, *args, mesh=None, **jit_kw):
+    with mesh if mesh is not None else contextlib.nullcontext():
+        return (jax.jit(fn, **jit_kw).trace(*args)
+                .lower(lowering_platforms=("tpu",)).compile())
+
+
+def _one(v5e):
+    return Mesh(np.array(v5e[:1]), ("x",))
+
+
+def _sds(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# -- the serving shapes bench.py measures -----------------------------------
+
+def _serving_shapes(bench):
+    cfg8 = dataclasses.replace(bench.BENCH_8B_CFG, fused_decode=False)
+    return {
+        "319m": (dataclasses.replace(bench.BENCH_CFG, max_seq_len=512), 64),
+        "1b": (dataclasses.replace(bench.BENCH_1B_CFG, max_seq_len=512), 32),
+        "8b_int8": (cfg8, 48),
+    }
+
+
+def _abstract_params(cfg, int8_weights: bool):
+    def make():
+        p = llama.init_params(jax.random.key(0), cfg)
+        if int8_weights:
+            p = quant.fuse_for_decode(
+                quant.quantize_params(p, cast_rest=cfg.dtype), cfg)
+        return p
+
+    return jax.eval_shape(make)
+
+
+def _abstract_cache(cfg, slots):
+    maxp = cfg.max_seq_len // PAGE
+    return jax.eval_shape(
+        lambda: llama.init_paged_cache(cfg, slots * maxp, PAGE)), maxp
+
+
+SERVE_CASES = [("319m", False), ("319m", True), ("1b", False),
+               ("8b_int8", True)]
+
+
+def _serve_setup(bench, v5e, name, kv_int8):
+    cfg, slots = _serving_shapes(bench)[name]
+    cfg = dataclasses.replace(cfg, kv_int8=kv_int8)
+    mesh = _one(v5e)
+    params = _on(mesh, _abstract_params(cfg, name == "8b_int8"))
+    cache, maxp = _abstract_cache(cfg, slots)
+    return cfg, slots, maxp, mesh, params, _on(mesh, cache)
+
+
+# -- kernels, one by one ----------------------------------------------------
+
+def test_flash_forward_and_backward(v5e):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    mesh = _one(v5e)
+    q = _on(mesh, _sds(8, 2048, 8, 128))
+    kv = _on(mesh, _sds(8, 2048, 4, 128))
+    _compile(flash_attention, q, kv, kv)
+    _compile(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), q, kv, kv)
+
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_paged_decode_kernels(v5e, kv_int8):
+    from ray_tpu.ops import paged_attention as pa
+
+    mesh = _one(v5e)
+    L, KVH, H, D, slots, maxp = 4, 8, 32, 128, 48, 4
+    Pn = slots * maxp + 1
+    pool = _sds(L, KVH, Pn, PAGE, D,
+                dtype=jnp.int8 if kv_int8 else jnp.bfloat16)
+    scales = _sds(L, Pn, KVH, 1, dtype=jnp.float32)
+    q = _sds(slots, H, D)
+    new = _sds(L, slots, KVH, D)
+    bt = _sds(slots, maxp, dtype=jnp.int32)
+    ints = _sds(slots, dtype=jnp.int32)
+    ly = _sds(dtype=jnp.int32)
+    a = lambda *xs: _on(mesh, xs)
+    if kv_int8:
+        _compile(lambda q, k, v, ks, vs, ly, bt, ln:
+                 pa.paged_decode_attention_partial(
+                     q, k, v, ly, bt, ln, k_scales=ks, v_scales=vs),
+                 *a(q, pool, pool, scales, scales, ly, bt, ints))
+        _compile(pa.paged_append_quantized,
+                 *a(pool, pool, scales, scales, new, new, ints, ints))
+    else:
+        _compile(pa.paged_decode_attention_partial,
+                 *a(q, pool, pool, ly, bt, ints))
+        _compile(pa.paged_append, *a(pool, pool, new, new, ints, ints))
+        page_pool = _sds(KVH, Pn, PAGE, D)
+        _compile(pa.paged_decode_attention,
+                 *a(q, page_pool, page_pool, bt, ints))
+
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_ragged_kernels(v5e, kv_int8):
+    from ray_tpu.ops import ragged_paged_attention as rpa
+
+    mesh = _one(v5e)
+    L, KVH, H, D, R, maxp, T = 4, 8, 32, 128, 48, 4, 112
+    Pn = R * maxp + 1
+    pool = _sds(L, KVH, Pn, PAGE, D,
+                dtype=jnp.int8 if kv_int8 else jnp.bfloat16)
+    scales = _sds(L, Pn, KVH, 1, dtype=jnp.float32)
+    q = _sds(T, H, D)
+    kv = _sds(T, KVH, D)
+    new = _sds(L, T, KVH, D)
+    rows = _sds(R, dtype=jnp.int32)
+    bt = _sds(R, maxp, dtype=jnp.int32)
+    ly = _sds(dtype=jnp.int32)
+    a = lambda *xs: _on(mesh, xs)
+    if kv_int8:
+        _compile(lambda q, k, v, kp, vp, ks, vs, ly, rs, r0, rl, ro, bt:
+                 rpa.ragged_paged_attention(
+                     q, k, v, kp, vp, ly, rs, r0, rl, ro, bt,
+                     k_scales=ks, v_scales=vs),
+                 *a(q, kv, kv, pool, pool, scales, scales, ly,
+                    rows, rows, rows, rows, bt))
+        _compile(rpa.ragged_paged_append_quantized,
+                 *a(pool, pool, scales, scales, new, new,
+                    rows, rows, rows, rows, bt))
+    else:
+        _compile(rpa.ragged_paged_attention,
+                 *a(q, kv, kv, pool, pool, ly, rows, rows, rows, rows, bt))
+        _compile(rpa.ragged_paged_append,
+                 *a(pool, pool, new, new, rows, rows, rows, rows, bt))
+
+
+def test_mamba_ssd_kernel(v5e):
+    from ray_tpu.ops.mamba_ssd import ssd_pallas
+
+    mesh = _one(v5e)
+    B, S, H, Pd, N = 4, 4096, 8, 64, 128
+    f32 = jnp.float32
+    _compile(lambda x, la, b, c: ssd_pallas(x, la, b, c, 128),
+             *_on(mesh, (_sds(B, S, H, Pd, dtype=f32),
+                         _sds(B, S, H, dtype=f32),
+                         _sds(B, S, N, dtype=f32),
+                         _sds(B, S, N, dtype=f32))))
+
+
+# -- the model steps the engine and the trainer jit -------------------------
+
+@pytest.mark.parametrize("name,kv_int8", SERVE_CASES)
+def test_decode_step(bench, v5e, name, kv_int8):
+    cfg, slots, maxp, mesh, params, cache = _serve_setup(
+        bench, v5e, name, kv_int8)
+    ints, bt, active = _on(mesh, (
+        _sds(slots, dtype=jnp.int32), _sds(slots, maxp, dtype=jnp.int32),
+        _sds(slots, dtype=jnp.bool_)))
+    _compile(lambda p, t, a, b, l, c: llama.decode_slots_paged(
+        p, t, a, b, l, cfg, c), params, ints, active, bt, ints, cache,
+        donate_argnums=(5,))
+
+
+@pytest.mark.parametrize("name,kv_int8", SERVE_CASES)
+def test_ragged_step(bench, v5e, name, kv_int8):
+    cfg, slots, maxp, mesh, params, cache = _serve_setup(
+        bench, v5e, name, kv_int8)
+    T = slots + PAGE                      # EngineConfig.token_budget=0
+    toks, rows, bt, idx = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, maxp, dtype=jnp.int32), _sds(40, dtype=jnp.int32)))
+    _compile(lambda p, t, pos, rs, r0, rl, ro, b, c:
+             llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+             params, toks, toks, rows, rows, rows, rows, bt, cache,
+             donate_argnums=(8,))
+    # speculative verify rows: extra logits at logit_idx
+    _compile(lambda p, t, pos, rs, r0, rl, ro, b, c, li:
+             llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c,
+                                     logit_idx=li),
+             params, toks, toks, rows, rows, rows, rows, bt, cache, idx,
+             donate_argnums=(8,))
+
+
+@pytest.mark.parametrize("name,kv_int8,prompt", [
+    ("319m", False, 128), ("319m", False, 512), ("1b", False, 512),
+    ("8b_int8", True, 128)])
+def test_prefill_batch(bench, v5e, name, kv_int8, prompt):
+    cfg, slots, maxp, mesh, params, cache = _serve_setup(
+        bench, v5e, name, kv_int8)
+    K = 4
+    toks, lens, pages = _on(mesh, (
+        _sds(K, prompt, dtype=jnp.int32), _sds(K, dtype=jnp.int32),
+        _sds(K, maxp, dtype=jnp.int32)))
+    _compile(lambda p, t, n, pg, c: llama.prefill_batch_paged(
+        p, t, n, pg, cfg, c), params, toks, lens, pages, cache,
+        donate_argnums=(4,))
+
+
+def test_prefill_long_prompt(bench, v5e):
+    """The long_rag / bursty mixes' 1536-token prompts (max_seq 2048)."""
+    cfg = dataclasses.replace(bench.BENCH_CFG, max_seq_len=2048)
+    mesh = _one(v5e)
+    params = _on(mesh, _abstract_params(cfg, False))
+    cache, maxp = _abstract_cache(cfg, 8)
+    toks, lens, pages = _on(mesh, (
+        _sds(2, 1536, dtype=jnp.int32), _sds(2, dtype=jnp.int32),
+        _sds(2, maxp, dtype=jnp.int32)))
+    _compile(lambda p, t, n, pg, c: llama.prefill_batch_paged(
+        p, t, n, pg, cfg, c), params, toks, lens, pages, _on(mesh, cache),
+        donate_argnums=(4,))
+
+
+def _train_step(bench, cfg, mesh, batch, optimizer=None):
+    """The jitted step JaxTrainer builds, and an abstract (state, batch)."""
+    from ray_tpu.train.state import create_train_state
+    from ray_tpu.train.step import compile_train_step
+
+    tx = optimizer or bench.default_optimizer(
+        1e-4, warmup_steps=10, mu_dtype=jnp.bfloat16)
+    with mesh:
+        state = jax.eval_shape(lambda: create_train_state(
+            llama.init_params(jax.random.key(0), cfg), tx))
+        step, _state_sh, _batch_sh = compile_train_step(
+            mesh, lambda p, b: llama.loss_fn(p, b, cfg), tx, state,
+            llama.logical_axes(cfg), {"tokens": ("batch", None)})
+    # The jit carries in_shardings over the topology's devices, so the
+    # abstract arguments need none of their own.
+    tokens = {"tokens": _sds(batch, bench.SEQ, dtype=jnp.int32)}
+    return step.__wrapped__, state, tokens
+
+
+@pytest.mark.parametrize("which,batch", [
+    ("BENCH_CFG", 8), ("BENCH_1B_CFG", 8), ("BENCH_2B_CFG", 4)])
+def test_train_step_one_device(bench, v5e, which, batch):
+    from ray_tpu.train import adamw8bit
+
+    mesh = create_mesh(MeshSpec(dp=1), devices=v5e[:1])
+    opt = (adamw8bit(1e-4, warmup_steps=10)
+           if which == "BENCH_2B_CFG" else None)
+    step, state, tokens = _train_step(
+        bench, getattr(bench, which), mesh, batch, opt)
+    with mesh:
+        compiled = (step.trace(state, tokens)
+                    .lower(lowering_platforms=("tpu",)).compile())
+    # Printed, not asserted: this sum says 15.6 GiB for the 319M step,
+    # whose peak_bytes_in_use on the chip is 3.6 GiB (chip_smoke, PR 21),
+    # so it is no predictor of what fits; memory_stats() is.
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(f"{which} B={batch}: compiler memory analysis "
+          f"{used / 2**30:.2f} GiB")
+
+
+# -- four devices: the flash kernel under dp/fsdp/tp -------------------------
+
+def test_train_step_fsdp4(bench, v5e):
+    """JaxTrainer with no ScalingConfig takes every device with fsdp."""
+    mesh = create_mesh(MeshSpec(dp=1, fsdp=4), devices=v5e)
+    step, state, tokens = _train_step(bench, bench.BENCH_CFG, mesh, 8)
+    with mesh:
+        step.trace(state, tokens).lower(
+            lowering_platforms=("tpu",)).compile()
+
+
+def test_train_step_tp_and_dp(bench, v5e):
+    mesh = create_mesh(MeshSpec(dp=2, tp=2), devices=v5e)
+    step, state, tokens = _train_step(bench, bench.BENCH_CFG, mesh, 8)
+    with mesh:
+        step.trace(state, tokens).lower(
+            lowering_platforms=("tpu",)).compile()
+
+
+def _tp4_setup(bench, v5e, kv_int8=False):
+    cfg = dataclasses.replace(bench.BENCH_CFG, max_seq_len=1024,
+                              tensor_parallel=True, kv_int8=kv_int8)
+    mesh = create_serving_mesh(1, 4, devices=v5e)
+    place = lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
+    params = _abstract_params(cfg, False)
+    # parameter shardings as shard_params_for_serving would place them
+    from ray_tpu.parallel.sharding import spec_for
+
+    rules = llama._SERVING_RULES
+    axes = frozenset(mesh.axis_names)
+    params = jax.tree.map(
+        lambda ax, leaf: place(leaf, NamedSharding(
+            mesh, spec_for(ax, rules, mesh_axes=axes))),
+        llama.logical_axes(cfg), params,
+        is_leaf=lambda x: isinstance(x, tuple))
+    slots = 16
+    cache, maxp = _abstract_cache(cfg, slots)
+    cache = jax.tree.map(place, cache, llama.paged_cache_shardings(
+        mesh, kv_int8=kv_int8))
+    return cfg, mesh, params, cache, slots, maxp
+
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_decode_step_tp4(bench, v5e, kv_int8):
+    cfg, mesh, params, cache, slots, maxp = _tp4_setup(bench, v5e, kv_int8)
+    ints, bt, active = _on(mesh, (
+        _sds(slots, dtype=jnp.int32), _sds(slots, maxp, dtype=jnp.int32),
+        _sds(slots, dtype=jnp.bool_)))
+    _compile(lambda p, t, a, b, l, c: llama.decode_slots_paged(
+        p, t, a, b, l, cfg, c), params, ints, active, bt, ints, cache,
+        mesh=mesh, donate_argnums=(5,))
+
+
+def test_prefill_512_tp4(bench, v5e):
+    """128 compiles even unsharded (_flash_eligible needs S >= 256);
+    512 is the length that enters the flash kernel."""
+    cfg, mesh, params, cache, slots, maxp = _tp4_setup(bench, v5e)
+    toks, lens, pages = _on(mesh, (
+        _sds(2, 512, dtype=jnp.int32), _sds(2, dtype=jnp.int32),
+        _sds(2, maxp, dtype=jnp.int32)))
+    _compile(lambda p, t, n, pg, c: llama.prefill_batch_paged(
+        p, t, n, pg, cfg, c), params, toks, lens, pages, cache,
+        mesh=mesh, donate_argnums=(4,))
+
+
+# -- the fused megakernel ---------------------------------------------------
+
+@pytest.mark.parametrize("name,kv_int8", [("319m", False),
+                                          ("8b_int8", True)])
+def test_fused_decode_step(bench, v5e, name, kv_int8):
+    cfg, slots, maxp, mesh, params, cache = _serve_setup(
+        bench, v5e, name, kv_int8)
+    cfg = dataclasses.replace(cfg, fused_decode=True)
+    ints, bt, active = _on(mesh, (
+        _sds(slots, dtype=jnp.int32), _sds(slots, maxp, dtype=jnp.int32),
+        _sds(slots, dtype=jnp.bool_)))
+    _compile(lambda p, t, a, b, l, c: llama.decode_slots_paged(
+        p, t, a, b, l, cfg, c), params, ints, active, bt, ints, cache,
+        donate_argnums=(5,))
+
+
+@pytest.mark.parametrize("name,kv_int8", [("319m", False),
+                                          ("8b_int8", True)])
+def test_fused_ragged_step(bench, v5e, name, kv_int8):
+    cfg, slots, maxp, mesh, params, cache = _serve_setup(
+        bench, v5e, name, kv_int8)
+    cfg = dataclasses.replace(cfg, fused_decode=True)
+    T = slots + PAGE
+    toks, rows, bt = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, maxp, dtype=jnp.int32)))
+    _compile(lambda p, t, pos, rs, r0, rl, ro, b, c:
+             llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+             params, toks, toks, rows, rows, rows, rows, bt, cache,
+             donate_argnums=(8,))

@@ -167,7 +167,9 @@ def test_cross_plane_trace_and_metrics(rt, tmp_path, cpu_devices):
     # produced utilization rows.
     progs = xprof.programs()
     assert {"train.step", "serve.prefill", "serve.decode"} <= set(progs)
-    rl = xprof.roofline()
+    from ray_tpu.utils.accelerator import chip_spec
+
+    rl = xprof.roofline(chip_spec("TPU v5 lite"))
     assert "train.step" in rl and "serve.decode" in rl
     assert rl["train.step"]["wall_s_per_step"] > 0
     assert 0 < rl["train.step"]["flops_utilization"]

@@ -47,9 +47,13 @@ def test_record_compiled_and_roofline():
     tracing.enable_tracing()
     t0 = time.time()
     tracing.record_span("t.span", t0, t0 + 0.01)
-    rl = xprof.roofline()
+    # No published peaks for a CPU: the default spec is an error here,
+    # never a nominal fallback; tests state the chip they pretend to be.
+    with pytest.raises(LookupError):
+        xprof.roofline()
+    spec = chip_spec("TPU v5 lite")
+    rl = xprof.roofline(spec)
     row = rl["t.matmul"]
-    spec = chip_spec()
     assert row["achieved_flops_per_s"] == pytest.approx(
         rec.flops / row["wall_s_per_step"])
     assert row["flops_utilization"] == pytest.approx(
@@ -66,7 +70,7 @@ def test_roofline_divides_wall_by_steps_attr():
     t0 = time.time()
     tracing.record_span("t.loop", t0, t0 + 1.0,
                         attributes={"tokens": 10})
-    row = xprof.roofline()["t.stepped"]
+    row = xprof.roofline(chip_spec("TPU v5 lite"))["t.stepped"]
     assert row["wall_s_per_step"] == pytest.approx(0.1, rel=1e-3)
 
 
@@ -90,7 +94,7 @@ def test_cost_analysis_missing_keys_yield_absent_metrics():
     # Absent means absent: no zero-valued samples for these programs.
     assert "t.none" not in text
     # And with no measured wall there is no roofline row either.
-    assert xprof.roofline() == {}
+    assert xprof.roofline(chip_spec("TPU v5 lite")) == {}
 
 
 def test_memory_stats_none_yields_absent_gauges(cpu_devices):
@@ -187,7 +191,7 @@ def test_cli_profile_command():
         ray_tpu.shutdown()
 
 
-def test_chip_spec_versions_and_fallback():
+def test_chip_spec_versions():
     from ray_tpu.utils import accelerator as acc
 
     for v in (acc.GOOGLE_TPU_V4, acc.GOOGLE_TPU_V5E, acc.GOOGLE_TPU_V5P,
@@ -196,5 +200,5 @@ def test_chip_spec_versions_and_fallback():
         assert spec["chip"] == v
         assert spec["peak_flops"] > 1e14
         assert spec["peak_hbm_bytes_per_s"] > 1e11
-    fb = chip_spec("TPU-v999")
-    assert fb["peak_flops"] > 0 and fb["peak_hbm_bytes_per_s"] > 0
+    with pytest.raises(LookupError):   # unknown device: error, no default
+        chip_spec("TPU-v999")
