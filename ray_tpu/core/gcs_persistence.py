@@ -27,12 +27,15 @@ A driver/head restart pointed at the same store rebuilds the tables
 
 from __future__ import annotations
 
+import contextlib
 import os
 import pickle
 import tempfile
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Sequence
+
+from ray_tpu.util import tracing
 
 _FORMAT_VERSION = 2
 
@@ -250,6 +253,7 @@ class GcsPersistence:
         self._save_lock = threading.Lock()
         self._seq = 0
         self._collect: Optional[Callable[[], Dict[str, Any]]] = None
+        self._span_name: Optional[str] = None
         self._thread: Optional[threading.Thread] = None
 
     # -- load --------------------------------------------------------------
@@ -277,8 +281,13 @@ class GcsPersistence:
 
     # -- flusher -----------------------------------------------------------
 
-    def start_flusher(self, collect: Callable[[], Dict[str, Any]]) -> None:
+    def start_flusher(self, collect: Callable[[], Dict[str, Any]],
+                      span_name: Optional[str] = None) -> None:
+        """``span_name``: each periodic flush runs inside this span
+        (util/tracing), so a profiler capture of the process shows
+        when the flusher held the interpreter."""
         self._collect = collect
+        self._span_name = span_name
         self._thread = threading.Thread(
             target=self._flush_loop, name="gcs-flush", daemon=True
         )
@@ -295,7 +304,9 @@ class GcsPersistence:
 
     def _try_flush(self) -> None:
         try:
-            with self._save_lock:
+            span = (tracing.span(self._span_name, record=False)
+                    if self._span_name else contextlib.nullcontext())
+            with span, self._save_lock:
                 self.save(self._collect())
         except Exception:
             pass  # persistence is best-effort; next tick retries

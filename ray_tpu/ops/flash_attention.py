@@ -116,6 +116,7 @@ def _flash_forward(q, k, v, *, scale, causal, block_q, block_kv):
     )
     return pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -265,6 +266,7 @@ def _flash_backward(q, k_exp, v_exp, o, lse, do, *, scale, causal,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_kv=block_kv),
+        name="flash_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=common_in,
         out_specs=pl.BlockSpec((1, 1, block_q, D),
@@ -286,6 +288,7 @@ def _flash_backward(q, k_exp, v_exp, o, lse, do, *, scale, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_kv=block_kv),
+        name="flash_bwd_dkv",
         grid=(B, H, nk, nq),
         in_specs=kv_in,
         out_specs=[

@@ -481,6 +481,7 @@ def fused_decode_layer(
         quantized=quantized, dot_dt=dt)
     x_out, k_new, v_new = pl.pallas_call(
         kern,
+        name="fused_decode_layer",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B_p, D), dt),

@@ -107,6 +107,7 @@ def _ssd_pallas_fwd_impl(x, log_a, Bm, Cm, chunk: int):
     out = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk, heads=H,
                           head_dim=P),
+        name="mamba_ssd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, H * P),

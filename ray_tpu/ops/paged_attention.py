@@ -159,6 +159,7 @@ def paged_decode_attention(
     out = pl.pallas_call(
         functools.partial(_kernel, page=page, scale=scale,
                           soft_cap=soft_cap, kvh=KVH, qpg_p=qpg_p),
+        name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, qpg_p, D), q.dtype),
         interpret=platform.interpret_mode(),
@@ -350,6 +351,7 @@ def paged_decode_attention_partial(
         functools.partial(_kernel_partial, page=page, scale=scale,
                           soft_cap=soft_cap, kvh=KVH, qpg_p=qpg_p,
                           pages_per_cell=G, quantized=quantized),
+        name="paged_attention_partial",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, KVH, qpg_p, D), jnp.float32),
@@ -499,6 +501,7 @@ def paged_append_quantized(k_pools, v_pools, k_scales, v_scales,
     )
     return pl.pallas_call(
         functools.partial(_append_kernel_q, kvh=KVH),
+        name="paged_attention_kv_append",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(k_pools.shape, k_pools.dtype),
@@ -565,6 +568,7 @@ def paged_append(k_pools: jax.Array, v_pools: jax.Array,
     )
     return pl.pallas_call(
         _append_kernel,
+        name="paged_attention_kv_append",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(k_pools.shape, k_pools.dtype),

@@ -381,6 +381,7 @@ def ragged_paged_attention(
         soft_cap=soft_cap, quantized=quantized)
     out = pl.pallas_call(
         kern,
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T_p, H, hd), jnp.float32),
         interpret=platform.interpret_mode(),
@@ -549,6 +550,7 @@ def ragged_paged_append(
         maxp=maxp, quantized=False)
     return pl.pallas_call(
         kern,
+        name="ragged_kv_append",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(k_pools.shape, k_pools.dtype),
@@ -614,6 +616,7 @@ def ragged_paged_append_quantized(
         maxp=maxp, quantized=True)
     return pl.pallas_call(
         kern,
+        name="ragged_kv_append",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(k_pools.shape, k_pools.dtype),
@@ -998,6 +1001,7 @@ def fused_ragged_layer(
         quantized=quantized, dot_dt=dt)
     x_out, k_new, v_new = pl.pallas_call(
         kern,
+        name="fused_ragged_layer",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((T_p, D), dt),

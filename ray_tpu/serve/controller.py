@@ -419,7 +419,8 @@ class ServeController:
                 self._ckpt.save(self._checkpoint_tables())
         except Exception:
             pass
-        self._ckpt.start_flusher(self._checkpoint_tables)
+        self._ckpt.start_flusher(self._checkpoint_tables,
+                                 span_name="serve.ckpt_flush")
         threading.Thread(
             target=self._reconcile_loop, daemon=True, name="serve-reconcile"
         ).start()

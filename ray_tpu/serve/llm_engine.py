@@ -26,7 +26,6 @@ import logging
 import queue
 import threading
 import time
-from collections import deque
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -37,16 +36,27 @@ import numpy as np
 from ray_tpu.core.exceptions import PreemptedError, ShedError
 from ray_tpu.serve import audit as _audit
 from ray_tpu.serve import request_events as _reqev
+from ray_tpu.serve.loop_clock import LoopClock, LoopSecondsFamily
 from ray_tpu.util import tracing
 
 log = logging.getLogger(__name__)
 
 _TELEMETRY = None
 
-# A decode step slower than this many times its running median is a
-# stall worth shouting about (BENCH_r05's 1.14B collapse showed p95
-# TTFT 200x p50 with no engine-side signal of WHERE time went).
-STALL_FACTOR = 5.0
+# At most one flight-recorder trigger per engine in this many seconds:
+# a stalling engine stalls again, and one bundle tells the story.
+LOOP_STALL_TRIGGER_INTERVAL_S = 30.0
+
+
+def _program(name: str, **jit_kwargs):
+    """``jax.jit`` of a function renamed to the program's registered
+    name with dots as underscores, so that a profiler trace shows the
+    module as ``jit_serve_ragged`` whatever the function was called in
+    this file and whatever the step holds."""
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name.replace(".", "_")
+        return jax.jit(fn, **jit_kwargs)
+    return wrap
 
 
 def _telemetry():
@@ -84,11 +94,13 @@ def _telemetry():
             ),
             "step_wall": metrics.Gauge(
                 "raytpu_serve_step_wall_seconds",
-                "High-water mark of per-decode-step wall time "
-                "(dispatch-to-fetch wall of a chunk / steps in it — an "
-                "upper bound on device step time including pipeline "
-                "queueing).",
+                "High-water mark of the step interval: the time "
+                "between consecutive fetched steps while the pipeline "
+                "holds work (a chunk's interval over the steps in it). "
+                "With the device saturated this is the device's step "
+                "time; a stall of host or device widens it.",
             ),
+            "loop_seconds": LoopSecondsFamily(),
             "queue_age": metrics.Gauge(
                 "raytpu_serve_admission_queue_age_seconds",
                 "Age of the oldest request still waiting for admission "
@@ -580,10 +592,12 @@ def _sample(logits: jax.Array, temperature: jax.Array,
             key: jax.Array) -> jax.Array:
     """logits [..., V], temperature broadcastable — greedy at temp 0,
     categorical otherwise; computed on device."""
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
-    sampled = jax.random.categorical(key, scaled, axis=-1)
-    return jnp.where(temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1)
+        scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
+        sampled = jax.random.categorical(key, scaled, axis=-1)
+        return jnp.where(temperature <= 0.0, greedy,
+                         sampled).astype(jnp.int32)
 
 
 @dataclasses.dataclass
@@ -1285,9 +1299,12 @@ class LLMEngine:
         # tokens of terminal requests.
         self._good_tokens = 0
         self._terminal_tokens = 0
-        self._step_walls: deque = deque(maxlen=64)  # recent s/step
-        self._step_wall_hw = 0.0  # watermark mirrored to the gauge
-        self._stall_events = 0  # steps past STALL_FACTOR x median
+        # Where the loop's wall time goes, and the pace of its steps
+        # (serve/loop_clock): the high-water mark of the step interval
+        # is mirrored to the gauge, a stall is named and recorded.
+        self._clock = LoopClock(on_stall=self._on_loop_stall,
+                                on_high_water=self._tm["step_wall"].set)
+        self._stall_trigger_at = float("-inf")
         self._xprof_recorded: set = set()  # programs already registered
 
         slots = config.max_slots
@@ -1299,7 +1316,8 @@ class LLMEngine:
         # vector and the updated cur come back as extra outputs), and
         # token fetches are deferred + batched.
 
-        @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+        @_program("serve.prefill", static_argnums=(0,),
+                  donate_argnums=(2,))
         def prefill_batch_fn(k, params, cache, tokens, true_lens,
                              slot_or_pages, temps, seed, cur, slot_ids):
             """Prefill k slots in ONE dispatch (k static: {1,2,4,8}).
@@ -1326,7 +1344,8 @@ class LLMEngine:
             # sample beat the emitted real-row token.
             return cache, toks, cur.at[slot_ids].set(toks, mode="drop")
 
-        @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+        @_program("serve.decode", static_argnums=(0,),
+                  donate_argnums=(2,))
         def decode_fn(n_steps, params, cache, cur, active, temps, seed):
             def step(carry, k):
                 cache, cur = carry
@@ -1339,7 +1358,8 @@ class LLMEngine:
             (cache, cur), toks = jax.lax.scan(step, (cache, cur), keys)
             return cache, toks, cur, None  # [n_steps, slots]
 
-        @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+        @_program("serve.decode", static_argnums=(0,),
+                  donate_argnums=(2,))
         def decode_paged_fn(n_steps, params, cache, cur, active, temps,
                             seed, bt, lens):
             def step(carry, k):
@@ -1360,7 +1380,7 @@ class LLMEngine:
             return cache, toks, cur, lens
 
         if self._paged and adapter.prefill_chunk is not None:
-            @partial(jax.jit, donate_argnums=(1,))
+            @_program("serve.prefill_chunk", donate_argnums=(1,))
             def prefill_chunk_fn(params, cache, tokens, start, chunk_lens,
                                  pages_rows, temps, seed, cur, slot_ids):
                 logits, cache = adapter.prefill_chunk(
@@ -1395,7 +1415,7 @@ class LLMEngine:
                     "token_budget must leave room for a prefill chunk "
                     f"beside {config.max_slots} decode rows")
 
-            @partial(jax.jit, donate_argnums=(1,))
+            @_program("serve.ragged", donate_argnums=(1,))
             def ragged_step_fn(params, cache, host_toks, decode_mask,
                                tok_slot, tok_pos, row_slot, row_start,
                                row_len, row_off, temps, seed, cur,
@@ -1431,7 +1451,7 @@ class LLMEngine:
                         "ragged_step_lora")
                 self._adapters = adapter.make_adapter_pool(config)
 
-                @partial(jax.jit, donate_argnums=(1,))
+                @_program("serve.ragged", donate_argnums=(1,))
                 def ragged_step_lora_fn(params, cache, host_toks,
                                         decode_mask, tok_slot, tok_pos,
                                         row_slot, row_start, row_len,
@@ -1460,7 +1480,7 @@ class LLMEngine:
                         "prefix_cache requires an adapter with "
                         "copy_page (the COW split of a shared page)")
 
-                @partial(jax.jit, donate_argnums=(0,))
+                @_program("serve.copy_page", donate_argnums=(0,))
                 def copy_page_fn(cache, src, dst):
                     return adapter.copy_page(cache, src, dst)
 
@@ -1472,7 +1492,7 @@ class LLMEngine:
                 # padding rows are sliced off on the host, the
                 # scatter's padding rows write zeros into the scratch
                 # page, where nothing can read them.
-                @jax.jit
+                @_program("serve.mig_gather")
                 def mig_gather_fn(cache, ids):
                     out = {"k": cache["k"][:, :, ids],
                            "v": cache["v"][:, :, ids]}
@@ -1481,7 +1501,7 @@ class LLMEngine:
                         out["v_scale"] = cache["v_scale"][:, ids]
                     return out
 
-                @partial(jax.jit, donate_argnums=(0,))
+                @_program("serve.mig_scatter", donate_argnums=(0,))
                 def mig_scatter_fn(cache, ids, payload):
                     out = dict(cache)
                     for key in ("k", "v"):
@@ -1522,7 +1542,7 @@ class LLMEngine:
         self._admitting: List[Request] = []
 
         if adapter.prefill_batch is not None:
-            @partial(jax.jit, donate_argnums=(1,))
+            @_program("serve.prefill", donate_argnums=(1,))
             def prefill_batched_fn(params, cache, tokens, true_lens,
                                    slot_or_pages, temps, seed, cur,
                                    slot_ids):
@@ -1620,7 +1640,7 @@ class LLMEngine:
         # of every verify row's k+1 candidate tokens, padded with 0.
         self._spec_tv = min(Td, R * (config.spec_k + 1))
 
-        @partial(jax.jit, donate_argnums=(1,))
+        @_program("serve.spec_draft", donate_argnums=(1,))
         def draft_feed_fn(params, cache, host_toks, tok_pos, row_slot,
                           row_start, row_len, row_off, bt):
             logits, cache = da.ragged_step(
@@ -1630,7 +1650,7 @@ class LLMEngine:
             # through its sequence end yields draft token 1 directly.
             return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        @partial(jax.jit, donate_argnums=(1,))
+        @_program("serve.spec_chain", donate_argnums=(1,))
         def draft_chain_fn(params, cache, prev, tok_pos, row_slot,
                            row_start, row_len, row_off, bt):
             # One-token rows at row_off = arange(R): the previous
@@ -1644,7 +1664,7 @@ class LLMEngine:
         self._draft_feed_fn = draft_feed_fn
         self._draft_chain_fn = draft_chain_fn
 
-        @partial(jax.jit, donate_argnums=(1,))
+        @_program("serve.ragged_spec", donate_argnums=(1,))
         def ragged_step_spec_fn(params, cache, host_toks, decode_mask,
                                 tok_slot, tok_pos, row_slot, row_start,
                                 row_len, row_off, temps, seed, cur,
@@ -1666,7 +1686,7 @@ class LLMEngine:
         self._ragged_step_spec_fn = ragged_step_spec_fn
         if (self._adapters is not None
                 and adapter.ragged_step_lora_verify is not None):
-            @partial(jax.jit, donate_argnums=(1,))
+            @_program("serve.ragged_spec", donate_argnums=(1,))
             def ragged_step_spec_lora_fn(params, cache, host_toks,
                                          decode_mask, tok_slot, tok_pos,
                                          row_slot, row_start, row_len,
@@ -1862,7 +1882,8 @@ class LLMEngine:
             "waiting": self._waiting.qsize(),
             "steps": self._steps,
             "tokens_out": self._tokens_out,
-            "stall_events": self._stall_events,
+            "stall_events": self._clock.stall_events,
+            "loop": self._clock.snapshot(),
             "requests": self._ring.counts_by_state(),
         }
         if self._paged:
@@ -2144,8 +2165,9 @@ class LLMEngine:
         self._admitting = []
         self._state_dirty = True  # active/temps/bt/lens changed
         self._unprocessed += 1
+        self._clock.step_dispatched()
         self._fetchq.put(("prefill", toks_dev, 0, list(batch),
-                          time.monotonic()))
+                          self._steps))
 
     def _alloc_slot_pages(self, req: Request,
                           need: Optional[int] = None) -> Optional[int]:
@@ -2565,16 +2587,42 @@ class LLMEngine:
                 for i, (slot, _req, k_eff, _n) in enumerate(plan)}
 
     def _dispatch_ragged_step(self) -> bool:
-        """Pack and dispatch ONE unified ragged step: first a decode
+        """One unified ragged step through its three phases on the
+        loop's clock, tied together by ``seq`` (the step's ordinal,
+        ``stats()["steps"]`` once it is dispatched): ``llm.pack`` builds
+        the step's host arrays and records what it holds, ``llm.dispatch``
+        is the jitted call, ``llm.commit`` advances the mirrors and hands
+        the step to the fetch thread.  Returns False when nothing fit."""
+        seq = self._steps + 1
+        with self._clock.phase("pack") as span:
+            step = self._pack_ragged_step()
+            if step is None:
+                return False
+            name, fn, args, parts, finishing, counts = step
+            span.set(seq=seq, **counts)
+        with self._clock.phase("dispatch", {"seq": seq}):
+            self._cache, toks_dev, self._cur_dev = \
+                self._instrumented_dispatch(
+                    name, fn, args,
+                    span_name="llm.ragged", steps_attr="tokens",
+                    cost_steps=float(self._token_budget),
+                )
+        with self._clock.phase("commit", {"seq": seq}):
+            self._commit_ragged_step(parts, finishing, counts, toks_dev)
+        return True
+
+    def _pack_ragged_step(self):
+        """Pack ONE unified ragged step: first a decode
         row (one token) or a speculative verify row (the slot's true
         last token + its k drafts) for every active slot with budget
         left, then prefill chunks from the incremental track until
         token_budget is full.  Decode rows are never displaced by
         prompt tokens — that priority IS the no-stall guarantee
         chunked prefill only approximates — and drafting never runs
-        while prefill chunks contend for the budget.  Returns False
-        when nothing fit (every slot budget-capped by in-flight
-        tokens, no prompt tokens pending)."""
+        while prefill chunks contend for the budget.  Returns the
+        program, its arguments and what the step holds, or None when
+        nothing fit (every slot budget-capped by in-flight tokens, no
+        prompt tokens pending)."""
         from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
 
         T, R = self._token_budget, self.config.max_slots
@@ -2709,7 +2757,7 @@ class LLMEngine:
             budget -= len(chunk)
             n_prefill += len(chunk)
         if not rows:
-            return False
+            return None
         self._refresh_state_args()
         if step_adapters:
             # LoRA variant: same program + the pool, the step's page
@@ -2756,12 +2804,26 @@ class LLMEngine:
                         ("serve.ragged", self._ragged_step_fn))
         if n_spec:
             args += (logit_idx,)
-        self._cache, toks_dev, self._cur_dev = \
-            self._instrumented_dispatch(
-                name, fn, args,
-                span_name="llm.ragged", steps_attr="tokens",
-                cost_steps=float(T),
-            )
+        page = self.config.page_size
+        counts = {
+            "n_decode": n_decode, "n_prefill": n_prefill,
+            "n_spec": n_spec, "rows": len(rows), "budget": T,
+            # pages that hold each packed row's tokens once this step
+            # has written them, against the cells the fused layer
+            # kernel's grid walks whatever the rows hold
+            "live_cells": sum(
+                -(-(r["start"] + len(r["tokens"] or (0,))) // page)
+                for r in rows),
+            "grid_cells": R * (self._maxp + 1),
+        }
+        return name, fn, args, parts, finishing, counts
+
+    def _commit_ragged_step(self, parts, finishing, counts,
+                            toks_dev) -> None:
+        """The dispatched step's bookkeeping: host mirrors advance at
+        dispatch, counters count, the fetch thread gets the result."""
+        n_decode, n_prefill, n_spec = (
+            counts["n_decode"], counts["n_prefill"], counts["n_spec"])
         now = time.monotonic()
         for kind, req, slot, i in parts:
             if kind == "verify":
@@ -2801,9 +2863,8 @@ class LLMEngine:
                                     + len(self._backlog))
         self._tm["queue_age"].set(self._admission_queue_age())
         self._unprocessed += 1
-        self._fetchq.put(("ragged", toks_dev, 1, list(parts),
-                          time.monotonic()))
-        return True
+        self._clock.step_dispatched()
+        self._fetchq.put(("ragged", toks_dev, 1, list(parts), self._steps))
 
     def _emit(self, req: Request, slot: int, tok: int, burst: int = 1):
         """Record one generated token; finish/free the slot if done.
@@ -3141,8 +3202,8 @@ class LLMEngine:
         else:
             # Completion marker: counts against the pipeline depth.
             self._unprocessed += 1
-            self._fetchq.put(("pfchunk", toks_dev, 0, [],
-                              time.monotonic()))
+            self._clock.step_dispatched()
+            self._fetchq.put(("pfchunk", toks_dev, 0, [], self._steps))
 
     def _refresh_state_args(self) -> None:
         """Rebuild the per-slot control arrays only when admission or a
@@ -3173,31 +3234,30 @@ class LLMEngine:
                 oldest = req.submitted_at
         return 0.0 if oldest is None else time.monotonic() - oldest
 
-    def _note_step_time(self, wall_s: float, chunk: int) -> bool:
-        """Record a decode chunk's dispatch-to-fetch wall time as
-        per-step cost; returns True (and logs a warning) when the step
-        blows past STALL_FACTOR x its running median.  The median is
-        over the last 64 chunks, so a slow ramp moves the baseline
-        while a one-off stall (page thrash, preempted host, device
-        queue collapse) stands out."""
-        per_step = wall_s / max(chunk, 1)
-        history = sorted(self._step_walls)
-        self._step_walls.append(per_step)
-        if per_step > self._step_wall_hw:
-            self._step_wall_hw = per_step
-            self._tm["step_wall"].set(per_step)
-        if len(history) < 8:
-            return False
-        median = history[len(history) // 2]
-        if median > 0 and per_step > STALL_FACTOR * median:
-            log.warning(
-                "decode step stall: %.1f ms/step vs running median "
-                "%.1f ms (x%.1f, chunk=%d, active=%d)",
-                per_step * 1e3, median * 1e3, per_step / median,
-                chunk, len(self._slot_req))
-            self._stall_events += 1
-            return True
-        return False
+    def _on_loop_stall(self, *, phase: str, wall_ms: float,
+                       seq: Optional[int], median_ms: float) -> None:
+        """The loop clock found an iteration, or a step interval, far
+        past the running median: say which phase held it, leave one
+        event in the flight recorder, and (rate-limited) arm a bundle,
+        so that what every other thread recorded around the stall is
+        kept."""
+        log.warning(
+            "engine loop stall: %.1f ms in phase %r near step %s "
+            "(running median step interval %.1f ms, active=%d)",
+            wall_ms, phase, seq, median_ms, len(self._slot_req))
+        try:
+            from ray_tpu.util import flight_recorder
+
+            # ``step_seq``: the recorder numbers its own events ``seq``
+            flight_recorder.record("loop_stall", phase=phase,
+                                   wall_ms=wall_ms, step_seq=seq,
+                                   engine=self._engine_id)
+            now = time.monotonic()
+            if now - self._stall_trigger_at >= LOOP_STALL_TRIGGER_INTERVAL_S:
+                self._stall_trigger_at = now
+                flight_recorder.trigger("loop_stall", detail=phase)
+        except Exception:
+            pass  # the recorder must never take the loop down with it
 
     def _dispatch_decode(self, chunk: int) -> None:
         """Enqueue one decode chunk WITHOUT a host sync: cur and lens
@@ -3250,8 +3310,9 @@ class LLMEngine:
                 self._inflight_tokens.get(slot, 0) + chunk
             )
         self._unprocessed += 1
+        self._clock.step_dispatched()
         self._fetchq.put(("decode", toks_dev, chunk, participants,
-                          time.monotonic()))
+                          self._steps))
 
     def _fetch_loop(self) -> None:
         """Dedicated fetch thread: drain every queued entry, batch them
@@ -3271,7 +3332,14 @@ class LLMEngine:
                     return
                 entries.append(nxt)
             try:
-                fetched = jax.device_get([e[1] for e in entries])
+                # Entries leave in dispatch order, so their steps are
+                # the range first..last (numbers only: a profiler stat
+                # that is a list in text reads back as its last item).
+                with tracing.span("llm.fetch", record=False, attributes={
+                        "seq_first": entries[0][4],
+                        "seq_last": entries[-1][4],
+                        "seqs": len(entries)}):
+                    fetched = jax.device_get([e[1] for e in entries])
             except BaseException as e:
                 self._fetched.put(e)
                 return
@@ -3288,83 +3356,99 @@ class LLMEngine:
         """Emit every fetched entry available; returns True if any was
         processed.  ``block`` waits briefly for the next one (used when
         the loop has nothing to dispatch)."""
-        processed = False
-        while True:
-            try:
-                item = self._fetched.get(timeout=0.02) if block \
-                    and not processed else self._fetched.get_nowait()
-            except queue.Empty:
-                return processed
-            if isinstance(item, BaseException):
-                raise item
-            processed = True
-            self._unprocessed -= 1
-            (kind, _dev, chunk, participants, t_disp), toks = item
-            now = time.monotonic()
-            if kind == "decode":
-                self._note_step_time(now - t_disp, chunk)
-            if kind == "pfchunk":
-                continue  # completion marker only (pipeline gating)
-            if kind == "ragged":
-                # One unified step: toks is the [R] row-sample vector;
-                # participants carry (kind, req, slot, row) for decode
-                # rows and final prefill chunks (mid-chunk rows have
-                # nothing to emit).  Wall time feeds the same stall
-                # watermark as decode — a ragged step IS a decode step
-                # for every running stream in it.
-                self._note_step_time(now - t_disp, 1)
-                if isinstance(toks, tuple):
-                    toks, ver = toks  # speculative step: (sampled, verify)
-                else:
-                    ver = None
-                for rkind, req, slot, i in participants:
-                    if rkind == "verify":
-                        self._finish_verify(req, slot, i, ver, now)
-                        continue
-                    left = self._inflight_tokens.get(slot, 0) - 1
-                    if left > 0:
-                        self._inflight_tokens[slot] = left
-                    else:
-                        self._inflight_tokens.pop(slot, None)
-                    if req.finished_at is not None:
-                        continue  # cancelled/preempted while in flight
-                    if rkind == "first":
-                        req.first_token_at = now
-                        self._ring.record(req.request_id,
-                                          _reqev.DECODING)
-                        self._emit(req, slot, int(toks[i]))
-                    elif self._slot_req.get(slot) is req:
-                        self._emit(req, slot, int(toks[i]))
-                continue
-            if kind == "prefill":
-                for i, (req, slot) in enumerate(participants):
-                    left = self._inflight_tokens.get(slot, 0) - 1
-                    if left > 0:
-                        self._inflight_tokens[slot] = left
-                    else:
-                        self._inflight_tokens.pop(slot, None)
-                    if req.finished_at is not None:
-                        # Cancelled while its prefill was in flight:
-                        # the slot is already freed (and may even be
-                        # re-owned) — emitting would re-register it.
-                        continue
-                    req.first_token_at = now
-                    self._ring.record(req.request_id, _reqev.DECODING)
-                    self._emit(req, slot, int(toks[i]))
-                continue
-            for slot, req in participants:
-                left = self._inflight_tokens.get(slot, 0) - chunk
+        try:
+            if block:
+                with self._clock.phase("idle"):
+                    item = self._fetched.get(timeout=0.02)
+            else:
+                item = self._fetched.get_nowait()
+        except queue.Empty:
+            return False
+        with self._clock.phase("emit") as span:
+            seqs: List[int] = []
+            steps = 0
+            while item is not None:
+                if isinstance(item, BaseException):
+                    raise item
+                self._unprocessed -= 1
+                seqs.append(item[0][4])
+                steps += self._emit_fetched(item)
+                try:
+                    item = self._fetched.get_nowait()
+                except queue.Empty:
+                    item = None
+            span.set(seq_first=seqs[0], seq_last=seqs[-1], seqs=len(seqs))
+            # The steps that came back together share one interval of
+            # the loop clock's pace.
+            self._clock.steps_fetched(steps, self._unprocessed, seqs[-1])
+        return True
+
+    def _emit_fetched(self, item) -> int:
+        """Emit one fetched entry's tokens; returns the decode steps it
+        carried (0 for a prefill entry or a completion marker)."""
+        (kind, _dev, chunk, participants, _seq), toks = item
+        now = time.monotonic()
+        if kind == "pfchunk":
+            return 0  # completion marker only (pipeline gating)
+        if kind == "ragged":
+            # One unified step: toks is the [R] row-sample vector;
+            # participants carry (kind, req, slot, row) for decode
+            # rows and final prefill chunks (mid-chunk rows have
+            # nothing to emit).  A ragged step IS a decode step for
+            # every running stream in it.
+            if isinstance(toks, tuple):
+                toks, ver = toks  # speculative step: (sampled, verify)
+            else:
+                ver = None
+            for rkind, req, slot, i in participants:
+                if rkind == "verify":
+                    self._finish_verify(req, slot, i, ver, now)
+                    continue
+                left = self._inflight_tokens.get(slot, 0) - 1
                 if left > 0:
                     self._inflight_tokens[slot] = left
                 else:
                     self._inflight_tokens.pop(slot, None)
-                if self._slot_req.get(slot) is not req:
-                    # Finished in an earlier chunk (EOS): overshoot.
+                if req.finished_at is not None:
+                    continue  # cancelled/preempted while in flight
+                if rkind == "first":
+                    req.first_token_at = now
+                    self._ring.record(req.request_id,
+                                      _reqev.DECODING)
+                    self._emit(req, slot, int(toks[i]))
+                elif self._slot_req.get(slot) is req:
+                    self._emit(req, slot, int(toks[i]))
+            return 1
+        if kind == "prefill":
+            for i, (req, slot) in enumerate(participants):
+                left = self._inflight_tokens.get(slot, 0) - 1
+                if left > 0:
+                    self._inflight_tokens[slot] = left
+                else:
+                    self._inflight_tokens.pop(slot, None)
+                if req.finished_at is not None:
+                    # Cancelled while its prefill was in flight:
+                    # the slot is already freed (and may even be
+                    # re-owned) — emitting would re-register it.
                     continue
-                for k in range(chunk):
-                    self._emit(req, slot, int(toks[k, slot]))
-                    if self._slot_req.get(slot) is not req:
-                        break  # finished mid-chunk
+                req.first_token_at = now
+                self._ring.record(req.request_id, _reqev.DECODING)
+                self._emit(req, slot, int(toks[i]))
+            return 0
+        for slot, req in participants:
+            left = self._inflight_tokens.get(slot, 0) - chunk
+            if left > 0:
+                self._inflight_tokens[slot] = left
+            else:
+                self._inflight_tokens.pop(slot, None)
+            if self._slot_req.get(slot) is not req:
+                # Finished in an earlier chunk (EOS): overshoot.
+                continue
+            for k in range(chunk):
+                self._emit(req, slot, int(toks[k, slot]))
+                if self._slot_req.get(slot) is not req:
+                    break  # finished mid-chunk
+        return chunk
 
     def _process_cancels(self) -> None:
         """Resolve pending cancellations against every registry the
@@ -3839,52 +3923,20 @@ class LLMEngine:
                     pass
                 req.stream.put(err)
             raise
+        finally:
+            self._clock.retire()
 
     def _loop_body(self):
+        clock = self._clock
         while not self._stopped.is_set():
-            self._process_cancels()
-            self._process_drain()
-            self._process_migrations()
-            self._process_audits()
-            backlog = self._paged and (self._backlog or self._prefilling)
-            if (not self._slot_req and self._waiting.empty()
-                    and not backlog and self._unprocessed == 0):
-                # Idle: settle the incremental audit debt, and
-                # opportunistically run the rate-limited deep audit —
-                # idle is the one time a full walk costs nobody
-                # latency.
-                self._auditor.maybe_incremental()
-                if not self._draining.is_set():
-                    self._auditor.maybe_idle_deep(time.monotonic())
-                self._work.wait(timeout=0.05)
-                self._work.clear()
-                continue
-            self._process_fetched(block=False)
-            self._admit()
-            self._auditor.maybe_incremental()
-            dispatched = False
-            if self._ragged:
-                if ((self._slot_req or self._prefilling)
-                        and self._unprocessed < self._PIPELINE_DEPTH):
-                    dispatched = self._dispatch_ragged_step()
-            else:
-                if (self._prefilling
-                        and self._unprocessed < self._PIPELINE_DEPTH):
-                    # One incremental-prefill chunk per iteration rides
-                    # the device queue BETWEEN decode chunks: running
-                    # streams stall at most one chunk per long-prompt
-                    # segment.
-                    self._dispatch_prefill_chunk()
-                    dispatched = True
-                if (self._slot_req
-                        and self._unprocessed < self._PIPELINE_DEPTH):
-                    chunk = self._chunk_size()
-                    if chunk > 0:
-                        self._dispatch_decode(chunk)
-                        dispatched = True
-            if not dispatched and self._unprocessed > 0:
-                # Nothing to dispatch — wait for the fetcher.
-                self._process_fetched(block=True)
+            clock.begin()
+            with tracing.span("llm.loop", record=False) as span:
+                seq = self._loop_iteration()
+                if seq is not None:
+                    span.set(seq=seq)
+            # a stall is reported near the step this iteration
+            # dispatched, else the last one dispatched
+            clock.end(seq if seq is not None else self._steps)
         # Clean stop: drain queued migration ops exactly like the crash
         # path does, so their waiters get an immediate "engine stopped"
         # instead of hanging until their timeout expires.
@@ -3911,3 +3963,64 @@ class LLMEngine:
             self._auditor.run(deep=True)
         except Exception:
             log.exception("final shutdown audit failed")
+
+    def _loop_iteration(self) -> Optional[int]:
+        """One iteration of the engine loop, every stretch of it inside
+        a phase of the loop clock (``llm.control``, ``llm.admit``,
+        ``llm.pack``/``llm.dispatch``/``llm.commit``, ``llm.emit``,
+        ``llm.idle``).  Returns the ``seq`` of the step it dispatched,
+        or None."""
+        clock = self._clock
+        with clock.phase("control"):
+            self._process_cancels()
+            self._process_drain()
+            self._process_migrations()
+            self._process_audits()
+            backlog = self._paged and (self._backlog or self._prefilling)
+            idle = (not self._slot_req and self._waiting.empty()
+                    and not backlog and self._unprocessed == 0)
+            if idle:
+                # Idle: settle the incremental audit debt, and
+                # opportunistically run the rate-limited deep audit —
+                # idle is the one time a full walk costs nobody
+                # latency.
+                self._auditor.maybe_incremental()
+                if not self._draining.is_set():
+                    self._auditor.maybe_idle_deep(time.monotonic())
+        if idle:
+            with clock.phase("idle"):
+                self._work.wait(timeout=0.05)
+                self._work.clear()
+            return None
+        self._process_fetched(block=False)
+        with clock.phase("admit"):
+            self._admit()
+        with clock.phase("control"):
+            self._auditor.maybe_incremental()
+        steps_before = self._steps
+        dispatched = False
+        if self._ragged:
+            if ((self._slot_req or self._prefilling)
+                    and self._unprocessed < self._PIPELINE_DEPTH):
+                dispatched = self._dispatch_ragged_step()
+        else:
+            if (self._prefilling
+                    and self._unprocessed < self._PIPELINE_DEPTH):
+                # One incremental-prefill chunk per iteration rides
+                # the device queue BETWEEN decode chunks: running
+                # streams stall at most one chunk per long-prompt
+                # segment.
+                with clock.phase("dispatch"):
+                    self._dispatch_prefill_chunk()
+                dispatched = True
+            if (self._slot_req
+                    and self._unprocessed < self._PIPELINE_DEPTH):
+                chunk = self._chunk_size()
+                if chunk > 0:
+                    with clock.phase("dispatch"):
+                        self._dispatch_decode(chunk)
+                    dispatched = True
+        if not dispatched and self._unprocessed > 0:
+            # Nothing to dispatch — wait for the fetcher.
+            self._process_fetched(block=True)
+        return self._steps if self._steps != steps_before else None

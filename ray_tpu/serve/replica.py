@@ -418,12 +418,13 @@ class ReplicaActor:
         """True = healthy; the string "DRAINING" = alive but draining
         (the controller starts a replacement without tearing this
         replica out of the route table first); raises = unhealthy."""
-        if self._draining:
-            return "DRAINING"
-        fn = getattr(self._callable, "check_health", None)
-        if fn is not None:
-            fn()  # raises on unhealthy (parity: serve health-check contract)
-        return True
+        with tracing.span("serve.health_probe", record=False):
+            if self._draining:
+                return "DRAINING"
+            fn = getattr(self._callable, "check_health", None)
+            if fn is not None:
+                fn()  # raises on unhealthy (parity: serve health-check contract)
+            return True
 
     def doctor(self, deep: bool = True) -> Optional[Dict[str, Any]]:
         """Run the invariant doctor on the user callable's engine
@@ -462,50 +463,52 @@ class ReplicaActor:
         while not self._metrics_stop.wait(
                 backoff if failing else interval_s):
             try:
-                controller = api.get_actor(CONTROLLER_NAME)
-                if failing:
-                    failing = False
-                    backoff = interval_s or 0.05
-                    self._last_prefix_summary = None
-                    self._last_adapter_summary = None
-                qage, goodput, arrivals = 0.0, None, None
-                if self._pressure_fn is not None:
-                    try:
-                        p = self._pressure_fn()
-                        qage = float(p.get("queue_age_s") or 0.0)
-                        goodput = p.get("goodput")
-                        arrivals = p.get("arrivals")
-                    except Exception:
-                        pass
-                controller.record_autoscaling_metric.remote(
-                    self.app_name, self.deployment_name, self.replica_id,
-                    self.num_ongoing_requests(), time.monotonic(),
-                    qage, goodput, arrivals,
-                )
-                if self._pushes_summary:
-                    try:
-                        summary = self._callable.prefix_summary()
-                    except Exception:
-                        summary = None
-                    if (summary is not None
-                            and summary != self._last_prefix_summary):
-                        self._last_prefix_summary = summary
-                        controller.record_prefix_summary.remote(
-                            self.app_name, self.deployment_name,
-                            self.replica_id, summary,
-                        )
-                if self._pushes_adapters:
-                    try:
-                        asum = self._callable.adapter_summary()
-                    except Exception:
-                        asum = None
-                    if (asum is not None
-                            and asum != self._last_adapter_summary):
-                        self._last_adapter_summary = asum
-                        controller.record_adapter_summary.remote(
-                            self.app_name, self.deployment_name,
-                            self.replica_id, asum,
-                        )
+                with tracing.span("serve.push_pressure",
+                                  record=False):
+                    controller = api.get_actor(CONTROLLER_NAME)
+                    if failing:
+                        failing = False
+                        backoff = interval_s or 0.05
+                        self._last_prefix_summary = None
+                        self._last_adapter_summary = None
+                    qage, goodput, arrivals = 0.0, None, None
+                    if self._pressure_fn is not None:
+                        try:
+                            p = self._pressure_fn()
+                            qage = float(p.get("queue_age_s") or 0.0)
+                            goodput = p.get("goodput")
+                            arrivals = p.get("arrivals")
+                        except Exception:
+                            pass
+                    controller.record_autoscaling_metric.remote(
+                        self.app_name, self.deployment_name, self.replica_id,
+                        self.num_ongoing_requests(), time.monotonic(),
+                        qage, goodput, arrivals,
+                    )
+                    if self._pushes_summary:
+                        try:
+                            summary = self._callable.prefix_summary()
+                        except Exception:
+                            summary = None
+                        if (summary is not None
+                                and summary != self._last_prefix_summary):
+                            self._last_prefix_summary = summary
+                            controller.record_prefix_summary.remote(
+                                self.app_name, self.deployment_name,
+                                self.replica_id, summary,
+                            )
+                    if self._pushes_adapters:
+                        try:
+                            asum = self._callable.adapter_summary()
+                        except Exception:
+                            asum = None
+                        if (asum is not None
+                                and asum != self._last_adapter_summary):
+                            self._last_adapter_summary = asum
+                            controller.record_adapter_summary.remote(
+                                self.app_name, self.deployment_name,
+                                self.replica_id, asum,
+                            )
             except Exception:
                 failing = True
                 backoff = min(max(backoff, 0.05) * 2.0, 2.0)

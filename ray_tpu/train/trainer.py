@@ -260,27 +260,33 @@ class JaxTrainer:
                             batch = self.shard_batch(next(it))
                         c0 = time.perf_counter()
                         tm["data_wait_s"].observe(c0 - w0)
-                        # Host-side timing: jax dispatch is async, so
-                        # off-report steps measure dispatch cost; report
-                        # steps sync below via device_get.
+                        # ``train.compute`` times the asynchronous
+                        # call, not the step: jax dispatch returns at
+                        # once, so this is dispatch cost (plus whatever
+                        # the call had to wait for).  The step's device
+                        # time ends in ``train.report``, where the
+                        # metrics are read back.  The name stays because
+                        # xprof.record_compiled joins on it.
                         with tracing.span("train.compute"):
                             self._state, metrics = self._step_fn(
                                 self._state, batch)
                         tm["step_s"].observe(time.perf_counter() - c0)
                         tm["steps"].inc()
                         if step % rc.report_every == 0 or step == num_steps:
-                            m = {k: float(jax.device_get(v))
-                                 for k, v in metrics.items()}
-                            m["steps_per_sec"] = step / (
-                                time.perf_counter() - t0)
-                            history.append(m)
-                            last_metrics = m
-                            # Shared device-plane sampler (TPU/GPU HBM
-                            # watermarks; absent on CPU backends).
-                            xprof.sample_device_memory()
-                            self._emit_memory_gauges()
-                            if report:
-                                report(m)
+                            with tracing.span("train.report"):
+                                m = {k: float(jax.device_get(v))
+                                     for k, v in metrics.items()}
+                                m["steps_per_sec"] = step / (
+                                    time.perf_counter() - t0)
+                                history.append(m)
+                                last_metrics = m
+                                # Shared device-plane sampler (TPU/GPU
+                                # HBM watermarks; absent on CPU
+                                # backends).
+                                xprof.sample_device_memory()
+                                self._emit_memory_gauges()
+                                if report:
+                                    report(m)
                         if ckpt and rc.checkpoint_every \
                                 and step % rc.checkpoint_every == 0:
                             # sharded arrays go straight to orbax — each

@@ -42,6 +42,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu.util import tracing
+
 _TELEMETRY = None
 
 # (resolution seconds, capacity points) — index 0 is the raw ring fed
@@ -387,7 +389,8 @@ def ensure_started(period_s: Optional[float] = None) -> None:
 def _sample_loop() -> None:
     while not _stop.wait(_period_s):
         try:
-            sample_now()
+            with tracing.span("telemetry.sample", record=False):
+                sample_now()
         except Exception:
             pass  # sampling is best-effort; next tick retries
 

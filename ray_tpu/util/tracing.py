@@ -12,6 +12,13 @@ spans land in a bounded in-memory buffer and optionally a JSONL file.
 The runtime calls ``capture_context()`` at submit time and
 ``activate(ctx)`` around execution — the exact two hook points the
 reference's propagator uses.
+
+One bridge to the profiler: wherever JAX is already imported in the
+process, every ``span`` also holds a ``jax.profiler.TraceAnnotation``
+open, whether or not tracing was enabled, so a profiler capture shows
+the program's spans (the engine loop's phases, the trainer's, the
+background threads') beside the device's operations on one clock.  This
+module never imports JAX and never initialises a backend.
 """
 
 from __future__ import annotations
@@ -19,6 +26,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import json
+import sys
 import threading
 import time
 import uuid
@@ -29,6 +37,7 @@ _lock = threading.Lock()
 _finished: "collections.deque" = collections.deque(maxlen=10000)
 _export_path: Optional[str] = None
 _tls = threading.local()
+_annotation_cls = None   # jax.profiler.TraceAnnotation, once JAX is imported
 
 
 def enable_tracing(export_file: Optional[str] = None) -> None:
@@ -132,34 +141,89 @@ def _finish(rec: Dict[str, Any]) -> None:
         pass
 
 
-@contextlib.contextmanager
-def span(name: str, ctx: Optional[Dict[str, str]] = None,
-         attributes: Optional[Dict[str, Any]] = None):
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once JAX is imported in this
+    process, else None.  Read from ``sys.modules``: this module never
+    imports JAX itself, and the class initialises no backend."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation_cls = getattr(profiler, "TraceAnnotation", None)
+    return _annotation_cls
+
+
+class span:
     """Open a span; ``ctx`` (from capture_context) makes it a child of
-    the remote caller's span."""
-    if not _enabled:
-        yield None
-        return
-    parent = ctx if ctx is not None else _current()
-    rec = {
-        "trace_id": (parent or {}).get("trace_id") or uuid.uuid4().hex,
-        "span_id": uuid.uuid4().hex[:16],
-        "parent_id": (parent or {}).get("span_id") or "",
-        "name": name,
-        "start": time.time(),
-        "attributes": dict(attributes or {}),
-    }
-    prev = _current()
-    _tls.ctx = {"trace_id": rec["trace_id"], "span_id": rec["span_id"]}
-    try:
-        yield rec
-    except BaseException as e:
-        rec["attributes"]["error"] = repr(e)
-        raise
-    finally:
-        rec["end"] = time.time()
-        _tls.ctx = prev
-        _finish(rec)
+    the remote caller's span.
+
+    Two records, one call.  The span's own record (trace id, span id,
+    parent, start, end, attributes) is kept only after
+    ``enable_tracing()``.  Whether or not it was called, the span also
+    holds a ``jax.profiler.TraceAnnotation`` open for its whole extent
+    wherever JAX is already imported, so any profiler capture
+    (``jax.profiler.start_trace``, ``xprof.capture()``, ``raytpu
+    profile``, the benchmark's traced window) shows the program's spans
+    on the clock of the device's operations, with the attributes as the
+    event's stats.  With tracing off and no capture running the
+    annotation is a flag test: no lock, no buffer, no file, no flight
+    recorder.
+
+    ``set(**attrs)`` adds attributes known only once the work is done
+    (the counts at a phase's boundary); they reach both records.
+    ``record=False`` is for a span that fires in every iteration of a
+    hot loop (the engine loop's phases, a background thread's tick): a
+    capture sees it, the span buffer and the flight recorder never do,
+    so turning tracing on does not flood either."""
+
+    __slots__ = ("record", "_ann", "_prev")
+
+    def __init__(self, name: str, ctx: Optional[Dict[str, str]] = None,
+                 attributes: Optional[Dict[str, Any]] = None, *,
+                 record: bool = True):
+        cls = _trace_annotation()
+        self._ann = (cls(name, **attributes) if attributes else cls(name)
+                     ) if cls is not None else None
+        self.record = None
+        if _enabled and record:
+            parent = ctx if ctx is not None else _current()
+            self.record = {
+                "trace_id": ((parent or {}).get("trace_id")
+                             or uuid.uuid4().hex),
+                "span_id": uuid.uuid4().hex[:16],
+                "parent_id": (parent or {}).get("span_id") or "",
+                "name": name,
+                "start": 0.0,
+                "attributes": dict(attributes or {}),
+            }
+
+    def set(self, **attrs: Any) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+        if self.record is not None:
+            self.record["attributes"].update(attrs)
+
+    def __enter__(self) -> "span":
+        rec = self.record
+        if rec is not None:
+            self._prev = _current()
+            _tls.ctx = {"trace_id": rec["trace_id"],
+                        "span_id": rec["span_id"]}
+            rec["start"] = time.time()
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        rec = self.record
+        if rec is not None:
+            if exc is not None:
+                rec["attributes"]["error"] = repr(exc)
+            rec["end"] = time.time()
+            _tls.ctx = self._prev
+            _finish(rec)
 
 
 def record_span(name: str, start: float, end: float, *,

@@ -1,5 +1,7 @@
-"""Stall telemetry: step-wall watermark, admission-queue age, and the
-N-x-median stall warning in serve/llm_engine.py (the instrumentation
+"""Stall telemetry: the loop clock's pace (the interval between
+consecutive fetched steps while the pipeline holds work, its high-water
+mark and its N-x-median stall count: serve/loop_clock.py), and the
+admission-queue age, in serve/llm_engine.py (the instrumentation
 BENCH_r05's 1.14B collapse was missing — p95 TTFT 200x p50 with no
 engine-side record of where the time went)."""
 
@@ -12,7 +14,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import llama
-from ray_tpu.serve import llm_engine
+from ray_tpu.serve import loop_clock
 from ray_tpu.serve.llm_engine import LLMEngine, _telemetry
 
 
@@ -24,17 +26,21 @@ class _Gauge:
         self.value = v
 
 
-def _shim(paged=True):
-    """A bare object carrying just the state _note_step_time and
-    _admission_queue_age touch, so the helpers are unit-testable
-    without building an engine."""
-    from collections import deque
+class _FakeTime:
+    """perf_counter the test moves by hand."""
 
+    def __init__(self):
+        self.t = 1000.0
+
+    def perf_counter(self):
+        return self.t
+
+
+def _shim(paged=True):
+    """A bare object carrying just the state _admission_queue_age
+    touches, so the helper is unit-testable without building an
+    engine."""
     ns = types.SimpleNamespace()
-    ns._step_walls = deque(maxlen=64)
-    ns._step_wall_hw = 0.0
-    ns._stall_events = 0
-    ns._tm = {"step_wall": _Gauge(), "queue_age": _Gauge()}
     ns._slot_req = {}
     ns._waiting = queue.Queue()
     ns._backlog = []
@@ -42,30 +48,65 @@ def _shim(paged=True):
     return ns
 
 
-def test_note_step_time_watermark_and_stall():
-    ns = _shim()
-    # 20 normal chunks at ~1 ms/step: no warning, watermark tracks max.
+def _paced_clock(monkeypatch):
+    """A loop clock on a fake perf_counter, its high-water mark mirrored
+    to a gauge and its stall reports collected."""
+    fake = _FakeTime()
+    monkeypatch.setattr(loop_clock, "time", fake)
+    gauge, reports = _Gauge(), []
+    clock = loop_clock.LoopClock(
+        on_stall=lambda **kw: reports.append(kw),
+        on_high_water=gauge.set)
+
+    def step(interval_s, n_steps=1, in_flight=1):
+        """One fetch ``interval_s`` x ``n_steps`` after the last."""
+        clock.step_dispatched()
+        fake.t += interval_s * n_steps
+        return clock.steps_fetched(n_steps, in_flight)
+
+    return clock, gauge, reports, step, fake
+
+
+def test_step_interval_watermark_and_stall(monkeypatch):
+    clock, gauge, reports, step, _fake = _paced_clock(monkeypatch)
+    # 20 normal fetches of 8-step chunks at ~1 ms/step: no stall, the
+    # watermark tracks the largest interval per step.
     for i in range(20):
-        warned = LLMEngine._note_step_time(ns, 0.008 + 0.0001 * i, 8)
-        assert not warned
-    assert ns._tm["step_wall"].value == pytest.approx(
-        (0.008 + 0.0019) / 8)
-    # One 10x stall: warned, and the watermark jumps to it.
-    warned = LLMEngine._note_step_time(ns, 0.080, 8)
-    assert warned
-    assert ns._tm["step_wall"].value == pytest.approx(0.010)
+        assert not step(0.001 + 0.0000125 * i, n_steps=8)
+    assert gauge.value == pytest.approx(0.001 + 0.0000125 * 19)
+    assert clock.stall_events == 0
+    # One 10x interval: counted, and the watermark jumps to it.  It is
+    # under the 250 ms floor, so it is no flight-recorder event.
+    assert step(0.010, n_steps=8)
+    assert gauge.value == pytest.approx(0.010)
+    assert clock.stall_events == 1 and reports == []
+    # A stall past the floor is reported with the phase that held the
+    # interval: nothing ran in the loop, so the largest share is the
+    # first phase's zero and the report still names a phase.
+    assert step(0.4)
+    assert clock.stall_events == 2
+    assert [r["phase"] for r in reports] == [loop_clock.PHASES[0]]
+    assert reports[0]["wall_ms"] == pytest.approx(400.0)
 
 
-def test_note_step_time_needs_history():
-    """The first few chunks establish the median — no warning before
-    there is a baseline to deviate from."""
-    ns = _shim()
+def test_step_interval_needs_history(monkeypatch):
+    """The first few intervals establish the median — no stall before
+    there is a baseline to deviate from, and idle time between an empty
+    pipeline and the next dispatch is no interval at all."""
+    clock, _gauge, _reports, step, fake = _paced_clock(monkeypatch)
     for _ in range(7):
-        assert not LLMEngine._note_step_time(ns, 0.001, 1)
+        assert not step(0.001)
     # 8th sample has 7 of history — still below the 8-sample floor.
-    assert not LLMEngine._note_step_time(ns, 1.0, 1)
-    # With >=8 samples of history the same stall now warns.
-    assert LLMEngine._note_step_time(ns, 1.0, 1)
+    assert not step(1.0)
+    # With >=8 samples of history the same interval now counts.
+    assert step(1.0)
+    assert clock.stall_events == 1
+    # The pipeline drains; ten idle seconds pass; the next step's
+    # interval starts at its own dispatch.
+    assert not step(0.001, in_flight=0)
+    fake.t += 10.0
+    assert not step(0.001)
+    assert clock.stall_events == 1
 
 
 def test_admission_queue_age():
@@ -108,6 +149,10 @@ def test_engine_run_populates_gauges_with_clean_grammar():
     text = metrics.export_prometheus()
     assert "raytpu_serve_step_wall_seconds" in text
     assert "raytpu_serve_admission_queue_age_seconds" in text
+    # The loop clock's per-phase seconds, read at scrape time.
+    assert 'raytpu_serve_loop_seconds_total{phase="idle"}' in text
+    loop = eng.stats()["loop"]
+    assert loop["iterations"] > 0 and loop["seconds"]["dispatch"] > 0
     # The decode path ran, so the watermark must be a real positive.
     samples = _telemetry()["step_wall"]._samples()
     assert samples and samples[0][2] > 0

@@ -1,0 +1,411 @@
+"""The program's spans in the profiler's trace (util/tracing's bridge to
+``jax.profiler.TraceAnnotation``), the spans and counts the engine loop
+and the trainer open through it, the stable names of the jitted steps
+and of the scopes inside them, and the engine's always-on loop clock.
+
+Everything runs on the CPU backend: a capture there holds the host plane
+with the program's spans and their stats, and the XLA operations with
+their ``hlo_module``; device times are no part of these tests.
+"""
+
+import collections
+import contextlib
+import glob
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import llama
+from ray_tpu.serve import llm_engine
+from ray_tpu.serve.llm_engine import (
+    EngineConfig,
+    LLMEngine,
+    llama_paged_adapter,
+)
+from ray_tpu.util import flight_recorder, tracing
+
+CFG = llama.LlamaConfig(
+    vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
+    mlp_dim=64, max_seq_len=128, remat=False, dtype=jnp.float32,
+    param_dtype=jnp.float32,
+)
+Span = collections.namedtuple("Span", "name line start end stats")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return llama.init_params(jax.random.key(0), CFG)
+
+
+def _engine(params, **kw):
+    cfg = dict(max_slots=4, max_seq_len=128, min_prefill_bucket=16,
+               page_size=16, ragged_batching=True, token_budget=36,
+               prefill_chunk=16)
+    cfg.update(kw)
+    return LLMEngine(params, llama_paged_adapter(CFG), EngineConfig(**cfg))
+
+
+@contextlib.contextmanager
+def _capture(tmp_path):
+    """A profiler capture without the Python tracer; yields a function
+    that, after the block, returns the trace's events."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    loaded = []
+
+    def events():
+        return loaded[0]
+
+    try:
+        yield events
+    finally:
+        jax.profiler.stop_trace()
+        from jax.profiler import ProfileData
+
+        path = sorted(glob.glob(str(
+            tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")))[-1]
+        out = []
+        for plane in ProfileData.from_file(path).planes:
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    out.append(Span(e.name, (plane.name, i), e.start_ns,
+                                    e.start_ns + e.duration_ns,
+                                    dict(e.stats)))
+        loaded.append(out)
+
+
+def _named(events, name):
+    return sorted((e for e in events if e.name == name),
+                  key=lambda e: e.start)
+
+
+def _inside(child, parent):
+    return (child.line == parent.line and parent.start <= child.start
+            and child.end <= parent.end)
+
+
+def _seqs(span):
+    """The steps a fetch or an emit span covers: entries leave in
+    dispatch order, so the span names the first and the last."""
+    assert span.stats["seqs"] >= 1
+    return list(range(span.stats["seq_first"], span.stats["seq_last"] + 1))
+
+
+# -- the bridge ------------------------------------------------------------
+
+def test_span_reaches_the_profiler_with_nesting_and_stats(tmp_path):
+    assert not tracing.is_enabled()
+    with _capture(tmp_path) as events:
+        with tracing.span("bridge.outer", attributes={"seq": 7}) as sp:
+            with tracing.span("bridge.inner"):
+                time.sleep(0.002)
+            sp.set(n_decode=3, share=0.5, label="x")
+    (outer,), (inner,) = (_named(events(), "bridge.outer"),
+                          _named(events(), "bridge.inner"))
+    assert _inside(inner, outer)
+    assert inner.end - inner.start >= 2_000_000
+    assert outer.stats == {"seq": 7, "n_decode": 3, "share": 0.5,
+                           "label": "x"}
+    # tracing was off: the span kept no record of its own
+    assert tracing.finished_spans() == [] or all(
+        s["name"] not in ("bridge.outer", "bridge.inner")
+        for s in tracing.finished_spans())
+
+
+def test_span_off_and_uncaptured_touches_nothing(monkeypatch):
+    """With tracing off and no capture a span is a flag test: it never
+    reaches the span buffer, the export file or the flight recorder."""
+    def boom(*_a, **_k):
+        raise AssertionError("a disabled span recorded something")
+
+    monkeypatch.setattr(tracing, "_finish", boom)
+    monkeypatch.setattr(flight_recorder, "record", boom)
+    assert not tracing.is_enabled()
+    with tracing.span("bridge.quiet", attributes={"a": 1}) as sp:
+        sp.set(b=2)
+        assert sp.record is None
+    # and a hot-loop span stays out of both even with tracing on
+    tracing.enable_tracing()
+    try:
+        with tracing.span("bridge.hot", record=False) as sp:
+            assert sp.record is None
+    finally:
+        tracing.disable_tracing()
+
+
+def test_enabled_span_keeps_its_record_and_context():
+    tracing.clear()
+    tracing.enable_tracing()
+    try:
+        with tracing.span("bridge.parent", attributes={"k": 1}) as parent:
+            with tracing.span("bridge.child") as child:
+                child.set(late=True)
+    finally:
+        tracing.disable_tracing()
+    by_name = {s["name"]: s for s in tracing.finished_spans()}
+    assert by_name["bridge.child"]["parent_id"] == parent.record["span_id"]
+    assert by_name["bridge.child"]["trace_id"] == parent.record["trace_id"]
+    assert by_name["bridge.child"]["attributes"] == {"late": True}
+    assert by_name["bridge.parent"]["attributes"] == {"k": 1}
+    assert (by_name["bridge.parent"]["start"]
+            <= by_name["bridge.child"]["start"]
+            <= by_name["bridge.child"]["end"]
+            <= by_name["bridge.parent"]["end"])
+    tracing.clear()
+
+
+# -- the engine loop's spans ----------------------------------------------
+
+def _step_tokens():
+    out = collections.Counter()
+    for _n, tags, value, _k in \
+            llm_engine._telemetry()["step_tokens"]._samples():
+        out[dict(tags).get("phase")] += value
+    return out
+
+
+def test_engine_spans_chain_by_seq_and_count_tokens(params, tmp_path):
+    eng = _engine(params)
+    try:
+        # warm up outside the capture: the first dispatch compiles
+        eng.generate([1, 2, 3], max_new_tokens=2)
+        before, steps0 = _step_tokens(), eng.stats()["steps"]
+        with _capture(tmp_path) as events:
+            rng = np.random.default_rng(0)
+            streams = [eng.submit(rng.integers(1, 127, size=n).tolist(),
+                                  max_new_tokens=6, temperature=0.0)
+                       for n in (40, 3, 23)]
+            for s in streams:
+                s.result(timeout_s=120)
+            # let the loop close its last iteration's spans
+            time.sleep(0.2)
+        after, steps1 = _step_tokens(), eng.stats()["steps"]
+    finally:
+        eng.shutdown()
+    ev = events()
+    packs = [p for p in _named(ev, "llm.pack") if "seq" in p.stats]
+    seqs = [p.stats["seq"] for p in packs]
+    assert seqs == list(range(steps0 + 1, steps1 + 1))
+    # the counts at the boundary are the step_tokens counter's growth
+    assert sum(p.stats["n_decode"] for p in packs) == \
+        after["decode"] - before["decode"]
+    assert sum(p.stats["n_prefill"] for p in packs) == \
+        after["prefill"] - before["prefill"] == 40 + 3 + 23
+    for p in packs:
+        assert 0 < p.stats["live_cells"] <= p.stats["grid_cells"]
+        assert p.stats["grid_cells"] == 4 * (128 // 16 + 1)
+        assert (p.stats["n_decode"] + p.stats["n_prefill"]
+                + p.stats["n_spec"]) <= p.stats["budget"] == 36
+        assert p.stats["rows"] >= 1
+    # every step has its dispatch and commit under the same seq, inside
+    # the loop iteration that names it
+    loops = _named(ev, "llm.loop")
+    for name in ("llm.dispatch", "llm.commit"):
+        assert [d.stats["seq"] for d in _named(ev, name)] == seqs
+    by_seq = {lp.stats["seq"]: lp for lp in loops if "seq" in lp.stats}
+    assert sorted(by_seq) == seqs
+    for span in packs + _named(ev, "llm.dispatch"):
+        assert _inside(span, by_seq[span.stats["seq"]])
+    # the fetch thread fetched, and the loop emitted, each step once,
+    # in order, on their own threads' lines
+    fetched = [s for f in _named(ev, "llm.fetch") for s in _seqs(f)]
+    emitted = [s for e in _named(ev, "llm.emit") for s in _seqs(e)]
+    assert fetched == seqs and emitted == seqs
+    assert ({f.line for f in _named(ev, "llm.fetch")}
+            .isdisjoint({p.line for p in packs}))
+    names = {e.name for e in ev}
+    assert {"llm.control", "llm.admit", "llm.idle"} <= names
+    # the step's operations run under the registered program's name
+    modules = {e.stats["hlo_module"] for e in ev if "hlo_module" in e.stats}
+    assert "jit_serve_ragged" in modules
+
+
+def test_trainer_spans_nest_under_the_step(tmp_path):
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+
+    trainer = JaxTrainer(
+        init_params=lambda r: llama.init_params(r, CFG),
+        loss_fn=lambda p, b: llama.loss_fn(p, b, CFG),
+        params_axes=llama.logical_axes(CFG),
+        batch_axes={"tokens": ("batch", None)},
+        scaling_config=ScalingConfig(mesh_spec=MeshSpec(dp=1),
+                                     devices=jax.devices("cpu")[:1]),
+        run_config=RunConfig(report_every=1), seed=0)
+    rng = np.random.default_rng(0)
+
+    def batches():
+        while True:
+            yield {"tokens": rng.integers(0, 128, (2, 16), dtype=np.int32)}
+
+    assert trainer.fit(batches(), num_steps=1).error is None   # compiles
+    with _capture(tmp_path) as events:
+        result = trainer.fit(batches(), num_steps=3)
+    assert result.error is None
+    ev = events()
+    steps = _named(ev, "train.step")
+    assert [s.stats["step"] for s in steps] == [1, 2, 3]
+    for name in ("train.data_wait", "train.compute", "train.report"):
+        children = _named(ev, name)
+        assert len(children) == 3, name
+        for child, step in zip(children, steps):
+            assert _inside(child, step), name
+    modules = {e.stats["hlo_module"] for e in ev if "hlo_module" in e.stats}
+    assert "jit_train_step" in modules
+
+
+# -- stable names inside the programs --------------------------------------
+
+def _lower_with_scopes(make_lowered, monkeypatch, scoped: bool):
+    if not scoped:
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda _name: contextlib.nullcontext())
+    try:
+        return make_lowered()
+    finally:
+        monkeypatch.undo()
+
+
+def _lower_serving_step(params):
+    adapter = llama_paged_adapter(CFG)
+    cache = adapter.init_cache(8, 16)
+    T, R, maxp = 16, 4, 8
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)   # noqa: E731
+
+    def serve_ragged(params, cache, toks, pos, rs, rst, rl, ro, bt):
+        logits, cache = adapter.ragged_step(params, toks, pos, rs, rst,
+                                            rl, ro, bt, cache)
+        return llm_engine._sample(logits, jnp.zeros((R,)),
+                                  jax.random.key(0)), cache
+
+    return jax.jit(serve_ragged).lower(
+        params, cache, i32(T), i32(T), i32(R), i32(R), i32(R), i32(R),
+        i32(R, maxp))
+
+
+def _lower_train_step(params):
+    from ray_tpu.train import default_optimizer
+    from ray_tpu.train.state import create_train_state
+    from ray_tpu.train.step import make_train_step
+
+    import dataclasses
+
+    cfg = dataclasses.replace(CFG, loss_chunk=8)   # the chunked head
+    tx = default_optimizer()
+    step = make_train_step(lambda p, b: llama.loss_fn(p, b, cfg), tx)
+    assert step.__name__ == "train_step"
+    state = create_train_state(params, tx)
+    return jax.jit(step).lower(
+        state, {"tokens": jnp.zeros((2, 16), jnp.int32)})
+
+
+@pytest.mark.parametrize("lower, scopes", [
+    (_lower_serving_step, ("embed", "weight_slice", "attention", "mlp",
+                           "kv_append", "lm_head", "sample")),
+    (_lower_train_step, ("loss", "optimizer", "grad_norm", "embed",
+                         "attention", "mlp", "lm_head")),
+], ids=["serve_ragged", "train_step"])
+def test_scopes_change_metadata_only(params, monkeypatch, lower, scopes):
+    scoped = _lower_with_scopes(lambda: lower(params), monkeypatch, True)
+    plain = _lower_with_scopes(lambda: lower(params), monkeypatch, False)
+    # a scope is one element of an operation's name path, which may
+    # start at it inside a loop's body; under a transformation it reads
+    # jvp(attention), transpose(jvp(mlp))
+    with_locations = scoped.as_text(debug_info=True)
+    without = plain.as_text(debug_info=True)
+    for scope in scopes:
+        element = rf"[/(\"]{scope}[/)\"]"
+        assert re.search(element, with_locations), scope
+        assert not re.search(element, without), scope
+    # without locations the two programs are the same text
+    assert scoped.as_text() == plain.as_text()
+
+
+def test_engine_programs_are_named_after_their_registration(params):
+    eng = _engine(params, prefix_cache=True)
+    try:
+        assert eng._ragged_step_fn.__name__ == "serve_ragged"
+        assert eng._prefill_batch_fn.__name__ == "serve_prefill"
+        assert eng._decode_fn.__name__ == "serve_decode"
+        assert eng._copy_page_fn.__name__ == "serve_copy_page"
+    finally:
+        eng.shutdown()
+
+
+# -- the loop clock ---------------------------------------------------------
+
+def test_loop_phases_account_for_the_loops_wall(params):
+    eng = _engine(params)
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=2)
+        rng = np.random.default_rng(1)
+        streams = [eng.submit(rng.integers(1, 127, size=n).tolist(),
+                              max_new_tokens=24, temperature=0.0)
+                   for n in (40, 9, 23, 5)]
+        for s in streams:
+            s.result(timeout_s=120)
+        loop = eng.stats()["loop"]
+    finally:
+        eng.shutdown()
+    assert set(loop["seconds"]) == {"control", "admit", "pack", "dispatch",
+                                    "commit", "emit", "idle"}
+    assert loop["iterations"] > 20
+    assert sum(loop["seconds"].values()) == pytest.approx(
+        loop["wall_s"], rel=0.05)
+    assert all(v >= 0 for v in loop["seconds"].values())
+    assert loop["seconds"]["pack"] > 0 and loop["seconds"]["emit"] > 0
+    longest = loop["longest"]
+    assert longest["wall_ms"] > 0 and longest["phase"] in loop["seconds"]
+    assert loop["step_interval_median_ms"] > 0
+    assert loop["step_interval_max_ms"] >= loop["step_interval_median_ms"]
+
+
+def test_slowed_phase_yields_one_named_loop_stall(params, monkeypatch):
+    flight_recorder.clear()
+    eng = _engine(params)
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=2)
+        stream = eng.submit(list(range(1, 30)), max_new_tokens=60,
+                            temperature=0.0)
+        deadline = time.monotonic() + 60
+        while (eng.stats()["loop"]["step_interval_median_ms"] is None
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        assert eng.stats()["loop"]["step_interval_median_ms"] is not None
+        admit, slowed = eng._admit, []
+
+        def slow_admit():
+            if not slowed:
+                slowed.append(True)
+                time.sleep(0.4)
+            return admit()
+
+        monkeypatch.setattr(eng, "_admit", slow_admit)
+        stream.result(timeout_s=120)
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert slowed
+    stalls = [e for e in flight_recorder.snapshot()["driver"]
+              if e["kind"] == "loop_stall"]
+    # exactly one event names the slowed phase (a starved test machine
+    # may add a stall of its own under another phase's name)
+    named = [s for s in stalls if s["phase"] == "admit"]
+    assert len(named) == 1, stalls
+    assert named[0]["wall_ms"] >= 400
+    assert 1 <= named[0]["step_seq"] <= stats["steps"]
+    # the trigger is rate-limited: one bundle for the first stall
+    triggers = [e for e in flight_recorder.snapshot()["driver"]
+                if e["kind"] == "trigger" and e["reason"] == "loop_stall"]
+    assert len(triggers) == 1
+    assert triggers[0]["detail"] == stalls[0]["phase"]
+    # the longest iteration is this one, or the first dispatch's compile
+    assert stats["loop"]["longest"]["phase"] in ("admit", "dispatch")
+    assert stats["loop"]["longest"]["wall_ms"] >= 400
+    assert stats["loop"]["seconds"]["admit"] >= 0.4
+    flight_recorder.clear()
