@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1371,6 +1371,22 @@ def decode_slots_paged_fused(
     return logits.astype(jnp.float32), new_cache, new_len
 
 
+def ragged_weight_routes(params: Params, cfg: LlamaConfig
+                         ) -> Optional[Dict[str, List[str]]]:
+    """Which operands the fused layer kernel of ``ragged_step_paged``
+    reads in place from the stacked weights and which it has XLA slice
+    (copy) per layer first, for these parameters; None where the step
+    does not run that kernel.  A fused artifact (``quant.
+    fuse_for_decode``) reads every matrix in place; a tree with separate
+    projections pays a copy of those every step, and this is where that
+    shows without a trace."""
+    if not cfg.fused_decode or cfg.tensor_parallel:
+        return None
+    from ray_tpu.ops.ragged_paged_attention import weight_routes
+
+    return weight_routes(params["layers"])
+
+
 def ragged_step_paged(
     params: Params,
     tokens: jax.Array,       # [T] flat ragged token buffer
@@ -1403,6 +1419,15 @@ def ragged_step_paged(
     new_cache).  Padding rows (row_len == 0) return garbage logits —
     callers mask by row_len.  Length bookkeeping stays host-side.
 
+    With ``cfg.fused_decode`` (and no ``lora``) each layer is one
+    ``ops/ragged_paged_attention.fused_ragged_layer`` call.  It is given
+    the stacked ``params["layers"]`` whole, with the layer's index, and
+    reads that layer's weights where they are stored: a fused artifact
+    (``quant.fuse_for_decode``) is read once a step and never copied;
+    ``ragged_weight_routes`` says what a given tree gets.  The unfused
+    and LoRA bodies take their layer's slice in XLA, which fuses it
+    into the einsum that reads it.
+
     ``lora`` is an optional ``(stacks, tok_adapter, scale)`` triple
     (ops/segmented_lora): per-token segmented LoRA deltas are added at
     every targeted projection — qkv PRE-RoPE, where the base
@@ -1417,6 +1442,7 @@ def ragged_step_paged(
             "yet — use the prefill/decode pipeline for tp serving")
     from ray_tpu.ops.ragged_paged_attention import (
         fused_ragged_layer,
+        layer_slice,
         ragged_paged_append,
         ragged_paged_append_quantized,
         ragged_paged_attention,
@@ -1433,17 +1459,13 @@ def ragged_step_paged(
         sin1, cos1 = sin[0], cos[0]                    # [T, hd//2]
         x = params["tok_embed"][tokens].astype(cfg.dtype)   # [T, D]
 
-    # The scan runs over the layer index and each body takes its
-    # layer's slice itself (what ``lax.scan`` over the stacked tree
-    # does, spelled out), so that the slice, which XLA materialises as
-    # a copy of each stacked weight, sits under a scope of its own.
+    # The scan runs over the layer index, not over the stacked tree.
+    # The fused kernel takes the tree whole and reads its layer's
+    # weights where they lie; the other two bodies take their layer's
+    # slice themselves (what ``lax.scan`` over the tree does, spelled
+    # out, so that the slice sits under the scope ``weight_slice``) and
+    # XLA fuses it into the einsum that reads it.
     stacked = params["layers"]
-
-    def layer_slice(tree, li):
-        with jax.named_scope("weight_slice"):
-            return jax.tree.map(
-                lambda w: lax.dynamic_index_in_dim(w, li, 0,
-                                                   keepdims=False), tree)
 
     if cfg.fused_decode and lora is None:
         layer_fn = partial(
@@ -1456,10 +1478,9 @@ def ragged_step_paged(
 
         def body(carry, _):
             x, li = carry
-            layer = layer_slice(stacked, li)
             with jax.named_scope("fused_layer"):
                 x, k1, v1 = layer_fn(
-                    x, layer, cache["k"], cache["v"], li, row_slot,
+                    x, stacked, cache["k"], cache["v"], li, row_slot,
                     row_start, row_len, row_off, block_tables, sin1, cos1)
             return (x, li + 1), (k1, v1)
     elif lora is None:

@@ -44,7 +44,13 @@ Kernel shape (mirrors ops/paged_attention.py's idioms):
 (ops/fused_decode.py) over the ragged batch: the same phase-indexed
 1-D grid (qkv tiles | attention cells | o-proj | MLP), with the
 attention phase iterating (row, page) cells instead of (slot, page) —
-so the fused path serves ragged batches too.
+so the fused path serves ragged batches too.  It takes the STACKED
+layer tree and a layer index: the weights reach the kernel the way the
+KV pools do, whole, and each BlockSpec squeezes the layer axis and
+picks the layer from the scalar-prefetched index.  XLA cannot fuse a
+slice into a Pallas call's operand, so a slice taken in front of it is
+a copy of the layer's weights, every layer of every step (16.6 ms of a
+46 ms step at Mistral-7B int8 before PR 25; PERF.md).
 
 Interpret-mode (CPU) numerics are tier-1 tested against the unfused
 paged reference for fp32 / int8-weight / int8-KV
@@ -57,7 +63,7 @@ ops/fused_decode.py.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +73,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import platform
+from ray_tpu.ops.fused_decode import (
+    _assemble_gateup,
+    _assemble_qkv,
+    _pick_tile,
+    _qdict,
+)
 from ray_tpu.ops.paged_attention import NEG_INF
 
 
@@ -841,9 +853,57 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
             ).astype(xo_ref.dtype)
 
 
+def layer_slice(tree, li):
+    """One layer's leaves of a stacked ``[L, ...]`` tree, under the
+    scope ``weight_slice``.  XLA fuses such a slice into an einsum that
+    reads it; in front of a Pallas call it is a copy of the leaf."""
+    with jax.named_scope("weight_slice"):
+        return jax.tree.map(
+            lambda w: lax.dynamic_index_in_dim(w, li, 0, keepdims=False),
+            tree)
+
+
+def weight_routes(layers) -> Dict[str, List[str]]:
+    """How ``fused_ragged_layer`` reads each operand of the stacked
+    layer tree ``layers``: ``in_place`` (the stored ``[L, ...]`` leaf
+    goes to the kernel whole and its index maps pick the layer) or
+    ``sliced`` (XLA takes the layer's slice first, a copy).  It follows
+    from the tree alone: an operand that is one stored leaf is read in
+    place; one that has to be built from several leaves (separate
+    ``wq/wk/wv``, ``w_gate/w_up``) is built from that layer's slices,
+    because concatenating the stacks would copy the model every step.
+    The norm vectors, a few kilobytes, are always sliced."""
+    routes: Dict[str, List[str]] = {"in_place": ["w_down", "wo"],
+                                    "sliced": ["ln_attn", "ln_mlp"]}
+    routes["in_place" if "wqkv" in layers["attn"]
+           else "sliced"].append("wqkv")
+    routes["in_place" if "w_gateup" in layers["mlp"]
+           else "sliced"].append("w_gateup")
+    return {k: sorted(v) for k, v in routes.items()}
+
+
+def _stored(leaf, rows: int):
+    """Kernel operands of one stored stacked leaf, as views: the matrix
+    ``[L, rows, N]`` and its per-output-channel scale ``[L, 1, N]``
+    (``[1, 1, N]`` ones for a plain leaf)."""
+    if _qdict(leaf):
+        L = leaf["q"].shape[0]
+        return (leaf["q"].reshape(L, rows, -1),
+                leaf["scale"].reshape(L, 1, -1).astype(jnp.float32))
+    w = leaf.reshape(leaf.shape[0], rows, -1)
+    return w, jnp.ones((1, 1, w.shape[2]), jnp.float32)
+
+
+def _built(pair):
+    """The same, of a matrix and scale assembled from one layer's
+    slices: a leading axis of 1."""
+    w, s = pair
+    return w[None], s[None]
+
+
 def fused_ragged_layer(
     x: jax.Array,            # [T, D] residual stream of the flat batch
-    layer,
+    layers,                  # the stacked [L, ...] layer tree
     k_pools: jax.Array,
     v_pools: jax.Array,
     layer_idx: jax.Array,
@@ -867,15 +927,17 @@ def fused_ragged_layer(
     batch: one pallas_call runs RMSNorm -> qkv -> RoPE -> ragged paged
     attention (pool pages + intra-row self phase) -> o-proj -> MLP for
     every packed token.  Pools read-only; fresh k/v rows ([T, KVH*hd])
-    ride out for the post-scan ragged append."""
-    from ray_tpu.ops.fused_decode import (
-        _assemble_gateup,
-        _assemble_qkv,
-        _pick_tile,
-        _qdict,
-        _weight_pair,
-    )
+    ride out for the post-scan ragged append.
 
+    ``layers`` is the whole stacked tree and ``layer_idx`` the layer to
+    run.  The weights go the way the KV pools go: every operand that is
+    one stored leaf is handed to the kernel stacked, and its BlockSpec
+    squeezes the layer axis and picks the layer with the
+    scalar-prefetched index, so the step reads each weight once, where
+    it lies (``weight_routes`` says which operands those are).  A slice
+    taken in XLA in front of the call would be a copy of the layer:
+    that is kept for what has to be assembled from several leaves, and
+    for the norm vectors."""
     T, D = x.shape
     H, KVH = n_heads, n_kv_heads
     hd = D // H
@@ -883,25 +945,28 @@ def fused_ragged_layer(
     assert KVH_p == KVH, (KVH_p, KVH)
     maxp = block_tables.shape[1]
     R = row_slot.shape[0]
-    M = (layer["mlp"]["w_down"]["q"].shape[0] if _qdict(
-        layer["mlp"]["w_down"]) else layer["mlp"]["w_down"].shape[0])
+    attn, mlp = layers["attn"], layers["mlp"]
+    M = (mlp["w_down"]["q"] if _qdict(mlp["w_down"])
+         else mlp["w_down"]).shape[1]
     qpg = H // KVH
     quantized = k_scales is not None
     dt = x.dtype
     Cw = (H + 2 * KVH) * hd
+    ly_s = jnp.asarray(layer_idx, jnp.int32)
 
-    wqkv, sqkv = _assemble_qkv(layer["attn"], H, KVH, hd, dt)
-    wg, sg = _assemble_gateup(layer["mlp"], dt)
-    wo_leaf = layer["attn"]["wo"]
-    if _qdict(wo_leaf):
-        wo = wo_leaf["q"].reshape(H * hd, D)
-        so = wo_leaf["scale"].reshape(1, D).astype(jnp.float32)
-    else:
-        wo = wo_leaf.reshape(H * hd, D)
-        so = jnp.ones((1, D), jnp.float32)
-    wd, sd = _weight_pair(layer["mlp"]["w_down"])
-    ln_a = layer["ln_attn"].reshape(1, D).astype(jnp.float32)
-    ln_m = layer["ln_mlp"].reshape(1, D).astype(jnp.float32)
+    in_place = weight_routes(layers)["in_place"]
+    wqkv, sqkv = (_stored(attn["wqkv"], D) if "wqkv" in in_place
+                  else _built(_assemble_qkv(layer_slice(
+                      {k: attn[k] for k in ("wq", "wk", "wv")}, ly_s),
+                      H, KVH, hd, dt)))
+    wg, sg = (_stored(mlp["w_gateup"], D) if "w_gateup" in in_place
+              else _built(_assemble_gateup(layer_slice(
+                  {k: mlp[k] for k in ("w_gate", "w_up")}, ly_s), dt)))
+    # wo contracts over (heads, head_dim): fold both into rows.
+    wo, so = _stored(attn["wo"], H * hd)
+    wd, sd = _stored(mlp["w_down"], M)
+    ln_a, ln_m = (v.reshape(1, D).astype(jnp.float32) for v in layer_slice(
+        (layers["ln_attn"], layers["ln_mlp"]), ly_s))
 
     T_p = _round8(T)
     if T_p != T:
@@ -937,6 +1002,22 @@ def fused_ragged_layer(
         pid = jnp.minimum(bt[s, pe], Pt - 1)
         return (ly[0], 0, jnp.where(len_p[r] > 0, pid, Pt - 1), 0, 0)
 
+    def wspec(operand, block, at):
+        """Block of a ``[L or 1, rows, cols]`` operand: the leading axis
+        is squeezed, and picks the layer (scalar prefetch 5) unless the
+        operand holds one layer only; ``at(t)`` is the block's (row,
+        column)."""
+        stacked = operand.shape[0] > 1
+        return pl.BlockSpec(
+            (None,) + block,
+            lambda t, *pf: (pf[5][0] if stacked else 0,) + at(t))
+
+    def gate_at(t):
+        return (0, clip(t - S3, Tm))
+
+    def up_at(t):
+        return (0, Tm + clip(t - S3, Tm))
+
     in_specs = [
         pl.BlockSpec((T_p, D), const2),                        # x (norm)
         pl.BlockSpec((T_p, to),
@@ -945,25 +1026,18 @@ def fused_ragged_layer(
         pl.BlockSpec((1, D), const2),                          # ln_mlp
         pl.BlockSpec((T_p, hd // 2), const2),                  # sin
         pl.BlockSpec((T_p, hd // 2), const2),                  # cos
-        pl.BlockSpec((D, tq), lambda t, *pf: (0, clip(t, Tq))),
-        pl.BlockSpec((1, tq), lambda t, *pf: (0, clip(t, Tq))),
+        wspec(wqkv, (D, tq), lambda t: (0, clip(t, Tq))),
+        wspec(sqkv, (1, tq), lambda t: (0, clip(t, Tq))),
         pl.BlockSpec((1, KVH, 1, page, hd), pool_map),         # k pages
         pl.BlockSpec((1, KVH, 1, page, hd), pool_map),         # v pages
-        pl.BlockSpec((H * hd, to),
-                     lambda t, *pf: (0, clip(t - S2, To))),    # wo
-        pl.BlockSpec((1, to),
-                     lambda t, *pf: (0, clip(t - S2, To))),    # so
-        pl.BlockSpec((D, tm),
-                     lambda t, *pf: (0, clip(t - S3, Tm))),    # w gate
-        pl.BlockSpec((D, tm),
-                     lambda t, *pf: (0, M // tm + clip(t - S3, Tm))),
-        pl.BlockSpec((1, tm),
-                     lambda t, *pf: (0, clip(t - S3, Tm))),    # s gate
-        pl.BlockSpec((1, tm),
-                     lambda t, *pf: (0, M // tm + clip(t - S3, Tm))),
-        pl.BlockSpec((tm, D),
-                     lambda t, *pf: (clip(t - S3, Tm), 0)),    # w_down
-        pl.BlockSpec((1, D), const2),                          # sd
+        wspec(wo, (H * hd, to), lambda t: (0, clip(t - S2, To))),
+        wspec(so, (1, to), lambda t: (0, clip(t - S2, To))),
+        wspec(wg, (D, tm), gate_at),                           # w gate
+        wspec(wg, (D, tm), up_at),                             # w up
+        wspec(sg, (1, tm), gate_at),                           # s gate
+        wspec(sg, (1, tm), up_at),                             # s up
+        wspec(wd, (tm, D), lambda t: (clip(t - S3, Tm), 0)),   # w_down
+        wspec(sd, (1, D), lambda t: (0, 0)),                   # sd
     ]
     out_specs = [
         pl.BlockSpec((T_p, D), const2),
@@ -981,12 +1055,12 @@ def fused_ragged_layer(
         pltpu.VMEM((To, T_p, to), jnp.float32),            # h_s
         pltpu.VMEM((T_p, D), jnp.float32),                 # y_s
     ]
-    ly_s = jnp.asarray(layer_idx, jnp.int32)
     prefetch = [row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
                 row_len.astype(jnp.int32), row_off.astype(jnp.int32),
                 block_tables.astype(jnp.int32), ly_s.reshape(1)]
     if quantized:
-        prefetch += [k_scales[ly_s, :, :, 0], v_scales[ly_s, :, :, 0]]
+        with jax.named_scope("weight_slice"):
+            prefetch += [k_scales[ly_s, :, :, 0], v_scales[ly_s, :, :, 0]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(S4,),

@@ -499,6 +499,12 @@ class PagedEngineAdapter:
         Callable[..., Tuple[jax.Array, jax.Array, Any]]] = None
     ragged_step_lora_verify: Optional[
         Callable[..., Tuple[jax.Array, jax.Array, Any]]] = None
+    # weight_routes(params) -> {"in_place": [...], "sliced": [...]} or
+    # None: which weight operands the ragged step's layer kernel reads
+    # where they are stored and which are copied out of the stack for
+    # every layer of every step.  The engine says it once at start-up.
+    weight_routes: Optional[
+        Callable[[Any], Optional[Dict[str, List[str]]]]] = None
 
 
 def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
@@ -585,6 +591,8 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
             llama.decode_collective_bytes(cfg, mesh, rows),
         collective_probes=lambda mesh:
             llama.serving_collective_probes(cfg, mesh),
+        weight_routes=lambda params:
+            llama.ragged_weight_routes(params, cfg),
     )
 
 
@@ -1398,6 +1406,7 @@ class LLMEngine:
         # (T, R) = (token_budget, max_slots) → a single compile serves
         # every mix.
         self._ragged = bool(config.ragged_batching)
+        self._weight_routes = None
         if self._ragged:
             if not self._paged or adapter.ragged_step is None:
                 raise ValueError(
@@ -1436,6 +1445,18 @@ class LLMEngine:
                 return cache, sampled, cur
 
             self._ragged_step_fn = ragged_step_fn
+            self._weight_routes = (adapter.weight_routes(params)
+                                   if adapter.weight_routes else None)
+            if self._weight_routes is not None:
+                from ray_tpu.util import flight_recorder
+                flight_recorder.record("ragged_weight_routes",
+                                       engine=self._engine_id,
+                                       **self._weight_routes)
+                log.info(
+                    "serve.ragged layer kernel reads in place: %s; "
+                    "sliced (copied) per layer and step: %s",
+                    ", ".join(self._weight_routes["in_place"]),
+                    ", ".join(self._weight_routes["sliced"]))
 
             # Multi-tenant LoRA multiplexing: the engine owns the paged
             # adapter pool and a LoRA variant of the ragged program
@@ -1898,6 +1919,8 @@ class LLMEngine:
             out["kv_migration"] = dict(self._mig_counts)
         if self._adapters is not None:
             out["adapters"] = self._adapters.stats()
+        if self._weight_routes is not None:
+            out["weight_routes"] = self._weight_routes
         if self._spec_on:
             out["spec"] = {
                 "rounds": self._spec_rounds,

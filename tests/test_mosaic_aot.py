@@ -18,7 +18,9 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import importlib.util
+import json
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -409,3 +411,66 @@ def test_fused_ragged_step(bench, v5e, name, kv_int8):
              llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c),
              params, toks, toks, rows, rows, rows, rows, bt, cache,
              donate_argnums=(8,))
+
+
+# -- the benchmark's chat cell: weights read where they are stored ----------
+
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = \(?(\w+)\[([\d,]*)\][^ ]* "
+    r"(dynamic-slice|copy|concatenate)\(", re.M)
+
+
+def weight_sized_int8_copies(hlo_text: str, min_bytes: int = 4 * 2**20):
+    """(opcode, shape) of every dynamic-slice, copy and concatenate in a
+    compiled module's text, in any computation, whose result is int8 and
+    at least ``min_bytes`` large: a layer's weight made a second time."""
+    return [(op, dims) for dt, dims, op in _HLO_RESULT.findall(hlo_text)
+            if dt == "s8"
+            and np.prod([int(d) for d in dims.split(",") if d]) >= min_bytes]
+
+
+def test_chat_cell_step_copies_no_weight(v5e):
+    """``mistral7b_w8-chat`` as the benchmark runs it: Mistral-7B widths,
+    the fused int8 artifact, 16 slots of 40 pages, a token budget of 80,
+    int8 KV pages.  The fused layer kernel takes the stacked weights
+    whole, so the compiled step holds no operation that writes a layer's
+    int8 weight again, inside the layer loop or hoisted out of it (a
+    reshape of a stacked leaf that stopped being a bitcast would be).
+    Before PR 25 it held four a layer, 16.6 ms of a 46 ms step."""
+    from benchmarks.runners.common import model_config
+
+    config = json.loads(
+        (REPO / "benchmarks/configs/mistral7b_w8.json").read_text())
+    cfg, eng = model_config(config), config["engine"]
+    assert cfg.fused_decode and cfg.kv_int8
+    slots, page = eng["max_slots"], eng["page_size"]
+    maxp = eng["max_seq_len"] // page
+    T = slots + page
+    assert (slots, maxp, T) == (16, 40, 80)
+    mesh = _one(v5e)
+    params = _on(mesh, jax.eval_shape(
+        lambda: quant.fuse_for_decode(
+            quant.init_quantized_llama(jax.random.key(0), cfg), cfg)))
+    assert llama.ragged_weight_routes(params, cfg)["sliced"] == [
+        "ln_attn", "ln_mlp"]
+    cache = _on(mesh, jax.eval_shape(
+        lambda: llama.init_paged_cache(cfg, eng["num_pages"], page)))
+    toks, rows, bt = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, maxp, dtype=jnp.int32)))
+    compiled = _compile(
+        lambda p, t, pos, rs, r0, rl, ro, b, c:
+        llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+        params, toks, toks, rows, rows, rows, rows, bt, cache,
+        donate_argnums=(8,))
+    text = compiled.as_text()
+    assert "fused_ragged_layer" in text
+    assert weight_sized_int8_copies(text) == []
+    # the reader does find what it looks for: a slice of the stack in
+    # front of the kernel, as the step had it before
+    before = ("  %dynamic_slice.123 = s8[1,4096,28672]{2,1,0:T(8,128)(4,1)}"
+              " dynamic-slice(%param_0.1, %p, %c, %c)\n"
+              "  ROOT %copy.3 = s8[32,4096,4096]{2,1,0} copy(%bitcast.30)\n"
+              "  %copy.52 = bf16[80,4096]{1,0} copy(%get-tuple-element.7)\n")
+    assert weight_sized_int8_copies(before) == [
+        ("dynamic-slice", "1,4096,28672"), ("copy", "32,4096,4096")]

@@ -222,3 +222,43 @@ def test_ragged_streaming_and_temperature(params):
         assert len(hot) == 16
     finally:
         eng.shutdown()
+
+
+@pytest.mark.parametrize("artifact,sliced", [
+    ("fused_int8", ["ln_attn", "ln_mlp"]),
+    ("separate", ["ln_attn", "ln_mlp", "w_gateup", "wqkv"]),
+    ("unfused_step", None)])
+def test_engine_says_how_the_layer_kernel_reads_its_weights(
+        params, artifact, sliced):
+    """An artifact that makes the fused layer kernel fall back to
+    copying a layer's weights out of the stack every step shows in
+    ``stats()`` and in the flight recorder, without a trace."""
+    import dataclasses
+
+    from ray_tpu.models import quant
+    from ray_tpu.util import flight_recorder
+
+    cfg = dataclasses.replace(CFG, fused_decode=artifact != "unfused_step")
+    p = params
+    if artifact == "fused_int8":
+        p = quant.fuse_for_decode(
+            quant.init_quantized_llama(jax.random.key(0), cfg), cfg)
+    flight_recorder.clear()
+    eng = LLMEngine(p, llama_paged_adapter(cfg), EngineConfig(
+        max_slots=4, max_seq_len=128, min_prefill_bucket=16, page_size=16,
+        ragged_batching=True, token_budget=36))
+    try:
+        routes = eng.stats().get("weight_routes")
+        events = [e for e in flight_recorder.snapshot()["driver"]
+                  if e["kind"] == "ragged_weight_routes"]
+        if sliced is None:
+            assert routes is None and events == []
+            return
+        assert routes["sliced"] == sliced
+        assert {"wo", "w_down"} <= set(routes["in_place"])
+        assert len(events) == 1 and events[0]["sliced"] == sliced
+        assert events[0]["in_place"] == routes["in_place"]
+        assert len(eng.submit([1, 2, 3], max_new_tokens=3,
+                              temperature=0.0).result(timeout_s=120)) == 3
+    finally:
+        eng.shutdown()

@@ -17,6 +17,8 @@ contract (XLA keeps excess precision under jit, so bf16 logit ties may
 round differently between fused programs — both roundings are valid).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -329,3 +331,152 @@ def test_ragged_step_matches_pipeline_int8_weights():
                       decode_steps=2)
     assert got[0] == want[0][:len(got[0])]
     assert got[1] == want[1][:len(got[1])]
+
+
+# ---------------------------------------------------------------------------
+# the fused layer reads the stacked weights in place
+# ---------------------------------------------------------------------------
+
+_TOY = dict(vocab_size=211, dim=128, n_layers=3, n_heads=2, n_kv_heads=1,
+            mlp_dim=256, max_seq_len=256, dtype=jnp.float32,
+            param_dtype=jnp.float32)
+IN_PLACE = {"in_place": ["w_down", "w_gateup", "wo", "wqkv"],
+            "sliced": ["ln_attn", "ln_mlp"]}
+ASSEMBLED = {"in_place": ["w_down", "wo"],
+             "sliced": ["ln_attn", "ln_mlp", "w_gateup", "wqkv"]}
+
+
+def _fused_artifact(cfg, key=0):
+    """The serving artifact: int8 weights, q/k/v and gate/up fused.
+    Random norm vectors, so that a layer read from the wrong place shows
+    in every operand."""
+    from ray_tpu.models import quant
+
+    params = quant.fuse_for_decode(
+        quant.init_quantized_llama(jax.random.PRNGKey(key), cfg), cfg)
+    for i, name in enumerate(("ln_attn", "ln_mlp")):
+        params["layers"][name] = 1.0 + 0.1 * jax.random.normal(
+            jax.random.PRNGKey(10 + i), params["layers"][name].shape)
+    return params
+
+
+def _fused_layer_call(layers, kp, vp, ks, vs, li, x, sin, cos):
+    slot, start, nlen, off = _mixed_rows()
+    bt = jnp.asarray(np.arange(16, dtype=np.int32).reshape(4, 4))
+    return rpa.fused_ragged_layer(
+        x, layers, kp, vp, jnp.int32(li), jnp.asarray(slot),
+        jnp.asarray(start), jnp.asarray(nlen), jnp.asarray(off), bt,
+        sin, cos, eps=1e-5, n_heads=2, n_kv_heads=1, k_scales=ks,
+        v_scales=vs, max_row_tokens=16)
+
+
+@pytest.mark.parametrize("li", [0, 1, 2])
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_fused_layer_reads_the_stack_in_place(kv_int8, li):
+    """Fed the whole stack and a layer index, the kernel gives the bits
+    it gives for a one-layer stack of that layer at index 0: its index
+    maps picked that layer's weights, scales and pages, and nothing was
+    rounded on the way."""
+    cfg = llama.LlamaConfig(**_TOY, kv_int8=kv_int8)
+    layers = _fused_artifact(cfg)["layers"]
+    assert rpa.weight_routes(layers) == IN_PLACE
+    rng = np.random.default_rng(5)
+    L, T, hd = cfg.n_layers, 48, cfg.head_dim
+    kp, vp, ks, vs = _pools(rng, L, 1, 17, 16, hd, int8=kv_int8)
+    x = jnp.asarray(rng.standard_normal((T, cfg.dim)), jnp.float32)
+    sin, cos = llama.rope_table(cfg, jnp.arange(T)[None])
+    sin, cos = sin[0], cos[0]
+
+    def one_layer(i):
+        # None (no page scales) is an empty subtree and stays None
+        return jax.tree.map(lambda a: a[i:i + 1], (layers, kp, vp, ks, vs))
+
+    got = _fused_layer_call(layers, kp, vp, ks, vs, li, x, sin, cos)
+    want = _fused_layer_call(*one_layer(li), 0, x, sin, cos)
+    other = _fused_layer_call(*one_layer((li + 1) % L), 0, x, sin, cos)
+    for g, w, o in zip(got, want, other):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert not np.array_equal(np.asarray(g), np.asarray(o))
+
+
+def _big_int8_slices(jaxpr, min_bytes):
+    """dynamic_slice equations, anywhere in ``jaxpr``, whose result is
+    int8 and at least ``min_bytes`` large."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _big_int8_slices(sub, min_bytes)
+        if eqn.primitive.name == "dynamic_slice":
+            aval = eqn.outvars[0].aval
+            if aval.dtype == jnp.int8 and aval.size >= min_bytes:
+                found.append(aval)
+    return found
+
+
+@pytest.mark.parametrize("tree,routes,sliced_int8", [
+    ("fused_int8", IN_PLACE, 0),
+    ("separate_int8", ASSEMBLED, 5),     # wq wk wv, w_gate w_up
+    ("separate_plain", ASSEMBLED, 0)])
+def test_weight_routes_follow_the_tree(tree, routes, sliced_int8):
+    """In place or sliced is read off the parameter tree: the fused
+    artifact's step takes no slice of an int8 weight at all; a tree with
+    separate projections slices exactly those and assembles them."""
+    from ray_tpu.models import quant
+
+    cfg = llama.LlamaConfig(**_TOY, kv_int8=True, fused_decode=True)
+    if tree == "fused_int8":
+        params = _fused_artifact(cfg)
+    elif tree == "separate_int8":
+        params = quant.init_quantized_llama(jax.random.PRNGKey(0), cfg)
+    else:
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    assert rpa.weight_routes(params["layers"]) == routes
+    assert llama.ragged_weight_routes(params, cfg) == routes
+    assert llama.ragged_weight_routes(
+        params, dataclasses.replace(cfg, fused_decode=False)) is None
+    slot, start, nlen, off = (jnp.asarray(a) for a in _mixed_rows())
+    T = 48
+    jaxpr = jax.make_jaxpr(
+        lambda p, c: llama.ragged_step_paged(
+            p, jnp.ones(T, jnp.int32), jnp.arange(T), slot, start, nlen,
+            off, jnp.zeros((4, 4), jnp.int32), cfg, c,
+            max_row_tokens=16))(
+                params, llama.init_paged_cache(cfg, 16, 16))
+    smallest_weight = cfg.dim * cfg.n_kv_heads * cfg.head_dim
+    assert len(_big_int8_slices(jaxpr.jaxpr, smallest_weight)) == sliced_int8
+
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_fused_artifact_step_matches_unfused(kv_int8):
+    """The serving artifact through the fused step (weights read in
+    place) against the same artifact through the unfused step, which
+    dequantizes each layer's slice: same logits to float32 rounding of
+    a different order of sums, and pages written alike."""
+    cfg = llama.LlamaConfig(**_TOY, kv_int8=kv_int8)
+    params = _fused_artifact(cfg)
+    rng = np.random.default_rng(2)
+    rows = [dict(slot=0, start=0, tokens=list(rng.integers(1, 211, 13))),
+            dict(slot=1, start=0, tokens=list(rng.integers(1, 211, 21)))]
+    bt = np.full((4, 4), 16, np.int32)
+    bt[0, :2], bt[1, :3] = [0, 1], [2, 3, 4]
+
+    def run(fused):
+        c = dataclasses.replace(cfg, fused_decode=fused)
+        cache = llama.init_paged_cache(c, 16, 16)
+        out = []
+        step_rows = rows
+        for _ in range(2):
+            (ht, _dm, _ts, pos, rs, r0, rl, ro) = rpa.pack_ragged_batch(
+                step_rows, 48, 4)
+            lg, cache = llama.ragged_step_paged(
+                params, jnp.asarray(ht), jnp.asarray(pos), jnp.asarray(rs),
+                jnp.asarray(r0), jnp.asarray(rl), jnp.asarray(ro),
+                jnp.asarray(bt), c, cache, max_row_tokens=32)
+            out.append(np.asarray(lg[:2]))
+            step_rows = [dict(slot=s, start=len(r["tokens"]), tokens=[7 + s])
+                         for s, r in enumerate(rows)]
+        return out
+
+    for got, want in zip(run(True), run(False)):
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-4 * np.abs(
+            want).max())
