@@ -757,6 +757,88 @@ def phase_serve(platform: str, *, cfg=None, slots: int = 48,
             "cache_misses": rep["cache_misses"]}
 
 
+def phase_serve_jamba(platform: str, *, config=None, n_requests: int = 8,
+                      prompt_len: int = 300, new_tokens: int = 16,
+                      ready_timeout_s: float = 900.0) -> dict:
+    """The serving phase's second case: a model with per-slot recurrent
+    state.  The benchmark's own replica class for Jamba
+    (benchmarks/runners/serve_jamba.py) at three layers (Mamba,
+    attention, Mamba) of the published widths: it checks the ragged step
+    against the plain reference before the engine takes the memory,
+    serves ``n_requests`` prompts of several chunks through
+    ``serve.run``, holds the tokens it served to the reference, and
+    shows that the check refuses an SSM state kept in bfloat16.
+    ``config`` defaults to the benchmark's file."""
+    import ray_tpu
+    from benchmarks.runners import serve_jamba
+    from ray_tpu import serve
+
+    if config is None:
+        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "benchmarks", "configs",
+                               "jamba2_3b.json")) as f:
+            config = json.load(f)
+    config = dict(config, **serve_jamba.CHECK_HF,
+                  engine=dict(config["engine"], max_slots=8,
+                              max_seq_len=512, num_pages=64,
+                              prefill_chunk=128))
+    ray_tpu.init(ignore_reinit_error=True)
+    try:
+        chips = int(ray_tpu.cluster_resources().get("TPU", 0))
+        app = serve.deployment(
+            ray_actor_options=({"num_tpus": chips} if platform == "tpu"
+                               else {}),
+            max_ongoing_requests=2 * n_requests,
+        )(serve_jamba.server_class()).bind({"config": config, "seed": 0})
+        t0 = time.perf_counter()
+        handle = serve.run(app, name="chip_smoke_jamba", route_prefix=None,
+                           timeout_s=ready_timeout_s)
+        rep = handle.device_report.remote().result(timeout_s=60)
+        check = rep["check"]
+        log(f"  jamba replica ready after {time.perf_counter() - t0:.1f}s "
+            f"on {rep['platform']}; reference check: "
+            + " ".join(f"{k}={check[k]['rel_err_prefill']:.2e}/"
+                       f"{check[k]['rel_err_decode']:.2e}"
+                       for k in serve_jamba.TOLERANCES))
+        if rep["platform"] != platform:
+            raise AssertionError(
+                f"replica computes on {rep['platform']!r}, not "
+                f"{platform!r}")
+        if not check["ok"]:
+            raise AssertionError(f"jamba reference check failed: {check}")
+        vocab = config["vocab_size"]
+        prompts = [[(7 * i + 3 * j) % (vocab - 1) + 1
+                    for j in range(prompt_len)] for i in range(n_requests)]
+        pending = [handle.remote({"tokens": p, "max_new_tokens": new_tokens,
+                                  "temperature": 0.0}) for p in prompts]
+        outs = [r.result(timeout_s=ready_timeout_s)["tokens"]
+                for r in pending]
+        check_answers(outs, new_tokens, vocab)
+        state = handle.counters.remote().result(timeout_s=60)["state_cache"]
+        log(f"  {len(outs)} jamba requests answered ({prompt_len} prompt "
+            f"+ {new_tokens} new tokens each); state cache {state}")
+        if state["resets"] < n_requests or state["live"] != 0:
+            raise AssertionError(f"state cache accounting: {state}")
+        served = handle.served_check.remote().result(
+            timeout_s=ready_timeout_s)
+        log(f"  served tokens against the reference: {served}")
+        if not served["ok"] or served["tokens"] < new_tokens:
+            raise AssertionError(f"jamba served-token check: {served}")
+        control = handle.state_control.remote().result(
+            timeout_s=ready_timeout_s)["ssm_state"]
+        log(f"  control, SSM state rounded to bfloat16: {control} against "
+            f"{check['ssm_state']['rel_err']}")
+        if control["ok"]:
+            raise AssertionError(
+                f"the check accepts a bfloat16 SSM state: {control}")
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    return {"platform": rep["platform"], "reference_check": check,
+            "state_cache": state, "served_check": served,
+            "state_control": control}
+
+
 # ---------------------------------------------------------------------------
 # phase: engine_legacy
 # ---------------------------------------------------------------------------
@@ -901,6 +983,8 @@ def run_child(phase: str, expect_loss0) -> int:
         jax.config.update("jax_platforms", "tpu")
         fn = phase_serve if phase == "serve" else phase_owners
         report.update(fn("tpu"))
+        if phase == "serve":
+            report["jamba"] = phase_serve_jamba("tpu")
     else:
         clock = CompileClock()
         try:

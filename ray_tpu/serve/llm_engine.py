@@ -220,6 +220,21 @@ def _telemetry():
                 "Cache pages evicted (refcount-0 LRU) under admission "
                 "pressure.",
             ),
+            "state_cache_bytes": metrics.Gauge(
+                "raytpu_serve_state_cache_bytes",
+                "Bytes of per-slot recurrent state (convolution tails "
+                "and SSM states of state-space layers) the engine's "
+                "cache holds beside its KV pages: slots x the "
+                "adapter's state_bytes_per_slot, 0 for a model with "
+                "none.",
+            ),
+            "state_resets": metrics.Counter(
+                "raytpu_serve_state_resets_total",
+                "Rows dispatched with row_start == 0 on an engine whose "
+                "cache holds recurrent state: each one resets its "
+                "slot's state to zero on the device (a new request, or "
+                "a preempted one prefilling again from token 0).",
+            ),
             "collective_bytes": metrics.Counter(
                 "raytpu_serve_collective_bytes_total",
                 "Bytes one shard puts on the wire for decode-step "
@@ -505,6 +520,16 @@ class PagedEngineAdapter:
     # every layer of every step.  The engine says it once at start-up.
     weight_routes: Optional[
         Callable[[Any], Optional[Dict[str, List[str]]]]] = None
+    # Bytes of recurrent state one sequence holds per slot, whatever its
+    # length (state-space layers: convolution tails, SSM states); 0 = the
+    # cache is KV pages only.  Non-zero, the engine calls
+    # init_cache(num_pages, page_size, max_slots) and the cache tree
+    # holds that state indexed by slot beside the page pools; the ragged
+    # step resets a slot's state where a row has row_start == 0.  State
+    # that is a function of every token so far cannot be shared by
+    # prefix, rewound, or shipped as pages, so the engine refuses the
+    # prefix cache, speculative decoding and KV migration for it.
+    state_bytes_per_slot: int = 0
 
 
 def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
@@ -593,6 +618,31 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
             llama.serving_collective_probes(cfg, mesh),
         weight_routes=lambda params:
             llama.ragged_weight_routes(params, cfg),
+    )
+
+
+def jamba_paged_adapter(cfg) -> PagedEngineAdapter:
+    """Jamba (models/jamba.py): Mamba-1 layers with per-slot recurrent
+    state beside paged KV for its few attention layers.  Served on the
+    ragged step only: the separate prefill and decode programs have no
+    recurrent-state form, and ragged batching replaces them."""
+    from ray_tpu.models import jamba
+
+    def ragged_only(*_a, **_k):
+        raise NotImplementedError(
+            "jamba_paged_adapter serves through the ragged step only: "
+            "set EngineConfig.ragged_batching=True")
+
+    return PagedEngineAdapter(
+        init_cache=lambda num_pages, page, max_slots: jamba.init_cache(
+            cfg, num_pages, page, max_slots),
+        prefill_slot=ragged_only,
+        decode_slots=ragged_only,
+        ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
+        row_len, row_off, bt, cache:
+            jamba.ragged_step(params, tokens, tok_pos, row_slot,
+                              row_start, row_len, row_off, bt, cfg, cache),
+        state_bytes_per_slot=cfg.state_bytes_per_slot(),
     )
 
 
@@ -785,8 +835,16 @@ class LLMServer:
             draft_adapter = make_adapter(draft_model_cfg
                                          if draft_model_cfg is not None
                                          else model_cfg)
+        adapter = make_adapter(model_cfg)
+        if (self._disagg is not None and self._disagg.role != "unified"
+                and getattr(adapter, "state_bytes_per_slot", 0)):
+            raise ValueError(
+                f"disaggregated serving role {self._disagg.role!r} hands "
+                "a request over by migrating its KV pages; this model's "
+                "cache also holds per-slot recurrent state that no page "
+                "carries")
         self.engine = LLMEngine(
-            param_loader(), make_adapter(model_cfg), engine_cfg,
+            param_loader(), adapter, engine_cfg,
             mesh=mesh, draft_params=draft_params,
             draft_adapter=draft_adapter,
         )
@@ -1154,6 +1212,28 @@ class LLMEngine:
                              "adapter (PagedEngineAdapter)")
         if mesh is not None and adapter.shard_params is not None:
             self._params = params = adapter.shard_params(params, mesh)
+        # Per-slot recurrent state in the cache (see PagedEngineAdapter.
+        # state_bytes_per_slot): what the engine may not do with it.
+        self._state_bytes_per_slot = int(
+            getattr(adapter, "state_bytes_per_slot", 0))
+        self._state_resets = 0
+        if self._state_bytes_per_slot:
+            why = ("the adapter's cache holds per-slot recurrent state "
+                   f"({self._state_bytes_per_slot} bytes a slot), which "
+                   "is a function of every token so far: ")
+            if config.prefix_cache:
+                raise ValueError(
+                    why + "a prefix-cache hit would start a row at "
+                    "depth d with no state for d — set "
+                    "EngineConfig.prefix_cache=False")
+            if config.spec_decode:
+                raise ValueError(
+                    why + "a rejected draft cannot be rewound out of a "
+                    "recurrence — set EngineConfig.spec_decode=False")
+            if not config.ragged_batching or mesh is not None:
+                raise ValueError(
+                    why + "only the unsharded ragged step carries it — "
+                    "set EngineConfig.ragged_batching=True, no mesh")
         if self._paged:
             page = config.page_size
             self._maxp = -(-config.max_seq_len // page)
@@ -1168,6 +1248,9 @@ class LLMEngine:
                     partial(adapter.init_cache, self._num_pages, page),
                     out_shardings=adapter.cache_shardings(mesh),
                 )()
+            elif self._state_bytes_per_slot:
+                self._cache = adapter.init_cache(self._num_pages, page,
+                                                 config.max_slots)
             else:
                 self._cache = adapter.init_cache(self._num_pages, page)
             if (isinstance(self._cache, dict)
@@ -1281,6 +1364,8 @@ class LLMEngine:
                 and adapter.collective_probes is not None):
             self._calibrate_collectives(adapter.collective_probes(mesh))
         self._update_page_gauges()
+        self._tm["state_cache_bytes"].set(
+            config.max_slots * self._state_bytes_per_slot)
         # Request-lifecycle ring (util/state.list_requests, dashboard
         # /api/v0/requests, timeline request rows all read it).  The
         # engine holds the only strong ref; the module registry is weak.
@@ -1445,6 +1530,24 @@ class LLMEngine:
                 return cache, sampled, cur
 
             self._ragged_step_fn = ragged_step_fn
+            if self._state_bytes_per_slot:
+                from ray_tpu.util import flight_recorder
+                parts = {k: int(v.size * v.dtype.itemsize)
+                         for k, v in self._cache.items()}
+                flight_recorder.record(
+                    "serve_cache_parts", engine=self._engine_id,
+                    paged_kv_bytes=parts.get("k", 0) + parts.get("v", 0),
+                    recurrent_state_bytes=sum(
+                        v for k, v in parts.items()
+                        if k not in ("k", "v")),
+                    slots=config.max_slots, pages=self._num_pages,
+                    state_bytes_per_slot=self._state_bytes_per_slot)
+                log.info(
+                    "serve.ragged cache: %d KV pages beside recurrent "
+                    "state for %d slots (%d bytes a slot); prefix "
+                    "cache, speculation and KV migration are refused",
+                    self._num_pages, config.max_slots,
+                    self._state_bytes_per_slot)
             self._weight_routes = (adapter.weight_routes(params)
                                    if adapter.weight_routes else None)
             if self._weight_routes is not None:
@@ -1921,6 +2024,15 @@ class LLMEngine:
             out["adapters"] = self._adapters.stats()
         if self._weight_routes is not None:
             out["weight_routes"] = self._weight_routes
+        if self._state_bytes_per_slot:
+            slots = self.config.max_slots
+            out["state_cache"] = {
+                "slots": slots,
+                "bytes_per_slot": self._state_bytes_per_slot,
+                "bytes": slots * self._state_bytes_per_slot,
+                "live": slots - len(self._free_slots),
+                "resets": self._state_resets,
+            }
         if self._spec_on:
             out["spec"] = {
                 "rounds": self._spec_rounds,
@@ -2838,6 +2950,11 @@ class LLMEngine:
                 -(-(r["start"] + len(r["tokens"] or (0,))) // page)
                 for r in rows),
             "grid_cells": R * (self._maxp + 1),
+            # rows that start a sequence (a recurrent-state cache resets
+            # their slot on the device) and the step's longest row (what
+            # a scan over a row's tokens walks)
+            "n_state_reset": sum(1 for r in rows if r["start"] == 0),
+            "scan_len": max(len(r["tokens"] or (0,)) for r in rows),
         }
         return name, fn, args, parts, finishing, counts
 
@@ -2879,6 +2996,9 @@ class LLMEngine:
         if n_spec:
             self._tm["step_tokens"].inc(n_spec,
                                         tags={"phase": "spec_verify"})
+        if self._state_bytes_per_slot and counts["n_state_reset"]:
+            self._state_resets += counts["n_state_reset"]
+            self._tm["state_resets"].inc(counts["n_state_reset"])
         self._count_collective_bytes(n_decode)
         if n_decode:
             self._tm["batch_size"].observe(n_decode)
@@ -3589,6 +3709,13 @@ class LLMEngine:
         only the loop may gather/scatter it — the same ownership rule
         the cancel queue follows).  Re-raises whatever the verb raised
         over there."""
+        if self._state_bytes_per_slot:
+            raise ValueError(
+                "KV migration ships pages only; this engine's cache "
+                "also holds per-slot recurrent state "
+                f"({self._state_bytes_per_slot} bytes a slot) that no "
+                "page carries, so a migrated prefix could not be "
+                "resumed")
         if not self._paged or self._prefix is None:
             raise RuntimeError(
                 "KV migration requires the paged engine with "

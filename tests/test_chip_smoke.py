@@ -92,6 +92,45 @@ def test_serve_phase_replica_is_a_cpu_worker_and_caller_stays_off_jax():
     assert "caller never initialised a JAX backend" in proc.stdout
 
 
+def test_serve_phase_jamba_case_runs_the_runner_end_to_end():
+    """The serving phase's Jamba case at toy widths: the benchmark's
+    replica class for it (benchmarks/runners/serve_jamba.py) checks the
+    ragged step against the plain reference, serves chunked prompts
+    through serve.run, holds the served tokens to the reference and
+    refuses an SSM state kept in bfloat16.  This is the CPU dry run of
+    that runner, which ``--rehearse`` has no preset for."""
+    code = (
+        "import json, chip_smoke\n"
+        "config = json.load(open('benchmarks/configs/jamba2_3b.json'))\n"
+        "config.update(hidden_size=64, intermediate_size=96,"
+        " num_attention_heads=4, head_dim=16, vocab_size=211,"
+        " mamba_dt_rank=8, torch_dtype='float32')\n"
+        "config['engine']['page_size'] = 16\n"
+        "out = chip_smoke.phase_serve_jamba('cpu', config=config,"
+        " n_requests=3, prompt_len=150, new_tokens=3,"
+        " ready_timeout_s=240)\n"
+        "check = out['reference_check']\n"
+        "assert check['ok'] and check['layers'] == 3, check\n"
+        "worst = max(check[k][e] for k in ('chunked', 'beside',"
+        " 'reused_slot') for e in ('rel_err_prefill', 'rel_err_decode'))\n"
+        "assert worst < 1e-5, check\n"
+        "assert out['state_cache']['resets'] == 3, out\n"
+        "served = out['served_check']\n"
+        "assert served['ok'] and served['layers'] == 3, served\n"
+        "assert served['requests'] == 3 and served['tokens'] == 9, served\n"
+        "assert served['rel_short_swapped_median'] > 0.1, served\n"
+        "control = out['state_control']\n"
+        "assert not control['ok'], control\n"
+        "assert min(control['rel_err'].values()) > 1000 * max("
+        "check['ssm_state']['rel_err'].values()), (control, check)\n"
+        "print('JAMBA_OK', worst)\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          env=_clean_env(), capture_output=True, text=True,
+                          timeout=420)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "JAMBA_OK" in proc.stdout
+
+
 def test_chip_smoke_without_a_chip_fails_and_names_the_platform():
     proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
                           env=_clean_env(), capture_output=True, text=True,

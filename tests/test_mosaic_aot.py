@@ -474,3 +474,69 @@ def test_chat_cell_step_copies_no_weight(v5e):
               "  %copy.52 = bf16[80,4096]{1,0} copy(%get-tuple-element.7)\n")
     assert weight_sized_int8_copies(before) == [
         ("dynamic-slice", "1,4096,28672"), ("copy", "32,4096,4096")]
+
+
+# -- Jamba: the selective-scan kernel and the cell's whole step ---------------
+
+def _jamba_cell():
+    from benchmarks.runners import serve_jamba
+
+    config = json.loads(
+        (REPO / "benchmarks/configs/jamba2_3b.json").read_text())
+    eng = config["engine"]
+    T = eng["max_slots"] + max(eng["prefill_chunk"], eng["page_size"])
+    return serve_jamba.model_config(config), eng, T
+
+
+def test_ssm_scan_kernel(v5e):
+    """The kernel at the cell's sizes: 320 packed tokens, 64 rows, the
+    states of 26 layers and 64 slots updated in place."""
+    from ray_tpu.ops.ssm_scan import ssm_scan
+
+    cfg, eng, T = _jamba_cell()
+    mesh = _one(v5e)
+    C, N, R = cfg.d_inner, cfg.d_state, eng["max_slots"]
+    f32 = jnp.float32
+    rows = _sds(R, dtype=jnp.int32)
+    compiled = _compile(
+        ssm_scan, *_on(mesh, (
+            _sds(T, C, dtype=f32), _sds(T, C, dtype=f32),
+            _sds(T, N, dtype=f32), _sds(T, N, dtype=f32),
+            _sds(N, C, dtype=f32), _sds(26, R + 1, N, C, dtype=f32),
+            _sds(dtype=jnp.int32), rows, rows, rows, rows)),
+        donate_argnums=(5,))
+    assert "ssm_scan" in compiled.as_text()
+
+
+def test_jamba_cell_step_updates_the_state_in_place(v5e):
+    """The step program of ``jamba2_3b-chat_short`` at full depth and
+    published widths fits the chip with its cache, and no copy of the
+    SSM states (0.55 GB) is made in it: the scan kernel's alias holds
+    through the layer scans."""
+    from ray_tpu.models import jamba
+
+    cfg, eng, T = _jamba_cell()
+    assert (cfg.n_layers, cfg.d_inner, T) == (28, 5120, 320)
+    mesh = _one(v5e)
+    slots, page = eng["max_slots"], eng["page_size"]
+    params = _on(mesh, jax.eval_shape(
+        lambda: jamba.init_params(jax.random.key(0), cfg)))
+    cache = _on(mesh, jax.eval_shape(
+        lambda: jamba.init_cache(cfg, eng["num_pages"], page, slots)))
+    toks, rows, bt = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, eng["max_seq_len"] // page, dtype=jnp.int32)))
+    compiled = _compile(
+        lambda p, t, pos, rs, r0, rl, ro, b, c:
+        jamba.ragged_step(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+        params, toks, toks, rows, rows, rows, rows, bt, cache,
+        donate_argnums=(8,))
+    text = compiled.as_text()
+    for kernel in ("ssm_scan", "ragged_paged_attention", "ragged_kv_append"):
+        assert kernel in text
+    state = f"f32[26,{slots + 1},16,5120]"
+    assert [ln for ln in text.splitlines()
+            if re.search(r"= \S*" + re.escape(state) + r"\S* copy\(", ln)
+            ] == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**30
