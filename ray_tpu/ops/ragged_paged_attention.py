@@ -44,7 +44,11 @@ Kernel shape (mirrors ops/paged_attention.py's idioms):
 (ops/fused_decode.py) over the ragged batch: the same phase-indexed
 1-D grid (qkv tiles | attention cells | o-proj | MLP), with the
 attention phase iterating (row, page) cells instead of (slot, page) —
-so the fused path serves ragged batches too.  It takes the STACKED
+so the fused path serves ragged batches too.  That phase walks a list
+of the cells that hold the step's rows (``live_page_cells``) and the
+grid ends where the list ends: a cell the rows do not reach is no grid
+step, where it used to cost 0.6 us in every layer (PERF.md, PR 28).
+It takes the STACKED
 layer tree and a layer index: the weights reach the kernel the way the
 KV pools do, whole, and each BlockSpec squeezes the layer axis and
 picks the layer from the scalar-prefetched index.  XLA cannot fuse a
@@ -652,17 +656,14 @@ def ragged_paged_append_quantized(
 
 def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
                          KVH: int, qpg: int, hd: int, page: int,
-                         Pt: int, maxp: int, R: int, M: int, tq: int,
+                         Pt: int, maxp: int, M: int, tq: int,
                          to: int, tm: int, eps: float, scale: float,
                          soft_cap: Optional[float], quantized: bool,
                          dot_dt):
-    n_pre = 8 if quantized else 6
-    if quantized:
-        (slot_r, start_r, len_r, off_r, bt_r, _ly_r,
-         ks_r, vs_r) = refs[:8]
-    else:
-        slot_r, start_r, len_r, off_r, bt_r, _ly_r = refs[:6]
-        ks_r = vs_r = None
+    n_pre = 10 if quantized else 8
+    (slot_r, start_r, len_r, off_r, bt_r, _ly_r, live_r,
+     n_live_r) = refs[:8]
+    ks_r, vs_r = refs[8:10] if quantized else (None, None)
     (x_ref, xt_ref, ln_a_ref, ln_m_ref, sin_ref, cos_ref,
      wqkv_ref, sqkv_ref, kp_ref, vp_ref, wo_ref, so_ref,
      wg_g_ref, wg_u_ref, sg_g_ref, sg_u_ref, wd_ref, sd_ref,
@@ -674,8 +675,11 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
     To = D // to
     Tm = M // tm
     cells = maxp + 1
+    # The attention phase is as long as the step's live-cell list
+    # (``live_page_cells``), so the later phases start where it ends.
+    n_live = n_live_r[0]
     S1 = Tq
-    S2 = S1 + R * cells
+    S2 = S1 + n_live
     S3 = S2 + To
     S4 = S3 + Tm
     t = pl.program_id(0)
@@ -731,7 +735,7 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
 
     # ---- phase 1: ragged attention, one (row, page/self) per cell ----
     in_attn = (t >= S1) & (t < S2)
-    ci = jnp.clip(t - S1, 0, R * cells - 1)
+    ci = live_r[jnp.clip(t - S1, 0, jnp.maximum(n_live - 1, 0))]
     r = ci // cells
     pc = ci % cells
     start = start_r[r]
@@ -827,7 +831,9 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
                 jnp.float32)
         y_s[...] = jnp.zeros_like(y_s)
 
-    @pl.when(t >= S3)
+    # t < S4 always holds under Mosaic, whose grid ends at S4; the
+    # interpreter's grid is the capacity (see ``fused_ragged_layer``).
+    @pl.when((t >= S3) & (t < S4))
     def _mlp_tile():
         hn = xn_s[...].astype(dot_dt)
         g = lax.dot_general(
@@ -901,6 +907,34 @@ def _built(pair):
     return w[None], s[None]
 
 
+def live_page_cells(row_start: jax.Array, row_len: jax.Array, maxp: int,
+                    page: int) -> Tuple[jax.Array, jax.Array]:
+    """The attention cells of ``fused_ragged_layer`` that hold work for
+    these rows: ``(live_ci, n_live)``.  Cell ``r * (maxp + 1) + pc`` is
+    row ``r``'s pool page ``pc``, or its self cell where ``pc == maxp``;
+    it is live where the row has tokens (``row_len > 0``) and, for a
+    pool page, where the page holds pooled tokens of the row
+    (``pc * page < row_start``).  ``live_ci`` ``[R * (maxp + 1)]`` lists
+    the live cells in ascending order, so a row's pool pages come before
+    its self cell, which finalises the row; past ``n_live`` ``[1]`` it is
+    padding.  It follows from the row arrays alone, not from the layer:
+    a step builds it once, in front of its layer loop."""
+    pc = jnp.arange(maxp + 1, dtype=jnp.int32)
+    live = (row_len[:, None] > 0) & (
+        (pc == maxp) | (pc * page < row_start[:, None]))
+    flat = live.reshape(-1)
+    (live_ci,) = jnp.nonzero(flat, size=flat.shape[0], fill_value=0)
+    return (live_ci.astype(jnp.int32),
+            jnp.sum(flat, dtype=jnp.int32).reshape(1))
+
+
+def live_cell_count(row_start, row_len, page: int) -> int:
+    """``live_page_cells``' ``n_live`` on the host, from the packed row
+    arrays: each live row's pooled pages plus its self cell."""
+    start, nlen = np.asarray(row_start), np.asarray(row_len)
+    return int(np.sum((nlen > 0) * (-(-start // page) + 1)))
+
+
 def fused_ragged_layer(
     x: jax.Array,            # [T, D] residual stream of the flat batch
     layers,                  # the stacked [L, ...] layer tree
@@ -919,6 +953,7 @@ def fused_ragged_layer(
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
     max_row_tokens: Optional[int] = None,
+    live_cells: Optional[Tuple[jax.Array, jax.Array]] = None,
     tile_qkv: int = 256,
     tile_out: int = 256,
     tile_mlp: int = 128,
@@ -937,7 +972,16 @@ def fused_ragged_layer(
     it lies (``weight_routes`` says which operands those are).  A slice
     taken in XLA in front of the call would be a copy of the layer:
     that is kept for what has to be assembled from several leaves, and
-    for the norm vectors."""
+    for the norm vectors.
+
+    The attention phase walks ``live_cells``, the ``live_page_cells`` of
+    the row arrays (a caller with a layer loop builds them once in front
+    of it; None builds them here), and the grid ends where the work
+    ends: its bound is ``Tq + n_live + To + Tm``, a value of the step
+    and not of the page table's capacity ``R * (maxp + 1)``.  The Pallas
+    interpreter takes no dynamic grid bound, so there the grid keeps the
+    capacity and the steps past the end do nothing: the same body
+    either way."""
     T, D = x.shape
     H, KVH = n_heads, n_kv_heads
     hd = D // H
@@ -981,10 +1025,18 @@ def fused_ragged_layer(
     tm = _pick_tile(M, tile_mlp, multiple=128 if M % 128 == 0 else 1)
     Tq, To, Tm = Cw // tq, D // to, M // tm
     cells = maxp + 1
+    if live_cells is None:
+        live_cells = live_page_cells(row_start, row_len, maxp, page)
+    live_ci, n_live = live_cells
+    # Phase starts, as the kernel body has them: the attention phase is
+    # n_live (scalar prefetch 7) steps long.
     S1 = Tq
-    S2 = S1 + R * cells
-    S3 = S2 + To
-    S4 = S3 + Tm
+
+    def s2(pf):
+        return S1 + pf[7][0]
+
+    def s3(pf):
+        return s2(pf) + To
 
     def clip(v, n):
         return jnp.clip(v, 0, n - 1)
@@ -992,8 +1044,8 @@ def fused_ragged_layer(
     def const2(t, *pf):
         return (0, 0)
 
-    def pool_map(t, slot_p, start_p, len_p, off_p, bt, ly, *sc):
-        ci = clip(t - S1, R * cells)
+    def pool_map(t, slot_p, start_p, len_p, off_p, bt, ly, live, nl, *sc):
+        ci = live[jnp.clip(t - S1, 0, jnp.maximum(nl[0] - 1, 0))]
         r = ci // cells
         pc = jnp.minimum(ci % cells, maxp - 1)
         s = slot_p[r]
@@ -1005,39 +1057,45 @@ def fused_ragged_layer(
     def wspec(operand, block, at):
         """Block of a ``[L or 1, rows, cols]`` operand: the leading axis
         is squeezed, and picks the layer (scalar prefetch 5) unless the
-        operand holds one layer only; ``at(t)`` is the block's (row,
+        operand holds one layer only; ``at(t, pf)`` is the block's (row,
         column)."""
         stacked = operand.shape[0] > 1
         return pl.BlockSpec(
             (None,) + block,
-            lambda t, *pf: (pf[5][0] if stacked else 0,) + at(t))
+            lambda t, *pf: (pf[5][0] if stacked else 0,) + at(t, pf))
 
-    def gate_at(t):
-        return (0, clip(t - S3, Tm))
+    def qkv_at(t, pf):
+        return (0, clip(t, Tq))
 
-    def up_at(t):
-        return (0, Tm + clip(t - S3, Tm))
+    def out_at(t, pf):
+        return (0, clip(t - s2(pf), To))
+
+    def gate_at(t, pf):
+        return (0, clip(t - s3(pf), Tm))
+
+    def up_at(t, pf):
+        return (0, Tm + clip(t - s3(pf), Tm))
 
     in_specs = [
         pl.BlockSpec((T_p, D), const2),                        # x (norm)
-        pl.BlockSpec((T_p, to),
-                     lambda t, *pf: (0, clip(t - S2, To))),    # x (resid)
+        pl.BlockSpec((T_p, to), lambda t, *pf: out_at(t, pf)), # x (resid)
         pl.BlockSpec((1, D), const2),                          # ln_attn
         pl.BlockSpec((1, D), const2),                          # ln_mlp
         pl.BlockSpec((T_p, hd // 2), const2),                  # sin
         pl.BlockSpec((T_p, hd // 2), const2),                  # cos
-        wspec(wqkv, (D, tq), lambda t: (0, clip(t, Tq))),
-        wspec(sqkv, (1, tq), lambda t: (0, clip(t, Tq))),
+        wspec(wqkv, (D, tq), qkv_at),
+        wspec(sqkv, (1, tq), qkv_at),
         pl.BlockSpec((1, KVH, 1, page, hd), pool_map),         # k pages
         pl.BlockSpec((1, KVH, 1, page, hd), pool_map),         # v pages
-        wspec(wo, (H * hd, to), lambda t: (0, clip(t - S2, To))),
-        wspec(so, (1, to), lambda t: (0, clip(t - S2, To))),
+        wspec(wo, (H * hd, to), out_at),
+        wspec(so, (1, to), out_at),
         wspec(wg, (D, tm), gate_at),                           # w gate
         wspec(wg, (D, tm), up_at),                             # w up
         wspec(sg, (1, tm), gate_at),                           # s gate
         wspec(sg, (1, tm), up_at),                             # s up
-        wspec(wd, (tm, D), lambda t: (clip(t - S3, Tm), 0)),   # w_down
-        wspec(sd, (1, D), lambda t: (0, 0)),                   # sd
+        wspec(wd, (tm, D),
+              lambda t, pf: (clip(t - s3(pf), Tm), 0)),        # w_down
+        wspec(sd, (1, D), lambda t, pf: (0, 0)),               # sd
     ]
     out_specs = [
         pl.BlockSpec((T_p, D), const2),
@@ -1057,20 +1115,23 @@ def fused_ragged_layer(
     ]
     prefetch = [row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
                 row_len.astype(jnp.int32), row_off.astype(jnp.int32),
-                block_tables.astype(jnp.int32), ly_s.reshape(1)]
+                block_tables.astype(jnp.int32), ly_s.reshape(1),
+                live_ci, n_live]
     if quantized:
         with jax.named_scope("weight_slice"):
             prefetch += [k_scales[ly_s, :, :, 0], v_scales[ly_s, :, :, 0]]
+    interpret = platform.interpret_mode()
+    steps = Tq + (R * cells if interpret else n_live[0]) + To + Tm
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(S4,),
+        grid=(steps,),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
     kern = functools.partial(
         _fused_ragged_kernel, T=T_p, Cq=Cq, D=D, H=H, KVH=KVH, qpg=qpg,
-        hd=hd, page=page, Pt=Pt, maxp=maxp, R=R, M=M, tq=tq, to=to,
+        hd=hd, page=page, Pt=Pt, maxp=maxp, M=M, tq=tq, to=to,
         tm=tm, eps=eps, scale=hd ** -0.5, soft_cap=soft_cap,
         quantized=quantized, dot_dt=dt)
     x_out, k_new, v_new = pl.pallas_call(
@@ -1084,7 +1145,7 @@ def fused_ragged_layer(
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=48 * 2**20),
-        interpret=platform.interpret_mode(),
+        interpret=interpret,
     )(*prefetch, x, x, ln_a, ln_m, sin.astype(jnp.float32),
       cos.astype(jnp.float32), wqkv, sqkv, k_pools, v_pools, wo, so,
       wg, wg, sg, sg, wd, sd)

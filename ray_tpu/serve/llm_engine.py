@@ -520,6 +520,12 @@ class PagedEngineAdapter:
     # every layer of every step.  The engine says it once at start-up.
     weight_routes: Optional[
         Callable[[Any], Optional[Dict[str, List[str]]]]] = None
+    # ragged_grid_cells(row_start, row_len, maxp, page, lora) -> int:
+    # the attention cells the ragged step's kernel walks for one step's
+    # packed row arrays ([R] each; ``lora`` says the step carries
+    # adapters).  None = the page table's capacity, R * (maxp + 1),
+    # whatever the rows hold.  ``llm.pack`` reports it as grid_cells.
+    ragged_grid_cells: Optional[Callable[..., int]] = None
     # Bytes of recurrent state one sequence holds per slot, whatever its
     # length (state-space layers: convolution tails, SSM states); 0 = the
     # cache is KV pages only.  Non-zero, the engine calls
@@ -618,6 +624,9 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
             llama.serving_collective_probes(cfg, mesh),
         weight_routes=lambda params:
             llama.ragged_weight_routes(params, cfg),
+        ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
+            llama.ragged_grid_cells(cfg, row_start, row_len, maxp, page,
+                                    lora),
     )
 
 
@@ -1216,6 +1225,8 @@ class LLMEngine:
         # state_bytes_per_slot): what the engine may not do with it.
         self._state_bytes_per_slot = int(
             getattr(adapter, "state_bytes_per_slot", 0))
+        self._ragged_grid_cells = getattr(adapter, "ragged_grid_cells",
+                                          None)
         self._state_resets = 0
         if self._state_bytes_per_slot:
             why = ("the adapter's cache holds per-slot recurrent state "
@@ -2944,12 +2955,16 @@ class LLMEngine:
             "n_decode": n_decode, "n_prefill": n_prefill,
             "n_spec": n_spec, "rows": len(rows), "budget": T,
             # pages that hold each packed row's tokens once this step
-            # has written them, against the cells the fused layer
-            # kernel's grid walks whatever the rows hold
+            # has written them, against the cells the step's attention
+            # kernel walks, as the adapter states them
             "live_cells": sum(
                 -(-(r["start"] + len(r["tokens"] or (0,))) // page)
                 for r in rows),
-            "grid_cells": R * (self._maxp + 1),
+            "grid_cells": (
+                self._ragged_grid_cells(row_start, row_len, self._maxp,
+                                        page, bool(step_adapters))
+                if self._ragged_grid_cells
+                else R * (self._maxp + 1)),
             # rows that start a sequence (a recurrent-state cache resets
             # their slot on the device) and the step's longest row (what
             # a scan over a row's tokens walks)

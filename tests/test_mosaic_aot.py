@@ -397,6 +397,30 @@ def test_fused_decode_step(bench, v5e, name, kv_int8):
         donate_argnums=(5,))
 
 
+def _pallas_grids(jaxpr, name):
+    """The grid of every ``pallas_call`` called ``name`` anywhere in
+    ``jaxpr``; a bound the call takes as an operand reads None."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_grids(sub, name)
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == name):
+            found.append(tuple(
+                b if isinstance(b, int) else None
+                for b in eqn.params["grid_mapping"].grid))
+    return found
+
+
+def _assert_fused_layer_grid_follows_the_rows(step, *args):
+    """The fused layer kernel's one grid bound is an operand of the call
+    (the step's live cells plus the weight tiles), not the page table's
+    capacity: a fall-back to the static bound on the chip fails here."""
+    grids = _pallas_grids(jax.make_jaxpr(step)(*args).jaxpr,
+                          "fused_ragged_layer")
+    assert grids == [(None,)], grids
+
+
 @pytest.mark.parametrize("name,kv_int8", [("319m", False),
                                           ("8b_int8", True)])
 def test_fused_ragged_step(bench, v5e, name, kv_int8):
@@ -407,10 +431,13 @@ def test_fused_ragged_step(bench, v5e, name, kv_int8):
     toks, rows, bt = _on(mesh, (
         _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
         _sds(slots, maxp, dtype=jnp.int32)))
-    _compile(lambda p, t, pos, rs, r0, rl, ro, b, c:
-             llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c),
-             params, toks, toks, rows, rows, rows, rows, bt, cache,
-             donate_argnums=(8,))
+
+    def step(p, t, pos, rs, r0, rl, ro, b, c):
+        return llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c)
+
+    args = (params, toks, toks, rows, rows, rows, rows, bt, cache)
+    _compile(step, *args, donate_argnums=(8,))
+    _assert_fused_layer_grid_follows_the_rows(step, *args)
 
 
 # -- the benchmark's chat cell: weights read where they are stored ----------
@@ -458,11 +485,13 @@ def test_chat_cell_step_copies_no_weight(v5e):
     toks, rows, bt = _on(mesh, (
         _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
         _sds(slots, maxp, dtype=jnp.int32)))
-    compiled = _compile(
-        lambda p, t, pos, rs, r0, rl, ro, b, c:
-        llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c),
-        params, toks, toks, rows, rows, rows, rows, bt, cache,
-        donate_argnums=(8,))
+
+    def step(p, t, pos, rs, r0, rl, ro, b, c):
+        return llama.ragged_step_paged(p, t, pos, rs, r0, rl, ro, b, cfg, c)
+
+    args = (params, toks, toks, rows, rows, rows, rows, bt, cache)
+    compiled = _compile(step, *args, donate_argnums=(8,))
+    _assert_fused_layer_grid_follows_the_rows(step, *args)
     text = compiled.as_text()
     assert "fused_ragged_layer" in text
     assert weight_sized_int8_copies(text) == []

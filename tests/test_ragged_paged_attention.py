@@ -480,3 +480,129 @@ def test_fused_artifact_step_matches_unfused(kv_int8):
     for got, want in zip(run(True), run(False)):
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-4 * np.abs(
             want).max())
+
+
+# ---------------------------------------------------------------------------
+# the fused layer walks only the page cells its rows hold
+# ---------------------------------------------------------------------------
+
+_PAGE, _MAXP, _SLOTS = 16, 4, 6
+# (slot, start, len, off) of six packed rows over a 6 x 4 page table of
+# 16-token pages: where a walk over the live cells only differs from a
+# walk over all 6 x 5 of them.
+LIVE_WALK_ROWS = {
+    # padding rows in front of, between and behind the live ones
+    "mostly_padding": ([0, 2, 0, 5, 1, 0], [0, 19, 0, 16, 40, 0],
+                       [0, 1, 0, 11, 1, 0], [0, 0, 0, 1, 12, 0]),
+    # rows with nothing pooled yet: a self cell and no pool cell
+    "start_zero": ([3, 1, 4, 0, 0, 0], [0, 0, 33, 0, 0, 0],
+                   [13, 1, 1, 0, 0, 0], [0, 13, 14, 0, 0, 0]),
+    # pooled tokens end exactly at a page's end
+    "start_on_page_boundary": ([0, 1, 2, 0, 0, 0], [16, 32, 48, 0, 0, 0],
+                               [1, 5, 1, 0, 0, 0], [0, 1, 6, 0, 0, 0]),
+    "prompts_beside_decode": ([4, 0, 2, 5, 0, 0], [21, 0, 50, 32, 0, 0],
+                              [1, 20, 1, 9, 0, 0], [0, 1, 21, 22, 0, 0]),
+    # every row live at the longest context: every cell of the table
+    "full_table": (list(range(6)), [63] * 6, [1] * 6, list(range(6))),
+}
+
+
+def _rows(case):
+    return tuple(np.asarray(a, np.int32) for a in LIVE_WALK_ROWS[case])
+
+
+def _old_walks_live_cells(start, nlen, maxp, page):
+    """The cells, in the order of the kernel's old walk over all of
+    them, at which its ``pl.when`` conditions fired."""
+    return [r * (maxp + 1) + pc
+            for r in range(len(nlen)) for pc in range(maxp + 1)
+            if nlen[r] > 0 and (pc == maxp or pc * page < start[r])]
+
+
+@pytest.mark.parametrize("case", list(LIVE_WALK_ROWS) + ["all_padding"])
+def test_live_page_cells_are_the_old_walks_live_cells(case):
+    if case == "all_padding":
+        start, nlen = np.zeros(6, np.int32), np.zeros(6, np.int32)
+    else:
+        _slot, start, nlen, _off = _rows(case)
+    want = _old_walks_live_cells(start, nlen, _MAXP, _PAGE)
+    live_ci, n_live = rpa.live_page_cells(
+        jnp.asarray(start), jnp.asarray(nlen), _MAXP, _PAGE)
+    assert live_ci.shape == (_SLOTS * (_MAXP + 1),) and n_live.shape == (1,)
+    assert live_ci.dtype == n_live.dtype == jnp.int32
+    n = int(n_live[0])
+    assert n == len(want) == rpa.live_cell_count(start, nlen, _PAGE)
+    assert np.asarray(live_ci)[:n].tolist() == want
+    assert (n == _SLOTS * (_MAXP + 1)) == (case == "full_table")
+    # a row's self cell, which finalises it, comes after its pool cells
+    assert want == sorted(want)
+
+
+def _live_walk_setup(kv_int8, seed=6):
+    cfg = llama.LlamaConfig(**dict(_TOY, n_layers=2), kv_int8=kv_int8)
+    params = _fused_artifact(cfg)
+    rng = np.random.default_rng(seed)
+    Pt = _SLOTS * _MAXP + 1
+    kp, vp, ks, vs = _pools(rng, cfg.n_layers, 1, Pt, _PAGE, cfg.head_dim,
+                            int8=kv_int8)
+    bt = rng.permutation(Pt - 1).reshape(_SLOTS, _MAXP).astype(np.int32)
+    return cfg, params, rng, (kp, vp, ks, vs), jnp.asarray(bt)
+
+
+@pytest.mark.parametrize("case", list(LIVE_WALK_ROWS))
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_fused_live_walk_matches_unfused_step(kv_int8, case):
+    """The fused step, whose attention phase walks the live cells only,
+    against the unfused step (``ragged_paged_attention``, held to the
+    dense reference above) on the same rows, pages and weights."""
+    cfg, params, rng, (kp, vp, ks, vs), bt = _live_walk_setup(kv_int8)
+    slot, start, nlen, off = _rows(case)
+    T = 48
+    toks = rng.integers(1, cfg.vocab_size, T).astype(np.int32)
+    pos = np.zeros(T, np.int32)
+    for r in range(_SLOTS):
+        pos[off[r]:off[r] + nlen[r]] = start[r] + np.arange(nlen[r])
+    cache = {"k": kp, "v": vp}
+    if kv_int8:
+        cache.update(k_scale=ks, v_scale=vs)
+
+    def run(fused):
+        logits, _ = llama.ragged_step_paged(
+            params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(slot),
+            jnp.asarray(start), jnp.asarray(nlen), jnp.asarray(off), bt,
+            dataclasses.replace(cfg, fused_decode=fused), cache,
+            max_row_tokens=32)
+        return np.asarray(logits)[nlen > 0]
+
+    got, want = run(True), run(False)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-4 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("case", list(LIVE_WALK_ROWS))
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_fused_live_walk_gives_the_old_walks_bits(kv_int8, case):
+    """Handed a list of ALL the table's cells the kernel is the walk it
+    was before (every cell a grid step, ``pl.when`` skipping the dead
+    ones).  The list of the live cells gives the same bits: the same
+    cells ran the same arithmetic in the same order."""
+    cfg, params, rng, (kp, vp, ks, vs), bt = _live_walk_setup(kv_int8)
+    slot, start, nlen, off = (jnp.asarray(a) for a in _rows(case))
+    T, cells = 48, _SLOTS * (_MAXP + 1)
+    x = jnp.asarray(rng.standard_normal((T, cfg.dim)), jnp.float32)
+    sin, cos = llama.rope_table(cfg, jnp.arange(T)[None])
+
+    def layer(live_cells):
+        return rpa.fused_ragged_layer(
+            x, params["layers"], kp, vp, jnp.int32(1), slot, start, nlen,
+            off, bt, sin[0], cos[0], eps=1e-5, n_heads=cfg.n_heads,
+            n_kv_heads=cfg.n_kv_heads, k_scales=ks, v_scales=vs,
+            max_row_tokens=32, live_cells=live_cells)
+
+    every_cell = (jnp.arange(cells, dtype=jnp.int32),
+                  jnp.full((1,), cells, jnp.int32))
+    got = layer(rpa.live_page_cells(start, nlen, _MAXP, _PAGE))
+    for g, old, built_here in zip(got, layer(every_cell), layer(None)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(old))
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(built_here))

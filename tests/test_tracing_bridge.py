@@ -41,12 +41,13 @@ def params():
     return llama.init_params(jax.random.key(0), CFG)
 
 
-def _engine(params, **kw):
+def _engine(params, adapter=None, **kw):
     cfg = dict(max_slots=4, max_seq_len=128, min_prefill_bucket=16,
                page_size=16, ragged_batching=True, token_budget=36,
                prefill_chunk=16)
     cfg.update(kw)
-    return LLMEngine(params, llama_paged_adapter(CFG), EngineConfig(**cfg))
+    return LLMEngine(params, adapter or llama_paged_adapter(CFG),
+                     EngineConfig(**cfg))
 
 
 @contextlib.contextmanager
@@ -94,6 +95,47 @@ def _seqs(span):
     dispatch order, so the span names the first and the last."""
     assert span.stats["seqs"] >= 1
     return list(range(span.stats["seq_first"], span.stats["seq_last"] + 1))
+
+
+def test_fused_route_reports_the_cells_its_kernel_walks(params, tmp_path):
+    """On the fused route ``llm.pack``'s grid_cells is what the layer
+    kernel walks for the step's rows: each live row's pooled pages plus
+    its self cell, below the page table's capacity."""
+    import dataclasses
+
+    adapter = llama_paged_adapter(dataclasses.replace(CFG, fused_decode=True))
+    stated, walked = adapter.ragged_grid_cells, []
+
+    def recording(row_start, row_len, maxp, page, lora):
+        start, nlen = np.asarray(row_start), np.asarray(row_len)
+        assert (maxp, page, lora) == (128 // 16, 16, False)
+        walked.append(sum(
+            sum(1 for pc in range(maxp) if pc * page < start[r]) + 1
+            for r in range(len(nlen)) if nlen[r] > 0))
+        return stated(row_start, row_len, maxp, page, lora)
+
+    eng = _engine(params, dataclasses.replace(
+        adapter, ragged_grid_cells=recording))
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=2)
+        del walked[:]
+        with _capture(tmp_path) as events:
+            streams = [eng.submit(list(range(1, n + 1)), max_new_tokens=6,
+                                  temperature=0.0) for n in (40, 3, 32)]
+            for s in streams:
+                s.result(timeout_s=120)
+            time.sleep(0.2)
+    finally:
+        eng.shutdown()
+    packs = [p for p in _named(events(), "llm.pack") if "seq" in p.stats]
+    assert [p.stats["grid_cells"] for p in packs] == walked
+    capacity = 4 * (128 // 16 + 1)
+    for p in packs:
+        # a self cell a row, and no more pool cells than its pages
+        assert (p.stats["rows"] <= p.stats["grid_cells"]
+                <= p.stats["live_cells"] + p.stats["rows"] < capacity)
+    # decode rows past their first page walk pool cells too
+    assert max(p.stats["grid_cells"] - p.stats["rows"] for p in packs) >= 3
 
 
 # -- the bridge ------------------------------------------------------------
@@ -196,9 +238,14 @@ def test_engine_spans_chain_by_seq_and_count_tokens(params, tmp_path):
         after["decode"] - before["decode"]
     assert sum(p.stats["n_prefill"] for p in packs) == \
         after["prefill"] - before["prefill"] == 40 + 3 + 23
+    # this adapter's step is the unfused one, whose attention kernel
+    # walks the page table's capacity whatever the rows hold
+    capacity = eng.adapter.ragged_grid_cells(
+        np.zeros(4, np.int32), np.zeros(4, np.int32), 128 // 16, 16, False)
+    assert capacity == 4 * (128 // 16 + 1)
     for p in packs:
         assert 0 < p.stats["live_cells"] <= p.stats["grid_cells"]
-        assert p.stats["grid_cells"] == 4 * (128 // 16 + 1)
+        assert p.stats["grid_cells"] == capacity
         assert (p.stats["n_decode"] + p.stats["n_prefill"]
                 + p.stats["n_spec"]) <= p.stats["budget"] == 36
         assert p.stats["rows"] >= 1
