@@ -849,10 +849,11 @@ def phase_engine_legacy(platform: str, *, cfg=None, slots: int = 64,
                         new_tokens: int = 16, mesh=None,
                         long_prompt: int = 0) -> dict:
     """``LLMEngine`` in-process on the two-program path (batched prefill
-    + decode chunks, ``ragged_batching=False``): the only path with chip
-    history (BENCH_r05's serving shape).  With ``mesh`` it is the
-    tensor-parallel engine, and ``long_prompt`` adds a prompt long
-    enough to enter the flash kernel under shard_map."""
+    + decode chunks, ``ragged_batching=False``), which is kept because
+    it is the only path that runs under a mesh: with ``mesh`` this is
+    the tensor-parallel engine (the ``multichip`` phase), and
+    ``long_prompt`` adds a prompt long enough to enter the flash kernel
+    under shard_map.  No benchmark cell measures this path."""
     import bench
     from ray_tpu.serve.llm_engine import (
         EngineConfig,

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.attention import decode_attention, dot_product_attention
+from ray_tpu.ops.attention import dot_product_attention
 
 Params = Dict[str, Any]
 
@@ -472,150 +472,7 @@ def loss_fn(
     return total, {"loss": total, "ntokens": ntokens}
 
 
-# --- inference (KV cache) -------------------------------------------------
-
-def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int) -> Dict[str, jax.Array]:
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-        "length": jnp.zeros((batch,), jnp.int32),
-    }
-
-
-def prefill(
-    params: Params,
-    tokens: jax.Array,
-    cfg: LlamaConfig,
-    cache: Dict[str, jax.Array],
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Run the prompt through the model, filling the cache.
-
-    tokens [B, S]; returns (logits_last [B, V], cache).  Assumes all rows
-    use the full S (ragged batching is handled by the serve engine via
-    per-row right-padding + length bookkeeping).
-    """
-    B, S = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    sin, cos = rope_table(cfg, positions)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)
-
-    ks, vs = [], []
-
-    def body(carry, layer):
-        x = carry
-        layer = _deq_layer(layer, cfg.dtype)
-        normed = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
-        out, (k, v) = _attn_block(normed, layer, cfg, sin, cos, None)
-        h = x + out
-        h = h + _mlp_block(rms_norm(h, layer["ln_mlp"], cfg.norm_eps), layer, cfg)
-        return h, (k, v)
-
-    x, (k_all, v_all) = lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bd,dv->bv", x[:, -1], head.astype(cfg.dtype))
-
-    cache = dict(cache)
-    cache["k"] = cache["k"].at[:, :, :S].set(k_all)
-    cache["v"] = cache["v"].at[:, :, :S].set(v_all)
-    cache["length"] = jnp.full((B,), S, jnp.int32)
-    return logits.astype(jnp.float32), cache
-
-
-def prefill_slot(
-    params: Params,
-    tokens: jax.Array,
-    true_len: jax.Array,
-    slot: jax.Array,
-    cfg: LlamaConfig,
-    cache: Dict[str, jax.Array],
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Prefill ONE sequence into one slot of a multi-slot cache.
-
-    The continuous-batching primitive (no reference counterpart — the
-    reference serves models via user torch code): tokens [S] is the
-    prompt right-padded to a bucket length; k/v are written into
-    ``cache[:, slot, :S]`` and ``length[slot] = true_len``.  Returns
-    (logits at position true_len-1 [V], cache).  Causality makes the
-    pad positions invisible to positions < true_len.
-    """
-    S = tokens.shape[0]
-    positions = jnp.arange(S)[None, :]
-    sin, cos = rope_table(cfg, positions)
-    x = params["tok_embed"][tokens[None, :]].astype(cfg.dtype)
-
-    def body(carry, layer):
-        x = carry
-        normed = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
-        out, (k, v) = _attn_block(normed, layer, cfg, sin, cos, None)
-        h = x + out
-        h = h + _mlp_block(rms_norm(h, layer["ln_mlp"], cfg.norm_eps), layer, cfg)
-        return h, (k[0], v[0])
-
-    x, (k_all, v_all) = lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = lax.dynamic_index_in_dim(x[0], true_len - 1, axis=0, keepdims=False)
-    head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = last @ head.astype(cfg.dtype)
-
-    # k_all/v_all: [L, S, kvh, hd] → write at [:, slot, 0:S]
-    cache = dict(cache)
-    cache["k"] = lax.dynamic_update_slice(
-        cache["k"], k_all[:, None], (0, slot, 0, 0, 0)
-    )
-    cache["v"] = lax.dynamic_update_slice(
-        cache["v"], v_all[:, None], (0, slot, 0, 0, 0)
-    )
-    cache["length"] = cache["length"].at[slot].set(true_len)
-    return logits.astype(jnp.float32), cache
-
-
-def prefill_batch(
-    params: Params,
-    tokens: jax.Array,
-    true_lens: jax.Array,
-    slots: jax.Array,
-    cfg: LlamaConfig,
-    cache: Dict[str, jax.Array],
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Prefill K sequences in ONE batched forward (the MXU-friendly
-    admission path: [K, S] beats K sequential [1, S] passes ~K-fold).
-
-    tokens [K, S], true_lens [K], slots [K] → (logits at each row's
-    true_len-1 [K, V], cache).  Rows attend only within themselves
-    (standard causal batch); duplicate slot ids (admission padding
-    rows) write identical values, so last-wins is benign."""
-    K, S = tokens.shape
-    positions = jnp.arange(S)[None, :]
-    sin, cos = rope_table(cfg, positions)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)
-
-    def body(carry, layer):
-        x = carry
-        layer = _deq_layer(layer, cfg.dtype)
-        normed = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
-        out, (k, v) = _attn_block(normed, layer, cfg, sin, cos, None)
-        h = x + out
-        h = h + _mlp_block(rms_norm(h, layer["ln_mlp"], cfg.norm_eps), layer, cfg)
-        return h, (k, v)
-
-    x, (k_all, v_all) = lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1
-    )[:, 0]  # [K, D]
-    head = (params["tok_embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = _head_matmul(last, head, cfg)
-
-    # k_all/v_all [L, K, S, KVH, D] → scatter whole rows into slots.
-    cache = dict(cache)
-    cache["k"] = cache["k"].at[:, slots, :S].set(k_all)
-    cache["v"] = cache["v"].at[:, slots, :S].set(v_all)
-    cache["length"] = cache["length"].at[slots].set(true_lens)
-    return logits.astype(jnp.float32), cache
-
+# --- inference (paged KV cache) -------------------------------------------
 
 def prefill_batch_paged(
     params: Params,
@@ -675,58 +532,6 @@ def prefill_batch_paged(
     else:
         cache["k"] = cache["k"].at[:, :, page_ids].set(to_pages(k_all))
         cache["v"] = cache["v"].at[:, :, page_ids].set(to_pages(v_all))
-    return logits.astype(jnp.float32), cache
-
-
-def decode_slots(
-    params: Params,
-    tokens: jax.Array,
-    active: jax.Array,
-    cfg: LlamaConfig,
-    cache: Dict[str, jax.Array],
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step over ALL slots (continuous batching).
-
-    tokens [slots] int32, active [slots] bool → (logits [slots, V],
-    cache).  Inactive slots compute garbage but their length is not
-    advanced, so their cache stays consistent for later reuse.
-    """
-    new_len = jnp.where(active, cache["length"] + 1, cache["length"])
-    positions = cache["length"][:, None]
-    sin, cos = rope_table(cfg, positions)
-    # Gather BEFORE convert (see decode_slots_paged).
-    x = params["tok_embed"][tokens[:, None]].astype(cfg.dtype)
-    B = tokens.shape[0]
-
-    def body(carry, layer):
-        # Caches ride the CARRY (slice → update → write-back at the
-        # same index, XLA's in-place idiom): scanning them as xs/ys
-        # made XLA copy both full stacks every step.
-        x, k_all, v_all, li = carry
-        normed = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
-        q, k, v = _qkv(normed, layer, cfg, sin, cos)
-        idx = cache["length"]
-        rows = jnp.arange(B)
-        kc = lax.dynamic_index_in_dim(k_all, li, 0, keepdims=False)
-        vc = lax.dynamic_index_in_dim(v_all, li, 0, keepdims=False)
-        kc = kc.at[rows, idx].set(k[:, 0])
-        vc = vc.at[rows, idx].set(v[:, 0])
-        out = decode_attention(q, kc, vc, new_len,
-                               logits_soft_cap=cfg.logits_soft_cap)
-        k_all = lax.dynamic_update_index_in_dim(k_all, kc, li, 0)
-        v_all = lax.dynamic_update_index_in_dim(v_all, vc, li, 0)
-        out = jnp.einsum("bshk,hkd->bsd", out,
-                         layer["attn"]["wo"].astype(cfg.dtype))
-        h = x + out
-        h = h + _mlp_block(rms_norm(h, layer["ln_mlp"], cfg.norm_eps), layer, cfg)
-        return (h, k_all, v_all, li + 1), None
-
-    (x, k_new, v_new, _), _ = lax.scan(
-        body, (x, cache["k"], cache["v"], jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bd,dv->bv", x[:, 0], head.astype(cfg.dtype))
-    cache = {"k": k_new, "v": v_new, "length": new_len}
     return logits.astype(jnp.float32), cache
 
 
@@ -1654,46 +1459,3 @@ def ragged_step_paged(
         x = rms_norm(x[sel], params["final_norm"], cfg.norm_eps)
         logits = _head_matmul(x, head, cfg).astype(jnp.float32)
         return logits[:R], logits[R:], new_cache
-
-
-def decode_step(
-    params: Params,
-    tokens: jax.Array,
-    cfg: LlamaConfig,
-    cache: Dict[str, jax.Array],
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step. tokens [B] → (logits [B, V], cache)."""
-    B = tokens.shape[0]
-    positions = cache["length"][:, None]  # [B, 1]
-    sin, cos = rope_table(cfg, positions)
-    x = params["tok_embed"][tokens[:, None]].astype(cfg.dtype)
-    new_len = cache["length"] + 1
-
-    def body(carry, layer):
-        x, k_all, v_all, li = carry
-        normed = rms_norm(x, layer["ln_attn"], cfg.norm_eps)
-        q, k, v = _qkv(normed, layer, cfg, sin, cos)
-        # write new k/v at position length (per row)
-        idx = cache["length"]  # [B]
-        rows = jnp.arange(B)
-        kc = lax.dynamic_index_in_dim(k_all, li, 0, keepdims=False)
-        vc = lax.dynamic_index_in_dim(v_all, li, 0, keepdims=False)
-        kc = kc.at[rows, idx].set(k[:, 0])
-        vc = vc.at[rows, idx].set(v[:, 0])
-        out = decode_attention(q, kc, vc, new_len,
-                               logits_soft_cap=cfg.logits_soft_cap)
-        k_all = lax.dynamic_update_index_in_dim(k_all, kc, li, 0)
-        v_all = lax.dynamic_update_index_in_dim(v_all, vc, li, 0)
-        out = jnp.einsum("bshk,hkd->bsd", out,
-                         layer["attn"]["wo"].astype(cfg.dtype))
-        h = x + out
-        h = h + _mlp_block(rms_norm(h, layer["ln_mlp"], cfg.norm_eps), layer, cfg)
-        return (h, k_all, v_all, li + 1), None
-
-    (x, k_new, v_new, _), _ = lax.scan(
-        body, (x, cache["k"], cache["v"], jnp.int32(0)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bd,dv->bv", x[:, 0], head.astype(cfg.dtype))
-    cache = {"k": k_new, "v": v_new, "length": new_len}
-    return logits.astype(jnp.float32), cache

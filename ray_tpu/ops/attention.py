@@ -148,35 +148,3 @@ def dot_product_attention(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
     return out.astype(orig_dtype)
-
-
-def decode_attention(
-    q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
-    cache_len: jax.Array,
-    *,
-    logits_soft_cap: Optional[float] = None,
-) -> jax.Array:
-    """Single-step attention against a (possibly longer) KV cache.
-
-    q: [B, 1, H, D]; caches: [B, S_max, KVH, D]; cache_len: [B] valid lengths
-    (the new token's k/v must already be written at cache_len-1).
-    """
-    orig_dtype = q.dtype
-    n_heads = q.shape[2]
-    n_kv = k_cache.shape[2]
-    k = _gqa_expand(k_cache, n_heads // n_kv)
-    v = _gqa_expand(v_cache, n_heads // n_kv)
-    scale = q.shape[-1] ** -0.5
-    logits = jnp.einsum(
-        "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) * scale
-    if logits_soft_cap is not None:
-        logits = logits_soft_cap * jnp.tanh(logits / logits_soft_cap)
-    ki = jnp.arange(k.shape[1])[None, None, None, :]
-    valid = ki < cache_len[:, None, None, None]
-    logits = jnp.where(valid, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
-    return out.astype(orig_dtype)

@@ -259,8 +259,6 @@ class EngineAuditor:
     # -- incremental checks ------------------------------------------------
 
     def _check_page_conservation(self, eng):
-        if not eng._paged:
-            return []
         free = len(eng._free_pages)
         cached = eng._prefix.cached_pages if eng._prefix is not None else 0
         owned = sum(len(p) for p in self._owned_pages(eng).values())
@@ -313,8 +311,6 @@ class EngineAuditor:
     # -- deep checks -------------------------------------------------------
 
     def _check_pool_partition(self, eng):
-        if not eng._paged:
-            return []
         out: List[InvariantViolation] = []
         owners: Dict[int, List[str]] = {}
 

@@ -6,16 +6,23 @@ ray: python/ray/serve/_private/replica.py just invokes the callable) —
 on TPU the engine must own the device loop, because XLA wants static
 shapes and hates per-request recompiles.  Design:
 
-  * a fixed number of KV-cache **slots** (the batch dimension of every
-    compiled program) — requests claim a slot, decode advances ALL
-    active slots in one jitted step (MXU stays batched);
-  * **bucketed prefill**: prompts are right-padded to power-of-two
-    buckets, one compile per bucket, causality hides the padding;
+  * a fixed number of **slots** (the batch dimension of every compiled
+    program) over one **paged KV cache**: requests claim a slot and
+    pages, block tables map a slot's positions to pages;
+  * the **ragged step** (``EngineConfig.ragged_batching``): one jitted
+    program a scheduler step, mixing one-token decode rows with prefill
+    chunks up to a token budget — the path every benchmark cell
+    measures, and the one the prefix cache, LoRA multiplexing,
+    speculation and recurrent-state models ride;
+  * the **two-program path** (``ragged_batching=False``): bucketed
+    prefill and chunked multi-step decode as separate programs — kept
+    because it is the only path that runs under a mesh
+    (tensor-parallel and multi-host serving);
   * sampling happens **on device** (greedy or temperature), so the only
     per-step host transfer is one int32 per slot;
-  * admission interleaves with decode: a new request prefills between
-    decode steps and joins the running batch (continuous batching à la
-    Orca; cf. PAPERS.md paged/ragged attention).
+  * admission interleaves with decode: a new request joins the running
+    batch (continuous batching à la Orca; cf. PAPERS.md paged/ragged
+    attention).
 """
 
 from __future__ import annotations
@@ -304,6 +311,10 @@ class SLO:
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
+    # ragged_batching=True serves through the one ragged step: the only
+    # path the benchmark measures.  False (the default, and the only
+    # choice under a mesh) serves through the two-program path, which
+    # alone reads min_prefill_bucket and decode_chunk.
     max_slots: int = 8
     max_seq_len: int = 1024
     min_prefill_bucket: int = 32
@@ -321,10 +332,10 @@ class EngineConfig:
     # (16 was sized for a ~100 ms round trip; not re-measured on a
     # directly attached chip — ROADMAP Queue 1 items 2-3.)
     decode_chunk: int = 16
-    # Chunked prefill (paged mode): prompts longer than this many
-    # tokens prefill in segments of this size, interleaved with decode
-    # chunks — a long prompt never stalls running streams for its full
-    # prefill (0 = always one-shot).
+    # Chunked prefill: prompts longer than this many tokens prefill in
+    # segments of this size, interleaved with decode chunks — a long
+    # prompt never stalls running streams for its full prefill (0 =
+    # always one-shot; the ragged step chunks by token_budget).
     prefill_chunk: int = 0
     # Latency objectives driving the SLO met/missed counters and the
     # goodput gauge (None = every finished request counts as met).
@@ -336,7 +347,7 @@ class EngineConfig:
     # timing the client out.  The natural setting is the e2e SLO
     # budget (slo.e2e_s).  None = never shed.
     shed_queue_age_s: Optional[float] = None
-    # Ragged batching (paged mode): one unified device step per
+    # Ragged batching: one unified device step per
     # dispatch mixing decode rows (1 token per active slot) with
     # prefill chunks from the admission queue, packed up to
     # token_budget tokens (ops/ragged_paged_attention.py).  Replaces
@@ -402,69 +413,48 @@ class EngineConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class EngineAdapter:
-    """Model plug: how the engine talks to a model family.
-
-    init_cache(slots, max_len) -> cache pytree with int32 "length"[slots]
-    prefill_slot(params, tokens[S], true_len, slot, cache) -> (logits[V], cache)
-    decode_slots(params, tokens[slots], active[slots], cache) -> (logits[slots,V], cache)
-    """
-
-    init_cache: Callable[[int, int], Any]
-    prefill_slot: Callable[..., Tuple[jax.Array, Any]]
-    decode_slots: Callable[..., Tuple[jax.Array, Any]]
-    # Optional batched admission: prefill_batch(params, tokens[K,S],
-    # true_lens[K], slots[K], cache) -> (logits[K,V], cache).  One
-    # [K, S] forward instead of K sequential rows — the MXU-friendly
-    # shape; the engine falls back to a fori_loop of prefill_slot when
-    # absent.
-    prefill_batch: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
-
-
-def llama_adapter(cfg) -> EngineAdapter:
-    from ray_tpu.models import llama
-
-    return EngineAdapter(
-        init_cache=lambda slots, max_len: llama.init_kv_cache(
-            cfg, slots, max_len
-        ),
-        prefill_slot=lambda params, tokens, true_len, slot, cache:
-            llama.prefill_slot(params, tokens, true_len, slot, cfg, cache),
-        decode_slots=lambda params, tokens, active, cache:
-            llama.decode_slots(params, tokens, active, cfg, cache),
-        prefill_batch=lambda params, tokens, true_lens, slots, cache:
-            llama.prefill_batch(params, tokens, true_lens, slots, cfg,
-                                cache),
-    )
-
-
-@dataclasses.dataclass(frozen=True)
 class PagedEngineAdapter:
-    """Model plug for the paged (block-table) cache:
+    """Model plug: how the engine talks to a model family.  The cache is
+    pages under block tables (no length field: the engine tracks lengths
+    host-side).  A model served on the ragged step provides two entries,
+    and ``state_bytes_per_slot`` if its cache also holds state by slot:
 
-    init_cache(num_pages, page_size) -> pytree (no length field; the
-        engine tracks lengths host-side)
-    prefill_slot(params, tokens[S], true_len, pages[S/page], cache)
-        -> (logits[V], cache)
-    decode_slots(params, tokens[slots], active, block_tables, lengths,
-        cache) -> (logits[slots, V], cache, new_lengths)
+    init_cache(num_pages, page_size) -> cache pytree
+    ragged_step(params, tokens[T], tok_pos[T], row_slot[R], row_start[R],
+        row_len[R], row_off[R], block_tables, cache, *, lora=None,
+        logit_idx=None) -> (logits[R,V], cache)
     """
 
-    init_cache: Callable[[int, int], Any]
-    prefill_slot: Callable[..., Tuple[jax.Array, Any]]
-    decode_slots: Callable[..., Tuple[jax.Array, Any, jax.Array]]
-    # Batched admission over page rows (see EngineAdapter.prefill_batch).
-    prefill_batch: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
-    # Incremental prefill: prefill_chunk(params, tokens[K,C], start[K],
-    # chunk_lens[K], pages_rows[K,maxp], cache) -> (logits[K,V], cache)
-    # — enables EngineConfig.prefill_chunk.
-    prefill_chunk: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
-    # Unified ragged step: ragged_step(params, tokens[T], tok_pos[T],
-    # row_slot[R], row_start[R], row_len[R], row_off[R], block_tables,
-    # cache) -> (logits[R,V], cache).  One device program serving a
-    # mixed batch of decode rows (len 1) and prefill chunks — enables
-    # EngineConfig.ragged_batching.
+    init_cache: Callable[..., Any]
+    # The unified ragged step (EngineConfig.ragged_batching): one device
+    # program serving a mixed batch of decode rows (len 1) and prefill
+    # chunks.  The engine passes a keyword only on the steps that need
+    # it, so a model that takes neither need not name them.
+    # lora=(pool, page_table, tok_adapter): the engine's adapter pool
+    # (make_adapter_pool), the step's page gather plan and the per-token
+    # adapter index, for per-token segmented LoRA deltas
+    # (ops/segmented_lora).  logit_idx[Tv]: flat-buffer positions of
+    # speculative verify rows' candidates; the step then returns
+    # (logits[R,V], verify_logits[Tv,V], cache), the first R
+    # bit-identical to the step without it (EngineConfig.spec_decode).
     ragged_step: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
+    # The two-program path (ragged_batching=False; the only one that
+    # runs under a mesh) needs the first two of:
+    #   prefill_slot(params, tokens[S], true_len, pages[S/page], cache)
+    #       -> (logits[V], cache)
+    #   decode_slots(params, tokens[slots], active, block_tables,
+    #       lengths, cache) -> (logits[slots, V], cache, new_lengths)
+    #   prefill_batch(params, tokens[K,S], true_lens[K],
+    #       pages_rows[K,S/page], cache) -> (logits[K,V], cache): one
+    #       [K, S] forward instead of a fori_loop of K prefill_slot rows
+    #   prefill_chunk(params, tokens[K,C], start[K], chunk_lens[K],
+    #       pages_rows[K,maxp], cache) -> (logits[K,V], cache) — enables
+    #       EngineConfig.prefill_chunk there.
+    prefill_slot: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
+    decode_slots: Optional[
+        Callable[..., Tuple[jax.Array, Any, jax.Array]]] = None
+    prefill_batch: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
+    prefill_chunk: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
     # COW split for the prefix cache: copy_page(cache, src, dst) ->
     # cache duplicates ONE physical page (all layers, k+v and any
     # per-page quantization scales) so a writer can diverge from a
@@ -491,29 +481,11 @@ class PagedEngineAdapter:
         Callable[[Any, int], Dict[str, int]]] = None
     collective_probes: Optional[
         Callable[[Any], Dict[str, Callable]]] = None
-    # Multi-tenant LoRA multiplexing: ragged_step_lora(params, tokens,
-    # tok_pos, row_slot, row_start, row_len, row_off, block_tables,
-    # cache, pool, page_table, tok_adapter) -> (logits[R,V], cache) —
-    # the unified step with per-token segmented adapter deltas
-    # (ops/segmented_lora) gathered from the paged pool.
-    # make_adapter_pool(EngineConfig) builds the pool the engine owns
-    # (serve/adapter_pool.AdapterPool); both set iff the model config
+    # Multi-tenant LoRA multiplexing: make_adapter_pool(EngineConfig)
+    # builds the pool the engine owns (serve/adapter_pool.AdapterPool)
+    # and hands back to ragged_step as lora=; set iff the model config
     # enables LoRA.
-    ragged_step_lora: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
     make_adapter_pool: Optional[Callable[[Any], Any]] = None
-    # Speculative decoding: ragged_step_verify(params, tokens, tok_pos,
-    # row_slot, row_start, row_len, row_off, block_tables, cache,
-    # logit_idx) -> (logits[R,V], verify_logits[Tv,V], cache) — the
-    # unified step returning EXTRA logits at the flat-buffer positions
-    # in logit_idx (each verify row's k+1 candidate tokens), with the
-    # first R row logits bit-identical to ragged_step.  The LoRA
-    # variant threads the adapter-pool args the same way so verify
-    # rows can ride a mixed-adapter batch — enables
-    # EngineConfig.spec_decode.
-    ragged_step_verify: Optional[
-        Callable[..., Tuple[jax.Array, jax.Array, Any]]] = None
-    ragged_step_lora_verify: Optional[
-        Callable[..., Tuple[jax.Array, jax.Array, Any]]] = None
     # weight_routes(params) -> {"in_place": [...], "sliced": [...]} or
     # None: which weight operands the ragged step's layer kernel reads
     # where they are stored and which are copied out of the stack for
@@ -546,50 +518,47 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
     failover relies on."""
     from ray_tpu.models import llama
 
-    lora_fields: Dict[str, Any] = {}
+    make_adapter_pool = None
     if getattr(cfg, "lora", None) is not None:
-        from ray_tpu.ops import segmented_lora as _sl
         from ray_tpu.serve.adapter_pool import AdapterPool
 
-        def ragged_step_lora(params, tokens, tok_pos, row_slot, row_start,
-                             row_len, row_off, bt, cache, pool, page_table,
-                             tok_adapter):
-            flat = _sl.gather_adapter_flat(pool, page_table)
-            stacks = _sl.gather_adapter_stacks(flat, cfg, cfg.lora)
-            return llama.ragged_step_paged(
-                params, tokens, tok_pos, row_slot, row_start, row_len,
-                row_off, bt, cfg, cache,
-                lora=(stacks, tok_adapter, cfg.lora.scale))
-
-        def ragged_step_lora_verify(params, tokens, tok_pos, row_slot,
-                                    row_start, row_len, row_off, bt,
-                                    cache, pool, page_table, tok_adapter,
-                                    logit_idx):
-            flat = _sl.gather_adapter_flat(pool, page_table)
-            stacks = _sl.gather_adapter_stacks(flat, cfg, cfg.lora)
-            return llama.ragged_step_paged(
-                params, tokens, tok_pos, row_slot, row_start, row_len,
-                row_off, bt, cfg, cache,
-                lora=(stacks, tok_adapter, cfg.lora.scale),
-                logit_idx=logit_idx)
-
-        lora_fields = {
-            "ragged_step_lora": ragged_step_lora,
-            "ragged_step_lora_verify": ragged_step_lora_verify,
-            "make_adapter_pool": lambda ecfg: AdapterPool(
+        def make_adapter_pool(ecfg):
+            return AdapterPool(
                 cfg, cfg.lora,
                 num_pages=ecfg.adapter_pool_pages,
                 page_elems=ecfg.adapter_page_elems,
                 max_batch_adapters=ecfg.max_batch_adapters,
                 int8=ecfg.adapter_int8,
-                loader=lora_loader),
-        }
+                loader=lora_loader)
+
+    def gathered(lora):
+        """The engine's ``lora=(pool, page_table, tok_adapter)`` as the
+        ``(stacks, tok_adapter, scale)`` ragged_step_paged takes: the
+        step's adapters gathered out of the pool's pages."""
+        if lora is None:
+            return None
+        if getattr(cfg, "lora", None) is None:
+            raise ValueError(
+                "ragged_step got lora= but the model config has no "
+                "lora= (LlamaConfig.lora)")
+        from ray_tpu.ops import segmented_lora as _sl
+
+        pool, page_table, tok_adapter = lora
+        flat = _sl.gather_adapter_flat(pool, page_table)
+        stacks = _sl.gather_adapter_stacks(flat, cfg, cfg.lora)
+        return stacks, tok_adapter, cfg.lora.scale
 
     return PagedEngineAdapter(
-        **lora_fields,
         init_cache=lambda num_pages, page: llama.init_paged_cache(
             cfg, num_pages, page
         ),
+        ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
+        row_len, row_off, bt, cache, *, lora=None, logit_idx=None:
+            llama.ragged_step_paged(params, tokens, tok_pos, row_slot,
+                                    row_start, row_len, row_off, bt, cfg,
+                                    cache, lora=gathered(lora),
+                                    logit_idx=logit_idx),
+        make_adapter_pool=make_adapter_pool,
         prefill_slot=lambda params, tokens, true_len, pages, cache:
             llama.prefill_slot_paged(params, tokens, true_len, pages,
                                      cfg, cache),
@@ -603,16 +572,6 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
         cache:
             llama.prefill_chunk_paged(params, tokens, start, chunk_lens,
                                       pages_rows, cfg, cache),
-        ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
-        row_len, row_off, bt, cache:
-            llama.ragged_step_paged(params, tokens, tok_pos, row_slot,
-                                    row_start, row_len, row_off, bt, cfg,
-                                    cache),
-        ragged_step_verify=lambda params, tokens, tok_pos, row_slot,
-        row_start, row_len, row_off, bt, cache, logit_idx:
-            llama.ragged_step_paged(params, tokens, tok_pos, row_slot,
-                                    row_start, row_len, row_off, bt, cfg,
-                                    cache, logit_idx=logit_idx),
         copy_page=llama.copy_page_paged,
         shard_params=lambda params, mesh:
             llama.shard_params_for_serving(params, cfg, mesh),
@@ -632,21 +591,14 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
 
 def jamba_paged_adapter(cfg) -> PagedEngineAdapter:
     """Jamba (models/jamba.py): Mamba-1 layers with per-slot recurrent
-    state beside paged KV for its few attention layers.  Served on the
-    ragged step only: the separate prefill and decode programs have no
-    recurrent-state form, and ragged batching replaces them."""
+    state beside paged KV for its few attention layers.  Its step takes
+    neither ``lora=`` nor ``logit_idx=``, and the two-program path has
+    no recurrent-state form."""
     from ray_tpu.models import jamba
-
-    def ragged_only(*_a, **_k):
-        raise NotImplementedError(
-            "jamba_paged_adapter serves through the ragged step only: "
-            "set EngineConfig.ragged_batching=True")
 
     return PagedEngineAdapter(
         init_cache=lambda num_pages, page, max_slots: jamba.init_cache(
             cfg, num_pages, page, max_slots),
-        prefill_slot=ragged_only,
-        decode_slots=ragged_only,
         ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
         row_len, row_off, bt, cache:
             jamba.ragged_step(params, tokens, tok_pos, row_slot,
@@ -787,7 +739,7 @@ class LLMServer:
 
     def __init__(self, model_cfg: Any, engine_cfg: EngineConfig,
                  param_loader: Callable[[], Any], *, adapter_factory:
-                 Callable[[Any], EngineAdapter] = None,
+                 Callable[[Any], PagedEngineAdapter] = None,
                  draft_param_loader: Callable[[], Any] = None,
                  draft_model_cfg: Any = None):
         # Rank 0 of a shard group (serve/shard_group.py) hosts the
@@ -832,8 +784,7 @@ class LLMServer:
         # Round-robin fallback for handoff-target spreading when a
         # payload carries no request id to hash.
         self._handoff_rr = itertools.count()
-        make_adapter = adapter_factory or (
-            llama_paged_adapter if mesh is not None else llama_adapter)
+        make_adapter = adapter_factory or llama_paged_adapter
         # Speculative decoding's draft model loads inside the replica
         # like the target (weights never cross the object store).  No
         # loader + spec_decode=True = the engine self-drafts.
@@ -846,7 +797,7 @@ class LLMServer:
                                          else model_cfg)
         adapter = make_adapter(model_cfg)
         if (self._disagg is not None and self._disagg.role != "unified"
-                and getattr(adapter, "state_bytes_per_slot", 0)):
+                and adapter.state_bytes_per_slot):
             raise ValueError(
                 f"disaggregated serving role {self._disagg.role!r} hands "
                 "a request over by migrating its KV pages; this model's "
@@ -1200,14 +1151,13 @@ _ENGINE_IDS = itertools.count()
 class LLMEngine:
     """Continuous-batching scheduler around jitted prefill/decode."""
 
-    def __init__(self, params: Any, adapter: EngineAdapter,
+    def __init__(self, params: Any, adapter: PagedEngineAdapter,
                  config: EngineConfig, *, seed: int = 0, mesh: Any = None,
                  draft_params: Any = None,
                  draft_adapter: Optional["PagedEngineAdapter"] = None):
         self.config = config
         self.adapter = adapter
         self._params = params
-        self._paged = isinstance(adapter, PagedEngineAdapter)
         # Speculative decoding is armed by _init_spec at the end of the
         # ragged setup; every other mode must still see the flag.
         self._spec_on = False
@@ -1216,17 +1166,12 @@ class LLMEngine:
         # the model's decode attention runs per shard (parity: serving
         # a model bigger than one chip — SURVEY §7 phase 7).
         self._mesh = mesh
-        if mesh is not None and not self._paged:
-            raise ValueError("mesh-sharded serving requires the paged "
-                             "adapter (PagedEngineAdapter)")
         if mesh is not None and adapter.shard_params is not None:
             self._params = params = adapter.shard_params(params, mesh)
         # Per-slot recurrent state in the cache (see PagedEngineAdapter.
         # state_bytes_per_slot): what the engine may not do with it.
-        self._state_bytes_per_slot = int(
-            getattr(adapter, "state_bytes_per_slot", 0))
-        self._ragged_grid_cells = getattr(adapter, "ragged_grid_cells",
-                                          None)
+        self._state_bytes_per_slot = int(adapter.state_bytes_per_slot)
+        self._ragged_grid_cells = adapter.ragged_grid_cells
         self._state_resets = 0
         if self._state_bytes_per_slot:
             why = ("the adapter's cache holds per-slot recurrent state "
@@ -1245,74 +1190,65 @@ class LLMEngine:
                 raise ValueError(
                     why + "only the unsharded ragged step carries it — "
                     "set EngineConfig.ragged_batching=True, no mesh")
-        if self._paged:
-            page = config.page_size
-            self._maxp = -(-config.max_seq_len // page)
-            self._num_pages = (config.num_pages
-                               or config.max_slots * self._maxp)
-            if mesh is not None and adapter.cache_shardings is not None:
-                # Allocate the pool directly under its shardings: a
-                # materialize-then-reshard would briefly hold the WHOLE
-                # unsharded pool on one device — an OOM at exactly the
-                # model sizes tp serving exists for.
-                self._cache = jax.jit(
-                    partial(adapter.init_cache, self._num_pages, page),
-                    out_shardings=adapter.cache_shardings(mesh),
-                )()
-            elif self._state_bytes_per_slot:
-                self._cache = adapter.init_cache(self._num_pages, page,
-                                                 config.max_slots)
-            else:
-                self._cache = adapter.init_cache(self._num_pages, page)
-            if (isinstance(self._cache, dict)
-                    and "k_scale" in self._cache
-                    and config.prefill_chunk > 0
-                    and not config.ragged_batching):
-                # The ragged path appends through a page-granular
-                # one-hot gather that CAN grow page scales, so int8 KV
-                # + chunked prompts is only a restriction of the legacy
-                # interleave.
-                raise ValueError(
-                    "kv_int8 pools do not support chunked prefill "
-                    "(per-token page scatters cannot grow page scales "
-                    "on the gather path) — set "
-                    "EngineConfig.prefill_chunk=0, enable "
-                    "ragged_batching, or serve with bf16 KV")
-            self._free_pages = list(range(self._num_pages))
-            self._slot_pages: Dict[int, List[int]] = {}
-            # Unallocated block-table entries hold the OOB sentinel
-            # (num_pages): a stale slot decoded past its allocation by
-            # an overshooting in-flight chunk then scatters out of
-            # bounds (mode="drop") instead of corrupting page 0.
-            self._bt = np.full((config.max_slots, self._maxp),
-                               self._num_pages, np.int32)
-            self._lens = np.zeros((config.max_slots,), np.int32)
-            self._backlog: List[Request] = []  # admitted-but-no-pages
-            # Radix-tree prefix cache (EngineConfig.prefix_cache):
-            # finished requests donate full pages to the trie; slots
-            # borrow them at admission (_slot_borrowed tracks which
-            # block-table entries are cache-owned so release never
-            # returns them to the free list).
-            self._prefix = None
-            self._slot_borrowed: Dict[int, List[int]] = {}
-            if config.prefix_cache:
-                if not config.ragged_batching:
-                    raise ValueError(
-                        "prefix_cache requires ragged_batching=True "
-                        "(prefill-from-offset rides the ragged step's "
-                        "per-row start descriptor)")
-                from ray_tpu.serve.prefix_index import PrefixIndex
-                self._prefix = PrefixIndex(page)
-            self._prefix_hit_tokens = 0
-            self._prefix_prompt_tokens = 0
+        page = config.page_size
+        self._maxp = -(-config.max_seq_len // page)
+        self._num_pages = (config.num_pages
+                           or config.max_slots * self._maxp)
+        if mesh is not None and adapter.cache_shardings is not None:
+            # Allocate the pool directly under its shardings: a
+            # materialize-then-reshard would briefly hold the WHOLE
+            # unsharded pool on one device — an OOM at exactly the
+            # model sizes tp serving exists for.
+            self._cache = jax.jit(
+                partial(adapter.init_cache, self._num_pages, page),
+                out_shardings=adapter.cache_shardings(mesh),
+            )()
+        elif self._state_bytes_per_slot:
+            self._cache = adapter.init_cache(self._num_pages, page,
+                                             config.max_slots)
         else:
-            if config.prefix_cache:
+            self._cache = adapter.init_cache(self._num_pages, page)
+        if (isinstance(self._cache, dict)
+                and "k_scale" in self._cache
+                and config.prefill_chunk > 0
+                and not config.ragged_batching):
+            # The ragged path appends through a page-granular
+            # one-hot gather that CAN grow page scales, so int8 KV
+            # + chunked prompts is only a restriction of the legacy
+            # interleave.
+            raise ValueError(
+                "kv_int8 pools do not support chunked prefill "
+                "(per-token page scatters cannot grow page scales "
+                "on the gather path) — set "
+                "EngineConfig.prefill_chunk=0, enable "
+                "ragged_batching, or serve with bf16 KV")
+        self._free_pages = list(range(self._num_pages))
+        self._slot_pages: Dict[int, List[int]] = {}
+        # Unallocated block-table entries hold the OOB sentinel
+        # (num_pages): a stale slot decoded past its allocation by
+        # an overshooting in-flight chunk then scatters out of
+        # bounds (mode="drop") instead of corrupting page 0.
+        self._bt = np.full((config.max_slots, self._maxp),
+                           self._num_pages, np.int32)
+        self._lens = np.zeros((config.max_slots,), np.int32)
+        self._backlog: List[Request] = []  # admitted-but-no-pages
+        # Radix-tree prefix cache (EngineConfig.prefix_cache):
+        # finished requests donate full pages to the trie; slots
+        # borrow them at admission (_slot_borrowed tracks which
+        # block-table entries are cache-owned so release never
+        # returns them to the free list).
+        self._prefix = None
+        self._slot_borrowed: Dict[int, List[int]] = {}
+        if config.prefix_cache:
+            if not config.ragged_batching:
                 raise ValueError(
-                    "prefix_cache requires the paged adapter "
-                    "(PagedEngineAdapter) — the cache indexes KV pages")
-            self._prefix = None
-            self._cache = adapter.init_cache(config.max_slots,
-                                             config.max_seq_len)
+                    "prefix_cache requires ragged_batching=True "
+                    "(prefill-from-offset rides the ragged step's "
+                    "per-row start descriptor)")
+            from ray_tpu.serve.prefix_index import PrefixIndex
+            self._prefix = PrefixIndex(page)
+        self._prefix_hit_tokens = 0
+        self._prefix_prompt_tokens = 0
         # KV page-migration plane (serve/kv_transfer): clients enqueue
         # lease/export/ingest ops here and the LOOP thread services
         # them (_process_migrations) — the cache is donated between
@@ -1367,12 +1303,11 @@ class LLMEngine:
         # Multi-host shard groups: per-step collective byte accounting
         # + one-time timed calibration probes (see PagedEngineAdapter).
         self._coll_bytes_fn = None
-        if (mesh is not None and self._paged
+        if (mesh is not None
                 and adapter.collective_step_bytes is not None):
             self._coll_bytes_fn = partial(
                 adapter.collective_step_bytes, mesh)
-        if (mesh is not None and self._paged
-                and adapter.collective_probes is not None):
+        if mesh is not None and adapter.collective_probes is not None:
             self._calibrate_collectives(adapter.collective_probes(mesh))
         self._update_page_gauges()
         self._tm["state_cache_bytes"].set(
@@ -1423,7 +1358,7 @@ class LLMEngine:
         @_program("serve.prefill", static_argnums=(0,),
                   donate_argnums=(2,))
         def prefill_batch_fn(k, params, cache, tokens, true_lens,
-                             slot_or_pages, temps, seed, cur, slot_ids):
+                             pages_rows, temps, seed, cur, slot_ids):
             """Prefill k slots in ONE dispatch (k static: {1,2,4,8}).
             Rows are sequential inside the program (each writes its own
             slot); padding rows are copies of the last real row — an
@@ -1434,7 +1369,7 @@ class LLMEngine:
             def body(i, carry):
                 cache, toks = carry
                 logits, cache = adapter.prefill_slot(
-                    params, tokens[i], true_lens[i], slot_or_pages[i], cache
+                    params, tokens[i], true_lens[i], pages_rows[i], cache
                 )
                 tok = _sample(logits[None, :], temps[i][None], keys[i])[0]
                 return cache, toks.at[i].set(tok)
@@ -1447,20 +1382,6 @@ class LLMEngine:
             # same slot, and the scatter must not let a padding row's
             # sample beat the emitted real-row token.
             return cache, toks, cur.at[slot_ids].set(toks, mode="drop")
-
-        @_program("serve.decode", static_argnums=(0,),
-                  donate_argnums=(2,))
-        def decode_fn(n_steps, params, cache, cur, active, temps, seed):
-            def step(carry, k):
-                cache, cur = carry
-                logits, cache = adapter.decode_slots(params, cur, active, cache)
-                toks = _sample(logits, temps, k)
-                toks = jnp.where(active, toks, cur)
-                return (cache, toks), toks
-
-            keys = jax.random.split(jax.random.key(seed[0]), n_steps)
-            (cache, cur), toks = jax.lax.scan(step, (cache, cur), keys)
-            return cache, toks, cur, None  # [n_steps, slots]
 
         @_program("serve.decode", static_argnums=(0,),
                   donate_argnums=(2,))
@@ -1483,7 +1404,7 @@ class LLMEngine:
             # feeds them straight in — no host round trip.
             return cache, toks, cur, lens
 
-        if self._paged and adapter.prefill_chunk is not None:
+        if adapter.prefill_chunk is not None:
             @_program("serve.prefill_chunk", donate_argnums=(1,))
             def prefill_chunk_fn(params, cache, tokens, start, chunk_lens,
                                  pages_rows, temps, seed, cur, slot_ids):
@@ -1504,7 +1425,7 @@ class LLMEngine:
         self._ragged = bool(config.ragged_batching)
         self._weight_routes = None
         if self._ragged:
-            if not self._paged or adapter.ragged_step is None:
+            if adapter.ragged_step is None:
                 raise ValueError(
                     "EngineConfig.ragged_batching requires a "
                     "PagedEngineAdapter with ragged_step")
@@ -1520,27 +1441,7 @@ class LLMEngine:
                     "token_budget must leave room for a prefill chunk "
                     f"beside {config.max_slots} decode rows")
 
-            @_program("serve.ragged", donate_argnums=(1,))
-            def ragged_step_fn(params, cache, host_toks, decode_mask,
-                               tok_slot, tok_pos, row_slot, row_start,
-                               row_len, row_off, temps, seed, cur,
-                               scatter_ids, bt):
-                # Decode rows read their token from the device-resident
-                # cur (no host round trip — same pipelining contract as
-                # decode_paged_fn); prefill rows carry host tokens.
-                toks = jnp.where(decode_mask, cur[tok_slot], host_toks)
-                logits, cache = adapter.ragged_step(
-                    params, toks, tok_pos, row_slot, row_start, row_len,
-                    row_off, bt, cache)
-                sampled = _sample(logits, temps,
-                                  jax.random.key(seed[0]))
-                # Mid-chunk prefill rows and padding rows carry OOB
-                # scatter ids: their sample is meaningless and must not
-                # clobber a live slot's cur.
-                cur = cur.at[scatter_ids].set(sampled, mode="drop")
-                return cache, sampled, cur
-
-            self._ragged_step_fn = ragged_step_fn
+            self._ragged_step_fn = self._ragged_program("serve.ragged")
             if self._state_bytes_per_slot:
                 from ray_tpu.util import flight_recorder
                 parts = {k: int(v.size * v.dtype.itemsize)
@@ -1573,41 +1474,15 @@ class LLMEngine:
                     ", ".join(self._weight_routes["sliced"]))
 
             # Multi-tenant LoRA multiplexing: the engine owns the paged
-            # adapter pool and a LoRA variant of the ragged program
-            # (pool + gather plan + per-token adapter index as extra
-            # args).  The pool array is NOT donated — the host manager
-            # mutates it on loads, not the step.  Batches with no
-            # adapter rows keep dispatching the base program above, so
-            # adapter-off traffic pays zero overhead.
-            if adapter.make_adapter_pool is not None:
-                if adapter.ragged_step_lora is None:
-                    raise ValueError(
-                        "adapter exposes make_adapter_pool without "
-                        "ragged_step_lora")
-                self._adapters = adapter.make_adapter_pool(config)
-
-                @_program("serve.ragged", donate_argnums=(1,))
-                def ragged_step_lora_fn(params, cache, host_toks,
-                                        decode_mask, tok_slot, tok_pos,
-                                        row_slot, row_start, row_len,
-                                        row_off, temps, seed, cur,
-                                        scatter_ids, bt, pool,
-                                        page_table, tok_adapter):
-                    toks = jnp.where(decode_mask, cur[tok_slot],
-                                     host_toks)
-                    logits, cache = adapter.ragged_step_lora(
-                        params, toks, tok_pos, row_slot, row_start,
-                        row_len, row_off, bt, cache, pool, page_table,
-                        tok_adapter)
-                    sampled = _sample(logits, temps,
-                                      jax.random.key(seed[0]))
-                    cur = cur.at[scatter_ids].set(sampled, mode="drop")
-                    return cache, sampled, cur
-
-                self._ragged_step_lora_fn = ragged_step_lora_fn
-            else:
-                self._adapters = None
-                self._ragged_step_lora_fn = None
+            # adapter pool; a step that carries adapter rows hands the
+            # pool, its gather plan and the per-token adapter index to
+            # the same program as lora= (a second trace of it).  The
+            # pool array is NOT donated — the host manager mutates it
+            # on loads, not the step.  Batches with no adapter rows
+            # keep the base trace, so adapter-off traffic pays zero
+            # overhead.
+            self._adapters = (adapter.make_adapter_pool(config)
+                              if adapter.make_adapter_pool else None)
 
             if self._prefix is not None:
                 if adapter.copy_page is None:
@@ -1658,12 +1533,18 @@ class LLMEngine:
                     "EngineConfig.spec_decode requires "
                     "ragged_batching=True — verify rows are k-token "
                     "prefill-chunk rows of the unified ragged step")
-            if getattr(adapter, "make_adapter_pool", None) is not None:
+            if adapter.make_adapter_pool is not None:
                 raise ValueError(
                     "LoRA multiplexing requires ragged_batching — the "
                     "segmented adapter matmul rides the unified step")
+            for need in ("prefill_slot", "decode_slots"):
+                if getattr(adapter, need) is None:
+                    raise ValueError(
+                        "EngineConfig.ragged_batching=False serves "
+                        "through the two-program path, which needs "
+                        f"PagedEngineAdapter.{need}; this adapter does "
+                        "not provide it — set ragged_batching=True")
             self._adapters = None
-            self._ragged_step_lora_fn = None
             self._ragged_step_fn = None
             self._token_budget = 0
         # Adapter borrow per slot ("" = base model): released with the
@@ -1679,10 +1560,10 @@ class LLMEngine:
         if adapter.prefill_batch is not None:
             @_program("serve.prefill", donate_argnums=(1,))
             def prefill_batched_fn(params, cache, tokens, true_lens,
-                                   slot_or_pages, temps, seed, cur,
+                                   pages_rows, temps, seed, cur,
                                    slot_ids):
                 logits, cache = adapter.prefill_batch(
-                    params, tokens, true_lens, slot_or_pages, cache
+                    params, tokens, true_lens, pages_rows, cache
                 )
                 toks = _sample(logits, temps, jax.random.key(seed[0]))
                 # Padding rows' scatter ids are OOB — see prefill_batch_fn.
@@ -1691,10 +1572,8 @@ class LLMEngine:
             self._prefill_batched_fn = prefill_batched_fn
         else:
             self._prefill_batched_fn = None
-        # One prefill program serves both modes: the adapter closure is
-        # what interprets the third per-row arg (slot id vs page list).
         self._prefill_batch_fn = prefill_batch_fn
-        self._decode_fn = decode_paged_fn if self._paged else decode_fn
+        self._decode_fn = decode_paged_fn
         self._seed_counter = itertools.count(seed * 1_000_003 + 1)
         # Decode chunk ladder: descending powers of two (see
         # _chunk_size).
@@ -1731,11 +1610,6 @@ class LLMEngine:
         dispatching it, so spec-off output is the byte-identical oracle
         by construction."""
         config, adapter = self.config, self.adapter
-        if adapter.ragged_step_verify is None:
-            raise ValueError(
-                "EngineConfig.spec_decode requires an adapter with "
-                "ragged_step_verify (the unified step with extra "
-                "verify logits)")
         da = draft_adapter if draft_params is not None else None
         if draft_params is None:
             # Self-draft: draft == target weights.  Every draft is
@@ -1799,59 +1673,49 @@ class LLMEngine:
         self._draft_feed_fn = draft_feed_fn
         self._draft_chain_fn = draft_chain_fn
 
-        @_program("serve.ragged_spec", donate_argnums=(1,))
-        def ragged_step_spec_fn(params, cache, host_toks, decode_mask,
-                                tok_slot, tok_pos, row_slot, row_start,
-                                row_len, row_off, temps, seed, cur,
-                                scatter_ids, bt, logit_idx):
-            toks = jnp.where(decode_mask, cur[tok_slot], host_toks)
-            logits, vlogits, cache = adapter.ragged_step_verify(
-                params, toks, tok_pos, row_slot, row_start, row_len,
-                row_off, bt, cache, logit_idx)
-            sampled = _sample(logits, temps, jax.random.key(seed[0]))
-            # Per-position target argmax of every verify candidate,
-            # computed on device — the fetch carries k+1 ints per
-            # verify row instead of k+1 logit vectors.
-            ver = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)
-            # Verify rows keep OOB scatter ids: their row sample never
-            # becomes the emitted token (the accept boundary decides).
-            cur = cur.at[scatter_ids].set(sampled, mode="drop")
-            return cache, (sampled, ver), cur
-
-        self._ragged_step_spec_fn = ragged_step_spec_fn
-        if (self._adapters is not None
-                and adapter.ragged_step_lora_verify is not None):
-            @_program("serve.ragged_spec", donate_argnums=(1,))
-            def ragged_step_spec_lora_fn(params, cache, host_toks,
-                                         decode_mask, tok_slot, tok_pos,
-                                         row_slot, row_start, row_len,
-                                         row_off, temps, seed, cur,
-                                         scatter_ids, bt, pool,
-                                         page_table, tok_adapter,
-                                         logit_idx):
-                toks = jnp.where(decode_mask, cur[tok_slot], host_toks)
-                logits, vlogits, cache = \
-                    adapter.ragged_step_lora_verify(
-                        params, toks, tok_pos, row_slot, row_start,
-                        row_len, row_off, bt, cache, pool, page_table,
-                        tok_adapter, logit_idx)
-                sampled = _sample(logits, temps,
-                                  jax.random.key(seed[0]))
-                ver = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)
-                cur = cur.at[scatter_ids].set(sampled, mode="drop")
-                return cache, (sampled, ver), cur
-
-            self._ragged_step_spec_lora_fn = ragged_step_spec_lora_fn
-        elif self._adapters is not None:
-            # A verify row can share a step with another slot's LoRA
-            # row, so multiplexing + speculation needs the combined
-            # program up front, not on first collision.
-            raise ValueError(
-                "spec_decode with LoRA multiplexing requires an "
-                "adapter with ragged_step_lora_verify")
-        else:
-            self._ragged_step_spec_lora_fn = None
+        self._ragged_step_spec_fn = self._ragged_program(
+            "serve.ragged_spec")
         self._spec_on = True
+
+    def _ragged_program(self, name: str):
+        """The jitted unified step under its registered name:
+        ``serve.ragged``, and ``serve.ragged_spec`` for steps with
+        speculative verify rows (always called with ``logit_idx``).  A
+        step with adapter rows passes ``lora``; None is an empty
+        argument, so the step without it traces to the base program."""
+        step = self.adapter.ragged_step
+
+        @_program(name, donate_argnums=(1,))
+        def ragged_step_fn(params, cache, host_toks, decode_mask,
+                           tok_slot, tok_pos, row_slot, row_start,
+                           row_len, row_off, temps, seed, cur,
+                           scatter_ids, bt, lora=None, logit_idx=None):
+            # Decode rows read their token from the device-resident
+            # cur (no host round trip — same pipelining contract as
+            # decode_paged_fn); prefill rows carry host tokens.
+            toks = jnp.where(decode_mask, cur[tok_slot], host_toks)
+            kw = {k: v for k, v in (("lora", lora),
+                                    ("logit_idx", logit_idx))
+                  if v is not None}
+            logits, *vlogits, cache = step(
+                params, toks, tok_pos, row_slot, row_start, row_len,
+                row_off, bt, cache, **kw)
+            out = sampled = _sample(logits, temps,
+                                    jax.random.key(seed[0]))
+            if vlogits:
+                # Per-position target argmax of every verify candidate,
+                # computed on device — the fetch carries k+1 ints per
+                # verify row instead of k+1 logit vectors.
+                out = (sampled, jnp.argmax(
+                    vlogits[0], axis=-1).astype(jnp.int32))
+            # Mid-chunk prefill rows, padding rows and verify rows
+            # carry OOB scatter ids: their sample is meaningless (a
+            # verify row's accept boundary decides its token) and must
+            # not clobber a live slot's cur.
+            cur = cur.at[scatter_ids].set(sampled, mode="drop")
+            return cache, out, cur
+
+        return ragged_step_fn
 
     # -- client API --------------------------------------------------------
 
@@ -1926,17 +1790,16 @@ class LLMEngine:
         # (router-minted, riding request metadata) > local mint.
         req.request_id = (request_id or _reqev.get_request_id()
                           or f"{self._engine_id}-r{req.req_id}")
-        if self._paged:
-            # Reject requests the page pool can NEVER satisfy — they
-            # would otherwise wedge admission head-of-line forever.
-            need = self._pages_needed(req)
-            if need > self._num_pages:
-                raise ValueError(
-                    f"request needs {need} pages "
-                    f"({len(prompt)}+{req.max_new_tokens} tokens, page "
-                    f"{self.config.page_size}) but the pool has only "
-                    f"{self._num_pages}"
-                )
+        # Reject requests the page pool can NEVER satisfy — they
+        # would otherwise wedge admission head-of-line forever.
+        need = self._pages_needed(req)
+        if need > self._num_pages:
+            raise ValueError(
+                f"request needs {need} pages "
+                f"({len(prompt)}+{req.max_new_tokens} tokens, page "
+                f"{self.config.page_size}) but the pool has only "
+                f"{self._num_pages}"
+            )
         self._ring.record(req.request_id, _reqev.QUEUED,
                           prompt_tokens=len(req.prompt),
                           adapter_id=req.adapter_id)
@@ -1996,9 +1859,7 @@ class LLMEngine:
         """No request the drain still has to account for."""
         if self._slot_req or not self._waiting.empty() or self._admitting:
             return False
-        if self._prefilling or (self._paged and self._backlog):
-            return False
-        return True
+        return not (self._prefilling or self._backlog)
 
     def generate(self, prompt: List[int], **kw) -> List[int]:
         return self.submit(prompt, **kw).result()
@@ -2021,10 +1882,9 @@ class LLMEngine:
             "loop": self._clock.snapshot(),
             "requests": self._ring.counts_by_state(),
         }
-        if self._paged:
-            out["kv_pages_free"] = len(self._free_pages)
-            out["kv_pages_cached"] = (self._prefix.cached_pages
-                                      if self._prefix else 0)
+        out["kv_pages_free"] = len(self._free_pages)
+        out["kv_pages_cached"] = (self._prefix.cached_pages
+                                  if self._prefix else 0)
         if self._prefix is not None:
             pstats = self._prefix.stats()
             pstats["hit_tokens"] = self._prefix_hit_tokens
@@ -2154,53 +2014,12 @@ class LLMEngine:
         return np.asarray([next(self._seed_counter) & 0x7FFFFFFF],
                           np.uint32)
 
-    def _bucket_for(self, n: int) -> int:
-        for b in self.config.buckets():
-            if n <= b:
-                return b
-        raise ValueError(f"prompt length {n} exceeds max bucket")
-
     def _admit(self):
         if self._draining.is_set():
             return  # racing submits are preempted, never admitted
         if self._ragged:
             return self._admit_ragged()
-        if self._paged:
-            return self._admit_paged()
-        while self._free_slots:
-            # Pull as many waiting requests as there are free slots and
-            # prefill them in one dispatch (padded to a {1,2,4,8} batch
-            # and to the largest prompt bucket of the group).
-            batch: List[Tuple[Request, int]] = []
-            while self._free_slots and len(batch) < 8:
-                try:
-                    req = self._waiting.get_nowait()
-                except queue.Empty:
-                    break
-                batch.append((req, self._free_slots.pop()))
-            if not batch:
-                return
-            bucket = max(self._bucket_for(len(r.prompt))
-                         for r, _ in batch)
-            k = 1
-            while k < len(batch):
-                k *= 2
-            tokens = np.zeros((k, bucket), np.int32)
-            true_lens = np.zeros((k,), np.int32)
-            slot_ids = np.zeros((k,), np.int32)
-            temps = np.zeros((k,), np.float32)
-            for i in range(k):
-                req, slot = batch[min(i, len(batch) - 1)]  # pad = row copy
-                tokens[i, : len(req.prompt)] = req.prompt
-                true_lens[i] = len(req.prompt)
-                slot_ids[i] = slot
-                temps[i] = req.temperature
-            self._admitting = [req for req, _slot in batch]
-            toks_dev = self._run_prefill(k, tokens, true_lens, slot_ids,
-                                         temps,
-                                         self._scatter_ids(slot_ids,
-                                                           len(batch)))
-            self._finish_admit(batch, toks_dev, slot_ids)
+        return self._admit_paged()
 
     def _scatter_ids(self, slot_ids: np.ndarray, n_real: int) -> np.ndarray:
         """cur-scatter indices: real rows keep their slot, padding rows
@@ -2251,7 +2070,7 @@ class LLMEngine:
                                             "program": name})
         return out
 
-    def _run_prefill(self, k, tokens, true_lens, slot_or_pages, temps,
+    def _run_prefill(self, k, tokens, true_lens, pages_rows, temps,
                      slot_ids):
         """One admission dispatch: batched [K, S] forward when the
         adapter provides it, else the fori_loop-of-rows program.  The
@@ -2268,7 +2087,7 @@ class LLMEngine:
                 self._instrumented_dispatch(
                     "serve.prefill", self._prefill_batched_fn,
                     (self._params, self._cache, tokens, true_lens,
-                     slot_or_pages, temps, self._next_seed(),
+                     pages_rows, temps, self._next_seed(),
                      self._cur_dev, slot_ids),
                     span_name="llm.prefill",
                     cost_steps=float(np.sum(true_lens)),
@@ -2278,7 +2097,7 @@ class LLMEngine:
                 self._instrumented_dispatch(
                     "serve.prefill", self._prefill_batch_fn,
                     (k, self._params, self._cache, tokens, true_lens,
-                     slot_or_pages, temps, self._next_seed(),
+                     pages_rows, temps, self._next_seed(),
                      self._cur_dev, slot_ids),
                     span_name="llm.prefill",
                     cost_steps=float(np.sum(true_lens)),
@@ -2286,7 +2105,7 @@ class LLMEngine:
         return toks_dev
 
     def _finish_admit(self, batch, toks_dev, slot_ids) -> None:
-        """Post-prefill bookkeeping shared by both cache modes.  The
+        """Post-prefill bookkeeping of the two-program path.  The
         first-token FETCH is deferred into the pipeline (one batched
         device_get covers several entries — each sync get is a host
         round trip); slots register NOW so
@@ -2299,8 +2118,7 @@ class LLMEngine:
                 req.admitted_at = now
             self._ring.record(
                 req.request_id, _reqev.PREFILLING, slot=slot,
-                num_pages=(len(self._slot_pages.get(slot, []))
-                           if self._paged else None))
+                num_pages=len(self._slot_pages.get(slot, [])))
             # The pending first token counts against the budget until
             # the prefill entry is processed.
             self._inflight_tokens[slot] = \
@@ -2425,8 +2243,6 @@ class LLMEngine:
                     nbytes * steps, tags={"link": link})
 
     def _update_page_gauges(self) -> None:
-        if not self._paged:
-            return
         self._tm["kv_pages_free"].set(len(self._free_pages))
         cached = self._prefix.cached_pages if self._prefix else 0
         self._tm["kv_pages_cached"].set(cached)
@@ -2446,8 +2262,10 @@ class LLMEngine:
         prefill writes whole pages, so a bucket smaller than a page
         would write NO prompt k/v at all."""
         page = self.config.page_size
-        b = self._bucket_for(n)
-        return -(-b // page) * page
+        for b in self.config.buckets():
+            if n <= b:
+                return -(-b // page) * page
+        raise ValueError(f"prompt length {n} exceeds max bucket")
 
     def _admit_paged(self):
         """Admission with page allocation: a request needs pages for
@@ -2906,17 +2724,21 @@ class LLMEngine:
             return None
         self._refresh_state_args()
         if step_adapters:
-            # LoRA variant: same program + the pool, the step's page
-            # gather plan, and the per-token adapter index.  Batches
-            # with no adapter rows never reach here — they stay on the
-            # untouched base program below (zero overhead, bit-equal).
+            # The step carries adapters: the program also gets the
+            # pool, the step's page gather plan, and the per-token
+            # adapter index.  Batches with no adapter rows never reach
+            # here — they stay on the base trace (zero overhead,
+            # bit-equal).
             (host_toks, decode_mask, tok_slot, tok_pos, row_slot,
              row_start, row_len, row_off, tok_adapter) = \
                 pack_ragged_batch(rows, T, R, with_adapters=True)
+            lora = (self._adapters.device_pool,
+                    self._adapters.page_table(list(step_adapters)),
+                    tok_adapter)
         else:
             (host_toks, decode_mask, tok_slot, tok_pos, row_slot,
              row_start, row_len, row_off) = pack_ragged_batch(rows, T, R)
-            tok_adapter = None
+            lora = None
         if n_spec:
             # Flat-buffer positions of every verify row's k+1
             # candidate tokens (static [Tv], padded with index 0 —
@@ -2936,20 +2758,12 @@ class LLMEngine:
         args = (self._params, self._cache, host_toks, decode_mask,
                 tok_slot, tok_pos, row_slot, row_start, row_len,
                 row_off, temps, self._next_seed(), self._cur_dev,
-                scatter, self._bt_arg)
-        if step_adapters:
-            page_table = self._adapters.page_table(list(step_adapters))
-            args += (self._adapters.device_pool, page_table, tok_adapter)
-            name, fn = (("serve.ragged_spec",
-                         self._ragged_step_spec_lora_fn)
-                        if n_spec else
-                        ("serve.ragged", self._ragged_step_lora_fn))
-        else:
-            name, fn = (("serve.ragged_spec", self._ragged_step_spec_fn)
-                        if n_spec else
-                        ("serve.ragged", self._ragged_step_fn))
+                scatter, self._bt_arg, lora)
         if n_spec:
+            name, fn = "serve.ragged_spec", self._ragged_step_spec_fn
             args += (logit_idx,)
+        else:
+            name, fn = "serve.ragged", self._ragged_step_fn
         page = self.config.page_size
         counts = {
             "n_decode": n_decode, "n_prefill": n_prefill,
@@ -3136,7 +2950,7 @@ class LLMEngine:
 
     def _release_slot(self, slot: int, *,
                       cache_tokens: Optional[List[int]] = None) -> None:
-        """Return a slot (and, paged, its pages) to the free pool —
+        """Return a slot and its pages to the free pool —
         shared by the finish, cancel, and failure paths so terminal
         accounting can never leak capacity.
 
@@ -3154,37 +2968,36 @@ class LLMEngine:
         self._free_slots.append(slot)
         self._state_dirty = True
         self._auditor.mark_dirty()
-        if self._paged:
-            if self._spec_on:
-                self._spec_inflight.discard(slot)
-                self._spec_stale_cur.discard(slot)
-                self._draft_fed.pop(slot, None)
-                dpages = self._draft_slot_pages.pop(slot, None)
-                if dpages:
-                    if _audit.corrupt(_audit.INJECT_DRAFT_PAGE):
-                        dpages = dpages[1:]  # leak one draft page
-                    self._draft_free.extend(dpages)
-                    self._draft_bt[slot] = self._draft_pages
-            pages = self._slot_pages.pop(slot, [])
-            if self._prefix is not None:
-                borrowed = self._slot_borrowed.pop(slot, [])
-                release = borrowed
-                if borrowed and _audit.corrupt(_audit.INJECT_TRIE_REF):
-                    release = borrowed[1:]  # leak one trie borrow ref
-                self._prefix.release(release)
-                adopted: set = set()
-                if cache_tokens is not None and not self._draining.is_set():
-                    full = len(cache_tokens) // self.config.page_size
-                    adopted = self._prefix.insert(cache_tokens,
-                                                  pages[:full])
-                owned = pages[len(borrowed):]
-                self._free_pages.extend(p for p in owned
-                                        if p not in adopted)
-            else:
-                self._free_pages.extend(pages)
-            self._bt[slot] = self._num_pages
-            self._lens[slot] = 0
-            self._update_page_gauges()
+        if self._spec_on:
+            self._spec_inflight.discard(slot)
+            self._spec_stale_cur.discard(slot)
+            self._draft_fed.pop(slot, None)
+            dpages = self._draft_slot_pages.pop(slot, None)
+            if dpages:
+                if _audit.corrupt(_audit.INJECT_DRAFT_PAGE):
+                    dpages = dpages[1:]  # leak one draft page
+                self._draft_free.extend(dpages)
+                self._draft_bt[slot] = self._draft_pages
+        pages = self._slot_pages.pop(slot, [])
+        if self._prefix is not None:
+            borrowed = self._slot_borrowed.pop(slot, [])
+            release = borrowed
+            if borrowed and _audit.corrupt(_audit.INJECT_TRIE_REF):
+                release = borrowed[1:]  # leak one trie borrow ref
+            self._prefix.release(release)
+            adopted: set = set()
+            if cache_tokens is not None and not self._draining.is_set():
+                full = len(cache_tokens) // self.config.page_size
+                adopted = self._prefix.insert(cache_tokens,
+                                              pages[:full])
+            owned = pages[len(borrowed):]
+            self._free_pages.extend(p for p in owned
+                                    if p not in adopted)
+        else:
+            self._free_pages.extend(pages)
+        self._bt[slot] = self._num_pages
+        self._lens[slot] = 0
+        self._update_page_gauges()
 
     def _slo_met(self, req: Request) -> bool:
         """Did a FINISHED request meet every configured bound?  (No slo
@@ -3375,9 +3188,8 @@ class LLMEngine:
             active[slot] = True
         self._active_arg = active
         self._temps_arg = np.array(self._temps)
-        if self._paged:
-            self._bt_arg = np.array(self._bt)
-            self._lens_arg = np.array(self._lens)
+        self._bt_arg = np.array(self._bt)
+        self._lens_arg = np.array(self._lens)
         self._state_dirty = False
 
     def _admission_queue_age(self) -> float:
@@ -3386,8 +3198,7 @@ class LLMEngine:
         queue's and backlog's internals — both only ever hold Request
         objects and a stale read just shifts the gauge one sample."""
         oldest = None
-        for req in list(self._waiting.queue) + (
-                list(self._backlog) if self._paged else []):
+        for req in list(self._waiting.queue) + list(self._backlog):
             if oldest is None or req.submitted_at < oldest:
                 oldest = req.submitted_at
         return 0.0 if oldest is None else time.monotonic() - oldest
@@ -3426,41 +3237,29 @@ class LLMEngine:
         directly attached chip needs either is ROADMAP Queue 1
         items 2-3."""
         self._refresh_state_args()
-        if self._paged:
-            self._cache, toks_dev, self._cur_dev, self._lens_arg = \
-                self._instrumented_dispatch(
-                    "serve.decode", self._decode_fn,
-                    (chunk, self._params, self._cache, self._cur_dev,
-                     self._active_arg, self._temps_arg,
-                     self._next_seed(), self._bt_arg, self._lens_arg),
-                    span_name="llm.decode", steps_attr="tokens",
-                    # One decode step produces one token per active
-                    # request: a request's per-token device share is a
-                    # full step, so the denominator is steps, not
-                    # steps x slots.
-                    cost_steps=float(chunk),
-                )
-            # Host mirror advances for slots active in THIS dispatch.
-            for slot in self._slot_req:
-                self._lens[slot] += chunk
-        else:
-            self._cache, toks_dev, self._cur_dev, _ = \
-                self._instrumented_dispatch(
-                    "serve.decode", self._decode_fn,
-                    (chunk, self._params, self._cache, self._cur_dev,
-                     self._active_arg, self._temps_arg,
-                     self._next_seed()),
-                    span_name="llm.decode", steps_attr="tokens",
-                    cost_steps=float(chunk),
-                )
+        self._cache, toks_dev, self._cur_dev, self._lens_arg = \
+            self._instrumented_dispatch(
+                "serve.decode", self._decode_fn,
+                (chunk, self._params, self._cache, self._cur_dev,
+                 self._active_arg, self._temps_arg,
+                 self._next_seed(), self._bt_arg, self._lens_arg),
+                span_name="llm.decode", steps_attr="tokens",
+                # One decode step produces one token per active
+                # request: a request's per-token device share is a
+                # full step, so the denominator is steps, not
+                # steps x slots.
+                cost_steps=float(chunk),
+            )
+        # Host mirror advances for slots active in THIS dispatch.
+        for slot in self._slot_req:
+            self._lens[slot] += chunk
         self._steps += chunk
         self._tm["step_tokens"].inc(chunk * len(self._slot_req),
                                     tags={"phase": "decode"})
         self._count_collective_bytes(len(self._slot_req), steps=chunk)
         self._tm["batch_size"].observe(len(self._slot_req))
         self._tm["queue_depth"].set(
-            self._waiting.qsize()
-            + (len(self._backlog) if self._paged else 0))
+            self._waiting.qsize() + len(self._backlog))
         self._tm["queue_age"].set(self._admission_queue_age())
         participants = list(self._slot_req.items())
         for slot, _req in participants:
@@ -3633,17 +3432,16 @@ class LLMEngine:
             if req.request_id in pending:
                 pending.discard(req.request_id)
                 _finish_cancel(req, slot)
-        if self._paged:
-            for st in list(self._prefilling):
-                if st["req"].request_id in pending:
-                    pending.discard(st["req"].request_id)
-                    self._prefilling.remove(st)
-                    _finish_cancel(st["req"], st["slot"])
-            for req in list(self._backlog):
-                if req.request_id in pending:
-                    pending.discard(req.request_id)
-                    self._backlog.remove(req)
-                    _finish_cancel(req, None)
+        for st in list(self._prefilling):
+            if st["req"].request_id in pending:
+                pending.discard(st["req"].request_id)
+                self._prefilling.remove(st)
+                _finish_cancel(st["req"], st["slot"])
+        for req in list(self._backlog):
+            if req.request_id in pending:
+                pending.discard(req.request_id)
+                self._backlog.remove(req)
+                _finish_cancel(req, None)
         if pending:
             kept: List[Request] = []
             while True:
@@ -3692,10 +3490,9 @@ class LLMEngine:
             except queue.Empty:
                 break
             self._preempt_request(req, None)
-        if self._paged:
-            for req in list(self._backlog):
-                self._backlog.remove(req)
-                self._preempt_request(req, None)
+        for req in list(self._backlog):
+            self._backlog.remove(req)
+            self._preempt_request(req, None)
         if not self._drain_evict.is_set():
             return
         for st in list(self._prefilling):
@@ -3731,9 +3528,9 @@ class LLMEngine:
                 f"({self._state_bytes_per_slot} bytes a slot) that no "
                 "page carries, so a migrated prefix could not be "
                 "resumed")
-        if not self._paged or self._prefix is None:
+        if self._prefix is None:
             raise RuntimeError(
-                "KV migration requires the paged engine with "
+                "KV migration requires "
                 "EngineConfig.prefix_cache=True (transfers are keyed "
                 "by the prefix trie's chained path hashes)")
         if self._stopped.is_set():
@@ -4064,9 +3861,8 @@ class LLMEngine:
             err.__cause__ = e
             failing = list(self._slot_req.values())
             failing += list(self._admitting)
-            if self._paged:
-                failing += list(self._backlog)
-                failing += [st["req"] for st in self._prefilling]
+            failing += list(self._backlog)
+            failing += [st["req"] for st in self._prefilling]
             while True:
                 try:
                     failing.append(self._waiting.get_nowait())
@@ -4141,7 +3937,7 @@ class LLMEngine:
             self._process_drain()
             self._process_migrations()
             self._process_audits()
-            backlog = self._paged and (self._backlog or self._prefilling)
+            backlog = self._backlog or self._prefilling
             idle = (not self._slot_req and self._waiting.empty()
                     and not backlog and self._unprocessed == 0)
             if idle:

@@ -86,8 +86,7 @@ class RequestRecord:
     state_ts: Dict[str, float] = dataclasses.field(default_factory=dict)
     prompt_tokens: int = 0
     generated_tokens: int = 0
-    # Slot/page assignment: None until admitted; num_pages stays None on
-    # the non-paged (slot-cache) engine — absent, not zero.
+    # Slot/page assignment: None until admitted (absent, not zero).
     slot: Optional[int] = None
     num_pages: Optional[int] = None
     terminal_cause: Optional[str] = None

@@ -254,22 +254,18 @@ def test_adapter_id_in_request_rows_and_cli(params):
 
 
 def _slow_lora_adapter_factory(cfg):
-    """Paged LoRA adapter with throttled steps so a 12-token stream
-    spans an observable window and the kill reliably lands mid-decode.
-    The sleep rides jax.debug.callback: the steps are traced under
-    jit, so a bare time.sleep would only fire at trace time."""
+    """Paged LoRA adapter with a throttled step (with and without
+    ``lora=``) so a 12-token stream spans an observable window and the
+    kill reliably lands mid-decode.  The sleep rides jax.debug.callback:
+    the step is traced under jit, so a bare time.sleep would only fire
+    at trace time."""
     base = llama_paged_adapter(cfg)
 
     def slow_step(*args, **kwargs):
         jax.debug.callback(lambda: time.sleep(0.03), ordered=True)
         return base.ragged_step(*args, **kwargs)
 
-    def slow_step_lora(*args, **kwargs):
-        jax.debug.callback(lambda: time.sleep(0.03), ordered=True)
-        return base.ragged_step_lora(*args, **kwargs)
-
-    return dataclasses.replace(base, ragged_step=slow_step,
-                               ragged_step_lora=slow_step_lora)
+    return dataclasses.replace(base, ragged_step=slow_step)
 
 
 def test_midstream_kill_reresolves_adapter_on_survivor(params):

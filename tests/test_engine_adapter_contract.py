@@ -1,0 +1,110 @@
+"""What ``LLMEngine`` may assume of a model: the seam is
+``PagedEngineAdapter`` with ONE step plug.  The same contract is held
+to every adapter in the tree (llama, llama with LoRA, Jamba), so a new
+model family knows what it has to provide."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import jamba, llama
+from ray_tpu.ops import segmented_lora
+from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
+from ray_tpu.serve.llm_engine import (
+    EngineConfig,
+    LLMEngine,
+    jamba_paged_adapter,
+    llama_paged_adapter,
+)
+
+LLAMA = llama.LlamaConfig(
+    vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
+    mlp_dim=64, max_seq_len=64, remat=False, dtype=jnp.float32,
+    param_dtype=jnp.float32)
+LLAMA_LORA = dataclasses.replace(
+    LLAMA, lora=segmented_lora.LoRAConfig(rank=4, alpha=8.0))
+JAMBA = jamba.JambaConfig(
+    vocab_size=97, dim=64, n_layers=4, n_heads=4, n_kv_heads=1, head_dim=16,
+    mlp_dim=96, attn_layer_period=3, attn_layer_offset=1, dt_rank=8,
+    dtype=jnp.float32, param_dtype=jnp.float32)
+PAGE, SLOTS, MAXP, BUDGET = 8, 4, 4, 24
+TABLE = np.arange(SLOTS * MAXP, dtype=np.int32).reshape(SLOTS, MAXP)
+ROWS = [{"slot": 2, "start": 0, "tokens": [5, 9, 2, 7, 1, 3]},
+        {"slot": 0, "start": 0, "tokens": [4, 8]}]
+
+# name: (adapter factory, model config, init_params, keywords its step takes)
+CASES = {
+    "llama": (llama_paged_adapter, LLAMA, llama.init_params,
+              {"logit_idx"}),
+    "llama_lora": (llama_paged_adapter, LLAMA_LORA, llama.init_params,
+                   {"lora", "logit_idx"}),
+    "jamba": (jamba_paged_adapter, JAMBA, jamba.init_params, set()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_ragged_step_is_the_one_step_plug(case):
+    make, cfg, init_params, takes = CASES[case]
+    adapter = make(cfg)
+    params = init_params(jax.random.key(0), cfg)
+    # one step field; what an adapter does not serve is absent, not a stub
+    step_fields = {f.name for f in dataclasses.fields(adapter)
+                   if f.name.startswith("ragged_step")}
+    assert step_fields == {"ragged_step"}
+    if adapter.state_bytes_per_slot:
+        assert adapter.prefill_slot is None and adapter.decode_slots is None
+        cache = adapter.init_cache(SLOTS * MAXP, PAGE, SLOTS)
+    else:
+        cache = adapter.init_cache(SLOTS * MAXP, PAGE)
+    (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off) = \
+        pack_ragged_batch(ROWS, BUDGET, SLOTS)
+    nine = (params, toks, pos, r_slot, r_start, r_len, r_off, TABLE, cache)
+
+    logits, new_cache = adapter.ragged_step(*nine)
+    assert logits.shape == (SLOTS, cfg.vocab_size)
+    assert logits.dtype == jnp.float32
+    assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
+    assert np.isfinite(np.asarray(logits[:len(ROWS)])).all()
+
+    idx = np.asarray([0, 1, 2, 6, 7], np.int32)
+    if "lora" in takes:
+        pool = adapter.make_adapter_pool(EngineConfig(max_slots=SLOTS))
+        # every token on the null adapter: the pool's zero scratch page
+        lora = (pool.device_pool, pool.page_table([]),
+                np.zeros((BUDGET,), np.int32))
+        with_lora, _ = adapter.ragged_step(*nine, lora=lora)
+        np.testing.assert_array_equal(np.asarray(with_lora),
+                                      np.asarray(logits))
+    else:
+        assert adapter.make_adapter_pool is None
+    if "logit_idx" in takes:
+        row, verify, _ = adapter.ragged_step(*nine, logit_idx=idx)
+        np.testing.assert_array_equal(np.asarray(row), np.asarray(logits))
+        assert verify.shape == (len(idx), cfg.vocab_size)
+        # the last token of row 0 sits at flat position 5, of row 1 at 7
+        np.testing.assert_array_equal(np.asarray(verify[4]),
+                                      np.asarray(logits[1]))
+    for name in sorted({"lora", "logit_idx"} - takes):
+        value = idx if name == "logit_idx" else (None, None, None)
+        with pytest.raises((TypeError, ValueError), match=name):
+            adapter.ragged_step(*nine, **{name: value})
+
+
+@pytest.mark.parametrize("field", ["prefill_slot", "decode_slots"])
+def test_two_program_path_names_the_field_it_misses(field):
+    params = llama.init_params(jax.random.key(0), LLAMA)
+    adapter = dataclasses.replace(llama_paged_adapter(LLAMA),
+                                  **{field: None})
+    with pytest.raises(ValueError, match=f"PagedEngineAdapter.{field}"):
+        LLMEngine(params, adapter, EngineConfig(
+            max_slots=2, max_seq_len=64, page_size=PAGE))
+    # the ragged step needs neither
+    eng = LLMEngine(params, adapter, EngineConfig(
+        max_slots=2, max_seq_len=64, page_size=PAGE, ragged_batching=True))
+    try:
+        assert len(eng.generate([1, 2, 3], max_new_tokens=3)) == 3
+    finally:
+        eng.shutdown()

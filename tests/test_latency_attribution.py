@@ -46,7 +46,6 @@ from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
     LLMServer,
-    llama_adapter,
     llama_paged_adapter,
 )
 from ray_tpu.util import flight_recorder
@@ -96,7 +95,7 @@ def unified(params):
     engine is cold, so the first stream's prefill phase overlaps the
     serve.prefill / serve.decode compile windows."""
     eng = LLMEngine(
-        params, llama_adapter(CFG),
+        params, llama_paged_adapter(CFG),
         EngineConfig(max_slots=4, max_seq_len=64, min_prefill_bucket=16),
     )
     streams = [eng.submit(p, max_new_tokens=N_NEW, temperature=0.0)
@@ -326,7 +325,7 @@ def _slow_adapter_factory(cfg):
     bare sleep would fire at trace time only) so every stream spans a
     few row-federation cadences (~1 s) and the kill lands mid-decode
     with the victim's DECODING row already on the driver."""
-    base = llama_adapter(cfg)
+    base = llama_paged_adapter(cfg)
 
     def slow_decode(*args, **kwargs):
         jax.debug.callback(lambda: time.sleep(0.2), ordered=True)

@@ -63,27 +63,44 @@ def test_loss_and_grads():
     assert bool(jnp.isfinite(gnorm)) and float(gnorm) > 0
 
 
-def test_prefill_decode_matches_forward():
-    """Greedy decode via KV cache must match full-forward argmax."""
+def test_ragged_step_matches_forward():
+    """The serving step over a paged cache against the cache-free
+    oracle (tests/oracle.py): the logits after each prompt's last token,
+    and after one more token read back through the pages, equal
+    ``llama.forward``'s over the whole sequence."""
+    from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
+    from tests import oracle
+
     cfg = LLAMA_TINY
     params = llama.init_params(jax.random.key(0), cfg)
-    B, S = 2, 8
-    tokens = jax.random.randint(jax.random.key(2), (B, S), 0, cfg.vocab_size)
+    B, S, page = 2, 8, 8
+    seqs = np.asarray(jax.random.randint(
+        jax.random.key(2), (B, S), 0, cfg.vocab_size)).tolist()
+    cache = llama.init_paged_cache(cfg, num_pages=2 * B, page_size=page)
+    table = np.arange(2 * B, dtype=np.int32).reshape(B, 2)
 
-    cache = llama.init_kv_cache(cfg, B, max_len=32)
-    logits_pf, cache = llama.prefill(params, tokens, cfg, cache)
-    full = llama.forward(params, tokens, cfg)
-    np.testing.assert_allclose(np.asarray(logits_pf), np.asarray(full[:, -1]),
-                               rtol=2e-2, atol=2e-2)
+    def step(rows, cache):
+        (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off) = \
+            pack_ragged_batch(rows, B * S, B)
+        return llama.ragged_step_paged(params, toks, pos, r_slot, r_start,
+                                       r_len, r_off, table, cfg, cache)
 
-    # one decode step == forward over the extended sequence
-    nxt = jnp.argmax(logits_pf, axis=-1).astype(tokens.dtype)
-    logits_dec, cache = llama.decode_step(params, nxt, cfg, cache)
-    ext = jnp.concatenate([tokens, nxt[:, None]], axis=1)
-    full2 = llama.forward(params, ext, cfg)
-    np.testing.assert_allclose(np.asarray(logits_dec), np.asarray(full2[:, -1]),
-                               rtol=2e-2, atol=2e-2)
-    assert np.asarray(cache["length"]).tolist() == [S + 1] * B
+    def check(logits):
+        for b in range(B):
+            np.testing.assert_allclose(
+                np.asarray(logits[b]),
+                oracle.next_token_logits(params, cfg, seqs[b]),
+                rtol=2e-2, atol=2e-2)
+
+    logits, cache = step([{"slot": b, "start": 0, "tokens": seqs[b]}
+                          for b in range(B)], cache)
+    check(logits)
+    # one decode row each == forward over the extended sequence
+    for b in range(B):
+        seqs[b].append(int(np.argmax(np.asarray(logits[b]))))
+    logits, cache = step([{"slot": b, "start": S, "tokens": seqs[b][S:]}
+                          for b in range(B)], cache)
+    check(logits)
 
 
 def test_sharded_forward_on_mesh(cpu_devices):

@@ -11,7 +11,6 @@ import threading
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -20,8 +19,9 @@ from ray_tpu.serve.llm_engine import (
     CompletionStream,
     EngineConfig,
     LLMEngine,
-    llama_adapter,
+    llama_paged_adapter,
 )
+from tests import oracle
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -36,20 +36,13 @@ def params():
 
 def greedy_reference(params, prompt, n_tokens):
     """Oracle: argmax decoding by recomputing the full prefix each step."""
-    toks = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = llama.forward(params, jnp.asarray([toks]), CFG)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+    return oracle.greedy_tokens(params, CFG, prompt, n_tokens)
 
 
 @pytest.fixture(scope="module")
 def engine(params):
     eng = LLMEngine(
-        params, llama_adapter(CFG),
+        params, llama_paged_adapter(CFG),
         EngineConfig(max_slots=4, max_seq_len=128, min_prefill_bucket=16),
     )
     yield eng
@@ -107,7 +100,7 @@ def test_sampling_respects_temperature(engine):
 
 def test_max_seq_len_stops_generation(params):
     eng = LLMEngine(
-        params, llama_adapter(CFG),
+        params, llama_paged_adapter(CFG),
         EngineConfig(max_slots=2, max_seq_len=32, min_prefill_bucket=16),
     )
     try:
@@ -147,6 +140,25 @@ def test_serve_llm_deployment(params):
         ray_tpu.shutdown()
 
 
+def test_llm_server_default_adapter_is_paged(params):
+    """``LLMServer`` with no ``adapter_factory`` serves through pages:
+    the engine reports its page pool, and the tokens are the cache-free
+    oracle's."""
+    from ray_tpu.serve.llm_engine import LLMServer
+
+    server = LLMServer(
+        CFG, EngineConfig(max_slots=2, max_seq_len=128, page_size=16,
+                          ragged_batching=True),
+        lambda: params)
+    try:
+        assert server.engine.adapter.ragged_step is not None
+        assert server.stats()["kv_pages_free"] == 2 * (128 // 16)
+        out = server({"tokens": [1, 5, 9, 2, 7], "max_new_tokens": 6})
+        assert out["tokens"] == greedy_reference(params, [1, 5, 9, 2, 7], 6)
+    finally:
+        server.engine.shutdown()
+
+
 def test_drain_preempts_with_resumable_continuation(params):
     """drain(): short grace, then eviction with a PreemptedError whose
     continuation (prompt + generated prefix) resumes on a second engine
@@ -157,7 +169,7 @@ def test_drain_preempts_with_resumable_continuation(params):
 
     from ray_tpu.core.exceptions import PreemptedError
 
-    base = llama_adapter(CFG)
+    base = llama_paged_adapter(CFG)
 
     def slow_decode(*a, **k):
         # decode_slots is traced under jit: the sleep must ride a
@@ -172,7 +184,7 @@ def test_drain_preempts_with_resumable_continuation(params):
     ecfg = EngineConfig(max_slots=2, max_seq_len=128, min_prefill_bucket=16,
                         decode_chunk=1)
     eng = LLMEngine(params, slow, ecfg)
-    eng2 = LLMEngine(params, llama_adapter(CFG), ecfg)
+    eng2 = LLMEngine(params, llama_paged_adapter(CFG), ecfg)
     try:
         want = eng2.generate([1, 2, 3], max_new_tokens=12, temperature=0.0)
         stream = eng.submit([1, 2, 3], max_new_tokens=12, temperature=0.0)
@@ -208,7 +220,7 @@ def test_drain_preempts_with_resumable_continuation(params):
 
 def test_drain_idle_engine_is_immediate(params):
     eng = LLMEngine(
-        params, llama_adapter(CFG),
+        params, llama_paged_adapter(CFG),
         EngineConfig(max_slots=2, max_seq_len=128, min_prefill_bucket=16),
     )
     try:

@@ -5,7 +5,7 @@ reference counterpart — the reference's serve layer runs user torch
 code; PAPERS.md ragged paged attention is the pattern source).  Kernel
 checked against a dense gather reference; the llama paged pipeline
 (prefill into pages → scattered decode writes → paged attention) is
-checked step-by-step against the dense-cache decode path.
+checked step-by-step against the cache-free oracle (tests/oracle.py).
 """
 
 import dataclasses
@@ -17,6 +17,7 @@ import pytest
 
 from ray_tpu.models import llama
 from ray_tpu.ops import paged_attention as pa
+from tests import oracle
 
 
 def test_kernel_matches_reference_ragged():
@@ -62,6 +63,8 @@ def tiny_cfg():
 
 
 def test_llama_paged_matches_dense(tiny_cfg):
+    """Prefill into pages, then scattered decode writes, logits against
+    ``llama.forward`` over each slot's whole sequence."""
     cfg = tiny_cfg
     page, slots, maxp = 64, 2, 4
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
@@ -69,44 +72,41 @@ def test_llama_paged_matches_dense(tiny_cfg):
     prompt_lens = [37, 64]
     bucket = 64
 
-    dense = llama.init_kv_cache(cfg, slots, cfg.max_seq_len)
     paged = llama.init_paged_cache(cfg, num_pages=slots * maxp,
                                    page_size=page)
     # Slot s owns pages [s*maxp, (s+1)*maxp).
     bt = np.arange(slots * maxp, dtype=np.int32).reshape(slots, maxp)
     lengths = np.zeros((slots,), np.int32)
 
-    last_logits = {}
+    seqs = []
     for s, plen in enumerate(prompt_lens):
         toks = np.zeros((bucket,), np.int32)
         toks[:plen] = rng.integers(0, cfg.vocab_size, plen)
-        jt = jnp.asarray(toks)
-        lg_d, dense = llama.prefill_slot(
-            params, jt, jnp.int32(plen), jnp.int32(s), cfg, dense)
+        seqs.append(toks[:plen].tolist())
         lg_p, paged = llama.prefill_slot_paged(
-            params, jt, jnp.int32(plen), jnp.asarray(bt[s][: bucket // page]),
-            cfg, paged)
-        np.testing.assert_allclose(np.asarray(lg_d), np.asarray(lg_p),
-                                   atol=1e-4, rtol=1e-4)
-        last_logits[s] = np.asarray(lg_p)
+            params, jnp.asarray(toks), jnp.int32(plen),
+            jnp.asarray(bt[s][: bucket // page]), cfg, paged)
+        np.testing.assert_allclose(
+            oracle.next_token_logits(params, cfg, seqs[s]),
+            np.asarray(lg_p), atol=1e-4, rtol=1e-4)
+        seqs[s].append(int(np.argmax(np.asarray(lg_p))))
         lengths[s] = plen
-    dense["length"] = jnp.asarray(lengths)
 
-    cur = np.array([int(np.argmax(last_logits[s])) for s in range(slots)],
-                   np.int32)
     active = jnp.ones((slots,), bool)
     for step in range(6):
-        lg_d, dense = llama.decode_slots(
-            params, jnp.asarray(cur), active, cfg, dense)
+        cur = np.array([seq[-1] for seq in seqs], np.int32)
         lg_p, paged, new_len = llama.decode_slots_paged(
             params, jnp.asarray(cur), active, jnp.asarray(bt),
             jnp.asarray(lengths), cfg, paged)
-        np.testing.assert_allclose(np.asarray(lg_d), np.asarray(lg_p),
+        lg_o = np.stack([oracle.next_token_logits(params, cfg, seq)
+                         for seq in seqs])
+        np.testing.assert_allclose(lg_o, np.asarray(lg_p),
                                    atol=1e-3, rtol=1e-3)
-        toks_d = np.argmax(np.asarray(lg_d), -1)
+        toks_o = np.argmax(lg_o, -1)
         toks_p = np.argmax(np.asarray(lg_p), -1)
-        assert (toks_d == toks_p).all(), f"step {step} diverged"
-        cur = toks_p.astype(np.int32)
+        assert (toks_o == toks_p).all(), f"step {step} diverged"
+        for seq, tok in zip(seqs, toks_p):
+            seq.append(int(tok))
         lengths = np.asarray(new_len)
 
 
@@ -136,12 +136,11 @@ def test_llama_paged_inactive_slot_isolated(tiny_cfg):
 
 
 def test_engine_paged_matches_dense(tiny_cfg):
-    """End-to-end: the paged engine generates the same greedy tokens as
-    the dense-cache engine."""
+    """End-to-end: the paged engine generates the oracle's greedy
+    tokens."""
     from ray_tpu.serve.llm_engine import (
         EngineConfig,
         LLMEngine,
-        llama_adapter,
         llama_paged_adapter,
     )
 
@@ -153,13 +152,11 @@ def test_engine_paged_matches_dense(tiny_cfg):
     ec = EngineConfig(max_slots=2, max_seq_len=128, decode_chunk=4,
                       max_new_tokens_default=6, min_prefill_bucket=64,
                       page_size=64)
-    dense = LLMEngine(params, llama_adapter(cfg), ec)
-    outs_d = [dense.generate(p) for p in prompts]
-    dense.shutdown()
     paged = LLMEngine(params, llama_paged_adapter(cfg), ec)
     outs_p = [paged.generate(p) for p in prompts]
     paged.shutdown()
-    assert outs_d == outs_p
+    assert outs_p == [oracle.greedy_tokens(params, cfg, p, 6)
+                      for p in prompts]
 
 
 def test_engine_paged_under_page_pressure(tiny_cfg):
@@ -194,7 +191,6 @@ def test_engine_paged_short_prompt(tiny_cfg):
     from ray_tpu.serve.llm_engine import (
         EngineConfig,
         LLMEngine,
-        llama_adapter,
         llama_paged_adapter,
     )
 
@@ -205,13 +201,10 @@ def test_engine_paged_short_prompt(tiny_cfg):
     ec = EngineConfig(max_slots=2, max_seq_len=128, decode_chunk=4,
                       max_new_tokens_default=6, min_prefill_bucket=16,
                       page_size=64)
-    dense = LLMEngine(params, llama_adapter(cfg), ec)
-    want = dense.generate(prompt)
-    dense.shutdown()
     paged = LLMEngine(params, llama_paged_adapter(cfg), ec)
     got = paged.generate(prompt)
     paged.shutdown()
-    assert got == want
+    assert got == oracle.greedy_tokens(params, cfg, prompt, 6)
 
 
 def test_engine_paged_backlog_drains_without_new_submits(tiny_cfg):

@@ -20,6 +20,7 @@ resumed from the survivor's cached prefix instead of re-prefilling
 from token 0.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -33,7 +34,6 @@ from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
     LLMServer,
-    llama_adapter,
     llama_paged_adapter,
 )
 from ray_tpu.serve.prefix_index import (
@@ -549,6 +549,8 @@ def test_prefix_cache_requires_ragged_paged(params):
         LLMEngine(params, llama_paged_adapter(CFG), EngineConfig(
             max_slots=2, max_seq_len=128, page_size=PAGE,
             prefix_cache=True))
-    with pytest.raises(ValueError, match="paged"):
-        LLMEngine(params, llama_adapter(CFG), EngineConfig(
-            max_slots=2, max_seq_len=128, prefix_cache=True))
+    with pytest.raises(ValueError, match="copy_page"):
+        LLMEngine(params, dataclasses.replace(
+            llama_paged_adapter(CFG), copy_page=None), EngineConfig(
+            max_slots=2, max_seq_len=128, page_size=PAGE,
+            ragged_batching=True, prefix_cache=True))

@@ -14,6 +14,7 @@ rows (decode packs FIRST, so prompt tokens can never displace it), and
 the PR-2 stall telemetry watermark must stay clean.
 """
 
+import dataclasses
 import time
 
 import jax
@@ -25,9 +26,9 @@ from ray_tpu.models import llama
 from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
-    llama_adapter,
     llama_paged_adapter,
 )
+from tests import oracle
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -42,14 +43,7 @@ def params():
 
 
 def greedy_reference(params, prompt, n_tokens):
-    toks = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = llama.forward(params, jnp.asarray([toks]), CFG)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+    return oracle.greedy_tokens(params, CFG, prompt, n_tokens)
 
 
 def _engine(params, **kw):
@@ -196,9 +190,10 @@ def test_ragged_unlocks_int8_kv_with_chunked_prefill(params):
         eng.shutdown()
 
 
-def test_ragged_requires_paged_adapter_and_sane_budget(params):
+def test_ragged_requires_ragged_step_and_sane_budget(params):
     with pytest.raises(ValueError, match="ragged"):
-        LLMEngine(params, llama_adapter(CFG), EngineConfig(
+        LLMEngine(params, dataclasses.replace(
+            llama_paged_adapter(CFG), ragged_step=None), EngineConfig(
             max_slots=2, max_seq_len=128, ragged_batching=True))
     with pytest.raises(ValueError, match="token_budget"):
         LLMEngine(params, llama_paged_adapter(CFG), EngineConfig(

@@ -22,7 +22,6 @@ from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
     PagedEngineAdapter,
-    llama_adapter,
     llama_paged_adapter,
 )
 from ray_tpu.util import metrics, state
@@ -328,10 +327,10 @@ def test_cancel_releases_slot_and_pages(params):
 
 def test_cancel_queued_request_never_ran(params):
     """A request cancelled while still queued reaches CANCELLED without
-    ever fabricating PREFILLING/DECODING stamps — and on the non-paged
-    engine num_pages stays absent (None), not zero."""
+    ever fabricating PREFILLING/DECODING stamps or a page count (absent,
+    not zero); the request that ran records the pages it held."""
     reqev.clear()
-    eng = LLMEngine(params, llama_adapter(CFG), EngineConfig(
+    eng = LLMEngine(params, llama_paged_adapter(CFG), EngineConfig(
         max_slots=1, max_seq_len=128, min_prefill_bucket=16,
     ))
     try:
@@ -356,7 +355,7 @@ def test_cancel_queued_request_never_ran(params):
         assert running["state"] == "CANCELLED"
         assert "DECODING" in running["state_ts"]
         assert running["ttft_s"] is not None
-        assert running["num_pages"] is None  # non-paged engine
+        assert running["num_pages"] == 2  # 128 tokens of 64-token pages
         # Cancel is idempotent: unknown/terminal ids are a no-op.
         eng.cancel("starved")
         eng.cancel("no-such-request")

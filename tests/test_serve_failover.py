@@ -35,7 +35,11 @@ from ray_tpu import serve
 from ray_tpu.core import api
 from ray_tpu.models import llama
 from ray_tpu.serve import request_events
-from ray_tpu.serve.llm_engine import EngineConfig, LLMServer, llama_adapter
+from ray_tpu.serve.llm_engine import (
+    EngineConfig,
+    LLMServer,
+    llama_paged_adapter,
+)
 from ray_tpu.utils.test_utils import ReplicaKiller
 
 CFG = llama.LlamaConfig(
@@ -82,7 +86,7 @@ def _slow_adapter_factory(cfg):
     reliably lands mid-decode.  The sleep rides a jax.debug.callback:
     decode_slots is traced under jit, so a bare time.sleep would only
     fire at trace time."""
-    base = llama_adapter(cfg)
+    base = llama_paged_adapter(cfg)
 
     def slow_decode(*args, **kwargs):
         jax.debug.callback(lambda: time.sleep(0.03), ordered=True)

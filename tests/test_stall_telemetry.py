@@ -36,7 +36,7 @@ class _FakeTime:
         return self.t
 
 
-def _shim(paged=True):
+def _shim():
     """A bare object carrying just the state _admission_queue_age
     touches, so the helper is unit-testable without building an
     engine."""
@@ -44,7 +44,6 @@ def _shim(paged=True):
     ns._slot_req = {}
     ns._waiting = queue.Queue()
     ns._backlog = []
-    ns._paged = paged
     return ns
 
 
@@ -117,8 +116,8 @@ def test_admission_queue_age():
     ns._backlog.append(types.SimpleNamespace(submitted_at=now - 5.0))
     age = LLMEngine._admission_queue_age(ns)
     assert 4.9 < age < 6.0  # the backlog request is the oldest
-    # Non-paged engines have no backlog to scan.
-    ns2 = _shim(paged=False)
+    # An empty backlog leaves the waiting queue's oldest.
+    ns2 = _shim()
     ns2._waiting.put(types.SimpleNamespace(submitted_at=now - 1.0))
     assert 0.9 < LLMEngine._admission_queue_age(ns2) < 2.0
 

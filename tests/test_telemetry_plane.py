@@ -18,7 +18,11 @@ import ray_tpu
 from ray_tpu import data as rd
 from ray_tpu import serve
 from ray_tpu.models import llama
-from ray_tpu.serve.llm_engine import EngineConfig, LLMEngine, llama_adapter
+from ray_tpu.serve.llm_engine import (
+    EngineConfig,
+    LLMEngine,
+    llama_paged_adapter,
+)
 from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 from ray_tpu.parallel import MeshSpec
 from ray_tpu.util import metrics, tracing, xprof
@@ -62,7 +66,7 @@ def _run_serve_request():
 def _run_engine_request():
     params = llama.init_params(jax.random.key(0), CFG)
     eng = LLMEngine(
-        params, llama_adapter(CFG),
+        params, llama_paged_adapter(CFG),
         EngineConfig(max_slots=2, max_seq_len=128, min_prefill_bucket=16),
     )
     try:
