@@ -463,25 +463,43 @@ def _kernels_ragged(d: KernelDims) -> None:
         jnp.int32(layer), *meta),
         reference(k8, v8, k_scales=k_sc[layer], v_scales=v_sc[layer]), 2e-2)
 
-    got_k, got_v = jax.jit(rpa.ragged_paged_append)(
-        k_pools, v_pools, k_new, v_new, *meta)
-    for li in range(d.layers):
-        want_k, want_v = jax.jit(rpa.ragged_append_reference)(
-            k_pools[li], v_pools[li], k_new[li], v_new[li], *meta)
-        # the last physical page is scratch: padding writes land there
-        check_close(f"ragged_paged_append.k[layer {li}]",
-                    got_k[li][:, :-1], want_k[:, :-1], 0.0)
-        check_close(f"ragged_paged_append.v[layer {li}]",
-                    got_v[li][:, :-1], want_v[:, :-1], 0.0)
-    got = jax.jit(rpa.ragged_paged_append_quantized)(
-        k8, v8, k_sc, v_sc, k_new, v_new, *meta)
-    places = []
-    for i in range(len(rows)):
-        pos = r_start[i] + np.arange(r_len[i])
-        places.append((r_off[i] + np.arange(r_len[i]),
-                       bt[r_slot[i], pos // page], pos % page))
-    _check_int8_rows("ragged_paged_append_quantized", got, (k_new, v_new),
-                     places)
+    # The append as packed, then with a padding row between live ones,
+    # then with no live row at all (its grid has no cell to walk): pages
+    # no row writes keep their bits, and their scales.
+    gap = np.where(np.arange(R) == 1, 0, r_len)
+    append = jax.jit(rpa.ragged_paged_append)
+    scatter = jax.jit(rpa.ragged_append_reference)
+    append_q = jax.jit(rpa.ragged_paged_append_quantized)
+    state8 = (k8, v8, k_sc, v_sc)
+    for case, lens in (("", r_len), ("[padding between]", gap),
+                       ("[no live row]", np.zeros_like(r_len))):
+        m = (r_slot, r_start, lens, r_off, bt)
+        got_k, got_v = append(k_pools, v_pools, k_new, v_new, *m)
+        for li in range(d.layers):
+            want_k, want_v = scatter(
+                k_pools[li], v_pools[li], k_new[li], v_new[li], *m)
+            # the last physical page is scratch: padding writes land there
+            check_close(f"ragged_paged_append{case}.k[layer {li}]",
+                        got_k[li][:, :-1], want_k[:, :-1], 0.0)
+            check_close(f"ragged_paged_append{case}.v[layer {li}]",
+                        got_v[li][:, :-1], want_v[:, :-1], 0.0)
+        got = append_q(*state8, k_new, v_new, *m)
+        places = []
+        for i in np.flatnonzero(lens):
+            pos = r_start[i] + np.arange(lens[i])
+            places.append((r_off[i] + np.arange(lens[i]),
+                           bt[r_slot[i], pos // page], pos % page))
+        _check_int8_rows(f"ragged_paged_append_quantized{case}", got,
+                         (k_new, v_new), places)
+        kept = np.setdiff1d(np.arange(k8.shape[2] - 1),
+                            [p for _, pid, _ in places for p in pid])
+        for which, g, before in zip(("k", "v", "k_scale", "v_scale"), got,
+                                    state8):
+            axis = 2 if g.ndim == 5 else 1        # pools, scales by page
+            check_close(
+                f"ragged_paged_append_quantized{case}.{which}[unwritten]",
+                jnp.take(g, kept, axis=axis),
+                jnp.take(before, kept, axis=axis), 0.0)
 
 
 def _kernels_ssd(d: KernelDims) -> None:

@@ -23,7 +23,12 @@ ONE aliased append per step writes every layer's new rows into the
 pages (``ragged_paged_append*``), preserving the deferred-append
 contract of models/llama.decode_slots_paged: pools are STRICTLY
 read-only inside the layer scan (in-loop pool mutation made XLA clone
-the multi-GB pools), and the append kernels alias in place.
+the multi-GB pools), and the append kernels alias in place.  The append
+walks, layer by layer, a list of the pages the step's fresh tokens land
+in (``live_append_cells``) and its grid ends where the list ends: a
+decode row is one cell a layer and a padding row none, where the grid
+used to be ``max_slots x layers x pages-per-row`` whatever the rows
+held (PERF.md, PR 31).
 
 Kernel shape (mirrors ops/paged_attention.py's idioms):
 
@@ -48,13 +53,14 @@ so the fused path serves ragged batches too.  That phase walks a list
 of the cells that hold the step's rows (``live_page_cells``) and the
 grid ends where the list ends: a cell the rows do not reach is no grid
 step, where it used to cost 0.6 us in every layer (PERF.md, PR 28).
-It takes the STACKED
-layer tree and a layer index: the weights reach the kernel the way the
-KV pools do, whole, and each BlockSpec squeezes the layer axis and
-picks the layer from the scalar-prefetched index.  XLA cannot fuse a
-slice into a Pallas call's operand, so a slice taken in front of it is
-a copy of the layer's weights, every layer of every step (16.6 ms of a
-46 ms step at Mistral-7B int8 before PR 25; PERF.md).
+The append's grid follows a list of its own the same way.  The layer
+kernel takes the STACKED layer tree and a layer index: the weights
+reach the kernel the way the KV pools do, whole, and each BlockSpec
+squeezes the layer axis and picks the layer from the scalar-prefetched
+index.  XLA cannot fuse a slice into a Pallas call's operand, so a
+slice taken in front of it is a copy of the layer's weights, every
+layer of every step (16.6 ms of a 46 ms step at Mistral-7B int8 before
+PR 25; PERF.md).
 
 Interpret-mode (CPU) numerics are tier-1 tested against the unfused
 paged reference for fp32 / int8-weight / int8-KV
@@ -415,113 +421,205 @@ def _pages_per_row(max_row_tokens: int, page: int) -> int:
     return (max_row_tokens + page - 2) // page + 1
 
 
+def _listed(live: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The set cells of a boolean table as a scalar-prefetch list: their
+    flat indices in ascending order ``int32[live.size]`` (past the count
+    it is padding) and the count ``int32[1]``."""
+    flat = live.reshape(-1)
+    (cells,) = jnp.nonzero(flat, size=flat.shape[0], fill_value=0)
+    return (cells.astype(jnp.int32),
+            jnp.sum(flat, dtype=jnp.int32).reshape(1))
+
+
+def live_append_cells(row_start: jax.Array, row_len: jax.Array, npr: int,
+                      page: int) -> Tuple[jax.Array, jax.Array]:
+    """The cells of the ragged append that write a page for these rows:
+    ``(live_ci, n_live)``.  Cell ``r * npr + j`` is the ``j``-th page
+    row ``r``'s fresh tokens touch, counted from the page its first one
+    lands in; it is live where the row has tokens and the page is not
+    past the one its last token lands in.  No two live cells name one
+    page.  Like ``live_page_cells`` it follows from the row arrays
+    alone, so it is the same in every layer."""
+    j = jnp.arange(npr, dtype=jnp.int32)
+    first = row_start // page
+    last = (row_start + row_len - 1) // page
+    return _listed((row_len[:, None] > 0)
+                   & (first[:, None] + j <= last[:, None]))
+
+
+def append_cell_count(row_start, row_len, page: int) -> int:
+    """``live_append_cells``' ``n_live`` on the host, from the packed
+    row arrays: the pages each live row's fresh tokens touch."""
+    start, nlen = np.asarray(row_start), np.asarray(row_len)
+    return int(np.sum((nlen > 0) * (
+        (start + nlen - 1) // page - start // page + 1)))
+
+
 def _ragged_append_kernel(*refs, T: int, Cq: int, KVH: int, page: int,
-                          Pt: int, maxp: int, quantized: bool):
+                          NPR: int, quantized: bool):
+    _slot_r, start_r, len_r, off_r, _bt_r, live_r, n_live_r = refs[:7]
     if quantized:
-        slot_r, start_r, len_r, off_r, bt_r = refs[:5]
         (kn_ref, vn_ref, kp_ref, vp_ref, ks_ref, vs_ref,
-         kp_out, vp_out, ks_out, vs_out) = refs[5:]
+         kp_out, vp_out, ks_out, vs_out) = refs[7:]
     else:
-        slot_r, start_r, len_r, off_r, bt_r = refs[:5]
-        kn_ref, vn_ref, kp_ref, vp_ref, kp_out, vp_out = refs[5:]
+        kn_ref, vn_ref, kp_ref, vp_ref, kp_out, vp_out = refs[7:]
 
-    r = pl.program_id(0)
-    j = pl.program_id(2)
-    start = start_r[r]
-    nt = len_r[r]
-    off = off_r[r]
-    w = jnp.minimum((off // 8) * 8, T - Cq)
-    w = pl.multiple_of(w, 8)
+    i = pl.program_id(1)
 
-    sp = start // page
-    pg = sp + j
-    base = pg * page
-    live = (base < start + nt) & (nt > 0)
-    rows_i = lax.broadcasted_iota(jnp.int32, (page, 1), 0)
-    tpage = base + rows_i - start          # token index landing here
-    mask_w = (tpage >= 0) & (tpage < nt) & live          # [page, 1]
-    cols = lax.broadcasted_iota(jnp.int32, (1, Cq), 1)
-    krel = w + cols - off                  # window col → token index
-    # one-hot gather: page row i takes window col c with token tpage[i]
-    oh = ((tpage == krel) & (krel >= 0) & (krel < nt)
-          & live).astype(jnp.float32)      # [page, Cq]
+    # i < n_live always holds under Mosaic, whose grid ends at n_live;
+    # the interpreter's grid is the capacity (see ``_ragged_append``).
+    @pl.when(i < n_live_r[0])
+    def _cell():
+        ci = live_r[i]
+        r = ci // NPR
+        j = ci % NPR
+        start = start_r[r]
+        nt = len_r[r]
+        off = off_r[r]
+        w = jnp.minimum((off // 8) * 8, T - Cq)
+        w = pl.multiple_of(w, 8)
 
-    for h in range(KVH):
-        kw = kn_ref[0, pl.ds(w, Cq), h, :].astype(jnp.float32)
-        vw = vn_ref[0, pl.ds(w, Cq), h, :].astype(jnp.float32)
-        newk = lax.dot_general(oh, kw, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-        newv = lax.dot_general(oh, vw, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-        curk = kp_ref[0, h, 0]
-        curv = vp_ref[0, h, 0]
-        if not quantized:
-            kp_out[0, h, 0] = jnp.where(
-                mask_w, newk, curk.astype(jnp.float32)).astype(
-                    kp_out.dtype)
-            vp_out[0, h, 0] = jnp.where(
-                mask_w, newv, curv.astype(jnp.float32)).astype(
-                    vp_out.dtype)
-            continue
-        # int8 pools: grow-only per-page-per-kv-head scale.  A page the
-        # row writes from offset 0 this step is FRESH (reset); a page
-        # extended past existing rows keeps old int8 values bit-stable
-        # unless the scale must grow (no cumulative requant error).
-        # Every per-page quantity below is a SCALAR (full reductions),
-        # not a [1, 1] vector: Mosaic splats a scalar over a
-        # [page, hd] tile but refuses to broadcast a [1, 1] vector in
-        # both sublanes and lanes.
-        wrote = jnp.max(mask_w.astype(jnp.float32)) > 0.0
-        fresh = (base >= start)
-        for (new, cur, sc_in, sc_out) in (
-                (newk, curk, ks_ref, ks_out),
-                (newv, curv, vs_ref, vs_out)):
-            s_old = jnp.sum(sc_in[0, 0, h:h + 1, 0:1].astype(jnp.float32))
-            amax = jnp.max(jnp.where(mask_w, jnp.abs(new), 0.0))
-            needed = jnp.maximum(amax / 127.0, 1e-8)
-            grown = jnp.where(fresh, needed,
-                              jnp.maximum(s_old, needed))
-            s_new = jnp.where(wrote, grown, jnp.maximum(s_old, 1e-8))
-            factor = jnp.where(fresh & wrote, 0.0,
-                               jnp.where(s_new > s_old,
-                                         s_old / s_new, 1.0))
-            requant = jnp.round(cur.astype(jnp.float32) * factor)
-            row_q = jnp.clip(jnp.round(new / s_new), -127, 127)
-            outp = jnp.where(mask_w, row_q, requant)
-            if new is newk:
-                kp_out[0, h, 0] = jnp.clip(outp, -127, 127).astype(
-                    kp_out.dtype)
-            else:
-                vp_out[0, h, 0] = jnp.clip(outp, -127, 127).astype(
-                    vp_out.dtype)
-            sc_out[0, 0, h:h + 1, 0:1] = jnp.full(
-                (1, 1), jnp.where(wrote, s_new, s_old), sc_out.dtype)
+        sp = start // page
+        pg = sp + j
+        base = pg * page
+        live = (base < start + nt) & (nt > 0)
+        rows_i = lax.broadcasted_iota(jnp.int32, (page, 1), 0)
+        tpage = base + rows_i - start          # token index landing here
+        mask_w = (tpage >= 0) & (tpage < nt) & live          # [page, 1]
+        cols = lax.broadcasted_iota(jnp.int32, (1, Cq), 1)
+        krel = w + cols - off                  # window col → token index
+        # one-hot gather: page row i takes window col c with token
+        # tpage[i]
+        oh = ((tpage == krel) & (krel >= 0) & (krel < nt)
+              & live).astype(jnp.float32)      # [page, Cq]
+
+        for h in range(KVH):
+            kw = kn_ref[0, pl.ds(w, Cq), h, :].astype(jnp.float32)
+            vw = vn_ref[0, pl.ds(w, Cq), h, :].astype(jnp.float32)
+            newk = lax.dot_general(oh, kw, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+            newv = lax.dot_general(oh, vw, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+            curk = kp_ref[0, h, 0]
+            curv = vp_ref[0, h, 0]
+            if not quantized:
+                kp_out[0, h, 0] = jnp.where(
+                    mask_w, newk, curk.astype(jnp.float32)).astype(
+                        kp_out.dtype)
+                vp_out[0, h, 0] = jnp.where(
+                    mask_w, newv, curv.astype(jnp.float32)).astype(
+                        vp_out.dtype)
+                continue
+            # int8 pools: grow-only per-page-per-kv-head scale.  A page
+            # the row writes from offset 0 this step is FRESH (reset); a
+            # page extended past existing rows keeps old int8 values
+            # bit-stable unless the scale must grow (no cumulative
+            # requant error).  Every per-page quantity below is a SCALAR
+            # (full reductions), not a [1, 1] vector: Mosaic splats a
+            # scalar over a [page, hd] tile but refuses to broadcast a
+            # [1, 1] vector in both sublanes and lanes.
+            wrote = jnp.max(mask_w.astype(jnp.float32)) > 0.0
+            fresh = (base >= start)
+            for (new, cur, sc_in, sc_out) in (
+                    (newk, curk, ks_ref, ks_out),
+                    (newv, curv, vs_ref, vs_out)):
+                s_old = jnp.sum(
+                    sc_in[0, 0, h:h + 1, 0:1].astype(jnp.float32))
+                amax = jnp.max(jnp.where(mask_w, jnp.abs(new), 0.0))
+                needed = jnp.maximum(amax / 127.0, 1e-8)
+                grown = jnp.where(fresh, needed,
+                                  jnp.maximum(s_old, needed))
+                s_new = jnp.where(wrote, grown, jnp.maximum(s_old, 1e-8))
+                factor = jnp.where(fresh & wrote, 0.0,
+                                   jnp.where(s_new > s_old,
+                                             s_old / s_new, 1.0))
+                requant = jnp.round(cur.astype(jnp.float32) * factor)
+                row_q = jnp.clip(jnp.round(new / s_new), -127, 127)
+                outp = jnp.where(mask_w, row_q, requant)
+                if new is newk:
+                    kp_out[0, h, 0] = jnp.clip(outp, -127, 127).astype(
+                        kp_out.dtype)
+                else:
+                    vp_out[0, h, 0] = jnp.clip(outp, -127, 127).astype(
+                        vp_out.dtype)
+                sc_out[0, 0, h:h + 1, 0:1] = jnp.full(
+                    (1, 1), jnp.where(wrote, s_new, s_old), sc_out.dtype)
 
 
-def _append_maps(page: int, Pt: int, maxp: int, NPR: int):
-    def pool_map(r, l, j, slot_p, start_p, len_p, off_p, bt, *sc):
-        s = slot_p[r]
+def _ragged_append(pools, scales, k_new, v_new, row_slot, row_start,
+                   row_len, row_off, block_tables,
+                   max_row_tokens: Optional[int]):
+    """The append behind ``ragged_paged_append`` (``scales`` empty) and
+    ``ragged_paged_append_quantized``: grid ``(L, n_live)`` over
+    ``live_append_cells``, the layer outermost so that a layer's fresh
+    rows are fetched once.  The bound follows the step's fresh tokens: a
+    decode row is one cell a layer, a chunk one cell a page it touches,
+    a padding row none, where the walk over ``(R, L, NPR)`` cost 2.6 us
+    a cell whatever the rows held (PERF.md, PR 31).  The Pallas
+    interpreter takes no dynamic grid bound, so there the grid keeps the
+    capacity ``R * NPR`` and the steps past the end do nothing: the same
+    body either way."""
+    L, KVH, Pt, page, D = pools[0].shape
+    T = k_new.shape[1]
+    R = row_slot.shape[0]
+    maxp = block_tables.shape[1]
+    T_p = _round8(T)
+    if T_p != T:
+        k_new = jnp.pad(k_new, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
+        v_new = jnp.pad(v_new, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
+    Cq = window_size(T_p, max_row_tokens)
+    NPR = _pages_per_row(Cq, page)
+    row_start = row_start.astype(jnp.int32)
+    row_len = row_len.astype(jnp.int32)
+    live_ci, n_live = live_append_cells(row_start, row_len, NPR, page)
+    prefetch = [row_slot.astype(jnp.int32), row_start, row_len,
+                row_off.astype(jnp.int32), block_tables.astype(jnp.int32),
+                live_ci, n_live]
+
+    def pool_map(l, i, slot_p, start_p, len_p, _off, bt, cells, nl):
+        ci = cells[i]
+        r = ci // NPR
         start = start_p[r]
         nt = len_p[r]
-        pg = start // page + j
+        pg = start // page + ci % NPR
         lastp = (start + jnp.maximum(nt, 1) - 1) // page
-        pe = jnp.minimum(jnp.minimum(pg, lastp), maxp - 1)
-        pid = jnp.minimum(bt[s, pe], Pt - 1)
-        # DEAD cells (padding rows, or j past the row's last touched
-        # page) must write the scratch page, never a live one: their
-        # aliased copy-through reads a stale input block (the previous
-        # cell's write is not visible through the alias) and would
-        # clobber a fresh append.  Scratch is garbage-tolerant.
-        live = (nt > 0) & (pg <= lastp)
+        pid = jnp.minimum(bt[slot_p[r], jnp.minimum(pg, maxp - 1)], Pt - 1)
+        # A cell that writes nothing (a step past the list's end, which
+        # only the interpreter has; a listed cell the rows do not reach,
+        # which ``live_append_cells`` does not list) must name the
+        # scratch page, never a live one: its aliased copy-through reads
+        # a stale input block (an earlier cell's write is not visible
+        # through the alias) and would clobber a fresh append.  Scratch
+        # is garbage-tolerant.
+        live = (i < nl[0]) & (nt > 0) & (pg <= lastp)
         return (l, 0, jnp.where(live, pid, Pt - 1), 0, 0)
 
-    def scale_map(r, l, j, slot_p, start_p, len_p, off_p, bt, *sc):
-        _, _, pid, _, _ = pool_map(r, l, j, slot_p, start_p, len_p,
-                                   off_p, bt)
-        return (l, pid, 0, 0)
+    def scale_map(l, i, *pf):
+        return (l, pool_map(l, i, *pf)[2], 0, 0)
 
-    new_map = lambda r, l, j, *pf: (l, 0, 0, 0)
-    return pool_map, scale_map, new_map
+    new_spec = pl.BlockSpec((1, T_p, KVH, D), lambda l, i, *pf: (l, 0, 0, 0))
+    state = list(pools) + list(scales)
+    state_specs = ([pl.BlockSpec((1, KVH, 1, page, D), pool_map)] * 2
+                   + [pl.BlockSpec((1, 1, KVH, 1), scale_map)] * len(scales))
+    interpret = platform.interpret_mode()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(L, R * NPR if interpret else n_live[0]),
+        in_specs=[new_spec, new_spec] + state_specs,
+        out_specs=state_specs,
+    )
+    kern = functools.partial(
+        _ragged_append_kernel, T=T_p, Cq=Cq, KVH=KVH, page=page, NPR=NPR,
+        quantized=bool(scales))
+    first = len(prefetch) + 2       # k_new and v_new sit in front
+    return pl.pallas_call(
+        kern,
+        name="ragged_kv_append",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state],
+        input_output_aliases={first + n: n for n in range(len(state))},
+        interpret=interpret,
+    )(*prefetch, k_new, v_new, *state)
 
 
 def ragged_paged_append(
@@ -536,49 +634,9 @@ def ragged_paged_append(
 ):
     """In-place append of every row's fresh tokens into its pages, all
     layers at once (aliased pools — same contract as paged_append)."""
-    L, KVH, Pt, page, D = k_pools.shape
-    T = k_new.shape[1]
-    R = row_slot.shape[0]
-    maxp = block_tables.shape[1]
-    T_p = _round8(T)
-    if T_p != T:
-        k_new = jnp.pad(k_new, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
-        v_new = jnp.pad(v_new, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
-    Cq = window_size(T_p, max_row_tokens)
-    NPR = _pages_per_row(Cq, page)
-    pool_map, _scale_map, new_map = _append_maps(page, Pt, maxp, NPR)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(R, L, NPR),
-        in_specs=[
-            pl.BlockSpec((1, T_p, KVH, D), new_map),
-            pl.BlockSpec((1, T_p, KVH, D), new_map),
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-        ],
-    )
-    kern = functools.partial(
-        _ragged_append_kernel, T=T_p, Cq=Cq, KVH=KVH, page=page, Pt=Pt,
-        maxp=maxp, quantized=False)
-    return pl.pallas_call(
-        kern,
-        name="ragged_kv_append",
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pools.shape, k_pools.dtype),
-            jax.ShapeDtypeStruct(v_pools.shape, v_pools.dtype),
-        ],
-        # prefetch: slot=0 start=1 len=2 off=3 bt=4, then kn=5 vn=6
-        # k_pools=7 v_pools=8
-        input_output_aliases={7: 0, 8: 1},
-        interpret=platform.interpret_mode(),
-    )(row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
-      row_len.astype(jnp.int32), row_off.astype(jnp.int32),
-      block_tables.astype(jnp.int32), k_new, v_new, k_pools, v_pools)
+    return _ragged_append(
+        (k_pools, v_pools), (), k_new, v_new, row_slot, row_start,
+        row_len, row_off, block_tables, max_row_tokens)
 
 
 def ragged_paged_append_quantized(
@@ -598,55 +656,9 @@ def ragged_paged_append_quantized(
     row's absmax demands it (existing int8 values stay bit-stable
     otherwise — the paged_append_quantized policy, per multi-token
     page)."""
-    L, KVH, Pt, page, D = k_pools.shape
-    T = k_new.shape[1]
-    R = row_slot.shape[0]
-    maxp = block_tables.shape[1]
-    T_p = _round8(T)
-    if T_p != T:
-        k_new = jnp.pad(k_new, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
-        v_new = jnp.pad(v_new, ((0, 0), (0, T_p - T), (0, 0), (0, 0)))
-    Cq = window_size(T_p, max_row_tokens)
-    NPR = _pages_per_row(Cq, page)
-    pool_map, scale_map, new_map = _append_maps(page, Pt, maxp, NPR)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(R, L, NPR),
-        in_specs=[
-            pl.BlockSpec((1, T_p, KVH, D), new_map),
-            pl.BlockSpec((1, T_p, KVH, D), new_map),
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-            pl.BlockSpec((1, 1, KVH, 1), scale_map),
-            pl.BlockSpec((1, 1, KVH, 1), scale_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-            pl.BlockSpec((1, KVH, 1, page, D), pool_map),
-            pl.BlockSpec((1, 1, KVH, 1), scale_map),
-            pl.BlockSpec((1, 1, KVH, 1), scale_map),
-        ],
-    )
-    kern = functools.partial(
-        _ragged_append_kernel, T=T_p, Cq=Cq, KVH=KVH, page=page, Pt=Pt,
-        maxp=maxp, quantized=True)
-    return pl.pallas_call(
-        kern,
-        name="ragged_kv_append",
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pools.shape, k_pools.dtype),
-            jax.ShapeDtypeStruct(v_pools.shape, v_pools.dtype),
-            jax.ShapeDtypeStruct(k_scales.shape, k_scales.dtype),
-            jax.ShapeDtypeStruct(v_scales.shape, v_scales.dtype),
-        ],
-        # prefetch 0-4, kn=5 vn=6 kp=7 vp=8 ks=9 vs=10
-        input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3},
-        interpret=platform.interpret_mode(),
-    )(row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
-      row_len.astype(jnp.int32), row_off.astype(jnp.int32),
-      block_tables.astype(jnp.int32), k_new, v_new, k_pools, v_pools,
-      k_scales, v_scales)
+    return _ragged_append(
+        (k_pools, v_pools), (k_scales, v_scales), k_new, v_new, row_slot,
+        row_start, row_len, row_off, block_tables, max_row_tokens)
 
 
 # --------------------------------------------------------------------------
@@ -920,12 +932,8 @@ def live_page_cells(row_start: jax.Array, row_len: jax.Array, maxp: int,
     padding.  It follows from the row arrays alone, not from the layer:
     a step builds it once, in front of its layer loop."""
     pc = jnp.arange(maxp + 1, dtype=jnp.int32)
-    live = (row_len[:, None] > 0) & (
-        (pc == maxp) | (pc * page < row_start[:, None]))
-    flat = live.reshape(-1)
-    (live_ci,) = jnp.nonzero(flat, size=flat.shape[0], fill_value=0)
-    return (live_ci.astype(jnp.int32),
-            jnp.sum(flat, dtype=jnp.int32).reshape(1))
+    return _listed((row_len[:, None] > 0) & (
+        (pc == maxp) | (pc * page < row_start[:, None])))
 
 
 def live_cell_count(row_start, row_len, page: int) -> int:

@@ -2587,7 +2587,10 @@ class LLMEngine:
         program, its arguments and what the step holds, or None when
         nothing fit (every slot budget-capped by in-flight tokens, no
         prompt tokens pending)."""
-        from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
+        from ray_tpu.ops.ragged_paged_attention import (
+            append_cell_count,
+            pack_ragged_batch,
+        )
 
         T, R = self._token_budget, self.config.max_slots
         budget = T
@@ -2779,6 +2782,9 @@ class LLMEngine:
                                         page, bool(step_adapters))
                 if self._ragged_grid_cells
                 else R * (self._maxp + 1)),
+            # pages this step's fresh tokens land in: the cells the
+            # append kernel walks in each layer
+            "append_cells": append_cell_count(row_start, row_len, page),
             # rows that start a sequence (a recurrent-state cache resets
             # their slot on the device) and the step's longest row (what
             # a scan over a row's tokens walks)

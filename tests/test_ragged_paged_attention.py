@@ -606,3 +606,113 @@ def test_fused_live_walk_gives_the_old_walks_bits(kv_int8, case):
     for g, old, built_here in zip(got, layer(every_cell), layer(None)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(old))
         np.testing.assert_array_equal(np.asarray(g), np.asarray(built_here))
+
+
+# ---------------------------------------------------------------------------
+# the append walks only the pages its rows write
+# ---------------------------------------------------------------------------
+
+_BUDGET = 48
+_NPR = rpa._pages_per_row(rpa.window_size(_BUDGET, None), _PAGE)
+# (slot, start, len, off) of six packed rows over the same 6 x 4 table,
+# in a 48-token buffer: where the pages a step writes differ from the
+# 6 x _NPR cells the append used to walk in every layer.
+APPEND_ROWS = {
+    "decode_only": ([2, 0, 5, 1, 0, 0], [19, 33, 16, 63, 0, 0],
+                    [1, 1, 1, 1, 0, 0], [0, 1, 2, 3, 0, 0]),
+    "chunk_crosses_pages": ([1, 3, 0, 0, 0, 0], [10, 30, 0, 0, 0, 0],
+                            [12, 20, 0, 0, 0, 0], [0, 12, 0, 0, 0, 0]),
+    # a row that starts a page, one that fills a page to its end
+    "start_on_page_boundary": ([0, 1, 2, 0, 0, 0], [16, 32, 48, 0, 0, 0],
+                               [1, 16, 5, 0, 0, 0], [0, 1, 17, 0, 0, 0]),
+    # one row of the whole budget, off a page's start: every cell of it
+    "full_budget_chunk": ([4, 0, 0, 0, 0, 0], [5, 0, 0, 0, 0, 0],
+                          [_BUDGET, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0]),
+    "padding_between": ([0, 2, 0, 5, 1, 0], [0, 19, 0, 16, 40, 0],
+                        [0, 1, 0, 11, 1, 0], [0, 0, 0, 1, 12, 0]),
+    "all_slots_live": (list(range(6)), [63, 0, 15, 16, 31, 47],
+                       [1, 9, 2, 1, 1, 1], [0, 1, 10, 12, 13, 14]),
+    "no_live_row": ([0] * 6, [0] * 6, [0] * 6, [0] * 6),
+}
+
+
+def _append_rows(case):
+    return tuple(np.asarray(a, np.int32) for a in APPEND_ROWS[case])
+
+
+def _old_appends_live_cells(start, nlen, npr, page):
+    """The cells of the append's old walk over ``(R, NPR)`` at which its
+    kernel wrote: the condition its body had (``live``)."""
+    return [r * npr + j for r in range(len(nlen)) for j in range(npr)
+            if nlen[r] > 0
+            and (start[r] // page + j) * page < start[r] + nlen[r]]
+
+
+@pytest.mark.parametrize("case", list(APPEND_ROWS))
+def test_live_append_cells_are_the_old_walks_live_cells(case):
+    _slot, start, nlen, _off = _append_rows(case)
+    want = _old_appends_live_cells(start, nlen, _NPR, _PAGE)
+    live_ci, n_live = rpa.live_append_cells(
+        jnp.asarray(start), jnp.asarray(nlen), _NPR, _PAGE)
+    assert live_ci.shape == (_SLOTS * _NPR,) and n_live.shape == (1,)
+    assert live_ci.dtype == n_live.dtype == jnp.int32
+    n = int(n_live[0])
+    assert n == len(want) == rpa.append_cell_count(start, nlen, _PAGE)
+    assert np.asarray(live_ci)[:n].tolist() == want == sorted(want)
+    assert (n == 0) == (case == "no_live_row")
+    if case == "full_budget_chunk":
+        # the budget's longest row touches every cell a row can have
+        assert want == list(range(_NPR))
+
+
+@pytest.mark.parametrize("case", list(APPEND_ROWS))
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_append_live_walk_gives_the_old_walks_bits(kv_int8, case,
+                                                   monkeypatch):
+    """Both appends over the list of the cells that write, against the
+    walk they were before (a list of ALL ``R x NPR`` cells: the ones the
+    rows do not reach go to the scratch page and copy it through) and,
+    for plain pools, against the scatter reference; a page no row wrote
+    keeps its input's bits."""
+    rng = np.random.default_rng(11)
+    L, KVH, D, Pt = 2, 2, 8, _SLOTS * _MAXP + 1
+    kp, vp, ks, vs = _pools(rng, L, KVH, Pt, _PAGE, D, int8=kv_int8)
+    bt = rng.permutation(Pt - 1).reshape(_SLOTS, _MAXP).astype(np.int32)
+    slot, start, nlen, off = _append_rows(case)
+    kn = rng.standard_normal((L, _BUDGET, KVH, D)).astype(np.float32)
+    vn = rng.standard_normal((L, _BUDGET, KVH, D)).astype(np.float32)
+    state = (kp, vp, ks, vs) if kv_int8 else (kp, vp)
+
+    def append():
+        fn = (rpa.ragged_paged_append_quantized if kv_int8
+              else rpa.ragged_paged_append)
+        return fn(*state, jnp.asarray(kn), jnp.asarray(vn),
+                  *(jnp.asarray(a) for a in (slot, start, nlen, off, bt)))
+
+    got = append()
+    cells = _SLOTS * _NPR
+    monkeypatch.setattr(rpa, "live_append_cells", lambda *a: (
+        jnp.arange(cells, dtype=jnp.int32), jnp.full((1,), cells, jnp.int32)))
+    old = append()
+    written = sorted({int(bt[slot[r], p // _PAGE]) for r in range(_SLOTS)
+                      for p in range(start[r], start[r] + nlen[r])})
+    assert len(written) == rpa.append_cell_count(start, nlen, _PAGE)
+    kept = np.setdiff1d(np.arange(Pt - 1), written)
+    for g, o, before in zip(got, old, state):
+        # pools [L, KVH, P, page, D] and scales [L, P, KVH, 1] by page;
+        # the scratch page Pt - 1 is garbage-tolerant
+        g, o, before = (np.moveaxis(np.asarray(a), 2 if a.ndim == 5 else 1,
+                                    0) for a in (g, o, before))
+        np.testing.assert_array_equal(g[:-1], o[:-1])
+        np.testing.assert_array_equal(g[kept], before[kept])
+        if written:
+            assert not np.array_equal(g[written], before[written])
+    if not kv_int8:
+        for layer in range(L):
+            wk, wv = rpa.ragged_append_reference(
+                kp[layer], vp[layer], kn[layer], vn[layer], slot, start,
+                nlen, off, bt)
+            np.testing.assert_array_equal(
+                np.asarray(got[0])[layer, :, :-1], np.asarray(wk)[:, :-1])
+            np.testing.assert_array_equal(
+                np.asarray(got[1])[layer, :, :-1], np.asarray(wv)[:, :-1])

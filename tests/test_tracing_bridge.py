@@ -246,6 +246,10 @@ def test_engine_spans_chain_by_seq_and_count_tokens(params, tmp_path):
     for p in packs:
         assert 0 < p.stats["live_cells"] <= p.stats["grid_cells"]
         assert p.stats["grid_cells"] == capacity
+        # the append writes a page a row at least, and none but the
+        # pages that hold the rows' tokens
+        assert (p.stats["rows"] <= p.stats["append_cells"]
+                <= p.stats["live_cells"])
         assert (p.stats["n_decode"] + p.stats["n_prefill"]
                 + p.stats["n_spec"]) <= p.stats["budget"] == 36
         assert p.stats["rows"] >= 1
