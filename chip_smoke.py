@@ -775,31 +775,19 @@ def phase_serve(platform: str, *, cfg=None, slots: int = 48,
             "cache_misses": rep["cache_misses"]}
 
 
-def phase_serve_jamba(platform: str, *, config=None, n_requests: int = 8,
-                      prompt_len: int = 300, new_tokens: int = 16,
-                      ready_timeout_s: float = 900.0) -> dict:
-    """The serving phase's second case: a model with per-slot recurrent
-    state.  The benchmark's own replica class for Jamba
-    (benchmarks/runners/serve_jamba.py) at three layers (Mamba,
-    attention, Mamba) of the published widths: it checks the ragged step
-    against the plain reference before the engine takes the memory,
-    serves ``n_requests`` prompts of several chunks through
-    ``serve.run``, holds the tokens it served to the reference, and
-    shows that the check refuses an SSM state kept in bfloat16.
-    ``config`` defaults to the benchmark's file."""
+def _serve_recurrent_case(platform: str, runner, config: dict, name: str,
+                          state_key: str, *, n_requests: int,
+                          prompt_len: int, new_tokens: int,
+                          ready_timeout_s: float) -> dict:
+    """One model with per-slot recurrent state through the benchmark's
+    own replica class (``runner.server_class()``): the reference check
+    before the engine takes the memory, ``n_requests`` prompts of
+    several chunks through ``serve.run``, the served tokens held to the
+    reference, and the control: the check has to refuse a state kept in
+    bfloat16 (``state_key`` of its report not ok)."""
     import ray_tpu
-    from benchmarks.runners import serve_jamba
     from ray_tpu import serve
 
-    if config is None:
-        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "benchmarks", "configs",
-                               "jamba2_3b.json")) as f:
-            config = json.load(f)
-    config = dict(config, **serve_jamba.CHECK_HF,
-                  engine=dict(config["engine"], max_slots=8,
-                              max_seq_len=512, num_pages=64,
-                              prefill_chunk=128))
     ray_tpu.init(ignore_reinit_error=True)
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -807,23 +795,23 @@ def phase_serve_jamba(platform: str, *, config=None, n_requests: int = 8,
             ray_actor_options=({"num_tpus": chips} if platform == "tpu"
                                else {}),
             max_ongoing_requests=2 * n_requests,
-        )(serve_jamba.server_class()).bind({"config": config, "seed": 0})
+        )(runner.server_class()).bind({"config": config, "seed": 0})
         t0 = time.perf_counter()
-        handle = serve.run(app, name="chip_smoke_jamba", route_prefix=None,
+        handle = serve.run(app, name=f"chip_smoke_{name}", route_prefix=None,
                            timeout_s=ready_timeout_s)
         rep = handle.device_report.remote().result(timeout_s=60)
         check = rep["check"]
-        log(f"  jamba replica ready after {time.perf_counter() - t0:.1f}s "
+        log(f"  {name} replica ready after {time.perf_counter() - t0:.1f}s "
             f"on {rep['platform']}; reference check: "
             + " ".join(f"{k}={check[k]['rel_err_prefill']:.2e}/"
                        f"{check[k]['rel_err_decode']:.2e}"
-                       for k in serve_jamba.TOLERANCES))
+                       for k in runner.TOLERANCES))
         if rep["platform"] != platform:
             raise AssertionError(
                 f"replica computes on {rep['platform']!r}, not "
                 f"{platform!r}")
         if not check["ok"]:
-            raise AssertionError(f"jamba reference check failed: {check}")
+            raise AssertionError(f"{name} reference check failed: {check}")
         vocab = config["vocab_size"]
         prompts = [[(7 * i + 3 * j) % (vocab - 1) + 1
                     for j in range(prompt_len)] for i in range(n_requests)]
@@ -833,7 +821,7 @@ def phase_serve_jamba(platform: str, *, config=None, n_requests: int = 8,
                 for r in pending]
         check_answers(outs, new_tokens, vocab)
         state = handle.counters.remote().result(timeout_s=60)["state_cache"]
-        log(f"  {len(outs)} jamba requests answered ({prompt_len} prompt "
+        log(f"  {len(outs)} {name} requests answered ({prompt_len} prompt "
             f"+ {new_tokens} new tokens each); state cache {state}")
         if state["resets"] < n_requests or state["live"] != 0:
             raise AssertionError(f"state cache accounting: {state}")
@@ -841,20 +829,68 @@ def phase_serve_jamba(platform: str, *, config=None, n_requests: int = 8,
             timeout_s=ready_timeout_s)
         log(f"  served tokens against the reference: {served}")
         if not served["ok"] or served["tokens"] < new_tokens:
-            raise AssertionError(f"jamba served-token check: {served}")
+            raise AssertionError(f"{name} served-token check: {served}")
         control = handle.state_control.remote().result(
-            timeout_s=ready_timeout_s)["ssm_state"]
-        log(f"  control, SSM state rounded to bfloat16: {control} against "
-            f"{check['ssm_state']['rel_err']}")
+            timeout_s=ready_timeout_s)[state_key]
+        log(f"  control, state rounded to bfloat16: {control} against "
+            f"{check[state_key]['rel_err']}")
         if control["ok"]:
             raise AssertionError(
-                f"the check accepts a bfloat16 SSM state: {control}")
+                f"the check accepts a bfloat16 state: {control}")
     finally:
         serve.shutdown()
         ray_tpu.shutdown()
     return {"platform": rep["platform"], "reference_check": check,
             "state_cache": state, "served_check": served,
             "state_control": control}
+
+
+def _benchmark_config(name: str) -> dict:
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+def phase_serve_jamba(platform: str, *, config=None, n_requests: int = 8,
+                      prompt_len: int = 300, new_tokens: int = 16,
+                      ready_timeout_s: float = 900.0) -> dict:
+    """The serving phase's second case: a model with per-slot recurrent
+    state beside paged KV.  Jamba at three layers (Mamba, attention,
+    Mamba) of the published widths; ``config`` defaults to the
+    benchmark's file."""
+    from benchmarks.runners import serve_jamba
+
+    config = config or _benchmark_config("jamba2_3b")
+    config = dict(config, **serve_jamba.CHECK_HF,
+                  engine=dict(config["engine"], max_slots=8,
+                              max_seq_len=512, num_pages=64,
+                              prefill_chunk=128))
+    return _serve_recurrent_case(
+        platform, serve_jamba, config, "jamba", "ssm_state",
+        n_requests=n_requests, prompt_len=prompt_len,
+        new_tokens=new_tokens, ready_timeout_s=ready_timeout_s)
+
+
+def phase_serve_brumby(platform: str, *, config=None, n_requests: int = 6,
+                       prompt_len: int = 1100, new_tokens: int = 16,
+                       ready_timeout_s: float = 900.0) -> dict:
+    """The serving phase's third case: a model whose cache is state by
+    slot and NOTHING by page.  Brumby at three power-retention layers of
+    the published widths (matrix state per KV head, 0.11 GB a slot at
+    this depth); ``config`` defaults to the benchmark's file."""
+    from benchmarks.runners import serve_brumby
+
+    config = config or _benchmark_config("brumby14b_pp4")
+    config = dict(config, num_hidden_layers=serve_brumby.CHECK_LAYERS,
+                  engine=dict(config["engine"], max_slots=8,
+                              max_seq_len=2048))
+    out = _serve_recurrent_case(
+        platform, serve_brumby, config, "brumby", "ret_state",
+        n_requests=n_requests, prompt_len=prompt_len,
+        new_tokens=new_tokens, ready_timeout_s=ready_timeout_s)
+    if out["state_cache"]["bytes"] <= 0:
+        raise AssertionError(f"no retention state held: {out}")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1004,6 +1040,7 @@ def run_child(phase: str, expect_loss0) -> int:
         report.update(fn("tpu"))
         if phase == "serve":
             report["jamba"] = phase_serve_jamba("tpu")
+            report["brumby"] = phase_serve_brumby("tpu")
     else:
         clock = CompileClock()
         try:

@@ -230,9 +230,10 @@ def _telemetry():
             "state_cache_bytes": metrics.Gauge(
                 "raytpu_serve_state_cache_bytes",
                 "Bytes of per-slot recurrent state (convolution tails "
-                "and SSM states of state-space layers) the engine's "
-                "cache holds beside its KV pages: slots x the "
-                "adapter's state_bytes_per_slot, 0 for a model with "
+                "and SSM states of state-space layers, retention "
+                "states) in the engine's cache tree, its scratch slot "
+                "included; beside the KV pages or, for a model with no "
+                "paged layer, all of the cache.  0 for a model with "
                 "none.",
             ),
             "state_resets": metrics.Counter(
@@ -417,7 +418,8 @@ class PagedEngineAdapter:
     """Model plug: how the engine talks to a model family.  The cache is
     pages under block tables (no length field: the engine tracks lengths
     host-side).  A model served on the ragged step provides two entries,
-    and ``state_bytes_per_slot`` if its cache also holds state by slot:
+    ``state_bytes_per_slot`` if its cache also holds state by slot, and
+    ``paged_kv=False`` if state by slot is all it holds:
 
     init_cache(num_pages, page_size) -> cache pytree
     ragged_step(params, tokens[T], tok_pos[T], row_slot[R], row_start[R],
@@ -508,6 +510,15 @@ class PagedEngineAdapter:
     # prefix, rewound, or shipped as pages, so the engine refuses the
     # prefix cache, speculative decoding and KV migration for it.
     state_bytes_per_slot: int = 0
+    # The cache tree's leaves that hold that state (indexed by slot);
+    # every other leaf is a page pool or its scales.
+    state_leaves: Tuple[str, ...] = ()
+    # False = no layer of the model keeps K/V by token: the cache is
+    # state by slot and nothing else (state_bytes_per_slot says how
+    # much).  The engine then sizes no page pool (init_cache is called
+    # with 0 pages), builds block tables of no column, admits by free
+    # slots alone, and its page counters read 0.
+    paged_kv: bool = True
 
 
 def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
@@ -604,6 +615,28 @@ def jamba_paged_adapter(cfg) -> PagedEngineAdapter:
             jamba.ragged_step(params, tokens, tok_pos, row_slot,
                               row_start, row_len, row_off, bt, cfg, cache),
         state_bytes_per_slot=cfg.state_bytes_per_slot(),
+        state_leaves=("conv", "ssm"),
+    )
+
+
+def brumby_paged_adapter(cfg) -> PagedEngineAdapter:
+    """Brumby (models/brumby.py): every mixer is a power-retention
+    layer whose past is a matrix and a vector per KV head and slot, so
+    the cache has no page at all.  Its step takes neither ``lora=`` nor
+    ``logit_idx=``, and the two-program path has no recurrent-state
+    form."""
+    from ray_tpu.models import brumby
+
+    return PagedEngineAdapter(
+        init_cache=lambda num_pages, page, max_slots: brumby.init_cache(
+            cfg, num_pages, page, max_slots),
+        ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
+        row_len, row_off, bt, cache:
+            brumby.ragged_step(params, tokens, tok_pos, row_slot,
+                               row_start, row_len, row_off, bt, cfg, cache),
+        state_bytes_per_slot=cfg.state_bytes_per_slot(),
+        state_leaves=("ret_s", "ret_z"),
+        paged_kv=False,
     )
 
 
@@ -801,7 +834,7 @@ class LLMServer:
             raise ValueError(
                 f"disaggregated serving role {self._disagg.role!r} hands "
                 "a request over by migrating its KV pages; this model's "
-                "cache also holds per-slot recurrent state that no page "
+                "cache holds per-slot recurrent state that no page "
                 "carries")
         self.engine = LLMEngine(
             param_loader(), adapter, engine_cfg,
@@ -1190,10 +1223,26 @@ class LLMEngine:
                 raise ValueError(
                     why + "only the unsharded ragged step carries it — "
                     "set EngineConfig.ragged_batching=True, no mesh")
+        # A model with no paged layer (PagedEngineAdapter.paged_kv) gets
+        # no pool: zero pages, block tables of no column, and every
+        # request needs zero pages, so admission is by free slots alone.
+        self._paged_kv = bool(adapter.paged_kv)
+        if not self._paged_kv and not self._state_bytes_per_slot:
+            raise ValueError(
+                "PagedEngineAdapter.paged_kv=False says the cache is "
+                "state by slot only, but state_bytes_per_slot is 0")
+        if bool(adapter.state_leaves) != bool(self._state_bytes_per_slot):
+            raise ValueError(
+                "PagedEngineAdapter.state_leaves names the cache's leaves "
+                "held by slot: some exactly where state_bytes_per_slot "
+                f"is not 0 (got {adapter.state_leaves!r} with "
+                f"{self._state_bytes_per_slot} bytes a slot)")
         page = config.page_size
-        self._maxp = -(-config.max_seq_len // page)
-        self._num_pages = (config.num_pages
-                           or config.max_slots * self._maxp)
+        self._maxp = (-(-config.max_seq_len // page)
+                      if self._paged_kv else 0)
+        self._num_pages = ((config.num_pages
+                            or config.max_slots * self._maxp)
+                           if self._paged_kv else 0)
         if mesh is not None and adapter.cache_shardings is not None:
             # Allocate the pool directly under its shardings: a
             # materialize-then-reshard would briefly hold the WHOLE
@@ -1208,6 +1257,16 @@ class LLMEngine:
                                              config.max_slots)
         else:
             self._cache = adapter.init_cache(self._num_pages, page)
+        # The cache's two parts in bytes, from the tree itself: what it
+        # holds by slot (the leaves the adapter names) and the page
+        # pools with their scales (every other leaf).
+        parts = ({k: int(v.size * v.dtype.itemsize)
+                  for k, v in self._cache.items()}
+                 if isinstance(self._cache, dict) else {})
+        self._state_cache_bytes = sum(
+            parts[k] for k in adapter.state_leaves)
+        self._paged_kv_bytes = (sum(parts.values())
+                                - self._state_cache_bytes)
         if (isinstance(self._cache, dict)
                 and "k_scale" in self._cache
                 and config.prefill_chunk > 0
@@ -1310,8 +1369,7 @@ class LLMEngine:
         if mesh is not None and adapter.collective_probes is not None:
             self._calibrate_collectives(adapter.collective_probes(mesh))
         self._update_page_gauges()
-        self._tm["state_cache_bytes"].set(
-            config.max_slots * self._state_bytes_per_slot)
+        self._tm["state_cache_bytes"].set(self._state_cache_bytes)
         # Request-lifecycle ring (util/state.list_requests, dashboard
         # /api/v0/requests, timeline request rows all read it).  The
         # engine holds the only strong ref; the module registry is weak.
@@ -1444,22 +1502,21 @@ class LLMEngine:
             self._ragged_step_fn = self._ragged_program("serve.ragged")
             if self._state_bytes_per_slot:
                 from ray_tpu.util import flight_recorder
-                parts = {k: int(v.size * v.dtype.itemsize)
-                         for k, v in self._cache.items()}
                 flight_recorder.record(
                     "serve_cache_parts", engine=self._engine_id,
-                    paged_kv_bytes=parts.get("k", 0) + parts.get("v", 0),
-                    recurrent_state_bytes=sum(
-                        v for k, v in parts.items()
-                        if k not in ("k", "v")),
+                    paged_kv_bytes=self._paged_kv_bytes,
+                    recurrent_state_bytes=self._state_cache_bytes,
                     slots=config.max_slots, pages=self._num_pages,
                     state_bytes_per_slot=self._state_bytes_per_slot)
                 log.info(
-                    "serve.ragged cache: %d KV pages beside recurrent "
-                    "state for %d slots (%d bytes a slot); prefix "
-                    "cache, speculation and KV migration are refused",
-                    self._num_pages, config.max_slots,
-                    self._state_bytes_per_slot)
+                    "serve.ragged cache: recurrent state for %d slots "
+                    "(%d bytes a slot, %d in all) and %s; prefix cache, "
+                    "speculation and KV migration are refused",
+                    config.max_slots, self._state_bytes_per_slot,
+                    self._state_cache_bytes,
+                    f"{self._num_pages} KV pages "
+                    f"({self._paged_kv_bytes} bytes)"
+                    if self._paged_kv else "no KV page")
             self._weight_routes = (adapter.weight_routes(params)
                                    if adapter.weight_routes else None)
             if self._weight_routes is not None:
@@ -1900,7 +1957,7 @@ class LLMEngine:
             out["state_cache"] = {
                 "slots": slots,
                 "bytes_per_slot": self._state_bytes_per_slot,
-                "bytes": slots * self._state_bytes_per_slot,
+                "bytes": self._state_cache_bytes,
                 "live": slots - len(self._free_slots),
                 "resets": self._state_resets,
             }
@@ -2791,6 +2848,8 @@ class LLMEngine:
             "n_state_reset": sum(1 for r in rows if r["start"] == 0),
             "scan_len": max(len(r["tokens"] or (0,)) for r in rows),
         }
+        if not self._paged_kv:      # no page, so no cell of any
+            counts.update(live_cells=0, grid_cells=0, append_cells=0)
         return name, fn, args, parts, finishing, counts
 
     def _commit_ragged_step(self, parts, finishing, counts,
@@ -3278,22 +3337,30 @@ class LLMEngine:
                           self._steps))
 
     def _fetch_loop(self) -> None:
-        """Dedicated fetch thread: drain every queued entry, batch them
-        into ONE device_get, hand the host arrays back to the engine
-        loop in dispatch order.  Gets overlap dispatching AND each
-        other's processing; the batch size self-balances to load."""
+        """Dedicated fetch thread: hand each step's host arrays back to
+        the engine loop in dispatch order, as soon as that step has run.
+        One device_get takes the oldest queued entry and every later one
+        whose arrays are ready already: a fetcher that fell behind
+        catches up in one call, and one that keeps up lets a step's
+        tokens leave when the step ends, not when the newest step
+        queued behind it does (a burst per pipeline depth)."""
+        pending: List[Any] = []
         while not self._stopped.is_set():
-            entries = [self._fetchq.get()]
-            if entries[0] is None:
-                return
+            if not pending:
+                pending.append(self._fetchq.get())
             while True:
                 try:
-                    nxt = self._fetchq.get_nowait()
+                    pending.append(self._fetchq.get_nowait())
                 except queue.Empty:
                     break
-                if nxt is None:
-                    return
-                entries.append(nxt)
+            if any(e is None for e in pending):
+                return
+            ready = 1
+            while ready < len(pending) and all(
+                    a.is_ready()
+                    for a in jax.tree_util.tree_leaves(pending[ready][1])):
+                ready += 1
+            entries, pending = pending[:ready], pending[ready:]
             try:
                 # Entries leave in dispatch order, so their steps are
                 # the range first..last (numbers only: a profiler stat
@@ -3530,7 +3597,7 @@ class LLMEngine:
         if self._state_bytes_per_slot:
             raise ValueError(
                 "KV migration ships pages only; this engine's cache "
-                "also holds per-slot recurrent state "
+                "holds per-slot recurrent state "
                 f"({self._state_bytes_per_slot} bytes a slot) that no "
                 "page carries, so a migrated prefix could not be "
                 "resumed")

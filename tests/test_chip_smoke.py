@@ -131,6 +131,46 @@ def test_serve_phase_jamba_case_runs_the_runner_end_to_end():
     assert "JAMBA_OK" in proc.stdout
 
 
+def test_serve_phase_brumby_case_runs_the_runner_end_to_end():
+    """The serving phase's Brumby case at toy widths: the benchmark's
+    replica class for it (benchmarks/runners/serve_brumby.py) checks the
+    ragged step against the quadratic reference (logits and the first
+    layer's state), serves chunked prompts through serve.run with no
+    page allocated, holds the served tokens to the reference and refuses
+    a retention state kept in bfloat16.  The CPU dry run of that runner,
+    which ``--rehearse`` has no preset for."""
+    code = (
+        "import json, chip_smoke\n"
+        "config = json.load(open('benchmarks/configs/brumby14b_pp4.json'))\n"
+        "config.update(hidden_size=64, intermediate_size=96,"
+        " num_attention_heads=4, num_key_value_heads=2, head_dim=16,"
+        " vocab_size=211, torch_dtype='float32',"
+        " model_options={'head_dim': 16, 'dtype': 'float32',"
+        " 'param_dtype': 'float32'})\n"
+        "config['engine']['prefill_chunk'] = 32\n"
+        "out = chip_smoke.phase_serve_brumby('cpu', config=config,"
+        " n_requests=3, prompt_len=70, new_tokens=3,"
+        " ready_timeout_s=300)\n"
+        "check = out['reference_check']\n"
+        "assert check['ok'] and check['layers'] == 3, check\n"
+        "state = out['state_cache']\n"
+        "assert state['resets'] == 3 and state['bytes'] == 3 * 9 * 2"
+        " * (160 * 16 + 160) * 4, state\n"
+        "served = out['served_check']\n"
+        "assert served['ok'] and served['layers'] == 3, served\n"
+        "assert served['requests'] == 3 and served['tokens'] == 9, served\n"
+        "control = out['state_control']\n"
+        "assert not control['ok'], control\n"
+        "worst = max(v for e in check['ret_state']['rel_err'].values()"
+        " for v in e.values())\n"
+        "print('BRUMBY_OK', worst, control['rel_err'])\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          env=_clean_env(), capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "BRUMBY_OK" in proc.stdout
+
+
 def test_chip_smoke_without_a_chip_fails_and_names_the_platform():
     proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
                           env=_clean_env(), capture_output=True, text=True,

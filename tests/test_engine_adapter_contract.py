@@ -1,6 +1,6 @@
 """What ``LLMEngine`` may assume of a model: the seam is
 ``PagedEngineAdapter`` with ONE step plug.  The same contract is held
-to every adapter in the tree (llama, llama with LoRA, Jamba), so a new
+to every adapter in the tree (llama, llama with LoRA, Jamba, Brumby), so a new
 model family knows what it has to provide."""
 
 import dataclasses
@@ -10,12 +10,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import jamba, llama
+from ray_tpu.models import brumby, jamba, llama
 from ray_tpu.ops import segmented_lora
 from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
 from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
+    brumby_paged_adapter,
     jamba_paged_adapter,
     llama_paged_adapter,
 )
@@ -30,6 +31,9 @@ JAMBA = jamba.JambaConfig(
     vocab_size=97, dim=64, n_layers=4, n_heads=4, n_kv_heads=1, head_dim=16,
     mlp_dim=96, attn_layer_period=3, attn_layer_offset=1, dt_rank=8,
     dtype=jnp.float32, param_dtype=jnp.float32)
+BRUMBY = brumby.BrumbyConfig(
+    vocab_size=97, dim=32, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=16,
+    mlp_dim=64, dtype=jnp.float32, param_dtype=jnp.float32)
 PAGE, SLOTS, MAXP, BUDGET = 8, 4, 4, 24
 TABLE = np.arange(SLOTS * MAXP, dtype=np.int32).reshape(SLOTS, MAXP)
 ROWS = [{"slot": 2, "start": 0, "tokens": [5, 9, 2, 7, 1, 3]},
@@ -42,6 +46,7 @@ CASES = {
     "llama_lora": (llama_paged_adapter, LLAMA_LORA, llama.init_params,
                    {"lora", "logit_idx"}),
     "jamba": (jamba_paged_adapter, JAMBA, jamba.init_params, set()),
+    "brumby": (brumby_paged_adapter, BRUMBY, brumby.init_params, set()),
 }
 
 
@@ -54,10 +59,18 @@ def test_ragged_step_is_the_one_step_plug(case):
     step_fields = {f.name for f in dataclasses.fields(adapter)
                    if f.name.startswith("ragged_step")}
     assert step_fields == {"ragged_step"}
+    # a cache of state by slot only says how much state that is
+    assert adapter.paged_kv or adapter.state_bytes_per_slot
     if adapter.state_bytes_per_slot:
         assert adapter.prefill_slot is None and adapter.decode_slots is None
-        cache = adapter.init_cache(SLOTS * MAXP, PAGE, SLOTS)
+        cache = adapter.init_cache(SLOTS * MAXP if adapter.paged_kv else 0,
+                                   PAGE, SLOTS)
+        # the adapter names its by-slot leaves; they are the whole tree
+        # exactly where it says that it holds no page
+        assert adapter.state_leaves and set(adapter.state_leaves) <= set(cache)
+        assert adapter.paged_kv == (set(adapter.state_leaves) < set(cache))
     else:
+        assert not adapter.state_leaves
         cache = adapter.init_cache(SLOTS * MAXP, PAGE)
     (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off) = \
         pack_ragged_batch(ROWS, BUDGET, SLOTS)

@@ -569,3 +569,74 @@ def test_jamba_cell_step_updates_the_state_in_place(v5e):
             ] == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2**30
+
+
+# -- the Brumby cell: power retention, state by slot and no page -------------
+
+def _brumby_cell():
+    from benchmarks.runners.common import model_config
+
+    config = json.loads(
+        (REPO / "benchmarks" / "configs" / "brumby14b_pp4.json").read_text())
+    eng = config["engine"]
+    return model_config(config), eng, eng["max_slots"] + eng["prefill_chunk"]
+
+
+@pytest.mark.parametrize("kernel", ["retention_decode", "retention_chunk"])
+def test_retention_kernels(v5e, kernel):
+    """Each kernel at the cell's sizes: 524 packed tokens, 12 rows, the
+    states of 10 layers and 12 slots (4.9 GB) updated in place."""
+    from ray_tpu.ops import power_retention as pr
+
+    cfg, eng, T = _brumby_cell()
+    mesh = _one(v5e)
+    H, KVH, d, R = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, eng["max_slots"]
+    Dp = cfg.feature_dim
+    assert (T, H, KVH, d, Dp) == (524, 40, 8, 128, 9216)
+    f32 = jnp.float32
+    rows = _sds(R, dtype=jnp.int32)
+    compiled = _compile(
+        getattr(pr, kernel), *_on(mesh, (
+            _sds(T, H, d), _sds(T, KVH, d), _sds(T, KVH, d),
+            _sds(T, KVH, dtype=f32),
+            _sds(cfg.n_layers, R + 1, KVH, Dp, d, dtype=f32),
+            _sds(cfg.n_layers, R + 1, KVH, Dp, dtype=f32),
+            _sds(dtype=jnp.int32), rows, rows, rows, rows)),
+        donate_argnums=(4, 5))
+    assert kernel in compiled.as_text()
+
+
+def test_brumby_cell_step_updates_the_state_in_place(v5e):
+    """The step program of ``brumby14b_pp4-doc_long`` at its ten layers
+    and published widths fits the chip with its weights (9.05 GiB) and
+    state (4.61 GiB), and no copy of either state array is made in it:
+    both kernels' aliases hold through the layer scan."""
+    from ray_tpu.models import brumby
+
+    cfg, eng, T = _brumby_cell()
+    assert (cfg.n_layers, cfg.dim, cfg.mlp_dim) == (10, 5120, 17408)
+    mesh = _one(v5e)
+    slots = eng["max_slots"]
+    params = _on(mesh, jax.eval_shape(
+        lambda: brumby.init_params(jax.random.key(0), cfg)))
+    cache = _on(mesh, jax.eval_shape(
+        lambda: brumby.init_cache(cfg, 0, 64, slots)))
+    assert set(cache) == {"ret_s", "ret_z"}
+    toks, rows, bt = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, 0, dtype=jnp.int32)))
+    compiled = _compile(
+        lambda p, t, pos, rs, r0, rl, ro, b, c:
+        brumby.ragged_step(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+        params, toks, toks, rows, rows, rows, rows, bt, cache,
+        donate_argnums=(8,))
+    text = compiled.as_text()
+    for kernel in ("retention_decode", "retention_chunk"):
+        assert kernel in text
+    for state in (f"f32[10,{slots + 1},8,9216,128]",
+                  f"f32[10,{slots + 1},8,9216]"):
+        assert [ln for ln in text.splitlines()
+                if re.search(r"= \S*" + re.escape(state) + r"\S* copy\(", ln)
+                ] == []
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30

@@ -257,3 +257,47 @@ def test_engine_says_how_the_layer_kernel_reads_its_weights(
                               temperature=0.0).result(timeout_s=120)) == 3
     finally:
         eng.shutdown()
+
+
+def test_fetch_thread_hands_back_a_step_when_it_has_run(monkeypatch):
+    """The fetch thread takes the oldest queued step and, with it, only
+    the later ones whose arrays are ready: a step's tokens leave when
+    the step has run, not when the newest step queued behind it has
+    (tokens in bursts of the pipeline's depth), and a fetcher that fell
+    behind still catches up in one ``device_get``."""
+    import queue
+    import threading
+    import types
+
+    class Toks:
+        def __init__(self, seq, ready):
+            self.seq, self.ready = seq, ready
+
+        def is_ready(self):
+            return self.ready
+
+    batches = []
+
+    def device_get(payloads):
+        batches.append([jax.tree.leaves(p)[0].seq for p in payloads])
+        return jax.tree.map(lambda a: np.asarray([a.seq]), payloads)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    eng = types.SimpleNamespace(_fetchq=queue.Queue(), _fetched=queue.Queue(),
+                                _stopped=threading.Event())
+    ready = {1: True, 2: True, 3: False, 4: False, 5: True, 6: True}
+    for seq in sorted(ready):
+        payload = Toks(seq, ready[seq])
+        eng._fetchq.put(("ragged", (payload, payload) if seq == 2
+                         else payload, 1, [], seq))
+    fetcher = threading.Thread(target=LLMEngine._fetch_loop, args=(eng,))
+    fetcher.start()
+    got = [eng._fetched.get(timeout=30) for _ in ready]
+    eng._fetchq.put(None)
+    fetcher.join(30)
+    assert not fetcher.is_alive()
+    # 3 is not ready: it is waited for as the oldest, alone with what is
+    # ready behind it; 4 likewise, with 5 and 6
+    assert batches == [[1, 2], [3], [4, 5, 6]]
+    assert [entry[4] for entry, _toks in got] == sorted(ready)
+    assert isinstance(got[1][1], tuple)     # a tuple payload stays one
