@@ -66,6 +66,16 @@ def _program(name: str, **jit_kwargs):
     return wrap
 
 
+def ragged_step_shapes(token_budget: int, max_slots: int) -> Tuple[int, ...]:
+    """Lengths of the packed token buffer the engine runs the ragged
+    step at, in rising order: ``max_slots`` rounded up to 8, which holds
+    every step that carries no more than its decode rows and a short
+    prompt tail, and the token budget, which holds every step.  One
+    length where rounding leaves no room under the budget."""
+    small = -(-max_slots // 8) * 8
+    return (small, token_budget) if small < token_budget else (token_budget,)
+
+
 def _telemetry():
     """Engine metric singletons (created on first engine construction,
     re-registered on later fetches so a test's registry clear() cannot
@@ -187,6 +197,14 @@ def _telemetry():
                 "rising prefill share explains decode-stream TPOT "
                 "regressions without any per-request change.",
                 tag_keys=("phase",),
+            ),
+            "steps": metrics.Counter(
+                "raytpu_serve_steps_total",
+                "Ragged steps dispatched, by the positions the step's "
+                "program was compiled for: the token budget, or "
+                "max_slots rounded up to 8 for a step whose tokens fit "
+                "there (no prompt chunk to carry).",
+                tag_keys=("shape",),
             ),
             "kv_pages_free": metrics.Gauge(
                 "raytpu_serve_kv_pages_free",
@@ -355,7 +373,11 @@ class EngineConfig:
     # the prefill-vs-decode interleave — a long prompt streams in
     # budget-sized chunks beside live decode rows instead of stalling
     # them.  token_budget=0 sizes it max_slots + max(prefill_chunk,
-    # page_size).
+    # page_size).  It bounds what a step may carry (admission and
+    # chunking); the program a step runs is compiled for the budget's
+    # positions or, where the step's tokens fit, for max_slots rounded
+    # up to 8 (ragged_step_shapes), so a decode step does not pay for
+    # the chunk it does not carry.
     ragged_batching: bool = False
     token_budget: int = 0
     # Radix-tree prefix cache over the page pool
@@ -1478,8 +1500,11 @@ class LLMEngine:
             self._prefill_chunk_fn = None
         # Ragged batching: ONE jitted program per scheduler step, fed a
         # packed token buffer of decode rows + prefill chunks.  Static
-        # (T, R) = (token_budget, max_slots) → a single compile serves
-        # every mix.
+        # (T, R): R = max_slots, and T is the smallest of
+        # ragged_step_shapes that holds the step's tokens, so jit keeps
+        # two executables of the one function and a step without a
+        # prompt chunk does not run the budget's positions through every
+        # matmul.  Only the [T] arrays differ between the two.
         self._ragged = bool(config.ragged_batching)
         self._weight_routes = None
         if self._ragged:
@@ -1498,6 +1523,9 @@ class LLMEngine:
                 raise ValueError(
                     "token_budget must leave room for a prefill chunk "
                     f"beside {config.max_slots} decode rows")
+            self._ragged_shapes = ragged_step_shapes(
+                self._token_budget, config.max_slots)
+            self._steps_by_shape = {T: 0 for T in self._ragged_shapes}
 
             self._ragged_step_fn = self._ragged_program("serve.ragged")
             if self._state_bytes_per_slot:
@@ -1604,6 +1632,7 @@ class LLMEngine:
             self._adapters = None
             self._ragged_step_fn = None
             self._token_budget = 0
+            self._steps_by_shape = {}
         # Adapter borrow per slot ("" = base model): released with the
         # slot on every terminal path.
         self._slot_adapter: Dict[int, str] = {}
@@ -1937,6 +1966,7 @@ class LLMEngine:
             "tokens_out": self._tokens_out,
             "stall_events": self._clock.stall_events,
             "loop": self._clock.snapshot(),
+            "steps_by_shape": dict(self._steps_by_shape),
             "requests": self._ring.counts_by_state(),
         }
         out["kv_pages_free"] = len(self._free_pages)
@@ -2086,18 +2116,25 @@ class LLMEngine:
         return out
 
     def _instrumented_dispatch(self, name, fn, args, span_name,
-                               steps_attr=None, cost_steps=None):
+                               steps_attr=None, cost_steps=None,
+                               shape=None):
         """Dispatch one jitted program; the FIRST dispatch of each
-        named program also registers it in the device plane
-        (util/xprof): lowered cost analysis must happen before the call
+        named program, at each ``shape`` it is compiled for, also
+        registers it in the device plane (util/xprof): lowered cost
+        analysis must happen before the call
         (the program donates its cache — afterwards those buffers are
         deleted), while the timed call itself measures trace+compile
         wall.  Later dispatches pass straight through.  ``cost_steps``
         declares how many tokens the recorded cost covers (the
-        per-token denominator for waterfall device estimates)."""
-        if name in self._xprof_recorded:
+        per-token denominator for waterfall device estimates).  The
+        device plane names a program's largest shape by the program's
+        name and any other ``<name>@<shape>``: each is an executable
+        with a cost and a compile window of its own."""
+        if (name, shape) in self._xprof_recorded:
             return fn(*args)
-        self._xprof_recorded.add(name)
+        self._xprof_recorded.add((name, shape))
+        if shape is not None and shape != self._token_budget:
+            name = f"{name}@{shape}"
         lowered = None
         try:
             lowered = fn.lower(*args)
@@ -2626,7 +2663,8 @@ class LLMEngine:
                 self._instrumented_dispatch(
                     name, fn, args,
                     span_name="llm.ragged", steps_attr="tokens",
-                    cost_steps=float(self._token_budget),
+                    cost_steps=float(counts["shape"]),
+                    shape=counts["shape"],
                 )
         with self._clock.phase("commit", {"seq": seq}):
             self._commit_ragged_step(parts, finishing, counts, toks_dev)
@@ -2649,8 +2687,8 @@ class LLMEngine:
             pack_ragged_batch,
         )
 
-        T, R = self._token_budget, self.config.max_slots
-        budget = T
+        R = self.config.max_slots
+        budget = self._token_budget
         rows: List[Dict[str, Any]] = []
         parts: List[Tuple[str, Request, int, int]] = []
         scatter = np.full((R,), R, np.int32)  # OOB = sample dropped
@@ -2782,6 +2820,10 @@ class LLMEngine:
             n_prefill += len(chunk)
         if not rows:
             return None
+        # The smallest compiled shape that holds the step's tokens; a
+        # step with verify rows has its own program, at the budget.
+        T = self._token_budget if n_spec else next(
+            t for t in self._ragged_shapes if n_decode + n_prefill <= t)
         self._refresh_state_args()
         if step_adapters:
             # The step carries adapters: the program also gets the
@@ -2827,7 +2869,10 @@ class LLMEngine:
         page = self.config.page_size
         counts = {
             "n_decode": n_decode, "n_prefill": n_prefill,
-            "n_spec": n_spec, "rows": len(rows), "budget": T,
+            "n_spec": n_spec, "rows": len(rows),
+            "budget": self._token_budget,
+            # the positions this step's program was compiled for
+            "shape": T,
             # pages that hold each packed row's tokens once this step
             # has written them, against the cells the step's attention
             # kernel walks, as the adapter states them
@@ -2884,6 +2929,8 @@ class LLMEngine:
                 req.admitted_at = now
         self._state_dirty = True
         self._steps += 1
+        self._steps_by_shape[counts["shape"]] += 1
+        self._tm["steps"].inc(tags={"shape": str(counts["shape"])})
         self._tm["step_tokens"].inc(n_decode, tags={"phase": "decode"})
         self._tm["step_tokens"].inc(n_prefill,
                                     tags={"phase": "prefill"})

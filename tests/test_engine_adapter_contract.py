@@ -19,6 +19,7 @@ from ray_tpu.serve.llm_engine import (
     brumby_paged_adapter,
     jamba_paged_adapter,
     llama_paged_adapter,
+    ragged_step_shapes,
 )
 
 LLAMA = llama.LlamaConfig(
@@ -50,6 +51,15 @@ CASES = {
 }
 
 
+def _init_cache(adapter):
+    """The adapter's cache as the engine asks for it: a cache that holds
+    state by slot is told the slots, and no page where it holds none."""
+    if adapter.state_bytes_per_slot:
+        return adapter.init_cache(SLOTS * MAXP if adapter.paged_kv else 0,
+                                  PAGE, SLOTS)
+    return adapter.init_cache(SLOTS * MAXP, PAGE)
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_ragged_step_is_the_one_step_plug(case):
     make, cfg, init_params, takes = CASES[case]
@@ -61,17 +71,15 @@ def test_ragged_step_is_the_one_step_plug(case):
     assert step_fields == {"ragged_step"}
     # a cache of state by slot only says how much state that is
     assert adapter.paged_kv or adapter.state_bytes_per_slot
+    cache = _init_cache(adapter)
     if adapter.state_bytes_per_slot:
         assert adapter.prefill_slot is None and adapter.decode_slots is None
-        cache = adapter.init_cache(SLOTS * MAXP if adapter.paged_kv else 0,
-                                   PAGE, SLOTS)
         # the adapter names its by-slot leaves; they are the whole tree
         # exactly where it says that it holds no page
         assert adapter.state_leaves and set(adapter.state_leaves) <= set(cache)
         assert adapter.paged_kv == (set(adapter.state_leaves) < set(cache))
     else:
         assert not adapter.state_leaves
-        cache = adapter.init_cache(SLOTS * MAXP, PAGE)
     (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off) = \
         pack_ragged_batch(ROWS, BUDGET, SLOTS)
     nine = (params, toks, pos, r_slot, r_start, r_len, r_off, TABLE, cache)
@@ -104,6 +112,54 @@ def test_ragged_step_is_the_one_step_plug(case):
         value = idx if name == "logit_idx" else (None, None, None)
         with pytest.raises((TypeError, ValueError), match=name):
             adapter.ragged_step(*nine, **{name: value})
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_a_decode_step_reads_the_same_at_either_compiled_shape(case):
+    """The engine runs a step at the smallest of ``ragged_step_shapes``
+    that holds its tokens.  Only the [T] arrays change length, and a
+    row's result does not depend on the padding beside it: the same
+    decode rows through the small shape and through the budget's give
+    the same tokens, logits and cache."""
+    make, cfg, init_params, takes = CASES[case]
+    adapter = make(cfg)
+    params = init_params(jax.random.key(0), cfg)
+    cache = _init_cache(adapter)
+    small, budget = ragged_step_shapes(BUDGET, SLOTS)
+    assert (small, budget) == (8, BUDGET)
+    pool = None
+    if "lora" in takes:
+        pool = adapter.make_adapter_pool(EngineConfig(max_slots=SLOTS))
+        pool.acquire("tenant-a")
+    # one jitted function and an executable a shape, as in the engine
+    step = jax.jit(adapter.ragged_step)
+
+    def run(rows, T, cache):
+        (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off, tok_ad) = \
+            pack_ragged_batch(rows, T, SLOTS, with_adapters=True)
+        kw = ({"lora": (pool.device_pool, pool.page_table(["tenant-a"]),
+                        tok_ad)} if pool is not None else {})
+        return step(params, toks, pos, r_slot, r_start, r_len, r_off,
+                    TABLE, cache, **kw)
+
+    prompts = [dict(ROWS[0], adapter=1), dict(ROWS[1], adapter=0)]
+    _, cache = run(prompts, budget, cache)
+    decode = [{"slot": 2, "start": 6, "tokens": [11], "adapter": 1},
+              {"slot": 0, "start": 2, "tokens": [13], "adapter": 0}]
+    (l_small, c_small), (l_budget, c_budget) = (
+        run(decode, T, cache) for T in (small, budget))
+    l_small, l_budget = (np.asarray(x[:len(decode)])
+                         for x in (l_small, l_budget))
+    np.testing.assert_array_equal(l_small.argmax(-1), l_budget.argmax(-1))
+    np.testing.assert_allclose(l_small, l_budget, rtol=1e-5, atol=1e-5)
+    for name in sorted(cache):
+        np.testing.assert_allclose(
+            np.asarray(c_small[name]), np.asarray(c_budget[name]),
+            rtol=1e-5, atol=1e-6, err_msg=name)
+    # and the step did write: the cache is not what it was
+    assert any(not np.array_equal(np.asarray(c_small[name]),
+                                  np.asarray(cache[name]))
+               for name in cache)
 
 
 @pytest.mark.parametrize("field", ["prefill_slot", "decode_slots"])

@@ -456,12 +456,22 @@ def weight_sized_int8_copies(hlo_text: str, min_bytes: int = 4 * 2**20):
             and np.prod([int(d) for d in dims.split(",") if d]) >= min_bytes]
 
 
-def test_chat_cell_step_copies_no_weight(v5e):
+def _step_shapes(token_budget, max_slots):
+    """The two shapes ``LLMEngine`` compiles of the ragged step."""
+    from ray_tpu.serve.llm_engine import ragged_step_shapes
+
+    small, budget = ragged_step_shapes(token_budget, max_slots)
+    return {"budget": budget, "small": small}
+
+
+@pytest.mark.parametrize("shape", ["budget", "small"])
+def test_chat_cell_step_copies_no_weight(v5e, shape):
     """``mistral7b_w8-chat`` as the benchmark runs it: Mistral-7B widths,
-    the fused int8 artifact, 16 slots of 40 pages, a token budget of 80,
-    int8 KV pages.  The fused layer kernel takes the stacked weights
-    whole, so the compiled step holds no operation that writes a layer's
-    int8 weight again, inside the layer loop or hoisted out of it (a
+    the fused int8 artifact, 16 slots of 40 pages, a token budget of 80
+    and the engine's small shape of 16 positions for steps that carry no
+    prompt chunk, int8 KV pages.  The fused layer kernel takes the
+    stacked weights whole, so the compiled step holds no operation that
+    writes a layer's int8 weight again, inside the layer loop or hoisted out of it (a
     reshape of a stacked leaf that stopped being a bitcast would be).
     Before PR 25 it held four a layer, 16.6 ms of a 46 ms step."""
     from benchmarks.runners.common import model_config
@@ -472,8 +482,9 @@ def test_chat_cell_step_copies_no_weight(v5e):
     assert cfg.fused_decode and cfg.kv_int8
     slots, page = eng["max_slots"], eng["page_size"]
     maxp = eng["max_seq_len"] // page
-    T = slots + page
-    assert (slots, maxp, T) == (16, 40, 80)
+    shapes = _step_shapes(slots + page, slots)
+    assert (slots, maxp, shapes) == (16, 40, {"budget": 80, "small": 16})
+    T = shapes[shape]
     mesh = _one(v5e)
     params = _on(mesh, jax.eval_shape(
         lambda: quant.fuse_for_decode(
@@ -537,17 +548,22 @@ def test_ssm_scan_kernel(v5e):
     assert "ssm_scan" in compiled.as_text()
 
 
-def test_jamba_cell_step_updates_the_state_in_place(v5e):
+@pytest.mark.parametrize("shape", ["budget", "small"])
+def test_jamba_cell_step_updates_the_state_in_place(v5e, shape):
     """The step program of ``jamba2_3b-chat_short`` at full depth and
-    published widths fits the chip with its cache, and no copy of the
-    SSM states (0.55 GB) is made in it: the scan kernel's alias holds
-    through the layer scans."""
+    published widths, in both shapes the engine compiles (320 positions,
+    and 64 for steps without a prompt chunk), fits the chip with its
+    cache, and no copy of the SSM states (0.55 GB) is made in it: the
+    scan kernel's alias holds through the layer scans."""
     from ray_tpu.models import jamba
 
     cfg, eng, T = _jamba_cell()
-    assert (cfg.n_layers, cfg.d_inner, T) == (28, 5120, 320)
-    mesh = _one(v5e)
     slots, page = eng["max_slots"], eng["page_size"]
+    shapes = _step_shapes(T, slots)
+    assert (cfg.n_layers, cfg.d_inner, shapes) == (
+        28, 5120, {"budget": 320, "small": 64})
+    T = shapes[shape]
+    mesh = _one(v5e)
     params = _on(mesh, jax.eval_shape(
         lambda: jamba.init_params(jax.random.key(0), cfg)))
     cache = _on(mesh, jax.eval_shape(
@@ -606,17 +622,23 @@ def test_retention_kernels(v5e, kernel):
     assert kernel in compiled.as_text()
 
 
-def test_brumby_cell_step_updates_the_state_in_place(v5e):
+@pytest.mark.parametrize("shape", ["budget", "small"])
+def test_brumby_cell_step_updates_the_state_in_place(v5e, shape):
     """The step program of ``brumby14b_pp4-doc_long`` at its ten layers
-    and published widths fits the chip with its weights (9.05 GiB) and
-    state (4.61 GiB), and no copy of either state array is made in it:
-    both kernels' aliases hold through the layer scan."""
+    and published widths, in both shapes the engine compiles (524
+    positions, and 16 = 12 slots rounded up to 8 for steps without a
+    prompt chunk), fits the chip with its weights (9.05 GiB) and state
+    (4.61 GiB), and no copy of either state array is made in it: both
+    kernels' aliases hold through the layer scan."""
     from ray_tpu.models import brumby
 
     cfg, eng, T = _brumby_cell()
-    assert (cfg.n_layers, cfg.dim, cfg.mlp_dim) == (10, 5120, 17408)
-    mesh = _one(v5e)
     slots = eng["max_slots"]
+    shapes = _step_shapes(T, slots)
+    assert (cfg.n_layers, cfg.dim, cfg.mlp_dim, shapes) == (
+        10, 5120, 17408, {"budget": 524, "small": 16})
+    T = shapes[shape]
+    mesh = _one(v5e)
     params = _on(mesh, jax.eval_shape(
         lambda: brumby.init_params(jax.random.key(0), cfg)))
     cache = _on(mesh, jax.eval_shape(

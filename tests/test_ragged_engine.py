@@ -27,6 +27,7 @@ from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
     llama_paged_adapter,
+    ragged_step_shapes,
 )
 from tests import oracle
 
@@ -101,6 +102,14 @@ def test_long_prefill_never_stalls_decode(params):
     rng = np.random.default_rng(1)
     eng = _engine(params, prefill_chunk=16)
     try:
+        # Both shapes of the step compile before anything is timed (a
+        # chunk takes the budget's, the lone decode tail the small one):
+        # a compile beside a live stream is no stall of the scheduler's.
+        # Two steps at the budget: the program's first call is the only
+        # one whose cache is not yet a result of its own, and jit's fast
+        # path keys on that (a dozen ms of host work at the second).
+        eng.generate(list(range(1, 81)), max_new_tokens=3)
+        steps0 = eng.stats()["steps"]
         short = eng.submit([1, 5, 9], max_new_tokens=24, temperature=0.0)
         # Let the short stream reach steady-state decode first.
         it = iter(short)
@@ -117,7 +126,7 @@ def test_long_prefill_never_stalls_decode(params):
         # their own: the short stream alone needs 24 (prefill + 23
         # decode rows).  A scheduler that parked decode behind the
         # prefill would serialize all 6 chunk steps on top (≥ 33).
-        assert eng.stats()["steps"] <= 28
+        assert eng.stats()["steps"] - steps0 <= 28
         # The decode stream never gapped by more than one step: its
         # worst inter-token latency stays at step scale, nowhere near a
         # monolithic 96-token prefill program.
@@ -127,6 +136,107 @@ def test_long_prefill_never_stalls_decode(params):
         assert eng.stats()["stall_events"] == 0
     finally:
         eng.shutdown()
+
+
+# -- the step's two compiled shapes -----------------------------------------
+
+def _record_packs(eng, monkeypatch):
+    """(program name, counts, length of the step's token array) of every
+    step the engine packs from here on."""
+    pack, packs = eng._pack_ragged_step, []
+
+    def recording():
+        step = pack()
+        if step is not None:
+            name, _fn, args, _parts, _finishing, counts = step
+            packs.append((name, counts, len(args[2])))     # host_toks
+        return step
+
+    monkeypatch.setattr(eng, "_pack_ragged_step", recording)
+    return packs
+
+
+@pytest.mark.parametrize("budget,slots,shapes", [
+    (80, 16, (16, 80)), (320, 64, (64, 320)),
+    (524, 12, (16, 524)),       # 12 slots round up to 16 positions
+    (36, 4, (8, 36)), (40, 33, (40,)), (13, 12, (13,))])
+def test_ragged_step_shapes(budget, slots, shapes):
+    assert ragged_step_shapes(budget, slots) == shapes
+
+
+def test_two_shapes_serve_one_run(params):
+    """A run that mixes chunk steps and decode steps goes through both
+    compiled shapes of the one program, says so, and decodes exactly."""
+    eng = _engine(params, prefill_chunk=16)
+    try:
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(1, 127, size=n).tolist()
+                   for n in (40, 3, 23, 9)]
+        wants = [greedy_reference(params, p, 8) for p in prompts]
+        streams = [eng.submit(p, max_new_tokens=8, temperature=0.0)
+                   for p in prompts]
+        assert [s.result(timeout_s=120) for s in streams] == wants
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    by_shape = stats["steps_by_shape"]
+    assert set(by_shape) == {8, 36}
+    assert by_shape[8] > 0 and by_shape[36] > 0
+    assert sum(by_shape.values()) == stats["steps"]
+    from ray_tpu.util import metrics
+
+    text = metrics.export_prometheus()
+    for shape in (8, 36):
+        assert f'raytpu_serve_steps_total{{shape="{shape}"' in text
+
+
+def test_step_takes_the_smallest_shape_that_holds_its_tokens(
+        params, monkeypatch):
+    """Decode rows beside a prompt tail that fits ``round8(max_slots)``
+    positions take the small shape; one token over takes the budget's."""
+    eng = _engine(params)
+    try:
+        packs = _record_packs(eng, monkeypatch)
+        long = eng.submit([1, 5, 9], max_new_tokens=100, temperature=0.0)
+        next(iter(long))            # decoding from here on
+        for n in (7, 8):
+            prompt = list(range(2, 2 + n))
+            assert eng.generate(prompt, max_new_tokens=2) == \
+                greedy_reference(params, prompt, 2)
+        long.cancel()
+    finally:
+        eng.shutdown()
+    shape_of = {(c["n_decode"], c["n_prefill"]): (c["shape"], T)
+                for _name, c, T in packs}
+    assert shape_of[(0, 3)] == (8, 8)       # the first prompt alone
+    assert shape_of[(1, 0)] == (8, 8)       # a lone decode row
+    assert shape_of[(1, 7)] == (8, 8)       # 1 + 7 tokens fit 8 positions
+    assert shape_of[(1, 8)] == (36, 36)     # one over
+    for name, c, T in packs:
+        assert name == "serve.ragged" and c["budget"] == 36
+        assert c["shape"] == T == (
+            8 if c["n_decode"] + c["n_prefill"] <= 8 else 36)
+
+
+def test_verify_rows_keep_their_program_at_the_budget(params, monkeypatch):
+    """A step with a speculative verify row runs ``serve.ragged_spec``,
+    compiled for the budget alone, as before; the plain decode steps of
+    the same engine take the small shape."""
+    eng = _engine(params, spec_decode=True)
+    try:
+        packs = _record_packs(eng, monkeypatch)
+        assert eng.generate([1, 5, 9], max_new_tokens=24) == \
+            greedy_reference(params, [1, 5, 9], 24)
+        assert eng.stats()["spec"]["rounds"] > 0
+    finally:
+        eng.shutdown()
+    spec = [(name, c, T) for name, c, T in packs if c["n_spec"]]
+    assert spec and len(spec) < len(packs)
+    for name, c, T in packs:
+        if c["n_spec"]:
+            assert (name, c["shape"], T) == ("serve.ragged_spec", 36, 36)
+        else:
+            assert (name, c["shape"], T) == ("serve.ragged", 8, 8)
 
 
 def test_ragged_step_token_phase_attribution(params):

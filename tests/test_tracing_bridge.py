@@ -250,8 +250,12 @@ def test_engine_spans_chain_by_seq_and_count_tokens(params, tmp_path):
         # pages that hold the rows' tokens
         assert (p.stats["rows"] <= p.stats["append_cells"]
                 <= p.stats["live_cells"])
-        assert (p.stats["n_decode"] + p.stats["n_prefill"]
-                + p.stats["n_spec"]) <= p.stats["budget"] == 36
+        tokens = (p.stats["n_decode"] + p.stats["n_prefill"]
+                  + p.stats["n_spec"])
+        assert tokens <= p.stats["budget"] == 36
+        # the positions the step's program was compiled for: 4 slots
+        # rounded up to 8 where the step's tokens fit, else the budget
+        assert p.stats["shape"] == (8 if tokens <= 8 else 36)
         assert p.stats["rows"] >= 1
     # every step has its dispatch and commit under the same seq, inside
     # the loop iteration that names it
@@ -274,6 +278,64 @@ def test_engine_spans_chain_by_seq_and_count_tokens(params, tmp_path):
     # the step's operations run under the registered program's name
     modules = {e.stats["hlo_module"] for e in ev if "hlo_module" in e.stats}
     assert "jit_serve_ragged" in modules
+
+
+def test_each_shapes_first_dispatch_is_tagged_as_a_compile(params):
+    """The step program has an executable a shape, so it compiles twice:
+    each first dispatch leaves an ``llm.ragged`` span tagged
+    ``compile=true`` (the waterfall and the roofline join skip it) and a
+    record of its own in the device plane, with its compile window."""
+    from ray_tpu.util import xprof
+
+    tracing.clear()
+    tracing.enable_tracing()
+    eng = _engine(params)
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=4)       # 3 tokens, then 1
+        first = [s for s in tracing.finished_spans()
+                 if s["name"] == "llm.ragged"]
+        eng.generate(list(range(1, 21)), max_new_tokens=4)  # a 16-token chunk
+        eng.generate([4, 5, 6], max_new_tokens=4)
+    finally:
+        eng.shutdown()
+        tracing.disable_tracing()
+    spans = [s for s in tracing.finished_spans() if s["name"] == "llm.ragged"]
+    tracing.clear()
+    assert [s["attributes"] for s in first] == [
+        {"compile": True, "program": "serve.ragged@8"}]
+    assert [s["attributes"] for s in spans] == [
+        {"compile": True, "program": "serve.ragged@8"},
+        {"compile": True, "program": "serve.ragged"}]
+    programs = xprof.programs()
+    for name, shape in (("serve.ragged@8", 8.0), ("serve.ragged", 36.0)):
+        assert programs[name].cost_steps == shape
+        assert programs[name].compile_time_s > 0
+        assert programs[name].compiled_at is not None
+
+
+def test_step_shape_fill_share_reads_the_shape_a_step_ran_at(monkeypatch):
+    """The benchmark's reader divides a step's tokens by the positions
+    its program was compiled for, and by the budget for a program that
+    records no ``shape`` (this PR's parent): there it reads what
+    ``token_budget_fill_share`` reads, and nothing without spans."""
+    from benchmarks import run as bench_run
+    from benchmarks.harness import program_spans
+
+    def read(metric, packs):
+        lines = [[("llm.pack", i, 1, dict(p, seq=i + 1, n_spec=0, budget=36))
+                  for i, p in enumerate(packs)]]
+        monkeypatch.setattr(program_spans, "lines_of", lambda run: lines)
+        return bench_run.reader(metric)(None)
+
+    packs = [{"n_decode": 2, "n_prefill": 0, "shape": 8},
+             {"n_decode": 1, "n_prefill": 17, "shape": 36}]
+    assert read("step_shape_fill_share", packs) == pytest.approx(37.5)
+    assert read("token_budget_fill_share", packs) == pytest.approx(
+        100 * (2 + 18) / 2 / 36)
+    parents = [{k: v for k, v in p.items() if k != "shape"} for p in packs]
+    assert read("step_shape_fill_share", parents) == \
+        read("token_budget_fill_share", parents)
+    assert read("step_shape_fill_share", []) is None
 
 
 def test_trainer_spans_nest_under_the_step(tmp_path):
