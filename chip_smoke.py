@@ -893,6 +893,104 @@ def phase_serve_brumby(platform: str, *, config=None, n_requests: int = 6,
     return out
 
 
+def phase_serve_xing(platform: str, *, config=None, n_requests: int = 6,
+                     prompt_len: int = 600, new_tokens: int = 16,
+                     ready_timeout_s: float = 1200.0) -> dict:
+    """The serving phase's fourth case: latent attention over one page
+    pool, routed experts with no token dropped, a four-stream residual.
+    Xing4.0 at three layers (one dense, two routed) of the published
+    widths through the benchmark's own replica class: the reference
+    check before the engine takes the memory, prompts of several chunks
+    through ``serve.run``, the experts' counters, the served tokens held
+    to the reference with the choices the engine's steps logged in
+    their pages, and the controls, each of which a check has to refuse:
+    a router computed in bfloat16 and a wrong expert on every 50th token
+    (``route``), a pool kept in float8_e4m3fn (``latent_pages``),
+    another request's answer and one replaced token (the served check).
+    ``config`` defaults to the benchmark's file."""
+    import ray_tpu
+    from benchmarks.runners import serve_xing
+    from ray_tpu import serve
+
+    config = config or _benchmark_config("xing4_29b_pp8")
+    config = dict(config, **serve_xing.CHECK_HF,
+                  engine=dict(config["engine"], max_slots=8,
+                              max_seq_len=1024))
+    ray_tpu.init(ignore_reinit_error=True)
+    try:
+        chips = int(ray_tpu.cluster_resources().get("TPU", 0))
+        app = serve.deployment(
+            ray_actor_options=({"num_tpus": chips} if platform == "tpu"
+                               else {}),
+            max_ongoing_requests=2 * n_requests,
+        )(serve_xing.server_class()).bind({"config": config, "seed": 0})
+        t0 = time.perf_counter()
+        handle = serve.run(app, name="chip_smoke_xing", route_prefix=None,
+                           timeout_s=ready_timeout_s)
+        rep = handle.device_report.remote().result(timeout_s=60)
+        check = rep["check"]
+        log(f"  xing replica ready after {time.perf_counter() - t0:.1f}s "
+            f"on {rep['platform']}; reference check: "
+            + " ".join(f"{k}={check[k]['rel_err_prefill']:.2e}/"
+                       f"{check[k]['rel_err_decode']:.2e}"
+                       for k in serve_xing.TOLERANCES)
+            + f" route={check['route']} latent={check['latent_pages']}")
+        if rep["platform"] != platform:
+            raise AssertionError(
+                f"replica computes on {rep['platform']!r}, not "
+                f"{platform!r}")
+        if not check["ok"]:
+            raise AssertionError(f"xing reference check failed: {check}")
+        vocab = config["vocab_size"]
+        prompts = [[(7 * i + 3 * j) % (vocab - 1) + 1
+                    for j in range(prompt_len)] for i in range(n_requests)]
+        pending = [handle.remote({"tokens": p, "max_new_tokens": new_tokens,
+                                  "temperature": 0.0}) for p in prompts]
+        outs = [r.result(timeout_s=ready_timeout_s)["tokens"]
+                for r in pending]
+        check_answers(outs, new_tokens, vocab)
+        counters = handle.counters.remote().result(
+            timeout_s=60)["model_counters"]
+        pairs = [sum(layer) for layer in counters["moe_tokens"]]
+        log(f"  {len(outs)} xing requests answered ({prompt_len} prompt + "
+            f"{new_tokens} new tokens each); pairs served by layer {pairs}")
+        # every token of every request met top-k experts in each layer
+        least = n_requests * (prompt_len + new_tokens - 1) \
+            * config["num_experts_per_tok"]
+        if min(pairs) < least or len(set(pairs)) != 1:
+            raise AssertionError(f"experts' counters: {pairs} < {least}")
+        served = handle.served_check.remote().result(
+            timeout_s=ready_timeout_s)
+        log(f"  served tokens against the reference: {served}")
+        if (not served["ok"] or served["held"] < n_requests
+                or served["tokens"] < new_tokens * min(
+                    n_requests, serve_xing.SERVED_SAMPLES - 1)):
+            raise AssertionError(f"xing served-token check: {served}")
+        controls = {}
+        for name, key in (("route_control", "route"),
+                          ("wrong_expert_control", "route"),
+                          ("cache_control", "latent_pages")):
+            controls[name] = getattr(handle, name).remote().result(
+                timeout_s=ready_timeout_s)[key]
+            log(f"  {name}: {controls[name]} against {check[key]}")
+            if controls[name]["ok"]:
+                raise AssertionError(
+                    f"the check accepts {name}: {controls[name]}")
+        planted = handle.served_control.remote().result(
+            timeout_s=ready_timeout_s)
+        for name in ("other_answer", "one_token"):
+            controls[name] = planted.get(name)
+            log(f"  served check, {name}: {controls[name]}")
+            if controls[name] is None or controls[name]["ok"]:
+                raise AssertionError(
+                    f"the served check accepts {name}: {planted}")
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    return dict(controls, platform=rep["platform"], reference_check=check,
+                model_counters=counters, served_check=served)
+
+
 # ---------------------------------------------------------------------------
 # phase: engine_legacy
 # ---------------------------------------------------------------------------
@@ -1041,6 +1139,7 @@ def run_child(phase: str, expect_loss0) -> int:
         if phase == "serve":
             report["jamba"] = phase_serve_jamba("tpu")
             report["brumby"] = phase_serve_brumby("tpu")
+            report["xing"] = phase_serve_xing("tpu")
     else:
         clock = CompileClock()
         try:

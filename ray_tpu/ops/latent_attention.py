@@ -1,0 +1,440 @@
+"""Ragged attention over a LATENT page pool (multi-head latent attention
+in its absorbed form), and the pool's append.
+
+A latent cache holds one vector a token and layer, ``c | kr``: the
+compressed key/value (``rank`` lanes) followed by the one rotary key all
+heads share.  With the up-projections absorbed into the query and the
+output, every head attends over the same keys, whose first ``rank``
+lanes are also the values:
+
+    score_h(t, s) = q_h(t) . (c | kr)(s) * scale        q_h [rank + rope]
+    out_h(t)      = sum_s softmax_s(score_h)(t, s) c(s)            [rank]
+
+so the pool is ONE operand ``[L, 1, P, page, rank + rope]`` that a cell
+reads once, and the heads are stacked into the ROWS of one matmul against
+it (``[HG * Cq, 576] x [576, page]``) where ``ragged_paged_attention``
+loops over heads of their own keys.  The batch is the engine's ragged
+one (see ``ops/ragged_paged_attention``): rows of (slot, start, len,
+offset) over a flat token buffer, each row's past in the pool under its
+block table, its fresh tokens beside it in ``new``; the pool is read-only
+here and ``ragged_latent_append`` writes the fresh rows afterwards, all
+layers at once, in place.
+
+The grid walks a LIST of the cells that hold work
+(``live_latent_cells``: head group, row, page or self), under a dynamic
+bound, so a page no row reaches is no grid step.  A step makes two
+calls: rows of ONE token through a window of that token alone, all heads
+in one group (32 stacked rows a cell: a decode row through a window of
+32 tokens cost 5.6 us a cell and 17.8 ms a step, PERF.md PR 34), the
+others through the step's whole window with the heads in groups of
+``CHUNK_HEADS`` (outermost in the list, so that a group's output block
+stays where it is; the chunk's flash state then fits the chip's VMEM).
+A call whose list is empty has no grid step.
+The Pallas interpreter takes no dynamic bound: there the grid keeps the
+list's capacity and the steps past its end do nothing, the same body.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import platform
+from ray_tpu.ops.paged_attention import NEG_INF
+from ray_tpu.ops.ragged_paged_attention import (
+    _listed,
+    _pages_per_row,
+    _round8,
+    live_append_cells,
+    window_size,
+)
+
+# heads a group of the chunk call stacks: 16 x a window of 288 rows of
+# float32 state is 9.4 MB of VMEM, all 32 would be 19
+CHUNK_HEADS = 16
+VMEM_LIMIT = 100 * 1024 * 1024
+
+
+# --------------------------------------------------------------------------
+# plain references
+# --------------------------------------------------------------------------
+
+def ragged_latent_attention_reference(
+        q: jax.Array,           # [T, H, rank + rope]
+        new: jax.Array,         # [T, rank + rope] this step's c | kr
+        pages: jax.Array,       # [P, page, rank + rope] one layer's pool
+        row_slot, row_start, row_len, row_off,
+        block_tables: jax.Array,    # [slots, maxp]
+        *, scale: float, rank: int) -> jax.Array:
+    """Dense gather twin: each row's fresh tokens over (its pooled past)
+    + (the row's own fresh tokens, causally), float32 [T, H, rank].
+    Buffer positions no row covers come back zero."""
+    T, H, _ = q.shape
+    P, page, W = pages.shape
+    maxp = block_tables.shape[1]
+    ctx = maxp * page
+    f32 = jnp.float32
+    qf, nf, pf = q.astype(f32), new.astype(f32), pages.astype(f32)
+    ti = jnp.arange(T)
+    out = jnp.zeros((T, H, rank), f32)
+    for r in range(int(row_slot.shape[0])):
+        start, nt, off = row_start[r], row_len[r], row_off[r]
+        kc = pf[jnp.clip(block_tables[row_slot[r]], 0, P - 1)].reshape(ctx, W)
+        trel = ti - off
+        in_row = (trel >= 0) & (trel < nt)
+        s_pool = jnp.einsum("thc,kc->thk", qf, kc) * scale
+        s_self = jnp.einsum("thc,uc->thu", qf, nf) * scale
+        m_pool = in_row[:, None, None] & (jnp.arange(ctx) < start)[None, None]
+        m_self = (in_row[:, None, None] & in_row[None, None, :]
+                  & (trel[None, None, :] <= trel[:, None, None]))
+        s = jnp.concatenate([jnp.where(m_pool, s_pool, NEG_INF),
+                             jnp.where(m_self, s_self, NEG_INF)], -1)
+        p = jax.nn.softmax(s, -1)
+        o = (jnp.einsum("thk,kc->thc", p[..., :ctx], kc[:, :rank])
+             + jnp.einsum("thu,uc->thc", p[..., ctx:], nf[:, :rank]))
+        out = jnp.where(in_row[:, None, None], o, out)
+    return out
+
+
+def ragged_latent_append_reference(pool, new, row_slot, row_start, row_len,
+                                   row_off, block_tables):
+    """Scatter twin of the append: ``pool`` [L, 1, P, page, W], ``new``
+    [L, T, W]; padding lands in the scratch page (the last)."""
+    L, _, P, page, W = pool.shape
+    T = new.shape[1]
+    maxp = block_tables.shape[1]
+    ti = jnp.arange(T)
+    for r in range(int(row_slot.shape[0])):
+        trel = ti - row_off[r]
+        in_row = (trel >= 0) & (trel < row_len[r])
+        pos = row_start[r] + trel
+        pid = jnp.take(jnp.clip(block_tables[row_slot[r]], 0, P - 1),
+                       jnp.clip(pos // page, 0, maxp - 1))
+        pid = jnp.where(in_row, pid, P - 1)
+        offp = jnp.where(in_row, pos % page, 0)
+        pool = pool.at[:, 0, pid, offp].set(
+            jnp.where(in_row[None, :, None], new.astype(pool.dtype),
+                      pool[:, 0, pid, offp]))
+    return pool
+
+
+# --------------------------------------------------------------------------
+# the cells a step walks
+# --------------------------------------------------------------------------
+
+def live_latent_cells(row_start: jax.Array, row_len: jax.Array,
+                      takes: jax.Array, groups: int, maxp: int,
+                      page: int) -> Tuple[jax.Array, jax.Array]:
+    """``(live_ci, n_live)`` of one call: cell ``(g * R + r) * (maxp + 1)
+    + pc`` is head group ``g``, row ``r``, pool page ``pc`` or the row's
+    self cell where ``pc == maxp``.  Live where the call takes the row
+    (``takes`` [R]) and, for a pool page, where it holds pooled tokens of
+    the row.  Ascending order puts a group's rows together and a row's
+    pages before its self cell, which finalises the row."""
+    pc = jnp.arange(maxp + 1, dtype=jnp.int32)
+    live = takes[:, None] & ((pc == maxp) | (pc * page < row_start[:, None]))
+    return _listed(jnp.broadcast_to(live[None], (groups,) + live.shape))
+
+
+def _calls(T: int, H: int, max_row_tokens: Optional[int]):
+    """The calls of a step of ``T`` positions: (query window, heads a
+    group, takes rows of one token / of more).  A window of 1 is the
+    row's token itself."""
+    return [(1, H, "one"),
+            (window_size(T, max_row_tokens), min(H, CHUNK_HEADS), "more")]
+
+
+def _takes(row_len, which: str):
+    return {"one": row_len == 1, "more": row_len > 1}[which]
+
+
+def latent_cell_count(row_start, row_len, page: int, H: int) -> int:
+    """The cells ``ragged_latent_attention`` walks for a step's packed
+    rows, on the host: each live row's pooled pages plus its self cell,
+    once for each head group of the call that takes it."""
+    start, nlen = np.asarray(row_start), np.asarray(row_len)
+    cells = -(-start // page) + 1
+    groups = np.where(nlen > 1, H // min(H, CHUNK_HEADS), 1)
+    return int(np.sum((nlen > 0) * groups * cells))
+
+
+# --------------------------------------------------------------------------
+# the kernel
+# --------------------------------------------------------------------------
+
+def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
+                   q_ref, new_ref, pool_ref, out_ref, m_s, l_s, acc_s, *,
+                   T: int, Cq: int, HG: int, R: int, maxp: int, page: int,
+                   rank: int, scale: float):
+    del slot_r, bt_r, ly_r      # the index maps' own
+    i = pl.program_id(0)
+    rows = Cq * HG
+    Ck = max(Cq, 8)             # the self cell's keys: a sublane tile
+    shift = HG.bit_length() - 1
+
+    # i < n_live always holds under Mosaic, whose grid ends at n_live;
+    # the interpreter's grid is the list's capacity.
+    @pl.when(i < nl_r[0])
+    def _cell():
+        ci = live_r[i]
+        pc = ci % (maxp + 1)
+        r = (ci // (maxp + 1)) % R
+        start, nt, off = start_r[r], len_r[r], off_r[r]
+        # the keys' window starts on a sublane tile; a query window of
+        # one token is that token (its HG stacked rows are aligned)
+        wk = pl.multiple_of(jnp.minimum((off // 8) * 8, T - Ck), 8)
+        w = off if Cq == 1 else wk
+        wr = pl.multiple_of(w * HG, HG if Cq == 1 else 8 * HG)
+        # stacked row j is token j // HG of the window, head j % HG
+        tj = lax.shift_right_logical(
+            lax.broadcasted_iota(jnp.int32, (rows, 1), 0), shift)
+        trel = w + tj - off
+        valid_q = (trel >= 0) & (trel < nt)
+
+        @pl.when((pc == 0) | (start == 0))
+        def _first():
+            m_s[...] = jnp.full_like(m_s, NEG_INF)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        qs = q_ref[0, pl.ds(wr, rows), :]
+
+        def scores(keys):
+            return lax.dot_general(
+                qs, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+
+        def flash_update(s, keys):
+            """Masked online-softmax update; rows of the window that are
+            not this row's tokens keep their state."""
+            m_prev = m_s[...]
+            m_new = jnp.where(
+                valid_q, jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True)),
+                m_prev)
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_s[...] + jnp.sum(p, -1, keepdims=True)
+            pv = lax.dot_general(
+                p.astype(keys.dtype), keys[:, :rank],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            a_new = acc_s[...] * corr + pv
+            l_s[...] = jnp.where(valid_q, l_new, l_s[...])
+            acc_s[...] = jnp.where(valid_q, a_new, acc_s[...])
+            m_s[...] = m_new
+            return l_new, a_new
+
+        @pl.when(pc < maxp)
+        def _pool_cell():
+            keys = pool_ref[0, 0, 0]
+            kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
+            s = jnp.where(valid_q & (kpos < start), scores(keys), NEG_INF)
+            flash_update(s, keys)
+
+        @pl.when(pc == maxp)
+        def _self_cell():
+            keys = new_ref[pl.ds(wk, Ck), :]
+            krel = wk + lax.broadcasted_iota(jnp.int32, (1, Ck), 1) - off
+            mask = valid_q & (krel >= 0) & (krel < nt) & (krel <= trel)
+            l_new, a_new = flash_update(
+                jnp.where(mask, scores(keys), NEG_INF), keys)
+            o = a_new / jnp.maximum(l_new, 1e-30)
+            cur = out_ref[0, pl.ds(wr, rows), :]
+            out_ref[0, pl.ds(wr, rows), :] = jnp.where(valid_q, o, cur)
+
+
+def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
+                 Cq: int, HG: int, scale: float, rank: int):
+    """One call: the rows ``takes`` marks, through a window of ``Cq``
+    tokens, the heads in groups of ``HG``.  Returns [T, H, rank] float32,
+    defined at the tokens of the rows taken and nowhere else."""
+    T, H, W = q.shape
+    L, _, Pt, page, _ = pool.shape
+    row_slot, row_start, row_len, row_off = rows
+    R = row_slot.shape[0]
+    maxp = block_tables.shape[1]
+    NG = H // HG
+    assert NG * HG == H and HG & (HG - 1) == 0, (H, HG)
+    # [NG, T * HG, W]: stacked row t * HG + h of group g
+    q2 = q.reshape(T, NG, HG, W).transpose(1, 0, 2, 3).reshape(NG, T * HG, W)
+    live_ci, n_live = live_latent_cells(row_start, row_len, takes, NG,
+                                        maxp, page)
+    cap = live_ci.shape[0]
+    prefetch = [row_slot, row_start, row_len, row_off,
+                block_tables.astype(jnp.int32),
+                jnp.asarray(layer, jnp.int32).reshape(1), live_ci, n_live]
+
+    def cell(i, live, nl):
+        return live[jnp.minimum(i, jnp.maximum(nl[0] - 1, 0))]
+
+    def group_map(i, _s, _st, _ln, _of, _bt, _ly, live, nl):
+        return (cell(i, live, nl) // ((maxp + 1) * R), 0, 0)
+
+    def pool_map(i, slot_p, start_p, _ln, _of, bt, ly, live, nl):
+        ci = cell(i, live, nl)
+        r = (ci // (maxp + 1)) % R
+        # the self cell repeats the row's last page: no DMA for it
+        last = jnp.maximum(start_p[r] - 1, 0) // page
+        pe = jnp.minimum(jnp.minimum(ci % (maxp + 1), maxp - 1), last)
+        return (ly[0], 0, jnp.minimum(bt[slot_p[r], pe], Pt - 1), 0, 0)
+
+    interpret = platform.interpret_mode()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(cap if interpret else n_live[0],),
+        in_specs=[
+            pl.BlockSpec((1, T * HG, W), group_map),
+            pl.BlockSpec((T, W), lambda i, *pf: (0, 0)),
+            pl.BlockSpec((1, 1, 1, page, W), pool_map),
+        ],
+        out_specs=pl.BlockSpec((1, T * HG, rank), group_map),
+        scratch_shapes=[
+            pltpu.VMEM((Cq * HG, 1), jnp.float32),
+            pltpu.VMEM((Cq * HG, 1), jnp.float32),
+            pltpu.VMEM((Cq * HG, rank), jnp.float32),
+        ],
+    )
+    kern = functools.partial(
+        _latent_kernel, T=T, Cq=Cq, HG=HG, R=R, maxp=maxp, page=page,
+        rank=rank, scale=scale)
+    out = pl.pallas_call(
+        kern,
+        name="ragged_latent_attention",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((NG, T * HG, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(*prefetch, q2, new, pool)
+    return out.reshape(NG, T, HG, rank).transpose(1, 0, 2, 3).reshape(
+        T, H, rank)
+
+
+def ragged_latent_attention(
+        q: jax.Array,            # [T, H, rank + rope]
+        new: jax.Array,          # [T, rank + rope]
+        pool: jax.Array,         # [L, 1, P, page, rank + rope], scratch last
+        layer: jax.Array,
+        row_slot, row_start, row_len, row_off,
+        block_tables: jax.Array,     # [slots, maxp]
+        *, scale: float, rank: int,
+        max_row_tokens: Optional[int] = None) -> jax.Array:
+    """Causal attention of a ragged token batch over ONE layer of the
+    latent pool (picked through the scalar-prefetched ``layer``), float32
+    [T, H, rank]; zero at positions no row covers.  ``q`` and ``new``
+    share the pool's dtype.  Rows occupy distinct slots."""
+    T, H, _ = q.shape
+    T_p = _round8(T)
+    if T_p != T:
+        q = jnp.pad(q, ((0, T_p - T), (0, 0), (0, 0)))
+        new = jnp.pad(new, ((0, T_p - T), (0, 0)))
+    rows = tuple(a.astype(jnp.int32)
+                 for a in (row_slot, row_start, row_len, row_off))
+    row_len, row_off = rows[2], rows[3]
+    trel = jnp.arange(T_p)[:, None] - row_off[None, :]       # [T, R]
+    out = jnp.zeros((T_p, H, rank), jnp.float32)
+    # each call's output is defined at its own rows' tokens and nowhere
+    # else (a call with no row writes nothing)
+    for Cq, HG, which in _calls(T_p, H, max_row_tokens):
+        takes = _takes(row_len, which)
+        got = _latent_call(q, new, pool, layer, rows, block_tables, takes,
+                           Cq=Cq, HG=HG, scale=scale, rank=rank)
+        mine = jnp.any((trel >= 0) & (trel < row_len[None, :])
+                       & takes[None, :], axis=1)
+        out = jnp.where(mine[:, None, None], got, out)
+    return out[:T]
+
+
+# --------------------------------------------------------------------------
+# the append
+# --------------------------------------------------------------------------
+
+def _append_kernel(_slot_r, start_r, len_r, off_r, _bt_r, live_r, nl_r,
+                   new_ref, pool_ref, pool_out, *, T: int, Cq: int,
+                   page: int, NPR: int):
+    i = pl.program_id(1)
+
+    @pl.when(i < nl_r[0])
+    def _cell():
+        ci = live_r[i]
+        r, j = ci // NPR, ci % NPR
+        start, nt, off = start_r[r], len_r[r], off_r[r]
+        w = pl.multiple_of(jnp.minimum((off // 8) * 8, T - Cq), 8)
+        base = (start // page + j) * page
+        live = (base < start + nt) & (nt > 0)
+        tpage = base + lax.broadcasted_iota(jnp.int32, (page, 1), 0) - start
+        mask_w = (tpage >= 0) & (tpage < nt) & live
+        krel = w + lax.broadcasted_iota(jnp.int32, (1, Cq), 1) - off
+        # one-hot gather of the window's rows into the page's: exact in
+        # the pool's own dtype, one term a row
+        fresh = new_ref[0, pl.ds(w, Cq), :]
+        oh = ((tpage == krel) & (krel >= 0) & (krel < nt) & live)
+        got = lax.dot_general(oh.astype(fresh.dtype), fresh,
+                              (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        pool_out[0, 0, 0] = jnp.where(
+            mask_w, got, pool_ref[0, 0, 0].astype(jnp.float32)).astype(
+                pool_out.dtype)
+
+
+def ragged_latent_append(pool: jax.Array,     # [L, 1, P, page, W]
+                         new: jax.Array,      # [L, T, W]
+                         row_slot, row_start, row_len, row_off,
+                         block_tables: jax.Array, *,
+                         max_row_tokens: Optional[int] = None) -> jax.Array:
+    """In-place append of every row's fresh ``c | kr`` into its pages,
+    all layers at once: ``ragged_paged_append``'s walk (grid ``(L,
+    n_live)`` over ``live_append_cells``) for a pool of one leaf and one
+    head."""
+    L, _, Pt, page, W = pool.shape
+    T = new.shape[1]
+    R = row_slot.shape[0]
+    maxp = block_tables.shape[1]
+    T_p = _round8(T)
+    if T_p != T:
+        new = jnp.pad(new, ((0, 0), (0, T_p - T), (0, 0)))
+    Cq = window_size(T_p, max_row_tokens)
+    NPR = _pages_per_row(Cq, page)
+    row_start = row_start.astype(jnp.int32)
+    row_len = row_len.astype(jnp.int32)
+    live_ci, n_live = live_append_cells(row_start, row_len, NPR, page)
+    prefetch = [row_slot.astype(jnp.int32), row_start, row_len,
+                row_off.astype(jnp.int32), block_tables.astype(jnp.int32),
+                live_ci, n_live]
+
+    def pool_map(l, i, slot_p, start_p, len_p, _off, bt, cells, nl):
+        ci = cells[i]
+        r = ci // NPR
+        start, nt = start_p[r], len_p[r]
+        pg = start // page + ci % NPR
+        lastp = (start + jnp.maximum(nt, 1) - 1) // page
+        pid = jnp.minimum(bt[slot_p[r], jnp.minimum(pg, maxp - 1)], Pt - 1)
+        # a cell that writes nothing names the scratch page, never a live
+        # one (see ``ragged_paged_attention._ragged_append``)
+        live = (i < nl[0]) & (nt > 0) & (pg <= lastp)
+        return (l, 0, jnp.where(live, pid, Pt - 1), 0, 0)
+
+    interpret = platform.interpret_mode()
+    pool_spec = pl.BlockSpec((1, 1, 1, page, W), pool_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(L, R * NPR if interpret else n_live[0]),
+        in_specs=[pl.BlockSpec((1, T_p, W), lambda l, i, *pf: (l, 0, 0)),
+                  pool_spec],
+        out_specs=pool_spec,
+    )
+    kern = functools.partial(_append_kernel, T=T_p, Cq=Cq, page=page,
+                             NPR=NPR)
+    return pl.pallas_call(
+        kern,
+        name="ragged_latent_append",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={len(prefetch) + 1: 0},
+        interpret=interpret,
+    )(*prefetch, new.astype(pool.dtype), pool)

@@ -28,6 +28,7 @@ shapes and hates per-request recompiles.  Design:
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import itertools
 import logging
 import queue
@@ -260,6 +261,16 @@ def _telemetry():
                 "cache holds recurrent state: each one resets its "
                 "slot's state to zero on the device (a new request, or "
                 "a preempted one prefilling again from token 0).",
+            ),
+            # keyed by the cache's counter leaf it is read from
+            # (PagedEngineAdapter.counter_leaves)
+            "moe_tokens": metrics.Counter(
+                "raytpu_serve_moe_expert_tokens_total",
+                "(token, expert) pairs a routed expert has served, by "
+                "routed layer and expert: read from the counter the "
+                "step keeps on the device, when the engine's stats are "
+                "asked for.",
+                tag_keys=("layer", "expert"),
             ),
             "collective_bytes": metrics.Counter(
                 "raytpu_serve_collective_bytes_total",
@@ -541,6 +552,13 @@ class PagedEngineAdapter:
     # with 0 pages), builds block tables of no column, admits by free
     # slots alone, and its page counters read 0.
     paged_kv: bool = True
+    # The cache tree's leaves that are counters the step adds to on the
+    # device (pairs an expert has served, ...): neither pages nor state
+    # by slot.  The engine leaves them out of its byte counts and reads
+    # them only when asked (``stats()["model_counters"]``); a leaf
+    # ``[layers, experts]`` named as one of the engine's own counters
+    # (``moe_tokens``) also feeds that counter.
+    counter_leaves: Tuple[str, ...] = ()
 
 
 def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
@@ -659,6 +677,47 @@ def brumby_paged_adapter(cfg) -> PagedEngineAdapter:
         state_bytes_per_slot=cfg.state_bytes_per_slot(),
         state_leaves=("ret_s", "ret_z"),
         paged_kv=False,
+    )
+
+
+def xing_paged_adapter(cfg) -> PagedEngineAdapter:
+    """Xing4.0 (models/xing.py): latent attention over ONE page pool
+    (``kv_c``: a token's ``c | kr``, no k and v), routed experts with no
+    token dropped, a four-stream residual.  The cache is pages and
+    nothing by slot, so requests are admitted and pages accounted as for
+    any paged model.  Its step takes neither ``lora=`` nor ``logit_idx=``
+    and it has no ``copy_page``, two-program path or shardings: the
+    engine refuses the prefix cache (and KV migration with it),
+    speculative decoding and a mesh for it."""
+    from ray_tpu.models import xing
+    from ray_tpu.ops.latent_attention import latent_cell_count
+
+    def init_cache(num_pages, page):
+        from ray_tpu.util import flight_recorder
+
+        cache = xing.init_cache(cfg, num_pages, page)
+        experts = 3 * cfg.dim * cfg.moe_dim * jnp.dtype(
+            cfg.param_dtype).itemsize
+        flight_recorder.record(
+            "serve_model_parts", model="xing",
+            expert_bytes=experts, experts_per_layer=cfg.n_experts,
+            routed_layers=cfg.n_moe,
+            routed_expert_bytes=experts * cfg.n_experts * cfg.n_moe,
+            latent_pool_bytes=int(cache["kv_c"].size
+                                  * cache["kv_c"].dtype.itemsize),
+            latent_bytes_per_token=cfg.n_layers * cfg.pool_width
+            * cache["kv_c"].dtype.itemsize)
+        return cache
+
+    return PagedEngineAdapter(
+        init_cache=init_cache,
+        ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
+        row_len, row_off, bt, cache:
+            xing.ragged_step(params, tokens, tok_pos, row_slot, row_start,
+                             row_len, row_off, bt, cfg, cache),
+        ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
+            latent_cell_count(row_start, row_len, page, cfg.n_heads),
+        counter_leaves=("moe_tokens", "moe_distinct"),
     )
 
 
@@ -1228,6 +1287,7 @@ class LLMEngine:
         self._state_bytes_per_slot = int(adapter.state_bytes_per_slot)
         self._ragged_grid_cells = adapter.ragged_grid_cells
         self._state_resets = 0
+        self._counters_exported: Dict[str, Any] = {}
         if self._state_bytes_per_slot:
             why = ("the adapter's cache holds per-slot recurrent state "
                    f"({self._state_bytes_per_slot} bytes a slot), which "
@@ -1283,7 +1343,8 @@ class LLMEngine:
         # holds by slot (the leaves the adapter names) and the page
         # pools with their scales (every other leaf).
         parts = ({k: int(v.size * v.dtype.itemsize)
-                  for k, v in self._cache.items()}
+                  for k, v in self._cache.items()
+                  if k not in adapter.counter_leaves}
                  if isinstance(self._cache, dict) else {})
         self._state_cache_bytes = sum(
             parts[k] for k in adapter.state_leaves)
@@ -1580,6 +1641,20 @@ class LLMEngine:
                     return adapter.copy_page(cache, src, dst)
 
                 self._copy_page_fn = copy_page_fn
+                # the migration programs below read and write the pool
+                # by these names
+                pool_leaves = ([] if not isinstance(self._cache, dict)
+                               else [k for k in self._cache
+                                     if k not in adapter.state_leaves
+                                     and k not in adapter.counter_leaves])
+                for leaf in pool_leaves:
+                    if leaf not in ("k", "v", "k_scale", "v_scale"):
+                        raise ValueError(
+                            "prefix_cache builds the KV migration "
+                            "programs, which ship the page pools "
+                            "\"k\"/\"v\" (and their scales) by name; this "
+                            f"adapter's pool has the leaf {leaf!r} — set "
+                            "EngineConfig.prefix_cache=False")
 
                 # Migration gather/scatter (serve/kv_transfer).  Page
                 # ids are padded to a power of two (fill = the OOB
@@ -1611,6 +1686,13 @@ class LLMEngine:
                 self._mig_gather_fn = mig_gather_fn
                 self._mig_scatter_fn = mig_scatter_fn
             if config.spec_decode:
+                if "logit_idx" not in inspect.signature(
+                        adapter.ragged_step).parameters:
+                    raise ValueError(
+                        "EngineConfig.spec_decode needs a ragged_step "
+                        "that takes logit_idx= (the verify rows' extra "
+                        "logits); this adapter's does not — set "
+                        "spec_decode=False")
                 self._init_spec(draft_params, draft_adapter)
         else:
             if config.spec_decode:
@@ -1991,6 +2073,8 @@ class LLMEngine:
                 "live": slots - len(self._free_slots),
                 "resets": self._state_resets,
             }
+        if self.adapter.counter_leaves:
+            out["model_counters"] = self._model_counters()
         if self._spec_on:
             out["spec"] = {
                 "rounds": self._spec_rounds,
@@ -2005,6 +2089,26 @@ class LLMEngine:
                 "draft_pages_free": len(self._draft_free),
             }
         return out
+
+    def _model_counters(self) -> Dict[str, Any]:
+        """The cache's counter leaves (PagedEngineAdapter.counter_leaves)
+        with the step whose end they show (``step``), handed out by the
+        loop thread (``read_cache``): a few hundred bytes, only when
+        asked.  A leaf the engine has a counter for feeds it by what it
+        grew."""
+        leaves = self.adapter.counter_leaves
+        step, got = self.read_cache(
+            lambda cache: {k: jnp.copy(cache[k]) for k in leaves})
+        for name, now in got.items():
+            if name not in self._tm:
+                continue
+            grew = now - self._counters_exported.get(name, 0)
+            self._counters_exported[name] = now
+            for layer, expert in zip(*np.nonzero(grew)):
+                self._tm[name].inc(
+                    int(grew[layer, expert]),
+                    tags={"layer": str(layer), "expert": str(expert)})
+        return {"step": step, **{k: v.tolist() for k, v in got.items()}}
 
     def admission_queue_age(self) -> float:
         """Public face of the admission-queue-age gauge: seconds the
@@ -2055,13 +2159,23 @@ class LLMEngine:
         once the engine is stopped the audit runs inline, because no
         mutator is left.  ``deep=False`` runs only the O(slots)
         conservation tier."""
+        return self._on_loop(lambda: self._auditor.run(deep=deep),
+                             timeout_s, "doctor audit")
+
+    def _on_loop(self, fn: Callable[[], Any], timeout_s: float,
+                 what: str) -> Any:
+        """``fn()`` on the loop thread, between two dispatches (the loop
+        owns the slot and page registries and the cache tree it donates
+        to every step); inline once the engine is stopped, because no
+        mutator is left."""
+        if threading.current_thread() is self._thread:
+            return fn()
         if self._stopped.is_set() or not self._thread.is_alive():
             # Let a stopping loop finish its final-audit/cleanup pass
-            # first so the inline walk never races it.
+            # first so the inline call never races it.
             self._thread.join(timeout=5.0)
-            return self._auditor.run(deep=deep)
-        op: Dict[str, Any] = {"deep": bool(deep),
-                              "done": threading.Event(),
+            return fn()
+        op: Dict[str, Any] = {"fn": fn, "done": threading.Event(),
                               "result": None, "error": None}
         with self._audit_lock:
             self._audit_ops.append(op)
@@ -2075,12 +2189,24 @@ class LLMEngine:
             if not op["done"].is_set():
                 if self._stopped.is_set():
                     self._thread.join(timeout=5.0)
-                    return self._auditor.run(deep=deep)
+                    return fn()
                 raise TimeoutError(
-                    f"doctor audit not serviced within {timeout_s}s")
+                    f"{what} not serviced within {timeout_s}s")
         if op["error"] is not None:
             raise op["error"]
         return op["result"]
+
+    def read_cache(self, fn: Callable[[Any], Any],
+                   timeout_s: float = 30.0) -> Tuple[int, Any]:
+        """``(step, fn(cache))`` as host arrays: what the cache tree
+        holds once step ``step`` (``stats()["steps"]``'s count) has run.
+        ``fn`` runs on the loop thread between two dispatches and must
+        return NEW device arrays (a slice, a copy, a reduction), never a
+        leaf itself: the next step donates the tree.  The transfer to
+        the host waits on the caller's thread, not the loop's."""
+        step, out = self._on_loop(
+            lambda: (self._steps, fn(self._cache)), timeout_s, "cache read")
+        return step, jax.device_get(out)
 
     def doctor_report(self) -> Optional[Dict[str, Any]]:
         """The most recent audit report without running a new pass
@@ -2890,11 +3016,15 @@ class LLMEngine:
             # rows that start a sequence (a recurrent-state cache resets
             # their slot on the device) and the step's longest row (what
             # a scan over a row's tokens walks)
+            # tokens the packed rows' sequences already hold: what a
+            # step's attention reads of the pool, to the token
+            "ctx_tokens": sum(r["start"] for r in rows),
             "n_state_reset": sum(1 for r in rows if r["start"] == 0),
             "scan_len": max(len(r["tokens"] or (0,)) for r in rows),
         }
         if not self._paged_kv:      # no page, so no cell of any
-            counts.update(live_cells=0, grid_cells=0, append_cells=0)
+            counts.update(live_cells=0, grid_cells=0, append_cells=0,
+                          ctx_tokens=0)
         return name, fn, args, parts, finishing, counts
 
     def _commit_ragged_step(self, parts, finishing, counts,
@@ -3900,16 +4030,16 @@ class LLMEngine:
     # -- invariant audits (serve/audit, util/doctor) ------------------------
 
     def _process_audits(self) -> None:
-        """Service queued doctor() ops on the loop thread — the only
-        thread allowed to walk slot/page state while the engine
-        runs."""
+        """Service queued doctor() and read_cache() ops (``_on_loop``) on
+        the loop thread — the only thread allowed to walk slot/page
+        state, or to touch the cache tree, while the engine runs."""
         with self._audit_lock:
             if not self._audit_ops:
                 return
             ops, self._audit_ops = self._audit_ops, []
         for op in ops:
             try:
-                op["result"] = self._auditor.run(deep=op["deep"])
+                op["result"] = op["fn"]()
             except Exception as e:
                 op["error"] = e
             op["done"].set()

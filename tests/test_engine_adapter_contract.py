@@ -1,6 +1,6 @@
 """What ``LLMEngine`` may assume of a model: the seam is
 ``PagedEngineAdapter`` with ONE step plug.  The same contract is held
-to every adapter in the tree (llama, llama with LoRA, Jamba, Brumby), so a new
+to every adapter in the tree (llama, llama with LoRA, Jamba, Brumby, Xing), so a new
 model family knows what it has to provide."""
 
 import dataclasses
@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import brumby, jamba, llama
+from ray_tpu.models import brumby, jamba, llama, xing
 from ray_tpu.ops import segmented_lora
 from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
 from ray_tpu.serve.llm_engine import (
@@ -20,6 +20,7 @@ from ray_tpu.serve.llm_engine import (
     jamba_paged_adapter,
     llama_paged_adapter,
     ragged_step_shapes,
+    xing_paged_adapter,
 )
 
 LLAMA = llama.LlamaConfig(
@@ -35,6 +36,11 @@ JAMBA = jamba.JambaConfig(
 BRUMBY = brumby.BrumbyConfig(
     vocab_size=97, dim=32, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=16,
     mlp_dim=64, dtype=jnp.float32, param_dtype=jnp.float32)
+XING = xing.XingConfig(
+    vocab_size=97, dim=64, n_layers=4, n_heads=4, n_kv_heads=4, mlp_dim=96,
+    first_dense=2, q_rank=16, kv_rank=8, nope_dim=16, rope_dim=8, v_dim=16,
+    n_experts=8, top_k=2, moe_dim=32, dtype=jnp.float32,
+    param_dtype=jnp.float32)
 PAGE, SLOTS, MAXP, BUDGET = 8, 4, 4, 24
 TABLE = np.arange(SLOTS * MAXP, dtype=np.int32).reshape(SLOTS, MAXP)
 ROWS = [{"slot": 2, "start": 0, "tokens": [5, 9, 2, 7, 1, 3]},
@@ -48,6 +54,7 @@ CASES = {
                    {"lora", "logit_idx"}),
     "jamba": (jamba_paged_adapter, JAMBA, jamba.init_params, set()),
     "brumby": (brumby_paged_adapter, BRUMBY, brumby.init_params, set()),
+    "xing": (xing_paged_adapter, XING, xing.init_params, set()),
 }
 
 
@@ -80,6 +87,9 @@ def test_ragged_step_is_the_one_step_plug(case):
         assert adapter.paged_kv == (set(adapter.state_leaves) < set(cache))
     else:
         assert not adapter.state_leaves
+    # counters the step keeps are leaves of the tree, and not state by slot
+    assert set(adapter.counter_leaves) <= set(cache)
+    assert not set(adapter.counter_leaves) & set(adapter.state_leaves)
     (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off) = \
         pack_ragged_batch(ROWS, BUDGET, SLOTS)
     nine = (params, toks, pos, r_slot, r_start, r_len, r_off, TABLE, cache)

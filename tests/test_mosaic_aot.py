@@ -662,3 +662,118 @@ def test_brumby_cell_step_updates_the_state_in_place(v5e, shape):
                 ] == []
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
+# -- the Xing cell: latent page pool, routed experts, four-stream residual ---
+
+def _xing_cell():
+    from benchmarks.runners.serve_xing import model_config
+
+    config = json.loads(
+        (REPO / "benchmarks" / "configs" / "xing4_29b_pp8.json").read_text())
+    eng = config["engine"]
+    return model_config(config), eng, eng["max_slots"] + eng["prefill_chunk"]
+
+
+@pytest.mark.parametrize("shape", ["budget", "small"])
+def test_latent_attention_kernel(v5e, shape):
+    """``ragged_latent_attention`` and the pool's append at the cell's
+    sizes: 32 heads of 640 lanes over a pool of 2817 pages of 64 tokens
+    and 7 layers (1.6 GB), in the two calls of a step that carries a
+    chunk (288 positions) and the one call of a decode step (32)."""
+    from ray_tpu.ops import latent_attention as la
+
+    cfg, eng, T = _xing_cell()
+    slots, page = eng["max_slots"], eng["page_size"]
+    maxp = eng["max_seq_len"] // page
+    T = _step_shapes(T, slots)[shape]
+    assert (cfg.n_heads, cfg.pool_width, cfg.kv_rank, maxp) == (
+        32, 640, 512, 88)
+    mesh = _one(v5e)
+    rows = _sds(slots, dtype=jnp.int32)
+    pool = _sds(cfg.n_layers, 1, slots * maxp + 1, page, cfg.pool_width)
+
+    def both(q, new, fresh, pool, layer, rs, r0, rl, ro, bt):
+        out = la.ragged_latent_attention(
+            q, new, pool, layer, rs, r0, rl, ro, bt,
+            scale=cfg.softmax_scale, rank=cfg.kv_rank)
+        return out, la.ragged_latent_append(pool, fresh, rs, r0, rl, ro, bt)
+
+    compiled = _compile(both, *_on(mesh, (
+        _sds(T, cfg.n_heads, cfg.pool_width), _sds(T, cfg.pool_width),
+        _sds(cfg.n_layers, T, cfg.pool_width), pool,
+        _sds(dtype=jnp.int32), rows, rows, rows, rows,
+        _sds(slots, maxp, dtype=jnp.int32))), donate_argnums=(3,))
+    text = compiled.as_text()
+    assert text.count('"ragged_latent_attention"') or \
+        "ragged_latent_attention" in text
+    assert "ragged_latent_append" in text
+    # the pool is appended to where it lies
+    assert [ln for ln in text.splitlines() if re.search(
+        r"= \S*bf16\[7,1,2817,64,640\]\S* copy\(", ln)] == []
+
+
+def test_moe_grouped_ffn_kernel(v5e):
+    """``moe_grouped_ffn`` at the cell's sizes: 288 tokens' 1152 pairs over
+    64 experts of 3 x 3584 x 1024, one routed layer's leaves whole."""
+    from ray_tpu.ops import moe_experts as moe
+
+    cfg, _eng, T = _xing_cell()
+    mesh = _one(v5e)
+    E, D, F, k = cfg.n_experts, cfg.dim, cfg.moe_dim, cfg.top_k
+    assert (T, E, D, F, k) == (288, 64, 3584, 1024, 4)
+    experts = {"w_gate": _sds(E, D, F), "w_up": _sds(E, D, F),
+               "w_down": _sds(E, F, D)}
+    compiled = _compile(
+        moe.routed_experts,
+        *_on(mesh, (_sds(T, D), _sds(T, k, dtype=jnp.int32),
+                    _sds(T, k, dtype=jnp.float32), experts,
+                    _sds(T, dtype=jnp.bool_))))
+    text = compiled.as_text()
+    assert "moe_grouped_ffn" in text
+    assert [ln for ln in text.splitlines() if re.search(
+        r"= \S*bf16\[64,(3584,1024|1024,3584)\]\S* copy\(", ln)] == []
+
+
+@pytest.mark.parametrize("shape", ["budget", "small"])
+def test_xing_cell_step_copies_neither_pool_nor_experts(v5e, shape):
+    """The step program of ``xing4_29b_pp8-reason`` at its seven layers
+    and published widths, in both shapes the engine compiles (288
+    positions and 32), fits the chip with its weights (9.17 GiB) and pool
+    (1.5 GiB), and copies neither: the pool's alias holds through the
+    layers, and no routed layer's experts (1.4 GB) are sliced out in
+    front of the grouped products."""
+    from ray_tpu.models import xing
+
+    cfg, eng, T = _xing_cell()
+    slots, page = eng["max_slots"], eng["page_size"]
+    maxp = eng["max_seq_len"] // page
+    shapes = _step_shapes(T, slots)
+    assert (cfg.n_layers, cfg.first_dense, cfg.dim, shapes) == (
+        7, 2, 3584, {"budget": 288, "small": 32})
+    T = shapes[shape]
+    mesh = _one(v5e)
+    params = _on(mesh, jax.eval_shape(
+        lambda: xing.init_params(jax.random.key(0), cfg)))
+    cache = _on(mesh, jax.eval_shape(
+        lambda: xing.init_cache(cfg, slots * maxp, page)))
+    assert set(cache) == {"kv_c", "moe_tokens", "moe_distinct"}
+    toks, rows, bt = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, maxp, dtype=jnp.int32)))
+    compiled = _compile(
+        lambda p, t, pos, rs, r0, rl, ro, b, c:
+        xing.ragged_step(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+        params, toks, toks, rows, rows, rows, rows, bt, cache,
+        donate_argnums=(8,))
+    text = compiled.as_text()
+    for kernel in ("ragged_latent_attention", "ragged_latent_append"):
+        assert kernel in text
+    for big in ("bf16[7,1,2817,64,640]", "bf16[64,3584,1024]",
+                "bf16[64,1024,3584]"):
+        assert [ln for ln in text.splitlines()
+                if re.search(r"= \S*" + re.escape(big) + r"\S* copy\(", ln)
+                ] == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
