@@ -442,26 +442,39 @@ def _kernels_ragged(d: KernelDims) -> None:
     v_new = _rand(ks[2], k_new.shape, jnp.bfloat16)
     layer = d.layers - 1
     f32 = jnp.float32
-    meta = (r_slot, r_start, r_len, r_off, bt)
 
-    def reference(kp, vp, **scales):
+    def reference(kp, vp, meta, **scales):
         with jax.default_matmul_precision("highest"):
             return jax.jit(functools.partial(
                 rpa.ragged_attention_reference, **scales))(
                     q.astype(f32), k_new[layer].astype(f32),
                     v_new[layer].astype(f32), kp[layer], vp[layer], *meta)
 
-    check_close("ragged_paged_attention", jax.jit(rpa.ragged_paged_attention)(
-        q, k_new[layer], v_new[layer], k_pools, v_pools, jnp.int32(layer),
-        *meta), reference(k_pools.astype(f32), v_pools.astype(f32)), 2e-2)
     k8, k_sc, _ = _quantize_pools(k_pools)
     v8, v_sc, _ = _quantize_pools(v_pools)
-    check_close("ragged_paged_attention[int8]", jax.jit(
+    attend = jax.jit(rpa.ragged_paged_attention)
+    attend8 = jax.jit(
         lambda q, k, v, kp, vp, ks, vs, ly, *m: rpa.ragged_paged_attention(
-            q, k, v, kp, vp, ly, *m, k_scales=ks, v_scales=vs))(
-        q, k_new[layer], v_new[layer], k8, v8, k_sc, v_sc,
-        jnp.int32(layer), *meta),
-        reference(k8, v8, k_scales=k_sc[layer], v_scales=v_sc[layer]), 2e-2)
+            q, k, v, kp, vp, ly, *m, k_scales=ks, v_scales=vs))
+    # The step as packed (decode rows beside chunks), then with only its
+    # rows of one token and only its chunks: each leaves one of the
+    # kernel's two calls no cell to walk, and what that call does not
+    # write must not reach the result.
+    for case, lens in (("", r_len),
+                       ("[decode rows only]", np.where(r_len == 1, 1, 0)),
+                       ("[chunks only]", np.where(r_len > 1, r_len, 0))):
+        meta = (r_slot, r_start, lens.astype(np.int32), r_off, bt)
+        check_close(
+            "ragged_paged_attention" + case,
+            attend(q, k_new[layer], v_new[layer], k_pools, v_pools,
+                   jnp.int32(layer), *meta),
+            reference(k_pools.astype(f32), v_pools.astype(f32), meta), 2e-2)
+        check_close(
+            "ragged_paged_attention[int8]" + case,
+            attend8(q, k_new[layer], v_new[layer], k8, v8, k_sc, v_sc,
+                    jnp.int32(layer), *meta),
+            reference(k8, v8, meta, k_scales=k_sc[layer],
+                      v_scales=v_sc[layer]), 2e-2)
 
     # The append as packed, then with a padding row between live ones,
     # then with no live row at all (its grid has no cell to walk): pages

@@ -54,6 +54,7 @@ from jax import lax
 from ray_tpu.models.llama import _head_matmul, _mlp_block, rms_norm
 from ray_tpu.ops.ragged_paged_attention import (
     layer_slice,
+    live_attention_cells,
     ragged_paged_append,
     ragged_paged_attention,
 )
@@ -289,7 +290,8 @@ def _mamba_mixer(u, p, cfg: JambaConfig, conv_l, ssm, li_m, maps, rows):
     return out, new_tail, ssm
 
 
-def _attention_mixer(u, p, cfg: JambaConfig, cache, li_a, rows, block_tables):
+def _attention_mixer(u, p, cfg: JambaConfig, cache, li_a, rows, block_tables,
+                     live_cells):
     """Causal MQA over the paged pool plus the row's own fresh tokens;
     no positional term.  Returns (out [T, D], k [T, KVH, hd], v)."""
     row_slot, row_start, row_len, row_off = rows
@@ -299,7 +301,8 @@ def _attention_mixer(u, p, cfg: JambaConfig, cache, li_a, rows, block_tables):
     v = jnp.einsum("td,dhk->thk", u, p["wv"].astype(dt_))
     out = ragged_paged_attention(
         q, k, v, cache["k"], cache["v"], li_a, row_slot, row_start,
-        row_len, row_off, block_tables)                    # [T, H, hd] f32
+        row_len, row_off, block_tables,
+        live_cells=live_cells)                             # [T, H, hd] f32
     out = jnp.einsum("thk,hkd->td", out.astype(dt_), p["wo"].astype(dt_))
     return out, k, v
 
@@ -346,6 +349,12 @@ def ragged_step(
             normed = rms_norm(h, params["ln_ff"][li], cfg.norm_eps)
             return h + _mlp_block(normed[None], layer, cfg)[0]
 
+    with jax.named_scope("attention"):
+        # the cells of the page table that hold these rows' tokens are
+        # the same in every attention layer: listed once, for all
+        live_cells = live_attention_cells(
+            row_start, row_len, row_off, T, block_tables.shape[1],
+            cache["k"].shape[3])
     ssm = cache["ssm"]
     tails, k_news, v_news = [], [], []
     li_m = li_a = 0
@@ -370,7 +379,8 @@ def ragged_step(
                 p = jax.tree.map(lambda w: w[li_a], params["attn"])
                 normed = rms_norm(x, params["ln_in"][li], cfg.norm_eps)
                 out, k1, v1 = _attention_mixer(
-                    normed, p, cfg, cache, li_a, rows, block_tables)
+                    normed, p, cfg, cache, li_a, rows, block_tables,
+                    live_cells)
             x = feed_forward(x + out, li)
             k_news.append(k1)
             v_news.append(v1)

@@ -1192,19 +1192,6 @@ def ragged_weight_routes(params: Params, cfg: LlamaConfig
     return weight_routes(params["layers"])
 
 
-def ragged_grid_cells(cfg: LlamaConfig, row_start, row_len, maxp: int,
-                      page: int, lora: bool) -> int:
-    """The attention cells ``ragged_step_paged``'s kernel walks for one
-    step's packed row arrays: the fused layer kernel walks each live
-    row's pooled pages plus its self cell; the unfused and LoRA routes'
-    ``ragged_paged_attention`` walks the page table's capacity."""
-    if cfg.fused_decode and not lora and not cfg.tensor_parallel:
-        from ray_tpu.ops.ragged_paged_attention import live_cell_count
-
-        return live_cell_count(row_start, row_len, page)
-    return len(row_len) * (maxp + 1)
-
-
 def ragged_step_paged(
     params: Params,
     tokens: jax.Array,       # [T] flat ragged token buffer
@@ -1261,6 +1248,7 @@ def ragged_step_paged(
     from ray_tpu.ops.ragged_paged_attention import (
         fused_ragged_layer,
         layer_slice,
+        live_attention_cells,
         live_page_cells,
         ragged_paged_append,
         ragged_paged_append_quantized,
@@ -1286,13 +1274,18 @@ def ragged_step_paged(
     # XLA fuses it into the einsum that reads it.
     stacked = params["layers"]
 
-    if cfg.fused_decode and lora is None:
-        # The cells of the page table that hold these rows' tokens are
-        # the same in every layer: listed once, here, for all of them.
-        with jax.named_scope("fused_layer"):
-            live_cells = live_page_cells(
-                row_start, row_len, block_tables.shape[1],
-                cache["k"].shape[3])
+    # The cells of the page table that hold these rows' tokens are the
+    # same in every layer: listed once, here, for all of them (one list
+    # for the fused kernel, one for each call of the unfused).
+    fused = cfg.fused_decode and lora is None
+    maxp, page = block_tables.shape[1], cache["k"].shape[3]
+    with jax.named_scope("fused_layer" if fused else "attention"):
+        live_cells = (
+            live_page_cells(row_start, row_len, maxp, page) if fused
+            else live_attention_cells(row_start, row_len, row_off, T, maxp,
+                                      page))
+
+    if fused:
         layer_fn = partial(
             fused_ragged_layer,
             eps=cfg.norm_eps, n_heads=cfg.n_heads,
@@ -1322,7 +1315,8 @@ def ragged_step_paged(
                     soft_cap=cfg.logits_soft_cap,
                     k_scales=cache.get("k_scale"),
                     v_scales=cache.get("v_scale"),
-                    max_row_tokens=max_row_tokens)     # [T, H, hd] f32
+                    max_row_tokens=max_row_tokens,
+                    live_cells=live_cells)             # [T, H, hd] f32
                 # Round the f32 flash output to cfg.dtype BEFORE the
                 # o-proj — the same cast point as the prefill/decode
                 # paths, which is what keeps greedy argmax bit-identical
@@ -1390,7 +1384,8 @@ def ragged_step_paged(
                 soft_cap=cfg.logits_soft_cap,
                 k_scales=cache.get("k_scale"),
                 v_scales=cache.get("v_scale"),
-                max_row_tokens=max_row_tokens)     # [T, H, hd] f32
+                max_row_tokens=max_row_tokens,
+                live_cells=live_cells)             # [T, H, hd] f32
             attn_f = out.astype(dt)                # base body's cast point
             o = jnp.einsum("thk,hkd->td", attn_f, a["wo"].astype(dt))
             do = delta("o", attn_f.reshape(T, H * hd))

@@ -12,8 +12,8 @@ lanes are also the values:
 
 so the pool is ONE operand ``[L, 1, P, page, rank + rope]`` that a cell
 reads once, and the heads are stacked into the ROWS of one matmul against
-it (``[HG * Cq, 576] x [576, page]``) where ``ragged_paged_attention``
-loops over heads of their own keys.  The batch is the engine's ragged
+it (``[HG * Cq, 576] x [576, page]``), as ``ragged_paged_attention``
+stacks the heads of one KV head.  The batch is the engine's ragged
 one (see ``ops/ragged_paged_attention``): rows of (slot, start, len,
 offset) over a flat token buffer, each row's past in the pool under its
 block table, its fresh tokens beside it in ``new``; the pool is read-only

@@ -35,15 +35,28 @@ Kernel shape (mirrors ops/paged_attention.py's idioms):
   * ``pltpu.PrefetchScalarGridSpec`` carries the row metadata, block
     tables and (int8) page scales on the scalar-prefetch channel so
     BlockSpec index maps can chase pages;
-  * grid (R, maxp + 1): for row r, cells 0..maxp-1 stream the row's
-    live pages (clamped index maps repeat the last live page so Mosaic
-    elides the dead DMAs), cell maxp is the SELF phase — intra-row
-    causal attention against the fresh k/v buffer — which also
-    finalizes the online softmax and writes the output rows;
-  * each row reads its tokens through a static window [w, w + Cq) of
-    the flat buffer with w aligned down to the sublane (8); masks do
-    the raggedness, so rows can start at any offset;
-  * flash state (m, l, acc) lives in VMEM scratch, per q-head.
+  * the grid walks a LIST of the cells that hold work
+    (``live_page_cells``: row r's pooled pages in ascending order, then
+    its SELF cell: intra-row causal attention against the fresh k/v
+    buffer, which also finalizes the online softmax and writes the
+    output rows), under a dynamic bound: a page no row reaches and a
+    padding row are no grid step, where the grid used to be ``(R,
+    maxp + 1)`` whatever the rows held (PERF.md, PR 36).  The Pallas
+    interpreter takes no dynamic bound: there the grid keeps the list's
+    capacity and the steps past its end do nothing, the same body;
+  * a step makes TWO calls, chosen by ``row_len``: rows of ONE token
+    go through a window of that token alone, rows of more through a
+    static window [w, w + Cq) of the flat buffer with w aligned down to
+    the sublane (8); masks do the raggedness, so rows can start at any
+    offset.  Each call's output is defined at its own rows' tokens; a
+    call whose list is empty has no grid step;
+  * the query heads of one KV head are stacked into the ROWS of one
+    product against its page (``[Cq * QP, hd] x [hd, page]``, QP the
+    group rounded up to a sublane tile: 24 for Jamba's 20 heads over
+    one KV head, 8 for a group of 4), the page read and cast once a
+    cell; a cell loops over the KV heads, whose pages arrive together;
+  * flash state (m, l, acc) lives in VMEM scratch, per KV head and
+    stacked row.
 
 ``fused_ragged_layer`` folds the PR-2 per-layer decode megakernel
 (ops/fused_decode.py) over the ragged batch: the same phase-indexed
@@ -90,6 +103,10 @@ from ray_tpu.ops.fused_decode import (
     _qdict,
 )
 from ray_tpu.ops.paged_attention import NEG_INF
+
+# scoped VMEM of the attention calls whose chunk window holds every head's
+# float32 state at once (the default, 16 MiB, holds a decode window only)
+VMEM_LIMIT = 100 * 1024 * 1024
 
 
 def _round8(n: int) -> int:
@@ -221,108 +238,181 @@ def ragged_append_reference(
 # --------------------------------------------------------------------------
 
 
-def _ragged_kernel(*refs, T: int, Cq: int, H: int, KVH: int, qpg: int,
-                   hd: int, page: int, Pt: int, maxp: int, scale: float,
-                   soft_cap: Optional[float], quantized: bool):
-    if quantized:
-        slot_r, start_r, len_r, off_r, bt_r, ly_r, ks_r, vs_r = refs[:8]
-        n_pre = 8
-    else:
-        slot_r, start_r, len_r, off_r, bt_r, ly_r = refs[:6]
-        ks_r = vs_r = None
-        n_pre = 6
-    q_ref, kn_ref, vn_ref, kp_ref, vp_ref = refs[n_pre:n_pre + 5]
-    out_ref = refs[n_pre + 5]
-    m_s, l_s, acc_s = refs[n_pre + 6:]
+def live_attention_cells(row_start: jax.Array, row_len: jax.Array,
+                         row_off: jax.Array, T: int, maxp: int, page: int):
+    """What ``ragged_paged_attention``'s two calls walk for these rows,
+    one entry a call (rows of ONE token, then rows of more): ``(live_ci,
+    n_live, mine)``, the call's ``live_page_cells`` and the buffer
+    positions ``bool[round8(T)]`` its rows' tokens stand at.  The same
+    in every layer: a step builds it once, in front of its layer loop."""
+    row_len = row_len.astype(jnp.int32)
+    trel = jnp.arange(_round8(T))[:, None] - row_off[None, :]     # [T, R]
+    in_row = (trel >= 0) & (trel < row_len[None, :])
+    return tuple(
+        live_page_cells(row_start, row_len, maxp, page, takes)
+        + (jnp.any(in_row & takes[None, :], axis=1),)
+        for takes in (row_len == 1, row_len > 1))
 
-    r = pl.program_id(0)
-    pc = pl.program_id(1)
-    start = start_r[r]
-    nt = len_r[r]
-    off = off_r[r]
-    w = jnp.minimum((off // 8) * 8, T - Cq)
-    w = pl.multiple_of(w, 8)
+
+def _ragged_kernel(*refs, T: int, Cq: int, KVH: int, QP: int, hd: int,
+                   page: int, Pt: int, maxp: int, scale: float,
+                   soft_cap: Optional[float], quantized: bool):
+    slot_r, start_r, len_r, off_r, bt_r, _ly_r, live_r, nl_r = refs[:8]
+    n_pre = 8
+    ks_r = vs_r = None
+    if quantized:
+        ks_r, vs_r = refs[8:10]
+        n_pre = 10
+    (q_ref, kn_ref, vn_ref, kp_ref, vp_ref, out_ref,
+     m_s, l_s, acc_s) = refs[n_pre:]
+
+    i = pl.program_id(0)
+    rows = Cq * QP
+    Ck = max(Cq, 8)             # the self cell's keys: a sublane tile
 
     def capped(s):
         if soft_cap is not None:
             return soft_cap * jnp.tanh(s / soft_cap)
         return s
 
-    @pl.when((r == 0) & (pc == 0))
-    def _init_out():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    # i < n_live always holds under Mosaic, whose grid ends at n_live;
+    # the interpreter's grid is the list's capacity.
+    @pl.when(i < nl_r[0])
+    def _cell():
+        ci = live_r[i]
+        r = ci // (maxp + 1)
+        pc = ci % (maxp + 1)
+        start, nt, off = start_r[r], len_r[r], off_r[r]
+        # the keys' window starts on a sublane tile; a query window of
+        # one token is that token (tokens are the queries' leading axis)
+        wk = pl.multiple_of(jnp.minimum((off // 8) * 8, T - Ck), 8)
+        w = off if Cq == 1 else wk
+        # stacked row j is token j // QP of the window, head j % QP of
+        # the KV head's group
+        tj = lax.broadcasted_iota(jnp.int32, (Cq, QP, 1), 0).reshape(rows, 1)
+        trel = w + tj - off                    # row-relative token index
+        valid_q = (trel >= 0) & (trel < nt)    # [rows, 1]
 
-    @pl.when(pc == 0)
-    def _init_state():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+        @pl.when((pc == 0) | (start == 0))
+        def _first():
+            m_s[...] = jnp.full_like(m_s, NEG_INF)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
 
-    ti = lax.broadcasted_iota(jnp.int32, (Cq, 1), 0)
-    trel = w + ti - off                    # row-relative token index
-    valid_q = (trel >= 0) & (trel < nt)    # [Cq, 1]
+        def scores(g, keys):
+            qg = q_ref[pl.ds(w, Cq), g].astype(jnp.float32).reshape(rows, hd)
+            return lax.dot_general(
+                qg, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
 
-    def flash_update(h, s, v, vscale):
-        """Masked online-softmax update of head h's state; rows whose
-        scores are fully NEG_INF must leave the state untouched (the
-        window overlaps NEIGHBOR rows' tokens)."""
-        upd = valid_q
-        m_prev = m_s[h]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_new = jnp.where(upd, m_new, m_prev)
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_s[h] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if vscale is not None:
-            pv = pv * vscale
-        a_new = acc_s[h] * corr + pv
-        l_s[h] = jnp.where(upd, l_new, l_s[h])
-        acc_s[h] = jnp.where(upd, a_new, acc_s[h])
-        m_s[h] = m_new
-        return l_new, a_new
+        def flash_update(g, s, v, vscale):
+            """Masked online-softmax update of KV head g's stacked
+            rows; rows whose scores are fully NEG_INF must leave the
+            state untouched (the window overlaps NEIGHBOR rows'
+            tokens)."""
+            m_prev = m_s[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_new = jnp.where(valid_q, m_new, m_prev)
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_s[g] + jnp.sum(p, axis=-1, keepdims=True)
+            pv = lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            if vscale is not None:
+                pv = pv * vscale
+            a_new = acc_s[g] * corr + pv
+            l_s[g] = jnp.where(valid_q, l_new, l_s[g])
+            acc_s[g] = jnp.where(valid_q, a_new, acc_s[g])
+            m_s[g] = m_new
+            return l_new, a_new
 
-    # ---- pool cells: one live page of the row's PAST per cell --------
-    @pl.when((pc < maxp) & (pc * page < start) & (nt > 0))
-    def _pool_cell():
-        s_idx = slot_r[r]
-        last = jnp.maximum(start - 1, 0) // page
-        pid = jnp.minimum(bt_r[s_idx, jnp.minimum(pc, last)], Pt - 1)
-        kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        mask = valid_q & (kpos < start)
-        for h in range(H):
-            kvh = h // qpg
-            qh = q_ref[pl.ds(w, Cq), h, :].astype(jnp.float32)
-            k = kp_ref[0, kvh, 0].astype(jnp.float32)
-            s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            if quantized:
-                s = s * ks_r[pid, kvh]
-            s = jnp.where(mask, capped(s), NEG_INF)
-            flash_update(h, s,
-                         vp_ref[0, kvh, 0].astype(jnp.float32),
-                         vs_r[pid, kvh] if quantized else None)
+        # ---- pool cell: one live page of the row's PAST --------------
+        @pl.when(pc < maxp)
+        def _pool_cell():
+            pid = jnp.minimum(bt_r[slot_r[r], pc], Pt - 1)
+            kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
+            mask = valid_q & (kpos < start)
+            for g in range(KVH):
+                s = scores(g, kp_ref[0, g, 0].astype(jnp.float32))
+                if quantized:
+                    s = s * ks_r[pid, g]
+                flash_update(g, jnp.where(mask, capped(s), NEG_INF),
+                             vp_ref[0, g, 0].astype(jnp.float32),
+                             vs_r[pid, g] if quantized else None)
 
-    # ---- self cell: intra-row causal attention + finalize ------------
-    @pl.when((pc == maxp) & (nt > 0))
-    def _self_cell():
-        kj = lax.broadcasted_iota(jnp.int32, (1, Cq), 1)
-        krel = w + kj - off
-        mask = (valid_q & (krel >= 0) & (krel < nt) & (krel <= trel))
-        for h in range(H):
-            kvh = h // qpg
-            qh = q_ref[pl.ds(w, Cq), h, :].astype(jnp.float32)
-            kw = kn_ref[pl.ds(w, Cq), kvh, :].astype(jnp.float32)
-            s = lax.dot_general(qh, kw, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, capped(s), NEG_INF)
-            vw = vn_ref[pl.ds(w, Cq), kvh, :].astype(jnp.float32)
-            l_new, a_new = flash_update(h, s, vw, None)
-            o = a_new / jnp.maximum(l_new, 1e-30)
-            cur = out_ref[pl.ds(w, Cq), h, :].astype(jnp.float32)
-            out_ref[pl.ds(w, Cq), h, :] = jnp.where(
-                valid_q, o, cur).astype(out_ref.dtype)
+        # ---- self cell: intra-row causal attention + finalize --------
+        @pl.when(pc == maxp)
+        def _self_cell():
+            krel = wk + lax.broadcasted_iota(jnp.int32, (1, Ck), 1) - off
+            mask = valid_q & (krel >= 0) & (krel < nt) & (krel <= trel)
+            for g in range(KVH):
+                s = scores(g, kn_ref[g, pl.ds(wk, Ck), :].astype(jnp.float32))
+                l_new, a_new = flash_update(
+                    g, jnp.where(mask, capped(s), NEG_INF),
+                    vn_ref[g, pl.ds(wk, Ck), :].astype(jnp.float32), None)
+                o = a_new / jnp.maximum(l_new, 1e-30)
+                cur = out_ref[pl.ds(w, Cq), g].reshape(rows, hd)
+                out_ref[pl.ds(w, Cq), g] = jnp.where(
+                    valid_q, o, cur).reshape(Cq, QP, hd)
+
+
+def _ragged_call(q, k_new, v_new, k_pools, v_pools, rows, scales, live_ci,
+                 n_live, *, Cq: int, soft_cap: Optional[float]):
+    """One call: the rows whose cells ``live_ci`` lists, through a window
+    of ``Cq`` tokens.  ``q`` [T, KVH, QP, hd], ``k_new`` / ``v_new``
+    [KVH, T, hd], ``rows`` the six scalar-prefetched row arrays (slot,
+    start, len, off, block tables, layer), ``scales`` the layer's two
+    tables of page scales or none; returns float32 like ``q``, defined
+    at the tokens of the rows walked and nowhere else."""
+    T, KVH, QP, hd = q.shape
+    Pt, page = k_pools.shape[2:4]
+    maxp = rows[4].shape[1]
+    prefetch = rows + [live_ci, n_live] + scales
+
+    def const4(i, *pf):
+        return (0, 0, 0, 0)
+
+    def const3(i, *pf):
+        return (0, 0, 0)
+
+    def pool_map(i, slot_p, start_p, _ln, _of, bt, ly, live, nl, *sc):
+        ci = live[jnp.minimum(i, jnp.maximum(nl[0] - 1, 0))]
+        r = ci // (maxp + 1)
+        # the self cell repeats the row's last page: no DMA for it
+        last = jnp.maximum(start_p[r] - 1, 0) // page
+        pe = jnp.minimum(jnp.minimum(ci % (maxp + 1), maxp - 1), last)
+        return (ly[0], 0, jnp.minimum(bt[slot_p[r], pe], Pt - 1), 0, 0)
+
+    interpret = platform.interpret_mode()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(live_ci.shape[0] if interpret else n_live[0],),
+        in_specs=[
+            pl.BlockSpec((T, KVH, QP, hd), const4),
+            pl.BlockSpec((KVH, T, hd), const3),
+            pl.BlockSpec((KVH, T, hd), const3),
+            pl.BlockSpec((1, KVH, 1, page, hd), pool_map),
+            pl.BlockSpec((1, KVH, 1, page, hd), pool_map),
+        ],
+        out_specs=pl.BlockSpec((T, KVH, QP, hd), const4),
+        scratch_shapes=[
+            pltpu.VMEM((KVH, Cq * QP, 1), jnp.float32),
+            pltpu.VMEM((KVH, Cq * QP, 1), jnp.float32),
+            pltpu.VMEM((KVH, Cq * QP, hd), jnp.float32),
+        ],
+    )
+    kern = functools.partial(
+        _ragged_kernel, T=T, Cq=Cq, KVH=KVH, QP=QP, hd=hd, page=page,
+        Pt=Pt, maxp=maxp, scale=hd ** -0.5, soft_cap=soft_cap,
+        quantized=bool(scales))
+    return pl.pallas_call(
+        kern,
+        name="ragged_paged_attention",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, KVH, QP, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(*prefetch, q, k_new, v_new, k_pools, v_pools)
 
 
 def ragged_paged_attention(
@@ -342,73 +432,51 @@ def ragged_paged_attention(
     k_scales: Optional[jax.Array] = None,   # [L, P, KVH, 1]
     v_scales: Optional[jax.Array] = None,
     max_row_tokens: Optional[int] = None,
+    live_cells=None,
 ) -> jax.Array:
     """Causal attention of a ragged token batch against the page pool
     of ONE layer (selected via scalar-prefetched ``layer``), f32 out
-    [T, H, D].  Pools are read-only; append the fresh K/V afterwards
-    with ragged_paged_append*.  Rows must occupy DISTINCT slots (the
-    engine packs at most one row per slot per step)."""
+    [T, H, D]; zero at positions no row covers.  Pools are read-only;
+    append the fresh K/V afterwards with ragged_paged_append*.  Rows
+    must occupy DISTINCT slots (the engine packs at most one row per
+    slot per step).
+
+    Two calls, chosen by ``row_len``: rows of ONE token through a window
+    of that token alone, rows of more through the step's window.  Each
+    walks ``live_cells``' list of its rows' cells (``live_attention_
+    cells``; a caller with a layer loop builds them once in front of it,
+    None builds them here) and its grid ends where the list ends."""
     T, H, hd = q.shape
-    L, KVH, Pt, page, _ = k_pools.shape
+    KVH = k_pools.shape[1]
     maxp = block_tables.shape[1]
-    R = row_slot.shape[0]
     qpg = H // KVH
-    quantized = k_scales is not None
+    QP = _round8(qpg)
     T_p = _round8(T)
-    if T_p != T:
-        padw = T_p - T
-        q = jnp.pad(q, ((0, padw), (0, 0), (0, 0)))
-        k_new = jnp.pad(k_new, ((0, padw), (0, 0), (0, 0)))
-        v_new = jnp.pad(v_new, ((0, padw), (0, 0), (0, 0)))
-    Cq = window_size(T_p, max_row_tokens)
-
-    def const_map(r, pc, *pf):
-        return (0, 0, 0)
-
-    def pool_map(r, pc, slot_p, start_p, len_p, off_p, bt, ly, *sc):
-        s = slot_p[r]
-        last = jnp.maximum(start_p[r] - 1, 0) // page
-        pe = jnp.minimum(jnp.minimum(pc, maxp - 1), last)
-        pid = jnp.minimum(bt[s, pe], Pt - 1)
-        # padding rows (len 0) read the scratch page — garbage-tolerant
-        return (ly[0], 0, jnp.where(len_p[r] > 0, pid, Pt - 1), 0, 0)
-
-    ly = jnp.asarray(layer, jnp.int32).reshape(1)
-    prefetch = [row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
-                row_len.astype(jnp.int32), row_off.astype(jnp.int32),
-                block_tables.astype(jnp.int32), ly]
-    if quantized:
-        ly_s = jnp.asarray(layer, jnp.int32)
-        prefetch += [k_scales[ly_s, :, :, 0], v_scales[ly_s, :, :, 0]]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(R, maxp + 1),
-        in_specs=[
-            pl.BlockSpec((T_p, H, hd), const_map),
-            pl.BlockSpec((T_p, KVH, hd), const_map),
-            pl.BlockSpec((T_p, KVH, hd), const_map),
-            pl.BlockSpec((1, KVH, 1, page, hd), pool_map),
-            pl.BlockSpec((1, KVH, 1, page, hd), pool_map),
-        ],
-        out_specs=pl.BlockSpec((T_p, H, hd), const_map),
-        scratch_shapes=[
-            pltpu.VMEM((H, Cq, 1), jnp.float32),
-            pltpu.VMEM((H, Cq, 1), jnp.float32),
-            pltpu.VMEM((H, Cq, hd), jnp.float32),
-        ],
-    )
-    kern = functools.partial(
-        _ragged_kernel, T=T_p, Cq=Cq, H=H, KVH=KVH, qpg=qpg, hd=hd,
-        page=page, Pt=Pt, maxp=maxp, scale=hd ** -0.5,
-        soft_cap=soft_cap, quantized=quantized)
-    out = pl.pallas_call(
-        kern,
-        name="ragged_paged_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T_p, H, hd), jnp.float32),
-        interpret=platform.interpret_mode(),
-    )(*prefetch, q, k_new, v_new, k_pools, v_pools)
-    return out[:T]
+    if live_cells is None:
+        live_cells = live_attention_cells(
+            row_start, row_len, row_off, T, maxp, k_pools.shape[3])
+    # the query heads of one KV head are the rows of one product against
+    # its page: [T, KVH, QP, hd], a token's group padded to a sublane
+    # tile so that a window's stacked rows are a view of it
+    q = jnp.pad(q.reshape(T, KVH, qpg, hd),
+                ((0, T_p - T), (0, 0), (0, QP - qpg), (0, 0)))
+    k_new, v_new = (jnp.pad(a, ((0, T_p - T), (0, 0), (0, 0))
+                            ).transpose(1, 0, 2) for a in (k_new, v_new))
+    ly = jnp.asarray(layer, jnp.int32)
+    rows = [row_slot.astype(jnp.int32), row_start.astype(jnp.int32),
+            row_len.astype(jnp.int32), row_off.astype(jnp.int32),
+            block_tables.astype(jnp.int32), ly.reshape(1)]
+    scales = ([] if k_scales is None
+              else [k_scales[ly, :, :, 0], v_scales[ly, :, :, 0]])
+    out = jnp.zeros((T_p, KVH, QP, hd), jnp.float32)
+    # each call's output is defined at its own rows' tokens and nowhere
+    # else (a call with no row writes nothing)
+    for Cq, (live_ci, n_live, mine) in zip(
+            (1, window_size(T_p, max_row_tokens)), live_cells):
+        got = _ragged_call(q, k_new, v_new, k_pools, v_pools, rows, scales,
+                           live_ci, n_live, Cq=Cq, soft_cap=soft_cap)
+        out = jnp.where(mine[:, None, None, None], got, out)
+    return out[:T, :, :qpg].reshape(T, H, hd)
 
 
 # --------------------------------------------------------------------------
@@ -920,25 +988,30 @@ def _built(pair):
 
 
 def live_page_cells(row_start: jax.Array, row_len: jax.Array, maxp: int,
-                    page: int) -> Tuple[jax.Array, jax.Array]:
-    """The attention cells of ``fused_ragged_layer`` that hold work for
-    these rows: ``(live_ci, n_live)``.  Cell ``r * (maxp + 1) + pc`` is
-    row ``r``'s pool page ``pc``, or its self cell where ``pc == maxp``;
-    it is live where the row has tokens (``row_len > 0``) and, for a
-    pool page, where the page holds pooled tokens of the row
-    (``pc * page < row_start``).  ``live_ci`` ``[R * (maxp + 1)]`` lists
-    the live cells in ascending order, so a row's pool pages come before
-    its self cell, which finalises the row; past ``n_live`` ``[1]`` it is
-    padding.  It follows from the row arrays alone, not from the layer:
-    a step builds it once, in front of its layer loop."""
+                    page: int, takes: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """The attention cells that hold work for these rows: ``(live_ci,
+    n_live)``.  Cell ``r * (maxp + 1) + pc`` is row ``r``'s pool page
+    ``pc``, or its self cell where ``pc == maxp``; it is live where the
+    walk takes the row (``takes`` [R]; None: every row with tokens,
+    ``row_len > 0``) and, for a pool page, where the page holds pooled
+    tokens of the row (``pc * page < row_start``).  ``live_ci``
+    ``[R * (maxp + 1)]`` lists the live cells in ascending order, so a
+    row's pool pages come before its self cell, which finalises the row;
+    past ``n_live`` ``[1]`` it is padding.  It follows from the row
+    arrays alone, not from the layer: a step builds it once, in front of
+    its layer loop."""
     pc = jnp.arange(maxp + 1, dtype=jnp.int32)
-    return _listed((row_len[:, None] > 0) & (
+    if takes is None:
+        takes = row_len > 0
+    return _listed(takes[:, None] & (
         (pc == maxp) | (pc * page < row_start[:, None])))
 
 
 def live_cell_count(row_start, row_len, page: int) -> int:
     """``live_page_cells``' ``n_live`` on the host, from the packed row
-    arrays: each live row's pooled pages plus its self cell."""
+    arrays: each live row's pooled pages plus its self cell.  Both calls
+    of ``ragged_paged_attention`` together walk as many."""
     start, nlen = np.asarray(row_start), np.asarray(row_len)
     return int(np.sum((nlen > 0) * (-(-start // page) + 1)))
 

@@ -568,6 +568,7 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
     which every replica resolves identically — the property adapter
     failover relies on."""
     from ray_tpu.models import llama
+    from ray_tpu.ops.ragged_paged_attention import live_cell_count
 
     make_adapter_pool = None
     if getattr(cfg, "lora", None) is not None:
@@ -634,9 +635,10 @@ def llama_paged_adapter(cfg, lora_loader=None) -> PagedEngineAdapter:
             llama.serving_collective_probes(cfg, mesh),
         weight_routes=lambda params:
             llama.ragged_weight_routes(params, cfg),
+        # every route of the step (fused, unfused, LoRA) walks the rows'
+        # pooled pages plus a self cell a row
         ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
-            llama.ragged_grid_cells(cfg, row_start, row_len, maxp, page,
-                                    lora),
+            live_cell_count(row_start, row_len, page),
     )
 
 
@@ -646,6 +648,7 @@ def jamba_paged_adapter(cfg) -> PagedEngineAdapter:
     neither ``lora=`` nor ``logit_idx=``, and the two-program path has
     no recurrent-state form."""
     from ray_tpu.models import jamba
+    from ray_tpu.ops.ragged_paged_attention import live_cell_count
 
     return PagedEngineAdapter(
         init_cache=lambda num_pages, page, max_slots: jamba.init_cache(
@@ -654,6 +657,8 @@ def jamba_paged_adapter(cfg) -> PagedEngineAdapter:
         row_len, row_off, bt, cache:
             jamba.ragged_step(params, tokens, tok_pos, row_slot,
                               row_start, row_len, row_off, bt, cfg, cache),
+        ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
+            live_cell_count(row_start, row_len, page),
         state_bytes_per_slot=cfg.state_bytes_per_slot(),
         state_leaves=("conv", "ssm"),
     )

@@ -198,6 +198,39 @@ def test_ragged_kernels(v5e, kv_int8):
                  *a(pool, pool, new, new, rows, rows, rows, rows, bt))
 
 
+@pytest.mark.parametrize("T, H, KVH, R, maxp, pages, kv_int8", [
+    (64, 20, 1, 64, 24, 1537, False),       # Jamba, the decode shape
+    (320, 20, 1, 64, 24, 1537, False),      # Jamba, the budget
+    (272, 32, 8, 16, 40, 897, True),        # a llama-8B class, unfused
+], ids=["jamba-64", "jamba-320", "llama-int8"])
+def test_ragged_attention_both_calls(v5e, T, H, KVH, R, maxp, pages, kv_int8):
+    """Both calls of ``ragged_paged_attention`` at the widths the cells
+    run: the one-token call's stacked heads and the chunk call's whole
+    window fit VMEM, and the live list fits SMEM beside the block table
+    (and, for int8 pools, the two tables of page scales)."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+
+    mesh = _one(v5e)
+    D = 128
+    pool = _sds(2, KVH, pages, PAGE, D,
+                dtype=jnp.int8 if kv_int8 else jnp.bfloat16)
+    scales = _sds(2, pages, KVH, 1, dtype=jnp.float32)
+    rows = _sds(R, dtype=jnp.int32)
+    args = _on(mesh, (_sds(T, H, D), _sds(T, KVH, D), _sds(T, KVH, D), pool,
+                      pool, _sds(dtype=jnp.int32), rows, rows, rows, rows,
+                      _sds(R, maxp, dtype=jnp.int32), scales, scales))
+
+    def attend(q, k, v, kp, vp, ly, rs, r0, rl, ro, bt, ks, vs):
+        return rpa.ragged_paged_attention(
+            q, k, v, kp, vp, ly, rs, r0, rl, ro, bt,
+            k_scales=ks if kv_int8 else None,
+            v_scales=vs if kv_int8 else None)
+
+    text = _compile(attend, *args).as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 2
+
+
 def test_mamba_ssd_kernel(v5e):
     from ray_tpu.ops.mamba_ssd import ssd_pallas
 

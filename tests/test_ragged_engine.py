@@ -108,7 +108,14 @@ def test_long_prefill_never_stalls_decode(params):
         # Two steps at the budget: the program's first call is the only
         # one whose cache is not yet a result of its own, and jit's fast
         # path keys on that (a dozen ms of host work at the second).
+        # A decode stream rides beside those chunks, as the timed one
+        # will: the unfused step's attention is two calls, and the CPU
+        # backend pays a dozen ms the first time a call's body runs at a
+        # shape (the one-token call's, at the budget, only so).
+        beside = eng.submit([2, 4], max_new_tokens=12, temperature=0.0)
+        next(iter(beside))
         eng.generate(list(range(1, 81)), max_new_tokens=3)
+        beside.result(timeout_s=120)
         steps0 = eng.stats()["steps"]
         short = eng.submit([1, 5, 9], max_new_tokens=24, temperature=0.0)
         # Let the short stream reach steady-state decode first.

@@ -609,6 +609,103 @@ def test_fused_live_walk_gives_the_old_walks_bits(kv_int8, case):
 
 
 # ---------------------------------------------------------------------------
+# ragged_paged_attention walks its rows' cells, in two calls
+# ---------------------------------------------------------------------------
+
+# (slot, start, len, off) over the same 6 x 4 table, in a 48-token
+# buffer.  "mixed": decode rows at unaligned offsets between two chunk
+# rows, a padding row in the middle, rows with nothing pooled (a chunk
+# and a one-token prompt), pasts that end exactly on a page's edge; the
+# other two leave one of the kernel's two calls no cell to walk.
+TWO_CALL_ROWS = {
+    "mixed": ([3, 1, 0, 4, 0, 2], [0, 32, 0, 21, 0, 16],
+              [11, 1, 0, 1, 1, 13], [0, 11, 0, 12, 13, 14]),
+    "one_token_rows_only": ([2, 0, 5, 1, 0, 3], [19, 33, 16, 63, 0, 0],
+                            [1, 1, 1, 1, 0, 1], [0, 1, 2, 3, 0, 4]),
+    "chunk_rows_only": ([1, 3, 0, 0, 5, 0], [10, 32, 0, 0, 0, 0],
+                        [12, 20, 0, 0, 9, 0], [0, 12, 0, 0, 32, 0]),
+}
+
+
+def _two_call_rows(case):
+    return tuple(np.asarray(a, np.int32) for a in TWO_CALL_ROWS[case])
+
+
+@pytest.mark.parametrize("case", list(TWO_CALL_ROWS) + list(LIVE_WALK_ROWS))
+def test_two_calls_walk_each_live_cell_once(case):
+    """The lists of the two calls are the live cells split by the row's
+    length: together what ``live_cell_count`` says on the host, each in
+    ascending order, and each call's positions its own rows' tokens."""
+    _slot, start, nlen, off = (
+        _two_call_rows(case) if case in TWO_CALL_ROWS else _rows(case))
+    one, more = rpa.live_attention_cells(
+        jnp.asarray(start), jnp.asarray(nlen), jnp.asarray(off), 45,
+        _MAXP, _PAGE)
+    walked = []
+    for (live_ci, n_live, mine), takes in ((one, nlen == 1), (more, nlen > 1)):
+        cells = np.asarray(live_ci)[:int(n_live[0])].tolist()
+        assert cells == sorted(cells)
+        assert {c // (_MAXP + 1) for c in cells} == set(np.flatnonzero(takes))
+        want = np.zeros(48, bool)       # round8(45) positions
+        for r in np.flatnonzero(takes):
+            want[off[r]:off[r] + nlen[r]] = True
+        np.testing.assert_array_equal(np.asarray(mine), want)
+        walked += cells
+    assert len(walked) == rpa.live_cell_count(start, nlen, _PAGE)
+    assert sorted(walked) == _old_walks_live_cells(start, nlen, _MAXP, _PAGE)
+    if case == "one_token_rows_only":
+        assert int(more[1][0]) == 0 and not np.asarray(more[2]).any()
+    if case == "chunk_rows_only":
+        assert int(one[1][0]) == 0 and not np.asarray(one[2]).any()
+
+
+@pytest.mark.parametrize("case", list(TWO_CALL_ROWS))
+@pytest.mark.parametrize("heads", [(20, 1), (8, 2)], ids=["mqa", "gqa"])
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+def test_two_call_kernel_matches_reference(kind, heads, case):
+    """Both calls against the dense reference: Jamba's MQA (20 query
+    heads padded to 24 stacked rows a token) and a GQA group of four
+    (padded to 8), plain and int8 pools, float32 and bfloat16 operands
+    (the kernel computes from float32 casts of either)."""
+    rng = np.random.default_rng(21)
+    (H, KVH), L, D, T = heads, 2, 8, 48
+    Pt = _SLOTS * _MAXP + 1
+    dt = jnp.bfloat16 if kind == "bfloat16" else jnp.float32
+    kp, vp, ks, vs = _pools(rng, L, KVH, Pt, _PAGE, D, int8=kind == "int8")
+    if kind != "int8":
+        kp, vp = kp.astype(dt), vp.astype(dt)
+    bt = rng.permutation(Pt - 1).reshape(_SLOTS, _MAXP).astype(np.int32)
+    slot, start, nlen, off = _two_call_rows(case)
+    q, kn, vn = (jnp.asarray(rng.standard_normal((T, n, D)), dt)
+                 for n in (H, KVH, KVH))
+    meta = tuple(jnp.asarray(a) for a in (slot, start, nlen, off, bt))
+    layer = 1
+    ref = rpa.ragged_attention_reference(
+        q.astype(jnp.float32), kn.astype(jnp.float32),
+        vn.astype(jnp.float32),
+        kp[layer] if kind == "int8" else kp[layer].astype(jnp.float32),
+        vp[layer] if kind == "int8" else vp[layer].astype(jnp.float32),
+        *meta, k_scales=None if ks is None else ks[layer],
+        v_scales=None if vs is None else vs[layer])
+    kw = dict(k_scales=ks, v_scales=vs, max_row_tokens=24)
+    got = rpa.ragged_paged_attention(q, kn, vn, kp, vp, layer, *meta, **kw)
+    assert got.shape == (T, H, D) and got.dtype == jnp.float32
+    mask = np.zeros(T, bool)
+    for r in range(_SLOTS):
+        mask[off[r]:off[r] + nlen[r]] = True
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[mask], np.asarray(ref)[mask],
+                               atol=2e-5, rtol=2e-5)
+    # positions no row covers are zero, whatever a call left unwritten
+    assert not np.any(np.asarray(got)[~mask])
+    # lists built once in front of a layer loop give the same bits
+    handed = rpa.ragged_paged_attention(
+        q, kn, vn, kp, vp, layer, *meta, **kw,
+        live_cells=rpa.live_attention_cells(*meta[1:4], T, _MAXP, _PAGE))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(handed))
+
+
+# ---------------------------------------------------------------------------
 # the append walks only the pages its rows write
 # ---------------------------------------------------------------------------
 

@@ -140,6 +140,78 @@ def test_fused_route_reports_the_cells_its_kernel_walks(params, tmp_path):
 
 # -- the bridge ------------------------------------------------------------
 
+def test_jamba_route_reports_the_cells_its_kernel_walks(tmp_path, monkeypatch):
+    """The Jamba adapter states what ``ragged_paged_attention`` walks for
+    the step's rows, the bounds of its two calls' grids together, not the
+    page table's capacity; and the benchmark's ``page_cells_live_share``
+    over such steps reads the pages the rows hold over that walk."""
+    import dataclasses
+
+    from benchmarks import run as bench_run
+    from benchmarks.harness import program_spans
+    from ray_tpu.models import jamba
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    from ray_tpu.serve.llm_engine import jamba_paged_adapter
+
+    cfg = jamba.JambaConfig(
+        vocab_size=97, dim=64, n_layers=4, n_heads=4, n_kv_heads=1,
+        head_dim=16, mlp_dim=96, attn_layer_period=3, attn_layer_offset=1,
+        dt_rank=8, dtype=jnp.float32, param_dtype=jnp.float32)
+    adapter = jamba_paged_adapter(cfg)
+    stated, walked = adapter.ragged_grid_cells, []
+
+    def recording(row_start, row_len, maxp, page, lora):
+        calls = rpa.live_attention_cells(
+            jnp.asarray(row_start), jnp.asarray(row_len),
+            jnp.zeros(len(row_len), jnp.int32), 16, maxp, page)
+        walked.append(sum(int(n_live[0]) for _ci, n_live, _mine in calls))
+        return stated(row_start, row_len, maxp, page, lora)
+
+    eng = LLMEngine(
+        jamba.init_params(jax.random.key(0), cfg),
+        dataclasses.replace(adapter, ragged_grid_cells=recording),
+        EngineConfig(max_slots=4, max_seq_len=64, page_size=8, num_pages=32,
+                     ragged_batching=True, prefill_chunk=8, token_budget=16))
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=2)
+        del walked[:]
+        with _capture(tmp_path) as events:
+            streams = [eng.submit(list(range(1, n + 1)), max_new_tokens=6,
+                                  temperature=0.0) for n in (29, 3, 17)]
+            for s in streams:
+                s.result(timeout_s=300)
+            time.sleep(0.2)
+    finally:
+        eng.shutdown()
+    packs = [p for p in _named(events(), "llm.pack") if "seq" in p.stats]
+    assert [p.stats["grid_cells"] for p in packs] == walked
+    capacity = 4 * (64 // 8 + 1)
+    for p in packs:
+        assert (p.stats["rows"] <= p.stats["grid_cells"]
+                <= p.stats["live_cells"] + p.stats["rows"] < capacity)
+    assert max(p.stats["grid_cells"] - p.stats["rows"] for p in packs) >= 3
+
+    # a chat_short-like step through the reader: 20 decode rows of a few
+    # hundred tokens (4.4 pages a row, about the ledger's 89 live cells a
+    # step) over a 64 x 24 table of 64-token pages.  A row of n pages
+    # walks n + 1 cells, so the share is the pages over pages + rows,
+    # where the capacity's walk read 100 * live / 1600
+    start = np.zeros(64, np.int32)
+    start[:20] = np.linspace(30, 460, 20).astype(np.int32)
+    nlen = (np.arange(64) < 20).astype(np.int32)
+    live = int(np.sum(-(-(start[:20] + 1) // 64)))
+    grid = stated(start, nlen, 24, 64, False)
+    assert live <= grid <= live + 20
+    pack = {"seq": 1, "n_decode": 20, "n_prefill": 0, "n_spec": 0,
+            "rows": 20, "budget": 320, "live_cells": live,
+            "grid_cells": grid}
+    monkeypatch.setattr(program_spans, "lines_of",
+                        lambda run: [[("llm.pack", 0, 1, pack)]])
+    share = bench_run.reader("page_cells_live_share")(None)
+    assert share == pytest.approx(100.0 * live / grid)
+    assert 80 < share < 85 and 100.0 * live / (64 * 25) < 6
+
+
 def test_span_reaches_the_profiler_with_nesting_and_stats(tmp_path):
     assert not tracing.is_enabled()
     with _capture(tmp_path) as events:
@@ -239,13 +311,13 @@ def test_engine_spans_chain_by_seq_and_count_tokens(params, tmp_path):
     assert sum(p.stats["n_prefill"] for p in packs) == \
         after["prefill"] - before["prefill"] == 40 + 3 + 23
     # this adapter's step is the unfused one, whose attention kernel
-    # walks the page table's capacity whatever the rows hold
-    capacity = eng.adapter.ragged_grid_cells(
-        np.zeros(4, np.int32), np.zeros(4, np.int32), 128 // 16, 16, False)
-    assert capacity == 4 * (128 // 16 + 1)
+    # walks what the rows hold too: a self cell a row, and no more pool
+    # cells than the rows' pages, below the page table's capacity
+    capacity = 4 * (128 // 16 + 1)
     for p in packs:
-        assert 0 < p.stats["live_cells"] <= p.stats["grid_cells"]
-        assert p.stats["grid_cells"] == capacity
+        assert 0 < p.stats["live_cells"]
+        assert (p.stats["rows"] <= p.stats["grid_cells"]
+                <= p.stats["live_cells"] + p.stats["rows"] < capacity)
         # the append writes a page a row at least, and none but the
         # pages that hold the rows' tokens
         assert (p.stats["rows"] <= p.stats["append_cells"]
