@@ -749,6 +749,11 @@ class Request:
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # Beside ``tokens``: the ordinal of the engine step whose execution
+    # produced each one (a speculative round's tokens share one),
+    # appended before the token enters ``stream``, so whoever takes the
+    # n-th token off the stream finds its step here.
+    token_seqs: List[int] = dataclasses.field(default_factory=list)
     # Telemetry: the submitter's span context (None when tracing is
     # off) and the prefill-dispatch stamp splitting queue wait from
     # prefill in the request's span tree.
@@ -791,6 +796,7 @@ class CompletionStream:
         self._req = req
         self._engine = engine
         self._done = threading.Event()
+        self._taken = 0   # tokens taken off the stream, by either way
 
     @property
     def request_id(self) -> str:
@@ -813,7 +819,15 @@ class CompletionStream:
             if isinstance(item, BaseException):
                 self._done.set()
                 raise item
-            yield item
+            # From the token handed on to the consumer asking for the
+            # next, on the consumer's thread: in a replica that is the
+            # item's way out (the deployment's own work on it, its
+            # encode and its seal), named by the step it came from.
+            seq = self._req.token_seqs[self._taken]
+            self._taken += 1
+            with tracing.span("serve.stream_item", record=False,
+                              attributes={"seq": seq}):
+                yield item
 
     def result(self, timeout_s: Optional[float] = None) -> List[int]:
         deadline = (None if timeout_s is None
@@ -833,6 +847,8 @@ class CompletionStream:
             elif isinstance(item, BaseException):
                 self._done.set()
                 raise item
+            else:
+                self._taken += 1
         return list(self._req.tokens)
 
     @property
@@ -1430,6 +1446,7 @@ class LLMEngine:
         self._fetchq: "queue.Queue" = queue.Queue()
         self._fetched: "queue.Queue" = queue.Queue()
         self._unprocessed = 0  # dispatched entries not yet emitted
+        self._emit_seq = 0     # step of the fetched entry being emitted
         self._inflight_tokens: Dict[int, int] = {}  # slot → undelivered
         self._req_counter = itertools.count()
         # Cumulative arrival count (every submit, shed included) —
@@ -1896,6 +1913,16 @@ class LLMEngine:
                temperature: float = 0.0,
                request_id: Optional[str] = None,
                adapter_id: str = "") -> CompletionStream:
+        # On the request's own thread, from the call to the request
+        # queued: a capture shows the span inside that thread's
+        # ``serve.replica``.
+        with tracing.span("llm.submit", record=False):
+            return self._submit(prompt, max_new_tokens, temperature,
+                                request_id, adapter_id)
+
+    def _submit(self, prompt: List[int], max_new_tokens: Optional[int],
+                temperature: float, request_id: Optional[str],
+                adapter_id: str) -> CompletionStream:
         if self._stopped.is_set():
             raise RuntimeError("engine is stopped (shut down or crashed)")
         if self._draining.is_set():
@@ -3098,6 +3125,7 @@ class LLMEngine:
             req.max_itl_s = max(req.max_itl_s, gap)
         req.last_token_at = now
         req.tokens.append(tok)
+        req.token_seqs.append(self._emit_seq)
         req.stream.put(tok)
         self._tokens_out += 1
         self._ring.update(req.request_id,
@@ -3567,10 +3595,12 @@ class LLMEngine:
     def _process_fetched(self, block: bool) -> bool:
         """Emit every fetched entry available; returns True if any was
         processed.  ``block`` waits briefly for the next one (used when
-        the loop has nothing to dispatch)."""
+        the loop has steps in flight and nothing to dispatch: phase
+        ``wait``, not ``idle``, which is the engine empty)."""
         try:
             if block:
-                with self._clock.phase("idle"):
+                with self._clock.phase(
+                        "wait", {"in_flight": self._unprocessed}):
                     item = self._fetched.get(timeout=0.02)
             else:
                 item = self._fetched.get_nowait()
@@ -3598,7 +3628,8 @@ class LLMEngine:
     def _emit_fetched(self, item) -> int:
         """Emit one fetched entry's tokens; returns the decode steps it
         carried (0 for a prefill entry or a completion marker)."""
-        (kind, _dev, chunk, participants, _seq), toks = item
+        (kind, _dev, chunk, participants, seq), toks = item
+        self._emit_seq = seq    # ``_emit`` notes it beside each token
         now = time.monotonic()
         if kind == "pfchunk":
             return 0  # completion marker only (pipeline gating)
@@ -4148,8 +4179,11 @@ class LLMEngine:
             clock.begin()
             with tracing.span("llm.loop", record=False) as span:
                 seq = self._loop_iteration()
+                cpu_us = int(clock.cpu_spent() * 1e6)
                 if seq is not None:
-                    span.set(seq=seq)
+                    span.set(seq=seq, cpu_us=cpu_us)
+                else:
+                    span.set(cpu_us=cpu_us)
             # a stall is reported near the step this iteration
             # dispatched, else the last one dispatched
             clock.end(seq if seq is not None else self._steps)
@@ -4184,8 +4218,9 @@ class LLMEngine:
         """One iteration of the engine loop, every stretch of it inside
         a phase of the loop clock (``llm.control``, ``llm.admit``,
         ``llm.pack``/``llm.dispatch``/``llm.commit``, ``llm.emit``,
-        ``llm.idle``).  Returns the ``seq`` of the step it dispatched,
-        or None."""
+        ``llm.wait`` with steps in flight and nothing to dispatch,
+        ``llm.idle`` with no request anywhere in the engine).  Returns
+        the ``seq`` of the step it dispatched, or None."""
         clock = self._clock
         with clock.phase("control"):
             self._process_cancels()

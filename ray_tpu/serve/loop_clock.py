@@ -9,6 +9,25 @@ call per step; ``snapshot()`` and the scrape-time metric family read
 the floats from other threads, where a torn read is off by one
 iteration at most.
 
+Two of the phases are waits, and they are not the same wait.  ``idle``
+is the loop with NO request anywhere in the engine (nothing queued,
+nothing live, nothing in flight): time there is nobody's loss.
+``wait`` is the loop with steps in flight and rows live and nothing it
+could dispatch, blocked until the fetch thread hands a step back: the
+pipeline is full (the span's ``in_flight`` reads the pipeline's depth:
+the device sets the pace, as it should) or a step could not be packed
+(below it: every live row's budget is out with its tokens in flight).
+Until PR 37 both were ``idle``.
+
+Beside the wall the clock keeps the loop thread's own CPU seconds
+(``cpu_s``, ``time.thread_time()`` over each iteration; the ``llm.loop``
+span carries the iteration's as ``cpu_us``).  ``wall_s`` less the two
+waits less ``cpu_s`` is time the thread wanted to run and did not: it
+waited for the interpreter lock, which every request's thread shares,
+for a core, or inside a native call.  (Where the thread's CPU clock ticks
+coarsely, 10 ms on some virtual machines, one iteration's ``cpu_us``
+reads 0 or a tick: sum over iterations, as ``cpu_s`` does.)
+
 Two things are judged against the running median step interval (the
 time between consecutive fetched steps while the pipeline holds work,
 over the last 64 of them, once there are 8):
@@ -18,9 +37,9 @@ over the last 64 of them, once there are 8):
   that held most of it: the host stood still;
 - a step interval longer than ``STALL_FACTOR`` x the median counts as
   a stall event (``stall_events``), and is reported the same way,
-  naming the phase that held most of the interval (``idle``: the loop
-  was waiting for the device or the fetch thread), unless an iteration
-  inside it was reported already.
+  naming the phase that held most of the interval (``wait``: the loop
+  was waiting for the device or the fetch thread with rows live),
+  unless an iteration inside it was reported already.
 """
 
 from __future__ import annotations
@@ -41,7 +60,8 @@ STALL_FACTOR = 5.0
 STALL_FLOOR_S = 0.25
 MIN_HISTORY = 8
 
-PHASES = ("control", "admit", "pack", "dispatch", "commit", "emit", "idle")
+PHASES = ("control", "admit", "pack", "dispatch", "commit", "emit", "wait",
+          "idle")
 
 _lock = threading.Lock()
 _live: "weakref.WeakSet[LoopClock]" = weakref.WeakSet()
@@ -75,6 +95,7 @@ class LoopClock:
         self.seconds: Dict[str, float] = {p: 0.0 for p in PHASES}
         self.iterations = 0
         self.wall_s = 0.0
+        self.cpu_s = 0.0
         self.longest = {"wall_ms": 0.0, "phase": None, "seq": None}
         self.stall_events = 0
         self.interval_high_water_s = 0.0
@@ -82,6 +103,7 @@ class LoopClock:
         self._on_high_water = on_high_water
         self._iter: Dict[str, float] = {p: 0.0 for p in PHASES}
         self._t_iter = time.perf_counter()
+        self._cpu_iter = 0.0   # the loop thread's own clock: begin() reads it
         self._intervals: deque = deque(maxlen=64)
         self._median_s: Optional[float] = None
         self._pace_t: Optional[float] = None   # None: pipeline empty
@@ -97,10 +119,19 @@ class LoopClock:
         for p in PHASES:
             it[p] = 0.0
         self._t_iter = time.perf_counter()
+        self._cpu_iter = time.thread_time()
 
     def phase(self, name: str,
               attributes: Optional[Dict[str, Any]] = None) -> _Phase:
         return _Phase(self, name, attributes)
+
+    def cpu_spent(self) -> float:
+        """CPU seconds the calling thread (the loop's) has used since
+        ``begin()``, added to ``cpu_s``.  Once an iteration, inside the
+        ``llm.loop`` span, which carries it as ``cpu_us``."""
+        cpu = time.thread_time() - self._cpu_iter
+        self.cpu_s += cpu
+        return cpu
 
     def end(self, seq: Optional[int] = None) -> None:
         now = time.perf_counter()
@@ -158,6 +189,7 @@ class LoopClock:
         return {
             "iterations": self.iterations,
             "wall_s": self.wall_s,
+            "cpu_s": self.cpu_s,
             "seconds": dict(self.seconds),
             "longest": dict(self.longest),
             "step_interval_median_ms": (None if median is None
@@ -199,7 +231,8 @@ class LoopSecondsFamily(metrics.Metric):
             "raytpu_serve_loop_seconds_total",
             "Seconds the engine loop thread spent in each phase of its "
             "iterations (control, admit, pack, dispatch, commit, emit, "
-            "idle), summed over this process's engines.",
+            "wait: steps in flight and nothing to dispatch, idle: no "
+            "request in the engine), summed over this process's engines.",
             tag_keys=("phase",))
 
     def _samples(self):

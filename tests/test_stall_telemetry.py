@@ -27,13 +27,18 @@ class _Gauge:
 
 
 class _FakeTime:
-    """perf_counter the test moves by hand."""
+    """perf_counter, and the thread's CPU clock, the test moves by
+    hand."""
 
     def __init__(self):
         self.t = 1000.0
+        self.cpu = 50.0
 
     def perf_counter(self):
         return self.t
+
+    def thread_time(self):
+        return self.cpu
 
 
 def _shim():
@@ -108,6 +113,61 @@ def test_step_interval_needs_history(monkeypatch):
     assert clock.stall_events == 1
 
 
+def test_stall_with_steps_in_flight_is_held_in_wait(monkeypatch):
+    """The loop blocked for the fetch thread with steps in flight: that
+    is phase ``wait`` (``idle`` until PR 37, which now means the engine
+    is empty), and a stalled interval held there is reported so."""
+    clock, _gauge, reports, step, fake = _paced_clock(monkeypatch)
+    for _ in range(10):
+        assert not step(0.01)
+    # (the engine's loop takes the wait 20 ms at a time, an iteration
+    # each: no single iteration is long, the step's interval is)
+    clock.step_dispatched()
+    for _ in range(25):
+        clock.begin()
+        with clock.phase("control"):
+            fake.t += 0.0001
+        with clock.phase("wait", {"in_flight": 6}):
+            fake.t += 0.02
+        clock.end()
+    assert clock.steps_fetched(1, 0, seq=11)
+    assert [(r["phase"], r["seq"]) for r in reports] == [("wait", 11)]
+    assert reports[0]["wall_ms"] == pytest.approx(502.5)
+    snap = clock.snapshot()
+    assert snap["seconds"]["wait"] == pytest.approx(0.5)
+    assert snap["seconds"]["idle"] == 0.0
+    assert snap["longest"]["phase"] == "wait"
+    # the same half second with the engine empty is no step's interval
+    # and nobody's loss: booked under ``idle``, and no report
+    for _ in range(10):
+        clock.begin()
+        with clock.phase("idle"):
+            fake.t += 0.05
+        clock.end()
+    assert not step(0.01)
+    assert len(reports) == 1
+    assert clock.snapshot()["seconds"]["idle"] == pytest.approx(0.5)
+
+
+def test_loop_cpu_beside_its_wall(monkeypatch):
+    """``cpu_s`` sums the loop thread's own CPU clock over its
+    iterations; against ``wall_s`` less the two waits it says how much
+    of the loop's working time the thread was not running."""
+    clock, _gauge, _reports, _step, fake = _paced_clock(monkeypatch)
+    for wall, cpu in ((0.010, 0.004), (0.020, 0.020)):
+        clock.begin()
+        with clock.phase("pack"):
+            fake.t += wall
+            fake.cpu += cpu
+        assert clock.cpu_spent() == pytest.approx(cpu)
+        fake.cpu += 7.0     # another iteration's business: not counted
+        clock.end()
+    snap = clock.snapshot()
+    assert snap["cpu_s"] == pytest.approx(0.024)
+    assert snap["wall_s"] == pytest.approx(0.030)
+    assert snap["cpu_s"] <= snap["wall_s"]
+
+
 def test_admission_queue_age():
     ns = _shim()
     assert LLMEngine._admission_queue_age(ns) == 0.0
@@ -149,9 +209,12 @@ def test_engine_run_populates_gauges_with_clean_grammar():
     assert "raytpu_serve_step_wall_seconds" in text
     assert "raytpu_serve_admission_queue_age_seconds" in text
     # The loop clock's per-phase seconds, read at scrape time.
-    assert 'raytpu_serve_loop_seconds_total{phase="idle"}' in text
+    for phase in loop_clock.PHASES:
+        assert f'raytpu_serve_loop_seconds_total{{phase="{phase}"}}' in text
+    assert {"wait", "idle"} <= set(loop_clock.PHASES)
     loop = eng.stats()["loop"]
     assert loop["iterations"] > 0 and loop["seconds"]["dispatch"] > 0
+    assert 0 < loop["cpu_s"] <= loop["wall_s"]
     # The decode path ran, so the watermark must be a real positive.
     samples = _telemetry()["step_wall"]._samples()
     assert samples and samples[0][2] > 0

@@ -11,7 +11,9 @@ their ``hlo_module``; device times are no part of these tests.
 import collections
 import contextlib
 import glob
+import queue
 import re
+import threading
 import time
 
 import jax
@@ -24,8 +26,10 @@ from ray_tpu.serve import llm_engine
 from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
+    LLMServer,
     llama_paged_adapter,
 )
+from ray_tpu.serve.replica import ReplicaActor
 from ray_tpu.util import flight_recorder, tracing
 
 CFG = llama.LlamaConfig(
@@ -524,7 +528,31 @@ def test_engine_programs_are_named_after_their_registration(params):
 
 # -- the loop clock ---------------------------------------------------------
 
-def test_loop_phases_account_for_the_loops_wall(params):
+@pytest.fixture()
+def slow_step(monkeypatch):
+    """Every step takes ``sleep_s`` longer to come back, as the engine
+    sees it: the fetch thread's ``device_get`` sleeps first.  (A sleep
+    inside the jitted step will not do here: the CPU backend runs a step
+    inside the call that dispatches it.)  The loop then fills its
+    pipeline and waits, as it does behind a device."""
+    device_get = jax.device_get
+
+    def arm(sleep_s=0.01):
+        def slow_get(x):
+            if threading.current_thread().name == "llm-fetch":
+                time.sleep(sleep_s)
+            return device_get(x)
+
+        monkeypatch.setattr(jax, "device_get", slow_get)
+
+    return arm
+
+
+@pytest.mark.parametrize("slow", [False, True],
+                         ids=["plain_step", "slow_step"])
+def test_loop_phases_account_for_the_loops_wall(params, slow, slow_step):
+    if slow:
+        slow_step()
     eng = _engine(params)
     try:
         eng.generate([1, 2, 3], max_new_tokens=2)
@@ -538,12 +566,21 @@ def test_loop_phases_account_for_the_loops_wall(params):
     finally:
         eng.shutdown()
     assert set(loop["seconds"]) == {"control", "admit", "pack", "dispatch",
-                                    "commit", "emit", "idle"}
+                                    "commit", "emit", "wait", "idle"}
     assert loop["iterations"] > 20
     assert sum(loop["seconds"].values()) == pytest.approx(
         loop["wall_s"], rel=0.05)
     assert all(v >= 0 for v in loop["seconds"].values())
     assert loop["seconds"]["pack"] > 0 and loop["seconds"]["emit"] > 0
+    if slow:
+        # a step slower than the loop: the pipeline fills and the loop
+        # waits for the fetch thread with rows live
+        assert loop["seconds"]["wait"] > 0.1
+    # the loop thread's own CPU beside its wall: it cannot have used
+    # more than passed, and it did not wait on the CPU's clock
+    assert 0 < loop["cpu_s"] <= loop["wall_s"]
+    assert loop["cpu_s"] <= (loop["wall_s"] - loop["seconds"]["wait"]
+                             - loop["seconds"]["idle"]) * 1.05 + 0.05
     longest = loop["longest"]
     assert longest["wall_ms"] > 0 and longest["phase"] in loop["seconds"]
     assert loop["step_interval_median_ms"] > 0
@@ -594,3 +631,244 @@ def test_slowed_phase_yields_one_named_loop_stall(params, monkeypatch):
     assert stats["loop"]["longest"]["wall_ms"] >= 400
     assert stats["loop"]["seconds"]["admit"] >= 0.4
     flight_recorder.clear()
+
+
+# -- the two waits, the loop's CPU, the replica's way in and out ------------
+
+def _engine_is_empty(eng):
+    return (not eng._slot_req and eng._waiting.empty()
+            and not eng._backlog and not eng._prefilling
+            and eng._unprocessed == 0)
+
+
+def test_wait_holds_steps_in_flight_and_idle_an_empty_engine(params,
+                                                             slow_step):
+    """``llm.wait`` opens only with steps in flight, ``llm.idle`` only
+    with no request anywhere in the engine: the state is read on the
+    loop's own thread, where each phase opens."""
+    slow_step()
+    eng = _engine(params)
+    opened = []
+    phase = eng._clock.phase
+
+    def watching(name, attributes=None):
+        if name in ("wait", "idle"):
+            opened.append((name, dict(attributes or {}), eng._unprocessed,
+                           _engine_is_empty(eng)))
+        return phase(name, attributes)
+
+    eng._clock.phase = watching
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=2)
+        streams = [eng.submit(list(range(1, n)), max_new_tokens=12,
+                              temperature=0.0) for n in (30, 6, 17)]
+        for s in streams:
+            s.result(timeout_s=120)
+        time.sleep(0.15)    # the engine is empty: the loop idles
+    finally:
+        eng.shutdown()
+    waits = [o for o in opened if o[0] == "wait"]
+    idles = [o for o in opened if o[0] == "idle"]
+    assert len(waits) > 5 and len(idles) >= 2
+    for _name, attributes, in_flight, empty in waits:
+        assert attributes == {"in_flight": in_flight}
+        assert 1 <= in_flight <= eng._PIPELINE_DEPTH and not empty
+    # the slow step lets the loop fill the pipeline before it waits
+    assert max(w[2] for w in waits) == eng._PIPELINE_DEPTH
+    for _name, attributes, in_flight, empty in idles:
+        assert attributes == {} and in_flight == 0 and empty
+
+
+def test_loop_span_carries_cpu_and_wait_the_depth(params, tmp_path,
+                                                  slow_step):
+    slow_step(0.005)
+    eng = _engine(params)
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=2)
+        with _capture(tmp_path) as events:
+            eng.generate(list(range(1, 20)), max_new_tokens=12)
+            time.sleep(0.2)
+    finally:
+        eng.shutdown()
+    ev = events()
+    loops = _named(ev, "llm.loop")
+    assert len(loops) > 10
+    for lp in loops:
+        # the thread's CPU over the iteration: never more than its wall
+        # (two clocks: a few microseconds of slack)
+        wall_us = (lp.end - lp.start) / 1e3
+        assert 0 <= lp.stats["cpu_us"] <= wall_us * 1.05 + 50
+    stepping = [lp for lp in loops if "seq" in lp.stats]
+    assert stepping and all(lp.stats["cpu_us"] > 0 for lp in stepping)
+    # waiting is not working: an iteration that only waited 20 ms for
+    # the fetch thread used next to none of it
+    waited = [lp for lp in loops if lp.end - lp.start > 15_000_000
+              and "seq" not in lp.stats]
+    assert waited and all(lp.stats["cpu_us"] < 10_000 for lp in waited)
+    # the depth at a dispatch is dispatches less emits in the capture:
+    # the span carries its step and no more
+    dispatches = _named(ev, "llm.dispatch")
+    assert dispatches and all(set(d.stats) == {"seq"} for d in dispatches)
+    waits = _named(ev, "llm.wait")
+    assert waits and all(1 <= w.stats["in_flight"] <= eng._PIPELINE_DEPTH
+                         for w in waits)
+    # the engine went empty at the end: that wait is the other name
+    assert _named(ev, "llm.idle")
+
+
+@pytest.fixture()
+def replica(params, monkeypatch, slow_step):
+    """A replica actor in this process over the real ``LLMServer.stream``
+    and a tiny engine; the engine's own record of which step emitted
+    each token, by request, is kept beside it."""
+    slow_step(0.003)
+    eng = _engine(params)
+    eng.generate([1, 2, 3], max_new_tokens=2)
+    server = LLMServer.__new__(LLMServer)
+    server.engine, server._disagg = eng, None
+    emitted = collections.defaultdict(list)
+    emit = eng._emit
+
+    def recording(req, slot, tok, burst=1):
+        emitted[req.request_id].append((tok, eng._emit_seq))
+        return emit(req, slot, tok, burst)
+
+    monkeypatch.setattr(eng, "_emit", recording)
+    monkeypatch.setattr(ReplicaActor, "_install_sigterm_drain",
+                        lambda self: None)
+    # (a controller's replica ids hold "#", which ends a span's stats in
+    # a capture: there ``request_id`` is lost and containment is the join)
+    actor = ReplicaActor("app", "llm", "llm-0", server, (), {}, None)
+    try:
+        yield actor, eng, emitted
+    finally:
+        eng.shutdown()
+
+
+def _stream_through(actor, prompts, n_new=10):
+    """One thread a request, as the worker runs them; returns the
+    tokens each got, by request id."""
+    got = {}
+
+    def one(i, prompt):
+        rid = f"streamed-{i}"
+        got[rid] = list(actor.handle_request_streaming(
+            "stream", ({"tokens": prompt, "max_new_tokens": n_new},), {},
+            {"request_id": rid}))
+
+    threads = [threading.Thread(target=one, args=(i, p))
+               for i, p in enumerate(prompts)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    assert not any(t.is_alive() for t in threads)
+    return got
+
+
+def test_stream_items_name_the_step_that_emitted_them(replica, tmp_path):
+    actor, eng, emitted = replica
+    prompts = [list(range(1, 28)), [5, 6, 7], list(range(40, 59))]
+    with _capture(tmp_path) as events:
+        got = _stream_through(actor, prompts)
+        time.sleep(0.2)
+    ev = events()
+    handled = {r.stats["request_id"]: r for r in _named(ev, "serve.replica")}
+    assert sorted(handled) == sorted(got)
+    items = _named(ev, "serve.stream_item")
+    submits = _named(ev, "llm.submit")
+    emits = _named(ev, "llm.emit")
+    assert len(items) == sum(len(t) for t in got.values())
+    for rid, tokens in got.items():
+        whole = handled[rid]
+        mine = [it for it in items if _inside(it, whole)]
+        # every item of the stream, in order, inside its request's span
+        assert len(mine) == len(tokens) == 10
+        assert [tok for tok, _seq in emitted[rid]] == tokens
+        seqs = [it.stats["seq"] for it in mine]
+        assert seqs == sorted(seqs) and seqs[0] >= 1
+        # and each names the step the engine emitted that token from
+        assert seqs == [seq for _tok, seq in emitted[rid]]
+        for it in mine:
+            # which the loop began to emit before the item left
+            emit = [e for e in emits if it.stats["seq"] in _seqs(e)]
+            assert len(emit) == 1 and emit[0].start <= it.start
+        # the request entered the engine once, on this thread, before
+        # its first item
+        (submit,) = [s for s in submits if _inside(s, whole)]
+        assert submit.end <= mine[0].start
+    # an item costs its thread something, and no item holds another
+    assert all(it.end > it.start for it in items)
+
+
+def test_stream_of_another_target_opens_no_item_span(monkeypatch, tmp_path):
+    """The span is the engine stream's own: a replica over any other
+    generator opens none, and nothing is left on the thread for it."""
+    class Plain:
+        def stream(self, n):
+            yield from range(n)
+
+    monkeypatch.setattr(ReplicaActor, "_install_sigterm_drain",
+                        lambda self: None)
+    actor = ReplicaActor("app", "plain", "plain-0", Plain(), (), {}, None)
+    with _capture(tmp_path) as events:
+        assert list(actor.handle_request_streaming(
+            "stream", (4,), {}, {"request_id": "plain-0"})) == [0, 1, 2, 3]
+    ev = events()
+    assert len(_named(ev, "serve.replica")) == 1
+    assert _named(ev, "serve.stream_item") == []
+
+
+def test_items_keep_their_step_after_a_result_that_timed_out(monkeypatch):
+    """``result()`` takes tokens off the same queue: what iteration
+    yields afterwards still names its own step."""
+    req = llm_engine.Request([1], 4, 0.0, queue.Queue(), 0)
+    stream = llm_engine.CompletionStream(req)
+    seen = []
+
+    class watching(tracing.span):
+        def __init__(self, name, ctx=None, attributes=None, *, record=True):
+            seen.append((name, dict(attributes or {}), record))
+            super().__init__(name, ctx, attributes, record=record)
+
+    monkeypatch.setattr(llm_engine.tracing, "span", watching)
+    for tok, seq in ((7, 3), (8, 3)):
+        req.tokens.append(tok)
+        req.token_seqs.append(seq)
+        req.stream.put(tok)
+    with pytest.raises(TimeoutError):
+        stream.result(timeout_s=0.01)
+    for tok, seq in ((9, 4), (10, 6)):
+        req.tokens.append(tok)
+        req.token_seqs.append(seq)
+        req.stream.put(tok)
+    req.stream.put(llm_engine._DONE)
+    assert list(stream) == [9, 10]
+    assert seen == [("serve.stream_item", {"seq": 4}, False),
+                    ("serve.stream_item", {"seq": 6}, False)]
+
+
+@pytest.mark.parametrize("enabled", [False, True],
+                         ids=["tracing_off", "tracing_on"])
+def test_new_spans_keep_no_record(replica, enabled):
+    """With no capture the new spans are a flag test.  Off, a streamed
+    request leaves the span buffer empty; on, the hot-loop spans
+    (``record=False``) still stay out of it."""
+    actor, _eng, _emitted = replica
+    tracing.clear()
+    if enabled:
+        tracing.enable_tracing()
+    try:
+        got = _stream_through(actor, [list(range(1, 20)), [3, 4]])
+        time.sleep(0.1)
+        names = {s["name"] for s in tracing.finished_spans()}
+    finally:
+        tracing.disable_tracing()
+        tracing.clear()
+    assert all(len(t) == 10 for t in got.values())
+    if not enabled:
+        assert names == set()
+    else:
+        assert "serve.replica" in names
+        assert not names & {"serve.stream_item", "llm.submit", "llm.wait",
+                            "llm.idle", "llm.loop", "llm.dispatch"}
