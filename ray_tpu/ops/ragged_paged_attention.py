@@ -56,7 +56,12 @@ Kernel shape (mirrors ops/paged_attention.py's idioms):
     one KV head, 8 for a group of 4), the page read and cast once a
     cell; a cell loops over the KV heads, whose pages arrive together;
   * flash state (m, l, acc) lives in VMEM scratch, per KV head and
-    stacked row.
+    stacked row, reset at a row's first cell;
+  * ``fused_ragged_layer``'s attention phase has the same arithmetic in
+    ONE call (it is one grid over all phases): the row's path is chosen
+    in the cell from ``row_len``, and its window's stack is head-major
+    (``qpg`` aligned ``[Cq, hd]`` slices of queries the kernel itself
+    laid out by KV head, no pad where ``Cq`` is a multiple of 8).
 
 ``fused_ragged_layer`` folds the PR-2 per-layer decode megakernel
 (ops/fused_decode.py) over the ragged batch: the same phase-indexed
@@ -66,6 +71,17 @@ so the fused path serves ragged batches too.  That phase walks a list
 of the cells that hold the step's rows (``live_page_cells``) and the
 grid ends where the list ends: a cell the rows do not reach is no grid
 step, where it used to cost 0.6 us in every layer (PERF.md, PR 28).
+A cell does a KV head's work once, as the kernel above does (PERF.md,
+PR 38): the query heads of a KV head are the rows of one product
+against its page, the page read and cast once a KV head, the KV heads
+looped inside the cell; a row of ONE token takes that token alone (its
+group's heads padded to a sublane tile, gathered at the row's first
+cell), a row of more the step's window, ``qpg x Cq`` stacked rows
+head-major; the flash state is per KV head and stacked row and is reset
+at a row's first cell.  One kernel and one call a layer: the two row
+paths are ``pl.when`` bodies chosen by ``row_len`` in the cell, side by
+side and never one inside another (nested, the kernel took the chip's
+host twice as long to trace, 11 s of a replica's set-up: PERF.md, PR 38).
 The append's grid follows a list of its own the same way.  The layer
 kernel takes the STACKED layer tree and a layer index: the weights
 reach the kernel the way the KV pools do, whole, and each BlockSpec
@@ -748,9 +764,10 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
      wqkv_ref, sqkv_ref, kp_ref, vp_ref, wo_ref, so_ref,
      wg_g_ref, wg_u_ref, sg_g_ref, sg_u_ref, wd_ref, sd_ref,
      xo_ref, kn_ref, vn_ref,
-     xn_s, qkv_s, qs, m_s, l_s, acc_s, ao_s, h_s, y_s) = refs[n_pre:]
+     xn_s, qkv_s, qs, q1_s, m_s, l_s, acc_s, ao_s, h_s, y_s) = refs[n_pre:]
 
     half = hd // 2
+    QP = _round8(qpg)
     Tq = ((H + 2 * KVH) * hd) // tq
     To = D // to
     Tm = M // tm
@@ -797,15 +814,17 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
             preferred_element_type=jnp.float32)
         qkv_s[t] = res * sqkv_ref[...].astype(jnp.float32)
 
-    # ---- phase 1 start: RoPE + per-token flash state init ------------
+    # ---- phase 1 start: RoPE; the queries stacked by KV head ----------
     @pl.when(t == S1)
     def _attn_setup():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
         ao_s[...] = jnp.zeros_like(ao_s)
+        q1_s[...] = jnp.zeros_like(q1_s)
+        # head-major: KV head g's stack holds its group's heads one
+        # after the other, T rows each, so a window's stacked rows are
+        # qpg aligned slices of it
         for h in range(H):
-            qs[h] = rope(head_slice(h))
+            g, j = divmod(h, qpg)
+            qs[g, j * T:(j + 1) * T] = rope(head_slice(h))
         for h in range(KVH):
             lo, hi = h * hd, (h + 1) * hd
             kn_ref[:, lo:hi] = rope(head_slice(H + h)).astype(
@@ -821,71 +840,138 @@ def _fused_ragged_kernel(*refs, T: int, Cq: int, D: int, H: int,
     start = start_r[r]
     nt = len_r[r]
     off = off_r[r]
-    w = jnp.minimum((off // 8) * 8, T - Cq)
-    w = pl.multiple_of(w, 8)
-    ti = lax.broadcasted_iota(jnp.int32, (Cq, 1), 0)
-    trel = w + ti - off
-    valid_q = (trel >= 0) & (trel < nt)
 
-    def flash_update(h, s, v, vscale):
-        upd = valid_q
-        m_prev = m_s[h, pl.ds(w, Cq)]
+    def flash_update(g, n, valid, s, v, vscale):
+        """Masked online-softmax update of KV head g's first ``n``
+        stacked rows; rows that are not ``valid`` (a neighbour's tokens
+        in the window, the pad of a group) keep their state."""
+        m_prev = m_s[g, :n]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_new = jnp.where(upd, m_new, m_prev)
+        m_new = jnp.where(valid, m_new, m_prev)
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_prev = l_s[h, pl.ds(w, Cq)]
+        l_prev = l_s[g, :n]
         l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         pv = lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
         if vscale is not None:
             pv = pv * vscale
-        a_prev = acc_s[h, pl.ds(w, Cq)]
+        a_prev = acc_s[g, :n]
         a_new = a_prev * corr + pv
-        m_s[h, pl.ds(w, Cq)] = m_new
-        l_s[h, pl.ds(w, Cq)] = jnp.where(upd, l_new, l_prev)
-        acc_s[h, pl.ds(w, Cq)] = jnp.where(upd, a_new, a_prev)
+        m_s[g, :n] = m_new
+        l_s[g, :n] = jnp.where(valid, l_new, l_prev)
+        acc_s[g, :n] = jnp.where(valid, a_new, a_prev)
         return l_new, a_new
 
-    @pl.when(in_attn & (pc < maxp) & (pc * page < start) & (nt > 0))
-    def _pool_cell():
-        s_idx = slot_r[r]
-        last = jnp.maximum(start - 1, 0) // page
-        pid = jnp.minimum(bt_r[s_idx, jnp.minimum(pc, last)], Pt - 1)
-        kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        mask = valid_q & (kpos < start)
-        for h in range(H):
-            kvh = h // qpg
-            qh = qs[h, pl.ds(w, Cq)]
-            k = kp_ref[0, kvh, 0].astype(jnp.float32)
-            s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            if quantized:
-                s = s * ks_r[pid, kvh]
-            s = jnp.where(mask, capped(s), NEG_INF)
-            flash_update(h, s, vp_ref[0, kvh, 0].astype(jnp.float32),
-                         vs_r[pid, kvh] if quantized else None)
+    # a row's cells are consecutive in the list: its pool pages in
+    # ascending order, then its self cell.  Every body below is a
+    # ``pl.when`` of the kernel's top level: one inside another doubled
+    # the time the chip's host takes to trace this kernel.
+    first = (pc == 0) | (start == 0)
+    pool = (pc < maxp) & (pc * page < start)
 
-    @pl.when(in_attn & (pc == maxp) & (nt > 0))
-    def _self_cell():
-        kj = lax.broadcasted_iota(jnp.int32, (1, Cq), 1)
-        krel = w + kj - off
-        mask = (valid_q & (krel >= 0) & (krel < nt) & (krel <= trel))
+    def row_cells(on, n, rows_of, q_of, wk, Ck, put):
+        """The cell's three bodies for the rows ``on`` selects, whose
+        queries are ``n`` stacked rows a KV head (``q_of(g)`` [n, hd];
+        ``rows_of()`` gives which stacked rows are the row's own and
+        their row-relative token index): the state reset at the row's
+        first cell, one product a KV head against the cell's page, and
+        in the self cell against the ``Ck`` fresh keys from ``wk``,
+        where ``put(g, o, valid_q)`` writes the row's finished tokens."""
+
+        def scores(g, keys):
+            return lax.dot_general(
+                q_of(g), keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+
+        @pl.when(on & first)
+        def _first():
+            m_s[:, :n] = jnp.full((KVH, n, 1), NEG_INF, jnp.float32)
+            l_s[:, :n] = jnp.zeros((KVH, n, 1), jnp.float32)
+            acc_s[:, :n] = jnp.zeros((KVH, n, hd), jnp.float32)
+
+        @pl.when(on & pool)
+        def _pool_cell():
+            valid_q, _ = rows_of()
+            s_idx = slot_r[r]
+            last = jnp.maximum(start - 1, 0) // page
+            pid = jnp.minimum(bt_r[s_idx, jnp.minimum(pc, last)], Pt - 1)
+            kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
+            mask = valid_q & (kpos < start)
+            for g in range(KVH):
+                s = scores(g, kp_ref[0, g, 0].astype(jnp.float32))
+                if quantized:
+                    s = s * ks_r[pid, g]
+                flash_update(g, n, valid_q,
+                             jnp.where(mask, capped(s), NEG_INF),
+                             vp_ref[0, g, 0].astype(jnp.float32),
+                             vs_r[pid, g] if quantized else None)
+
+        @pl.when(on & (pc == maxp))
+        def _self_cell():
+            valid_q, trel = rows_of()
+            krel = wk + lax.broadcasted_iota(jnp.int32, (1, Ck), 1) - off
+            mask = valid_q & (krel >= 0) & (krel < nt) & (krel <= trel)
+            for g in range(KVH):
+                lo, hi = g * hd, (g + 1) * hd
+                s = scores(g, kn_ref[pl.ds(wk, Ck), lo:hi].astype(
+                    jnp.float32))
+                l_new, a_new = flash_update(
+                    g, n, valid_q, jnp.where(mask, capped(s), NEG_INF),
+                    vn_ref[pl.ds(wk, Ck), lo:hi].astype(jnp.float32), None)
+                put(g, a_new / jnp.maximum(l_new, 1e-30), valid_q)
+
+    # A row of ONE token takes that token alone: its group's heads are
+    # the stacked rows, padded to a sublane tile and gathered once, at
+    # the row's first cell.
+    one = in_attn & (nt == 1)
+    w1 = pl.multiple_of((off // 8) * 8, 8)
+
+    @pl.when(one & first)
+    def _gather():
         for h in range(H):
-            kvh = h // qpg
-            lo, hi = kvh * hd, (kvh + 1) * hd
-            qh = qs[h, pl.ds(w, Cq)]
-            kw = kn_ref[pl.ds(w, Cq), lo:hi].astype(jnp.float32)
-            s = lax.dot_general(qh, kw, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, capped(s), NEG_INF)
-            vw = vn_ref[pl.ds(w, Cq), lo:hi].astype(jnp.float32)
-            l_new, a_new = flash_update(h, s, vw, None)
-            o = a_new / jnp.maximum(l_new, 1e-30)
-            hlo = h * hd
-            cur = ao_s[pl.ds(w, Cq), hlo:hlo + hd]
-            ao_s[pl.ds(w, Cq), hlo:hlo + hd] = jnp.where(
-                valid_q, o, cur)
+            g, j = divmod(h, qpg)
+            q1_s[g, j:j + 1] = qs[g, pl.ds(j * T + off, 1)]
+
+    def group_rows():
+        return lax.broadcasted_iota(jnp.int32, (QP, 1), 0) < qpg, 0
+
+    # Mosaic stores no single row at a dynamic offset: the token's
+    # sublane tile is read, the row put in, the tile written back
+    def put_token(g, o, _valid):
+        at_off = lax.broadcasted_iota(jnp.int32, (8, 1), 0) == off - w1
+        for j in range(qpg):
+            lo = (g * qpg + j) * hd
+            ao_s[pl.ds(w1, 8), lo:lo + hd] = jnp.where(
+                at_off, jnp.broadcast_to(o[j:j + 1], (8, hd)),
+                ao_s[pl.ds(w1, 8), lo:lo + hd])
+
+    row_cells(one, QP, group_rows, lambda g: q1_s[g], w1, 8, put_token)
+
+    # A row of more takes the static window [w, w + Cq) of the buffer,
+    # w aligned down to the sublane: qpg x Cq stacked rows, head-major;
+    # masks do the raggedness.
+    w = pl.multiple_of(jnp.minimum((off // 8) * 8, T - Cq), 8)
+
+    def window_rows():
+        trel = w + lax.broadcasted_iota(jnp.int32, (Cq, 1), 0) - off
+        valid = (trel >= 0) & (trel < nt)
+        return (jnp.concatenate([valid] * qpg, axis=0),
+                jnp.concatenate([trel] * qpg, axis=0))
+
+    def window_q(g):
+        return jnp.concatenate(
+            [qs[g, pl.ds(j * T + w, Cq)] for j in range(qpg)], axis=0)
+
+    def put_window(g, o, valid):
+        for j in range(qpg):
+            lo = (g * qpg + j) * hd
+            ao_s[pl.ds(w, Cq), lo:lo + hd] = jnp.where(
+                valid[:Cq], o[j * Cq:(j + 1) * Cq],
+                ao_s[pl.ds(w, Cq), lo:lo + hd])
+
+    row_cells(in_attn & (nt > 1), qpg * Cq, window_rows, window_q, w, Cq,
+              put_window)
 
     # ---- phase 2: o-proj tiles + residual add ------------------------
     @pl.when((t >= S2) & (t < S3))
@@ -1062,7 +1148,17 @@ def fused_ragged_layer(
     and not of the page table's capacity ``R * (maxp + 1)``.  The Pallas
     interpreter takes no dynamic grid bound, so there the grid keeps the
     capacity and the steps past the end do nothing: the same body
-    either way."""
+    either way.
+
+    A cell makes one product a KV head, whose rows are the head's group
+    of ``n_heads / n_kv_heads`` query heads: for a row of one token
+    that token's heads, padded to a sublane tile; for a row of more the
+    step's window ``[w, w + Cq)`` of each head, one head after the
+    other.  The page is read and cast to float32 once a KV head; the
+    flash state is per KV head and stacked row, reset at a row's first
+    cell (a row's cells are consecutive in the list); the self cell
+    finalises the row and writes its tokens for the o-proj phase.  MHA
+    is the group of one, MQA the group of ``n_heads``."""
     T, D = x.shape
     H, KVH = n_heads, n_kv_heads
     hd = D // H
@@ -1100,6 +1196,10 @@ def fused_ragged_layer(
         sin = jnp.pad(sin, ((0, pad), (0, 0)))
         cos = jnp.pad(cos, ((0, pad), (0, 0)))
     Cq = window_size(T_p, max_row_tokens)
+    # stacked rows a KV head: a one-token row's group padded to a
+    # sublane tile, a longer row's group times the window
+    QP = _round8(qpg)
+    SR = max(QP, qpg * Cq)
 
     tq = _pick_tile(Cw, tile_qkv, multiple=hd)
     to = _pick_tile(D, tile_out, multiple=128 if D % 128 == 0 else 1)
@@ -1186,10 +1286,11 @@ def fused_ragged_layer(
     scratch = [
         pltpu.VMEM((T_p, D), jnp.float32),                 # xn_s
         pltpu.VMEM((Tq, T_p, tq), jnp.float32),            # qkv_s
-        pltpu.VMEM((H, T_p, hd), jnp.float32),             # qs
-        pltpu.VMEM((H, T_p, 1), jnp.float32),              # m_s
-        pltpu.VMEM((H, T_p, 1), jnp.float32),              # l_s
-        pltpu.VMEM((H, T_p, hd), jnp.float32),             # acc_s
+        pltpu.VMEM((KVH, qpg * T_p, hd), jnp.float32),     # qs
+        pltpu.VMEM((KVH, QP, hd), jnp.float32),            # q1_s
+        pltpu.VMEM((KVH, SR, 1), jnp.float32),             # m_s
+        pltpu.VMEM((KVH, SR, 1), jnp.float32),             # l_s
+        pltpu.VMEM((KVH, SR, hd), jnp.float32),            # acc_s
         pltpu.VMEM((T_p, H * hd), jnp.float32),            # ao_s
         pltpu.VMEM((To, T_p, to), jnp.float32),            # h_s
         pltpu.VMEM((T_p, D), jnp.float32),                 # y_s

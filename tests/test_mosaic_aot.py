@@ -497,6 +497,26 @@ def _step_shapes(token_budget, max_slots):
     return {"budget": budget, "small": small}
 
 
+def _chat_cell(v5e):
+    """``mistral7b_w8-chat`` as the benchmark runs it, abstract on one
+    v5e device: the model config, the engine's settings, the mesh, the
+    fused int8 artifact and the int8 page cache."""
+    from benchmarks.runners.common import model_config
+
+    config = json.loads(
+        (REPO / "benchmarks/configs/mistral7b_w8.json").read_text())
+    cfg, eng = model_config(config), config["engine"]
+    assert cfg.fused_decode and cfg.kv_int8
+    mesh = _one(v5e)
+    params = _on(mesh, jax.eval_shape(
+        lambda: quant.fuse_for_decode(
+            quant.init_quantized_llama(jax.random.key(0), cfg), cfg)))
+    cache = _on(mesh, jax.eval_shape(
+        lambda: llama.init_paged_cache(cfg, eng["num_pages"],
+                                       eng["page_size"])))
+    return cfg, eng, mesh, params, cache
+
+
 @pytest.mark.parametrize("shape", ["budget", "small"])
 def test_chat_cell_step_copies_no_weight(v5e, shape):
     """``mistral7b_w8-chat`` as the benchmark runs it: Mistral-7B widths,
@@ -507,25 +527,14 @@ def test_chat_cell_step_copies_no_weight(v5e, shape):
     writes a layer's int8 weight again, inside the layer loop or hoisted out of it (a
     reshape of a stacked leaf that stopped being a bitcast would be).
     Before PR 25 it held four a layer, 16.6 ms of a 46 ms step."""
-    from benchmarks.runners.common import model_config
-
-    config = json.loads(
-        (REPO / "benchmarks/configs/mistral7b_w8.json").read_text())
-    cfg, eng = model_config(config), config["engine"]
-    assert cfg.fused_decode and cfg.kv_int8
+    cfg, eng, mesh, params, cache = _chat_cell(v5e)
     slots, page = eng["max_slots"], eng["page_size"]
     maxp = eng["max_seq_len"] // page
     shapes = _step_shapes(slots + page, slots)
     assert (slots, maxp, shapes) == (16, 40, {"budget": 80, "small": 16})
     T = shapes[shape]
-    mesh = _one(v5e)
-    params = _on(mesh, jax.eval_shape(
-        lambda: quant.fuse_for_decode(
-            quant.init_quantized_llama(jax.random.key(0), cfg), cfg)))
     assert llama.ragged_weight_routes(params, cfg)["sliced"] == [
         "ln_attn", "ln_mlp"]
-    cache = _on(mesh, jax.eval_shape(
-        lambda: llama.init_paged_cache(cfg, eng["num_pages"], page)))
     toks, rows, bt = _on(mesh, (
         _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
         _sds(slots, maxp, dtype=jnp.int32)))
@@ -547,6 +556,43 @@ def test_chat_cell_step_copies_no_weight(v5e, shape):
               "  %copy.52 = bf16[80,4096]{1,0} copy(%get-tuple-element.7)\n")
     assert weight_sized_int8_copies(before) == [
         ("dynamic-slice", "1,4096,28672"), ("copy", "32,4096,4096")]
+
+
+@pytest.mark.parametrize("T", [16, 80])
+def test_fused_layer_kernel_at_chat_widths(v5e, T):
+    """``fused_ragged_layer`` alone at the chat cell's widths (32 query
+    heads over 8 KV heads of 128, int8 weights, int8 pools of 64-token
+    pages, 16 slots of 40 pages) at the two step shapes the cell runs.
+    Its attention phase stacks a KV head's four query heads into the
+    rows of one product: 8 rows for a row of one token, 4 x the step's
+    window for a chunk row (the engine names no longest row, so the
+    window is the buffer: 320 rows at 80 positions), whose flash state
+    and unrolled KV heads have to fit the 48 MiB the call states.  One
+    Mosaic call, its grid bound an operand."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+
+    cfg, eng, mesh, params, cache = _chat_cell(v5e)
+    slots = eng["max_slots"]
+    maxp = eng["max_seq_len"] // eng["page_size"]
+    x, rope, rows, bt, ly = _on(mesh, (
+        _sds(T, cfg.dim), _sds(T, cfg.head_dim // 2, dtype=jnp.float32),
+        _sds(slots, dtype=jnp.int32), _sds(slots, maxp, dtype=jnp.int32),
+        _sds(dtype=jnp.int32)))
+
+    def layer(x, layers, c, ly, rs, r0, rl, ro, bt, sin, cos):
+        return rpa.fused_ragged_layer(
+            x, layers, c["k"], c["v"], ly, rs, r0, rl, ro, bt, sin, cos,
+            eps=cfg.norm_eps, n_heads=cfg.n_heads,
+            n_kv_heads=cfg.n_kv_heads, k_scales=c["k_scale"],
+            v_scales=c["v_scale"])
+
+    args = (x, params["layers"], cache, ly, rows, rows, rows, rows, bt,
+            rope, rope)
+    text = _compile(layer, *args).as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    assert _pallas_grids(jax.make_jaxpr(layer)(*args).jaxpr,
+                         "fused_ragged_layer") == [(None,)]
 
 
 # -- Jamba: the selective-scan kernel and the cell's whole step ---------------

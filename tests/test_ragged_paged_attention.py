@@ -18,6 +18,7 @@ round differently between fused programs — both roundings are valid).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -606,6 +607,146 @@ def test_fused_live_walk_gives_the_old_walks_bits(kv_int8, case):
     for g, old, built_here in zip(got, layer(every_cell), layer(None)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(old))
         np.testing.assert_array_equal(np.asarray(g), np.asarray(built_here))
+
+
+# ---------------------------------------------------------------------------
+# the fused layer's attention phase: a KV head's group stacked into one
+# product, a one-token row through a window of that token
+# ---------------------------------------------------------------------------
+
+# (slot, start, len, off) of six packed rows over the same 6 x 4 table
+# of 16-token pages, in a 40-token buffer: what decides which of the
+# phase's two row paths a cell takes, and where each can go wrong.
+STACKED_ROWS = {
+    # rows of one token at offsets that are no multiple of 8
+    "decode_unaligned": ([2, 0, 5, 1, 3, 0], [19, 33, 63, 5, 40, 0],
+                         [1, 1, 1, 1, 1, 0], [1, 3, 6, 11, 13, 0]),
+    # a chunk whose window holds its neighbours' tokens on both sides
+    "chunk_beside_decode": ([4, 0, 2, 5, 0, 0], [21, 9, 50, 32, 0, 0],
+                            [1, 11, 1, 1, 0, 0], [0, 1, 12, 13, 0, 0]),
+    # one-token prompts and a fresh chunk: a self cell and no pool
+    # cell, the state reset there
+    "one_token_start_zero": ([3, 1, 4, 2, 0, 0], [0, 27, 0, 0, 0, 0],
+                             [1, 1, 1, 6, 0, 0], [2, 5, 9, 12, 0, 0]),
+    # a prompt's LAST chunk of one token, behind chunks of two prompts
+    "last_chunk_one_token": ([5, 1, 2, 0, 0, 0], [52, 20, 37, 0, 0, 0],
+                             [1, 9, 1, 0, 0, 0], [0, 1, 10, 0, 0, 0]),
+    # pooled tokens end exactly at a page's end, on both paths
+    "start_on_page_boundary": ([0, 1, 2, 3, 0, 0], [16, 32, 48, 16, 0, 0],
+                               [1, 5, 1, 7, 0, 0], [3, 4, 9, 10, 0, 0]),
+    # padding rows in front of, between and behind the live ones
+    "padding_between": ([0, 2, 0, 5, 1, 0], [0, 19, 0, 16, 40, 0],
+                        [0, 1, 0, 11, 1, 0], [0, 0, 0, 1, 12, 0]),
+    # no live row: an empty list, the attention phase no grid step
+    "empty_list": ([0] * 6, [0] * 6, [0] * 6, [0] * 6),
+}
+# (H, KVH): MHA is the group of one, GQA's four pad to a sublane tile,
+# MQA is the group of H
+STACKED_HEADS = {"qpg1": (4, 4), "qpg4": (8, 2), "qpgH": (8, 1)}
+_HD, _MLP, _T = 32, 256, 40
+
+
+def _plain_layers(rng, L, H, KVH, dt):
+    """A stacked layer tree of plain (unquantized) fused leaves."""
+    D = H * _HD
+
+    def w(*shape):
+        return jnp.asarray(rng.standard_normal(shape) * shape[1] ** -0.5, dt)
+
+    return {"attn": {"wqkv": w(L, D, (H + 2 * KVH) * _HD), "wo": w(L, D, D)},
+            "mlp": {"w_gateup": w(L, D, 2 * _MLP), "w_down": w(L, _MLP, D)},
+            "ln_attn": jnp.asarray(1 + 0.1 * rng.standard_normal((L, D)), dt),
+            "ln_mlp": jnp.asarray(1 + 0.1 * rng.standard_normal((L, D)), dt)}
+
+
+def _layer_reference(x, layers, li, kp, vp, ks, vs, meta, sin, cos, H, KVH,
+                     eps):
+    """The layer ``fused_ragged_layer`` runs, in plain float32 jnp round
+    ``ragged_attention_reference``; what the kernel rounds to the
+    operands' dtype (fresh k/v, the attention's output) is rounded."""
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    dt, T = x.dtype, x.shape[0]
+    thru = lambda a: a.astype(dt).astype(jnp.float32)
+
+    def norm(v, g):
+        return v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps) * g
+
+    def rope(a):
+        a1, a2 = a[..., :_HD // 2], a[..., _HD // 2:]
+        sn, cs = sin[:, None], cos[:, None]
+        return jnp.concatenate([a1 * cs - a2 * sn, a2 * cs + a1 * sn], -1)
+
+    x32 = f32(x)
+    heads = (thru(norm(x32, f32(layers["ln_attn"][li])))
+             @ f32(layers["attn"]["wqkv"][li])).reshape(T, H + 2 * KVH, _HD)
+    q, k = rope(heads[:, :H]), thru(rope(heads[:, H:H + KVH]))
+    v = thru(heads[:, H + KVH:])
+    attn = rpa.ragged_attention_reference(
+        q, k, v, kp[li] if ks is not None else f32(kp[li]),
+        vp[li] if ks is not None else f32(vp[li]), *meta,
+        k_scales=None if ks is None else ks[li],
+        v_scales=None if vs is None else vs[li])
+    h = x32 + thru(attn.reshape(T, H * _HD)) @ f32(layers["attn"]["wo"][li])
+    gu = thru(norm(h, f32(layers["ln_mlp"][li]))) @ f32(
+        layers["mlp"]["w_gateup"][li])
+    act = jax.nn.silu(gu[:, :_MLP]) * gu[:, _MLP:]
+    return h + thru(act) @ f32(layers["mlp"]["w_down"][li]), k, v
+
+
+# one compile a (heads, dtype): the row arrays are operands
+_STACKED_LAYER = {
+    name: jax.jit(functools.partial(
+        rpa.fused_ragged_layer, eps=1e-5, n_heads=H, n_kv_heads=KVH))
+    for name, (H, KVH) in STACKED_HEADS.items()}
+
+
+@pytest.mark.parametrize("case", list(STACKED_ROWS))
+@pytest.mark.parametrize("heads", list(STACKED_HEADS))
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+def test_fused_layer_stacked_heads_match_reference(kind, heads, case):
+    """``fused_ragged_layer`` against the dense reference layer: its
+    attention phase makes one product a KV head and cell (the group's
+    query heads stacked, head-major), takes a row of one token through
+    a window of that token and a longer row through the step's window,
+    and resets the flash state at a row's first cell."""
+    rng = np.random.default_rng(38)
+    (H, KVH), L, li = STACKED_HEADS[heads], 2, 1
+    D, Pt = H * _HD, _SLOTS * _MAXP + 1
+    dt = jnp.bfloat16 if kind == "bfloat16" else jnp.float32
+    kp, vp, ks, vs = _pools(rng, L, KVH, Pt, _PAGE, _HD, int8=kind == "int8")
+    if kind != "int8":
+        kp, vp = kp.astype(dt), vp.astype(dt)
+    bt = rng.permutation(Pt - 1).reshape(_SLOTS, _MAXP).astype(np.int32)
+    slot, start, nlen, off = (np.asarray(a, np.int32)
+                              for a in STACKED_ROWS[case])
+    meta = tuple(jnp.asarray(a) for a in (slot, start, nlen, off, bt))
+    layers = _plain_layers(rng, L, H, KVH, dt)
+    x = jnp.asarray(rng.standard_normal((_T, D)), dt)
+    pos = np.zeros(_T, np.int32)
+    for r in range(_SLOTS):
+        pos[off[r]:off[r] + nlen[r]] = start[r] + np.arange(nlen[r])
+    ang = pos[:, None] * (1e4 ** (-np.arange(_HD // 2) / (_HD // 2)))[None]
+    sin, cos = jnp.asarray(np.sin(ang), jnp.float32), jnp.asarray(
+        np.cos(ang), jnp.float32)
+    pools_before = [np.asarray(a).copy() for a in (kp, vp)]
+
+    got = _STACKED_LAYER[heads](
+        x, layers, kp, vp, jnp.int32(li), *meta, sin, cos, k_scales=ks,
+        v_scales=vs)
+    want = _layer_reference(x, layers, li, kp, vp, ks, vs, meta, sin, cos,
+                            H, KVH, 1e-5)
+    n_live = int(rpa.live_page_cells(meta[1], meta[2], _MAXP, _PAGE)[1][0])
+    assert (n_live == 0) == (case == "empty_list")
+    tol = 3e-2 if kind == "bfloat16" else 2e-4
+    for g, w_, shape in zip(got, want, ((_T, D), (_T, KVH, _HD),
+                                        (_T, KVH, _HD))):
+        g, w_ = np.asarray(g, np.float32), np.asarray(w_, np.float32)
+        assert g.shape == shape and np.isfinite(g).all()
+        np.testing.assert_allclose(g, w_, rtol=0,
+                                   atol=tol * np.abs(w_).max())
+    # the pools are operands the layer only reads
+    for before, after in zip(pools_before, (kp, vp)):
+        np.testing.assert_array_equal(before, np.asarray(after))
 
 
 # ---------------------------------------------------------------------------
