@@ -1004,6 +1004,104 @@ def phase_serve_xing(platform: str, *, config=None, n_requests: int = 6,
                 model_counters=counters, served_check=served)
 
 
+def phase_serve_glm5(platform: str, *, config=None, n_requests: int = 4,
+                     prompt_len: int = 2600, new_tokens: int = 16,
+                     controls=None, ready_timeout_s: float = 1500.0) -> dict:
+    """The serving phase's fifth case: latent attention kept to the
+    positions a learned indexer selects, over a latent and an index-key
+    pool, and routed experts of which the chip holds 16 of 256.  GLM-5 at
+    three layers (one dense, two routed) of the published widths through
+    the benchmark's own replica class: the reference check before the
+    engine takes the memory (logits, routing, the selection, the first
+    layer's attention output and both pools' pages), prompts past
+    ``index_topk`` through ``serve.run`` so that decode rows select, the
+    held experts' counters, the served tokens held to the reference with
+    the logged choices, and the controls (``serve_glm5.CONTROLS``: a
+    planted fault each, which its part of the check has to refuse).
+    ``config`` defaults to the benchmark's file; ``controls`` to all."""
+    import ray_tpu
+    from benchmarks.runners import serve_glm5
+    from ray_tpu import serve
+
+    config = config or _benchmark_config("glm5_ep16")
+    config = dict(config, **serve_glm5.CHECK_HF,
+                  engine=dict(config["engine"], max_slots=8,
+                              max_seq_len=prompt_len + 8 * new_tokens))
+    names = list(serve_glm5.CONTROLS if controls is None else controls)
+    ray_tpu.init(ignore_reinit_error=True)
+    try:
+        chips = int(ray_tpu.cluster_resources().get("TPU", 0))
+        app = serve.deployment(
+            ray_actor_options=({"num_tpus": chips} if platform == "tpu"
+                               else {}),
+            max_ongoing_requests=2 * n_requests,
+        )(serve_glm5.server_class()).bind({"config": config, "seed": 0})
+        t0 = time.perf_counter()
+        handle = serve.run(app, name="chip_smoke_glm5", route_prefix=None,
+                           timeout_s=ready_timeout_s)
+        rep = handle.device_report.remote().result(timeout_s=60)
+        check = rep["check"]
+        log(f"  glm5 replica ready after {time.perf_counter() - t0:.1f}s "
+            f"on {rep['platform']}; reference check: "
+            + " ".join(f"{k}={check[k]['rel_err_prefill']:.2e}/"
+                       f"{check[k]['rel_err_decode']:.2e}"
+                       for k in serve_glm5.TOLERANCES)
+            + f" route={check['route']} selection={check['selection']}"
+              f" pools={check['pool_pages']}")
+        if rep["platform"] != platform:
+            raise AssertionError(
+                f"replica computes on {rep['platform']!r}, not "
+                f"{platform!r}")
+        if not check["ok"]:
+            raise AssertionError(f"glm5 reference check failed: {check}")
+        vocab = config["vocab_size"]
+        prompts = [[(7 * i + 3 * j) % (vocab - 1) + 1
+                    for j in range(prompt_len)] for i in range(n_requests)]
+        pending = [handle.remote({"tokens": p, "max_new_tokens": new_tokens,
+                                  "temperature": 0.0}) for p in prompts]
+        outs = [r.result(timeout_s=ready_timeout_s)["tokens"]
+                for r in pending]
+        check_answers(outs, new_tokens, vocab)
+        counters = handle.counters.remote().result(
+            timeout_s=60)["model_counters"]
+        pairs = [sum(layer) for layer in counters["moe_tokens"]]
+        log(f"  {len(outs)} glm5 requests answered ({prompt_len} prompt + "
+            f"{new_tokens} new tokens each); pairs served by the experts "
+            f"held, by layer {pairs}")
+        # the experts held serve their share of the pairs and no more
+        fed = n_requests * (prompt_len + new_tokens - 1) \
+            * config["num_experts_per_tok"]
+        if min(pairs) <= 0 or max(pairs) >= fed:
+            raise AssertionError(f"experts' counters: {pairs} of {fed}")
+        served = handle.served_check.remote().result(
+            timeout_s=ready_timeout_s)
+        log(f"  served tokens against the reference: {served}")
+        if not served["ok"] or served["held"] < n_requests:
+            raise AssertionError(f"glm5 served-token check: {served}")
+        found = {}
+        for name in names:
+            part = serve_glm5.CONTROLS[name][1]
+            found[name] = handle.control.remote(name).result(
+                timeout_s=ready_timeout_s)[part]
+            log(f"  {name}: {found[name]} against {check[part]}")
+            if found[name]["ok"]:
+                raise AssertionError(
+                    f"the check accepts {name}: {found[name]}")
+        planted = handle.served_control.remote().result(
+            timeout_s=ready_timeout_s)
+        for name in ("other_answer", "one_token"):
+            found[name] = planted.get(name)
+            log(f"  served check, {name}: {found[name]}")
+            if found[name] is None or found[name]["ok"]:
+                raise AssertionError(
+                    f"the served check accepts {name}: {planted}")
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    return dict(found, platform=rep["platform"], reference_check=check,
+                model_counters=counters, served_check=served)
+
+
 # ---------------------------------------------------------------------------
 # phase: engine_legacy
 # ---------------------------------------------------------------------------
@@ -1153,6 +1251,7 @@ def run_child(phase: str, expect_loss0) -> int:
             report["jamba"] = phase_serve_jamba("tpu")
             report["brumby"] = phase_serve_brumby("tpu")
             report["xing"] = phase_serve_xing("tpu")
+            report["glm5"] = phase_serve_glm5("tpu")
     else:
         clock = CompileClock()
         try:

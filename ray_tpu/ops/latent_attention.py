@@ -30,6 +30,20 @@ others through the step's whole window with the heads in groups of
 ``CHUNK_HEADS`` (outermost in the list, so that a group's output block
 stays where it is; the chunk's flash state then fits the chip's VMEM).
 A call whose list is empty has no grid step.
+
+SPARSE attention (a learned indexer selects, for every query, the cached
+positions it may attend to: ``ops/dsa_index``) is
+``ragged_sparse_latent_attention``.  A row of ONE token reads the
+selected pool rows and no other: they are gathered through the block
+table (``selected_rows_attention``, plain XLA: a gather of ``topk`` rows
+of the pool and two small products).  A row of more tokens (a prompt
+chunk) keeps the walk over every live cell of its context ONCE, under
+the selection as a mask: 512 queries' selections cover most of a context
+between them, and the same function is computed; what it costs past the
+selected share is the arithmetic on the masked scores (reading a chunk's
+union of selections once is ROADMAP Queue 2's).  In that call the heads
+of a group are stacked head-major (stacked row ``h * T + t``), so that a
+``[T, page]`` mask tiles over them, and its window is the whole step.
 The Pallas interpreter takes no dynamic bound: there the grid keeps the
 list's capacity and the steps past its end do nothing, the same body.
 """
@@ -59,6 +73,11 @@ from ray_tpu.ops.ragged_paged_attention import (
 # heads a group of the chunk call stacks: 16 x a window of 288 rows of
 # float32 state is 9.4 MB of VMEM, all 32 would be 19
 CHUNK_HEADS = 16
+# heads a group of the masked (sparse) chunk call stacks: the step's
+# whole window is its query window (520 positions x 8 heads of float32
+# state and the self cell's [rows, 520] scores are 60 MB of VMEM; 16 heads
+# would not fit)
+SPARSE_CHUNK_HEADS = 8
 VMEM_LIMIT = 100 * 1024 * 1024
 
 
@@ -165,15 +184,29 @@ def latent_cell_count(row_start, row_len, page: int, H: int) -> int:
     return int(np.sum((nlen > 0) * groups * cells))
 
 
+def sparse_cell_count(row_start, row_len, page: int, H: int) -> int:
+    """The cells ``ragged_sparse_latent_attention``'s masked walk takes
+    for a step's packed rows, on the host: the pooled pages and the self
+    cell of each row of more than one token, once a head group; a row of
+    one token walks none (its rows are gathered)."""
+    start, nlen = np.asarray(row_start), np.asarray(row_len)
+    groups = H // min(H, SPARSE_CHUNK_HEADS)
+    return int(np.sum((nlen > 1) * groups * (-(-start // page) + 1)))
+
+
 # --------------------------------------------------------------------------
 # the kernel
 # --------------------------------------------------------------------------
 
 def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
-                   q_ref, new_ref, pool_ref, out_ref, m_s, l_s, acc_s, *,
-                   T: int, Cq: int, HG: int, R: int, maxp: int, page: int,
-                   rank: int, scale: float):
+                   q_ref, new_ref, pool_ref, *rest, T: int, Cq: int, HG: int,
+                   R: int, maxp: int, page: int, rank: int, scale: float,
+                   masked: bool = False):
     del slot_r, bt_r, ly_r      # the index maps' own
+    if masked:      # the selection: this page's columns, the step's own
+        selp_ref, sels_ref, out_ref, m_s, l_s, acc_s = rest
+    else:
+        out_ref, m_s, l_s, acc_s = rest
     i = pl.program_id(0)
     rows = Cq * HG
     Ck = max(Cq, 8)             # the self cell's keys: a sublane tile
@@ -189,12 +222,18 @@ def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
         start, nt, off = start_r[r], len_r[r], off_r[r]
         # the keys' window starts on a sublane tile; a query window of
         # one token is that token (its HG stacked rows are aligned)
-        wk = pl.multiple_of(jnp.minimum((off // 8) * 8, T - Ck), 8)
-        w = off if Cq == 1 else wk
-        wr = pl.multiple_of(w * HG, HG if Cq == 1 else 8 * HG)
-        # stacked row j is token j // HG of the window, head j % HG
-        tj = lax.shift_right_logical(
-            lax.broadcasted_iota(jnp.int32, (rows, 1), 0), shift)
+        if masked:
+            # the whole step is the window; stacked row j is head j // T,
+            # token j % T, so a [T, keys] mask tiles over the heads
+            wk = w = wr = 0
+            tj = lax.rem(lax.broadcasted_iota(jnp.int32, (rows, 1), 0), T)
+        else:
+            wk = pl.multiple_of(jnp.minimum((off // 8) * 8, T - Ck), 8)
+            w = off if Cq == 1 else wk
+            wr = pl.multiple_of(w * HG, HG if Cq == 1 else 8 * HG)
+            # stacked row j is token j // HG of the window, head j % HG
+            tj = lax.shift_right_logical(
+                lax.broadcasted_iota(jnp.int32, (rows, 1), 0), shift)
         trel = w + tj - off
         valid_q = (trel >= 0) & (trel < nt)
 
@@ -211,14 +250,22 @@ def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
                 qs, keys, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
 
-        def flash_update(s, keys):
+        def selected(ref_value):
+            return jnp.concatenate([ref_value] * HG, axis=0) > 0
+
+        def flash_update(s, keys, keep=None):
             """Masked online-softmax update; rows of the window that are
-            not this row's tokens keep their state."""
+            not this row's tokens keep their state.  Under a selection a
+            query may find no key in a cell before it has found any
+            (its maximum is then still NEG_INF, and exp(s - m) of a
+            masked score would read 1): ``keep`` zeroes those."""
             m_prev = m_s[...]
             m_new = jnp.where(
                 valid_q, jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True)),
                 m_prev)
             p = jnp.exp(s - m_new)
+            if keep is not None:
+                p = jnp.where(keep, p, 0.0)
             corr = jnp.exp(m_prev - m_new)
             l_new = corr * l_s[...] + jnp.sum(p, -1, keepdims=True)
             pv = lax.dot_general(
@@ -234,26 +281,34 @@ def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
         def _pool_cell():
             keys = pool_ref[0, 0, 0]
             kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
-            s = jnp.where(valid_q & (kpos < start), scores(keys), NEG_INF)
-            flash_update(s, keys)
+            keep = valid_q & (kpos < start)
+            if masked:
+                keep = keep & selected(selp_ref[0])
+            flash_update(jnp.where(keep, scores(keys), NEG_INF), keys,
+                         keep if masked else None)
 
         @pl.when(pc == maxp)
         def _self_cell():
             keys = new_ref[pl.ds(wk, Ck), :]
             krel = wk + lax.broadcasted_iota(jnp.int32, (1, Ck), 1) - off
             mask = valid_q & (krel >= 0) & (krel < nt) & (krel <= trel)
+            if masked:
+                mask = mask & selected(sels_ref[...])
             l_new, a_new = flash_update(
-                jnp.where(mask, scores(keys), NEG_INF), keys)
+                jnp.where(mask, scores(keys), NEG_INF), keys,
+                mask if masked else None)
             o = a_new / jnp.maximum(l_new, 1e-30)
             cur = out_ref[0, pl.ds(wr, rows), :]
             out_ref[0, pl.ds(wr, rows), :] = jnp.where(valid_q, o, cur)
 
 
 def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
-                 Cq: int, HG: int, scale: float, rank: int):
+                 Cq: int, HG: int, scale: float, rank: int, sel=None):
     """One call: the rows ``takes`` marks, through a window of ``Cq``
     tokens, the heads in groups of ``HG``.  Returns [T, H, rank] float32,
-    defined at the tokens of the rows taken and nowhere else."""
+    defined at the tokens of the rows taken and nowhere else.  ``sel``
+    (``(pool [T, maxp * page], self [T, T])`` bool) keeps a query to the
+    keys it marks; the window is then the step."""
     T, H, W = q.shape
     L, _, Pt, page, _ = pool.shape
     row_slot, row_start, row_len, row_off = rows
@@ -261,8 +316,19 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
     maxp = block_tables.shape[1]
     NG = H // HG
     assert NG * HG == H and HG & (HG - 1) == 0, (H, HG)
-    # [NG, T * HG, W]: stacked row t * HG + h of group g
-    q2 = q.reshape(T, NG, HG, W).transpose(1, 0, 2, 3).reshape(NG, T * HG, W)
+    masked = sel is not None
+    if masked:
+        assert Cq == T, (Cq, T)
+        # [NG, HG * T, W]: stacked row h * T + t of group g
+        q2 = q.reshape(T, NG, HG, W).transpose(1, 2, 0, 3).reshape(
+            NG, HG * T, W)
+        sel_in = [sel[0].reshape(T, maxp, page).transpose(1, 0, 2).astype(
+            jnp.float32), sel[1].astype(jnp.float32)]
+    else:
+        # [NG, T * HG, W]: stacked row t * HG + h of group g
+        q2 = q.reshape(T, NG, HG, W).transpose(1, 0, 2, 3).reshape(
+            NG, T * HG, W)
+        sel_in = []
     live_ci, n_live = live_latent_cells(row_start, row_len, takes, NG,
                                         maxp, page)
     cap = live_ci.shape[0]
@@ -284,6 +350,12 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
         pe = jnp.minimum(jnp.minimum(ci % (maxp + 1), maxp - 1), last)
         return (ly[0], 0, jnp.minimum(bt[slot_p[r], pe], Pt - 1), 0, 0)
 
+    def selp_map(i, *pf):
+        live, nl = pf[-2:]
+        return (jnp.minimum(cell(i, live, nl) % (maxp + 1), maxp - 1), 0, 0)
+
+    sel_specs = [pl.BlockSpec((1, T, page), selp_map),
+                 pl.BlockSpec((T, T), lambda i, *pf: (0, 0))] if masked else []
     interpret = platform.interpret_mode()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -292,7 +364,7 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
             pl.BlockSpec((1, T * HG, W), group_map),
             pl.BlockSpec((T, W), lambda i, *pf: (0, 0)),
             pl.BlockSpec((1, 1, 1, page, W), pool_map),
-        ],
+        ] + sel_specs,
         out_specs=pl.BlockSpec((1, T * HG, rank), group_map),
         scratch_shapes=[
             pltpu.VMEM((Cq * HG, 1), jnp.float32),
@@ -302,7 +374,7 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
     )
     kern = functools.partial(
         _latent_kernel, T=T, Cq=Cq, HG=HG, R=R, maxp=maxp, page=page,
-        rank=rank, scale=scale)
+        rank=rank, scale=scale, masked=masked)
     out = pl.pallas_call(
         kern,
         name="ragged_latent_attention",
@@ -310,7 +382,10 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
         out_shape=jax.ShapeDtypeStruct((NG, T * HG, rank), jnp.float32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(*prefetch, q2, new, pool)
+    )(*prefetch, q2, new, pool, *sel_in)
+    if masked:
+        return out.reshape(NG, HG, T, rank).transpose(2, 0, 1, 3).reshape(
+            T, H, rank)
     return out.reshape(NG, T, HG, rank).transpose(1, 0, 2, 3).reshape(
         T, H, rank)
 
@@ -347,6 +422,77 @@ def ragged_latent_attention(
         mine = jnp.any((trel >= 0) & (trel < row_len[None, :])
                        & takes[None, :], axis=1)
         out = jnp.where(mine[:, None, None], got, out)
+    return out[:T]
+
+
+# --------------------------------------------------------------------------
+# sparse: a selection a query
+# --------------------------------------------------------------------------
+
+def selected_rows_attention(q, new, pool, layer, row_slot, row_start,
+                            row_off, block_tables, idx, ok, *,
+                            scale: float, rank: int) -> jax.Array:
+    """Rows of ONE token over the pool rows their selection names:
+    ``idx [R, K]`` positions of the row's sequence, ``ok [R, K]`` which
+    of them count.  The position ``row_start`` is the row's own fresh
+    token (``new``: the pool does not hold it yet).  Reads ``K`` pool
+    rows a row and nothing else of the pool.  Returns [R, H, rank]
+    float32 (garbage where a row has no selection: the caller keeps it
+    to rows of one token)."""
+    T = q.shape[0]
+    L, _, Pt, page, W = pool.shape
+    t = jnp.clip(row_off, 0, T - 1)
+    q1, new1 = q[t], new[t]                                   # [R, H, W]
+    tables = block_tables[row_slot]                           # [R, maxp]
+    pg = jnp.minimum(jnp.take_along_axis(tables, idx // page, axis=1),
+                     Pt - 1)
+    lat = pool[layer, 0, pg, idx % page]                      # [R, K, W]
+    lat = jnp.where((idx == row_start[:, None])[..., None],
+                    new1[:, None, :].astype(lat.dtype), lat)
+    s = jnp.einsum("rhw,rkw->rhk", q1, lat,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(ok[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("rhk,rkc->rhc", p.astype(lat.dtype), lat[..., :rank],
+                      preferred_element_type=jnp.float32)
+
+
+def ragged_sparse_latent_attention(
+        q: jax.Array, new: jax.Array, pool: jax.Array, layer,
+        row_slot, row_start, row_len, row_off, block_tables: jax.Array,
+        sel, *, scale: float, rank: int) -> jax.Array:
+    """``ragged_latent_attention`` with every query kept to the keys a
+    selection marks (``ops/dsa_index.Selection``): rows of one token
+    through ``selected_rows_attention`` (``sel.one_idx``), the others
+    (``sel.more``: the rows the masked walk takes) over their whole
+    context once under ``sel.pool`` / ``sel.self`` as a mask, the same
+    function.  float32 [T, H, rank]; zero at positions no row covers."""
+    T, H, _ = q.shape
+    T_p = _round8(T)
+    if T_p != T:
+        q = jnp.pad(q, ((0, T_p - T), (0, 0), (0, 0)))
+        new = jnp.pad(new, ((0, T_p - T), (0, 0)))
+    pad = ((0, T_p - T), (0, 0))
+    sel_pool = jnp.pad(sel.pool, pad)
+    sel_self = jnp.pad(sel.self, ((0, T_p - T), (0, T_p - T)))
+    rows = tuple(a.astype(jnp.int32)
+                 for a in (row_slot, row_start, row_len, row_off))
+    row_slot, row_start, row_len, row_off = rows
+    HG = min(H, SPARSE_CHUNK_HEADS)
+    out = _latent_call(q, new, pool, layer, rows, block_tables, sel.more,
+                       Cq=T_p, HG=HG, scale=scale, rank=rank,
+                       sel=(sel_pool, sel_self))
+    trel = jnp.arange(T_p)[:, None] - row_off[None, :]       # [T, R]
+    mine = jnp.any((trel >= 0) & (trel < row_len[None, :])
+                   & sel.more[None, :], axis=1)
+    out = jnp.where(mine[:, None, None], out, 0.0)
+    one = (row_len == 1) & ~sel.more
+    o1 = selected_rows_attention(
+        q, new, pool, layer, row_slot, row_start, row_off, block_tables,
+        sel.one_idx, sel.one_ok, scale=scale, rank=rank)
+    # a row's one token sits at row_off; rows not taken write nowhere
+    at = jnp.where(one, row_off, T_p)
+    out = out.at[at].set(o1, mode="drop")
     return out[:T]
 
 

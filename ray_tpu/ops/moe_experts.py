@@ -8,6 +8,14 @@ as the pairs that chose it, and every pair is computed
 pairs past an expert's share).  A pair whose token is padding
 (``valid`` false) belongs to no group and costs nothing.
 
+A chip that holds a SHARE of a layer's experts (expert parallelism: the
+router scores every expert of the layer, this chip stores ids ``[first,
+first + E)`` of them) says so with ``first``: the choices stay the
+layer's ids, a pair whose expert lives on another chip belongs to no
+group here, exactly as a padding token's, and the sum returned is this
+chip's part of the layer's.  Nothing stands in for the other chips or
+for the exchange that adds their parts.
+
 The three grouped products are the kernel ``moe_grouped_ffn``.  Each
 group is padded to tiles of ``TILE`` rows; the grid walks the list of live
 tiles under a dynamic bound, a tile's expert named by a scalar-prefetched
@@ -43,13 +51,15 @@ VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def routed_experts_reference(u, choice, weight, experts: Dict[str, jax.Array],
-                             valid=None) -> jax.Array:
-    """The loop over experts: every expert on every token, masked."""
+                             valid=None, first: int = 0) -> jax.Array:
+    """The loop over the experts held (ids ``first`` and up): every one
+    of them on every token, masked."""
     f32 = jnp.float32
     uf = u.astype(f32)
     out = jnp.zeros(u.shape, f32)
     for e in range(experts["w_gate"].shape[0]):
-        we = jnp.sum(jnp.where(choice == e, weight.astype(f32), 0.0), -1)
+        we = jnp.sum(jnp.where(choice == first + e, weight.astype(f32),
+                               0.0), -1)
         g = uf @ experts["w_gate"][e].astype(f32)
         up = uf @ experts["w_up"][e].astype(f32)
         out = out + we[:, None] * (
@@ -57,11 +67,15 @@ def routed_experts_reference(u, choice, weight, experts: Dict[str, jax.Array],
     return out if valid is None else jnp.where(valid[:, None], out, 0.0)
 
 
-def sort_pairs(choice: jax.Array, valid: jax.Array, n_experts: int):
+def sort_pairs(choice: jax.Array, valid: jax.Array, n_experts: int,
+               first: int = 0):
     """The step's (token, expert) pairs in expert order: ``(order [P],
     group_sizes [E])`` with ``order`` indexing the flat pairs ``t * k +
-    j``; pairs of padding tokens sort last and belong to no group."""
-    e = jnp.where(valid[:, None], choice, n_experts).reshape(-1)
+    j``, over the ``n_experts`` held from id ``first``; pairs of padding
+    tokens and of experts not held sort last and belong to no group."""
+    local = choice - first
+    here = valid[:, None] & (local >= 0) & (local < n_experts)
+    e = jnp.where(here, local, n_experts).reshape(-1)
     order = jnp.argsort(e, stable=True).astype(jnp.int32)
     sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[e].add(1)[:n_experts]
     return order, sizes
@@ -161,14 +175,16 @@ def routed_experts(u: jax.Array,            # [T, D]
                    choice: jax.Array,       # [T, k] int32
                    weight: jax.Array,       # [T, k] float32
                    experts: Dict[str, jax.Array],   # [E, D, F], [E, F, D]
-                   valid: jax.Array):       # [T] bool
+                   valid: jax.Array,        # [T] bool
+                   first: int = 0):
     """Returns ``(y [T, D] float32, group_sizes [E])``; ``y`` is zero at
-    padding tokens."""
+    padding tokens.  ``experts`` are the ``E`` held, ids ``first`` to
+    ``first + E`` of the layer's; ``choice`` names the layer's ids."""
     T, D = u.shape
     k = choice.shape[1]
     E = experts["w_gate"].shape[0]
     mats = tuple(experts[n] for n in ("w_gate", "w_up", "w_down"))
-    order, sizes = sort_pairs(choice, valid, E)
+    order, sizes = sort_pairs(choice, valid, E, first)
     xs = u[order // k]
     ys = _grouped_ffn(xs, sizes, *mats)
     ys = ys * weight.reshape(-1)[order][:, None]

@@ -533,6 +533,12 @@ class PagedEngineAdapter:
     # adapters).  None = the page table's capacity, R * (maxp + 1),
     # whatever the rows hold.  ``llm.pack`` reports it as grid_cells.
     ragged_grid_cells: Optional[Callable[..., int]] = None
+    # ragged_sel_tokens(row_start, row_len) -> int: the cached positions
+    # a SPARSE attention selects for one step's packed rows, summed over
+    # its query tokens (``min(position + 1, topk)`` each); None = the
+    # model attends to every cached position.  ``llm.pack`` reports it
+    # as sel_tokens.
+    ragged_sel_tokens: Optional[Callable[..., int]] = None
     # Bytes of recurrent state one sequence holds per slot, whatever its
     # length (state-space layers: convolution tails, SSM states); 0 = the
     # cache is KV pages only.  Non-zero, the engine calls
@@ -722,6 +728,49 @@ def xing_paged_adapter(cfg) -> PagedEngineAdapter:
                              row_len, row_off, bt, cfg, cache),
         ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
             latent_cell_count(row_start, row_len, page, cfg.n_heads),
+        counter_leaves=("moe_tokens", "moe_distinct"),
+    )
+
+
+def glm5_paged_adapter(cfg) -> PagedEngineAdapter:
+    """GLM-5 (models/glm5.py): latent attention kept to the positions a
+    learned indexer selects, over TWO page pools under the same block
+    tables (``kv_c``: a token's ``c | kr``; ``kv_i``: its index key), and
+    routed experts of which this chip holds ``cfg.n_experts``.  The cache
+    is pages and nothing by slot.  As for ``xing_paged_adapter`` the
+    engine refuses the prefix cache (and KV migration with it: its
+    programs ship ``k``/``v`` by name), speculative decoding and a mesh."""
+    from ray_tpu.models import glm5
+    from ray_tpu.ops.dsa_index import sel_token_count
+    from ray_tpu.ops.latent_attention import sparse_cell_count
+
+    def init_cache(num_pages, page):
+        from ray_tpu.util import flight_recorder
+
+        cache = glm5.init_cache(cfg, num_pages, page)
+        experts = 3 * cfg.dim * cfg.moe_dim * jnp.dtype(
+            cfg.param_dtype).itemsize
+        flight_recorder.record(
+            "serve_model_parts", model="glm5",
+            expert_bytes=experts, experts_per_layer=cfg.n_experts,
+            experts_of_layer=cfg.n_routed, routed_layers=cfg.n_moe,
+            routed_expert_bytes=experts * cfg.n_experts * cfg.n_moe,
+            latent_pool_bytes=int(cache["kv_c"].size
+                                  * cache["kv_c"].dtype.itemsize),
+            index_pool_bytes=int(cache["kv_i"].size
+                                 * cache["kv_i"].dtype.itemsize))
+        return cache
+
+    return PagedEngineAdapter(
+        init_cache=init_cache,
+        ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
+        row_len, row_off, bt, cache:
+            glm5.ragged_step(params, tokens, tok_pos, row_slot, row_start,
+                             row_len, row_off, bt, cfg, cache),
+        ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
+            sparse_cell_count(row_start, row_len, page, cfg.n_heads),
+        ragged_sel_tokens=lambda row_start, row_len:
+            sel_token_count(row_start, row_len, cfg.index_topk),
         counter_leaves=("moe_tokens", "moe_distinct"),
     )
 
@@ -1307,6 +1356,7 @@ class LLMEngine:
         # state_bytes_per_slot): what the engine may not do with it.
         self._state_bytes_per_slot = int(adapter.state_bytes_per_slot)
         self._ragged_grid_cells = adapter.ragged_grid_cells
+        self._ragged_sel_tokens = adapter.ragged_sel_tokens
         self._state_resets = 0
         self._counters_exported: Dict[str, Any] = {}
         if self._state_bytes_per_slot:
@@ -3054,6 +3104,9 @@ class LLMEngine:
             "n_state_reset": sum(1 for r in rows if r["start"] == 0),
             "scan_len": max(len(r["tokens"] or (0,)) for r in rows),
         }
+        if self._ragged_sel_tokens:
+            counts["sel_tokens"] = self._ragged_sel_tokens(row_start,
+                                                           row_len)
         if not self._paged_kv:      # no page, so no cell of any
             counts.update(live_cells=0, grid_cells=0, append_cells=0,
                           ctx_tokens=0)

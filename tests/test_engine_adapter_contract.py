@@ -10,13 +10,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import brumby, jamba, llama, xing
+from ray_tpu.models import brumby, glm5, jamba, llama, xing
 from ray_tpu.ops import segmented_lora
 from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
 from ray_tpu.serve.llm_engine import (
     EngineConfig,
     LLMEngine,
     brumby_paged_adapter,
+    glm5_paged_adapter,
     jamba_paged_adapter,
     llama_paged_adapter,
     ragged_step_shapes,
@@ -41,6 +42,12 @@ XING = xing.XingConfig(
     first_dense=2, q_rank=16, kv_rank=8, nope_dim=16, rope_dim=8, v_dim=16,
     n_experts=8, top_k=2, moe_dim=32, dtype=jnp.float32,
     param_dtype=jnp.float32)
+GLM5 = glm5.Glm5Config(
+    vocab_size=97, dim=64, n_layers=3, n_heads=4, n_kv_heads=4, mlp_dim=96,
+    first_dense=1, q_rank=16, kv_rank=8, nope_dim=16, rope_dim=8, v_dim=16,
+    n_routed=8, n_experts=4, expert_first=4, top_k=2, moe_dim=32,
+    index_heads=4, index_dim=16, index_topk=4, dtype=jnp.float32,
+    param_dtype=jnp.float32)
 PAGE, SLOTS, MAXP, BUDGET = 8, 4, 4, 24
 TABLE = np.arange(SLOTS * MAXP, dtype=np.int32).reshape(SLOTS, MAXP)
 ROWS = [{"slot": 2, "start": 0, "tokens": [5, 9, 2, 7, 1, 3]},
@@ -55,6 +62,7 @@ CASES = {
     "jamba": (jamba_paged_adapter, JAMBA, jamba.init_params, set()),
     "brumby": (brumby_paged_adapter, BRUMBY, brumby.init_params, set()),
     "xing": (xing_paged_adapter, XING, xing.init_params, set()),
+    "glm5": (glm5_paged_adapter, GLM5, glm5.init_params, set()),
 }
 
 

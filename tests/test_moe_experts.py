@@ -76,3 +76,71 @@ def test_tile_plan_pads_each_group_to_whole_tiles():
     # every sorted pair has exactly one padded row
     live = src[src < 40]
     np.testing.assert_array_equal(np.sort(live), np.arange(37))
+
+
+# ------------------------------------------------------- a share of a layer
+
+RANKS = 4       # the layer's 8 experts over four chips, two each
+
+
+def _whole_layer_reference(u, choice, weight, experts, shared):
+    """The uncut layer: every expert of the layer on every token it
+    chose, plus the shared expert."""
+    return (moe.routed_experts_reference(u, choice, weight, experts)
+            + _shared(u, shared))
+
+
+def _shared(u, shared):
+    g, up, down = shared
+    return (jax.nn.silu(u @ g) * (u @ up)) @ down
+
+
+@pytest.mark.parametrize("padding", [5, 0])
+@pytest.mark.parametrize("kind", ["spread", "one_expert", "half_idle"])
+def test_the_shares_of_all_ranks_add_up_to_the_whole_layer(padding, kind):
+    """The share test: each rank holds ``E / RANKS`` experts, routes over
+    all ``E`` and computes its own experts' part; the parts of all ranks
+    plus the shared expert counted ONCE equal the uncut reference's whole
+    layer, and every pair is served by exactly one rank."""
+    u = jax.random.normal(jax.random.key(7), (T, D))
+    choice, weight = _routing(kind)
+    live = T - padding
+    valid = jnp.arange(T) < live
+    experts = _experts()
+    ks = jax.random.split(jax.random.key(11), 3)
+    shared = (jax.random.normal(ks[0], (D, F)) * D ** -0.5,
+              jax.random.normal(ks[1], (D, F)) * D ** -0.5,
+              jax.random.normal(ks[2], (F, D)) * F ** -0.5)
+    held = E // RANKS
+    total, served = jnp.zeros((T, D)), 0
+    for rank in range(RANKS):
+        mine = {k: v[rank * held:(rank + 1) * held]
+                for k, v in experts.items()}
+        y, sizes = moe.routed_experts(u, choice, weight, mine, valid,
+                                      first=rank * held)
+        want = moe.routed_experts_reference(u, choice, weight, mine, valid,
+                                            first=rank * held)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        assert sizes.shape == (held,)
+        np.testing.assert_array_equal(
+            np.asarray(sizes),
+            np.bincount(np.asarray(choice[:live]).reshape(-1),
+                        minlength=E)[rank * held:(rank + 1) * held])
+        total, served = total + y, served + int(jnp.sum(sizes))
+    assert served == live * K
+    whole = _whole_layer_reference(u, choice, weight, experts, shared)
+    got = total + jnp.where(valid[:, None], _shared(u, shared), 0.0)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(jnp.where(valid[:, None], whole, 0.0)),
+        rtol=5e-5, atol=5e-5)
+
+
+def test_a_rank_whose_experts_nobody_chose_does_no_work():
+    u = jax.random.normal(jax.random.key(7), (T, D))
+    choice, weight = _routing("half_idle")      # experts 0 to 3 only
+    experts = {k: v[6:] for k, v in _experts().items()}
+    y, sizes = moe.routed_experts(u, choice, weight, experts,
+                                  jnp.ones((T,), bool), first=6)
+    np.testing.assert_array_equal(np.asarray(y), 0.0)
+    np.testing.assert_array_equal(np.asarray(sizes), 0)

@@ -856,3 +856,56 @@ def test_xing_cell_step_copies_neither_pool_nor_experts(v5e, shape):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2**30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
+# -- the GLM-5 cell: sparse latent attention, two pools, a share of experts --
+
+@pytest.mark.parametrize("shape", ["budget", "small"])
+def test_glm5_cell_step_copies_neither_pool_nor_experts(v5e, shape):
+    """The step program of ``glm5_ep16-doc_32k`` at its five layers and
+    published widths, in both shapes the engine compiles (520 positions
+    and 8): the masked walk of ``ragged_latent_attention`` (whole-step
+    window, heads in groups of 8, a selection block a cell), the indexer
+    and the bisection, the gathered list of the one-token rows and the
+    append of both pools compile for a v5e, fit the chip with the weights
+    (7.28 GiB) and pools (1.92 GiB), and copy neither pool nor a routed
+    layer's sixteen experts."""
+    from benchmarks.runners.serve_glm5 import model_config
+    from ray_tpu.models import glm5
+
+    config = json.loads(
+        (REPO / "benchmarks" / "configs" / "glm5_ep16.json").read_text())
+    cfg, eng = model_config(config), config["engine"]
+    slots, page = eng["max_slots"], eng["page_size"]
+    maxp = eng["max_seq_len"] // page
+    shapes = _step_shapes(slots + eng["prefill_chunk"], slots)
+    assert (cfg.n_layers, cfg.first_dense, cfg.dim, cfg.n_experts,
+            cfg.n_routed, maxp, shapes) == (
+        5, 1, 6144, 16, 256, 524, {"budget": 520, "small": 8})
+    T = shapes[shape]
+    mesh = _one(v5e)
+    params = _on(mesh, jax.eval_shape(
+        lambda: glm5.init_params(jax.random.key(0), cfg)))
+    cache = _on(mesh, jax.eval_shape(
+        lambda: glm5.init_cache(cfg, slots * maxp, page)))
+    assert set(cache) == {"kv_c", "kv_i", "moe_tokens", "moe_distinct"}
+    toks, rows, bt = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, maxp, dtype=jnp.int32)))
+    compiled = _compile(
+        lambda p, t, pos, rs, r0, rl, ro, b, c:
+        glm5.ragged_step(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+        params, toks, toks, rows, rows, rows, rows, bt, cache,
+        donate_argnums=(8,))
+    text = compiled.as_text()
+    for kernel in ("ragged_latent_attention", "ragged_latent_append",
+                   "moe_grouped_ffn"):
+        assert kernel in text
+    for big in ("bf16[5,1,4193,64,640]", "bf16[5,1,4193,64,128]",
+                "bf16[16,6144,2048]", "bf16[16,2048,6144]"):
+        assert [ln for ln in text.splitlines()
+                if re.search(r"= \S*" + re.escape(big) + r"\S* copy\(", ln)
+                ] == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5 * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
