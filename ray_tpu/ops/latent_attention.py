@@ -12,7 +12,7 @@ lanes are also the values:
 
 so the pool is ONE operand ``[L, 1, P, page, rank + rope]`` that a cell
 reads once, and the heads are stacked into the ROWS of one matmul against
-it (``[HG * Cq, 576] x [576, page]``), as ``ragged_paged_attention``
+it (``[HG * Cq, 576] x [576, G * page]``), as ``ragged_paged_attention``
 stacks the heads of one KV head.  The batch is the engine's ragged
 one (see ``ops/ragged_paged_attention``): rows of (slot, start, len,
 offset) over a flat token buffer, each row's past in the pool under its
@@ -21,15 +21,27 @@ here and ``ragged_latent_append`` writes the fresh rows afterwards, all
 layers at once, in place.
 
 The grid walks a LIST of the cells that hold work
-(``live_latent_cells``: head group, row, page or self), under a dynamic
-bound, so a page no row reaches is no grid step.  A step makes two
-calls: rows of ONE token through a window of that token alone, all heads
-in one group (32 stacked rows a cell: a decode row through a window of
-32 tokens cost 5.6 us a cell and 17.8 ms a step, PERF.md PR 34), the
-others through the step's whole window with the heads in groups of
-``CHUNK_HEADS`` (outermost in the list, so that a group's output block
-stays where it is; the chunk's flash state then fits the chip's VMEM).
-A call whose list is empty has no grid step.
+(``live_latent_cells``: head group, row, pool cell or self), under a
+dynamic bound, so a page no row reaches is no grid step.  A POOL CELL
+spans ``G = cell_pages(page, maxp)`` consecutive pages of the row's block
+table (``CELL_KEYS`` keys: four pages of 64): the pool is passed ``G``
+times, a page an operand, the pages are joined into one ``[G * page, W]``
+key block, and a cell makes ONE ``[rows, G * page]`` score tile and ONE
+online-softmax update of the flash state.  A page of a cell past the
+row's last page repeats that page (no DMA, and no page another sequence
+owns is read) and lies past ``start``, so position masks it.  What a
+cell pays whatever it holds (the state read, scaled and written back;
+a row maximum and a row sum across lanes for every stacked row; the grid
+step) is paid once for 256 keys, and both products fill the 128-wide
+MXU.  A step makes two calls: rows of ONE token through a window of that
+token alone, all heads in one group (32 stacked rows a cell: a decode
+row through a window of 32 tokens cost 5.6 us a cell and 17.8 ms a step,
+PERF.md PR 34), the others through the step's whole window with the
+heads in groups of ``CHUNK_HEADS`` (outermost in the list, so that a
+group's output block stays where it is; the chunk's flash state then
+fits the chip's VMEM).  One geometry serves all three calls (``G``
+follows from the page size and the table's width alone).  A call whose
+list is empty has no grid step.
 
 SPARSE attention (a learned indexer selects, for every query, the cached
 positions it may attend to: ``ops/dsa_index``) is
@@ -43,7 +55,8 @@ between them, and the same function is computed; what it costs past the
 selected share is the arithmetic on the masked scores (reading a chunk's
 union of selections once is ROADMAP Queue 2's).  In that call the heads
 of a group are stacked head-major (stacked row ``h * T + t``), so that a
-``[T, page]`` mask tiles over them, and its window is the whole step.
+cell's ``[T, G * page]`` block of the selection tiles over them, and its
+window is the whole step.
 The Pallas interpreter takes no dynamic bound: there the grid keeps the
 list's capacity and the steps past its end do nothing, the same body.
 """
@@ -78,7 +91,24 @@ CHUNK_HEADS = 16
 # state and the self cell's [rows, 520] scores are 60 MB of VMEM; 16 heads
 # would not fit)
 SPARSE_CHUNK_HEADS = 8
+# keys a pool cell spans: CELL_KEYS // page consecutive pages of the row's
+# block table, one score tile and one update of the flash state a cell.
+# On a v5e at pages of 64 (PERF.md PR 40), by pages a cell 1 / 2 / 4 / 8:
+# the masked chunk call (4160 stacked rows) 18.1 / 11.7 / 5.5 / 6.2 us a
+# pooled page (a cell of one, two or four pages costs the same 18 to 23
+# us: the state's rescale and 4160 row maxima and sums, not the keys; at
+# eight the [4160, 512] tiles cost more than they save); the unmasked
+# chunk call (4608 rows, a 256-token chunk beside nine rows, seven layers)
+# 7.9 / 6.2 / 4.8 / 24.0 ms; the one-token call (32 rows, 164 pages, seven
+# layers) 1.65 / 1.15 / 0.86 / 0.79 ms.  Four pages serve all three.
+CELL_KEYS = 256
 VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def cell_pages(page: int, maxp: int) -> int:
+    """G, the pages a pool cell of the walk spans: what ``CELL_KEYS``
+    holds of them, and no more than a block table has."""
+    return max(1, min(CELL_KEYS // page, maxp))
 
 
 # --------------------------------------------------------------------------
@@ -151,14 +181,20 @@ def ragged_latent_append_reference(pool, new, row_slot, row_start, row_len,
 def live_latent_cells(row_start: jax.Array, row_len: jax.Array,
                       takes: jax.Array, groups: int, maxp: int,
                       page: int) -> Tuple[jax.Array, jax.Array]:
-    """``(live_ci, n_live)`` of one call: cell ``(g * R + r) * (maxp + 1)
-    + pc`` is head group ``g``, row ``r``, pool page ``pc`` or the row's
-    self cell where ``pc == maxp``.  Live where the call takes the row
-    (``takes`` [R]) and, for a pool page, where it holds pooled tokens of
-    the row.  Ascending order puts a group's rows together and a row's
-    pages before its self cell, which finalises the row."""
-    pc = jnp.arange(maxp + 1, dtype=jnp.int32)
-    live = takes[:, None] & ((pc == maxp) | (pc * page < row_start[:, None]))
+    """``(live_ci, n_live)`` of one call: cell ``(g * R + r) * (NC + 1)
+    + pc`` is head group ``g``, row ``r``, the pool cell of pages
+    ``pc * G`` to ``pc * G + G - 1`` of the row's block table (``G =
+    cell_pages(page, maxp)``, ``NC = ceil(maxp / G)``) or the row's self
+    cell where ``pc == NC``.  Live where the call takes the row
+    (``takes`` [R]) and, for a pool cell, where its first page holds
+    pooled tokens of the row.  Ascending order puts a group's rows
+    together and a row's pool cells before its self cell, which
+    finalises the row."""
+    G = cell_pages(page, maxp)
+    nc = -(-maxp // G)
+    pc = jnp.arange(nc + 1, dtype=jnp.int32)
+    live = takes[:, None] & (
+        (pc == nc) | (pc * (G * page) < row_start[:, None]))
     return _listed(jnp.broadcast_to(live[None], (groups,) + live.shape))
 
 
@@ -174,24 +210,35 @@ def _takes(row_len, which: str):
     return {"one": row_len == 1, "more": row_len > 1}[which]
 
 
-def latent_cell_count(row_start, row_len, page: int, H: int) -> int:
-    """The cells ``ragged_latent_attention`` walks for a step's packed
-    rows, on the host: each live row's pooled pages plus its self cell,
-    once for each head group of the call that takes it."""
-    start, nlen = np.asarray(row_start), np.asarray(row_len)
-    cells = -(-start // page) + 1
+def _pages_walked(row_start, page: int, maxp: int):
+    """The PAGES a row's cells span, on the host: ``G`` a pool cell (the
+    last one's tail past the row's pages included), one for the self
+    cell."""
+    G = cell_pages(page, maxp)
+    return -(-np.asarray(row_start) // (G * page)) * G + 1
+
+
+def latent_cell_count(row_start, row_len, page: int, H: int,
+                      maxp: int) -> int:
+    """The pages ``ragged_latent_attention``'s cells span for a step's
+    packed rows, on the host: each live row's pool cells (``G`` pages
+    each) plus its self cell, once for each head group of the call that
+    takes it."""
+    nlen = np.asarray(row_len)
     groups = np.where(nlen > 1, H // min(H, CHUNK_HEADS), 1)
-    return int(np.sum((nlen > 0) * groups * cells))
+    return int(np.sum((nlen > 0) * groups
+                      * _pages_walked(row_start, page, maxp)))
 
 
-def sparse_cell_count(row_start, row_len, page: int, H: int) -> int:
-    """The cells ``ragged_sparse_latent_attention``'s masked walk takes
-    for a step's packed rows, on the host: the pooled pages and the self
+def sparse_cell_count(row_start, row_len, page: int, H: int,
+                      maxp: int) -> int:
+    """The pages ``ragged_sparse_latent_attention``'s masked walk spans
+    for a step's packed rows, on the host: the pool cells and the self
     cell of each row of more than one token, once a head group; a row of
     one token walks none (its rows are gathered)."""
-    start, nlen = np.asarray(row_start), np.asarray(row_len)
     groups = H // min(H, SPARSE_CHUNK_HEADS)
-    return int(np.sum((nlen > 1) * groups * (-(-start // page) + 1)))
+    return int(np.sum((np.asarray(row_len) > 1) * groups
+                      * _pages_walked(row_start, page, maxp)))
 
 
 # --------------------------------------------------------------------------
@@ -199,17 +246,19 @@ def sparse_cell_count(row_start, row_len, page: int, H: int) -> int:
 # --------------------------------------------------------------------------
 
 def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
-                   q_ref, new_ref, pool_ref, *rest, T: int, Cq: int, HG: int,
-                   R: int, maxp: int, page: int, rank: int, scale: float,
+                   q_ref, new_ref, *rest, T: int, Cq: int, HG: int, R: int,
+                   G: int, NC: int, page: int, rank: int, scale: float,
                    masked: bool = False):
     del slot_r, bt_r, ly_r      # the index maps' own
-    if masked:      # the selection: this page's columns, the step's own
+    pool_refs, rest = rest[:G], rest[G:]
+    if masked:      # the selection: this cell's columns, the step's own
         selp_ref, sels_ref, out_ref, m_s, l_s, acc_s = rest
     else:
         out_ref, m_s, l_s, acc_s = rest
     i = pl.program_id(0)
     rows = Cq * HG
     Ck = max(Cq, 8)             # the self cell's keys: a sublane tile
+    Kp = G * page               # a pool cell's keys
     shift = HG.bit_length() - 1
 
     # i < n_live always holds under Mosaic, whose grid ends at n_live;
@@ -217,8 +266,8 @@ def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
     @pl.when(i < nl_r[0])
     def _cell():
         ci = live_r[i]
-        pc = ci % (maxp + 1)
-        r = (ci // (maxp + 1)) % R
+        pc = ci % (NC + 1)
+        r = (ci // (NC + 1)) % R
         start, nt, off = start_r[r], len_r[r], off_r[r]
         # the keys' window starts on a sublane tile; a query window of
         # one token is that token (its HG stacked rows are aligned)
@@ -245,58 +294,50 @@ def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
 
         qs = q_ref[0, pl.ds(wr, rows), :]
 
-        def scores(keys):
-            return lax.dot_general(
-                qs, keys, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-
         def selected(ref_value):
             return jnp.concatenate([ref_value] * HG, axis=0) > 0
 
-        def flash_update(s, keys, keep=None):
-            """Masked online-softmax update; rows of the window that are
-            not this row's tokens keep their state.  Under a selection a
-            query may find no key in a cell before it has found any
-            (its maximum is then still NEG_INF, and exp(s - m) of a
-            masked score would read 1): ``keep`` zeroes those."""
+        def flash_update(keys, keep):
+            """One online-softmax update over a cell's keys, the scores
+            kept where ``keep`` [rows, keys] says.  A stacked row that
+            keeps nothing (not this row's token, or a query whose
+            selection names no key of the cell: its maximum may still be
+            NEG_INF, and exp(s - m) of a masked score would read 1)
+            leaves its state as it was: its ``p`` is zeroed."""
+            s = lax.dot_general(
+                qs, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
             m_prev = m_s[...]
-            m_new = jnp.where(
-                valid_q, jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True)),
-                m_prev)
-            p = jnp.exp(s - m_new)
-            if keep is not None:
-                p = jnp.where(keep, p, 0.0)
+            m_new = jnp.maximum(m_prev, jnp.max(
+                jnp.where(keep, s, NEG_INF), -1, keepdims=True))
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
             corr = jnp.exp(m_prev - m_new)
             l_new = corr * l_s[...] + jnp.sum(p, -1, keepdims=True)
-            pv = lax.dot_general(
+            a_new = acc_s[...] * corr + lax.dot_general(
                 p.astype(keys.dtype), keys[:, :rank],
                 (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-            a_new = acc_s[...] * corr + pv
-            l_s[...] = jnp.where(valid_q, l_new, l_s[...])
-            acc_s[...] = jnp.where(valid_q, a_new, acc_s[...])
-            m_s[...] = m_new
+            m_s[...], l_s[...], acc_s[...] = m_new, l_new, a_new
             return l_new, a_new
 
-        @pl.when(pc < maxp)
+        @pl.when(pc < NC)
         def _pool_cell():
-            keys = pool_ref[0, 0, 0]
-            kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
+            # the cell's G pages as one key block; a page past the row's
+            # last repeats it, and lies past ``start``
+            keys = jnp.concatenate([ref[0, 0, 0] for ref in pool_refs], 0)
+            kpos = pc * Kp + lax.broadcasted_iota(jnp.int32, (1, Kp), 1)
             keep = valid_q & (kpos < start)
             if masked:
                 keep = keep & selected(selp_ref[0])
-            flash_update(jnp.where(keep, scores(keys), NEG_INF), keys,
-                         keep if masked else None)
+            flash_update(keys, keep)
 
-        @pl.when(pc == maxp)
+        @pl.when(pc == NC)
         def _self_cell():
             keys = new_ref[pl.ds(wk, Ck), :]
             krel = wk + lax.broadcasted_iota(jnp.int32, (1, Ck), 1) - off
-            mask = valid_q & (krel >= 0) & (krel < nt) & (krel <= trel)
+            keep = valid_q & (krel >= 0) & (krel < nt) & (krel <= trel)
             if masked:
-                mask = mask & selected(sels_ref[...])
-            l_new, a_new = flash_update(
-                jnp.where(mask, scores(keys), NEG_INF), keys,
-                mask if masked else None)
+                keep = keep & selected(sels_ref[...])
+            l_new, a_new = flash_update(keys, keep)
             o = a_new / jnp.maximum(l_new, 1e-30)
             cur = out_ref[0, pl.ds(wr, rows), :]
             out_ref[0, pl.ds(wr, rows), :] = jnp.where(valid_q, o, cur)
@@ -305,15 +346,19 @@ def _latent_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
 def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
                  Cq: int, HG: int, scale: float, rank: int, sel=None):
     """One call: the rows ``takes`` marks, through a window of ``Cq``
-    tokens, the heads in groups of ``HG``.  Returns [T, H, rank] float32,
-    defined at the tokens of the rows taken and nowhere else.  ``sel``
-    (``(pool [T, maxp * page], self [T, T])`` bool) keeps a query to the
-    keys it marks; the window is then the step."""
+    tokens, the heads in groups of ``HG``, a pool cell ``G`` pages of the
+    row's block table (the pool is passed ``G`` times, a page each).
+    Returns [T, H, rank] float32, defined at the tokens of the rows taken
+    and nowhere else.  ``sel`` (``(pool [T, maxp * page], self [T, T])``
+    bool) keeps a query to the keys it marks; the window is then the
+    step."""
     T, H, W = q.shape
     L, _, Pt, page, _ = pool.shape
     row_slot, row_start, row_len, row_off = rows
     R = row_slot.shape[0]
     maxp = block_tables.shape[1]
+    G = cell_pages(page, maxp)
+    NC = -(-maxp // G)
     NG = H // HG
     assert NG * HG == H and HG & (HG - 1) == 0, (H, HG)
     masked = sel is not None
@@ -322,8 +367,10 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
         # [NG, HG * T, W]: stacked row h * T + t of group g
         q2 = q.reshape(T, NG, HG, W).transpose(1, 2, 0, 3).reshape(
             NG, HG * T, W)
-        sel_in = [sel[0].reshape(T, maxp, page).transpose(1, 0, 2).astype(
-            jnp.float32), sel[1].astype(jnp.float32)]
+        # [NC, T, G * page]: a cell's columns, padded past the table
+        sel_pool = jnp.pad(sel[0], ((0, 0), (0, (NC * G - maxp) * page)))
+        sel_in = [sel_pool.reshape(T, NC, G * page).transpose(
+            1, 0, 2).astype(jnp.float32), sel[1].astype(jnp.float32)]
     else:
         # [NG, T * HG, W]: stacked row t * HG + h of group g
         q2 = q.reshape(T, NG, HG, W).transpose(1, 0, 2, 3).reshape(
@@ -340,21 +387,26 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
         return live[jnp.minimum(i, jnp.maximum(nl[0] - 1, 0))]
 
     def group_map(i, _s, _st, _ln, _of, _bt, _ly, live, nl):
-        return (cell(i, live, nl) // ((maxp + 1) * R), 0, 0)
+        return (cell(i, live, nl) // ((NC + 1) * R), 0, 0)
 
-    def pool_map(i, slot_p, start_p, _ln, _of, bt, ly, live, nl):
-        ci = cell(i, live, nl)
-        r = (ci // (maxp + 1)) % R
-        # the self cell repeats the row's last page: no DMA for it
-        last = jnp.maximum(start_p[r] - 1, 0) // page
-        pe = jnp.minimum(jnp.minimum(ci % (maxp + 1), maxp - 1), last)
-        return (ly[0], 0, jnp.minimum(bt[slot_p[r], pe], Pt - 1), 0, 0)
+    def pool_map(j):
+        def page_j(i, slot_p, start_p, _ln, _of, bt, ly, live, nl):
+            ci = cell(i, live, nl)
+            r = (ci // (NC + 1)) % R
+            # the self cell repeats the row's last pool cell, and a page
+            # past the row's last that page: no DMA for either
+            last = jnp.minimum(jnp.maximum(start_p[r] - 1, 0) // page,
+                               maxp - 1)
+            pe = jnp.minimum(
+                jnp.minimum(ci % (NC + 1), last // G) * G + j, last)
+            return (ly[0], 0, jnp.minimum(bt[slot_p[r], pe], Pt - 1), 0, 0)
+        return page_j
 
     def selp_map(i, *pf):
         live, nl = pf[-2:]
-        return (jnp.minimum(cell(i, live, nl) % (maxp + 1), maxp - 1), 0, 0)
+        return (jnp.minimum(cell(i, live, nl) % (NC + 1), NC - 1), 0, 0)
 
-    sel_specs = [pl.BlockSpec((1, T, page), selp_map),
+    sel_specs = [pl.BlockSpec((1, T, G * page), selp_map),
                  pl.BlockSpec((T, T), lambda i, *pf: (0, 0))] if masked else []
     interpret = platform.interpret_mode()
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -363,8 +415,8 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
         in_specs=[
             pl.BlockSpec((1, T * HG, W), group_map),
             pl.BlockSpec((T, W), lambda i, *pf: (0, 0)),
-            pl.BlockSpec((1, 1, 1, page, W), pool_map),
-        ] + sel_specs,
+        ] + [pl.BlockSpec((1, 1, 1, page, W), pool_map(j))
+             for j in range(G)] + sel_specs,
         out_specs=pl.BlockSpec((1, T * HG, rank), group_map),
         scratch_shapes=[
             pltpu.VMEM((Cq * HG, 1), jnp.float32),
@@ -373,7 +425,7 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
         ],
     )
     kern = functools.partial(
-        _latent_kernel, T=T, Cq=Cq, HG=HG, R=R, maxp=maxp, page=page,
+        _latent_kernel, T=T, Cq=Cq, HG=HG, R=R, G=G, NC=NC, page=page,
         rank=rank, scale=scale, masked=masked)
     out = pl.pallas_call(
         kern,
@@ -382,7 +434,7 @@ def _latent_call(q, new, pool, layer, rows, block_tables, takes, *,
         out_shape=jax.ShapeDtypeStruct((NG, T * HG, rank), jnp.float32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(*prefetch, q2, new, pool, *sel_in)
+    )(*prefetch, q2, new, *[pool] * G, *sel_in)
     if masked:
         return out.reshape(NG, HG, T, rank).transpose(2, 0, 1, 3).reshape(
             T, H, rank)

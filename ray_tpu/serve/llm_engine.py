@@ -531,7 +531,11 @@ class PagedEngineAdapter:
     # the attention cells the ragged step's kernel walks for one step's
     # packed row arrays ([R] each; ``lora`` says the step carries
     # adapters).  None = the page table's capacity, R * (maxp + 1),
-    # whatever the rows hold.  ``llm.pack`` reports it as grid_cells.
+    # whatever the rows hold.  ``llm.pack`` reports it as grid_cells.  It
+    # counts PAGES: a kernel whose cell spans several pages of a row (the
+    # latent walk's) counts every page the cell's tile spans, the tail
+    # past the row's last page included, so that live_cells over it says
+    # how full the cells are.
     ragged_grid_cells: Optional[Callable[..., int]] = None
     # ragged_sel_tokens(row_start, row_len) -> int: the cached positions
     # a SPARSE attention selects for one step's packed rows, summed over
@@ -727,7 +731,7 @@ def xing_paged_adapter(cfg) -> PagedEngineAdapter:
             xing.ragged_step(params, tokens, tok_pos, row_slot, row_start,
                              row_len, row_off, bt, cfg, cache),
         ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
-            latent_cell_count(row_start, row_len, page, cfg.n_heads),
+            latent_cell_count(row_start, row_len, page, cfg.n_heads, maxp),
         counter_leaves=("moe_tokens", "moe_distinct"),
     )
 
@@ -768,7 +772,7 @@ def glm5_paged_adapter(cfg) -> PagedEngineAdapter:
             glm5.ragged_step(params, tokens, tok_pos, row_slot, row_start,
                              row_len, row_off, bt, cfg, cache),
         ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
-            sparse_cell_count(row_start, row_len, page, cfg.n_heads),
+            sparse_cell_count(row_start, row_len, page, cfg.n_heads, maxp),
         ragged_sel_tokens=lambda row_start, row_len:
             sel_token_count(row_start, row_len, cfg.index_topk),
         counter_leaves=("moe_tokens", "moe_distinct"),

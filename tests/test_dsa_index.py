@@ -3,6 +3,8 @@ scores, causality, the top-k selection and the attention over what was
 selected, against plain ``jnp``; ragged rows, rotated block tables, and
 padding rows that leave both pools bit-equal."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,15 +20,18 @@ C = MAXP * PAGE
 P = SLOTS * MAXP
 
 
-def _batch(seed, rows):
-    """rows: [(slot, start, len)] -> packed arrays and random operands."""
+def _batch(seed, rows, roll=0):
+    """rows: [(slot, start, len)] -> packed arrays and random operands;
+    ``roll`` hands every slot the next one's pages (a slot that a new
+    sequence took over)."""
     rng = np.random.default_rng(seed)
     row = np.zeros((4, R), np.int32)
     off = 0
     for i, (slot, start, n) in enumerate(rows):
         row[:, i] = slot, start, n, off
         off += n
-    table = rng.permutation(P).astype(np.int32).reshape(SLOTS, MAXP)
+    table = np.roll(rng.permutation(P).astype(np.int32).reshape(
+        SLOTS, MAXP), roll, axis=0)
     k = jax.random.split(jax.random.key(seed), 8)
     ops = {
         "qI": jax.random.normal(k[0], (T, J, D)),
@@ -58,12 +63,46 @@ def _position_space(rows, sel, more):
     return out
 
 
+# the last three for the masked walk's cells of several pages: pasts that
+# end inside a cell of four pages (in its second page, and one page into
+# the table's last, short cell), on its edge, and under one page
 ROWS = {
     "chunk_and_decodes": [(2, 19, 9), (0, 33, 1), (3, 7, 1), (1, 0, 5)],
     "decodes_only": [(0, 40, 1), (1, 3, 1), (2, 17, 1), (3, 29, 1)],
     "two_chunks": [(1, 24, 10), (3, 16, 12)],
     "first_chunk": [(0, 0, 20)],
+    "ends_inside": [(1, 11, 6), (3, 41, 7), (0, 39, 1)],
+    "on_the_edge": [(0, 32, 8), (2, 16, 5), (1, 32, 1)],
+    "under_a_page": [(3, 5, 4), (0, 3, 9), (2, 7, 1)],
 }
+# keys a pool cell of the walk spans: one page, two, four (the table's
+# six columns are no multiple of it), the module's own (the whole table)
+CELL_KEYS = [PAGE, 2 * PAGE, 4 * PAGE, la.CELL_KEYS]
+
+
+# The steps below are traced as the engine traces its step, so that the
+# cases of one shape share a compile (an eager call compiles the
+# interpreted kernel anew, a second or more each).
+
+@functools.partial(jax.jit, static_argnames=("topk",))
+def _select(ops, layer, rs, r0, rl, ro, table, *, topk):
+    scores = dsa.index_scores(ops["qI"], ops["wI"], ops["newI"],
+                              ops["pool_i"], layer, rs, r0, rl, ro, table)
+    return scores, dsa.select(scores, topk)
+
+
+@functools.partial(jax.jit, static_argnames=("cell_keys", "dense"))
+def _attend(ops, layer, rs, r0, rl, ro, table, sel=None, *, cell_keys,
+            dense=False):
+    """The sparse attention under ``sel`` (the dense walk where ``dense``
+    says so), a pool cell ``cell_keys`` keys while it is traced."""
+    args = (ops["q"], ops["new"], ops["pool"], layer, rs, r0, rl, ro, table)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(la, "CELL_KEYS", cell_keys)
+        if dense:
+            return la.ragged_latent_attention(*args, scale=0.3, rank=RANK)
+        return la.ragged_sparse_latent_attention(*args, sel, scale=0.3,
+                                                 rank=RANK)
 
 
 @pytest.mark.parametrize("topk", [6, 64])
@@ -71,9 +110,7 @@ ROWS = {
 def test_scores_and_selection_equal_the_dense_twin(kind, topk):
     rows = ROWS[kind]
     (rs, r0, rl, ro), table, ops = _batch(1, rows)
-    scores = dsa.index_scores(ops["qI"], ops["wI"], ops["newI"],
-                              ops["pool_i"], 1, rs, r0, rl, ro, table)
-    sel = dsa.select(scores, topk)
+    scores, sel = _select(ops, 1, rs, r0, rl, ro, table, topk=topk)
     want_s, want_m = dsa.index_select_reference(
         ops["qI"], ops["wI"], ops["newI"], ops["pool_i"][1, 0], rs, r0, rl,
         ro, table, topk)
@@ -184,43 +221,38 @@ def _sparse_reference(ops, rows, table, mask, layer, scale):
     return out
 
 
-@pytest.mark.parametrize("topk", [6, 64])
+@pytest.mark.parametrize("topk,cell_keys", [
+    (6, 4 * PAGE), (64, 4 * PAGE), (6, 2 * PAGE)])
 @pytest.mark.parametrize("kind", sorted(ROWS))
-def test_sparse_attention_equals_plain_jnp(kind, topk):
+def test_sparse_attention_equals_plain_jnp(kind, topk, cell_keys):
     """The gathered list (rows of one token) and the masked walk (rows
-    of more) over the positions the selection names, under a permuted
-    block table."""
+    of more, cells of two and of four pages) over the positions the
+    selection names, under a permuted block table."""
     rows = ROWS[kind]
     (rs, r0, rl, ro), table, ops = _batch(3, rows)
-    scores = dsa.index_scores(ops["qI"], ops["wI"], ops["newI"],
-                              ops["pool_i"], 1, rs, r0, rl, ro, table)
-    sel = dsa.select(scores, topk)
-    got = la.ragged_sparse_latent_attention(
-        ops["q"], ops["new"], ops["pool"], 1, rs, r0, rl, ro, table, sel,
-        scale=0.3, rank=RANK)
+    scores, sel = _select(ops, 1, rs, r0, rl, ro, table, topk=topk)
+    got = _attend(ops, 1, rs, r0, rl, ro, table, sel, cell_keys=cell_keys)
     mask = _position_space(rows, sel, np.asarray(scores.more))
     want = _sparse_reference(ops, rows, table, mask, 1, 0.3)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
     if topk >= C + T:       # nothing cut: the dense kernel's function
-        dense = la.ragged_latent_attention(
-            ops["q"], ops["new"], ops["pool"], 1, rs, r0, rl, ro, table,
-            scale=0.3, rank=RANK)
+        dense = _attend(ops, 1, rs, r0, rl, ro, table, cell_keys=cell_keys,
+                        dense=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
                                    rtol=2e-4, atol=2e-5)
 
 
-def test_everything_selected_is_the_dense_kernel():
+@pytest.mark.parametrize("cell_keys,roll", [
+    (keys, 0) for keys in CELL_KEYS] + [(4 * PAGE, 1), (la.CELL_KEYS, 1)])
+def test_everything_selected_is_the_dense_kernel(cell_keys, roll):
+    """Whatever the cells span, and under the table a slot's next
+    sequence brings (``roll``)."""
     rows = ROWS["chunk_and_decodes"]
-    (rs, r0, rl, ro), table, ops = _batch(4, rows)
-    scores = dsa.index_scores(ops["qI"], ops["wI"], ops["newI"],
-                              ops["pool_i"], 0, rs, r0, rl, ro, table)
-    sel = dsa.select(scores, C + T)
-    got = la.ragged_sparse_latent_attention(
-        ops["q"], ops["new"], ops["pool"], 0, rs, r0, rl, ro, table, sel,
-        scale=0.3, rank=RANK)
-    dense = la.ragged_latent_attention(
-        ops["q"], ops["new"], ops["pool"], 0, rs, r0, rl, ro, table,
-        scale=0.3, rank=RANK)
+    (rs, r0, rl, ro), table, ops = _batch(4, rows, roll)
+    _scores, sel = _select(ops, 0, rs, r0, rl, ro, table, topk=C + T)
+    got = _attend(ops, 0, rs, r0, rl, ro, table, sel, cell_keys=cell_keys)
+    dense = _attend(ops, 0, rs, r0, rl, ro, table, cell_keys=cell_keys,
+                    dense=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
                                rtol=2e-4, atol=2e-5)
 

@@ -759,7 +759,9 @@ def test_latent_attention_kernel(v5e, shape):
     """``ragged_latent_attention`` and the pool's append at the cell's
     sizes: 32 heads of 640 lanes over a pool of 2817 pages of 64 tokens
     and 7 layers (1.6 GB), in the two calls of a step that carries a
-    chunk (288 positions) and the one call of a decode step (32)."""
+    chunk (288 positions) and the one call of a decode step (32); a pool
+    cell spans four pages of the table's 88 columns (22 cells a row: the
+    pool is four operands, 256 keys a score tile)."""
     from ray_tpu.ops import latent_attention as la
 
     cfg, eng, T = _xing_cell()
@@ -768,6 +770,7 @@ def test_latent_attention_kernel(v5e, shape):
     T = _step_shapes(T, slots)[shape]
     assert (cfg.n_heads, cfg.pool_width, cfg.kv_rank, maxp) == (
         32, 640, 512, 88)
+    assert (la.cell_pages(page, maxp), la.CELL_KEYS) == (4, 256)
     mesh = _one(v5e)
     rows = _sds(slots, dtype=jnp.int32)
     pool = _sds(cfg.n_layers, 1, slots * maxp + 1, page, cfg.pool_width)
@@ -865,13 +868,16 @@ def test_glm5_cell_step_copies_neither_pool_nor_experts(v5e, shape):
     """The step program of ``glm5_ep16-doc_32k`` at its five layers and
     published widths, in both shapes the engine compiles (520 positions
     and 8): the masked walk of ``ragged_latent_attention`` (whole-step
-    window, heads in groups of 8, a selection block a cell), the indexer
+    window, heads in groups of 8: 4160 stacked rows; a pool cell four
+    pages of the table's 524 columns, 131 cells a row, with a
+    ``[520, 256]`` block of the selection a cell), the indexer
     and the bisection, the gathered list of the one-token rows and the
     append of both pools compile for a v5e, fit the chip with the weights
     (7.28 GiB) and pools (1.92 GiB), and copy neither pool nor a routed
     layer's sixteen experts."""
     from benchmarks.runners.serve_glm5 import model_config
     from ray_tpu.models import glm5
+    from ray_tpu.ops import latent_attention as la
 
     config = json.loads(
         (REPO / "benchmarks" / "configs" / "glm5_ep16.json").read_text())
@@ -879,6 +885,9 @@ def test_glm5_cell_step_copies_neither_pool_nor_experts(v5e, shape):
     slots, page = eng["max_slots"], eng["page_size"]
     maxp = eng["max_seq_len"] // page
     shapes = _step_shapes(slots + eng["prefill_chunk"], slots)
+    G = la.cell_pages(page, maxp)
+    assert (G, -(-maxp // G), shapes["budget"] * la.SPARSE_CHUNK_HEADS) == (
+        4, 131, 4160)
     assert (cfg.n_layers, cfg.first_dense, cfg.dim, cfg.n_experts,
             cfg.n_routed, maxp, shapes) == (
         5, 1, 6144, 16, 256, 524, {"budget": 520, "small": 8})
