@@ -586,6 +586,75 @@ def _kernels_fused(d: KernelDims) -> None:
                 ragged(fused), ragged(cfg), 5e-2)
 
 
+def _mixed_rows(d: KernelDims):
+    """Packed rows of both kinds over ``d.slots`` slots: two rows of one
+    token, a chunk of several pages deep in its sequence and one that
+    starts its sequence, with a padding row between them."""
+    import jax.numpy as jnp
+
+    long = (d.maxp - 2) * d.page - 20
+    ctx = (d.maxp - 1) * d.page - long
+    rows = [(0, ctx + 5, 1, 0), (2, ctx, long, 3), (0, 0, 0, 0),
+            (1, 9, 1, long + 4), (3, 0, 40, long + 8)]
+    return (tuple(jnp.asarray(x, jnp.int32) for x in zip(*rows)),
+            long + 48)
+
+
+def _kernels_lightning(d: KernelDims) -> None:
+    """``lightning_decode`` and ``lightning_chunk`` against their plain
+    forms: float32 throughout, so the limit is rounding of sums."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import lightning_attention as la
+
+    rows, T = _mixed_rows(d)
+    H, hd = d.heads, d.head_dim
+    ks = jax.random.split(jax.random.key(6), 4)
+    q = _rand(ks[0], (T, H, hd), jnp.bfloat16).astype(jnp.float32) \
+        * hd ** -0.5
+    k, v = (_rand(ks[i], (T, H, hd), jnp.bfloat16) for i in (1, 2))
+    lam = jnp.exp(-0.5 * jnp.exp2(-8.0 * (jnp.arange(H) + 1.0) / H))
+    s0 = _rand(ks[3], (d.layers, d.slots + 1, H, hd, hd), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        for kind in ("decode", "chunk"):
+            o, s = jax.jit(getattr(la, f"lightning_{kind}"))(
+                q, k, v, lam, s0, 1, *rows)
+            o2, s2 = jax.jit(getattr(la, f"lightning_{kind}_reference"))(
+                q, k, v, lam, s0, 1, *rows)
+            check_close(f"lightning_{kind} (output)", o, o2, 1e-4)
+            check_close(f"lightning_{kind} (state)", s, s2, 1e-4)
+
+
+def _kernels_block_sparse(d: KernelDims) -> None:
+    """``block_sparse_walk`` under a selection that differs by KV head
+    against the dense gather."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import block_sparse_attention as bsa
+
+    rows, T = _mixed_rows(d)
+    H, KVH, hd = d.heads, 2, d.head_dim
+    ks = jax.random.split(jax.random.key(7), 6)
+    q = _rand(ks[0], (T, H, hd), jnp.bfloat16)
+    kn, vn = (_rand(ks[i], (T, KVH, hd), jnp.bfloat16) for i in (1, 2))
+    P = d.slots * d.maxp
+    kp, vp = (_rand(ks[i], (d.layers, KVH, P + 1, d.page, hd), jnp.bfloat16)
+              for i in (3, 4))
+    bt = jnp.asarray(np.random.default_rng(0).permutation(P).reshape(
+        d.slots, d.maxp), jnp.int32)
+    mask = jax.random.bernoulli(ks[5], 0.5, (T, KVH, d.maxp)).at[
+        :, :, 0].set(True)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(bsa.block_sparse_attention_reference)(
+            q, kn, vn, kp[1], vp[1], *rows, bt, mask)
+    got, _pages = jax.jit(bsa.block_sparse_attention)(
+        q, kn, vn, kp, vp, 1, *rows, bt, mask)
+    check_close("block_sparse_walk", got, want, 2e-2)
+
+
 def phase_kernels(platform: str, *, dims: KernelDims = KernelDims()) -> dict:
     require_platform(platform)
     _kernels_flash(dims, platform)
@@ -593,6 +662,8 @@ def phase_kernels(platform: str, *, dims: KernelDims = KernelDims()) -> dict:
     _kernels_ragged(dims)
     _kernels_ssd(dims)
     _kernels_fused(dims)
+    _kernels_lightning(dims)
+    _kernels_block_sparse(dims)
     return {}
 
 
@@ -1102,6 +1173,88 @@ def phase_serve_glm5(platform: str, *, config=None, n_requests: int = 4,
                 model_counters=counters, served_check=served)
 
 
+def phase_serve_sala(platform: str, *, config=None, n_requests: int = 3,
+                     prompt_len: int = 8300, new_tokens: int = 12,
+                     ready_timeout_s: float = 1500.0) -> dict:
+    """The serving phase's sixth case: a matrix state by slot beside
+    pages and paged compressed keys.  MiniCPM-SALA at three layers
+    (lightning, minicpm4, lightning) of the published widths through the
+    benchmark's own replica class: the reference check before the engine
+    takes the memory (logits, the lightning state, the selected pages,
+    and the three controls, each of which it has to refuse), prompts past
+    ``dense_len`` through ``serve.run`` so that decode rows select, the
+    state cache's accounting, and the replica's served check: two of the
+    answers against the reference at the layers held, and the device's
+    count of the pages read against the host's.  ``config`` defaults to
+    the benchmark's file."""
+    import ray_tpu
+    from benchmarks.harness import reference_sala
+    from benchmarks.runners import serve_sala
+    from ray_tpu import serve
+
+    config = config or _benchmark_config("minicpm_sala_pp2")
+    hf = config.get("check_hf") or serve_sala.CHECK_HF
+    config = dict(config, **hf, engine=dict(
+        config["engine"], max_seq_len=-(-(prompt_len + new_tokens) // 64)
+        * 64))
+    # every request here is as long as the others: each one "passed"
+    config.setdefault("served_plan", {"past": prompt_len})
+    ray_tpu.init(ignore_reinit_error=True)
+    try:
+        chips = int(ray_tpu.cluster_resources().get("TPU", 0))
+        app = serve.deployment(
+            ray_actor_options=({"num_tpus": chips} if platform == "tpu"
+                               else {}),
+            max_ongoing_requests=2 * n_requests,
+        )(serve_sala.server_class()).bind({"config": config, "seed": 0})
+        t0 = time.perf_counter()
+        handle = serve.run(app, name="chip_smoke_sala", route_prefix=None,
+                           timeout_s=ready_timeout_s)
+        rep = handle.device_report.remote().result(timeout_s=60)
+        check = rep["check"]
+        log(f"  sala replica ready after {time.perf_counter() - t0:.1f}s "
+            f"on {rep['platform']}; reference check: "
+            + " ".join(f"{k}={check[k]['rel_err_prefill']:.2e}/"
+                       f"{check[k]['rel_err_decode']:.2e}"
+                       for k in serve_sala.TOLERANCES)
+            + f" state={check['lin_state']} selection={check['selection']}"
+            + "".join(f" {c}={check[c]}" for c in reference_sala.CONTROLS))
+        if rep["platform"] != platform:
+            raise AssertionError(
+                f"replica computes on {rep['platform']!r}, not "
+                f"{platform!r}")
+        if not check["ok"]:
+            raise AssertionError(f"sala reference check failed: {check}")
+        vocab = config["vocab_size"]
+        prompts = [[(7 * i + 3 * j) % (vocab - 1) + 1
+                    for j in range(prompt_len)] for i in range(n_requests)]
+        pending = [handle.remote({"tokens": p, "max_new_tokens": new_tokens,
+                                  "temperature": 0.0}) for p in prompts]
+        outs = [r.result(timeout_s=ready_timeout_s)["tokens"]
+                for r in pending]
+        check_answers(outs, new_tokens, vocab)
+        counters = handle.counters.remote().result(timeout_s=60)
+        state, pages = counters["state_cache"], counters["model_counters"]
+        log(f"  {len(outs)} sala requests answered ({prompt_len} prompt + "
+            f"{new_tokens} new tokens each); state cache {state}; pages "
+            f"read {pages}")
+        if state["resets"] < n_requests or state["live"] != 0:
+            raise AssertionError(f"state cache accounting: {state}")
+        if min(pages["sel_pages"]) <= 0:
+            raise AssertionError(f"no page counted as read: {pages}")
+        served = handle.served_check.remote().result(
+            timeout_s=ready_timeout_s)
+        log(f"  sala served check: {served}")
+        if not served["ok"]:
+            raise AssertionError(f"sala served check failed: {served}")
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    return {"platform": rep["platform"], "reference_check": check,
+            "state_cache": state, "model_counters": pages,
+            "served_check": served}
+
+
 # ---------------------------------------------------------------------------
 # phase: engine_legacy
 # ---------------------------------------------------------------------------
@@ -1252,6 +1405,7 @@ def run_child(phase: str, expect_loss0) -> int:
             report["brumby"] = phase_serve_brumby("tpu")
             report["xing"] = phase_serve_xing("tpu")
             report["glm5"] = phase_serve_glm5("tpu")
+            report["sala"] = phase_serve_sala("tpu")
     else:
         clock = CompileClock()
         try:

@@ -543,6 +543,10 @@ class PagedEngineAdapter:
     # model attends to every cached position.  ``llm.pack`` reports it
     # as sel_tokens.
     ragged_sel_tokens: Optional[Callable[..., int]] = None
+    # The longest row of fresh tokens the model's ragged step takes; None
+    # = any.  A row may take the whole token budget, so the engine
+    # refuses a budget past it.
+    max_row_tokens: Optional[int] = None
     # Bytes of recurrent state one sequence holds per slot, whatever its
     # length (state-space layers: convolution tails, SSM states); 0 = the
     # cache is KV pages only.  Non-zero, the engine calls
@@ -776,6 +780,54 @@ def glm5_paged_adapter(cfg) -> PagedEngineAdapter:
         ragged_sel_tokens=lambda row_start, row_len:
             sel_token_count(row_start, row_len, cfg.index_topk),
         counter_leaves=("moe_tokens", "moe_distinct"),
+    )
+
+
+def sala_paged_adapter(cfg) -> PagedEngineAdapter:
+    """MiniCPM-SALA (models/minicpm_sala.py): lightning layers with a
+    matrix state by slot (``lin_s``) beside the sparse layers' page pools
+    (``k``/``v`` and ``kh``, their compressed keys, under the same block
+    tables) and a counter of the pages the walk read.  As for
+    ``jamba_paged_adapter`` the state is why the engine refuses the
+    prefix cache, speculative decoding and migration; its step takes
+    neither ``lora=`` nor ``logit_idx=``."""
+    from ray_tpu.models import minicpm_sala as sala
+    from ray_tpu.ops.block_sparse_attention import (
+        sel_token_count,
+        walk_page_count,
+    )
+
+    def init_cache(num_pages, page, max_slots):
+        from ray_tpu.util import flight_recorder
+
+        flight_recorder.record(
+            "serve_model_parts", model="minicpm_sala",
+            state_bytes_per_slot=cfg.state_bytes_per_slot(),
+            pool_bytes_per_token=cfg.pool_bytes_per_token(),
+            lightning_layers=cfg.layer_kinds().count(sala.LIGHTNING),
+            sparse_layers=cfg.layer_kinds().count(sala.SPARSE))
+        return sala.init_cache(cfg, num_pages, page, max_slots)
+
+    return PagedEngineAdapter(
+        init_cache=init_cache,
+        ragged_step=lambda params, tokens, tok_pos, row_slot, row_start,
+        row_len, row_off, bt, cache:
+            sala.ragged_step(params, tokens, tok_pos, row_slot, row_start,
+                             row_len, row_off, bt, cfg, cache),
+        # pool pages the walk reads in one sparse layer, over rows and KV
+        # heads, plus a self cell a (row, KV head)
+        ragged_grid_cells=lambda row_start, row_len, maxp, page, lora:
+            walk_page_count(row_start, row_len, cfg.n_kv_heads, cfg.sparse,
+                            page)
+            + cfg.n_kv_heads * int(sum(1 for n in row_len if n > 0)),
+        ragged_sel_tokens=lambda row_start, row_len:
+            sel_token_count(row_start, row_len, cfg.sparse),
+        # the walk's self cell is plain causal attention: a row's fresh
+        # tokens have to lie inside the forced window of its last
+        max_row_tokens=cfg.sparse.max_row_tokens,
+        state_bytes_per_slot=cfg.state_bytes_per_slot(),
+        state_leaves=("lin_s",),
+        counter_leaves=("sel_pages",),
     )
 
 
@@ -1660,6 +1712,15 @@ class LLMEngine:
                 raise ValueError(
                     "token_budget must leave room for a prefill chunk "
                     f"beside {config.max_slots} decode rows")
+            if (adapter.max_row_tokens is not None
+                    and self._token_budget > adapter.max_row_tokens):
+                raise ValueError(
+                    f"a row may take the whole token budget "
+                    f"({self._token_budget}: token_budget, or max_slots + "
+                    f"prefill_chunk), and the adapter's step takes rows of "
+                    f"at most {adapter.max_row_tokens} fresh tokens "
+                    f"(PagedEngineAdapter.max_row_tokens) — set a smaller "
+                    f"EngineConfig.prefill_chunk or token_budget")
             self._ragged_shapes = ragged_step_shapes(
                 self._token_budget, config.max_slots)
             self._steps_by_shape = {T: 0 for T in self._ragged_shapes}

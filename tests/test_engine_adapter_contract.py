@@ -1,6 +1,7 @@
 """What ``LLMEngine`` may assume of a model: the seam is
 ``PagedEngineAdapter`` with ONE step plug.  The same contract is held
-to every adapter in the tree (llama, llama with LoRA, Jamba, Brumby, Xing), so a new
+to every adapter in the tree (llama, llama with LoRA, Jamba, Brumby, Xing, GLM-5,
+MiniCPM-SALA), so a new
 model family knows what it has to provide."""
 
 import dataclasses
@@ -10,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import brumby, glm5, jamba, llama, xing
+from ray_tpu.models import brumby, glm5, jamba, llama, minicpm_sala, xing
+from ray_tpu.ops.block_sparse_attention import BlockSparse
 from ray_tpu.ops import segmented_lora
 from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
 from ray_tpu.serve.llm_engine import (
@@ -21,6 +23,7 @@ from ray_tpu.serve.llm_engine import (
     jamba_paged_adapter,
     llama_paged_adapter,
     ragged_step_shapes,
+    sala_paged_adapter,
     xing_paged_adapter,
 )
 
@@ -48,6 +51,13 @@ GLM5 = glm5.Glm5Config(
     n_routed=8, n_experts=4, expert_first=4, top_k=2, moe_dim=32,
     index_heads=4, index_dim=16, index_topk=4, dtype=jnp.float32,
     param_dtype=jnp.float32)
+SALA = minicpm_sala.SalaConfig(
+    vocab_size=97, dim=64, n_heads=4, n_kv_heads=2, head_dim=16, mlp_dim=96,
+    mixer_types=("lightning-attn", "minicpm4", "lightning-attn"),
+    first_layer=21, dim_model_base=32,
+    sparse=BlockSparse(block=8, kernel=4, stride=2, topk=4, window=16,
+                       dense_len=32),
+    dtype=jnp.float32, param_dtype=jnp.float32)
 PAGE, SLOTS, MAXP, BUDGET = 8, 4, 4, 24
 TABLE = np.arange(SLOTS * MAXP, dtype=np.int32).reshape(SLOTS, MAXP)
 ROWS = [{"slot": 2, "start": 0, "tokens": [5, 9, 2, 7, 1, 3]},
@@ -63,6 +73,7 @@ CASES = {
     "brumby": (brumby_paged_adapter, BRUMBY, brumby.init_params, set()),
     "xing": (xing_paged_adapter, XING, xing.init_params, set()),
     "glm5": (glm5_paged_adapter, GLM5, glm5.init_params, set()),
+    "sala": (sala_paged_adapter, SALA, minicpm_sala.init_params, set()),
 }
 
 
@@ -98,6 +109,9 @@ def test_ragged_step_is_the_one_step_plug(case):
     # counters the step keeps are leaves of the tree, and not state by slot
     assert set(adapter.counter_leaves) <= set(cache)
     assert not set(adapter.counter_leaves) & set(adapter.state_leaves)
+    # a step that bounds its rows says so; the rows here are inside it
+    assert (adapter.max_row_tokens is None
+            or max(len(r["tokens"]) for r in ROWS) <= adapter.max_row_tokens)
     (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off) = \
         pack_ragged_batch(ROWS, BUDGET, SLOTS)
     nine = (params, toks, pos, r_slot, r_start, r_len, r_off, TABLE, cache)

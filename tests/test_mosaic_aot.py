@@ -918,3 +918,107 @@ def test_glm5_cell_step_copies_neither_pool_nor_experts(v5e, shape):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.5 * 2**30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
+# -- the MiniCPM-SALA cell: a matrix state beside pages and compressed keys --
+
+@pytest.mark.parametrize("shape", ["budget", "small"])
+def test_sala_cell_step_copies_no_pool_state_or_weight_stack(v5e, shape):
+    """The step program of ``minicpm_sala_pp2-doc_64k`` at its sixteen
+    layers and published widths, in both shapes the engine compiles (520
+    positions and 8): ``lightning_decode``, ``lightning_chunk``,
+    ``block_sparse_walk`` (the chunk's whole-window form) and the append
+    compile for a v5e, fit the chip with the weights (9.39 GiB), pools
+    (2.16 GiB) and state (0.21 GiB), and copy neither a pool, the state,
+    nor a stack of projection weights (XLA re-laid ``lin.wq/wk/wv`` out
+    every step until the head split stood behind a barrier)."""
+    from benchmarks.runners.serve_sala import model_config
+    from ray_tpu.models import minicpm_sala as sala
+
+    config = json.loads((REPO / "benchmarks" / "configs"
+                         / "minicpm_sala_pp2.json").read_text())
+    cfg, eng = model_config(config), config["engine"]
+    slots, page = eng["max_slots"], eng["page_size"]
+    maxp = eng["max_seq_len"] // page
+    shapes = _step_shapes(slots + eng["prefill_chunk"], slots)
+    assert (cfg.n_layers, cfg.dim, cfg.n_heads, cfg.n_kv_heads, maxp,
+            shapes) == (16, 4096, 32, 2, 1040, {"budget": 520, "small": 8})
+    T = shapes[shape]
+    mesh = _one(v5e)
+    params = _on(mesh, jax.eval_shape(
+        lambda: sala.init_params(jax.random.key(0), cfg)))
+    cache = _on(mesh, jax.eval_shape(
+        lambda: sala.init_cache(cfg, slots * maxp, page, slots)))
+    assert set(cache) == {"k", "v", "kh", "lin_s", "sel_pages"}
+    toks, rows, bt = _on(mesh, (
+        _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
+        _sds(slots, maxp, dtype=jnp.int32)))
+    compiled = _compile(
+        lambda p, t, pos, rs, r0, rl, ro, b, c:
+        sala.ragged_step(p, t, pos, rs, r0, rl, ro, b, cfg, c),
+        params, toks, toks, rows, rows, rows, rows, bt, cache,
+        donate_argnums=(8,))
+    text = compiled.as_text()
+    for kernel in ("lightning_decode", "lightning_chunk",
+                   "block_sparse_walk", "ragged_kv_append"):
+        assert kernel in text
+    for big in ("bf16[4,2,8321,64,128]", "f32[4,33284,256]",
+                "f32[12,9,32,128,128]", "bf16[12,4096,4096]",
+                "bf16[16,4096,16384]", "bf16[16,16384,4096]"):
+        assert [ln for ln in text.splitlines()
+                if re.search(r"= \S*" + re.escape(big) + r"\S* copy\(", ln)
+                ] == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.25 * 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
+@pytest.mark.parametrize("piece", ["embed", "lightning-attn", "minicpm4",
+                                   "readings"])
+def test_sala_served_check_fits_beside_the_engine(v5e, piece):
+    """The served check's reference (``serve_sala.served_programs``) runs
+    after the window with the engine idle on the same chip: weights, pools
+    and state hold 11.76 of 15.75 GiB.  Each compiled piece, at the
+    configuration's widths and the check's 17,408 positions, has to fit
+    in half of what is left beside the activations it is handed."""
+    from benchmarks.runners import serve_sala
+    from ray_tpu.models import minicpm_sala as sala
+
+    config = json.loads((REPO / "benchmarks" / "configs"
+                         / "minicpm_sala_pp2.json").read_text())
+    cfg, plan = serve_sala.model_config(config), serve_sala.SERVED_PLAN
+    assert plan["length"] == plan["past"] + plan["answer"] == 17408
+    mesh = _one(v5e)
+    params = _on(mesh, jax.eval_shape(
+        lambda: sala.init_params(jax.random.key(0), cfg)))
+    x, toks, i32, ans = _on(mesh, (
+        _sds(plan["length"], cfg.dim, dtype=jnp.float32),
+        _sds(plan["length"], dtype=jnp.int32), _sds(dtype=jnp.int32),
+        _sds(plan["answer"], dtype=jnp.int32)))
+    embed, layer, readings = serve_sala.served_programs(config, plan)
+    fn, args = {"embed": (embed, (params, toks)),
+                "readings": (readings, (x, params, i32, ans, ans)),
+                }.get(piece) or (layer[piece], (x, params, i32, i32, i32))
+    with jax.default_matmul_precision("highest"):
+        compiled = (fn.trace(*args).lower(lowering_platforms=("tpu",))
+                    .compile())
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2.0 * 2**30
+
+
+def test_block_sparse_walk_compiles(v5e):
+    """The walk alone at the cell's shapes: rows of one token through
+    their own pages, a chunk's rows through the context once under the
+    selection as a mask."""
+    from ray_tpu.ops import block_sparse_attention as bsa
+
+    T, H, KVH, hd, maxp, slots = 520, 32, 2, 128, 1040, 8
+    mesh = _one(v5e)
+    pool = _sds(4, KVH, slots * maxp + 1, PAGE, hd)
+    args = _on(mesh, (
+        _sds(T, H, hd), _sds(T, KVH, hd), _sds(T, KVH, hd), pool, pool,
+        _sds(dtype=jnp.int32), *[_sds(slots, dtype=jnp.int32)] * 4,
+        _sds(slots, maxp, dtype=jnp.int32),
+        _sds(T, KVH, maxp, dtype=jnp.bool_)))
+    compiled = _compile(bsa.block_sparse_attention, *args)
+    assert "block_sparse_walk" in compiled.as_text()
