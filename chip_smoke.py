@@ -628,31 +628,54 @@ def _kernels_lightning(d: KernelDims) -> None:
 
 def _kernels_block_sparse(d: KernelDims) -> None:
     """``block_sparse_walk`` under a selection that differs by KV head
-    against the dense gather."""
+    against the dense gather, over block tables of three pool cells: a
+    row of one token whose list ends inside a cell, one whose list is
+    shorter than a cell, a chunk whose tokens' picks leave gaps in their
+    union, a chunk that starts its sequence; and the counts it returns
+    (pages listed, cells of ``cell_pages`` entries) against the host's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.ops import block_sparse_attention as bsa
 
-    rows, T = _mixed_rows(d)
-    H, KVH, hd = d.heads, 2, d.head_dim
+    H, KVH, hd, page, maxp = d.heads, 2, d.head_dim, d.page, 24
+    G = bsa.cell_pages(maxp)
+    spec = [(0, 19 * page + 5, 1, 0), (2, 17 * page, 2 * page, 3),
+            (0, 0, 0, 0), (1, 9, 1, 2 * page + 4),
+            (3, 0, 40, 2 * page + 8)]
+    rows = tuple(jnp.asarray(x, jnp.int32) for x in zip(*spec))
+    T = 2 * page + 48
     ks = jax.random.split(jax.random.key(7), 6)
     q = _rand(ks[0], (T, H, hd), jnp.bfloat16)
     kn, vn = (_rand(ks[i], (T, KVH, hd), jnp.bfloat16) for i in (1, 2))
-    P = d.slots * d.maxp
-    kp, vp = (_rand(ks[i], (d.layers, KVH, P + 1, d.page, hd), jnp.bfloat16)
+    P = d.slots * maxp
+    kp, vp = (_rand(ks[i], (d.layers, KVH, P + 1, page, hd), jnp.bfloat16)
               for i in (3, 4))
     bt = jnp.asarray(np.random.default_rng(0).permutation(P).reshape(
-        d.slots, d.maxp), jnp.int32)
-    mask = jax.random.bernoulli(ks[5], 0.5, (T, KVH, d.maxp)).at[
-        :, :, 0].set(True)
+        d.slots, maxp), jnp.int32)
+    few = np.zeros(T, bool)
+    for _slot, _start, n, off in spec:
+        few[off:off + n] = n > 1
+    mask = (jax.random.uniform(ks[5], (T, KVH, maxp))
+            < jnp.where(few, 0.12, 0.5)[:, None, None]).at[:, :, 0].set(True)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(bsa.block_sparse_attention_reference)(
             q, kn, vn, kp[1], vp[1], *rows, bt, mask)
-    got, _pages = jax.jit(bsa.block_sparse_attention)(
+    got, pages, cells = jax.jit(bsa.block_sparse_attention)(
         q, kn, vn, kp, vp, 1, *rows, bt, mask)
     check_close("block_sparse_walk", got, want, 2e-2)
+    m = np.asarray(mask)
+    host = np.zeros((2, 2), int)        # pages, cells x one token, more
+    for _slot, start, n, off in spec:
+        if n:
+            lists = (m[off:off + n].any(axis=0)
+                     & (np.arange(maxp) * page < start)).sum(axis=-1)
+            host[:, int(n > 1)] += [lists.sum(), (-(-lists // G)).sum()]
+    if [pages.tolist(), cells.tolist()] != host.tolist():
+        raise AssertionError(
+            f"block_sparse_walk counted {pages.tolist()} pages in "
+            f"{cells.tolist()} cells of {G}; the host {host.tolist()}")
 
 
 def phase_kernels(platform: str, *, dims: KernelDims = KernelDims()) -> dict:

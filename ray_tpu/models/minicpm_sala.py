@@ -34,7 +34,9 @@ paged pool of their compressed keys (one entry a 16 tokens, float32),
 and ``lin_s`` ``[Ll, slots + 1, 32, 128, 128]`` float32 by slot for the
 lightning layers, which no page table addresses; ``sel_pages`` counts
 on the device the pool pages the walk read (``[2]``: by rows of one
-token, by rows of more; summed over rows, KV heads and layers).
+token, by rows of more; summed over rows, KV heads and layers) and
+``walk_cells`` the pool cells it read them in (the same ``[2]``; a cell
+holds up to ``ops/block_sparse_attention.CELL_PAGES`` pages).
 
 ``ragged_step`` is the engine's unified step (see
 ``llama.ragged_step_paged`` for the contract).  Pools are read-only in
@@ -201,7 +203,8 @@ def init_cache(cfg: SalaConfig, num_pages: int, page_size: int,
     hd]`` with a scratch page last, as ``llama.init_paged_cache``; ``kh``
     ``[La, (P + 1) * page / stride, KVH * hd]`` float32 under the same
     block tables (a row an entry: ``ops/block_sparse_attention``); ``lin_s`` ``[Ll, slots + 1, H, hd, hd]`` float32 with a
-    scratch slot last; the counter ``sel_pages`` ``[2]``."""
+    scratch slot last; the counters ``sel_pages`` and ``walk_cells``, ``[2]``
+    each."""
     assert page_size == cfg.sparse.block, (
         "a selection is a list of pages: the engine's page has to be the "
         f"model's block ({cfg.sparse.block}), not {page_size}")
@@ -216,6 +219,7 @@ def init_cache(cfg: SalaConfig, num_pages: int, page_size: int,
         "lin_s": jnp.zeros((Ll, max_slots + 1, cfg.n_heads, cfg.head_dim,
                             cfg.head_dim), jnp.float32),
         "sel_pages": jnp.zeros((2,), jnp.int32),
+        "walk_cells": jnp.zeros((2,), jnp.int32),
     }
 
 
@@ -257,8 +261,8 @@ def _lightning_mixer(u, p, cfg: SalaConfig, lin_s, lm, rows, sin, cos):
 def _sparse_mixer(u, p, cfg: SalaConfig, cache, li_a, rows, block_tables,
                   groups):
     """One minicpm4 mixer.  Returns (out [T, D], k [T, KVH, hd], v, the
-    step's group sums [NG, KVH, hd], pages read [2], the selection
-    bool[T, KVH, maxp])."""
+    step's group sums [NG, KVH, hd], pages read and cells walked [2, 2],
+    the selection bool[T, KVH, maxp])."""
     dt_ = cfg.dtype
     T = u.shape[0]
     H, KVH, hd, sp = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.sparse
@@ -273,12 +277,12 @@ def _sparse_mixer(u, p, cfg: SalaConfig, cache, li_a, rows, block_tables,
         q.reshape(T, KVH, H // KVH, hd), cache["kh"], li_a, sums, groups,
         *rows, block_tables, sp)
     with jax.named_scope("sparse_attn"):
-        o, pages = bsa.block_sparse_attention(
+        o, pages, cells = bsa.block_sparse_attention(
             q, k, v, cache["k"], cache["v"], li_a, *rows, block_tables,
             picked)
     with jax.named_scope("attention"):
         out = _gated_out(o.reshape(T, -1), gate, p["wo"], cfg)
-    return out, k, v, sums, pages, picked
+    return out, k, v, sums, jnp.stack([pages, cells]), picked
 
 
 def ragged_step(
@@ -325,7 +329,7 @@ def ragged_step(
         # the same in every sparse layer: listed once, for all
         groups = bsa.step_groups(*rows[1:], T, cfg.sparse.stride)
     lin_s = cache["lin_s"]
-    k_news, v_news, sums, pages, picks = [], [], [], [], []
+    k_news, v_news, sums, walked, picks = [], [], [], [], []
     li_m = li_a = 0
     for kind, first, count in _segments(cfg.layer_kinds()):
         if kind == LIGHTNING:
@@ -353,7 +357,7 @@ def ragged_step(
             k_news.append(k1)
             v_news.append(v1)
             sums.append(s1)
-            pages.append(n1)
+            walked.append(n1)
             picks.append(m1)
             li_a += 1
 
@@ -367,7 +371,9 @@ def ragged_step(
             new_cache["kh"] = bsa.compressed_append(
                 cache["kh"], jnp.stack(sums), groups, rows[0],
                 block_tables, cfg.sparse)
-        new_cache["sel_pages"] = cache["sel_pages"] + sum(pages)
+        pages, cells = sum(walked)
+        new_cache["sel_pages"] = cache["sel_pages"] + pages
+        new_cache["walk_cells"] = cache["walk_cells"] + cells
     with jax.named_scope("lm_head"):
         last = jnp.clip(rows[3] + jnp.maximum(rows[2], 1) - 1, 0, T - 1)
         x = rms_norm(x[last], params["final_norm"], cfg.norm_eps)

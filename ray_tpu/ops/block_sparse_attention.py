@@ -40,14 +40,57 @@ ONE token gathers its row's halves through the block table, a row of
 more is scored one row at a time under a dynamic trip count.
 
 **The walk** (kernel ``block_sparse_walk``) is ``ragged_paged_
-attention``'s flash walk with a cell a (row, KV head, page) and a list
-of live cells that differs by KV head: a row of one token lists the
-pages it selected and no other, so its grid is at most ``topk`` pool
-cells a KV head whatever its context; a row of more lists the pages ANY
-of its tokens selected and masks each token's own away inside the cell
-(``tok_mask``).  The house rules of ``ops/ragged_paged_attention.py``
-hold: lists under a dynamic bound, pools read-only, one aliased append
-at the step's end.
+attention``'s flash walk over LISTS that differ by KV head.  A UNIT is a
+(row, KV head); its list is the pages it selected, in ascending order: a
+row of one token lists the pages it selected and no other, a row of more
+the pages ANY of its tokens selected, and masks each token's own away
+inside the cell (``tok_mask``).  A POOL CELL is ``G = cell_pages(maxp)``
+consecutive ENTRIES of a unit's list (``CELL_PAGES``: eight pages, 512 keys
+at the model's block of 64), whichever pages those are: the ``k`` and
+``v`` pools are passed ``G`` times, a page an operand with an index map
+each (and for a row of more so is the mask, the block of 128 pages that
+holds each entry's), the pages are joined into one ``[G * page, hd]`` key block, and
+a cell makes ONE ``[rows, G * page]`` score tile, one mask and ONE update
+of the flash state.  A list's tail short of ``G`` is masked inside the
+cell and each of its operands stands on the page it held a cell before,
+so nothing is fetched for it; the unit's self cell (its fresh tokens,
+causally, then the division and the write) does the same with the last
+pool cell.  The flash state and the scores lie BY HEAD, ``[QP, Cq, .]``,
+so that a mask of ``[Cq, keys]``, the same for every head of a token,
+broadcasts over the stacked heads.  So a row of one token walks
+``ceil(topk / G)`` pool cells a KV head whatever its context.  The house
+rules of ``ops/ragged_paged_attention.py`` hold: lists under a dynamic
+bound, pools read-only, one aliased append at the step's end.
+
+What the walk counts (``block_sparse_attention`` returns both, the
+model's cache keeps their sums in ``sel_pages`` and ``walk_cells``, ``[2]``
+each: by rows of one token, by rows of more): the PAGES its lists hold,
+which for a row of one token is what it selected of the pages that hold
+pooled tokens (``walk_page_count`` on the host) and for a row of more
+the union of its tokens' picks, no page beside them; and the pool CELLS
+it walked them in, ``ceil(pages / G)`` a unit: pages over cells is the
+pages a cell holds, ``G`` at best.
+
+``G`` is a constant of the op, chosen on a v5e (PERF.md PR 42; ONE
+layer, ms, the op with its lists, median of five; PR 41's one-page cell
+first, then this walk at 1 / 2 / 4 / 8 pages a cell):
+
+    a 512-token row at 16k of context     6.97    8.04 / 4.09 / 4.83 / 2.86
+    the same at 64k                      27.20   31.39 / 15.72 / 18.57 / 10.67
+    eight one-token rows past 16,384      0.954   0.619 / 0.466 / 0.378 / 0.357
+
+A chunk cell pays for its ``[8320, .]`` float32 tiles by the 128 LANES:
+a cell of one page and of two cost the same 15.3 us, of four 36, of
+eight 42, so eight pages are 5.2 us a page where PR 41's cell was 13.3.
+The one-token call's 16 stacked rows pay about 0.3 us a grid step (1,040
+steps at one page a cell, 144 at eight); the 0.31 ms left at eight are
+its 1,024 pages' DMAs and the lists' XLA.  Both calls want the eight, so
+``G`` is no function of the call; ``ops/latent_attention.cell_pages``
+holds four (its ``[4160, 512]`` tiles lost at eight), so this op keeps
+its own two lines.  The constant counts PAGES: the table was measured at
+the published block of 64, the only page the engine takes for the model
+(``minicpm_sala.init_cache``), and a test's toy pages walk the same
+eight operands a cell as the chip does.
 
 A row's fresh tokens must lie inside their own forced window, so that
 the self cell is plain causal attention: ``row_len <= max_row_tokens(sp)
@@ -83,6 +126,15 @@ from ray_tpu.ops.ragged_paged_attention import (
 
 _HI = lax.Precision.HIGHEST
 _FORCED = 1e30
+# entries of a (row, KV head)'s list of selected pages that a pool cell of
+# the walk spans.  The table is in the module docstring.
+CELL_PAGES = 8
+
+
+def cell_pages(maxp: int) -> int:
+    """G, the entries a pool cell of the walk spans: ``CELL_PAGES``, and no
+    more than a block table has."""
+    return max(1, min(CELL_PAGES, maxp))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -397,171 +449,239 @@ def walk_page_count(row_start, row_len, kv_heads: int, sp: BlockSparse,
 # the walk
 # --------------------------------------------------------------------------
 
-def walk_cells(tok_mask: jax.Array, in_row: jax.Array, row_start,
-               takes, maxp: int, page: int):
-    """``(live_ci, n_live, n_pool)`` for the rows ``takes`` [R]: cell
-    ``(r * KVH + g) * (maxp + 1) + pc`` is pool page ``pc`` of row ``r``
-    and KV head ``g``, live where the page holds pooled tokens of the row
-    and any of the row's tokens (``in_row`` [T, R]) selected it
-    (``tok_mask`` [KVH, T, MP] float32), or the self cell at ``pc ==
-    maxp``.  A (row, KV head)'s cells are adjacent and end with its self
-    cell."""
-    mine = (in_row & takes[None, :]).astype(jnp.float32)
-    picked = jnp.einsum("tr,gtm->rgm", mine, tok_mask[:, :, :maxp]) > 0.0
-    pooled = jnp.arange(maxp)[None, :] * page < row_start[:, None]
-    pool = picked & pooled[:, None, :] & takes[:, None, None]
-    live = jnp.concatenate(
-        [pool, jnp.broadcast_to(takes[:, None, None], pool.shape[:2] + (1,))],
-        axis=-1)
-    return _listed(live) + (jnp.sum(pool, dtype=jnp.int32),)
+def walk_lists(tok_mask: jax.Array, in_row: jax.Array, row_start,
+               takes, maxp: int, page: int, G: int):
+    """The lists one call walks, for the rows ``takes`` [R]; a UNIT is a
+    (row, KV head), ``u = r * KVH + g``:
+
+    ``ent`` ``int32[U * maxp]``  unit ``u``'s selected pages (indices into
+        the row's block table) in ascending order from ``u * maxp``: a
+        page is listed where it holds pooled tokens of the row and any of
+        the row's tokens (``in_row`` [T, R]) selected it (``tok_mask``
+        [KVH, T, MP] float32).  A sort of each unit's ``maxp`` candidates:
+        29 us for 16 units of 1040 where ``jnp.nonzero`` over them took
+        185, a scatter of every candidate (PERF.md PR 42);
+    ``cnt`` ``int32[U]``  a unit's entries;
+    ``live_ci`` ``int32[U * (NC + 1)]``, ``n_live`` ``int32[1]``  the grid:
+        cell ``u * (NC + 1) + c`` is entries ``c * G`` to ``c * G + G - 1``
+        of unit ``u`` (``NC = ceil(maxp / G)``), or its self cell at ``c ==
+        NC``; a unit's ``ceil(cnt / G)`` pool cells are adjacent and end
+        with its self cell;
+    ``n_pages``, ``n_cells`` ``int32[]``  entries and pool cells in all."""
+    i32 = jnp.int32
+    KVH = tok_mask.shape[0]
+    NC = -(-maxp // G)
+    taken = (in_row & takes[None, :]).astype(jnp.float32)
+    picked = jnp.einsum("tr,gtm->rgm", taken, tok_mask[:, :, :maxp]) > 0.0
+    m = jnp.arange(maxp, dtype=i32)
+    pooled = m[None, :] * page < row_start[:, None]
+    pool = (picked & pooled[:, None, :] & takes[:, None, None]).reshape(
+        -1, maxp)                                                 # [U, maxp]
+    ent = jnp.minimum(jnp.sort(jnp.where(pool, m[None, :], maxp), axis=-1),
+                      maxp - 1)
+    cnt = jnp.sum(pool, axis=-1, dtype=i32)
+    U = cnt.shape[0]
+    cells = -(-cnt // G)
+    steps = cells + jnp.repeat(takes.astype(i32), KVH)
+    end = jnp.cumsum(steps)
+    # grid step i is a cell of the unit whose steps hold it
+    i = jnp.arange(U * (NC + 1), dtype=i32)
+    u = jnp.minimum(jnp.sum(i[:, None] >= end[None, :], axis=1, dtype=i32),
+                    U - 1)
+    mine = u[:, None] == jnp.arange(U, dtype=i32)[None, :]
+    c = i - jnp.sum(jnp.where(mine, (end - steps)[None, :], 0), axis=1)
+    pool_c = jnp.sum(jnp.where(mine, cells[None, :], 0), axis=1)
+    live_ci = u * (NC + 1) + jnp.where(c < pool_c, c, NC)
+    return (ent.reshape(-1), cnt, live_ci, end[-1:], jnp.sum(cnt),
+            jnp.sum(cells))
+
+
+def _div(a, b: int):
+    """``a // b`` and, below, ``a % b`` of what is never negative: the
+    floored forms lower to a division, a sign and a select each, a few
+    hundred times over a call's index maps, which a step's set-up pays."""
+    return lax.div(a, jnp.int32(b))
+
+
+def _rem(a, b: int):
+    return lax.rem(a, jnp.int32(b))
+
+
+def _entry(ci, j: int, cnt_r, ent_r, *, G: int, NC: int, maxp: int):
+    """The page (index into the row's block table) under operand ``j`` of
+    cell ``ci``: entry ``c * G + j`` of its unit's list.  The self cell
+    stands on the unit's last pool cell, and an entry past the list's end
+    on the one ``G`` before it: the operand's page of the step before, so
+    neither is fetched (the kernel masks both)."""
+    u, c = _div(ci, NC + 1), _rem(ci, NC + 1)
+    n = cnt_r[u]
+    c = jnp.minimum(c, jnp.maximum(_div(n + G - 1, G) - 1, 0))
+    e = c * G + j
+    return ent_r[u * maxp + jnp.where(e < n, e, jnp.maximum(e - G, 0))]
 
 
 def _walk_kernel(slot_r, start_r, len_r, off_r, bt_r, ly_r, live_r, nl_r,
-                 *refs, T: int, Cq: int, KVH: int, QP: int,
-                 hd: int, page: int, Pt: int, maxp: int, scale: float):
-    del ly_r
-    if Cq == 1:
-        q_ref, kn_ref, vn_ref, kp_ref, vp_ref, out_ref, m_s, l_s, acc_s = refs
-        msk_ref = None
-    else:
-        (q_ref, kn_ref, vn_ref, msk_ref, kp_ref, vp_ref, out_ref,
-         m_s, l_s, acc_s) = refs
+                 cnt_r, ent_r, q_ref, kn_ref, vn_ref, *refs, T: int, Cq: int,
+                 KVH: int, QP: int, hd: int, page: int, G: int, NC: int,
+                 maxp: int, scale: float):
+    del slot_r, bt_r, ly_r      # the index maps' own
+    msk_refs, refs = (refs[:G], refs[G:]) if Cq > 1 else ((), refs)
+    kp_refs, vp_refs = refs[:G], refs[G:2 * G]
+    out_ref, m_s, l_s, acc_s = refs[2 * G:]
     i = pl.program_id(0)
     rows = QP * Cq
     Ck = max(Cq, 8)
+    Kp = G * page               # a pool cell's keys
     f32 = jnp.float32
+
+    def by_head(x):
+        """``[rows, n]`` as ``[QP, Cq, n]`` (a window is whole sublane
+        tiles, so no element moves): what is the same for every head of
+        a token, ``[Cq, n]``, then broadcasts over the heads."""
+        return x if Cq == 1 else x.reshape(QP, Cq, x.shape[-1])
 
     # i < n_live always holds under Mosaic, whose grid ends at n_live;
     # the interpreter's grid is the list's capacity.
     @pl.when(i < nl_r[0])
     def _cell():
         ci = live_r[i]
-        u = ci // (maxp + 1)
-        pc = ci % (maxp + 1)
-        r, g = u // KVH, u % KVH
+        u, pc = _div(ci, NC + 1), _rem(ci, NC + 1)
+        r, g = _div(u, KVH), _rem(u, KVH)
         start, nt, off = start_r[r], len_r[r], off_r[r]
-        wk = pl.multiple_of(jnp.minimum((off // 8) * 8, T - Ck), 8)
+        wk = pl.multiple_of(jnp.minimum(_div(off, 8) * 8, T - Ck), 8)
         w = off if Cq == 1 else wk
+        # the window's tokens, row-relative, and which of them are the row's
+        trel = w + lax.broadcasted_iota(jnp.int32, (Cq, 1), 0) - off
+        valid_q = (trel >= 0) & (trel < nt)
+        # the window's stacked queries [rows, hd], head-major
+        qs = (q_ref[g, w] if Cq == 1 else
+              q_ref[g, :, pl.ds(w, Cq), :].reshape(rows, hd))
 
-        @pl.when((i == 0) | (live_r[jnp.maximum(i - 1, 0)] // (maxp + 1) != u))
+        @pl.when((i == 0) | (_div(live_r[jnp.maximum(i - 1, 0)], NC + 1) != u))
         def _first():
             m_s[...] = jnp.full_like(m_s, NEG_INF)
             l_s[...] = jnp.zeros_like(l_s)
             acc_s[...] = jnp.zeros_like(acc_s)
 
-        def queries():
-            """The window's stacked queries ``[rows, hd]``, head-major,
-            and each stacked row's row-relative token index."""
-            if Cq == 1:
-                return q_ref[g, w], jnp.zeros((rows, 1), jnp.int32)
-            qs = jnp.concatenate(
-                [q_ref[g, h, pl.ds(w, Cq), :] for h in range(QP)], axis=0)
-            tj = lax.broadcasted_iota(jnp.int32, (QP, Cq, 1), 1)
-            return qs, w + tj.reshape(rows, 1) - off
-
-        def flash_update(qs, keys, vals, mask):
-            s = lax.dot_general(qs, keys, (((1,), (1,)), ((), ())),
-                                preferred_element_type=f32) * scale
-            s = jnp.where(mask, s, NEG_INF)
+        def flash_update(keys, vals, keep):
+            """One online-softmax update over a cell's keys; ``keep``
+            ``[Cq, keys]`` is every head's."""
+            s = by_head(lax.dot_general(
+                qs, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) * scale)
+            s = jnp.where(keep, s, NEG_INF)
             m_prev = m_s[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             # a stacked row the mask leaves nothing adds nothing
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
             corr = jnp.exp(m_prev - m_new)
             l_new = corr * l_s[...] + jnp.sum(p, axis=-1, keepdims=True)
-            a_new = acc_s[...] * corr + jnp.dot(
-                p.astype(vals.dtype), vals, preferred_element_type=f32)
+            a_new = acc_s[...] * corr + by_head(jnp.dot(
+                p.astype(vals.dtype).reshape(rows, -1), vals,
+                preferred_element_type=f32))
             m_s[...], l_s[...], acc_s[...] = m_new, l_new, a_new
             return l_new, a_new
 
-        # ---- pool cell: one selected page of the row's PAST ----------
-        @pl.when(pc < maxp)
+        # ---- pool cell: G selected pages of the row's PAST -----------
+        @pl.when(pc < NC)
         def _pool_cell():
-            kpos = pc * page + lax.broadcasted_iota(jnp.int32, (1, page), 1)
-            qs, trel = queries()
-            mask = (trel >= 0) & (trel < nt) & (kpos < start)
-            if msk_ref is not None:     # each token's own selection
-                blk = msk_ref[0, pl.ds(w, Cq), :]              # [Cq, 128]
-                lane = lax.broadcasted_iota(jnp.int32, blk.shape, 1)
-                col = jnp.sum(jnp.where(lane == pc % 128, blk, 0.0),
-                              axis=1, keepdims=True)           # [Cq, 1]
-                mask = mask & (jnp.concatenate([col] * QP, axis=0) > 0.0)
-            flash_update(qs, kp_ref[0, 0, 0], vp_ref[0, 0, 0], mask)
+            lane = lax.broadcasted_iota(jnp.int32, (1, Kp), 1)
+            held = _div(lane, page)     # the operand a key came under
+            pages = [_entry(ci, j, cnt_r, ent_r, G=G, NC=NC, maxp=maxp)
+                     for j in range(G)]
+            pg = pages[0]
+            for j in range(1, G):
+                pg = jnp.where(held == j, pages[j], pg)
+            # an entry past the list's end repeats a page: masked
+            keep = (valid_q & (pg * page + _rem(lane, page) < start)
+                    & (held < cnt_r[u] - pc * G))
+            if msk_refs:                # each token's own selection
+                lane128 = lax.broadcasted_iota(jnp.int32, (Cq, 128), 1)
+                sel = jnp.zeros((Cq, Kp), f32)
+                for j in range(G):
+                    blk = msk_refs[j][0, pl.ds(w, Cq), :]      # [Cq, 128]
+                    col = jnp.sum(
+                        jnp.where(lane128 == _rem(pages[j], 128), blk, 0.0),
+                        axis=1, keepdims=True)
+                    sel = jnp.where(held == j, col, sel)
+                keep = keep & (sel > 0.0)
+            flash_update(jnp.concatenate([k[0, 0, 0] for k in kp_refs], 0),
+                         jnp.concatenate([v[0, 0, 0] for v in vp_refs], 0),
+                         keep)
 
         # ---- self cell: intra-row causal attention + finalize --------
-        @pl.when(pc == maxp)
+        @pl.when(pc == NC)
         def _self_cell():
             krel = wk + lax.broadcasted_iota(jnp.int32, (1, Ck), 1) - off
-            qs, trel = queries()
-            valid_q = (trel >= 0) & (trel < nt)
             l_new, a_new = flash_update(
-                qs, kn_ref[g, pl.ds(wk, Ck), :], vn_ref[g, pl.ds(wk, Ck), :],
+                kn_ref[g, pl.ds(wk, Ck), :], vn_ref[g, pl.ds(wk, Ck), :],
                 valid_q & (krel >= 0) & (krel < nt) & (krel <= trel))
             o = a_new / jnp.maximum(l_new, 1e-30)
-            if Cq == 1:
-                out_ref[g, w] = jnp.where(valid_q, o, out_ref[g, w])
-                return
-            for h in range(QP):
-                at = (g, h, pl.ds(w, Cq))
-                out_ref[at] = jnp.where(valid_q[h * Cq:(h + 1) * Cq],
-                                        o[h * Cq:(h + 1) * Cq], out_ref[at])
+            at = (g, w) if Cq == 1 else (g, slice(None), pl.ds(w, Cq))
+            out_ref[at] = jnp.where(valid_q, o, out_ref[at])
 
 
-def _walk_call(q, k_new, v_new, tok_mask, k_pools, v_pools, rows, live_ci,
-               n_live, *, Cq: int):
-    """One call: the (row, KV head) units whose cells ``live_ci`` lists,
-    through a window of ``Cq`` tokens.  ``q`` is ``[KVH, T, QP, hd]``
-    where ``Cq == 1`` and ``[KVH, QP, T, hd]`` otherwise; the output has
-    its layout, float32, defined at the tokens of the rows walked."""
+def _walk_call(q, k_new, v_new, tok_mask, k_pools, v_pools, rows, lists, *,
+               Cq: int, G: int):
+    """One call: the units whose cells ``lists`` names (``walk_lists``),
+    through a window of ``Cq`` tokens, a pool cell ``G`` entries of the
+    unit's list (the pools are passed ``G`` times, a page each, and where
+    ``Cq > 1`` so is the mask, the block of 128 pages that holds each
+    entry's).  ``q`` is ``[KVH, T, QP, hd]`` where ``Cq == 1`` and ``[KVH,
+    QP, T, hd]`` otherwise; the output has its layout, float32, defined at
+    the tokens of the rows walked."""
     KVH, hd = q.shape[0], q.shape[-1]
     T, QP = (q.shape[1:3] if Cq == 1 else q.shape[2:0:-1])
     Pt, page = k_pools.shape[2:4]
     maxp = rows[4].shape[1]
-    prefetch = rows + [live_ci, n_live]
+    NC = -(-maxp // G)
+    ent, cnt, live_ci, n_live = lists
+    # the flash state, by head where a window holds more than one token
+    state = (QP,) if Cq == 1 else (QP, Cq)
+    prefetch = rows + [live_ci, n_live, cnt, ent]
 
     def whole(ndim):
         return lambda i, *pf: (0,) * ndim
 
-    def cell(i, live, nl):
-        return live[jnp.minimum(i, jnp.maximum(nl[0] - 1, 0))]
+    def entry(i, j, live, nl, *unit_lists):
+        ci = live[jnp.minimum(i, jnp.maximum(nl[0] - 1, 0))]
+        return _div(ci, NC + 1), _entry(ci, j, *unit_lists, G=G, NC=NC,
+                                        maxp=maxp)
 
-    def pool_map(i, slot_p, start_p, _ln, _of, bt, ly, live, nl):
-        ci = cell(i, live, nl)
-        u = ci // (maxp + 1)
-        r = u // KVH
-        # the self cell repeats the row's last page: no DMA for it
-        last = jnp.maximum(start_p[r] - 1, 0) // page
-        pe = jnp.minimum(jnp.minimum(ci % (maxp + 1), maxp - 1), last)
-        return (ly[0], u % KVH, jnp.minimum(bt[slot_p[r], pe], Pt - 1), 0, 0)
+    def pool_map(j):
+        def page_j(i, slot_p, _st, _ln, _of, bt, ly, *lists_p):
+            u, pe = entry(i, j, *lists_p)
+            return (ly[0], _rem(u, KVH),
+                    jnp.clip(bt[slot_p[_div(u, KVH)], pe], 0, Pt - 1), 0, 0)
+        return page_j
 
-    def mask_map(i, _s, _st, _ln, _of, _bt, _ly, live, nl):
-        ci = cell(i, live, nl)
-        return ((ci // (maxp + 1)) % KVH, 0,
-                jnp.minimum(ci % (maxp + 1), maxp - 1) // 128)
+    def mask_map(j):
+        def block_j(i, _s, _st, _ln, _of, _bt, _ly, *lists_p):
+            u, pe = entry(i, j, *lists_p)
+            return (_rem(u, KVH), 0, _div(pe, 128))
+        return block_j
 
     in_specs = [pl.BlockSpec(q.shape, whole(4)),
                 pl.BlockSpec(k_new.shape, whole(3)),
                 pl.BlockSpec(v_new.shape, whole(3))]
     operands = [q, k_new, v_new]
     if Cq > 1:
-        in_specs.append(pl.BlockSpec((1, T, 128), mask_map))
-        operands.append(tok_mask)
-    page_spec = pl.BlockSpec((1, 1, 1, page, hd), pool_map)
+        in_specs += [pl.BlockSpec((1, T, 128), mask_map(j)) for j in range(G)]
+        operands += [tok_mask] * G
+    in_specs += [pl.BlockSpec((1, 1, 1, page, hd), pool_map(j))
+                 for _pool in (k_pools, v_pools) for j in range(G)]
     interpret = platform.interpret_mode()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(live_ci.shape[0] if interpret else n_live[0],),
-        in_specs=in_specs + [page_spec, page_spec],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(q.shape, whole(4)),
-        scratch_shapes=[
-            pltpu.VMEM((QP * Cq, 1), jnp.float32),
-            pltpu.VMEM((QP * Cq, 1), jnp.float32),
-            pltpu.VMEM((QP * Cq, hd), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM(state + (n,), jnp.float32)
+                        for n in (1, 1, hd)],
     )
     kern = functools.partial(
-        _walk_kernel, T=T, Cq=Cq, KVH=KVH, QP=QP, hd=hd, page=page,
-        Pt=Pt, maxp=maxp, scale=hd ** -0.5)
+        _walk_kernel, T=T, Cq=Cq, KVH=KVH, QP=QP, hd=hd, page=page, G=G,
+        NC=NC, maxp=maxp, scale=hd ** -0.5)
     return pl.pallas_call(
         kern,
         name="block_sparse_walk",
@@ -569,7 +689,7 @@ def _walk_call(q, k_new, v_new, tok_mask, k_pools, v_pools, rows, live_ci,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(*prefetch, *operands, k_pools, v_pools)
+    )(*prefetch, *operands, *[k_pools] * G, *[v_pools] * G)
 
 
 def block_sparse_attention(
@@ -585,12 +705,28 @@ def block_sparse_attention(
     row_off: jax.Array,
     block_tables: jax.Array,  # [slots, maxp]
     tok_mask: jax.Array,     # [T, KVH, maxp] bool: token t attends page m
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Causal attention of a ragged token batch against the pages each
     token selected, ONE layer's pools.  Returns (out [T, H, hd] float32,
     zero where no row covers; pool pages read ``int32[2]``: by the rows
-    of one token, by the rows of more).  Two calls, chosen by
-    ``row_len``, as ``ragged_paged_attention``."""
+    of one token, by the rows of more; pool cells walked ``int32[2]``, the
+    same two).  The pages are the lists' entries: what a row of one token
+    selected, and for a row of more the union of its tokens' picks, no
+    page beside them.  Two calls, chosen by ``row_len``, as
+    ``ragged_paged_attention``."""
+    return _attention(
+        q, k_new, v_new, k_pools, v_pools, layer, row_slot, row_start,
+        row_len, row_off, block_tables, tok_mask,
+        per_cell=cell_pages(block_tables.shape[1]))
+
+
+# a function of its own under ``jit``, so that a step's sparse layers share
+# ONE trace and ONE lowering of the two kernels: with a page an operand, a
+# kernel's trace and its 2 or 3 x G index maps are set-up time a layer, and
+# a serving cell's set-up lowers six steps (PERF.md PR 42)
+@functools.partial(jax.jit, static_argnames=("per_cell",))
+def _attention(q, k_new, v_new, k_pools, v_pools, layer, row_slot, row_start,
+               row_len, row_off, block_tables, tok_mask, *, per_cell: int):
     T, H, hd = q.shape
     KVH, page = k_pools.shape[1], k_pools.shape[3]
     maxp = block_tables.shape[1]
@@ -612,23 +748,25 @@ def block_sparse_attention(
     t = jnp.arange(T_p, dtype=i32)[:, None] - row_off[None, :]
     in_row = (t >= 0) & (t < row_len[None, :])                 # [T_p, R]
     out = jnp.zeros((T_p, KVH, QP, hd), jnp.float32)
-    pages = []
+    pages, cells = [], []
     Cq = window_size(T_p, None)
     for cq, takes in ((1, row_len == 1), (Cq, row_len > 1)):
-        live_ci, n_live, n_pool = walk_cells(mask, in_row, row_start, takes,
-                                             maxp, page)
-        pages.append(n_pool)
+        *lists, n_pages, n_cells = walk_lists(mask, in_row, row_start, takes,
+                                              maxp, page, per_cell)
+        pages.append(n_pages)
+        cells.append(n_cells)
         if cq == 1:
             got = _walk_call(qg.transpose(1, 0, 2, 3), k_new, v_new, None,
-                             k_pools, v_pools, rows, live_ci, n_live,
-                             Cq=1).transpose(1, 0, 2, 3)
+                             k_pools, v_pools, rows, lists, Cq=1,
+                             G=per_cell).transpose(1, 0, 2, 3)
         else:
             got = _walk_call(qg.transpose(1, 2, 0, 3), k_new, v_new, mask,
-                             k_pools, v_pools, rows, live_ci, n_live,
-                             Cq=cq).transpose(2, 0, 1, 3)
+                             k_pools, v_pools, rows, lists, Cq=cq,
+                             G=per_cell).transpose(2, 0, 1, 3)
         mine = jnp.any(in_row & takes[None, :], axis=1)
         out = jnp.where(mine[:, None, None, None], got, out)
-    return out[:T, :, :G].reshape(T, H, hd), jnp.stack(pages)
+    return (out[:T, :, :G].reshape(T, H, hd), jnp.stack(pages),
+            jnp.stack(cells))
 
 
 def block_sparse_attention_reference(q, k_new, v_new, k_pages, v_pages,

@@ -787,7 +787,8 @@ def sala_paged_adapter(cfg) -> PagedEngineAdapter:
     """MiniCPM-SALA (models/minicpm_sala.py): lightning layers with a
     matrix state by slot (``lin_s``) beside the sparse layers' page pools
     (``k``/``v`` and ``kh``, their compressed keys, under the same block
-    tables) and a counter of the pages the walk read.  As for
+    tables) and two counters, of the pages the walk read and of the
+    cells it read them in.  As for
     ``jamba_paged_adapter`` the state is why the engine refuses the
     prefix cache, speculative decoding and migration; its step takes
     neither ``lora=`` nor ``logit_idx=``."""
@@ -827,7 +828,7 @@ def sala_paged_adapter(cfg) -> PagedEngineAdapter:
         max_row_tokens=cfg.sparse.max_row_tokens,
         state_bytes_per_slot=cfg.state_bytes_per_slot(),
         state_leaves=("lin_s",),
-        counter_leaves=("sel_pages",),
+        counter_leaves=("sel_pages", "walk_cells"),
     )
 
 

@@ -5,6 +5,7 @@ switch by the query's position, page lists that differ by KV head, and
 the engine over pages and a matrix state side by side."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -138,7 +139,7 @@ def test_walk_takes_a_page_list_by_kv_head(layer):
     mask = jax.random.bernoulli(ks[5], 0.5, (T, KVH, maxp)).at[:, :, 0].set(
         True)
     assert bool(jnp.any(mask[:, 0] != mask[:, 1]))
-    o, pages = jax.jit(bsa.block_sparse_attention)(
+    o, pages, _cells = jax.jit(bsa.block_sparse_attention)(
         q, kn, vn, kp, vp, layer, *rows, bt, mask)
     want = jax.jit(bsa.block_sparse_attention_reference)(
         q, kn, vn, kp[layer], vp[layer], *rows, bt, mask)
@@ -148,6 +149,127 @@ def test_walk_takes_a_page_list_by_kv_head(layer):
     read = sum(int(m[t, g, :-(-start // PAGE)].sum())
                for t, start in ((0, 37), (20, 9)) for g in range(KVH))
     assert int(pages[0]) == read
+
+
+@functools.partial(jax.jit, static_argnames=("cell_pages", "lists"))
+def _walk(q, kn, vn, kp, vp, slot, start, nlen, off, bt, mask, *, cell_pages,
+          lists=None):
+    """``block_sparse_attention`` in layer 1 of the pools, a pool cell
+    ``cell_pages`` pages (and ``lists`` in ``walk_lists``' place) while it is
+    traced; traced as the model's step traces it."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(bsa, "CELL_PAGES", cell_pages)
+        if lists is not None:
+            # past the op's own jit, which holds the real lists' trace
+            patch.setattr(bsa, "walk_lists", lists)
+            patch.setattr(bsa, "_attention", bsa._attention.__wrapped__)
+        return bsa.block_sparse_attention(q, kn, vn, kp, vp, 1, slot, start,
+                                          nlen, off, bt, mask)
+
+
+# rows of one token and chunks side by side; in "long" the block tables
+# are wider than one 128-page block of the mask and two rows reach past it
+WALK_ROWS = {
+    "short": (12, ROWS),
+    "long": (136, [(0, 1050, 1, 0), (2, 1043, 17, 3), (1, 9, 1, 20),
+                   (3, 0, 12, 22), (0, 0, 0, 0)]),
+}
+
+
+@functools.cache
+def _walk_case(tables):
+    """Inputs whose selection differs between the two KV heads, and the
+    plain reference's answer."""
+    maxp, rows = WALK_ROWS[tables]
+    T, H, KVH, hd, slots = 40, 8, 2, 16, 4
+    ks = jax.random.split(jax.random.key(1), 6)
+    q = jax.random.normal(ks[0], (T, H, hd))
+    kn, vn = (jax.random.normal(ks[i], (T, KVH, hd)) for i in (1, 2))
+    P = slots * maxp
+    kp, vp = (jax.random.normal(ks[i], (2, KVH, P + 1, PAGE, hd))
+              for i in (3, 4))
+    bt = jnp.asarray(np.random.default_rng(0).permutation(P).reshape(
+        slots, maxp), jnp.int32)
+    # a token of a chunk picks few pages, so that their union has gaps
+    few = np.zeros(T, bool)
+    for _slot, _start, n, off in rows:
+        few[off:off + n] = n > 1
+    mask = (jax.random.uniform(ks[5], (T, KVH, maxp))
+            < jnp.where(few, 0.12, 0.5)[:, None, None]).at[:, :, 0].set(True)
+    args = (q, kn, vn, kp, vp, *_rows(rows), bt, mask)
+    want = jax.jit(bsa.block_sparse_attention_reference)(
+        q, kn, vn, kp[1], vp[1], *args[5:])
+    return args, np.asarray(want)
+
+
+def _unit_pages(tables):
+    """The pooled pages each (row, KV head) lists, on the host: ``{(row,
+    KV head): pages}`` for the rows of one token and for the others."""
+    maxp, rows = WALK_ROWS[tables]
+    mask = np.asarray(_walk_case(tables)[0][-1])
+    one, more = {}, {}
+    for r, (_slot, start, n, off) in enumerate(rows):
+        if n == 0:
+            continue
+        pooled = np.arange(maxp) * PAGE < start
+        for g in range(mask.shape[1]):
+            picked = mask[off:off + n, g].any(axis=0) & pooled
+            (one if n == 1 else more)[r, g] = np.flatnonzero(picked)
+    return one, more
+
+
+@pytest.mark.parametrize("tables,pages_a_cell", [
+    ("short", 1), ("short", 2), ("short", 4), ("short", 8), ("long", 4),
+    ("long", 8)])
+def test_walk_cells_of_several_pages_match_the_reference(tables,
+                                                         pages_a_cell):
+    """Both calls against the dense gather where a (row, KV head)'s list
+    is no multiple of the cell, where it is shorter than one cell, where
+    the two KV heads of a row selected different pages and where a
+    chunk's cell takes pages from both sides of a 128-page mask block;
+    the pages counted are the lists', the cells ``ceil(pages / G)`` a
+    (row, KV head)."""
+    G = pages_a_cell
+    args, want = _walk_case(tables)
+    one, more = _unit_pages(tables)
+    lists = list(one.values()) + list(more.values())
+    if G > 1:
+        assert any(len(pages) % G for pages in lists)
+    if G >= 4:
+        assert any(0 < len(pages) < G for pages in lists)
+    assert any(set(one[r, 0]) != set(one[r, 1]) for r, _ in one)
+    assert any(set(more[r, 0]) != set(more[r, 1]) for r, _ in more)
+    if tables == "long":
+        # a cell of a chunk's list holds pages below 128 and past it
+        assert any((pages < 128).sum() % G and (pages >= 128).any()
+                   for pages in more.values())
+    o, pages, cells = _walk(*args, cell_pages=G)
+    np.testing.assert_allclose(o, want, rtol=1e-4, atol=1e-5)
+    assert pages.tolist() == [sum(map(len, one.values())),
+                              sum(map(len, more.values()))]
+    assert cells.tolist() == [sum(-(-len(p) // G) for p in side.values())
+                              for side in (one, more)]
+
+
+def test_a_tail_entry_left_unmasked_is_caught():
+    """The planted fault: a list's tail short of a cell, which repeats the
+    page a cell before it, counted as entries.  The page is attended
+    twice and the comparison above refuses it."""
+    G = 4
+    real = bsa.walk_lists
+
+    def unmasked_tail(*a):
+        ent, cnt, *rest = real(*a)
+        maxp = ent.shape[0] // cnt.shape[0]
+        e = jnp.arange(maxp)[None, :]
+        src = jnp.where(e < cnt[:, None], e, jnp.maximum(e - G, 0))
+        ent = jnp.take_along_axis(ent.reshape(-1, maxp), src, axis=1)
+        return (ent.reshape(-1), -(-cnt // G) * G, *rest)
+
+    args, want = _walk_case("short")
+    o, _, _ = _walk(*args, cell_pages=G, lists=unmasked_tail)
+    rel = np.abs(np.asarray(o) - want).max() / np.abs(want).max()
+    assert rel > 1e-2, rel
 
 
 def test_the_switch_at_dense_len_is_by_the_querys_position():
@@ -250,6 +372,47 @@ def test_device_counter_is_the_host_count_for_rows_of_one_token():
         assert bsa.walk_page_count([start], [1], 1, SP, PAGE) <= SP.topk
     after = np.asarray(cache["sel_pages"])
     assert after[0] - before[0] == want and after[1] == before[1]
+
+
+def test_device_counts_the_cells_of_rows_of_one_token():
+    """``walk_cells[0]`` is ``ceil(pages / G)`` a (row, KV head) and layer
+    where ``sel_pages[0]`` is the pages: three pages in cells of two are
+    two cells."""
+    G = 2
+    cfg = dataclasses.replace(CFG, mixer_types=(L, S))
+    params = sala.init_params(jax.random.key(0), cfg)
+    maxp, slots = 16, 4
+    cache = sala.init_cache(cfg, slots * maxp, PAGE, slots)
+    table = np.arange(slots * maxp, dtype=np.int32).reshape(slots, maxp)
+
+    @jax.jit
+    def step(*a):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(bsa, "CELL_PAGES", G)
+            return sala.ragged_step(*a[:8], cfg, a[8])
+
+    from ray_tpu.ops.ragged_paged_attention import pack_ragged_batch
+
+    toks = np.random.default_rng(0).integers(1, 97, 50).tolist()
+    for start in range(0, 40, 8):
+        packed = pack_ragged_batch(
+            [{"slot": 1, "start": start, "tokens": toks[start:start + 8]}],
+            16, slots)
+        _, cache = step(params, packed[0], *packed[3:], table, cache)
+    assert cache["walk_cells"][0] == 0 and cache["walk_cells"][1] > 0
+    pages = cells = 0
+    for start in range(40, 44):
+        packed = pack_ragged_batch(
+            [{"slot": 1, "start": start, "tokens": toks[start:start + 1]}],
+            8, slots)
+        _, cache = step(params, packed[0], *packed[3:], table, cache)
+        n = bsa.walk_page_count([start], [1], 1, SP, PAGE)
+        pages += cfg.n_kv_heads * n
+        cells += cfg.n_kv_heads * -(-n // G)
+    # position 40 opens a block: its three pages are two cells
+    assert bsa.walk_page_count([40], [1], 1, SP, PAGE) == 3
+    assert int(cache["sel_pages"][0]) == pages
+    assert int(cache["walk_cells"][0]) == cells == 16
 
 
 def _engine_config(**kw):

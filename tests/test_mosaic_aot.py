@@ -949,7 +949,8 @@ def test_sala_cell_step_copies_no_pool_state_or_weight_stack(v5e, shape):
         lambda: sala.init_params(jax.random.key(0), cfg)))
     cache = _on(mesh, jax.eval_shape(
         lambda: sala.init_cache(cfg, slots * maxp, page, slots)))
-    assert set(cache) == {"k", "v", "kh", "lin_s", "sel_pages"}
+    assert set(cache) == {"k", "v", "kh", "lin_s", "sel_pages",
+                          "walk_cells"}
     toks, rows, bt = _on(mesh, (
         _sds(T, dtype=jnp.int32), _sds(slots, dtype=jnp.int32),
         _sds(slots, maxp, dtype=jnp.int32)))
@@ -1009,10 +1010,14 @@ def test_sala_served_check_fits_beside_the_engine(v5e, piece):
 def test_block_sparse_walk_compiles(v5e):
     """The walk alone at the cell's shapes: rows of one token through
     their own pages, a chunk's rows through the context once under the
-    selection as a mask."""
+    selection as a mask; a pool cell eight entries of a (row, KV head)'s
+    list, so both pools eight times and, for the chunk, the mask's
+    blocks of the eight (the chunk call's ``[8320, 512]`` float32 tiles
+    beside its state, queries and output have to fit the chip's VMEM)."""
     from ray_tpu.ops import block_sparse_attention as bsa
 
     T, H, KVH, hd, maxp, slots = 520, 32, 2, 128, 1040, 8
+    assert bsa.cell_pages(maxp) == bsa.CELL_PAGES == 8
     mesh = _one(v5e)
     pool = _sds(4, KVH, slots * maxp + 1, PAGE, hd)
     args = _on(mesh, (
@@ -1020,5 +1025,9 @@ def test_block_sparse_walk_compiles(v5e):
         _sds(dtype=jnp.int32), *[_sds(slots, dtype=jnp.int32)] * 4,
         _sds(slots, maxp, dtype=jnp.int32),
         _sds(T, KVH, maxp, dtype=jnp.bool_)))
-    compiled = _compile(bsa.block_sparse_attention, *args)
-    assert "block_sparse_walk" in compiled.as_text()
+    text = _compile(bsa.block_sparse_attention, *args).as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "block_sparse_walk" in ln]
+    # two calls; neither copies a pool to pass it sixteen times
+    assert len(calls) == 2
+    assert not re.search(r"bf16\[4,2,8321,64,128\]\S* copy\(", text)
