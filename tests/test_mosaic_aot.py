@@ -120,12 +120,16 @@ def _serve_setup(bench, v5e, name, kv_int8):
 
 # -- kernels, one by one ----------------------------------------------------
 
-def test_flash_forward_and_backward(v5e):
+@pytest.mark.parametrize("B,S,H,KVH", [
+    (8, 2048, 8, 4),
+    (4, 4096, 16, 8),    # internlm2_1b8-pretrain_4k: 4 MB of dq in VMEM
+])
+def test_flash_forward_and_backward(v5e, B, S, H, KVH):
     from ray_tpu.ops.flash_attention import flash_attention
 
     mesh = _one(v5e)
-    q = _on(mesh, _sds(8, 2048, 8, 128))
-    kv = _on(mesh, _sds(8, 2048, 4, 128))
+    q = _on(mesh, _sds(B, S, H, 128))
+    kv = _on(mesh, _sds(B, S, KVH, 128))
     _compile(flash_attention, q, kv, kv)
     _compile(jax.grad(
         lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
