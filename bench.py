@@ -1443,81 +1443,6 @@ def _measure_serving_multihost(cfg, *, shard_counts=(1, 2, 4),
     return {"ladder": ladder}
 
 
-def _measure_ssd(B=4, S=4096, H=8, P=64, N=128, chunk=128,
-                 iters=64) -> dict:
-    """Fused Pallas SSD kernel vs the einsum+associative_scan path
-    (models/mamba2.ssd_chunked), same inputs, forward pass.
-
-    DEVICE time, not wall time: all ``iters`` iterations chain inside
-    ONE jitted ``lax.scan`` (each feeds a damped mix of its output
-    back into the next input, so XLA can neither hoist nor DCE the
-    body), which amortizes per-dispatch overhead to <1/iters of the
-    measurement — host contention can no longer mask kernel
-    differences (round-4 verdict weak #2)."""
-    from ray_tpu.models.mamba2 import ssd_chunked
-    from ray_tpu.ops.mamba_ssd import ssd_pallas
-
-    key = jax.random.key(0)
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    x = jax.random.normal(k1, (B, S, H, P), jnp.float32)
-    la = -jax.nn.softplus(jax.random.normal(k2, (B, S, H)))
-    Bm = jax.random.normal(k3, (B, S, N), jnp.float32) * 0.3
-    Cm = jax.random.normal(k4, (B, S, N), jnp.float32) * 0.3
-
-    def compiled(fn):
-        def many(x0):
-            def body(carry, _):
-                out = fn(carry, la, Bm, Cm)
-                # Damped feedback: a REAL data dependency between
-                # iterations at the same input statistics.
-                return 0.9 * carry + 0.1 * out, ()
-
-            final, _ = jax.lax.scan(body, x0, None, length=iters)
-            return final
-
-        f = jax.jit(many)
-        out = f(x)
-        float(jax.device_get(out[0, 0, 0, 0]))  # compile + fence
-        return f
-
-    def timed_once(f):
-        t0 = time.perf_counter()
-        out = f(x)
-        float(jax.device_get(out[0, 0, 0, 0]))
-        return (time.perf_counter() - t0) / iters
-
-    f_scan = compiled(lambda *a: ssd_chunked(*a, chunk=chunk))
-    f_pallas = compiled(lambda *a: ssd_pallas(*a, chunk))
-    # A shared chip's effective speed can drift on minute timescales
-    # (common mode: both paths swing together).  INTERLEAVE the two
-    # paths' timed calls and take per-path medians so the ratio
-    # samples the same windows — a ratio from two disjoint windows can
-    # be off 40% in either direction.
-    reps_s, reps_p = [], []
-    for _ in range(5):
-        reps_s.append(timed_once(f_scan))
-        reps_p.append(timed_once(f_pallas))
-    t_scan = float(np.median(reps_s))
-    t_pallas = float(np.median(reps_p))
-    # On-chip correctness ride-along: interpret-mode CPU tests can't
-    # catch a hardware-only Mosaic miscompile of the flattened layout.
-    out_scan = jax.jit(lambda *a: ssd_chunked(*a, chunk=chunk))(
-        x, la, Bm, Cm)
-    out_pallas = jax.jit(lambda *a: ssd_pallas(*a, chunk))(
-        x, la, Bm, Cm)
-    max_diff = float(jnp.max(jnp.abs(out_scan - out_pallas)))
-    tok_s = B * S / t_pallas
-    return {
-        "shape": f"B{B} S{S} H{H} P{P} N{N} chunk{chunk}",
-        "assoc_scan_ms": round(t_scan * 1e3, 2),
-        "pallas_ms": round(t_pallas * 1e3, 2),
-        "speedup": round(t_scan / t_pallas, 2),
-        "pallas_tokens_per_s": round(tok_s, 0),
-        "max_abs_diff_vs_reference": max_diff,
-        "timing": "device (iters chained in one jitted scan)",
-    }
-
-
 def _require_tpu():
     """The devices this benchmark measures.  It measures a TPU; on any
     other platform it fails instead of timing something nobody
@@ -1620,12 +1545,6 @@ def main():
     _leg(extra, "serving_1b_mixed", _measure_serving_mixed,
          dataclasses.replace(BENCH_1B_CFG, max_seq_len=2048),
          n_requests=48, slots=32, arrival_rate=6.0)
-    # BASELINE.json config-matrix: Pallas SSD kernel vs the
-    # associative_scan/einsum path, measured on-chip.  Runs BEFORE the
-    # 8B leg: after 8+ GB of weights churn through HBM the chip measures
-    # both paths slower and noisier (observed 1.21x post-8B vs 1.60x on
-    # a fresh chip).
-    _leg(extra, "mamba_ssd", _measure_ssd)
     # North star #3: the 8B artifact — int8 serving (measured) + the
     # full-8B train rung (measured where the hardware holds it).
     _leg(extra, "llama_8b", _measure_8b, peak)

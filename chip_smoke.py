@@ -255,7 +255,6 @@ class KernelDims:
     flash_seq: int = 1024
     flash_heads: int = 8
     flash_kv_heads: int = 4
-    ssd: tuple = (2, 1024, 8, 64, 128, 128)     # B, S, H, P, N, chunk
     fused_cfg: object = None     # None: llama3_8b_int8 cut to 2 layers
 
 
@@ -515,27 +514,6 @@ def _kernels_ragged(d: KernelDims) -> None:
                 jnp.take(before, kept, axis=axis), 0.0)
 
 
-def _kernels_ssd(d: KernelDims) -> None:
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.mamba2 import ssd_chunked
-    from ray_tpu.ops.mamba_ssd import ssd_pallas
-
-    B, S, H, P, N, chunk = d.ssd
-    ks = jax.random.split(jax.random.key(3), 4)
-    x = _rand(ks[0], (B, S, H, P), jnp.float32)
-    la = -jax.nn.softplus(_rand(ks[1], (B, S, H), jnp.float32))
-    Bm = _rand(ks[2], (B, S, N), jnp.float32) * 0.3
-    Cm = _rand(ks[3], (B, S, N), jnp.float32) * 0.3
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda *a: ssd_chunked(*a, chunk=chunk))(
-            x, la, Bm, Cm)
-    check_close("ssd_pallas",
-                jax.jit(lambda *a: ssd_pallas(*a, chunk))(x, la, Bm, Cm),
-                want, 2e-2)
-
-
 def _kernels_fused(d: KernelDims) -> None:
     """The fused per-layer megakernels against the unfused path: one
     decode step and one ragged step over the same random int8 cache."""
@@ -683,7 +661,6 @@ def phase_kernels(platform: str, *, dims: KernelDims = KernelDims()) -> dict:
     _kernels_flash(dims, platform)
     _kernels_paged(dims)
     _kernels_ragged(dims)
-    _kernels_ssd(dims)
     _kernels_fused(dims)
     _kernels_lightning(dims)
     _kernels_block_sparse(dims)

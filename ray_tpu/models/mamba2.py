@@ -40,10 +40,6 @@ class Mamba2Config:
     n_heads: int = 80          # head_dim = dim * expand / n_heads
     conv_kernel: int = 4
     chunk: int = 64            # SSD chunk length
-    # Fused Pallas SSD kernel (ops/mamba_ssd.py): chunk state stays in
-    # VMEM across the sequential chunk walk instead of materializing
-    # per-chunk states + decay masks in HBM for associative_scan.
-    use_pallas_ssd: bool = False
     # Jamba-style hybrid: every k-th layer is attention (0 = pure SSM).
     attn_every: int = 0
     n_attn_heads: int = 20
@@ -282,16 +278,9 @@ def _mamba_block(x: jax.Array, layer: Params, cfg: Mamba2Config) -> jax.Array:
     log_a = a * dt                                        # [B,S,H], <= 0
 
     xh = xin.reshape(Bsz, S, H, P)
-    if cfg.use_pallas_ssd:
-        from ray_tpu.ops.mamba_ssd import ssd_pallas
-
-        y = ssd_pallas(
-            xh.astype(dt_f32) * dt[..., None], log_a, Bm, Cm, cfg.chunk
-        )
-    else:
-        y = ssd_chunked(
-            xh.astype(dt_f32) * dt[..., None], log_a, Bm, Cm, cfg.chunk
-        )
+    y = ssd_chunked(
+        xh.astype(dt_f32) * dt[..., None], log_a, Bm, Cm, cfg.chunk
+    )
     y = y + layer["d_skip"].astype(dt_f32)[None, None, :, None] \
         * xh.astype(dt_f32)
     y = y.reshape(Bsz, S, di).astype(cfg.dtype)

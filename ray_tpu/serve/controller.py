@@ -1166,8 +1166,13 @@ class ServeController:
                     api.get(r.creation_ref)
                     r.state = "RUNNING"
                     self._maybe_warm_start(st, r)
-                except Exception:
-                    r.state = "STOPPING"  # constructor failed → replace
+                except Exception as err:
+                    # constructor failed → replace; say why, or a
+                    # deployment whose every replica fails to build sits
+                    # in UPDATING with nothing to read
+                    log.warning("replica %s failed to start (%r): "
+                                "replacing it", r.replica_id, err)
+                    r.state = "STOPPING"
 
     def _maybe_warm_start(self, st: _DeploymentState, r: _Replica) -> None:
         """A freshly RUNNING replica of an autoscaled deployment starts

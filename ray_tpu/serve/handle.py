@@ -283,7 +283,12 @@ class DeploymentResponseGenerator:
             self.request_id, prompt_tokens=len(first.get("tokens", ())),
             adapter_id=first.get("adapter_id", ""))
         attempt = 0
-        dead: set = set()
+        # Replicas this request is never sent to again: one that died,
+        # and one that preempted it (a draining replica refuses every
+        # later attempt too, and each refusal would be charged to the
+        # retry budget).  With every replica tried the router holds the
+        # request for the replacement, under the same deadline.
+        tried: set = set()
         rng = random.Random(self.request_id)
         backoff = 0.05
         while True:
@@ -297,7 +302,7 @@ class DeploymentResponseGenerator:
                                   else min(assign_timeout, left))
             gen, replica_id, _ = self._router.assign_streaming(
                 self._method_name, call_args, self._kwargs,
-                timeout=assign_timeout, exclude=dead,
+                timeout=assign_timeout, exclude=tried,
                 model_id=self._model_id, request_id=self.request_id,
                 prefer_replica=self._prefer_replica)
             try:
@@ -354,15 +359,16 @@ class DeploymentResponseGenerator:
                         cause=type(err).__name__,
                         generated_tokens=len(self._delivered))
                     raise
-                if died:
-                    dead.add(replica_id)
+                tried.add(replica_id)
                 attempt += 1
                 self._router.note_retry(self.request_id, attempt,
                                         replica_id,
                                         reason=type(err).__name__)
                 # Half-fixed + half-jitter (see DeploymentResponse
-                # .result): spacing never collapses to ~0, so a bounced
-                # request outlasts its replacement replica's startup.
+                # .result): spacing never collapses to ~0.  What makes a
+                # bounced request outlast its replacement's start-up is
+                # ``tried``: the router holds it until a replica it has
+                # not tried is routable.
                 delay = backoff / 2.0 + rng.uniform(0.0, backoff / 2.0)
                 backoff = min(backoff * 2.0, 1.0)
                 if deadline is not None:

@@ -18,6 +18,13 @@ from typing import List, Optional
 
 from ray_tpu.core.exceptions import PreemptedError
 
+# How long a serving test waits for a replica to be RUNNING or routable.
+# A replica is a process that imports JAX and compiles its engine:
+# seconds alone, minutes beside five other test workers.  A wait ends
+# when the controller or the route table shows the event, so the bound
+# only has to be past anything a loaded machine takes.
+REPLICA_READY_S = 300.0
+
 
 class NodeKiller:
     """Kills a random non-head alive node every ``interval_s`` until

@@ -57,7 +57,8 @@ from ray_tpu.serve.llm_engine import (
     LLMServer,
     llama_paged_adapter,
 )
-from ray_tpu.utils.test_utils import ReplicaKiller
+from ray_tpu.utils.test_utils import REPLICA_READY_S, ReplicaKiller
+from tests import oracle
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -68,8 +69,9 @@ CFG = llama.LlamaConfig(
 DEP = "LLMServer"
 
 # 12 new tokens keeps every resumed continuation's re-prefill (prompt
-# + delivered prefix <= 15 tokens) inside the 16-token prefill bucket,
-# the one the recompute oracle is exact against for this tiny config.
+# + delivered prefix <= 15 tokens) inside the 16-token prefill bucket.
+# The model is fp32, where the recompute oracle's argmax is every
+# program's argmax (tests/oracle.py).
 N_STREAMS = 8
 N_NEW = 12
 PROMPTS = [[i + 1, i + 2, i + 3] for i in range(N_STREAMS)]
@@ -87,21 +89,10 @@ def params():
     return llama.init_params(jax.random.key(0), CFG)
 
 
-def _greedy_reference(params, prompt, n_tokens):
-    toks = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = llama.forward(params, jnp.asarray([toks]), CFG)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
-
-
 @pytest.fixture(scope="module")
 def references(params):
     """Oracle token sequences: greedy decoding by full-prefix recompute."""
-    return [_greedy_reference(params, p, N_NEW) for p in PROMPTS]
+    return [oracle.greedy_tokens(params, CFG, p, N_NEW) for p in PROMPTS]
 
 
 def _slow_paged_adapter_factory(cfg):
@@ -209,7 +200,8 @@ def _serve_autoscaled(params, app_name, **auto_kw):
         autoscaling_config=auto,
     )(LLMServer).bind(CFG, ENG, lambda: params,
                       adapter_factory=_slow_paged_adapter_factory)
-    return serve.run(app, name=app_name, route_prefix=None)
+    return serve.run(app, name=app_name, route_prefix=None,
+                     timeout_s=REPLICA_READY_S)
 
 
 def _launch_stream(shandle, prompt_idx, recs, n_new=N_NEW,
@@ -285,7 +277,8 @@ def shed_app(params):
         lambda: params,
         adapter_factory=_slow_adapter_factory,
     )
-    handle = serve.run(app, name="shed", route_prefix=None)
+    handle = serve.run(app, name="shed", route_prefix=None,
+                       timeout_s=REPLICA_READY_S)
     yield handle
     serve.shutdown()
     ray_tpu.shutdown()
@@ -316,8 +309,11 @@ def test_chaos_scale_up_kill_drain_down_byte_exact(chaos_app,
     kills = 0
     max_groups = 0
     # Ramp: each wave lands before the last drains, so ongoing count
-    # and admission-queue age climb and the reconciler scales up.
-    for wave in range(16):
+    # and admission-queue age climb and the reconciler scales up.  The
+    # waves go on until the second group is live and one is killed.
+    wave = 0
+    deadline = time.monotonic() + REPLICA_READY_S
+    while time.monotonic() < deadline:
         for i in range(N_STREAMS):
             _launch_stream(shandle, i, recs)
         time.sleep(0.4)
@@ -330,6 +326,7 @@ def test_chaos_scale_up_kill_drain_down_byte_exact(chaos_app,
                 kills += 1
         if kills and wave >= 2:
             break
+        wave += 1
     assert kills == 1, \
         f"fleet never reached 2 live groups to kill one (max {max_groups})"
     assert max_groups >= 2, f"never scaled up: max {max_groups} group(s)"
@@ -363,7 +360,7 @@ def test_chaos_scale_up_kill_drain_down_byte_exact(chaos_app,
     # (1, 0) is a state on the way: the group left standing may be the
     # killed replica's replacement, still starting.
     assert _wait(lambda: downs() > downs0 and _groups("chaos") == (1, 1),
-                 timeout_s=120), \
+                 timeout_s=REPLICA_READY_S), \
         "fleet never drained back down to one group after the ramp"
     assert downs() >= downs0 + 1, "no scale-down decision after ramp"
     assert _wait(lambda: _metric("raytpu_serve_replica_drains_total")
@@ -459,21 +456,21 @@ def test_policy_scale_down_drains_without_capacity_dip(scdn_app,
     # Sustain load until the second group is actually routable.
     recs = []
     scaled = False
-    for wave in range(16):
+    deadline = time.monotonic() + REPLICA_READY_S
+    while not scaled and time.monotonic() < deadline:
         for i in range(N_STREAMS):
             _launch_stream(shandle, i, recs)
         time.sleep(0.3)
         with router._lock:
             scaled = len(router._replicas) >= 2
-        if scaled:
-            break
     assert scaled, "never scaled up to 2 routable groups"
 
     # Two trailing long streams ride the drain window: 24 throttled
     # steps outlive the 0.3 s downscale delay, so the down decision
     # lands while they are mid-decode on the shrinking fleet.
     long_prompts = [[101, 102, 103], [111, 112, 113]]
-    long_refs = [_greedy_reference(params, p, 24) for p in long_prompts]
+    long_refs = [oracle.greedy_tokens(params, CFG, p, 24)
+                 for p in long_prompts]
     tails = []
     for k, p in enumerate(long_prompts):
         _launch_stream(shandle, k, tails, n_new=24, prompt=p)

@@ -720,7 +720,6 @@ def _stubbed_bench(monkeypatch):
             ("_measure_serving", _serving),
             ("_measure_serving_mixed",
              lambda: _mixed_record()["extra"]["serving_mixed"]),
-            ("_measure_ssd", lambda: {"speedup": 1.0}),
             ("_measure_8b",
              lambda: {"params_b": 8.03,
                       "train": {**_zero_train(), "optimizer": "adamw8bit"}}),
@@ -754,13 +753,13 @@ def test_bench_main_exits_nonzero_when_a_leg_errors(tmp_path, monkeypatch,
     def boom(*a, **k):
         raise RuntimeError("Mosaic refused")
 
-    monkeypatch.setattr(bench, "_measure_ssd", boom)
+    monkeypatch.setattr(bench, "_measure_8b", boom)
     monkeypatch.chdir(tmp_path)
     with pytest.raises(SystemExit) as exc:
         bench.main()
     assert exc.value.code == 1
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "Mosaic refused" in rec["extra"]["mamba_ssd"]["error"]
+    assert "Mosaic refused" in rec["extra"]["llama_8b"]["error"]
     assert rec["extra"]["serving"]["knee_req_s"] == 2.0
 
 

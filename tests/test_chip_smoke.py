@@ -22,7 +22,6 @@ TINY = llama.LlamaConfig(
 DIMS = chip_smoke.KernelDims(
     heads=4, kv_heads=2, head_dim=16, page=16, slots=4, maxp=4, layers=2,
     flash_batch=1, flash_seq=256, flash_heads=4, flash_kv_heads=2,
-    ssd=(1, 128, 2, 16, 16, 64),
     fused_cfg=dataclasses.replace(
         TINY, dim=128, n_heads=2, n_kv_heads=1, mlp_dim=256,
         max_seq_len=64, kv_int8=True))
@@ -35,8 +34,7 @@ def _clean_env():
     return env
 
 
-@pytest.mark.parametrize("part", ["flash", "paged", "ragged", "ssd",
-                                  "fused"])
+@pytest.mark.parametrize("part", ["flash", "paged", "ragged", "fused"])
 def test_kernels_phase_parts(part):
     fn = getattr(chip_smoke, f"_kernels_{part}")
     fn(DIMS, "cpu") if part == "flash" else fn(DIMS)

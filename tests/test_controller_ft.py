@@ -79,6 +79,7 @@ from ray_tpu.serve.deployment import DeploymentInfo
 from ray_tpu.serve.llm_engine import EngineConfig, LLMServer
 from ray_tpu.serve.long_poll import LongPollHost
 from ray_tpu.utils.test_utils import ReplicaKiller, kill_actor_hard
+from tests import oracle
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -106,14 +107,7 @@ def params():
 
 
 def _greedy_reference(params, prompt, n_tokens):
-    toks = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = llama.forward(params, jnp.asarray([toks]), CFG)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+    return oracle.greedy_tokens(params, CFG, prompt, n_tokens)
 
 
 @pytest.fixture(scope="module")

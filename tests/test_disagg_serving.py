@@ -38,6 +38,8 @@ from ray_tpu.serve.llm_engine import (
     LLMServer,
     llama_paged_adapter,
 )
+from ray_tpu.utils.test_utils import REPLICA_READY_S
+from tests import oracle
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -54,14 +56,7 @@ def params():
 
 
 def greedy_reference(params, prompt, n_tokens):
-    toks = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = llama.forward(params, jnp.asarray([toks]), CFG)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+    return oracle.greedy_tokens(params, CFG, prompt, n_tokens)
 
 
 def _engine(params, **kw):
@@ -303,7 +298,8 @@ def _serve_app(params, *, disagg, adapter_factory=llama_paged_adapter):
         lambda: params,
         adapter_factory=adapter_factory,
     )
-    return serve.run(app, name=APP, route_prefix=None)
+    return serve.run(app, name=APP, route_prefix=None,
+                     timeout_s=REPLICA_READY_S)
 
 
 def _wait_roles():
@@ -311,7 +307,7 @@ def _wait_roles():
     decode replica; returns {role: replica_id}."""
     from ray_tpu.util import state
 
-    deadline = time.monotonic() + 120
+    deadline = time.monotonic() + REPLICA_READY_S
     while time.monotonic() < deadline:
         rows = state.list_replicas()
         running = [r for r in rows if r["state"] == "RUNNING"]
@@ -366,10 +362,16 @@ def test_disagg_streams_byte_identical_to_unified_oracle(params):
     pull_prompts = _prompts(22, 2)
     pull_wants = [greedy_reference(params, p, 2) for p in pull_prompts]
 
+    # Every handoff below is counted (migrated == N_STREAMS, failed ==
+    # 0), so none may ride the clock: a migration op that the decode
+    # replica's loop, busy compiling beside five other test workers,
+    # takes up later than the default 5 s falls back to recompute by
+    # design, and that is another test's subject.
     handle = _serve_app(
         params,
         disagg={"prefill_replicas": 1, "transfer": "exact",
-                "handoff_after_tokens": 2})
+                "handoff_after_tokens": 2,
+                "migration_timeout_s": REPLICA_READY_S})
     try:
         roles = _wait_roles()
 

@@ -14,6 +14,7 @@ this after every engine-spawning tier-1 test).
 """
 
 import dataclasses
+import functools
 import glob
 import json
 import os
@@ -34,6 +35,7 @@ from ray_tpu.serve.llm_engine import (
     LLMServer,
     llama_paged_adapter,
 )
+from ray_tpu.utils.test_utils import REPLICA_READY_S
 from ray_tpu.util import doctor, flight_recorder
 
 CFG = llama.LlamaConfig(
@@ -328,6 +330,9 @@ def _slow_lora_adapter_factory(cfg):
     test_prefix_cache)."""
     base = llama_paged_adapter(cfg)
 
+    # wraps: the engine reads the step's signature for ``logit_idx=``
+    # before it speculates (see tests/test_spec_decode.py)
+    @functools.wraps(base.ragged_step)
     def slow_step(*args, **kwargs):
         jax.debug.callback(lambda: time.sleep(0.02), ordered=True)
         return base.ragged_step(*args, **kwargs)
@@ -364,7 +369,8 @@ def test_cross_feature_survivor_audits_clean(params):
             lambda: params,
             adapter_factory=_slow_lora_adapter_factory,
         )
-        handle = serve.run(app, name="llmdoc", route_prefix=None)
+        handle = serve.run(app, name="llmdoc", route_prefix=None,
+                           timeout_s=REPLICA_READY_S)
         # Adapter-pool churn beyond residency (8 pages) + trie warmth:
         # distinct tenants over a shared prefix force refcount-0 LRU
         # eviction while spec rounds draft against every stream.

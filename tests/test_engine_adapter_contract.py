@@ -5,6 +5,7 @@ MiniCPM-SALA), so a new
 model family knows what it has to provide."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +78,17 @@ CASES = {
 }
 
 
+@functools.cache
+def _family(case):
+    """A family's adapter, parameters and step, built once for both
+    tests: one jitted function and an executable a shape, as in the
+    engine, so that the shapes the tests share are compiled once."""
+    make, cfg, init_params, takes = CASES[case]
+    adapter = make(cfg)
+    return (adapter, cfg, init_params(jax.random.key(0), cfg), takes,
+            jax.jit(adapter.ragged_step))
+
+
 def _init_cache(adapter):
     """The adapter's cache as the engine asks for it: a cache that holds
     state by slot is told the slots, and no page where it holds none."""
@@ -88,9 +100,7 @@ def _init_cache(adapter):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_ragged_step_is_the_one_step_plug(case):
-    make, cfg, init_params, takes = CASES[case]
-    adapter = make(cfg)
-    params = init_params(jax.random.key(0), cfg)
+    adapter, cfg, params, takes, step = _family(case)
     # one step field; what an adapter does not serve is absent, not a stub
     step_fields = {f.name for f in dataclasses.fields(adapter)
                    if f.name.startswith("ragged_step")}
@@ -116,7 +126,7 @@ def test_ragged_step_is_the_one_step_plug(case):
         pack_ragged_batch(ROWS, BUDGET, SLOTS)
     nine = (params, toks, pos, r_slot, r_start, r_len, r_off, TABLE, cache)
 
-    logits, new_cache = adapter.ragged_step(*nine)
+    logits, new_cache = step(*nine)
     assert logits.shape == (SLOTS, cfg.vocab_size)
     assert logits.dtype == jnp.float32
     assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
@@ -128,13 +138,13 @@ def test_ragged_step_is_the_one_step_plug(case):
         # every token on the null adapter: the pool's zero scratch page
         lora = (pool.device_pool, pool.page_table([]),
                 np.zeros((BUDGET,), np.int32))
-        with_lora, _ = adapter.ragged_step(*nine, lora=lora)
+        with_lora, _ = step(*nine, lora=lora)
         np.testing.assert_array_equal(np.asarray(with_lora),
                                       np.asarray(logits))
     else:
         assert adapter.make_adapter_pool is None
     if "logit_idx" in takes:
-        row, verify, _ = adapter.ragged_step(*nine, logit_idx=idx)
+        row, verify, _ = step(*nine, logit_idx=idx)
         np.testing.assert_array_equal(np.asarray(row), np.asarray(logits))
         assert verify.shape == (len(idx), cfg.vocab_size)
         # the last token of row 0 sits at flat position 5, of row 1 at 7
@@ -143,7 +153,7 @@ def test_ragged_step_is_the_one_step_plug(case):
     for name in sorted({"lora", "logit_idx"} - takes):
         value = idx if name == "logit_idx" else (None, None, None)
         with pytest.raises((TypeError, ValueError), match=name):
-            adapter.ragged_step(*nine, **{name: value})
+            step(*nine, **{name: value})
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -153,9 +163,7 @@ def test_a_decode_step_reads_the_same_at_either_compiled_shape(case):
     row's result does not depend on the padding beside it: the same
     decode rows through the small shape and through the budget's give
     the same tokens, logits and cache."""
-    make, cfg, init_params, takes = CASES[case]
-    adapter = make(cfg)
-    params = init_params(jax.random.key(0), cfg)
+    adapter, _cfg, params, takes, step = _family(case)
     cache = _init_cache(adapter)
     small, budget = ragged_step_shapes(BUDGET, SLOTS)
     assert (small, budget) == (8, BUDGET)
@@ -163,9 +171,6 @@ def test_a_decode_step_reads_the_same_at_either_compiled_shape(case):
     if "lora" in takes:
         pool = adapter.make_adapter_pool(EngineConfig(max_slots=SLOTS))
         pool.acquire("tenant-a")
-    # one jitted function and an executable a shape, as in the engine
-    step = jax.jit(adapter.ragged_step)
-
     def run(rows, T, cache):
         (toks, _mask, _slot, pos, r_slot, r_start, r_len, r_off, tok_ad) = \
             pack_ragged_batch(rows, T, SLOTS, with_adapters=True)

@@ -17,6 +17,11 @@ import pytest
 
 from ray_tpu.models import llama, quant
 
+# Under the Pallas interpreter an eager call of a model step compiles its
+# kernels anew; the steps of one shape share these (``cfg`` is static).
+_prefill_slot = jax.jit(llama.prefill_slot_paged, static_argnames=("cfg",))
+_decode_slots = jax.jit(llama.decode_slots_paged, static_argnames=("cfg",))
+
 
 @pytest.fixture(scope="module")
 def tiny_cfg():
@@ -41,7 +46,7 @@ def _prefilled(cfg, params, prompt_lens, *, page=64, maxp=4, rng_seed=2):
         bucket = -(-plen // page) * page
         toks = np.zeros((bucket,), np.int32)
         toks[:plen] = rng.integers(0, cfg.vocab_size, plen)
-        lg, cache = llama.prefill_slot_paged(
+        lg, cache = _prefill_slot(
             params, jnp.asarray(toks), jnp.int32(plen),
             jnp.asarray(bt[s][: bucket // page]), cfg, cache)
         lengths[s] = plen
@@ -59,10 +64,10 @@ def test_fused_matches_unfused_fp32(tiny_cfg):
     cache_u = cache_f = cache
     active = jnp.ones((2,), bool)
     for step in range(4):
-        lg_u, cache_u, nl_u = llama.decode_slots_paged(
+        lg_u, cache_u, nl_u = _decode_slots(
             params, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_u, cache_u)
-        lg_f, cache_f, nl_f = llama.decode_slots_paged(
+        lg_f, cache_f, nl_f = _decode_slots(
             params, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_f, cache_f)
         np.testing.assert_allclose(np.asarray(lg_f), np.asarray(lg_u),
@@ -93,7 +98,7 @@ def test_fused_inactive_slot_isolated(tiny_cfg):
     cache, bt, lengths, cur = _prefilled(tiny_cfg, params, [40, 20])
     before = np.asarray(cache["k"])
     active = jnp.asarray([False, True])
-    _, cache, new_len = llama.decode_slots_paged(
+    _, cache, new_len = _decode_slots(
         params, jnp.asarray(cur), active, bt, jnp.asarray(lengths),
         cfg_f, cache)
     after = np.asarray(cache["k"])
@@ -117,10 +122,10 @@ def test_fused_matches_unfused_int8_weights(tiny_cfg):
     cache_u = cache_f = cache
     active = jnp.ones((2,), bool)
     for step in range(4):
-        lg_u, cache_u, nl = llama.decode_slots_paged(
+        lg_u, cache_u, nl = _decode_slots(
             fparams, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_u, cache_u)
-        lg_f, cache_f, _ = llama.decode_slots_paged(
+        lg_f, cache_f, _ = _decode_slots(
             fparams, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_f, cache_f)
         np.testing.assert_allclose(np.asarray(lg_f), np.asarray(lg_u),
@@ -146,10 +151,10 @@ def test_fused_int8_kv_append_invariants(tiny_cfg):
     active = jnp.ones((2,), bool)
     agree = 0
     for step in range(6):
-        lg_u, cache_u, nl = llama.decode_slots_paged(
+        lg_u, cache_u, nl = _decode_slots(
             params, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_u, cache_u)
-        lg_f, cache_f, _ = llama.decode_slots_paged(
+        lg_f, cache_f, _ = _decode_slots(
             params, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_f, cache_f)
         agree += int((np.argmax(np.asarray(lg_u), -1)
@@ -216,10 +221,10 @@ def test_fused_quantized_end_to_end(tiny_cfg):
     active = jnp.ones((2,), bool)
     agree = 0
     for step in range(6):
-        lg_u, cache_u, nl = llama.decode_slots_paged(
+        lg_u, cache_u, nl = _decode_slots(
             fparams, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_u, cache_u)
-        lg_f, cache_f, _ = llama.decode_slots_paged(
+        lg_f, cache_f, _ = _decode_slots(
             fparams, jnp.asarray(cur), active, bt,
             jnp.asarray(lengths), cfg_f, cache_f)
         agree += int((np.argmax(np.asarray(lg_u), -1)

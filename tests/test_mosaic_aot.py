@@ -235,19 +235,6 @@ def test_ragged_attention_both_calls(v5e, T, H, KVH, R, maxp, pages, kv_int8):
                           text)) == 2
 
 
-def test_mamba_ssd_kernel(v5e):
-    from ray_tpu.ops.mamba_ssd import ssd_pallas
-
-    mesh = _one(v5e)
-    B, S, H, Pd, N = 4, 4096, 8, 64, 128
-    f32 = jnp.float32
-    _compile(lambda x, la, b, c: ssd_pallas(x, la, b, c, 128),
-             *_on(mesh, (_sds(B, S, H, Pd, dtype=f32),
-                         _sds(B, S, H, dtype=f32),
-                         _sds(B, S, N, dtype=f32),
-                         _sds(B, S, N, dtype=f32))))
-
-
 # -- the model steps the engine and the trainer jit -------------------------
 
 @pytest.mark.parametrize("name,kv_int8", SERVE_CASES)

@@ -19,6 +19,11 @@ from ray_tpu.models import llama
 from ray_tpu.ops import paged_attention as pa
 from tests import oracle
 
+# Under the Pallas interpreter an eager call of a model step compiles its
+# kernels anew; the steps of one shape share these (``cfg`` is static).
+_prefill_slot = jax.jit(llama.prefill_slot_paged, static_argnames=("cfg",))
+_decode_slots = jax.jit(llama.decode_slots_paged, static_argnames=("cfg",))
+
 
 def test_kernel_matches_reference_ragged():
     rng = np.random.default_rng(0)
@@ -83,7 +88,7 @@ def test_llama_paged_matches_dense(tiny_cfg):
         toks = np.zeros((bucket,), np.int32)
         toks[:plen] = rng.integers(0, cfg.vocab_size, plen)
         seqs.append(toks[:plen].tolist())
-        lg_p, paged = llama.prefill_slot_paged(
+        lg_p, paged = _prefill_slot(
             params, jnp.asarray(toks), jnp.int32(plen),
             jnp.asarray(bt[s][: bucket // page]), cfg, paged)
         np.testing.assert_allclose(
@@ -95,7 +100,7 @@ def test_llama_paged_matches_dense(tiny_cfg):
     active = jnp.ones((slots,), bool)
     for step in range(6):
         cur = np.array([seq[-1] for seq in seqs], np.int32)
-        lg_p, paged, new_len = llama.decode_slots_paged(
+        lg_p, paged, new_len = _decode_slots(
             params, jnp.asarray(cur), active, jnp.asarray(bt),
             jnp.asarray(lengths), cfg, paged)
         lg_o = np.stack([oracle.next_token_logits(params, cfg, seq)
@@ -121,12 +126,12 @@ def test_llama_paged_inactive_slot_isolated(tiny_cfg):
     bt = np.arange(slots * maxp, dtype=np.int32).reshape(slots, maxp)
     rng = np.random.default_rng(3)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, 64), jnp.int32)
-    _, paged = llama.prefill_slot_paged(
+    _, paged = _prefill_slot(
         params, toks, jnp.int32(40), jnp.asarray(bt[0][:1]), cfg, paged)
     before = np.asarray(paged["k"])
     active = jnp.asarray([False, True])
     cur = jnp.asarray([5, 7], jnp.int32)
-    _, paged, new_len = llama.decode_slots_paged(
+    _, paged, new_len = _decode_slots(
         params, cur, active, jnp.asarray(bt),
         jnp.asarray([40, 0], np.int32), cfg, paged)
     after = np.asarray(paged["k"])
@@ -384,10 +389,10 @@ def test_llama_paged_int8_tracks_fp(tiny_cfg):
         toks = np.zeros((64,), np.int32)
         toks[:plen] = rng.integers(0, cfg.vocab_size, plen)
         jt = jnp.asarray(toks)
-        lg_f, fp = llama.prefill_slot_paged(
+        lg_f, fp = _prefill_slot(
             params, jt, jnp.int32(plen), jnp.asarray(bt[s][:1]),
             tiny_cfg, fp)
-        lg_q, qd = llama.prefill_slot_paged(
+        lg_q, qd = _prefill_slot(
             params, jt, jnp.int32(plen), jnp.asarray(bt[s][:1]), cfg, qd)
         np.testing.assert_allclose(np.asarray(lg_q), np.asarray(lg_f),
                                    atol=1e-4, rtol=1e-4)
@@ -397,10 +402,10 @@ def test_llama_paged_int8_tracks_fp(tiny_cfg):
     active = jnp.ones((slots,), bool)
     agree = 0
     for step in range(6):
-        lg_f, fp, nl_f = llama.decode_slots_paged(
+        lg_f, fp, nl_f = _decode_slots(
             params, jnp.asarray(cur), active, jnp.asarray(bt),
             jnp.asarray(lengths), tiny_cfg, fp)
-        lg_q, qd, nl_q = llama.decode_slots_paged(
+        lg_q, qd, nl_q = _decode_slots(
             params, jnp.asarray(cur), active, jnp.asarray(bt),
             jnp.asarray(lengths), cfg, qd)
         tf = np.argmax(np.asarray(lg_f), -1)

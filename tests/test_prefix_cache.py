@@ -41,6 +41,7 @@ from ray_tpu.serve.prefix_index import (
     match_depth,
     prefix_hashes,
 )
+from tests import oracle
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -57,14 +58,7 @@ def params():
 
 
 def greedy_reference(params, prompt, n_tokens):
-    toks = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = llama.forward(params, jnp.asarray([toks]), CFG)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+    return oracle.greedy_tokens(params, CFG, prompt, n_tokens)
 
 
 def _engine(params, **kw):

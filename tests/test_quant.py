@@ -12,6 +12,11 @@ import pytest
 
 from ray_tpu.models import llama, quant
 
+# Under the Pallas interpreter an eager call of a model step compiles its
+# kernels anew; the steps of one shape share these (``cfg`` is static).
+_prefill_slot = jax.jit(llama.prefill_slot_paged, static_argnames=("cfg",))
+_decode_slots = jax.jit(llama.decode_slots_paged, static_argnames=("cfg",))
+
 
 @pytest.fixture(scope="module")
 def tiny():
@@ -120,14 +125,14 @@ def test_fused_decode_matches_unfused():
     outs = {}
     for name, p in (("unfused", q), ("fused", fused)):
         cache = llama.init_paged_cache(cfg, slots * maxp, page)
-        lg, cache = llama.prefill_slot_paged(
+        lg, cache = _prefill_slot(
             p, jnp.asarray(toks), jnp.int32(40),
             jnp.asarray(bt[0][:1]), cfg, cache)
         lengths = np.asarray([40], np.int32)
         cur = np.asarray([int(np.argmax(np.asarray(lg)))], np.int32)
         seq = [int(cur[0])]
         for _ in range(5):
-            lg, cache, nl = llama.decode_slots_paged(
+            lg, cache, nl = _decode_slots(
                 p, jnp.asarray(cur), jnp.ones((slots,), bool),
                 jnp.asarray(bt), jnp.asarray(lengths), cfg, cache)
             cur = np.argmax(np.asarray(lg), -1).astype(np.int32)

@@ -29,6 +29,39 @@ from ray_tpu.models import llama
 from ray_tpu.ops import ragged_paged_attention as rpa
 
 
+# Every kernel call goes through one of these: under the Pallas
+# interpreter an EAGER call compiles its kernel anew, so the cases of one
+# shape share a ``jax.jit`` and differ in operands only (the row arrays,
+# the layer index and the cell lists are arrays, never constants).
+_attend = jax.jit(rpa.ragged_paged_attention,
+                  static_argnames=("soft_cap", "max_row_tokens"))
+_fused_layer = jax.jit(
+    rpa.fused_ragged_layer,
+    static_argnames=("eps", "n_heads", "n_kv_heads", "max_row_tokens"))
+_ragged_step = jax.jit(llama.ragged_step_paged,
+                       static_argnames=("cfg", "max_row_tokens"))
+_prefill_slot = jax.jit(llama.prefill_slot_paged, static_argnames=("cfg",))
+_decode_slots = jax.jit(llama.decode_slots_paged, static_argnames=("cfg",))
+
+
+@functools.partial(jax.jit, static_argnames=("every_cell",))
+def _append(state, k_new, v_new, slot, start, nlen, off, bt, *,
+            every_cell=False):
+    """``ragged_paged_append`` (two pools) or ``..._quantized`` (two pools
+    and their scales) over the cells that write, or, traced with
+    ``every_cell``, over a list of ALL ``R x NPR`` cells: the walk the
+    append was before it had a list."""
+    fn = (rpa.ragged_paged_append if len(state) == 2
+          else rpa.ragged_paged_append_quantized)
+    with pytest.MonkeyPatch.context() as patch:
+        if every_cell:
+            cells = slot.shape[0] * _NPR
+            patch.setattr(rpa, "live_append_cells", lambda *a: (
+                jnp.arange(cells, dtype=jnp.int32),
+                jnp.full((1,), cells, jnp.int32)))
+        return fn(*state, k_new, v_new, slot, start, nlen, off, bt)
+
+
 def _mixed_rows(T=48, R=4):
     """One decode row, one mid-prompt prefill chunk, one fresh prefill,
     one padding row — the shapes a real engine step packs."""
@@ -73,11 +106,11 @@ def test_kernel_matches_reference(mrt, int8):
             q, kn, vn, kl, vl, rs, rst, rl, ro, bt,
             k_scales=None if ks is None else ks[layer],
             v_scales=None if vs is None else vs[layer])
-        got = rpa.ragged_paged_attention(
+        got = _attend(
             jnp.asarray(q), jnp.asarray(kn), jnp.asarray(vn), kp, vp,
-            layer, jnp.asarray(rs), jnp.asarray(rst), jnp.asarray(rl),
-            jnp.asarray(ro), jnp.asarray(bt), k_scales=ks, v_scales=vs,
-            max_row_tokens=mrt)
+            jnp.int32(layer), jnp.asarray(rs), jnp.asarray(rst),
+            jnp.asarray(rl), jnp.asarray(ro), jnp.asarray(bt), k_scales=ks,
+            v_scales=vs, max_row_tokens=mrt)
         mask = np.zeros(T, bool)
         for r in range(4):
             mask[ro[r]:ro[r] + rl[r]] = rl[r] > 0
@@ -102,9 +135,9 @@ def test_kernel_soft_cap():
     vn = rng.standard_normal((T, KVH, D)).astype(np.float32)
     ref = rpa.ragged_attention_reference(
         q, kn, vn, kp[0], vp[0], rs, rst, rl, ro, bt, soft_cap=20.0)
-    got = rpa.ragged_paged_attention(
-        jnp.asarray(q), jnp.asarray(kn), jnp.asarray(vn), kp, vp, 0,
-        jnp.asarray(rs), jnp.asarray(rst), jnp.asarray(rl),
+    got = _attend(
+        jnp.asarray(q), jnp.asarray(kn), jnp.asarray(vn), kp, vp,
+        jnp.int32(0), jnp.asarray(rs), jnp.asarray(rst), jnp.asarray(rl),
         jnp.asarray(ro), jnp.asarray(bt), soft_cap=20.0)
     np.testing.assert_allclose(np.asarray(got)[0], np.asarray(ref)[0],
                                atol=2e-5, rtol=2e-5)
@@ -125,8 +158,8 @@ def test_append_matches_reference():
             rs, rst, rl, ro, bt)
         want_k = want_k.at[layer].set(wk)
         want_v = want_v.at[layer].set(wv)
-    got_k, got_v = rpa.ragged_paged_append(
-        kp, vp, jnp.asarray(kn), jnp.asarray(vn), jnp.asarray(rs),
+    got_k, got_v = _append(
+        (kp, vp), jnp.asarray(kn), jnp.asarray(vn), jnp.asarray(rs),
         jnp.asarray(rst), jnp.asarray(rl), jnp.asarray(ro),
         jnp.asarray(bt))
     # The scratch page (Pt-1) is garbage-tolerant; everything else must
@@ -156,9 +189,9 @@ def test_append_quantized_grow_only_scales():
     ro = np.asarray([0], np.int32)
     kn = (rng.standard_normal((L, T, KVH, D)) * 0.01).astype(np.float32)
     vn = (rng.standard_normal((L, T, KVH, D)) * 0.01).astype(np.float32)
-    gk, gv, gks, gvs = rpa.ragged_paged_append_quantized(
-        jnp.asarray(kq), jnp.asarray(vq), jnp.asarray(ks),
-        jnp.asarray(vs), jnp.asarray(kn), jnp.asarray(vn),
+    gk, gv, gks, gvs = _append(
+        (jnp.asarray(kq), jnp.asarray(vq), jnp.asarray(ks),
+         jnp.asarray(vs)), jnp.asarray(kn), jnp.asarray(vn),
         jnp.asarray(rs), jnp.asarray(rst), jnp.asarray(rl),
         jnp.asarray(ro), jnp.asarray(bt))
     # grow-only: the small appended row must not shrink page 0's scale
@@ -226,7 +259,7 @@ def _pipeline_oracle(params, cfg, prompts, bt, num_pages, page,
         S = ((len(p) + page - 1) // page) * page
         toks = np.zeros(S, np.int32)
         toks[:len(p)] = p
-        lg, cache = llama.prefill_slot_paged(
+        lg, cache = _prefill_slot(
             params, jnp.asarray(toks), jnp.asarray(len(p)),
             jnp.asarray(bt[s, :S // page]), cfg, cache)
         firsts.append(int(jnp.argmax(lg)))
@@ -234,7 +267,7 @@ def _pipeline_oracle(params, cfg, prompts, bt, num_pages, page,
     cur = np.asarray(firsts, np.int32)
     outs = [[c] for c in cur]
     for _ in range(decode_steps):
-        lg, cache, lens = llama.decode_slots_paged(
+        lg, cache, lens = _decode_slots(
             params, jnp.asarray(cur), jnp.ones(len(prompts), bool),
             jnp.asarray(bt), jnp.asarray(lens), cfg, cache)
         cur = np.asarray(jnp.argmax(lg, -1)).astype(np.int32)
@@ -255,7 +288,7 @@ def _ragged_run(params, cfg, prompts, bt, num_pages, page, decode_steps):
         nonlocal cache
         (htoks, _dm, _ts, tpos, rslot, rstart, rlen, roff
          ) = rpa.pack_ragged_batch(rows, T, R)
-        lg, cache2 = llama.ragged_step_paged(
+        lg, cache2 = _ragged_step(
             params, jnp.asarray(htoks), jnp.asarray(tpos),
             jnp.asarray(rslot), jnp.asarray(rstart), jnp.asarray(rlen),
             jnp.asarray(roff), jnp.asarray(bt), cfg, cache,
@@ -347,10 +380,11 @@ ASSEMBLED = {"in_place": ["w_down", "wo"],
              "sliced": ["ln_attn", "ln_mlp", "w_gateup", "wqkv"]}
 
 
+@functools.cache
 def _fused_artifact(cfg, key=0):
     """The serving artifact: int8 weights, q/k/v and gate/up fused.
     Random norm vectors, so that a layer read from the wrong place shows
-    in every operand."""
+    in every operand.  Built once a config: the cases only read it."""
     from ray_tpu.models import quant
 
     params = quant.fuse_for_decode(
@@ -364,7 +398,7 @@ def _fused_artifact(cfg, key=0):
 def _fused_layer_call(layers, kp, vp, ks, vs, li, x, sin, cos):
     slot, start, nlen, off = _mixed_rows()
     bt = jnp.asarray(np.arange(16, dtype=np.int32).reshape(4, 4))
-    return rpa.fused_ragged_layer(
+    return _fused_layer(
         x, layers, kp, vp, jnp.int32(li), jnp.asarray(slot),
         jnp.asarray(start), jnp.asarray(nlen), jnp.asarray(off), bt,
         sin, cos, eps=1e-5, n_heads=2, n_kv_heads=1, k_scales=ks,
@@ -469,7 +503,7 @@ def test_fused_artifact_step_matches_unfused(kv_int8):
         for _ in range(2):
             (ht, _dm, _ts, pos, rs, r0, rl, ro) = rpa.pack_ragged_batch(
                 step_rows, 48, 4)
-            lg, cache = llama.ragged_step_paged(
+            lg, cache = _ragged_step(
                 params, jnp.asarray(ht), jnp.asarray(pos), jnp.asarray(rs),
                 jnp.asarray(r0), jnp.asarray(rl), jnp.asarray(ro),
                 jnp.asarray(bt), c, cache, max_row_tokens=32)
@@ -568,7 +602,7 @@ def test_fused_live_walk_matches_unfused_step(kv_int8, case):
         cache.update(k_scale=ks, v_scale=vs)
 
     def run(fused):
-        logits, _ = llama.ragged_step_paged(
+        logits, _ = _ragged_step(
             params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(slot),
             jnp.asarray(start), jnp.asarray(nlen), jnp.asarray(off), bt,
             dataclasses.replace(cfg, fused_decode=fused), cache,
@@ -595,7 +629,7 @@ def test_fused_live_walk_gives_the_old_walks_bits(kv_int8, case):
     sin, cos = llama.rope_table(cfg, jnp.arange(T)[None])
 
     def layer(live_cells):
-        return rpa.fused_ragged_layer(
+        return _fused_layer(
             x, params["layers"], kp, vp, jnp.int32(1), slot, start, nlen,
             off, bt, sin[0], cos[0], eps=1e-5, n_heads=cfg.n_heads,
             n_kv_heads=cfg.n_kv_heads, k_scales=ks, v_scales=vs,
@@ -693,13 +727,6 @@ def _layer_reference(x, layers, li, kp, vp, ks, vs, meta, sin, cos, H, KVH,
     return h + thru(act) @ f32(layers["mlp"]["w_down"][li]), k, v
 
 
-# one compile a (heads, dtype): the row arrays are operands
-_STACKED_LAYER = {
-    name: jax.jit(functools.partial(
-        rpa.fused_ragged_layer, eps=1e-5, n_heads=H, n_kv_heads=KVH))
-    for name, (H, KVH) in STACKED_HEADS.items()}
-
-
 @pytest.mark.parametrize("case", list(STACKED_ROWS))
 @pytest.mark.parametrize("heads", list(STACKED_HEADS))
 @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
@@ -730,9 +757,9 @@ def test_fused_layer_stacked_heads_match_reference(kind, heads, case):
         np.cos(ang), jnp.float32)
     pools_before = [np.asarray(a).copy() for a in (kp, vp)]
 
-    got = _STACKED_LAYER[heads](
-        x, layers, kp, vp, jnp.int32(li), *meta, sin, cos, k_scales=ks,
-        v_scales=vs)
+    got = _fused_layer(
+        x, layers, kp, vp, jnp.int32(li), *meta, sin, cos, eps=1e-5,
+        n_heads=H, n_kv_heads=KVH, k_scales=ks, v_scales=vs)
     want = _layer_reference(x, layers, li, kp, vp, ks, vs, meta, sin, cos,
                             H, KVH, 1e-5)
     n_live = int(rpa.live_page_cells(meta[1], meta[2], _MAXP, _PAGE)[1][0])
@@ -829,7 +856,7 @@ def test_two_call_kernel_matches_reference(kind, heads, case):
         *meta, k_scales=None if ks is None else ks[layer],
         v_scales=None if vs is None else vs[layer])
     kw = dict(k_scales=ks, v_scales=vs, max_row_tokens=24)
-    got = rpa.ragged_paged_attention(q, kn, vn, kp, vp, layer, *meta, **kw)
+    got = _attend(q, kn, vn, kp, vp, jnp.int32(layer), *meta, **kw)
     assert got.shape == (T, H, D) and got.dtype == jnp.float32
     mask = np.zeros(T, bool)
     for r in range(_SLOTS):
@@ -840,8 +867,8 @@ def test_two_call_kernel_matches_reference(kind, heads, case):
     # positions no row covers are zero, whatever a call left unwritten
     assert not np.any(np.asarray(got)[~mask])
     # lists built once in front of a layer loop give the same bits
-    handed = rpa.ragged_paged_attention(
-        q, kn, vn, kp, vp, layer, *meta, **kw,
+    handed = _attend(
+        q, kn, vn, kp, vp, jnp.int32(layer), *meta, **kw,
         live_cells=rpa.live_attention_cells(*meta[1:4], T, _MAXP, _PAGE))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(handed))
 
@@ -905,8 +932,7 @@ def test_live_append_cells_are_the_old_walks_live_cells(case):
 
 @pytest.mark.parametrize("case", list(APPEND_ROWS))
 @pytest.mark.parametrize("kv_int8", [False, True])
-def test_append_live_walk_gives_the_old_walks_bits(kv_int8, case,
-                                                   monkeypatch):
+def test_append_live_walk_gives_the_old_walks_bits(kv_int8, case):
     """Both appends over the list of the cells that write, against the
     walk they were before (a list of ALL ``R x NPR`` cells: the ones the
     rows do not reach go to the scratch page and copy it through) and,
@@ -921,17 +947,10 @@ def test_append_live_walk_gives_the_old_walks_bits(kv_int8, case,
     vn = rng.standard_normal((L, _BUDGET, KVH, D)).astype(np.float32)
     state = (kp, vp, ks, vs) if kv_int8 else (kp, vp)
 
-    def append():
-        fn = (rpa.ragged_paged_append_quantized if kv_int8
-              else rpa.ragged_paged_append)
-        return fn(*state, jnp.asarray(kn), jnp.asarray(vn),
-                  *(jnp.asarray(a) for a in (slot, start, nlen, off, bt)))
-
-    got = append()
-    cells = _SLOTS * _NPR
-    monkeypatch.setattr(rpa, "live_append_cells", lambda *a: (
-        jnp.arange(cells, dtype=jnp.int32), jnp.full((1,), cells, jnp.int32)))
-    old = append()
+    operands = (state, jnp.asarray(kn), jnp.asarray(vn),
+                *(jnp.asarray(a) for a in (slot, start, nlen, off, bt)))
+    got = _append(*operands)
+    old = _append(*operands, every_cell=True)
     written = sorted({int(bt[slot[r], p // _PAGE]) for r in range(_SLOTS)
                       for p in range(start[r], start[r] + nlen[r])})
     assert len(written) == rpa.append_cell_count(start, nlen, _PAGE)

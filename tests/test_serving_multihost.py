@@ -22,6 +22,9 @@ Scenarios, all through the real router/controller path:
 """
 
 import dataclasses
+import functools
+import os
+import random
 import re
 import threading
 import time
@@ -81,15 +84,22 @@ def references(params):
     return refs
 
 
-def _slow_paged_adapter_factory(cfg):
-    """Paged adapter with a throttled decode step so a kill reliably
-    lands mid-stream (same trick as test_serve_failover)."""
+def _slow_paged_adapter_factory(cfg, hold):
+    """Paged adapter with a throttled decode step, which stands still
+    while the file ``hold`` exists, so that a kill lands mid-stream
+    (same trick as test_serve_failover)."""
     base = llama_paged_adapter(cfg)
+
+    def throttle():
+        time.sleep(0.03)
+        deadline = time.monotonic() + 60
+        while os.path.exists(hold) and time.monotonic() < deadline:
+            time.sleep(0.002)
 
     def slow_decode(*args, **kwargs):
         # ordered=True is not allowed on a >1-device mesh; the
         # unordered callback still runs and throttles the step.
-        jax.debug.callback(lambda: time.sleep(0.03))
+        jax.debug.callback(throttle)
         return base.decode_slots(*args, **kwargs)
 
     return dataclasses.replace(base, decode_slots=slow_decode)
@@ -116,9 +126,17 @@ def mh_app(params):
 
 
 @pytest.fixture
-def mh_app_two_groups(params):
+def hold(tmp_path):
+    """While this file exists no group takes a decode step (the groups
+    are other processes: a path is what they can see)."""
+    return tmp_path / "hold_decode"
+
+
+@pytest.fixture
+def mh_app_two_groups(params, hold):
     handle = _serve_app(params, num_replicas=2,
-                        adapter_factory=_slow_paged_adapter_factory)
+                        adapter_factory=functools.partial(
+                            _slow_paged_adapter_factory, hold=str(hold)))
     yield handle
     serve.shutdown()
     ray_tpu.shutdown()
@@ -263,7 +281,7 @@ def _wait_all_decoding(outs, min_tokens=2, timeout_s=180.0):
 
 
 def test_shard_member_kill_fails_over_whole_group(
-        mh_app_two_groups, references):
+        mh_app_two_groups, references, hold):
     """SIGKILL one ShardMemberActor (rank >= 1) mid-decode: the
     controller detects the member loss, fails the WHOLE group (rank 0
     is hard-killed — a lost member means lost collectives), and every
@@ -274,9 +292,35 @@ def test_shard_member_kill_fails_over_whole_group(
     gens, outs, errs, threads = _start_streams(mh_app_two_groups)
     _wait_all_decoding(outs)
 
-    killer = ReplicaKiller(api.runtime(), seed=0,
-                           class_name="ShardMemberActor")
-    assert killer.kill_one() is not None
+    # Every stream stands where it is.  The group that compiled first
+    # may have ended its streams by now: the victim is a member of a
+    # seeded choice among the groups that still hold one mid-decode.
+    from ray_tpu.serve.handle import _routers
+    from ray_tpu.util import state
+
+    hold.touch()
+    try:
+        router = _routers[(APP, DEP)]
+        with router._lock:
+            groups = {rid: info.handle
+                      for rid, info in router._replicas.items()}
+        live = sorted(rid for rid, h in groups.items() if api.get(
+            h.num_ongoing_requests.remote(), timeout=60) > 0)
+        assert live, f"every stream ended before the kill: " \
+            f"{[len(o) for o in outs]}"
+        group = random.Random(0).choice(live)
+        (row,) = [r for r in state.list_replicas()
+                  if r["replica_id"] == group]
+        # "0:<rank 0's id>,1:<member's id>": the actors' hex[8:16]
+        member_ids = {part.split(":")[1]
+                      for part in row["members"].split(",")[1:]}
+        killer = ReplicaKiller(api.runtime(),
+                               class_name="ShardMemberActor")
+        (victim,) = [a for a in killer.victims()
+                     if a.hex()[8:16] in member_ids]
+        assert killer.kill_one(actor_id=victim) is not None
+    finally:
+        hold.unlink()
 
     for t in threads:
         t.join(timeout=180)

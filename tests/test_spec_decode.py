@@ -23,6 +23,7 @@ with output unchanged either way.
 """
 
 import dataclasses
+import functools
 import threading
 import time
 
@@ -38,6 +39,7 @@ from ray_tpu.serve.llm_engine import (
     LLMServer,
     llama_paged_adapter,
 )
+from ray_tpu.utils.test_utils import REPLICA_READY_S
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -71,16 +73,36 @@ def _engine(params, *, spec, **kw):
                      EngineConfig(**cfg), draft_params=draft)
 
 
+# The spec-off engine at the file's default configuration, which four
+# cases ask for their oracle: built (and its programs compiled) once.  It
+# keeps nothing between requests (no prefix cache) and no case asserts
+# on it.  An oracle at any other configuration is built for its case.
+_default_oracle = []
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _shut_default_oracle():
+    yield
+    while _default_oracle:
+        _default_oracle.pop().shutdown()
+
+
 def _spec_off_oracle(params, reqs, **ekw):
     """The oracle this whole file is measured against: the SAME engine
     configuration with spec_decode=False, greedy."""
-    eng = _engine(params, spec=False, **ekw)
+    if ekw:
+        eng = _engine(params, spec=False, **ekw)
+    else:
+        if not _default_oracle:
+            _default_oracle.append(_engine(params, spec=False))
+        eng = _default_oracle[0]
     try:
         streams = [eng.submit(p, max_new_tokens=n, temperature=0.0)
                    for p, n in reqs]
         return [s.result(timeout_s=300) for s in streams]
     finally:
-        eng.shutdown()
+        if ekw:
+            eng.shutdown()
 
 
 def _assert_pool_consistent(eng):
@@ -356,7 +378,8 @@ def test_spec_disagg_handoff_parity(params):
         lambda: params,
         adapter_factory=llama_paged_adapter,
     )
-    handle = serve.run(app, name="llmspecdis", route_prefix=None)
+    handle = serve.run(app, name="llmspecdis", route_prefix=None,
+                       timeout_s=REPLICA_READY_S)
     try:
         deadline = time.monotonic() + 120
         while time.monotonic() < deadline:
@@ -384,6 +407,12 @@ def _slow_spec_adapter_factory(cfg):
     jit, so a bare time.sleep would only fire at trace time."""
     base = llama_paged_adapter(cfg)
 
+    # wraps: the engine reads the step's signature for ``logit_idx=``
+    # before it speculates, and refuses to build over a step that hides
+    # it (every replica's constructor then raised, the controller
+    # replaced each for ever, and the app was never healthy: this test
+    # was red alone, not for a slow start under load)
+    @functools.wraps(base.ragged_step)
     def slow_step(*args, **kwargs):
         jax.debug.callback(lambda: time.sleep(0.02), ordered=True)
         return base.ragged_step(*args, **kwargs)
@@ -419,7 +448,8 @@ def test_spec_midstream_kill_failover_parity(params):
         lambda: params,
         adapter_factory=_slow_spec_adapter_factory,
     )
-    handle = serve.run(app, name="llmspecft", route_prefix=None)
+    handle = serve.run(app, name="llmspecft", route_prefix=None,
+                       timeout_s=REPLICA_READY_S)
     try:
         shandle = handle.options(stream=True)
         gens = [shandle.remote({"tokens": p, "max_new_tokens": n_new,

@@ -28,20 +28,24 @@ def test_task_streaming_basic(rt):
     assert values == [0, 10, 20, 30, 40]
 
 
-def test_streaming_consumes_while_running(rt):
+def test_streaming_consumes_while_running(rt, tmp_path):
+    """The first item reaches the consumer while the producer is still
+    running: the producer goes on to its second item only once the
+    consumer says that it holds the first (an order of events, where a
+    latency under 0.5 s was a race against five other test workers)."""
+    got_first = tmp_path / "got_first"    # seen from the worker process
+
     @ray_tpu.remote(num_returns="streaming")
     def slow():
         yield "first"
-        time.sleep(0.8)
-        yield "second"
+        deadline = time.monotonic() + 60
+        while not got_first.exists() and time.monotonic() < deadline:
+            time.sleep(0.005)
+        yield "second" if got_first.exists() else "first never consumed"
 
-    t0 = time.monotonic()
     gen = slow.remote()
-    first = ray_tpu.get(next(gen))
-    first_latency = time.monotonic() - t0
-    assert first == "first"
-    # The first item arrived well before the producer finished.
-    assert first_latency < 0.5
+    assert ray_tpu.get(next(gen)) == "first"
+    got_first.touch()
     assert ray_tpu.get(next(gen)) == "second"
     with pytest.raises(StopIteration):
         next(gen)

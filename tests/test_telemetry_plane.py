@@ -121,6 +121,12 @@ def _sample_value(text, sample_name):
 
 def test_cross_plane_trace_and_metrics(rt, tmp_path, cpu_devices):
     tracing.enable_tracing()
+    # The registry is the process's: a test file this worker ran before
+    # has left its rows and steps in the counters, so what THIS workload
+    # adds is what is compared.
+    before = metrics.export_prometheus()
+    rows_before = _sample_value(before, "raytpu_data_output_rows_total") or 0
+    steps_before = _sample_value(before, "raytpu_train_steps_total") or 0
 
     with tracing.span("workload"):
         _run_serve_request()
@@ -228,8 +234,10 @@ def test_cross_plane_trace_and_metrics(rt, tmp_path, cpu_devices):
     assert "raytpu_serve_router_requests_total{" in text
     assert "raytpu_serve_request_latency_seconds_bucket{" in text
     assert "raytpu_data_op_tasks_total{" in text
-    assert _sample_value(text, "raytpu_data_output_rows_total") == 64
-    assert _sample_value(text, "raytpu_train_steps_total") == 2
+    assert _sample_value(
+        text, "raytpu_data_output_rows_total") == rows_before + 64
+    assert _sample_value(
+        text, "raytpu_train_steps_total") == steps_before + 2
     assert _sample_value(text, "raytpu_train_compile_seconds_total") > 0
     # Memory plane: opt-state footprint is derived from the arrays'
     # shardings so it exports real bytes even on CPU; the HBM-headroom

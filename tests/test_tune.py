@@ -1,6 +1,8 @@
 """Tune tests (models the reference's tune test approach: tiny
 trainables, deterministic schedulers — python/ray/tune/tests/)."""
 
+import time
+
 import pytest
 
 import ray_tpu
@@ -117,13 +119,29 @@ def test_class_trainable_api():
     assert best.metrics["total"] == 20
 
 
-def test_pbt_exploits_checkpoints():
+def test_pbt_exploits_checkpoints(tmp_path):
+    """The two trials are actors that start when their processes do: the
+    weak one, started first, used to run all nine steps alone on a
+    loaded machine, with nobody to exploit (0.1 nine times over is
+    0.8999999999999999, the number the assertion then showed).  It now
+    takes no step before the driver has seen a result of the strong
+    one, whose SECOND step says so."""
+    strong_reported = tmp_path / "strong_reported"  # seen across processes
+
     class PBTTrainable(tune.Trainable):
         def setup(self, config):
             self.lr = config["lr"]
             self.score = 0.0
 
         def step(self):
+            if self.lr == 0.1:
+                deadline = time.monotonic() + 120
+                while (not strong_reported.exists()
+                       and time.monotonic() < deadline):
+                    time.sleep(0.005)
+            elif self.score > 0:
+                # a second step is handed out on the first one's result
+                strong_reported.touch()
             self.score += self.lr
             return {"score": self.score}
 
