@@ -19,9 +19,16 @@ holds ``u_i u_i'``, times ``sqrt(2)`` where ``a < b``.  A diagonal pair
 holds both orders of ``i != i'``, each with weight 1, which sums to the
 same ``(a . b)^2``.  That is ``D' = nb(nb+1)/2 * bs^2`` features (9216
 at ``d`` 128 against the deduplicated 8256: 12% of padding) in which
-every pair is ``bs^2`` whole lanes, and ``phi(u) = (u E) * (u F) * w``
-with two 0/1 matrices ``E, F [d, D']``: a product the MXU does exactly.
-``to_canonical`` maps a state in this layout to the deduplicated one.
+every pair is ``bs^2`` whole features: ``phi(u) = (u E) * (u F) * w``
+with two 0/1 matrices ``E, F [d, D']`` (``feature_maps``; ``features``
+forms it so for the decode tokens and the twins), and a state block of
+1024 features is four whole pairs.  With the FEATURES ON SUBLANES and the
+tokens on lanes a pair needs no selection at all: for each ``i`` in
+``a``, row ``i`` of ``u^T`` spread over the sublanes of block ``b``'s
+``[bs, tokens]`` slab, times the slab, times the pair's one weight
+(``pair_features_t``): the same float32 products in the same order, bit
+for bit.  ``to_canonical`` maps a state in this layout to the
+deduplicated one.
 
 **The state** of all slots and layers is ``ret_s [L, slots + 1, KVH, D',
 d]`` and ``ret_z [L, slots + 1, KVH, D']`` in float32, updated in place;
@@ -42,10 +49,25 @@ by the MXU's weight loads).
 ``retention_chunk``: rows of several tokens (prompt chunks).  Per row,
 KV head and block of ``D'``: ``phi(Q)`` of the row's token tiles against
 the carried state, decayed to each position, and the state's update
-``exp(G_end) S + phi(K)^T (decayed V)``; ``phi`` is formed in VMEM from
-``E`` and ``F`` (in HBM ``phi(Q)`` of a 512-token chunk would be 380 MB a
-layer).  At the first block also the masked quadratic part inside the
-row.  Matmul operands are bfloat16, sums and the state float32.
+``exp(G_end) S + phi(K)^T (decayed V)``; ``phi`` is formed in VMEM (in
+HBM ``phi(Q)`` of a 512-token chunk would be 380 MB a layer), TRANSPOSED:
+``q`` and ``k`` come in as ``[tiles, heads * d, 128 tokens]`` (``v`` by
+token: only the MXU reads it) and ``phi^T [D' block, tokens]`` is made
+pair by pair on the vector unit.  The MXU does only what the
+mathematics needs: ``phi^T(K) (decayed V)`` into the state's update and
+``S^T phi^T(Q)`` into a numerator kept transposed (``S^T`` in bfloat16
+once a grid step; the wrapper turns the numerator back); the normaliser
+``phi(Q) . z`` and ``z``'s own update are sums over sublanes and lanes
+of float32 products.  With the features on lanes, as the kernel first
+had them, forming a pair takes lane repeats and tiles, and it went
+through the MXU as two 0/1 selection products that were two thirds of
+the kernel's operations.  The heads and a block's pairs are unrolled
+(the heads by ``fori_loop(unroll=True)``, one trace), so that one head's
+vector products overlap another's matmuls.  At the first block also the
+masked quadratic part inside the row, keys on sublanes and queries on
+lanes, from the same one layout of each operand (``K^T`` and ``V``
+contracted over their first dimension).  Matmul operands are bfloat16,
+sums and the state float32.
 
 ``retention_decode_reference`` and ``retention_chunk_reference`` are the
 same contracts in plain ``jax.numpy``, one token at a time.
@@ -340,99 +362,152 @@ def retention_decode(
 
 # -- rows of several tokens -------------------------------------------------
 
-def _chunk_kernel(rows_r, n_r, slot_r, start_r, len_r, off_r, ly_r,
-                  q_ref, k_ref, v_ref, gc_ref, gr_ref, ge_ref,
-                  e_ref, f_ref, w_ref, s_in, z_in,
+@functools.lru_cache(maxsize=None)
+def _block_pairs(d: int):
+    """The pairs ``(a <= b)`` of blocks in the layout's order: row 0 the
+    block of each pair's first factor, row 1 of its second."""
+    nb = d // feature_block(d)
+    return np.asarray([(a, b) for a in range(nb) for b in range(a, nb)],
+                      np.int32).T                          # [2, pairs]
+
+
+def pair_features_t(ua: jax.Array, ub: jax.Array, weight) -> jax.Array:
+    """``phi^T`` of one pair of blocks for a tile of tokens, features on
+    sublanes and tokens on lanes: ``ua, ub [bs, TT]`` float32 (the tile's
+    ``u^T`` at the blocks ``a`` and ``b``) -> ``[bs * bs, TT]`` float32
+    whose row ``i * bs + i'`` holds ``(ua[i] * ub[i']) * weight``.  Each
+    row of ``ua`` is spread over the sublanes of ``ub``: vector products
+    and no lane moves.  One broadcast product, not ``bs`` slices: the
+    kernel's trace is host time of every set-up."""
+    bs, tt = ua.shape
+    return ((ua[:, None, :] * ub[None, :, :]) * weight).reshape(bs * bs, tt)
+
+
+def _chunk_kernel(rows_r, n_r, slot_r, start_r, len_r, off_r, ly_r, pair_r,
+                  qt_ref, kt_ref, v_ref, gc_ref, gr_ref, ge_ref, s_in, z_in,
                   num_ref, den_ref, zu_ref, s_out,
-                  ds_ref, dz_ref, *, G: int, d: int, TT: int):
+                  ds_ref, dz_ref, st_ref, zb_ref,
+                  *, G: int, d: int, TT: int, bs: int):
     del slot_r, ly_r
     j, i, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     f32, bf = jnp.float32, jnp.bfloat16
+    Db = ds_ref.shape[0]
+    pf = bs * bs                           # features of a pair of blocks
+    ppb = Db // pf                         # whole pairs in a state block
 
     @pl.when((i == 0) & (b == 0))
     def _init():
         num_ref[...] = jnp.zeros_like(num_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
 
-    def phi(u):
-        return (jnp.dot(u, e_ref[...], preferred_element_type=f32)
-                * jnp.dot(u, f_ref[...], preferred_element_type=f32)
-                * w_ref[0:1, :])
-
-    def add_den(t0, h, col):
-        lane = lax.broadcasted_iota(jnp.int32, (TT, d), 1)
-        den_ref[0, pl.ds(t0, TT), :] += jnp.where(lane == h, col, 0.0)
+    def pair_phi(u_ref, t, base, p):
+        """``phi^T`` of the tile ``t`` of ``u_ref`` (rows from ``base``)
+        at the ``p``-th pair of this state block: [pf, TT] float32."""
+        a, bb = pair_r[0, b * ppb + p], pair_r[1, b * ppb + p]
+        ua = u_ref[t, pl.ds(pl.multiple_of(base + a * bs, bs), bs), :]
+        ub = u_ref[t, pl.ds(pl.multiple_of(base + bb * bs, bs), bs), :]
+        weight = jnp.where(a == bb, 1.0, np.float32(np.sqrt(2.0)))
+        return pair_features_t(ua.astype(f32), ub.astype(f32), weight)
 
     @pl.when(i < n_r[0])
     def _row():
         r = rows_r[i]
         off, n = off_r[r], len_r[r]
         fresh = start_r[r] == 0
-        lo, hi = off // TT, (off + n - 1) // TT + 1
+        lo, hi = lax.div(off, TT), lax.div(off + n - 1, TT) + 1
         ge = ge_ref[0, 0][0:1, :]                          # [1, d]
         s_prev = jnp.where(fresh, 0.0, s_in[0, 0, 0])      # [Db, d]
         z_prev = jnp.where(fresh, 0.0, z_in[0, 0, pl.ds(j, 1), :])
-        sb = s_prev.astype(bf)
+        # the carried state as every tile and head reads it: S^T in
+        # bfloat16, z down the sublanes and across a tile's lanes
+        st_ref[...] = s_prev.T.astype(bf)                  # [d, Db]
+        zb_ref[...] = jnp.broadcast_to(z_prev, (TT, Db)).T  # [Db, TT]
         ds_ref[...] = jnp.zeros_like(ds_ref)
         dz_ref[...] = jnp.zeros_like(dz_ref)
 
         def tile(t, carry):
             t0 = pl.multiple_of(t * TT, TT)
+            # v decayed to the row's end, tokens on sublanes as it came
             tok = t0 + lax.broadcasted_iota(jnp.int32, (TT, d), 0)
-            mine = (tok >= off) & (tok < off + n)
             gc = gc_ref[0, pl.ds(t0, TT), :]               # [TT, d]
-            q_dec = jnp.where(mine, jnp.exp(gc), 0.0)
-            k_dec = jnp.where(mine, jnp.exp(ge - gc), 0.0)
-            fk = phi(k_ref[pl.ds(t0, TT), :])              # [TT, Db]
-            vd = (v_ref[pl.ds(t0, TT), :].astype(f32) * k_dec).astype(bf)
-            ds_ref[...] += lax.dot_general(
-                fk.astype(bf), vd, (((0,), (0,)), ((), ())),
-                preferred_element_type=f32)
-            dz_ref[0:1, :] += jnp.sum(fk * k_dec[:, 0:1], axis=0,
-                                      keepdims=True)
-            for h in range(G):
-                fq = phi(q_ref[pl.ds(t0, TT), h * d:(h + 1) * d])
-                num_ref[pl.ds(t0, TT), h * d:(h + 1) * d] += q_dec * jnp.dot(
-                    fq.astype(bf), sb, preferred_element_type=f32)
-                add_den(t0, h, q_dec * jnp.sum(fq * z_prev, axis=1,
-                                               keepdims=True))
-            return carry
+            vd = (v_ref[pl.ds(t0, TT), :].astype(f32) * jnp.where(
+                (tok >= off) & (tok < off + n), jnp.exp(ge - gc),
+                0.0)).astype(bf)
+            # the decays of phi^T's columns, tokens on lanes
+            tok_l = t0 + lax.broadcasted_iota(jnp.int32, (1, TT), 1)
+            mine = (tok_l >= off) & (tok_l < off + n)
+            gl = gr_ref[0, t, 0:1, :]                      # [1, TT]
+            q_dec = jnp.where(mine, jnp.exp(gl), 0.0)
+            k_dec = jnp.where(mine, jnp.exp(ge[:, 0:1] - gl), 0.0)
+
+            for p in range(ppb):
+                rows = slice(p * pf, (p + 1) * pf)
+                fk = pair_phi(kt_ref, t, 0, p)             # [pf, TT]
+                ds_ref[rows, :] += jnp.dot(fk.astype(bf), vd,
+                                           preferred_element_type=f32)
+                dz_ref[rows, :] += fk * k_dec
+
+            # the heads unrolled (traced once): one head's products
+            # overlap another's matmuls only inside one block of code
+            def head(h, carry):
+                base = pl.multiple_of(h * d, d)
+                num = jnp.zeros((d, TT), f32)
+                den = jnp.zeros((1, TT), f32)
+                for p in range(ppb):
+                    rows = slice(p * pf, (p + 1) * pf)
+                    fq = pair_phi(qt_ref, t, base, p)
+                    num += jnp.dot(st_ref[:, rows], fq.astype(bf),
+                                   preferred_element_type=f32)
+                    den += jnp.sum(fq * zb_ref[rows, :], axis=0,
+                                   keepdims=True)
+                num_ref[t, pl.ds(base, d), :] += q_dec * num
+                den_ref[0, t, pl.ds(h, 1), :] += q_dec * den
+                return carry
+
+            return lax.fori_loop(0, G, head, carry, unroll=True)
 
         lax.fori_loop(lo, hi, tile, 0)
         eg = jnp.exp(ge)
         s_out[0, 0, 0] = s_prev * eg + ds_ref[...]
-        zu_ref[0, 0] = jnp.broadcast_to(
-            z_prev * eg[0:1, 0:1] + dz_ref[0:1, :], zu_ref.shape[2:])
+        dz = jnp.sum(dz_ref[...].T, axis=0, keepdims=True)  # [1, Db]
+        zu_ref[0, 0] = jnp.broadcast_to(z_prev * eg[0:1, 0:1] + dz,
+                                        zu_ref.shape[2:])
 
         @pl.when(b == 0)
         def _inside():
-            # the quadratic part between the row's own tokens
+            # the quadratic part between the row's own tokens, keys on
+            # sublanes and queries on lanes: K^T and V are contracted
+            # over their first dimension
             def q_tile(tq, carry):
-                q0 = pl.multiple_of(tq * TT, TT)
-                tok_q = q0 + lax.broadcasted_iota(jnp.int32, (TT, TT), 0)
-                gq = gc_ref[0, pl.ds(q0, TT), :][:, 0:1]   # [TT, 1]
+                tok_q = tq * TT + lax.broadcasted_iota(
+                    jnp.int32, (TT, TT), 1)
+                gq = gr_ref[0, tq, 0:1, :]                 # [1, TT]
 
                 def k_tile(tk, carry):
                     k0 = pl.multiple_of(tk * TT, TT)
                     tok_k = k0 + lax.broadcasted_iota(
-                        jnp.int32, (TT, TT), 1)
+                        jnp.int32, (TT, TT), 0)
                     seen = ((tok_q >= tok_k) & (tok_k >= off)
                             & (tok_q < off + n))
-                    decay = jnp.exp(jnp.where(
-                        seen, gq - gr_ref[0, tk, 0:1, :], _NEG))
-                    kt = k_ref[pl.ds(k0, TT), :]
-                    vt = v_ref[pl.ds(k0, TT), :]
-                    for h in range(G):
+                    gk = gc_ref[0, pl.ds(k0, TT), :][:, 0:1]  # [TT, 1]
+                    decay = jnp.exp(jnp.where(seen, gq - gk, _NEG))
+                    kt = kt_ref[tk]                        # [d, TT]
+                    vk = v_ref[pl.ds(k0, TT), :]           # [TT, d]
+
+                    def head(h, carry):
+                        at = pl.ds(pl.multiple_of(h * d, d), d)
                         s = lax.dot_general(
-                            q_ref[pl.ds(q0, TT), h * d:(h + 1) * d], kt,
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=f32)    # [TT, TT]
-                        a = s * s * decay
-                        num_ref[pl.ds(q0, TT), h * d:(h + 1) * d] += \
-                            jnp.dot(a.astype(bf), vt,
-                                    preferred_element_type=f32)
-                        add_den(q0, h, jnp.sum(a, axis=1, keepdims=True))
-                    return carry
+                            kt, qt_ref[tq, at, :], (((0,), (0,)), ((), ())),
+                            preferred_element_type=f32)
+                        a = s * s * decay                  # [keys, queries]
+                        num_ref[tq, at, :] += lax.dot_general(
+                            vk, a.astype(bf), (((0,), (0,)), ((), ())),
+                            preferred_element_type=f32)
+                        den_ref[0, tq, pl.ds(h, 1), :] += jnp.sum(
+                            a, axis=0, keepdims=True)
+                        return carry
+
+                    return lax.fori_loop(0, G, head, carry, unroll=True)
 
                 return lax.fori_loop(lo, tq + 1, k_tile, carry)
 
@@ -461,10 +536,14 @@ def retention_chunk(
     L, S1, KVH, Dp, _ = ret_s.shape
     R = row_slot.shape[0]
     G = H // KVH
-    assert G <= d and Dp == feature_dim(d)
+    assert G <= FEAT_ROWS and Dp == feature_dim(d)
     Db = state_block(Dp)
+    bs = feature_block(d)
+    assert Db % (bs * bs) == 0, (Db, bs)
     f32, i32, bf = jnp.float32, jnp.int32, jnp.bfloat16
-    TT = 128 if T >= 128 else 8
+    # a tile of tokens lies along the lanes: a lane width where the blocks
+    # are the published 16, 8 at the tests' small heads (the interpreter)
+    TT = 128 if bs >= 16 else 8
     Tp = -(-T // TT) * TT
     NT = Tp // TT
     row_slot, row_start, row_len, row_off = (
@@ -476,35 +555,39 @@ def retention_chunk(
     def padded(a):
         return jnp.pad(a, ((0, Tp - T),) + ((0, 0),) * (a.ndim - 1))
 
-    q2 = padded(q.astype(bf).reshape(T, H * d))
-    k2 = padded(k.astype(bf).reshape(T, KVH * d))
+    def tiles_t(a):
+        """``[Tp, C] -> [NT, C, TT]``: each tile of tokens transposed."""
+        return a.reshape(NT, TT, -1).transpose(0, 2, 1)
+
+    qt = tiles_t(padded(q.astype(bf).reshape(T, H * d)))
+    kt = tiles_t(padded(k.astype(bf).reshape(T, KVH * d)))
     v2 = padded(v.astype(bf).reshape(T, KVH * d))
     gc = padded(gc).T                                      # [KVH, Tp]
     gc_col = jnp.broadcast_to(gc[:, :, None], (KVH, Tp, d))
     gc_row = jnp.broadcast_to(gc.reshape(KVH, NT, 1, TT),
                               (KVH, NT, FEAT_ROWS, TT))
     ge8 = jnp.broadcast_to(g_end[:, :, None, None], (R, KVH, FEAT_ROWS, d))
-    e, f, w = feature_maps(d)
-    e, f = jnp.asarray(e, bf), jnp.asarray(f, bf)
-    w8 = jnp.broadcast_to(jnp.asarray(w)[None, :], (FEAT_ROWS, Dp))
 
-    def s_map(j, i, b, rows_p, n_p, slot_p, st, ln, of, ly):
+    def s_map(j, i, b, rows_p, n_p, slot_p, st, ln, of, ly, *pf):
         return (ly[0], _listed_slot(i, rows_p, n_p, slot_p, S1 - 1), j, b, 0)
 
-    def z_map(j, i, b, rows_p, n_p, slot_p, st, ln, of, ly):
+    def z_map(j, i, b, rows_p, n_p, slot_p, st, ln, of, ly, *pf):
         return (ly[0], _listed_slot(i, rows_p, n_p, slot_p, S1 - 1), 0, b)
 
     def zu_map(j, i, b, rows_p, n_p, *pf):
         return (_listed_row(i, rows_p, n_p, R), j, 0, b)
 
+    def by_head(j, i, b, *pf):
+        return (0, j, 0)
+
     interpret = platform.interpret_mode()
     s_spec = pl.BlockSpec((1, 1, 1, Db, d), s_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=8,
         grid=(KVH, R if interpret else n[0], Dp // Db),
         in_specs=[
-            pl.BlockSpec((Tp, G * d), lambda j, i, b, *pf: (0, j)),
-            pl.BlockSpec((Tp, d), lambda j, i, b, *pf: (0, j)),
+            pl.BlockSpec((NT, G * d, TT), by_head),
+            pl.BlockSpec((NT, d, TT), by_head),
             pl.BlockSpec((Tp, d), lambda j, i, b, *pf: (0, j)),
             pl.BlockSpec((1, Tp, d), lambda j, i, b, *pf: (j, 0, 0)),
             pl.BlockSpec((1, NT, FEAT_ROWS, TT),
@@ -512,46 +595,47 @@ def retention_chunk(
             pl.BlockSpec((1, 1, FEAT_ROWS, d),
                          lambda j, i, b, rows_p, n_p, *pf:
                          (_listed_row(i, rows_p, n_p, 0), j, 0, 0)),
-            pl.BlockSpec((d, Db), lambda j, i, b, *pf: (0, b)),
-            pl.BlockSpec((d, Db), lambda j, i, b, *pf: (0, b)),
-            pl.BlockSpec((FEAT_ROWS, Db), lambda j, i, b, *pf: (0, b)),
             s_spec,
             pl.BlockSpec((1, 1, KVH, Db), z_map),
         ],
         out_specs=[
-            pl.BlockSpec((Tp, G * d), lambda j, i, b, *pf: (0, j)),
-            pl.BlockSpec((1, Tp, d), lambda j, i, b, *pf: (j, 0, 0)),
+            pl.BlockSpec((NT, G * d, TT), by_head),
+            pl.BlockSpec((1, NT, FEAT_ROWS, TT),
+                         lambda j, i, b, *pf: (j, 0, 0, 0)),
             pl.BlockSpec((1, 1, FEAT_ROWS, Db), zu_map),
             s_spec,
         ],
         scratch_shapes=[pltpu.VMEM((Db, d), f32),
-                        pltpu.VMEM((FEAT_ROWS, Db), f32)],
+                        pltpu.VMEM((Db, TT), f32),
+                        pltpu.VMEM((d, Db), bf),
+                        pltpu.VMEM((Db, TT), f32)],
     )
     num, den, z_rows, ret_s = pl.pallas_call(
-        functools.partial(_chunk_kernel, G=G, d=d, TT=TT),
+        functools.partial(_chunk_kernel, G=G, d=d, TT=TT, bs=bs),
         name="retention_chunk",
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Tp, H * d), f32),
-                   jax.ShapeDtypeStruct((KVH, Tp, d), f32),
+        out_shape=[jax.ShapeDtypeStruct((NT, H * d, TT), f32),
+                   jax.ShapeDtypeStruct((KVH, NT, FEAT_ROWS, TT), f32),
                    jax.ShapeDtypeStruct((R + 1, KVH, FEAT_ROWS, Dp), f32),
                    jax.ShapeDtypeStruct(ret_s.shape, ret_s.dtype)],
-        # prefetch: rows=0 n=1 slot=2 start=3 len=4 off=5 layer=6, then
-        # q=7 k=8 v=9 gc_col=10 gc_row=11 g_end=12 e=13 f=14 w=15
-        # ret_s=16 ret_z=17
-        input_output_aliases={16: 3},
+        # prefetch: rows=0 n=1 slot=2 start=3 len=4 off=5 layer=6 and the
+        # pairs' blocks 7; then q^T=8 k^T=9 v=10 gc_col=11 gc_row=12
+        # g_end=13 ret_s=14 ret_z=15
+        input_output_aliases={14: 3},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3,
             vmem_limit_bytes=64 * 2**20),
         interpret=interpret,
     )(rows, n, row_slot, row_start, row_len, row_off,
-      jnp.asarray(layer, i32).reshape(1),
-      q2, k2, v2, gc_col, gc_row, ge8, e, f, w8, ret_s, ret_z)
+      jnp.asarray(layer, i32).reshape(1), jnp.asarray(_block_pairs(d)),
+      qt, kt, v2, gc_col, gc_row, ge8,
+      ret_s, ret_z)
     ret_z = _scatter_z(ret_z, layer, z_rows[:R, :, 0], row_slot, many)
-    den = den[:, :T, :G].transpose(1, 0, 2).reshape(T, H)
+    num = num.transpose(0, 2, 1).reshape(Tp, H, d)[:T]
+    den = den[:, :, :G].transpose(1, 3, 0, 2).reshape(Tp, H)[:T]
     tok_row, valid = token_rows(row_len, row_off, T)
     mine = valid & many[tok_row]
-    y = jnp.where(mine[:, None, None],
-                  num[:T].reshape(T, H, d) / (den[:, :, None] + eps), 0.0)
+    y = jnp.where(mine[:, None, None], num / (den[:, :, None] + eps), 0.0)
     return y, ret_s, ret_z
 
 

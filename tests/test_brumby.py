@@ -63,14 +63,17 @@ def _rows(rows, budget=BUDGET, slots=SLOTS):
     return pack_ragged_batch(rows, budget, slots)
 
 
-def _operands(rng, T=BUDGET, L=2):
+def _operands(rng, T=BUDGET, L=2, heads=4, kv_heads=2, d=D):
     f32 = jnp.float32
-    q = jnp.asarray(rng.standard_normal((T, 4, D)), f32)
-    k = jnp.asarray(rng.standard_normal((T, 2, D)), f32)
-    v = jnp.asarray(rng.standard_normal((T, 2, D)), f32)
-    log_g = jnp.asarray(np.log(rng.uniform(0.8, 0.999, (T, 2))), f32)
-    s0 = jnp.asarray(rng.standard_normal((L, SLOTS + 1, 2, DP, D)), f32)
-    z0 = jnp.asarray(np.abs(rng.standard_normal((L, SLOTS + 1, 2, DP))), f32)
+    dp = pr.feature_dim(d)
+    q = jnp.asarray(rng.standard_normal((T, heads, d)), f32)
+    k = jnp.asarray(rng.standard_normal((T, kv_heads, d)), f32)
+    v = jnp.asarray(rng.standard_normal((T, kv_heads, d)), f32)
+    log_g = jnp.asarray(np.log(rng.uniform(0.8, 0.999, (T, kv_heads))), f32)
+    s0 = jnp.asarray(
+        rng.standard_normal((L, SLOTS + 1, kv_heads, dp, d)), f32)
+    z0 = jnp.asarray(
+        np.abs(rng.standard_normal((L, SLOTS + 1, kv_heads, dp))), f32)
     return q, k, v, log_g, s0, z0
 
 
@@ -92,42 +95,93 @@ def test_feature_map_squares_the_score():
     assert ref.phi(jnp.zeros((128,))).shape == (8256,)
 
 
-def test_recurrence_is_the_quadratic_form():
-    """One row whole through the token-by-token twin: outputs and the
-    final (S, z) are the reference's quadratic sum and direct sum."""
+@pytest.mark.parametrize("d", [16, 128])
+def test_pair_features_t_is_the_feature_map(d):
+    """What the chunk kernel forms of a tile on the vector unit, pair of
+    blocks by pair, features on sublanes: ``features(u).T`` bit for bit,
+    in float32 and as the kernel casts it for the MXU."""
+    rng = np.random.default_rng(4)
+    u = jnp.asarray(rng.standard_normal((8, d)), jnp.bfloat16)
+    ut = u.astype(jnp.float32).T                           # [d, tokens]
+    bs = pr.feature_block(d)
+    pairs = zip(*pr._block_pairs(d))
+    got = jnp.concatenate([
+        pr.pair_features_t(ut[a * bs:(a + 1) * bs], ut[b * bs:(b + 1) * bs],
+                           np.float32(1.0 if a == b else np.sqrt(2.0)))
+        for a, b in pairs], axis=0)
+    want = pr.features(u).T
+    assert got.shape == want.shape == (pr.feature_dim(d), 8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.bfloat16).astype(jnp.float32)),
+        np.asarray(want.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("through,limits", [
+    (pr.retention_chunk_reference, lambda want: dict(rtol=1e-4, atol=1e-4)),
+    # bfloat16 operands on the MXU: the limit the kernels are held to
+    # against their twins, of the largest entry
+    (pr.retention_chunk, lambda want: dict(
+        rtol=TOL, atol=TOL * float(np.max(np.abs(want))))),
+], ids=["twin", "kernel"])
+def test_recurrence_is_the_quadratic_form(through, limits):
+    """One row whole through the token-by-token twin, and through the
+    chunk kernel (three tiles of a tile's tokens and five feature
+    blocks): outputs and the final (S, z) are the reference's quadratic
+    sum and direct sum."""
     rng = np.random.default_rng(1)
     q, k, v, log_g, s0, z0 = _operands(rng)
     (_t, _m, _s, _p, rs, r0, rl, ro) = _rows(
         [{"slot": 1, "start": 0, "tokens": list(range(BUDGET))}])
-    y, s1, z1 = pr.retention_chunk_reference(
+    y, s1, z1 = through(
         q.astype(jnp.bfloat16).astype(jnp.float32), k, v, log_g, s0, z0, 0,
         rs, r0, rl, ro)
     bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
     with jax.default_matmul_precision("highest"):
         want = ref.retention(bf(q), bf(k), bf(v), log_g)
         want_s, want_z = ref.final_state(bf(k), bf(v), log_g)
-    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-4)
+
+    def close(got, want):
+        np.testing.assert_allclose(got, want, **limits(want))
+
+    close(y, want)
     for j in range(2):
-        np.testing.assert_allclose(pr.to_canonical(s1[0, 1, j], D),
-                                   want_s[j], rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(pr.to_canonical(z1[0, 1, j], D),
-                                   want_z[j], rtol=1e-4, atol=1e-4)
+        close(pr.to_canonical(s1[0, 1, j], D), want_s[j])
+        close(pr.to_canonical(z1[0, 1, j], D), want_z[j])
 
 
 MIXED = [{"slot": 2, "start": 5, "tokens": None},
          {"slot": 0, "start": 0, "tokens": list(range(11))},
          {"slot": 3, "start": 7, "tokens": list(range(9))},
          {"slot": 1, "start": 3, "tokens": None}]
+# a chunk that starts at position 1 of the buffer and ends inside its
+# fourth tile of 8, a second one from there to inside the fifth
+RAGGED = [{"slot": 2, "start": 5, "tokens": None},
+          {"slot": 0, "start": 0, "tokens": list(range(26))},
+          {"slot": 3, "start": 7, "tokens": list(range(10))},
+          {"slot": 1, "start": 3, "tokens": None}]
+# at the published head size a tile is 128 tokens: the first chunk ends
+# one short of the tile's end, the second lies across it
+WIDE = [{"slot": 2, "start": 5, "tokens": None},
+        {"slot": 0, "start": 0, "tokens": list(range(126))},
+        {"slot": 3, "start": 7, "tokens": list(range(9))},
+        {"slot": 1, "start": 3, "tokens": None}]
 
 
-@pytest.mark.parametrize("kernel,twin", [
-    (pr.retention_decode, pr.retention_decode_reference),
-    (pr.retention_chunk, pr.retention_chunk_reference),
-], ids=["retention_decode", "retention_chunk"])
-def test_kernel_matches_its_twin(kernel, twin):
+@pytest.mark.parametrize("kernel,twin,rows,shape", [
+    (pr.retention_decode, pr.retention_decode_reference, MIXED, {}),
+    (pr.retention_chunk, pr.retention_chunk_reference, MIXED, {}),
+    (pr.retention_chunk, pr.retention_chunk_reference, RAGGED,
+     dict(T=40)),
+    # blocks of 16, D' 9216, nine state blocks of four whole pairs
+    (pr.retention_chunk, pr.retention_chunk_reference, WIDE,
+     dict(T=144, heads=2, kv_heads=1, d=128)),
+], ids=["retention_decode", "retention_chunk", "retention_chunk_ragged",
+        "retention_chunk_d128"])
+def test_kernel_matches_its_twin(kernel, twin, rows, shape):
     rng = np.random.default_rng(2)
-    q, k, v, log_g, s0, z0 = _operands(rng)
-    (_t, _m, _s, _p, rs, r0, rl, ro) = _rows(MIXED)
+    q, k, v, log_g, s0, z0 = _operands(rng, **shape)
+    (_t, _m, _s, _p, rs, r0, rl, ro) = _rows(rows, shape.get("T", BUDGET))
     # a padding row between live ones
     rl = np.array(rl)
     rs, r0, rl, ro = (np.insert(a, 1, 0) for a in (rs, r0, rl, ro))
