@@ -1291,7 +1291,7 @@ def _measure_8b_train(peak_flops: float) -> dict:
     extras: dict = {}
     tps = _measure(
         cfg8t, devs, steps=3, batch=batch,
-        optimizer=adamw8bit(1e-4, warmup_steps=10, shard_update=True),
+        optimizer=adamw8bit(1e-4, warmup_steps=10),
         trainer_config=TrainerConfig(zero_sharding=True,
                                      grad_accum=grad_accum),
         extras=extras,

@@ -656,6 +656,50 @@ def _kernels_block_sparse(d: KernelDims) -> None:
             f"{cells.tolist()} cells of {G}; the host {host.tolist()}")
 
 
+def _kernels_adam8(d: KernelDims) -> None:
+    """``adam8_update`` (the whole fused chain, applied) against its
+    plain form over two steps, on a view of each kind: rows of whole
+    blocks, blocks of two rows, rows that end in a short block with a
+    ragged last tile.  Mosaic and XLA divide and take roots their own
+    way, so the limits leave a code one step and a parameter one
+    rounding of room; at PR 48 nothing differed, to the bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from ray_tpu.ops import adam8bit
+
+    hp = adam8bit.Adam8(fused=True, clip=1.0, weight_decay=0.1, apply=True)
+    kernel = jax.jit(adam8bit.adam8_update, static_argnames="hp")
+    plain = jax.jit(adam8bit.adam8_update_reference, static_argnames="hp")
+    for rows, cols in ((512, 2048), (1024, 128), (256, 2432)):
+        assert adam8bit.tile_shape(rows, cols) is not None
+        ks = jax.random.split(jax.random.key(rows), 3)
+        p = _rand(ks[0], (rows, cols), jnp.bfloat16) * 0.02
+        zero = (jnp.zeros((rows, cols), jnp.int8), jnp.full(
+            adam8bit.scale_shape(rows, cols), 1e-12, jnp.float32))
+        got = want = (p,) + zero + zero
+        for i in (1, 2):
+            g = _rand(ks[i], (rows, cols), jnp.bfloat16) * 0.01 * i
+            scal = adam8bit.scalars(hp, jnp.int32(i), jnp.bfloat16,
+                                    gnorm=optax.global_norm(g),
+                                    step_size=jnp.float32(-1e-3))
+            got = kernel(scal, g, got[0], *got[1:], hp=hp)
+            want = plain(scal, g, want[0], *want[1:], hp=hp)
+        name = f"adam8_update [{rows}, {cols}]"
+        check_close(f"{name} (parameter)", got[0], want[0], 2.0 ** -7)
+        for what, i in (("mu", 1), ("nu", 3)):
+            check_close(f"{name} ({what} scales)", got[i + 1], want[i + 1],
+                        1e-5)
+            off = np.abs(np.asarray(got[i], np.int32)
+                         - np.asarray(want[i], np.int32))
+            log(f"  kernel={name} ({what} codes) differ in "
+                f"{float((off > 0).mean()):.2e} by at most {int(off.max())}")
+            if off.max() > 1 or (off > 0).mean() > 1e-2:
+                raise AssertionError(f"{name}: {what} codes differ")
+
+
 def phase_kernels(platform: str, *, dims: KernelDims = KernelDims()) -> dict:
     require_platform(platform)
     _kernels_flash(dims, platform)
@@ -664,6 +708,7 @@ def phase_kernels(platform: str, *, dims: KernelDims = KernelDims()) -> dict:
     _kernels_fused(dims)
     _kernels_lightning(dims)
     _kernels_block_sparse(dims)
+    _kernels_adam8(dims)
     return {}
 
 

@@ -150,7 +150,7 @@ def current_mesh():
     is set, else the thread-resources physical mesh (the trainer's
     ``with mesh:`` idiom), else None.  Lets traced code adapt its
     sharding constraints to whatever mesh it is being partitioned for
-    (see train/optim8.py's ZeRO block constraints)."""
+    (train/optim8.py runs its kernel only where nothing is partitioned)."""
     from jax._src import mesh as _mesh_lib
 
     abstract = jax.sharding.get_abstract_mesh()
@@ -158,17 +158,6 @@ def current_mesh():
         return abstract
     physical = _mesh_lib.thread_resources.env.physical_mesh
     return None if physical.empty else physical
-
-
-def constrain_to_spec(x: jax.Array, spec: P) -> jax.Array:
-    """with_sharding_constraint against the current mesh (abstract or
-    physical); no-op outside any mesh context."""
-    mesh = current_mesh()
-    if mesh is None:
-        return x
-    if isinstance(mesh, Mesh):
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def shard_tree(mesh: Mesh, tree: Any, logical_tree: Any,

@@ -2,42 +2,46 @@
 analogue).
 
 The reference ecosystem fits big models with 8-bit optimizers
-(bitsandbytes' CUDA kernels); on TPU the same memory play is plain XLA:
-Adam's m/v tensors live as int8 with one float32 absmax scale per
-256-element block, dequantized/requantized inside the fused update —
-2 bytes/param of optimizer state instead of 8, which is what lets a
-~2.4B-param AdamW config train on one 16 GB chip (bench.py's measured
-multi-billion point).  Quantization error behaves like rounding noise
-on m/v; each block keeps full dynamic range via its own scale.
+(bitsandbytes' CUDA kernels).  Here Adam's m/v tensors live as int8 with
+one float32 absmax scale per block of at most 256 consecutive elements,
+laid out AS THE LEAF IS (``ops/adam8bit.py`` says how a leaf's shape
+decides its blocks), and one Mosaic kernel a leaf dequantises, updates
+and requantises them where they lie — 2 bytes/param of optimizer state
+instead of 8, which is what lets a ~2.4B-param AdamW config train on one
+16 GB chip, at 14 bytes of memory traffic a parameter and step.
+Quantization error behaves like rounding noise on m/v; each block keeps
+full dynamic range via its own scale.
+
+Under a mesh of several devices the same update runs as plain
+``jax.numpy`` (``adam8bit.adam8_update_reference``), which the
+partitioner can shard: blocks run along a leaf's last axis, so the
+state shards by rows as the parameters' mirrors do (train/zero.py) and
+no shard cuts a block.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Any, NamedTuple, Optional
+import functools
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import optax
 
-BLOCK = 256
+from ray_tpu.ops import adam8bit
+from ray_tpu.ops.adam8bit import BLOCK, Adam8  # noqa: F401  (re-export)
+from ray_tpu.parallel.sharding import current_mesh
 
 
-def _quantize(x: jax.Array):
-    """flat float32 → (int8 [nb, BLOCK], f32 scale [nb, 1])."""
-    flat = x.reshape(-1).astype(jnp.float32)
-    pad = (-flat.shape[0]) % BLOCK
-    blocks = jnp.pad(flat, (0, pad)).reshape(-1, BLOCK)
-    scale = jnp.max(jnp.abs(blocks), axis=1, keepdims=True) / 127.0
-    scale = jnp.maximum(scale, 1e-12)
-    q = jnp.clip(jnp.round(blocks / scale), -127, 127).astype(jnp.int8)
-    return (q, scale)
-
-def _dequantize(s, shape) -> jax.Array:
-    q, scale = s
-    n = math.prod(shape)
-    flat = (q.astype(jnp.float32) * scale).reshape(-1)
-    return flat[:n].reshape(shape)
+class InPlaceTransformation(NamedTuple):
+    """An ``optax.GradientTransformation`` (``init``, ``update``) that
+    can also ``apply(grads, state, params) -> (params, state)``: the
+    update added to the parameter where it is computed, which saves
+    ``optax.apply_updates``' read and write of every parameter
+    (``train/step.apply_gradients`` asks for it)."""
+    init: Callable
+    update: Callable
+    apply: Callable
 
 
 class ScaleByAdam8State(NamedTuple):
@@ -46,129 +50,65 @@ class ScaleByAdam8State(NamedTuple):
     nu: Any
 
 
-def _constrain_blocks(x: jax.Array, dim: int = 0) -> jax.Array:
-    """Pin the block dim of an int8-Adam buffer to the ZeRO shard axes
-    of whatever mesh encloses the trace (train/zero.py's layout), so
-    the partitioner keeps the blockwise update local to each shard
-    instead of gathering state — the reduce-scatter → local-update →
-    all-gather pattern of arXiv 2004.13336.  No-op outside a mesh or
-    when the block count doesn't divide the shard axes."""
-    from jax.sharding import PartitionSpec as P
+def _init(params) -> ScaleByAdam8State:
+    def zero(p):
+        rows, cols = adam8bit.view_shape(p.shape)
+        return (jnp.zeros((rows, cols), jnp.int8),
+                jnp.full(adam8bit.scale_shape(rows, cols), 1e-12,
+                         jnp.float32))
 
-    from ray_tpu.parallel.sharding import constrain_to_spec, current_mesh
-    from ray_tpu.train import zero as zero_mod
+    return ScaleByAdam8State(jnp.zeros([], jnp.int32),
+                             jax.tree.map(zero, params),
+                             jax.tree.map(zero, params))
 
+
+def _sharded() -> bool:
+    """Is the trace partitioned over several devices?  Then the plain
+    ``jax.numpy`` update serves: a kernel is opaque to the partitioner."""
     mesh = current_mesh()
-    if mesh is None:
-        return x
-    ax = zero_mod.shardable_prefix(
-        x.shape[dim], zero_mod.zero_axes(mesh), mesh)
-    if not ax:
-        return x
-    entries = [None] * x.ndim
-    entries[dim] = ax[0] if len(ax) == 1 else ax
-    return constrain_to_spec(x, P(*entries))
+    return mesh is not None and mesh.size > 1
+
+
+def _update(hp: Adam8, grads, state: ScaleByAdam8State, params, *,
+            gnorm=None, step_size=None):
+    """Every leaf through ``adam8_update`` in its own layout."""
+    count = state.count + 1
+    step = (adam8bit.adam8_update_reference if _sharded()
+            else adam8bit.adam8_update)
+
+    scal = {}   # the step's scalars, once a dtype
+
+    def leaf(g, p, mu, nu):
+        view = functools.partial(adam8bit.to_view, rows=mu[0].shape[0],
+                                 cols=mu[0].shape[1])
+        if g.dtype not in scal:
+            scal[g.dtype] = adam8bit.scalars(hp, count, g.dtype, gnorm,
+                                             step_size)
+        out, mq, ms, nq, ns = step(
+            scal[g.dtype], view(g), view(p) if hp.fused else None,
+            *mu, *nu, hp=hp)
+        return adam8bit.from_view(out, g.shape), (mq, ms), (nq, ns)
+
+    flat_g, treedef = jax.tree.flatten(grads)
+    flat_p = treedef.flatten_up_to(params) if hp.fused else flat_g
+    outs = [leaf(*a) for a in zip(flat_g, flat_p,
+                                  treedef.flatten_up_to(state.mu),
+                                  treedef.flatten_up_to(state.nu))]
+    return (treedef.unflatten([o[0] for o in outs]),
+            ScaleByAdam8State(count,
+                              treedef.unflatten([o[1] for o in outs]),
+                              treedef.unflatten([o[2] for o in outs])))
 
 
 def scale_by_adam8bit(b1: float = 0.9, b2: float = 0.95,
-                      eps: float = 1e-8, *, shard_update: bool = False
-                      ) -> optax.GradientTransformation:
-    """Adam moment tracking with int8 block-quantized mu/nu.
-
-    ``shard_update=True`` adds ZeRO sharding constraints on the block
-    dim of every buffer entering/leaving the fused update (grads in
-    block space, the segment-stacked m/v, and their replacements), for
-    use with ``TrainerConfig(zero_sharding=True)``."""
-
-    def init(params):
-        q0 = lambda p: _quantize(jnp.zeros(p.shape, jnp.float32))
-        return ScaleByAdam8State(
-            jnp.zeros([], jnp.int32),
-            jax.tree.map(q0, params),
-            jax.tree.map(q0, params),
-        )
+                      eps: float = 1e-8) -> optax.GradientTransformation:
+    """Adam moment tracking with int8 block-quantized mu/nu."""
+    hp = Adam8(b1=b1, b2=b2, eps=eps)
 
     def update(grads, state, params=None):
-        count = state.count + 1
-        cf = count.astype(jnp.float32)
+        return _update(hp, grads, state, params)
 
-        def upd(g, mq, nq):
-            # The whole update runs in BLOCK space, streamed over
-            # segments with lax.map: dequantizing a multi-hundred-M
-            # stacked leaf's m, v, and grads to f32 at once is
-            # ~5 x leaf f32 bytes of transient HBM — the difference
-            # between a 2.2B model fitting a 16 GB chip or not.
-            shape, dt = g.shape, g.dtype
-            nb = mq[0].shape[0]
-            pad = nb * BLOCK - math.prod(shape)
-            gb = jnp.pad(g.reshape(-1), (0, pad)).reshape(nb, BLOCK)
-            if shard_update:
-                gb = _constrain_blocks(gb)
-            nseg = min(16, nb)
-            segp = (-nb) % nseg
-            def seg(args):
-                gs, mqs, mss, nqs, nss = args
-                g32 = gs.astype(jnp.float32)
-                m = mqs.astype(jnp.float32) * mss
-                # nu stored as sqrt(v): linear int8 only spans a 127:1
-                # ratio per block — storing the root doubles the
-                # covered dynamic range, which is the difference
-                # between converging and small-v blocks rounding to 0
-                # (update explosion).  (bitsandbytes uses a nonlinear
-                # dynamic code for the same reason.)
-                u = nqs.astype(jnp.float32) * nss
-                n = b2 * (u * u) + (1 - b2) * (g32 * g32)
-                m = b1 * m + (1 - b1) * g32
-                mhat = m / (1 - b1 ** cf)
-                nhat = n / (1 - b2 ** cf)
-                out = mhat / (jnp.sqrt(nhat) + eps)
-                out = jnp.clip(out, -10.0, 10.0).astype(dt)
-                ms2 = jnp.maximum(
-                    jnp.max(jnp.abs(m), axis=1, keepdims=True) / 127.0,
-                    1e-12)
-                mq2 = jnp.clip(jnp.round(m / ms2), -127, 127
-                               ).astype(jnp.int8)
-                un = jnp.sqrt(n)
-                ns2 = jnp.maximum(
-                    jnp.max(un, axis=1, keepdims=True) / 127.0, 1e-12)
-                nq2 = jnp.clip(jnp.round(un / ns2), -127, 127
-                               ).astype(jnp.int8)
-                return out, mq2, ms2, nq2, ns2
-
-            def segify(x):
-                if segp:
-                    x = jnp.concatenate(
-                        [x, jnp.zeros((segp,) + x.shape[1:], x.dtype)])
-                return x.reshape(nseg, -1, *x.shape[1:])
-
-            args = tuple(segify(a) for a in
-                         (gb, mq[0], mq[1], nq[0], nq[1]))
-            if shard_update:
-                args = tuple(_constrain_blocks(a, dim=1) for a in args)
-            out, mq2, ms2, nq2, ns2 = jax.lax.map(seg, args)
-            if shard_update:
-                mq2, ms2, nq2, ns2 = (
-                    _constrain_blocks(a, dim=1)
-                    for a in (mq2, ms2, nq2, ns2))
-            out = out.reshape(-1)[: math.prod(shape)].reshape(shape)
-
-            def unseg(x):
-                x = x.reshape(-1, *x.shape[2:])
-                return x[:nb] if segp else x
-
-            return (out, (unseg(mq2), unseg(ms2)),
-                    (unseg(nq2), unseg(ns2)))
-
-        flat_g, treedef = jax.tree.flatten(grads)
-        flat_m = treedef.flatten_up_to(state.mu)
-        flat_n = treedef.flatten_up_to(state.nu)
-        outs = [upd(g, m, n) for g, m, n in zip(flat_g, flat_m, flat_n)]
-        return (treedef.unflatten([o[0] for o in outs]),
-                ScaleByAdam8State(count,
-                                  treedef.unflatten([o[1] for o in outs]),
-                                  treedef.unflatten([o[2] for o in outs])))
-
-    return optax.GradientTransformation(init, update)
+    return optax.GradientTransformation(_init, update)
 
 
 def adamw8bit(
@@ -181,11 +121,14 @@ def adamw8bit(
     grad_clip: float = 1.0,
     warmup_steps: int = 100,
     total_steps: Optional[int] = None,
-    shard_update: bool = False,
-) -> optax.GradientTransformation:
+) -> InPlaceTransformation:
     """AdamW with 8-bit states + the same schedule/clipping wrapping as
-    train.default_optimizer.  ``shard_update=True`` enables the ZeRO
-    block-dim sharding constraints (see scale_by_adam8bit)."""
+    train.default_optimizer: ``optax.chain(clip_by_global_norm,
+    scale_by_adam8bit, add_decayed_weights, scale_by_learning_rate)``
+    as ONE transformation, because the clip's scale, the decay and the
+    step size ride in the moments' one pass over the leaf (rounded to
+    the leaf's dtype where the compiled chain rounds:
+    ``adam8bit._stepped``)."""
     if total_steps:
         schedule = optax.warmup_cosine_decay_schedule(
             0.0, learning_rate, warmup_steps,
@@ -193,12 +136,19 @@ def adamw8bit(
     else:
         schedule = optax.linear_schedule(
             0.0, learning_rate, max(1, warmup_steps))
-    parts = []
-    if grad_clip:
-        parts.append(optax.clip_by_global_norm(grad_clip))
-    parts.append(scale_by_adam8bit(b1=b1, b2=b2, eps=eps,
-                                   shard_update=shard_update))
-    if weight_decay:
-        parts.append(optax.add_decayed_weights(weight_decay))
-    parts.append(optax.scale_by_learning_rate(schedule))
-    return optax.chain(*parts)
+    hp = Adam8(b1=b1, b2=b2, eps=eps, fused=True,
+               clip=float(grad_clip or 0.0),
+               weight_decay=float(weight_decay or 0.0))
+
+    def run(hp, grads, state, params=None):
+        if params is None:
+            raise ValueError("adamw8bit reads the parameters (weight "
+                             "decay): call update(grads, state, params)")
+        gnorm = optax.global_norm(grads) if hp.clip else 0.0
+        # the chain's schedule counts the updates BEFORE this one
+        return _update(hp, grads, state, params, gnorm=gnorm,
+                       step_size=-schedule(state.count))
+
+    return InPlaceTransformation(
+        _init, functools.partial(run, hp),
+        functools.partial(run, hp._replace(apply=True)))

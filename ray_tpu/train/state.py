@@ -83,7 +83,8 @@ def state_shardings(
     """Shardings for a whole TrainState.  ``zero=True`` switches to the
     ZeRO layout (train/zero.py): optimizer state — including optim8's
     int8 (q, scale) blockwise leaves, which the mirror-structure check
-    below can only replicate — shards over the data axes."""
+    below can only replicate — shards over the data axes (the codes and
+    scales of a leaf by its rows)."""
     if zero:
         from ray_tpu.train.zero import zero_state_shardings
 

@@ -85,6 +85,18 @@ def _instrument_first_call(jitted):
 LossFn = Callable[[Any, Dict[str, jax.Array]], Tuple[jax.Array, Dict[str, jax.Array]]]
 
 
+def apply_gradients(tx, grads, opt_state, params):
+    """``tx.update`` then ``optax.apply_updates`` -> (params, opt_state).
+    A transformation that adds its update to the parameter in the pass
+    that computes it (``train/optim8.InPlaceTransformation``) brings its
+    own ``apply`` with that contract, which is used instead."""
+    apply = getattr(tx, "apply", None)
+    if apply is not None:
+        return apply(grads, opt_state, params)
+    updates, opt_state = tx.update(grads, opt_state, params)
+    return optax.apply_updates(params, updates), opt_state
+
+
 def make_train_step(
     loss_fn: LossFn,
     tx: optax.GradientTransformation,
@@ -137,9 +149,8 @@ def make_train_step(
         with jax.named_scope("loss"):
             (loss, aux), grads = _grads(state, batch)
         with jax.named_scope("optimizer"):
-            updates, new_opt_state = tx.update(
-                grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+            new_params, new_opt_state = apply_gradients(
+                tx, grads, state.opt_state, state.params)
         with jax.named_scope("grad_norm"):
             gnorm = optax.global_norm(grads)
         # Canonical keys win over aux duplicates: under grad_accum the

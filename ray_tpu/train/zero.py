@@ -7,9 +7,7 @@ over the data axes instead of replicated per dp member.  With the
 optimizer state's out_shardings pinned here, the GSPMD partitioner
 converts the gradient all-reduce into reduce-scatter → local update on
 1/dp of the blocks → all-gather of the updated params — no explicit
-collectives in the step function (the in-update sharding constraints in
-train/optim8.py are the escape hatch that keeps the partitioner honest
-on the int8 blockwise path).
+collectives in the step function.
 
 Memory math this buys: int8 Adam states cost ~2 B/param replicated
 (train/optim8.py); sharded they cost ~2/dp B/param per device, which is
@@ -21,8 +19,10 @@ Layout rules, per optimizer-state subtree of a ``TrainState``:
 * param-mirror subtrees (fp32/bf16 mu/nu with the params' structure)
   keep their param logical axes and additionally shard their largest
   still-replicated dim over the free data axes when sizes divide;
-* int8 blockwise subtrees (optim8's ``(q [nb, 256], scale [nb, 1])``
-  leaves) shard the leading block dim — the natural ZeRO shard dim;
+* int8 blockwise subtrees (optim8's ``(q [rows, cols], scale [blocks a
+  row, rows])`` leaves, the leaf's own 2-D view) shard their ROWS, the
+  codes' first dim and the scales' last: blocks run along a row, so no
+  shard cuts one (where a block is two rows, shards of an even number);
 * scalars (counts, schedule state) replicate.
 
 Sharding never pads: a dim is sharded over the longest prefix of the
@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops import adam8bit
 from ray_tpu.parallel.sharding import Rules, spec_for
 from ray_tpu.train.state import TrainState, _is_axes_leaf
 
@@ -82,26 +83,31 @@ def _axis_tuple(entry) -> Tuple[str, ...]:
 
 
 def _is_blockpair(node) -> bool:
-    """optim8's (q int8 [nb, BLOCK], f32 scale [nb, 1]) leaf pair."""
+    """optim8's (q int8 [rows, cols], f32 scale [blocks a row, rows])
+    leaf pair (ops/adam8bit.py)."""
     if not (isinstance(node, tuple) and not hasattr(node, "_fields")
             and len(node) == 2):
         return False
     q, s = node
     return (getattr(q, "ndim", 0) == 2 and getattr(s, "ndim", 0) == 2
             and str(getattr(q, "dtype", "")) == "int8"
-            and tuple(s.shape) == (q.shape[0], 1))
+            and tuple(s.shape) == adam8bit.scale_shape(*q.shape))
 
 
-def block_sharding(mesh, shape: Tuple[int, ...],
-                   rules: Optional[Rules] = None) -> NamedSharding:
-    """Sharding for a blockwise buffer: leading (block) dim over the
-    data axes, divisibility permitting; replicated otherwise."""
-    ax = shardable_prefix(shape[0], zero_axes(mesh, rules), mesh) \
-        if shape else ()
+def block_shardings(mesh, q_shape: Tuple[int, int],
+                    rules: Optional[Rules] = None
+                    ) -> Tuple[NamedSharding, NamedSharding]:
+    """Shardings of a blockwise pair: the rows over the data axes,
+    every shard whole blocks, divisibility permitting; replicated
+    otherwise."""
+    rows, cols = q_shape
+    units = rows // 2 if cols == adam8bit.HALF else rows
+    ax = shardable_prefix(units, zero_axes(mesh, rules), mesh)
     if not ax:
-        return NamedSharding(mesh, P())
+        return NamedSharding(mesh, P()), NamedSharding(mesh, P())
     entry = ax[0] if len(ax) == 1 else ax
-    return NamedSharding(mesh, P(entry, *([None] * (len(shape) - 1))))
+    return (NamedSharding(mesh, P(entry, None)),
+            NamedSharding(mesh, P(None, entry)))
 
 
 def _extend_spec(entries, shape, free: Tuple[str, ...], mesh):
@@ -158,9 +164,8 @@ def zero_state_shardings(mesh, state: TrainState, params_axes: Any,
         if sub is not None and all(_is_blockpair(x) for x in sub):
             return jax.tree.unflatten(
                 params_struct,
-                [(block_sharding(mesh, tuple(q.shape), rules),
-                  block_sharding(mesh, tuple(s.shape), rules))
-                 for q, s in sub])
+                [block_shardings(mesh, tuple(q.shape), rules)
+                 for q, _s in sub])
         if isinstance(node, dict):
             return {k: rec(v) for k, v in node.items()}
         if isinstance(node, tuple) and hasattr(node, "_fields"):

@@ -34,7 +34,8 @@ def _clean_env():
     return env
 
 
-@pytest.mark.parametrize("part", ["flash", "paged", "ragged", "fused"])
+@pytest.mark.parametrize("part", ["flash", "paged", "ragged", "fused",
+                                  "adam8"])
 def test_kernels_phase_parts(part):
     fn = getattr(chip_smoke, f"_kernels_{part}")
     fn(DIMS, "cpu") if part == "flash" else fn(DIMS)

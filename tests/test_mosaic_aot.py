@@ -340,6 +340,46 @@ def test_train_step_one_device(bench, v5e, which, batch):
           f"{used / 2**30:.2f} GiB")
 
 
+def test_adamw8bit_update_moves_no_leaf(v5e):
+    """The optimizer alone on three of internlm2_1b8-pretrain_4k's leaf
+    shapes: one kernel a leaf in the leaf's own layout.  Round it no
+    loop over segments, no copy and no reshape that is not a bitcast of
+    anything the size of a leaf (the flat ``[nb, 256]`` form cost seven
+    passes over the parameters, PERF.md PR 48), and next to no
+    temporaries (that form: 2.26 GiB for 1.3 GiB of leaves)."""
+    from ray_tpu.train import adamw8bit
+    from ray_tpu.train.step import apply_gradients
+
+    mesh = _one(v5e)
+    params = _on(mesh, {"w_gate": _sds(24, 2048, 8192),
+                        "wq": _sds(24, 2048, 16, 128),
+                        "lm_head": _sds(2048, 92544)})
+    tx = adamw8bit(1e-4, warmup_steps=10)
+    state = _on(mesh, jax.eval_shape(tx.init, params))
+
+    def update(params, grads, state):
+        with jax.named_scope("optimizer"):
+            return apply_gradients(tx, grads, state, params)
+
+    compiled = _compile(update, params, params, state, mesh=mesh,
+                        donate_argnums=(0, 2))
+    text = compiled.as_text()
+    assert len(re.findall(r"%optimizer[.\d]* = .* custom-call\(", text)) == 3
+    assert " while(" not in text
+    width = {"s8": 1, "u8": 1, "pred": 1, "bf16": 2, "f16": 2}
+    moved = []
+    for line in text.splitlines():
+        op = re.search(r" (copy|copy-start|reshape|transpose)\(", line)
+        shape = re.search(r"= \(?(\w+)\[([\d,]*)\]", line)
+        if op and shape and (
+                np.prod([int(d) for d in shape.group(2).split(",") if d])
+                * width.get(shape.group(1), 4) >= 16 * 2**20):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    leaves = sum(np.prod(p.shape) * 2 for p in params.values())
+    assert compiled.memory_analysis().temp_size_in_bytes < leaves / 10
+
+
 # -- four devices: the flash kernel under dp/fsdp/tp -------------------------
 
 def test_train_step_fsdp4(bench, v5e):

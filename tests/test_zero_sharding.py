@@ -95,7 +95,7 @@ def test_fp32_parity_and_per_replica_bytes(cpu_devices):
 def test_int8_parity_and_block_sharding(cpu_devices):
     base = _trainer(adamw8bit(1e-3, warmup_steps=5),
                     zero_sharding=False)
-    shrd = _trainer(adamw8bit(1e-3, warmup_steps=5, shard_update=True),
+    shrd = _trainer(adamw8bit(1e-3, warmup_steps=5),
                     zero_sharding=True)
     bl, _ = _fit_losses(base, steps=20)
     sl, _ = _fit_losses(shrd, steps=20)
@@ -130,12 +130,12 @@ def test_checkpoint_roundtrip_of_sharded_opt_state(cpu_devices,
                                                    tmp_path):
     """dp-sharded optimizer state round-trips through orbax: exact leaf
     equality, shardings preserved, and training continues after."""
-    t1 = _trainer(adamw8bit(1e-3, warmup_steps=5, shard_update=True),
+    t1 = _trainer(adamw8bit(1e-3, warmup_steps=5),
                   zero_sharding=True, storage_path=str(tmp_path))
     res = t1.fit(_batches(), num_steps=5)
     assert res.error is None
 
-    t2 = _trainer(adamw8bit(1e-3, warmup_steps=5, shard_update=True),
+    t2 = _trainer(adamw8bit(1e-3, warmup_steps=5),
                   zero_sharding=True)
     step = t2.restore(str(tmp_path) + "/run")
     assert step == 5
@@ -169,7 +169,7 @@ def test_zero_resume_survives_real_worker_death(rt_zero):
     def loop():
         first = rtrain.get_checkpoint() is None
         trainer = _trainer(
-            adamw8bit(1e-3, warmup_steps=5, shard_update=True),
+            adamw8bit(1e-3, warmup_steps=5),
             zero_sharding=True, mesh=MeshSpec(dp=2),
             devices=jax.devices("cpu")[:2],
             storage_path=store, checkpoint_every=1)
