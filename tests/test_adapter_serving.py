@@ -37,6 +37,8 @@ from ray_tpu.serve.llm_engine import (
     llama_paged_adapter,
 )
 
+pytestmark = pytest.mark.long_file(60)
+
 PAGE = 16
 
 CFG = llama.LlamaConfig(

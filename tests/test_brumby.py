@@ -22,6 +22,8 @@ from ray_tpu.serve.llm_engine import (
     brumby_paged_adapter,
 )
 
+pytestmark = pytest.mark.long_file(121)
+
 CFG = brumby.BrumbyConfig(
     vocab_size=97, dim=32, n_layers=3, n_heads=4, n_kv_heads=2, head_dim=16,
     mlp_dim=64, rope_theta=1e4, dtype=jnp.float32, param_dtype=jnp.float32)

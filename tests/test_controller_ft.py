@@ -45,6 +45,7 @@ Deterministic where it matters: greedy (temperature=0) decoding,
 seeded victim choice, bounded waits everywhere.
 """
 
+import functools
 import logging
 import re
 import threading
@@ -79,7 +80,8 @@ from ray_tpu.serve.deployment import DeploymentInfo
 from ray_tpu.serve.llm_engine import EngineConfig, LLMServer
 from ray_tpu.serve.long_poll import LongPollHost
 from ray_tpu.utils.test_utils import ReplicaKiller, kill_actor_hard
-from tests import oracle
+from tests import midstream_kill, oracle
+from tests.midstream_kill import hold  # noqa: F401 (fixture)
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -116,18 +118,20 @@ def references(params):
     return [_greedy_reference(params, p, N_NEW) for p in PROMPTS]
 
 
-def _slow_paged_adapter_factory(cfg):
+def _slow_paged_adapter_factory(cfg, hold):
     """Paged adapter with a throttled ragged step so a 12-token stream
-    spans an observable window and the controller/replica kills
-    reliably land mid-decode (see test_autoscale_chaos)."""
+    spans an observable window and the controller kills land
+    mid-decode (see test_autoscale_chaos), and one that stands still
+    while the file ``hold`` exists, so that the replica kill does."""
     import dataclasses
 
     from ray_tpu.serve.llm_engine import llama_paged_adapter
 
     base = llama_paged_adapter(cfg)
+    throttle = midstream_kill.throttle(hold, 0.03)
 
     def slow_step(*args, **kwargs):
-        jax.debug.callback(lambda: time.sleep(0.03), ordered=True)
+        jax.debug.callback(throttle, ordered=True)
         return base.ragged_step(*args, **kwargs)
 
     return dataclasses.replace(base, ragged_step=slow_step)
@@ -190,7 +194,7 @@ def _router(app, dep=DEP):
     return _routers[(app, dep)]
 
 
-def _serve_autoscaled(params, app_name, **auto_kw):
+def _serve_autoscaled(params, app_name, hold, **auto_kw):
     ray_tpu.init(num_cpus=16, ignore_reinit_error=True)
     serve.start()
     auto = dict(min_replicas=1, target_ongoing_requests=2.0,
@@ -202,7 +206,8 @@ def _serve_autoscaled(params, app_name, **auto_kw):
         max_ongoing_requests=8, health_check_period_s=0.1,
         autoscaling_config=auto,
     )(LLMServer).bind(CFG, ENG, lambda: params,
-                      adapter_factory=_slow_paged_adapter_factory)
+                      adapter_factory=functools.partial(
+                          _slow_paged_adapter_factory, hold=str(hold)))
     return serve.run(app, name=app_name, route_prefix=None)
 
 
@@ -229,14 +234,14 @@ def _launch_stream(shandle, prompt_idx, recs, n_new=N_NEW):
 
 
 @pytest.fixture
-def ft_app(params, monkeypatch):
+def ft_app(params, hold, monkeypatch):
     # THREAD worker mode (the annotated exception; process is the
     # default): kill_actor_hard / ReplicaKiller semantics, the driver
     # metric registry, and the post-kill generation fence all assume
     # the controller shares the driver process (see test_doctor.py).
     monkeypatch.setenv("RAYTPU_WORKERS", "thread")
     ray_tpu.shutdown()
-    handle = _serve_autoscaled(params, "ft", max_replicas=3)
+    handle = _serve_autoscaled(params, "ft", hold, max_replicas=3)
     yield handle
     serve.shutdown()
     ray_tpu.shutdown()
@@ -282,7 +287,7 @@ def bare_runtime(monkeypatch):
 # -- the acceptance chaos test ----------------------------------------------
 
 
-def test_controller_kill_recovery_byte_exact(ft_app, references):
+def test_controller_kill_recovery_byte_exact(ft_app, references, hold):
     """SIGKILL the controller mid-traffic with autoscaling and the
     replica killer active: streams keep flowing on the last-known
     routing table, the data plane resurrects the control plane from
@@ -349,8 +354,9 @@ def test_controller_kill_recovery_byte_exact(ft_app, references):
     # …and a replica dies DURING the outage, with no controller alive
     # to see it — the router's per-request eviction carries the load
     # until the recovered controller replaces it.
-    victim = killer.kill_one()
-    assert victim is not None, "no live replica to kill mid-outage"
+    # It is one that holds a stream of this wave: the steps stand still
+    # while the victim is chosen, so none can end before the kill.
+    midstream_kill.kill_a_replica_mid_stream("ft", DEP, hold, arrive_s=30)
     for i in range(N_STREAMS):
         _launch_stream(shandle, i, recs)
 

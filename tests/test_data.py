@@ -182,11 +182,21 @@ def test_streaming_split_equal_block_counts():
 
 
 def test_early_break_does_not_leak_prefetch_thread():
+    """The producers of five abandoned iterations end.  They are counted
+    by name: the process's thread count also moves with the pools of
+    whatever file this worker ran before."""
     import threading
-    before = threading.active_count()
+    import time
+
+    def producers():
+        return [t for t in threading.enumerate()
+                if t.name == "batch-prefetch"]
+
+    before = len(producers())
     for _ in range(5):
         for batch in rd.range(10_000).iter_batches(batch_size=100):
             break
-    import time
-    time.sleep(0.5)
-    assert threading.active_count() <= before + 3
+    deadline = time.monotonic() + 10
+    while len(producers()) > before and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert len(producers()) <= before

@@ -38,6 +38,8 @@ from ray_tpu.serve.llm_engine import (
 from ray_tpu.utils.test_utils import REPLICA_READY_S
 from ray_tpu.util import doctor, flight_recorder
 
+pytestmark = pytest.mark.long_file(64)
+
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
     mlp_dim=64, max_seq_len=128, remat=False, dtype=jnp.float32,

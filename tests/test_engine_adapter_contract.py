@@ -28,6 +28,8 @@ from ray_tpu.serve.llm_engine import (
     xing_paged_adapter,
 )
 
+pytestmark = pytest.mark.long_file(123)
+
 LLAMA = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
     mlp_dim=64, max_seq_len=64, remat=False, dtype=jnp.float32,

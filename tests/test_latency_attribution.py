@@ -22,6 +22,7 @@ byte-deterministic over static terminal rows; and the bench legs'
 """
 
 import dataclasses
+import functools
 import importlib.util
 import io
 import json
@@ -37,7 +38,6 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.core import api
 from ray_tpu.models import llama
 from ray_tpu.serve import latency_attribution as lat
 from ray_tpu.serve import request_events
@@ -49,6 +49,8 @@ from ray_tpu.serve.llm_engine import (
     llama_paged_adapter,
 )
 from ray_tpu.util import flight_recorder
+from tests import midstream_kill
+from tests.midstream_kill import hold  # noqa: F401 (fixture)
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
@@ -320,15 +322,17 @@ FAIL_NEW = 12
 FAIL_PROMPTS = [[i + 1, i + 2, i + 3] for i in range(FAIL_STREAMS)]
 
 
-def _slow_adapter_factory(cfg):
+def _slow_adapter_factory(cfg, hold):
     """Throttled decode (jax.debug.callback: decode_slots is traced, a
     bare sleep would fire at trace time only) so every stream spans a
-    few row-federation cadences (~1 s) and the kill lands mid-decode
-    with the victim's DECODING row already on the driver."""
+    few row-federation cadences (~1 s), and one that stands still while
+    the file ``hold`` exists, so the kill lands mid-decode with the
+    victim's DECODING row already on the driver."""
     base = llama_paged_adapter(cfg)
+    throttle = midstream_kill.throttle(hold, 0.2)
 
     def slow_decode(*args, **kwargs):
-        jax.debug.callback(lambda: time.sleep(0.2), ordered=True)
+        jax.debug.callback(throttle, ordered=True)
         return base.decode_slots(*args, **kwargs)
 
     return dataclasses.replace(base, decode_slots=slow_decode)
@@ -340,15 +344,13 @@ def _engine_rows(rid):
             and not str(r.get("engine", "")).startswith("router:")]
 
 
-def test_failover_waterfall_and_slo_miss_bundle(params, tmp_path):
+def test_failover_waterfall_and_slo_miss_bundle(params, tmp_path, hold):
     """SIGKILL a replica mid-decode: the retried stream's waterfall
     books the survivor re-prefill under ``retry_reprefill`` and its
     stitched ttft/e2e run from FIRST admission (satellite 2); every
     finished stream misses the (absurdly tight) e2e SLO, so the flight
     recorder writes a bundle holding the offending request's events
     from >= 2 processes."""
-    from ray_tpu.utils.test_utils import ReplicaKiller
-
     flight_recorder.clear()
     flight_recorder.configure(dump_dir=str(tmp_path), auto_dump=True,
                               min_dump_interval_s=0.0)
@@ -364,7 +366,8 @@ def test_failover_waterfall_and_slo_miss_bundle(params, tmp_path):
         EngineConfig(max_slots=8, max_seq_len=128, min_prefill_bucket=16,
                      decode_chunk=1, slo=SLO(e2e_s=0.001)),
         lambda: params,
-        adapter_factory=_slow_adapter_factory,
+        adapter_factory=functools.partial(_slow_adapter_factory,
+                                          hold=str(hold)),
     )
     handle = serve.run(app, name=APP, route_prefix=None)
     try:
@@ -403,8 +406,8 @@ def test_failover_waterfall_and_slo_miss_bundle(params, tmp_path):
             raise TimeoutError(
                 f"DECODING rows never federated: {[len(o) for o in outs]}")
 
-        killer = ReplicaKiller(api.runtime(), seed=0)
-        assert killer.kill_one() is not None
+        # a replica that still holds a stream (and so its DECODING row)
+        midstream_kill.kill_a_replica_mid_stream(APP, DEP, hold)
         for t in threads:
             t.join(timeout=300)
         assert not any(t.is_alive() for t in threads), \

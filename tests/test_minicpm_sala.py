@@ -19,6 +19,8 @@ from ray_tpu.ops import block_sparse_attention as bsa
 from ray_tpu.ops import lightning_attention as la
 from ray_tpu.serve.llm_engine import EngineConfig, LLMEngine, sala_paged_adapter
 
+pytestmark = pytest.mark.long_file(236)
+
 L, S = sala.LIGHTNING, sala.SPARSE
 PAGE = 8
 # 2 sparse + 6 lightning layers of hidden 64; blocks of 8, top 4, a

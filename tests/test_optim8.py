@@ -14,6 +14,8 @@ from ray_tpu.ops import adam8bit
 from ray_tpu.train.optim8 import BLOCK, adamw8bit, scale_by_adam8bit
 from ray_tpu.train.step import apply_gradients
 
+pytestmark = pytest.mark.long_file(112)
+
 
 def _fit(opt, steps=500):
     """Train a small least-squares problem; return final loss."""

@@ -43,6 +43,8 @@ from ray_tpu.serve.prefix_index import (
 )
 from tests import oracle
 
+pytestmark = pytest.mark.long_file(82)
+
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
     mlp_dim=64, max_seq_len=128, remat=False, dtype=jnp.float32,

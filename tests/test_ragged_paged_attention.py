@@ -28,6 +28,8 @@ import pytest
 from ray_tpu.models import llama
 from ray_tpu.ops import ragged_paged_attention as rpa
 
+pytestmark = pytest.mark.long_file(274)
+
 
 # Every kernel call goes through one of these: under the Pallas
 # interpreter an EAGER call compiles its kernel anew, so the cases of one

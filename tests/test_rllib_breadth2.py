@@ -19,6 +19,8 @@ from ray_tpu.rllib import (
 )
 from ray_tpu.rllib.env import Pendulum
 
+pytestmark = pytest.mark.long_file(111)
+
 
 def test_ddpg_runs_pendulum_single_critic():
     algo = (DDPGConfig()

@@ -41,8 +41,6 @@ What "the exact token sequence" is compared with, twice over:
 
 import dataclasses
 import functools
-import os
-import random
 import re
 import threading
 import time
@@ -60,8 +58,11 @@ from ray_tpu.serve.llm_engine import (
     LLMServer,
     llama_paged_adapter,
 )
-from ray_tpu.utils.test_utils import REPLICA_READY_S, ReplicaKiller
-from tests import oracle as recompute
+from ray_tpu.utils.test_utils import REPLICA_READY_S
+from tests import midstream_kill, oracle as recompute
+from tests.midstream_kill import hold  # noqa: F401 (fixture)
+
+pytestmark = pytest.mark.long_file(76)
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -106,25 +107,13 @@ def _slow_adapter_factory(cfg, hold):
     The wait rides a jax.debug.callback: decode_slots is traced under
     jit, so a bare time.sleep would only fire at trace time."""
     base = llama_paged_adapter(cfg)
-
-    def throttle():
-        time.sleep(0.03)
-        deadline = time.monotonic() + 60
-        while os.path.exists(hold) and time.monotonic() < deadline:
-            time.sleep(0.002)
+    throttle = midstream_kill.throttle(hold, 0.03)
 
     def slow_decode(*args, **kwargs):
         jax.debug.callback(throttle, ordered=True)
         return base.decode_slots(*args, **kwargs)
 
     return dataclasses.replace(base, decode_slots=slow_decode)
-
-
-@pytest.fixture
-def hold(tmp_path):
-    """While this file exists no replica takes a decode step (the
-    replicas are other processes: a path is what they can see)."""
-    return tmp_path / "hold_decode"
 
 
 @pytest.fixture
@@ -239,22 +228,7 @@ def test_midstream_kill_failover_exact_tokens(llm_app, oracle, hold):
     # may have ended its streams by now (one in four runs on an idle
     # machine): the victim is a seeded choice among the replicas that
     # still hold one mid-decode.
-    hold.touch()
-    try:
-        router = _router()
-        with router._lock:
-            replicas = {rid: info.handle
-                        for rid, info in router._replicas.items()}
-        live = sorted(rid for rid, h in replicas.items() if api.get(
-            h.num_ongoing_requests.remote(), timeout=60) > 0)
-        assert live, f"every stream ended before the kill: " \
-            f"{[len(o) for o in outs]}"
-        victim = random.Random(0).choice(live)
-        killer = ReplicaKiller(api.runtime())
-        assert killer.kill_one(
-            actor_id=replicas[victim]._actor_id) is not None
-    finally:
-        hold.unlink()
+    midstream_kill.kill_a_replica_mid_stream(APP, DEP, hold)
 
     for t in threads:
         t.join(timeout=180)

@@ -23,7 +23,6 @@ Scenarios, all through the real router/controller path:
 
 import dataclasses
 import functools
-import os
 import random
 import re
 import threading
@@ -45,6 +44,8 @@ from ray_tpu.serve.llm_engine import (
     llama_paged_adapter,
 )
 from ray_tpu.utils.test_utils import ReplicaKiller
+from tests import midstream_kill
+from tests.midstream_kill import hold  # noqa: F401 (fixture)
 
 CFG = dataclasses.replace(
     llama.LlamaConfig(
@@ -89,12 +90,7 @@ def _slow_paged_adapter_factory(cfg, hold):
     while the file ``hold`` exists, so that a kill lands mid-stream
     (same trick as test_serve_failover)."""
     base = llama_paged_adapter(cfg)
-
-    def throttle():
-        time.sleep(0.03)
-        deadline = time.monotonic() + 60
-        while os.path.exists(hold) and time.monotonic() < deadline:
-            time.sleep(0.002)
+    throttle = midstream_kill.throttle(hold, 0.03)
 
     def slow_decode(*args, **kwargs):
         # ordered=True is not allowed on a >1-device mesh; the
@@ -123,13 +119,6 @@ def mh_app(params):
     yield handle
     serve.shutdown()
     ray_tpu.shutdown()
-
-
-@pytest.fixture
-def hold(tmp_path):
-    """While this file exists no group takes a decode step (the groups
-    are other processes: a path is what they can see)."""
-    return tmp_path / "hold_decode"
 
 
 @pytest.fixture
@@ -295,20 +284,13 @@ def test_shard_member_kill_fails_over_whole_group(
     # Every stream stands where it is.  The group that compiled first
     # may have ended its streams by now: the victim is a member of a
     # seeded choice among the groups that still hold one mid-decode.
-    from ray_tpu.serve.handle import _routers
     from ray_tpu.util import state
 
-    hold.touch()
-    try:
-        router = _routers[(APP, DEP)]
-        with router._lock:
-            groups = {rid: info.handle
-                      for rid, info in router._replicas.items()}
-        live = sorted(rid for rid, h in groups.items() if api.get(
-            h.num_ongoing_requests.remote(), timeout=60) > 0)
+    with midstream_kill.streams_held(hold):
+        live = midstream_kill.replicas_holding_a_request(APP, DEP)
         assert live, f"every stream ended before the kill: " \
             f"{[len(o) for o in outs]}"
-        group = random.Random(0).choice(live)
+        group = random.Random(0).choice(list(live))
         (row,) = [r for r in state.list_replicas()
                   if r["replica_id"] == group]
         # "0:<rank 0's id>,1:<member's id>": the actors' hex[8:16]
@@ -319,8 +301,6 @@ def test_shard_member_kill_fails_over_whole_group(
         (victim,) = [a for a in killer.victims()
                      if a.hex()[8:16] in member_ids]
         assert killer.kill_one(actor_id=victim) is not None
-    finally:
-        hold.unlink()
 
     for t in threads:
         t.join(timeout=180)

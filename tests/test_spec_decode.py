@@ -40,6 +40,10 @@ from ray_tpu.serve.llm_engine import (
     llama_paged_adapter,
 )
 from ray_tpu.utils.test_utils import REPLICA_READY_S
+from tests import midstream_kill
+from tests.midstream_kill import hold  # noqa: F401 (fixture)
+
+pytestmark = pytest.mark.long_file(289)
 
 CFG = llama.LlamaConfig(
     vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -400,12 +404,14 @@ def test_spec_disagg_handoff_parity(params):
 
 # -- SIGKILL mid-stream failover ---------------------------------------------
 
-def _slow_spec_adapter_factory(cfg):
+def _slow_spec_adapter_factory(cfg, hold):
     """Paged adapter with throttled ragged steps (plain AND verify) so
-    streams span an observable window and the kill lands mid-decode.
-    The sleep rides jax.debug.callback: the steps are traced under
+    streams span an observable window, and steps that stand still while
+    the file ``hold`` exists, so the kill lands mid-decode.
+    The wait rides jax.debug.callback: the steps are traced under
     jit, so a bare time.sleep would only fire at trace time."""
     base = llama_paged_adapter(cfg)
+    throttle = midstream_kill.throttle(hold, 0.02)
 
     # wraps: the engine reads the step's signature for ``logit_idx=``
     # before it speculates, and refuses to build over a step that hides
@@ -414,13 +420,13 @@ def _slow_spec_adapter_factory(cfg):
     # was red alone, not for a slow start under load)
     @functools.wraps(base.ragged_step)
     def slow_step(*args, **kwargs):
-        jax.debug.callback(lambda: time.sleep(0.02), ordered=True)
+        jax.debug.callback(throttle, ordered=True)
         return base.ragged_step(*args, **kwargs)
 
     return dataclasses.replace(base, ragged_step=slow_step)
 
 
-def test_spec_midstream_kill_failover_parity(params):
+def test_spec_midstream_kill_failover_parity(params, hold):
     """Hard-kill the replica serving speculative streams mid-decode:
     every stream finishes byte-identical to the spec-off oracle — the
     continuation replay (prompt + delivered prefix) re-enters the
@@ -428,8 +434,6 @@ def test_spec_midstream_kill_failover_parity(params):
     token."""
     import ray_tpu
     from ray_tpu import serve
-    from ray_tpu.core import api
-    from ray_tpu.utils.test_utils import ReplicaKiller
 
     n_streams, n_new = 4, 24
     prompts = [[i + 1, i + 2, i + 3] for i in range(n_streams)]
@@ -446,7 +450,8 @@ def test_spec_midstream_kill_failover_parity(params):
                      ragged_batching=True, token_budget=36,
                      spec_decode=True),
         lambda: params,
-        adapter_factory=_slow_spec_adapter_factory,
+        adapter_factory=functools.partial(_slow_spec_adapter_factory,
+                                          hold=str(hold)),
     )
     handle = serve.run(app, name="llmspecft", route_prefix=None,
                        timeout_s=REPLICA_READY_S)
@@ -476,8 +481,10 @@ def test_spec_midstream_kill_failover_parity(params):
             time.sleep(0.005)
         assert all(len(o) >= 2 for o in outs), "streams never started"
 
-        killer = ReplicaKiller(api.runtime(), seed=0)
-        assert killer.kill_one() is not None
+        # a replica that still holds a stream: the one that compiled
+        # first may have ended its own by now
+        midstream_kill.kill_a_replica_mid_stream(
+            "llmspecft", "LLMServer", hold)
 
         for t in threads:
             t.join(timeout=180)

@@ -123,7 +123,16 @@ def test_cross_plane_trace_and_metrics(rt, tmp_path, cpu_devices):
     tracing.enable_tracing()
     # The registry is the process's: a test file this worker ran before
     # has left its rows and steps in the counters, so what THIS workload
-    # adds is what is compared.
+    # adds is what is compared.  The counters outlive a
+    # ``registry().clear()`` (test_metrics.py, test_timeseries_plane.py)
+    # in their modules, which put them back, counts and all, at the next
+    # step or batch: they are put back here, before the first reading,
+    # or it reads 0 where the second reads the earlier file's steps too.
+    from ray_tpu.data import iterator as data_iterator
+    from ray_tpu.train import trainer as train_trainer
+
+    data_iterator._telemetry()
+    train_trainer._telemetry()
     before = metrics.export_prometheus()
     rows_before = _sample_value(before, "raytpu_data_output_rows_total") or 0
     steps_before = _sample_value(before, "raytpu_train_steps_total") or 0

@@ -139,6 +139,11 @@ def test_memory_bound_is_structural_and_drops_are_counted():
     # Tiny rings and a budget that admits exactly 4 counter/gauge
     # series ((8 + 4) points * 120 bytes = 1440 each).
     timeseries.configure(rings=((1.0, 8), (10.0, 4)), max_bytes=4 * 1440)
+    # The counter is the process's and outlives ``registry().clear()`` in
+    # its module: a file this worker ran before has left its drops in
+    # it, so what THIS load adds is what is compared.
+    dropped = timeseries._telemetry()["dropped"]
+    dropped_before = sum(s[2] for s in dropped._samples())
     g = metrics.Gauge("raytpu_test_wide", "t", tag_keys=("i",))
     for i in range(20):
         g.set(float(i), tags={"i": str(i)})
@@ -148,9 +153,9 @@ def test_memory_bound_is_structural_and_drops_are_counted():
     assert timeseries.memory_bytes() <= 4 * 1440
     series = timeseries.query(family="raytpu_test_wide")["series"]
     assert len(series) == 4, [s["tags"] for s in series]
-    dropped = metrics.registry().get(
-        "raytpu_timeseries_dropped_series_total")
-    assert sum(s[2] for s in dropped._samples()) == 16.0
+    assert metrics.registry().get(
+        "raytpu_timeseries_dropped_series_total") is dropped
+    assert sum(s[2] for s in dropped._samples()) - dropped_before == 16.0
     # Admitted series kept sampling: rings are full, not starved.
     assert all(len(s["points"]) == 8 for s in series)
 

@@ -11,6 +11,8 @@ import ray_tpu
 from ray_tpu import train as rtrain
 from ray_tpu.util import collective as col
 
+pytestmark = pytest.mark.long_file(95)
+
 
 @pytest.fixture
 def rt():
@@ -28,8 +30,9 @@ def test_worker_group_execute(rt):
     finally:
         wg.shutdown()
     # Resources return after shutdown (asynchronously: the actor death
-    # path releases them once each shell drains).
-    deadline = time.monotonic() + 10
+    # path releases them once each shell drains; under the whole suite's
+    # load that has taken over 10 s: the deadline only bounds a failure).
+    deadline = time.monotonic() + 60
     while time.monotonic() < deadline:
         if ray_tpu.available_resources().get("CPU") == 8.0:
             break

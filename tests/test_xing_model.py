@@ -23,6 +23,8 @@ from ray_tpu.serve.llm_engine import (
     xing_paged_adapter,
 )
 
+pytestmark = pytest.mark.long_file(110)
+
 HF = dict(
     vocab_size=128, hidden_size=64, num_hidden_layers=4,
     num_attention_heads=4, num_key_value_heads=4, intermediate_size=128,

@@ -31,6 +31,8 @@ from ray_tpu.train import (
     zero,
 )
 
+pytestmark = pytest.mark.long_file(67)
+
 CFG = LLAMA_TINY
 
 
