@@ -42,9 +42,27 @@ is empty and the state untouched.
 ``retention_decode``: rows of one token.  The state streams through
 VMEM once in blocks of ``D'`` (read, decayed, updated, read against
 ``phi(q)``, written back through the alias): 2 x 4 x D' x d bytes a row
-and KV head, which is the kernel's bound.  ``phi`` of the few decode
-tokens is formed outside (a selection matmul with 8 rows would be bound
-by the MXU's weight loads).
+and KV head, which is the kernel's bound.  A grid step moves one
+``[1024, d]`` block in and one out (0.5 MB each at ``d`` 128, 72 steps a
+row and layer).  On a v5e that stream runs at the rate the chip gives any
+read-and-write stream, 0.65 TB/s of the 0.82 its memory is sold at (a
+plain copy of the same bytes, by Pallas or by XLA, reads 0.63 to 0.65;
+reads alone 0.68, writes alone 0.60): a block of 3072, 4608 or the whole
+``D'`` costs the same to the per cent, and so does the body with both its
+products taken out, so neither a grid step's fixed part nor the
+arithmetic is in the way (PERF.md, PR 50).  ``z`` goes out through an
+alias as ``S`` does, a row's ``[KV heads, 1024]`` block at a time (the
+heads are the innermost grid dimension, each writes its row of the
+block): as rows scattered by XLA it cost a pass a layer, and XLA re-laid
+the chunk kernel's eightfold normaliser out whole beside it.  ``phi`` of
+the few decode tokens is formed outside (a selection matmul with 8 rows
+would be bound by the MXU's weight loads), by ``decode_features``: the
+operands are
+bfloat16 already and the selections 0/1, so ONE pass with a float32 sum
+is ``features`` bit for bit, where ``features``' ``HIGHEST`` costs six.
+The operand is ``[rows, KV heads, 8, D']`` for every row of capacity
+(28 MB at 12 rows, which XLA keeps in VMEM): its two selections are
+bound by the MXU's 768 rows, not by memory.
 
 ``retention_chunk``: rows of several tokens (prompt chunks).  Per row,
 KV head and block of ``D'``: ``phi(Q)`` of the row's token tiles against
@@ -154,6 +172,18 @@ def features(u: jax.Array) -> jax.Array:
     return (jnp.dot(u, e, precision=hi) * jnp.dot(u, f, precision=hi)) * w
 
 
+def decode_features(u: jax.Array) -> jax.Array:
+    """``features`` of bfloat16 ``u`` in one MXU pass a selection:
+    bfloat16 operands and a float32 sum.  ``E`` and ``F`` are 0/1, so each
+    selected value is one bfloat16 times 1.0 plus zeros and the result is
+    ``features(u)`` bit for bit, at a sixth of its ``HIGHEST`` passes."""
+    assert u.dtype == jnp.bfloat16, u.dtype
+    e, f, w = feature_maps(u.shape[-1])
+    f32, bf = jnp.float32, jnp.bfloat16
+    return (jnp.dot(u, e.astype(bf), preferred_element_type=f32)
+            * jnp.dot(u, f.astype(bf), preferred_element_type=f32)) * w
+
+
 def to_canonical(state: np.ndarray, d: int) -> np.ndarray:
     """A state ``[D', ...]`` in the kernels' layout as the deduplicated
     ``[D, ...]``: first the ``d`` squares, then ``sqrt(2) u_i u_i'`` for
@@ -217,7 +247,7 @@ def _scatter_z(ret_z, layer, z_rows, row_slot, live):
 
 def _decode_kernel(rows_r, n_r, slot_r, start_r, ly_r,
                    feat_ref, v_ref, g_ref, s_in, z_in,
-                   num_ref, den_ref, zu_ref, s_out, *, G: int):
+                   num_ref, den_ref, z_out, s_out, *, G: int):
     del slot_r, ly_r                       # index maps read them
     i, b, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -237,7 +267,7 @@ def _decode_kernel(rows_r, n_r, slot_r, start_r, ly_r,
         s_out[0, 0, 0] = s_new
         z = jnp.where(fresh, 0.0, z_in[0, 0, pl.ds(j, 1), :])
         z_new = z * g[0:1, 0:1] + f8[G:G + 1, :]           # [1, Db]
-        zu_ref[0, pl.ds(j, 1), :] = z_new
+        z_out[0, 0, pl.ds(j, 1), :] = z_new
         num = jnp.dot(f8.astype(jnp.bfloat16), s_new.astype(jnp.bfloat16),
                       preferred_element_type=jnp.float32)  # [8, d]
         den = jnp.broadcast_to(
@@ -260,7 +290,7 @@ def retention_decode(
     v: jax.Array,            # [T, KVH, d]
     log_g: jax.Array,        # [T, KVH] float32, log of the gate
     ret_s: jax.Array,        # [L, S + 1, KVH, D', d] float32, in place
-    ret_z: jax.Array,        # [L, S + 1, KVH, D'] float32
+    ret_z: jax.Array,        # [L, S + 1, KVH, D'] float32, in place
     layer: jax.Array,
     row_slot: jax.Array,     # [R]
     row_start: jax.Array,
@@ -290,7 +320,8 @@ def retention_decode(
     qd = q[at].astype(bf).reshape(R, KVH, G, d)
     kd = k[at].astype(bf)[:, :, None, :]
     pad = jnp.zeros((R, KVH, FEAT_ROWS - G - 1, d), bf)
-    feat = features(jnp.concatenate([qd, kd, pad], axis=2))  # [R,KVH,8,D']
+    feat = decode_features(
+        jnp.concatenate([qd, kd, pad], axis=2))            # [R,KVH,8,D']
     v8 = jnp.broadcast_to(v[at].astype(bf).astype(f32)[:, :, None, :],
                           (R, KVH, FEAT_ROWS, d))
     g8 = jnp.broadcast_to(jnp.exp(log_g[at])[:, :, None, None],
@@ -313,11 +344,11 @@ def retention_decode(
     def y_map(i, b, j, rows_p, n_p, *pf):
         return (_listed_row(i, rows_p, n_p, R), 0, 0, 0)
 
-    def zu_map(i, b, j, rows_p, n_p, *pf):
-        return (_listed_row(i, rows_p, n_p, R), 0, b)
-
     interpret = platform.interpret_mode()
     s_spec = pl.BlockSpec((1, 1, 1, Db, d), s_map)
+    # a row's KV heads are the innermost grid dimension, so a block of
+    # ``z`` stays in VMEM while each head writes its row of it
+    z_spec = pl.BlockSpec((1, 1, KVH, Db), z_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(R if interpret else n[0], Dp // Db, KVH),
@@ -326,33 +357,32 @@ def retention_decode(
             pl.BlockSpec((1, 1, FEAT_ROWS, d), by_row),
             pl.BlockSpec((1, 1, FEAT_ROWS, d), by_row),
             s_spec,
-            pl.BlockSpec((1, 1, KVH, Db), z_map),
+            z_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, KVH, FEAT_ROWS, d), y_map),
             pl.BlockSpec((1, KVH, FEAT_ROWS, d), y_map),
-            pl.BlockSpec((1, KVH, Db), zu_map),
+            z_spec,
             s_spec,
         ],
     )
-    num, den, z_rows, ret_s = pl.pallas_call(
+    num, den, ret_z, ret_s = pl.pallas_call(
         functools.partial(_decode_kernel, G=G),
         name="retention_decode",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((R + 1, KVH, FEAT_ROWS, d), f32),
                    jax.ShapeDtypeStruct((R + 1, KVH, FEAT_ROWS, d), f32),
-                   jax.ShapeDtypeStruct((R + 1, KVH, Dp), f32),
+                   jax.ShapeDtypeStruct(ret_z.shape, ret_z.dtype),
                    jax.ShapeDtypeStruct(ret_s.shape, ret_s.dtype)],
         # prefetch: rows=0 n=1 slot=2 start=3 layer=4, then feat=5 v=6
         # g=7 ret_s=8 ret_z=9
-        input_output_aliases={8: 3},
+        input_output_aliases={8: 3, 9: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3,
             vmem_limit_bytes=48 * 2**20),
         interpret=interpret,
     )(rows, n, row_slot, row_start, jnp.asarray(layer, i32).reshape(1),
       feat, v8, g8, ret_s, ret_z)
-    ret_z = _scatter_z(ret_z, layer, z_rows[:R], row_slot, one)
     y_rows = (num[:R, :, :G] / (den[:R, :, :G] + eps)).reshape(R, H, d)
     tok_row, valid = token_rows(row_len, row_off, T)
     mine = valid & one[tok_row]

@@ -65,7 +65,12 @@ def _rows(rows, budget=BUDGET, slots=SLOTS):
     return pack_ragged_batch(rows, budget, slots)
 
 
-def _operands(rng, T=BUDGET, L=2, heads=4, kv_heads=2, d=D):
+def _operands(rng, T=BUDGET, L=2, heads=4, kv_heads=2, d=D, keys=0):
+    """``keys`` > 0: each normaliser is the sum of ``phi`` of that many
+    random keys, as a served one is, and each token's key leans to its
+    queries, so that ``z . phi(q)`` and ``(q . k)^2`` are sums of
+    squares that stand clear of zero (a fresh row's whole denominator
+    is the second)."""
     f32 = jnp.float32
     dp = pr.feature_dim(d)
     q = jnp.asarray(rng.standard_normal((T, heads, d)), f32)
@@ -76,6 +81,10 @@ def _operands(rng, T=BUDGET, L=2, heads=4, kv_heads=2, d=D):
         rng.standard_normal((L, SLOTS + 1, kv_heads, dp, d)), f32)
     z0 = jnp.asarray(
         np.abs(rng.standard_normal((L, SLOTS + 1, kv_heads, dp))), f32)
+    if keys:
+        k = k + jnp.sum(q.reshape(T, kv_heads, -1, d), axis=2)
+        z0 = jnp.sum(pr.features(jnp.asarray(rng.standard_normal(
+            (keys, L, SLOTS + 1, kv_heads, d)), f32)), axis=0)
     return q, k, v, log_g, s0, z0
 
 
@@ -117,6 +126,27 @@ def test_pair_features_t_is_the_feature_map(d):
     np.testing.assert_array_equal(
         np.asarray(got.astype(jnp.bfloat16).astype(jnp.float32)),
         np.asarray(want.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("d", [16, 128])
+def test_decode_features_are_the_feature_map_bit_for_bit(d):
+    """``phi`` of the decode tokens, formed in one pass with bfloat16
+    operands and a float32 sum, is ``features`` of the same operands bit
+    for bit: every selected value is one bfloat16 times 1.0 plus zeros.
+    Negative values, zeros, a whole zero row (the operand's padding) and
+    the largest and smallest magnitudes among them."""
+    rng = np.random.default_rng(5)
+    u = rng.standard_normal((3, 2, pr.FEAT_ROWS, d)).astype(np.float32)
+    u[rng.random(u.shape) < 0.1] = 0.0
+    u[0, 0, -2:] = 0.0
+    u[1, 1, 0, :4] = [3.0e38, -3.0e38, 1e-38, -0.0]
+    u[2, 0, 3] *= 1e-12
+    u = jnp.asarray(u, jnp.bfloat16)
+    assert bool(jnp.any(u < 0)) and bool(jnp.any(u == 0))
+    got, want = jax.jit(pr.decode_features)(u), jax.jit(pr.features)(u)
+    assert got.dtype == want.dtype == jnp.float32
+    assert got.shape == want.shape == u.shape[:-1] + (pr.feature_dim(d),)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("through,limits", [
@@ -168,18 +198,27 @@ WIDE = [{"slot": 2, "start": 5, "tokens": None},
         {"slot": 0, "start": 0, "tokens": list(range(126))},
         {"slot": 3, "start": 7, "tokens": list(range(9))},
         {"slot": 1, "start": 3, "tokens": None}]
+# rows of one token only: two that carry a state and one that opens its
+# sequence (a one-token prompt), whose slot holds what the last left
+FRESH = [{"slot": 2, "start": 5, "tokens": None},
+         {"slot": 0, "start": 0, "tokens": [0]},
+         {"slot": 1, "start": 3, "tokens": None}]
 
 
 @pytest.mark.parametrize("kernel,twin,rows,shape", [
+    # D' 160: five state blocks of 32
     (pr.retention_decode, pr.retention_decode_reference, MIXED, {}),
+    # D' 9216, the size the cell runs: nine state blocks of 1024
+    (pr.retention_decode, pr.retention_decode_reference, FRESH,
+     dict(heads=2, kv_heads=1, d=128, keys=6)),
     (pr.retention_chunk, pr.retention_chunk_reference, MIXED, {}),
     (pr.retention_chunk, pr.retention_chunk_reference, RAGGED,
      dict(T=40)),
     # blocks of 16, D' 9216, nine state blocks of four whole pairs
     (pr.retention_chunk, pr.retention_chunk_reference, WIDE,
      dict(T=144, heads=2, kv_heads=1, d=128)),
-], ids=["retention_decode", "retention_chunk", "retention_chunk_ragged",
-        "retention_chunk_d128"])
+], ids=["retention_decode", "retention_decode_d128", "retention_chunk",
+        "retention_chunk_ragged", "retention_chunk_d128"])
 def test_kernel_matches_its_twin(kernel, twin, rows, shape):
     rng = np.random.default_rng(2)
     q, k, v, log_g, s0, z0 = _operands(rng, **shape)
@@ -196,14 +235,16 @@ def test_kernel_matches_its_twin(kernel, twin, rows, shape):
     # the other layer, the slots of the other kind of row and scratch
     # are as they were, bit for bit
     assert bool(jnp.all(s1[0] == s0[0])) and bool(jnp.all(z1[0] == z0[0]))
-    mine = [2, 1] if kernel is pr.retention_decode else [0, 3]
+    mine = [int(slot) for slot, n in zip(rs, rl)
+            if (n == 1 if kernel is pr.retention_decode else n > 1)]
+    assert len(mine) >= 2
     for slot in set(range(SLOTS + 1)) - set(mine):
         assert bool(jnp.all(s1[1, slot] == s0[1, slot])), slot
         assert bool(jnp.all(z1[1, slot] == z0[1, slot])), slot
     for slot in mine:
         assert not bool(jnp.all(s1[1, slot] == s0[1, slot]))
     # a row that starts a sequence ignores what its slot held
-    if kernel is pr.retention_chunk:
+    if 0 in mine:
         y2, s2, _ = kernel(q, k, v, log_g, s0.at[1, 0].set(7.0), z0, 1,
                            rs, r0, rl, ro)
         np.testing.assert_array_equal(s2[1, 0], s1[1, 0])

@@ -249,15 +249,23 @@ def test_retention_kernels(v5e, kernel):
     assert (T, H, KVH, d, Dp) == (524, 40, 8, 128, 9216)
     f32 = jnp.float32
     rows = _sds(R, dtype=jnp.int32)
-    compiled = _compile(
-        getattr(pr, kernel), *_on(mesh, (
-            _sds(T, H, d), _sds(T, KVH, d), _sds(T, KVH, d),
-            _sds(T, KVH, dtype=f32),
-            _sds(cfg.n_layers, R + 1, KVH, Dp, d, dtype=f32),
-            _sds(cfg.n_layers, R + 1, KVH, Dp, dtype=f32),
-            _sds(dtype=jnp.int32), rows, rows, rows, rows)),
-        donate_argnums=(4, 5))
-    assert kernel in compiled.as_text()
+    args = _on(mesh, (
+        _sds(T, H, d), _sds(T, KVH, d), _sds(T, KVH, d),
+        _sds(T, KVH, dtype=f32),
+        _sds(cfg.n_layers, R + 1, KVH, Dp, d, dtype=f32),
+        _sds(cfg.n_layers, R + 1, KVH, Dp, dtype=f32),
+        _sds(dtype=jnp.int32), rows, rows, rows, rows))
+    fn = getattr(pr, kernel)
+    assert kernel in _compile(fn, *args, donate_argnums=(4, 5)).as_text()
+    # the live rows bound the grid (an operand of the call); a state
+    # block is 1024 features, nine a row and KV head.  PR 50 swept the
+    # decode kernel's block up to the whole D' on the chip: its row costs
+    # what a plain copy of its bytes costs at every size, so the block
+    # stays the one both kernels share
+    assert pr.state_block(Dp) == 1024
+    grid = {"retention_decode": (None, 9, KVH),
+            "retention_chunk": (KVH, None, 9)}[kernel]
+    assert _pallas_grids(jax.make_jaxpr(fn)(*args).jaxpr, kernel) == [grid]
 
 
 @pytest.mark.parametrize("shape", ["budget", "small"])

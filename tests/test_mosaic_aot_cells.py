@@ -153,11 +153,20 @@ def test_brumby_cell_step_updates_the_state_in_place(v5e, shape):
     text = compiled.as_text()
     for kernel in ("retention_decode", "retention_chunk"):
         assert kernel in text
-    for state in (f"f32[10,{slots + 1},8,9216,128]",
-                  f"f32[10,{slots + 1},8,9216]"):
+    ret_z = f"f32[10,{slots + 1},8,9216]"
+    for state in (f"f32[10,{slots + 1},8,9216,128]", ret_z):
         assert [ln for ln in text.splitlines()
                 if re.search(r"= \S*" + re.escape(state) + r"\S* copy\(", ln)
                 ] == []
+    # nor does ``ret_z`` make a round trip through VMEM every layer (38 MB
+    # in and out: XLA prefetches it for a scatter as soon as VMEM has the
+    # room), nor is the chunk kernel's ``[rows, 8, 8, D']`` normaliser
+    # re-laid out whole to read one row of eight (31 MB a layer, 0.38 ms
+    # of every step until PR 50 wrote the decode rows' ``z`` in the kernel)
+    assert re.search(re.escape(ret_z) + r"\{[^}]*S\(1\)\}", text) is None
+    assert [ln for ln in text.splitlines()
+            if re.search(r"= f32\[%d,8,8,9216\]\S* copy\(" % (slots + 1), ln)
+            ] == []
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
 
