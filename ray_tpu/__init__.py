@@ -18,6 +18,15 @@ Subpackage map:
 
 __version__ = "0.1.0"
 
+import time as _time
+
+# Top and bottom of this import on the wall clock, for the start-up
+# record's ``import{ray_tpu}``.  Importing util/tracing here would pull
+# the core runtime in with ``ray_tpu.util`` and `import ray_tpu` would
+# stop being light, so tracing writes the event when it is first
+# imported (any process that starts anything imports it).
+_import_times = [_time.time(), None]
+
 from ray_tpu.utils.ids import ActorID, JobID, NodeID, ObjectID, TaskID
 
 _API = None
@@ -100,3 +109,6 @@ def cluster_resources():
 
 def available_resources():
     return _api().available_resources()
+
+
+_import_times[1] = _time.time()

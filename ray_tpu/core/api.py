@@ -17,6 +17,7 @@ from ray_tpu.core.exceptions import RuntimeNotInitializedError
 from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core.remote_function import RemoteFunction
 from ray_tpu.core.runtime import LocalRuntime
+from ray_tpu.util import tracing
 from ray_tpu.utils.config import get_config
 
 _runtime: Optional[LocalRuntime] = None
@@ -56,33 +57,39 @@ def init(
                 return _runtime
             raise RuntimeError("ray_tpu.init() called twice — pass "
                                "ignore_reinit_error=True to allow")
-        if system_config:
-            get_config().update(system_config)
-        total = dict(resources or {})
-        labels = None
-        if num_cpus is not None:
-            total["CPU"] = float(num_cpus)
-        if num_tpus is not None:
-            total["TPU"] = float(num_tpus)
-        elif "TPU" not in total:
-            # Full detection path (parity: _private/accelerator.py):
-            # chip count, version resource, slice-head resource, ICI
-            # topology labels.
-            from ray_tpu.utils.accelerator import node_resources_and_labels
-
-            extra, labels = node_resources_and_labels()
-            for k, v in extra.items():
-                total.setdefault(k, v)
-            labels = labels or None
-        _runtime = LocalRuntime(resources=total, labels=labels)
-        # Always-on telemetry history plane: the driver samples its own
-        # registry; worker points arrive via reply piggyback
-        # (runtime.apply_ref_batches → timeseries.ingest).
-        from ray_tpu.util import timeseries
-
-        timeseries.ensure_started()
-        atexit.register(shutdown)
+        with tracing.span("runtime.init", startup=True):
+            _start_runtime(resources, num_cpus, num_tpus, system_config)
         return _runtime
+
+
+def _start_runtime(resources, num_cpus, num_tpus, system_config) -> None:
+    global _runtime
+    if system_config:
+        get_config().update(system_config)
+    total = dict(resources or {})
+    labels = None
+    if num_cpus is not None:
+        total["CPU"] = float(num_cpus)
+    if num_tpus is not None:
+        total["TPU"] = float(num_tpus)
+    elif "TPU" not in total:
+        # Full detection path (parity: _private/accelerator.py):
+        # chip count, version resource, slice-head resource, ICI
+        # topology labels.
+        from ray_tpu.utils.accelerator import node_resources_and_labels
+
+        extra, labels = node_resources_and_labels()
+        for k, v in extra.items():
+            total.setdefault(k, v)
+        labels = labels or None
+    _runtime = LocalRuntime(resources=total, labels=labels)
+    # Always-on telemetry history plane: the driver samples its own
+    # registry; worker points arrive via reply piggyback
+    # (runtime.apply_ref_batches → timeseries.ingest).
+    from ray_tpu.util import timeseries
+
+    timeseries.ensure_started()
+    atexit.register(shutdown)
 
 
 def shutdown() -> None:

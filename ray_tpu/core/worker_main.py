@@ -504,6 +504,7 @@ class _WorkerServer:
         # In-flight pushed work: the 1s ref sweep only flushes when
         # idle, so a sweep-sent del can't overtake a reply-attached add.
         self._busy = 0
+        self._booted = False
         self._busy_lock = threading.Lock()
         # Cancellation registry: task_bin → ("thread", ident) while a
         # sync body runs, ("async", fut) while a coroutine is in flight
@@ -674,6 +675,15 @@ class _WorkerServer:
         to pin, so ordering doesn't matter there."""
         with self._busy_lock:
             self._busy += 1
+            booted, self._booted = self._booted, True
+        if not booted:
+            # the start-up record's ``worker.boot``: this process's own
+            # start to its first operation
+            from ray_tpu.util import tracing
+
+            born = tracing.process_start()
+            if born is not None:
+                tracing.startup_event("worker.boot", born, time.time())
         try:
             try:
                 rep = body()

@@ -12,7 +12,11 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, Optional
 
-from ray_tpu.core import api as _api
+from ray_tpu.util import tracing
+
+_importing = tracing.import_span(__name__)
+
+from ray_tpu.core import api as _api  # noqa: E402
 from ray_tpu.serve.batching import batch
 from ray_tpu.serve.config import AutoscalingConfig, DeploymentConfig
 from ray_tpu.serve.deployment import (
@@ -32,6 +36,8 @@ from ray_tpu.serve.graph import (
     build_graph_app,
 )
 from ray_tpu.serve.multiplex import get_multiplexed_model_id, multiplexed
+
+_importing.__exit__(None, None, None)
 
 __all__ = [
     "Application", "AutoscalingConfig", "Deployment", "DeploymentConfig",
@@ -80,26 +86,31 @@ def run(app: Application, *, name: str = "default",
         timeout_s: float = 60.0) -> DeploymentHandle:
     """Deploy an application; returns a handle to its ingress deployment
     (parity: ray serve.run api.py:479)."""
-    controller = _get_or_create_controller()
-    infos = build_application(app, name)
-    _api.get(controller.deploy_application.remote(name, infos, route_prefix))
-    if wait_for_ready:
-        deadline = time.monotonic() + timeout_s
-        while time.monotonic() < deadline:
-            st = _api.get(controller.status.remote())
-            deps = st["applications"].get(name, {}).get("deployments", {})
-            if deps and all(
-                d["status"] == "HEALTHY" for d in deps.values()
-            ):
-                break
-            time.sleep(0.02)
-        else:
-            raise TimeoutError(
-                f"application {name!r} not healthy after {timeout_s}s: "
-                f"{_api.get(controller.status.remote())}"
-            )
-    ingress = _api.get(controller.get_ingress.remote(name))
+    with tracing.span("serve.run", startup=True):
+        with tracing.span("serve.deploy", startup=True):
+            controller = _get_or_create_controller()
+            infos = build_application(app, name)
+            _api.get(controller.deploy_application.remote(
+                name, infos, route_prefix))
+        if wait_for_ready:
+            with tracing.span("serve.wait_ready", startup=True):
+                _wait_healthy(controller, name, timeout_s)
+        ingress = _api.get(controller.get_ingress.remote(name))
     return DeploymentHandle(ingress, name)
+
+
+def _wait_healthy(controller, name: str, timeout_s: float) -> None:
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        st = _api.get(controller.status.remote())
+        deps = st["applications"].get(name, {}).get("deployments", {})
+        if deps and all(d["status"] == "HEALTHY" for d in deps.values()):
+            return
+        time.sleep(0.02)
+    raise TimeoutError(
+        f"application {name!r} not healthy after {timeout_s}s: "
+        f"{_api.get(controller.status.remote())}"
+    )
 
 
 def get_app_handle(name: str = "default") -> DeploymentHandle:

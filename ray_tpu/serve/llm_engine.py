@@ -37,15 +37,25 @@ import time
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from ray_tpu.core.exceptions import PreemptedError, ShedError
-from ray_tpu.serve import audit as _audit
-from ray_tpu.serve import request_events as _reqev
-from ray_tpu.serve.loop_clock import LoopClock, LoopSecondsFamily
 from ray_tpu.util import tracing
+
+_importing = tracing.import_span(__name__)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from ray_tpu.core.exceptions import PreemptedError, ShedError  # noqa: E402
+from ray_tpu.serve import audit as _audit  # noqa: E402
+from ray_tpu.serve import request_events as _reqev  # noqa: E402
+from ray_tpu.serve.loop_clock import (  # noqa: E402
+    LoopClock,
+    LoopSecondsFamily,
+)
+from ray_tpu.util import xprof  # noqa: E402
+
+xprof.watch_compiles()
+_importing.__exit__(None, None, None)
 
 log = logging.getLogger(__name__)
 
@@ -1044,8 +1054,10 @@ class LLMServer:
                 "a request over by migrating its KV pages; this model's "
                 "cache holds per-slot recurrent state that no page "
                 "carries")
+        with tracing.span("llm.load_weights", startup=True):
+            params = jax.block_until_ready(param_loader())
         self.engine = LLMEngine(
-            param_loader(), adapter, engine_cfg,
+            params, adapter, engine_cfg,
             mesh=mesh, draft_params=draft_params,
             draft_adapter=draft_adapter,
         )
@@ -1392,6 +1404,7 @@ _ENGINE_IDS = itertools.count()
 class LLMEngine:
     """Continuous-batching scheduler around jitted prefill/decode."""
 
+    @tracing.in_startup_span("llm.engine_init")
     def __init__(self, params: Any, adapter: PagedEngineAdapter,
                  config: EngineConfig, *, seed: int = 0, mesh: Any = None,
                  draft_params: Any = None,
@@ -1453,20 +1466,22 @@ class LLMEngine:
         self._num_pages = ((config.num_pages
                             or config.max_slots * self._maxp)
                            if self._paged_kv else 0)
-        if mesh is not None and adapter.cache_shardings is not None:
-            # Allocate the pool directly under its shardings: a
-            # materialize-then-reshard would briefly hold the WHOLE
-            # unsharded pool on one device — an OOM at exactly the
-            # model sizes tp serving exists for.
-            self._cache = jax.jit(
-                partial(adapter.init_cache, self._num_pages, page),
-                out_shardings=adapter.cache_shardings(mesh),
-            )()
-        elif self._state_bytes_per_slot:
-            self._cache = adapter.init_cache(self._num_pages, page,
-                                             config.max_slots)
-        else:
-            self._cache = adapter.init_cache(self._num_pages, page)
+        with tracing.span("llm.init_cache", startup=True):
+            if mesh is not None and adapter.cache_shardings is not None:
+                # Allocate the pool directly under its shardings: a
+                # materialize-then-reshard would briefly hold the WHOLE
+                # unsharded pool on one device — an OOM at exactly the
+                # model sizes tp serving exists for.
+                self._cache = jax.jit(
+                    partial(adapter.init_cache, self._num_pages, page),
+                    out_shardings=adapter.cache_shardings(mesh),
+                )()
+            elif self._state_bytes_per_slot:
+                self._cache = adapter.init_cache(self._num_pages, page,
+                                                 config.max_slots)
+            else:
+                self._cache = adapter.init_cache(self._num_pages, page)
+            jax.block_until_ready(self._cache)
         # The cache's two parts in bytes, from the tree itself: what it
         # holds by slot (the leaves the adapter names) and the page
         # pools with their scales (every other leaf).
@@ -2198,6 +2213,9 @@ class LLMEngine:
             "loop": self._clock.snapshot(),
             "steps_by_shape": dict(self._steps_by_shape),
             "requests": self._ring.counts_by_state(),
+            # how this process started: seconds by start-up span,
+            # ready_s, programs compiled, cache misses
+            "startup": xprof.startup_table(),
         }
         out["kv_pages_free"] = len(self._free_pages)
         out["kv_pages_cached"] = (self._prefix.cached_pages
@@ -2393,50 +2411,27 @@ class LLMEngine:
                                steps_attr=None, cost_steps=None,
                                shape=None):
         """Dispatch one jitted program; the FIRST dispatch of each
-        named program, at each ``shape`` it is compiled for, also
-        registers it in the device plane (util/xprof): lowered cost
-        analysis must happen before the call
-        (the program donates its cache — afterwards those buffers are
-        deleted), while the timed call itself measures trace+compile
-        wall.  Later dispatches pass straight through.  ``cost_steps``
-        declares how many tokens the recorded cost covers (the
-        per-token denominator for waterfall device estimates).  The
-        device plane names a program's largest shape by the program's
-        name and any other ``<name>@<shape>``: each is an executable
-        with a cost and a compile window of its own."""
+        named program, at each ``shape`` it is compiled for, goes
+        through ``xprof.first_call``: the device plane gets its cost
+        and its compile window, the start-up record the span
+        ``llm.first_step{program, shape}``, and the step's own span
+        name one record tagged ``compile=true``, which the roofline
+        join and the victim request's waterfall skip.  Later dispatches
+        pass straight through.  ``cost_steps`` declares how many tokens
+        the recorded cost covers (the per-token denominator for
+        waterfall device estimates).  The device plane names a
+        program's largest shape by the program's name and any other
+        ``<name>@<shape>``: each is an executable with a cost and a
+        compile window of its own."""
         if (name, shape) in self._xprof_recorded:
             return fn(*args)
         self._xprof_recorded.add((name, shape))
         if shape is not None and shape != self._token_budget:
             name = f"{name}@{shape}"
-        lowered = None
-        try:
-            lowered = fn.lower(*args)
-        except Exception:
-            pass
-        t0 = time.time()
-        out = fn(*args)
-        t1 = time.time()
-        if lowered is not None:
-            try:
-                from ray_tpu.util import xprof
-
-                xprof.record_compiled(
-                    name, lowered, compile_time_s=t1 - t0,
-                    span_name=span_name, steps_attr=steps_attr,
-                    cost_steps=cost_steps, compiled_at=t1)
-            except Exception:
-                pass  # device-plane attribution is best-effort
-        # The first dispatch's wall is XLA trace+compile, not a step:
-        # tag its span compile=true so the roofline wall join skips it
-        # and the victim request's waterfall excludes it (the xprof
-        # compile window above carries the same exclusion when span
-        # capture is off).
-        if tracing.is_enabled():
-            tracing.record_span(span_name, t0, t1,
-                                attributes={"compile": True,
-                                            "program": name})
-        return out
+        return xprof.first_call(
+            name, fn, args, span_name=span_name, steps_attr=steps_attr,
+            cost_steps=cost_steps, tag_compile=True,
+            **({} if shape is None else {"shape": shape}))
 
     def _run_prefill(self, k, tokens, true_lens, pages_rows, temps,
                      slot_ids):

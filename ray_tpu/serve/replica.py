@@ -100,7 +100,11 @@ class ReplicaActor:
 
             set_disagg(DisaggContext(**disagg))
         if inspect.isclass(func_or_class):
-            self._callable = func_or_class(*init_args, **init_kwargs)
+            # the start-up record's span of the user's own constructor
+            with tracing.span("serve.replica_init", startup=True,
+                              attributes={"deployment": deployment_name,
+                                          "replica": replica_id}):
+                self._callable = func_or_class(*init_args, **init_kwargs)
         else:
             if init_args or init_kwargs:
                 raise ValueError(

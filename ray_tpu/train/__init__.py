@@ -1,3 +1,7 @@
+from ray_tpu.util import tracing as _tracing
+
+_importing = _tracing.import_span(__name__)
+
 from ray_tpu.train.checkpoint import CheckpointManager
 from ray_tpu.train.optim8 import adamw8bit, scale_by_adam8bit
 from ray_tpu.train.state import (
@@ -29,6 +33,10 @@ from ray_tpu.train.worker_group import (
     TrainOutput,
     WorkerGroup,
 )
+from ray_tpu.util import xprof as _xprof
+
+_xprof.watch_compiles()
+_importing.__exit__(None, None, None)
 
 __all__ = [
     "BackendExecutor",

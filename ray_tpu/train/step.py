@@ -10,7 +10,6 @@ hides inside torch DDP; ray: python/ray/train/torch/config.py:63).
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -19,64 +18,23 @@ import optax
 
 from ray_tpu.parallel.sharding import Rules, tree_shardings
 from ray_tpu.train.state import TrainState, state_shardings
-from ray_tpu.util import tracing
-
-_TELEMETRY = None
-
-
-def _telemetry():
-    """Step-compilation metric singleton (re-registered on refetch —
-    see serve/llm_engine._telemetry for the registry-clear rationale)."""
-    global _TELEMETRY
-    from ray_tpu.util import metrics
-
-    if _TELEMETRY is None:
-        _TELEMETRY = {
-            "compile": metrics.Counter(
-                "raytpu_train_compile_seconds_total",
-                "Seconds spent in first-call XLA compilation of train "
-                "steps.",
-            ),
-        }
-    else:
-        reg = metrics.registry()
-        for m in _TELEMETRY.values():
-            reg.register(m)
-    return _TELEMETRY
-
 
 def _instrument_first_call(jitted):
     """The first invocation of a jitted step traces + compiles the XLA
-    program; time it so compile cost shows up next to step time in the
-    registry and the timeline.  Subsequent calls pass straight through."""
+    program: it goes through ``xprof.first_call``, which registers the
+    step's cost in the device plane as ``train.step`` and leaves the
+    start-up span ``train.first_step``.  Subsequent calls pass straight
+    through."""
     compiled = []
 
     def wrapped(state, batch):
         if compiled:
             return jitted(state, batch)
-        # Lower BEFORE executing: the step donates ``state``, so after
-        # the call those buffers are gone and cost analysis would have
-        # nothing to trace against.
-        lowered = None
-        try:
-            lowered = jitted.lower(state, batch)
-        except Exception:
-            pass
-        t0 = time.time()
-        out = jitted(state, batch)
-        compiled.append(True)
-        elapsed = time.time() - t0
-        _telemetry()["compile"].inc(elapsed)
-        tracing.record_span("train.compile", t0, t0 + elapsed)
-        if lowered is not None:
-            try:
-                from ray_tpu.util import xprof
+        from ray_tpu.util import xprof
 
-                xprof.record_compiled(
-                    "train.step", lowered, compile_time_s=elapsed,
-                    span_name="train.compute")
-            except Exception:
-                pass  # device-plane attribution is best-effort
+        out = xprof.first_call("train.step", jitted, (state, batch),
+                               span_name="train.compute")
+        compiled.append(True)
         return out
 
     wrapped.__wrapped__ = jitted

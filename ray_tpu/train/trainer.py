@@ -126,6 +126,9 @@ class Result:
     metrics_history: List[Dict[str, float]]
     checkpoint_path: Optional[str]
     error: Optional[BaseException] = None
+    # How this process started (``xprof.startup_table``): seconds by
+    # start-up span, ``ready_s``, programs compiled, cache misses.
+    startup: Optional[Dict[str, Any]] = None
 
 
 class JaxTrainer:
@@ -164,25 +167,33 @@ class JaxTrainer:
     # -- setup -------------------------------------------------------------
 
     def _build(self):
+        """Start-up spans: ``train.build`` round ``train.shardings``
+        (the abstract pass) and ``train.init_state`` (the sharded init,
+        run to completion)."""
         rng = jax.random.key(self.seed)
-        with self.mesh:
-            abstract = jax.eval_shape(
-                lambda r: create_train_state(self.init_params_fn(r), self.tx), rng
-            )
-            # Compile the step against abstract state to get shardings first.
-            self._step_fn, self._state_sh, self._batch_sh = compile_train_step(
-                self.mesh, self.loss_fn, self.tx, abstract, self.params_axes,
-                self.batch_axes, self.rules,
-                zero_sharding=self.trainer_config.zero_sharding,
-                grad_accum=self.trainer_config.grad_accum,
-            )
+        with tracing.span("train.build", startup=True), self.mesh:
+            with tracing.span("train.shardings", startup=True):
+                abstract = jax.eval_shape(
+                    lambda r: create_train_state(
+                        self.init_params_fn(r), self.tx), rng)
+                # Compile the step against abstract state to get
+                # shardings first.
+                self._step_fn, self._state_sh, self._batch_sh = \
+                    compile_train_step(
+                        self.mesh, self.loss_fn, self.tx, abstract,
+                        self.params_axes, self.batch_axes, self.rules,
+                        zero_sharding=self.trainer_config.zero_sharding,
+                        grad_accum=self.trainer_config.grad_accum,
+                    )
             # Init params *directly sharded* — no host-memory full copy, so
             # 70B-scale states can initialize on the mesh.
-            init = jax.jit(
-                lambda r: create_train_state(self.init_params_fn(r), self.tx),
-                out_shardings=self._state_sh,
-            )
-            self._state = init(rng)
+            with tracing.span("train.init_state", startup=True):
+                init = jax.jit(
+                    lambda r: create_train_state(
+                        self.init_params_fn(r), self.tx),
+                    out_shardings=self._state_sh,
+                )
+                self._state = jax.block_until_ready(init(rng))
         self._emit_memory_gauges()
 
     def _emit_memory_gauges(self):
@@ -314,4 +325,5 @@ class JaxTrainer:
             metrics_history=history,
             checkpoint_path=path,
             error=error,
+            startup=xprof.startup_table(),
         )

@@ -25,6 +25,17 @@ contract as metrics/span/request-row federation.  A trigger event
 arriving from a worker fires the driver-side auto-dump, so the bundle
 holds the offending request's events from every process that saw it.
 
+Start-up record: events of the start-up kinds (``STARTUP_KINDS``: a
+``startup`` span's end from util/tracing, a ``compile`` stage from
+util/xprof's compile watch, and the engine's one-off
+``serve_cache_parts`` / ``serve_model_parts`` / ``ragged_weight_routes``)
+go into the ring like any event AND into a list of their own, the first
+``STARTUP_CAP`` of each process, which ring traffic never evicts and the
+window never filters.  They ship on the same piggyback; ``startup()``
+reads them and every bundle's ``events.json`` holds them, so the
+process that started a deployment can say how it started an hour later,
+and after ``ray_tpu.shutdown()``.
+
 Surfaces: ``raytpu flightrec dump`` (CLI) and
 ``POST /api/v0/flightrec/dump`` (dashboard) force a manual bundle;
 ``snapshot()`` backs both plus the tests.
@@ -53,6 +64,11 @@ _dump_n = 0
 _last_auto_dump_t = 0.0
 _min_dump_interval_s = 2.0
 _counter_baseline: Dict[str, float] = {}
+STARTUP_KINDS = frozenset({"startup", "compile", "serve_cache_parts",
+                           "serve_model_parts", "ragged_weight_routes"})
+STARTUP_CAP = 512              # start-up events kept, a process
+_startup: List[Dict[str, Any]] = []
+_remote_startup: Dict[str, List[Dict[str, Any]]] = {}
 
 
 def _telemetry():
@@ -126,6 +142,8 @@ def clear() -> None:
     with _lock:
         _events.clear()
         _remote.clear()
+        _startup.clear()
+        _remote_startup.clear()
         _counter_baseline.clear()
         _seq = _ship_seq = _dump_n = 0
         _last_auto_dump_t = 0.0
@@ -140,6 +158,8 @@ def record(kind: str, **fields: Any) -> int:
         _seq += 1
         ev["seq"] = _seq
         _events.append(ev)
+        if kind in STARTUP_KINDS and len(_startup) < STARTUP_CAP:
+            _startup.append(ev)
         n = len(_events)
     try:
         _telemetry()["events"].set(float(n))
@@ -218,7 +238,13 @@ def ship() -> List[Dict[str, Any]]:
     reply piggyback).  Advances the cursor; returns [] when idle."""
     global _ship_seq
     with _lock:
-        evs = [dict(e) for e in _events if e["seq"] > _ship_seq]
+        evs = []
+        if _startup and _startup[-1]["seq"] > _ship_seq:
+            # a start-up event the ring lost before any reply carried it
+            oldest = _events[0]["seq"] if _events else _seq + 1
+            evs = [dict(e) for e in _startup
+                   if _ship_seq < e["seq"] < oldest]
+        evs.extend(dict(e) for e in _events if e["seq"] > _ship_seq)
         if evs:
             _ship_seq = evs[-1]["seq"]
     return evs
@@ -236,6 +262,10 @@ def ingest(proc: str, events: List[Dict[str, Any]]) -> Optional[str]:
             ring = _remote[proc] = collections.deque(
                 maxlen=_events.maxlen)
         ring.extend(dict(e) for e in events)
+        kept = _remote_startup.setdefault(proc, [])
+        kept.extend(dict(e) for e in events
+                    if e.get("kind") in STARTUP_KINDS)
+        del kept[STARTUP_CAP:]
     triggers = [e for e in events if e.get("kind") == "trigger"]
     if triggers:
         return _maybe_auto_dump(triggers[0].get("reason", "remote"),
@@ -266,6 +296,19 @@ def snapshot(request_id: Optional[str] = None,
     return {p: evs for p, evs in out.items() if evs or p == "driver"}
 
 
+def startup(proc: Optional[str] = None):
+    """The start-up record: ``{"driver": [...], proc: [...]}``, each
+    process's events of the start-up kinds in the order they happened,
+    whatever the ring has dropped since and however old they are (local
+    events under "driver", as in ``snapshot``).  With ``proc``, that
+    process's list alone."""
+    with _lock:
+        out = {"driver": [dict(e) for e in _startup]}
+        for p, evs in sorted(_remote_startup.items()):
+            out[p] = [dict(e) for e in evs]
+    return out if proc is None else out.get(proc, [])
+
+
 def dump(reason: str = "manual", dump_dir: Optional[str] = None,
          detail: Optional[str] = None) -> Optional[str]:
     """Write a bundle directory (events.json + metrics.prom +
@@ -285,7 +328,8 @@ def dump(reason: str = "manual", dump_dir: Optional[str] = None,
     events = snapshot()
     with open(os.path.join(path, "events.json"), "w") as f:
         json.dump({"reason": reason, "created_at": time.time(),
-                   "window_s": _window_s, "events": events}, f, indent=1)
+                   "window_s": _window_s, "events": events,
+                   "startup": startup()}, f, indent=1)
     try:
         from ray_tpu.util import metrics
         with open(os.path.join(path, "metrics.prom"), "w") as f:

@@ -19,13 +19,20 @@ open, whether or not tracing was enabled, so a profiler capture shows
 the program's spans (the engine loop's phases, the trainer's, the
 background threads') beside the device's operations on one clock.  This
 module never imports JAX and never initialises a backend.
+
+One bridge to the flight recorder that is always on: a span opened
+with ``startup=True`` (a phase of a process's start) leaves one event
+of kind ``startup`` when it ends, tracing enabled or not, which the
+recorder keeps apart from its ring (util/flight_recorder).
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import json
+import os
 import sys
 import threading
 import time
@@ -174,17 +181,27 @@ class span:
     ``record=False`` is for a span that fires in every iteration of a
     hot loop (the engine loop's phases, a background thread's tick): a
     capture sees it, the span buffer and the flight recorder never do,
-    so turning tracing on does not flood either."""
+    so turning tracing on does not flood either.
 
-    __slots__ = ("record", "_ann", "_prev")
+    ``startup=True`` is for a phase of a process's start (an import, the
+    runtime's start, a replica's construction, the first call of a
+    program): whether or not tracing is enabled the span's end also
+    leaves one flight-recorder event of kind ``startup`` (see
+    ``startup_event``), which the recorder keeps apart from its ring.
+    ``parent`` there is the start-up span open round it on this
+    thread."""
+
+    __slots__ = ("record", "start", "end", "_ann", "_prev", "_startup")
 
     def __init__(self, name: str, ctx: Optional[Dict[str, str]] = None,
                  attributes: Optional[Dict[str, Any]] = None, *,
-                 record: bool = True):
+                 record: bool = True, startup: bool = False):
         cls = _trace_annotation()
         self._ann = (cls(name, **attributes) if attributes else cls(name)
                      ) if cls is not None else None
         self.record = None
+        self._startup = ((name, dict(attributes or {})) if startup
+                         else None)
         if _enabled and record:
             parent = ctx if ctx is not None else _current()
             self.record = {
@@ -202,8 +219,13 @@ class span:
             self._ann.set_metadata(**attrs)
         if self.record is not None:
             self.record["attributes"].update(attrs)
+        if self._startup is not None:
+            self._startup[1].update(attrs)
 
     def __enter__(self) -> "span":
+        if self._startup is not None:
+            _startup_stack().append(self._startup[0])
+            self.start = time.time()
         rec = self.record
         if rec is not None:
             self._prev = _current()
@@ -224,6 +246,82 @@ class span:
             rec["end"] = time.time()
             _tls.ctx = self._prev
             _finish(rec)
+        if self._startup is not None:
+            self.end = time.time()
+            stack = _startup_stack()
+            stack.pop()
+            name, attrs = self._startup
+            startup_event(name, self.start, self.end,
+                          parent=stack[-1] if stack else None, **attrs)
+
+
+def import_span(package: str) -> span:
+    """The start-up span ``import{package}``, entered: a package stamps
+    the top of its ``__init__`` with this and the bottom with the
+    span's ``__exit__(None, None, None)``; nested imports nest."""
+    return span("import", attributes={"package": package},
+                startup=True).__enter__()
+
+
+def in_startup_span(name: str):
+    """Decorator: the call runs under the start-up span ``name``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with span(name, startup=True):
+                return fn(*args, **kwargs)
+        return wrapped
+    return wrap
+
+
+def _startup_stack() -> List[str]:
+    stack = getattr(_tls, "startup", None)
+    if stack is None:
+        stack = _tls.startup = []
+    return stack
+
+
+def current_startup() -> Optional[str]:
+    """The innermost start-up span open on this thread, by name."""
+    stack = _startup_stack()
+    return stack[-1] if stack else None
+
+
+def startup_event(name: str, start: float, end: float, *,
+                  parent: Optional[str] = None, **attributes: Any) -> None:
+    """One flight-recorder event of kind ``startup``: ``{name, start,
+    end, parent, pid, **attributes}`` on the wall clock.  Always on; a
+    process leaves a few tens.  Where a backend is ALREADY initialised
+    the event also says how full the fullest local device has been
+    (``hbm_peak_bytes``); this never initialises one."""
+    from ray_tpu.util import flight_recorder
+
+    ev = dict(attributes, name=name, start=start, end=end, parent=parent,
+              pid=os.getpid())
+    if "jax" in sys.modules:
+        try:
+            from ray_tpu.util import xprof
+
+            peak = xprof.hbm_peak_bytes()
+            if peak is not None:
+                ev["hbm_peak_bytes"] = peak
+        except Exception:
+            pass   # the record must never take a start down with it
+    flight_recorder.record("startup", **ev)
+
+
+def process_start() -> Optional[float]:
+    """Wall-clock time this process started, good to a clock tick: its
+    age is ``/proc/self/stat``'s start time (field 22, in ticks since
+    boot) against ``CLOCK_BOOTTIME``.  None where there is no /proc."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+        return time.time() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
 
 
 def record_span(name: str, start: float, end: float, *,
@@ -265,3 +363,14 @@ def task_span(name: str, ctx: Optional[Dict[str, str]],
     """Span for one task execution on a worker thread (parity: the
     server-side wrapper in tracing_helper)."""
     return span(name, ctx=ctx, attributes=attributes)
+
+
+def _stamp_package_import() -> None:
+    """``import{ray_tpu}`` from the two times ``ray_tpu/__init__.py``
+    took (it cannot import this module and stay light)."""
+    times = getattr(sys.modules.get("ray_tpu"), "_import_times", None)
+    if times and times[1] is not None:
+        startup_event("import", times[0], times[1], package="ray_tpu")
+
+
+_stamp_package_import()

@@ -6,10 +6,19 @@ The host-side telemetry plane (util/metrics.py + util/tracing.py) sees
 walls and queues; this module is its device-side half:
 
   * ``record_compiled(name, lowered)`` — every named jitted program
-    registers its ``cost_analysis()`` flops / bytes-accessed and first
-    -call compile wall into ``raytpu_xla_*`` families.  Producers:
-    train/step.py (the SPMD train step) and serve/llm_engine.py
-    (prefill + decode programs).
+    registers its ``cost_analysis()`` flops / bytes-accessed into
+    ``raytpu_xla_*`` families.  ``first_call()`` is the one wrapper
+    round a named program's first call (train/step.py's train step,
+    serve/llm_engine.py's programs at each shape): it lowers for the
+    cost analysis, runs the call to its result under a start-up span
+    and takes the program's compile window from the compile watch.
+  * ``watch_compiles()`` — the compile watch: one listener a process on
+    ``jax.monitoring`` that sees the trace, the lowering and the
+    backend compile (or persistent-cache read) of EVERY jitted function
+    by name, with no wrapper at any call site.  Stages of 50 ms or more
+    are flight-recorder events of kind ``compile``; all feed
+    ``raytpu_xla_compile_seconds_total{program, stage}`` and
+    ``raytpu_xla_compile_cache_total{result}``.
   * ``roofline()`` — joins the registered cost numbers against the
     span walls the producers already emit (train.compute, llm.decode)
     and the chip's peak flops / HBM bandwidth
@@ -39,15 +48,35 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import sys
 import tempfile
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.util import flight_recorder, tracing
+
 _TELEMETRY = None
 _lock = threading.Lock()
 _programs: "Dict[str, ProgramRecord]" = {}
 _capture_lock = threading.Lock()
+
+# jax/_src/dispatch.py's three events, one a stage of a jitted
+# function's way to an executable, and the persistent cache's.
+_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+# A stage this long is an event of its own; shorter ones (a thousand
+# eager operations' worth) add to one tally a stage, which leaves an
+# event each time it has gathered TALLY_EVENT_S more.
+STAGE_EVENT_S = 0.05
+TALLY_EVENT_S = 1.0
+_watching = False
+_watch_tls = threading.local()
+_tally: Dict[str, Dict[str, float]] = {}
 
 
 @dataclasses.dataclass
@@ -57,7 +86,6 @@ class ProgramRecord:
     name: str
     flops: Optional[float] = None
     bytes_accessed: Optional[float] = None
-    compile_time_s: Optional[float] = None
     # Which tracer span carries this program's measured wall, and which
     # span attribute holds the number of device steps the wall covers
     # (None = the span is one step).
@@ -67,10 +95,13 @@ class ProgramRecord:
     # cover — lets latency_attribution turn flops/bytes into a
     # per-token device estimate.  None = unknown, no estimate.
     cost_steps: Optional[float] = None
-    # Wall-clock END of the first-call trace+compile; with
-    # compile_time_s this bounds the compile window so a waterfall can
-    # exclude compilation from the victim request's attribution even
-    # when span capture is off.
+    # The compile window, from the compile watch: ``compiled_at`` is
+    # the wall-clock END of the last stage (trace, lowering, compile or
+    # cache read) seen during the program's first call and
+    # ``compile_time_s`` reaches back to the first one's start, so a
+    # waterfall can exclude compilation from the victim request's
+    # attribution even when span capture is off.
+    compile_time_s: Optional[float] = None
     compiled_at: Optional[float] = None
 
 
@@ -96,8 +127,20 @@ def _telemetry():
             ),
             "compile": metrics.Counter(
                 "raytpu_xla_compile_seconds_total",
-                "First-call trace+compile wall seconds, by program.",
-                tag_keys=("program",),
+                "Seconds jitted functions spent on the way to an "
+                "executable, by stage (trace, lower, compile: the "
+                "backend compile or the persistent cache's read) and "
+                "program: the registered name during a named "
+                "program's first call, else the function's, and "
+                "'short' for stages under 50 ms outside one.",
+                tag_keys=("program", "stage"),
+            ),
+            "compile_cache": metrics.Counter(
+                "raytpu_xla_compile_cache_total",
+                "Backend compiles by what the persistent compilation "
+                "cache did: hit, miss (compiled and written) or "
+                "uncached (under the cache's thresholds, or no cache).",
+                tag_keys=("result",),
             ),
             "flops_util": metrics.Gauge(
                 "raytpu_xla_roofline_flops_utilization",
@@ -163,7 +206,9 @@ def record_compiled(name: str, program,
     ``Compiled``) in the device plane.  Extracted cost numbers land as
     ``raytpu_xla_*`` samples; keys the backend doesn't report stay
     absent.  ``span_name``/``steps_attr`` declare which tracer span
-    measures this program's wall, for the roofline join."""
+    measures this program's wall, for the roofline join;
+    ``compile_time_s``/``compiled_at`` are the program's compile window
+    (``first_call`` reads it off the compile watch)."""
     cost = _cost_dict(program)
     rec = ProgramRecord(
         name=name,
@@ -173,8 +218,7 @@ def record_compiled(name: str, program,
         span_name=span_name,
         steps_attr=steps_attr,
         cost_steps=cost_steps,
-        compiled_at=(compiled_at if compiled_at is not None
-                     else (time.time() if compile_time_s else None)),
+        compiled_at=compiled_at,
     )
     with _lock:
         _programs[name] = rec
@@ -184,8 +228,6 @@ def record_compiled(name: str, program,
         tm["flops"].set(rec.flops, tags=tags)
     if rec.bytes_accessed is not None:
         tm["bytes"].set(rec.bytes_accessed, tags=tags)
-    if compile_time_s is not None and compile_time_s >= 0:
-        tm["compile"].inc(compile_time_s, tags=tags)
     return rec
 
 
@@ -198,6 +240,185 @@ def clear() -> None:
     """Drop every registered program (test isolation)."""
     with _lock:
         _programs.clear()
+
+
+# -- the compile watch ------------------------------------------------------
+
+def watch_compiles() -> bool:
+    """Register the compile watch on ``jax.monitoring`` (once a process;
+    False, and nothing imported, where JAX is not imported yet)."""
+    global _watching
+    if "jax" not in sys.modules:
+        return False
+    from jax import monitoring
+
+    with _lock:
+        if not _watching:
+            monitoring.register_event_listener(_on_cache_event)
+            monitoring.register_event_duration_secs_listener(
+                _on_cache_seconds)
+            monitoring.register_event_time_span_listener(_on_stage)
+            _watching = True
+    return True
+
+
+def _on_cache_event(event: str, **_: Any) -> None:
+    # fires on the compiling thread inside that program's compile stage
+    result = _CACHE_EVENTS.get(event)
+    if result is not None:
+        _watch_tls.cache = result
+
+
+def _on_cache_seconds(event: str, duration: float, **_: Any) -> None:
+    if event == _CACHE_RETRIEVAL:
+        _watch_tls.retrieval_s = duration
+
+
+def _on_stage(event: str, start: float, end: float, **kw: Any) -> None:
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    # tracing names the function ``f``, lowering and compiling its
+    # module ``jit(f)``: one program
+    program = str(kw.get("fun_name"))
+    if program.startswith("jit(") and program.endswith(")"):
+        program = program[4:-1]
+    try:
+        _record_stage(stage, program, start, end)
+    except Exception:
+        pass    # the watch must never fail a compilation
+
+
+def _record_stage(stage: str, program: str, start: float,
+                  end: float) -> None:
+    rec: Dict[str, Any] = {"program": program, "stage": stage,
+                           "start": start, "end": end}
+    if stage == "compile":
+        rec["cache"] = getattr(_watch_tls, "cache", None) or "uncached"
+        retrieval_s = getattr(_watch_tls, "retrieval_s", None)
+        if retrieval_s is not None:
+            rec["retrieval_s"] = retrieval_s
+        _watch_tls.cache = _watch_tls.retrieval_s = None
+    calls = getattr(_watch_tls, "calls", None)
+    if calls:       # inside first_call(): a stage of that program's
+        rec["registered"] = calls[-1][0]
+        calls[-1][1].append((start, end))
+    seconds = end - start
+    # what the cache held or was given is an event however short
+    long = (seconds >= STAGE_EVENT_S
+            or rec.get("cache") in ("hit", "miss"))
+    tm = _telemetry()
+    tm["compile"].inc(max(seconds, 0.0), tags={
+        "program": rec.get("registered") or (program if long else "short"),
+        "stage": stage})
+    if stage == "compile":
+        tm["compile_cache"].inc(tags={"result": rec["cache"]})
+    if not long:
+        with _lock:
+            t = _tally.setdefault(stage, {"n": 0, "seconds": 0.0,
+                                          "shown_n": 0, "shown_s": 0.0,
+                                          "start": start})
+            t["n"] += 1
+            t["seconds"] += seconds
+            if t["seconds"] - t["shown_s"] < TALLY_EVENT_S:
+                return
+            rec = {"program": "short", "stage": stage, "tally": True,
+                   "n": t["n"] - t["shown_n"],
+                   "seconds": t["seconds"] - t["shown_s"],
+                   "start": t["start"], "end": end}
+            t.update(shown_n=t["n"], shown_s=t["seconds"], start=end)
+    flight_recorder.record("compile", pid=os.getpid(),
+                           parent=tracing.current_startup(), **rec)
+
+
+def first_call(name: str, fn, args, *, span_name: str,
+               steps_attr: Optional[str] = None,
+               cost_steps: Optional[float] = None,
+               tag_compile: bool = False, **attributes: Any):
+    """The first call of the jitted ``fn`` as the program ``name`` (one
+    compiled shape of it): registers it in the device plane and returns
+    the call's result.  The cost analysis wants a lowering of its own,
+    and BEFORE the call (a step donates its state: afterwards those
+    buffers are deleted): that is the start-up span
+    ``<plane>.cost_analysis`` (``<plane>`` is ``span_name``'s: ``llm``,
+    ``train``), inside ``<plane>.first_step{program, **attributes}``,
+    which runs on from the call to its result ready: trace, lowering,
+    compile or cache read, first execution.  The compile watch says
+    which stages ran on this thread
+    meanwhile; their extent is the program's compile window
+    (``ProgramRecord.compile_time_s`` / ``compiled_at``).
+    ``tag_compile`` also records the call as a ``span_name`` span
+    tagged ``compile=true`` (tracing enabled), for a plane whose step
+    spans are recorded one by one: the roofline join and a request's
+    waterfall skip it."""
+    import jax
+
+    watch_compiles()
+    stages: List = []
+    calls = getattr(_watch_tls, "calls", None)
+    if calls is None:
+        calls = _watch_tls.calls = []
+    calls.append((name, stages))
+    plane = span_name.split(".")[0]
+    try:
+        with tracing.span(plane + ".first_step", startup=True,
+                          attributes=dict(attributes, program=name)) as sp:
+            rec = None
+            with tracing.span(plane + ".cost_analysis", startup=True):
+                try:
+                    rec = record_compiled(
+                        name, fn.lower(*args), span_name=span_name,
+                        steps_attr=steps_attr, cost_steps=cost_steps)
+                except Exception:
+                    pass    # device-plane attribution is best-effort
+            out = jax.block_until_ready(fn(*args))
+    finally:
+        calls.pop()
+    if rec is not None and stages:
+        rec.compiled_at = max(e for _s, e in stages)
+        rec.compile_time_s = rec.compiled_at - min(s for s, _e in stages)
+    if tag_compile and tracing.is_enabled():
+        tracing.record_span(span_name, sp.start, sp.end,
+                            attributes={"compile": True, "program": name})
+    return out
+
+
+def startup_table() -> Dict[str, Any]:
+    """How THIS process started, for an operator
+    (``LLMEngine.stats()["startup"]``, ``JaxTrainer``'s result): seconds
+    by start-up span, ``ready_s`` (process start to the first step's
+    result), the compile watch's stages by program and the cache's
+    results, from the flight recorder's start-up record; ``short``
+    is the tally of the stages under 50 ms."""
+    phases: Dict[str, float] = {}
+    progs: Dict[str, Dict[str, Any]] = {}
+    cache = {"hit": 0, "miss": 0, "uncached": 0}
+    first_end = None
+    for ev in flight_recorder.startup("driver"):
+        if ev["kind"] == "startup":
+            phases[ev["name"]] = (phases.get(ev["name"], 0.0)
+                                  + ev["end"] - ev["start"])
+            if ev["name"].endswith(".first_step"):
+                first_end = min(first_end or ev["end"], ev["end"])
+        elif ev["kind"] == "compile" and not ev.get("tally"):
+            row = progs.setdefault(
+                ev.get("registered") or ev["program"], {})
+            st = row.setdefault(ev["stage"], {"n": 0, "seconds": 0.0})
+            st["n"] += 1
+            st["seconds"] += ev["end"] - ev["start"]
+            if "cache" in ev:
+                row.setdefault("cache", []).append(ev["cache"])
+                cache[ev["cache"]] += 1
+    born = tracing.process_start()
+    with _lock:
+        short = {stage: {"n": t["n"], "seconds": t["seconds"]}
+                 for stage, t in _tally.items()}
+    return {"seconds": phases,
+            "ready_s": (None if first_end is None or born is None
+                        else first_end - born),
+            "programs": progs, "programs_compiled": len(progs),
+            "cache": cache, "cache_misses": cache["miss"],
+            "short": short}
 
 
 # -- roofline attribution ---------------------------------------------------
@@ -300,6 +521,19 @@ def roofline(spec: Optional[Dict[str, Any]] = None
 
 
 # -- device memory ----------------------------------------------------------
+
+def hbm_peak_bytes() -> Optional[int]:
+    """``peak_bytes_in_use`` of the fullest local device; None where
+    this process holds no backend or the backend keeps no such count
+    (a CPU)."""
+    peaks = []
+    for d in _local_devices():
+        try:
+            peaks.append((d.memory_stats() or {}).get("peak_bytes_in_use"))
+        except Exception:
+            pass
+    return max((p for p in peaks if p is not None), default=None)
+
 
 def sample_device_memory() -> None:
     """Per-device HBM watermarks → shared gauges.  TPU/GPU backends

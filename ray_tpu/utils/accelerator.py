@@ -266,13 +266,18 @@ def claim_tpu() -> Dict[str, str]:
     backend before first use, so a missing or busy chip raises instead
     of landing on the CPU, and initialise it.  Returns a report
     (platform, device_kind, count, library versions, cache directory)
-    for the caller to print."""
-    import jax
-    import jaxlib
+    for the caller to print.  The start-up record's span
+    ``runtime.claim_tpu``: the import of JAX, where this is the first,
+    and the backend's initialisation."""
+    from ray_tpu.util import tracing
 
-    jax.config.update("jax_platforms", "tpu")
-    cache = enable_compile_cache()
-    devices = jax.devices()
+    with tracing.span("runtime.claim_tpu", startup=True):
+        import jax
+        import jaxlib
+
+        jax.config.update("jax_platforms", "tpu")
+        cache = enable_compile_cache()
+        devices = jax.devices()
     if devices[0].platform != "tpu":
         raise RuntimeError(
             f"asked for the TPU backend, got {devices[0].platform!r}")

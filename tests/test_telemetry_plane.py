@@ -173,9 +173,10 @@ def test_cross_plane_trace_and_metrics(rt, tmp_path, cpu_devices):
     assert any("Range" in n for n in data_spans)
 
     # Train plane: per-step span with data-wait and compute children,
-    # plus the first-call compile span.
+    # plus the first call's start-up span (the compile watch's counters
+    # are tests/test_startup_record.py's).
     assert {"train.step", "train.data_wait", "train.compute",
-            "train.compile"} <= set(spans)
+            "train.first_step"} <= set(spans)
     assert (spans["train.data_wait"]["parent_id"]
             == spans["train.step"]["span_id"])
     assert (spans["train.compute"]["parent_id"]
@@ -221,7 +222,7 @@ def test_cross_plane_trace_and_metrics(rt, tmp_path, cpu_devices):
     assert 'raytpu_xla_program_bytes_accessed{program="serve.prefill"}' \
         in text
     assert _sample_value(
-        text, 'raytpu_xla_compile_seconds_total{program="train.step"}') > 0
+        text, 'raytpu_xla_compile_seconds_total{program="train.step",') > 0
     assert 'raytpu_xla_roofline_flops_utilization{program="train.step"}' \
         in text
     assert 'raytpu_xla_roofline_hbm_utilization{program="serve.decode"}' \
@@ -247,7 +248,7 @@ def test_cross_plane_trace_and_metrics(rt, tmp_path, cpu_devices):
         text, "raytpu_data_output_rows_total") == rows_before + 64
     assert _sample_value(
         text, "raytpu_train_steps_total") == steps_before + 2
-    assert _sample_value(text, "raytpu_train_compile_seconds_total") > 0
+    assert "raytpu_train_compile_seconds_total" not in text
     # Memory plane: opt-state footprint is derived from the arrays'
     # shardings so it exports real bytes even on CPU; the HBM-headroom
     # gauge follows the absent-not-zero rule (declared family, zero

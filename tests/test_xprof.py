@@ -36,12 +36,12 @@ def _family_samples(name):
 
 def test_record_compiled_and_roofline():
     lowered = jax.jit(lambda x: (x @ x).sum()).lower(jnp.ones((64, 64)))
-    rec = xprof.record_compiled("t.matmul", lowered, compile_time_s=0.25,
-                                span_name="t.span")
+    rec = xprof.record_compiled("t.matmul", lowered, span_name="t.span")
     assert rec.flops and rec.flops > 0
     assert rec.bytes_accessed and rec.bytes_accessed > 0
     assert _family_samples("raytpu_xla_program_flops{")
-    assert _family_samples("raytpu_xla_compile_seconds_total{")
+    # the compile counter is the compile watch's, not this call's:
+    # tests/test_startup_record.py
 
     # Join a measured wall → achieved vs. the chip peak.
     tracing.enable_tracing()
