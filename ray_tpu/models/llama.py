@@ -234,6 +234,86 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def _rotate_half_matrix(dim: int, dtype) -> jax.Array:
+    """[dim, dim], a signed permutation: ``x @ P == [-x2, x1]``."""
+    eye = jnp.eye(dim // 2, dtype=dtype)
+    zero = jnp.zeros_like(eye)
+    return jnp.block([[zero, eye], [-eye, zero]])
+
+
+def _rope_product(x, sin, cos):
+    """``x * [cos, cos] + [-x2, x1] * [sin, sin]`` in float32, the
+    swapped halves taken through the product."""
+    rot = jnp.einsum("bshd,de->bshe", x,
+                     _rotate_half_matrix(x.shape[-1], x.dtype))
+    sin = jnp.concatenate([sin, sin], axis=-1)[:, :, None, :]
+    cos = jnp.concatenate([cos, cos], axis=-1)[:, :, None, :]
+    out = x.astype(jnp.float32) * cos + rot.astype(jnp.float32) * sin
+    return out.astype(x.dtype)
+
+
+@jax.custom_vjp
+def _rope_bf16(x, sin, cos):
+    return _rope_product(x, sin, cos)
+
+
+def _rope_bf16_fwd(x, sin, cos):
+    return _rope_product(x, sin, cos), (sin, cos)
+
+
+def _rope_bf16_bwd(tables, g):
+    # the rotation's transpose is the rotation by the opposite angle
+    sin, cos = tables
+    return (_rope_product(g, -sin, cos), jnp.zeros_like(sin),
+            jnp.zeros_like(cos))
+
+
+_rope_bf16.defvjp(_rope_bf16_fwd, _rope_bf16_bwd)
+
+
+_ROPE_TRACES = None
+
+
+def _rope_traces():
+    """Counter of RoPE traces by form (re-registered on refetch: see
+    serve/llm_engine._telemetry)."""
+    global _ROPE_TRACES
+    from ray_tpu.util import metrics
+
+    if _ROPE_TRACES is None:
+        _ROPE_TRACES = metrics.Counter(
+            "raytpu_rope_traces_total",
+            "Times _qkv's RoPE was traced into a program, by form: "
+            "product (the rotation as one product's epilogue, a single "
+            "pass over the tensor) or concat (apply_rope's slices and "
+            "concatenate: another dtype than bfloat16, or one position "
+            "a row).",
+            tag_keys=("form",),
+        )
+    else:
+        metrics.registry().register(_ROPE_TRACES)
+    return _ROPE_TRACES
+
+
+def rope_in_one_pass(x: jax.Array, sin: jax.Array, cos: jax.Array):
+    """``apply_rope``'s arithmetic (the same two products and one sum
+    an element, in float32) as ONE pass over ``x`` in the compiled
+    program: the halves change places through a product with a signed
+    permutation (exact in bfloat16: every sum has one term), so the
+    compiler fuses the rotation into that product's epilogue, where
+    ``apply_rope``'s slices and ``concatenate`` along the lanes cost it
+    two passes over half-filled tiles forward and a float32 copy of the
+    cotangent backward (PERF.md, PR 52).  The tables are functions of
+    the positions and get no cotangent.  Another dtype's product would
+    round on a TPU, and a decode step's one position a row has nothing
+    to pass over: both take ``apply_rope``."""
+    by_product = x.dtype == jnp.bfloat16 and x.shape[1] > 1
+    _rope_traces().inc(tags={"form": "product" if by_product else "concat"})
+    if by_product:
+        return _rope_bf16(x, sin, cos)
+    return apply_rope(x, sin, cos)
+
+
 def _qkv(x, layer, cfg: LlamaConfig, sin, cos):
     """Shared q/k/v projection + RoPE (used by train, prefill and decode).
 
@@ -254,7 +334,7 @@ def _qkv(x, layer, cfg: LlamaConfig, sin, cos):
         q = jnp.einsum("bsd,dhk->bshk", x, a["wq"].astype(dt))
         k = jnp.einsum("bsd,dhk->bshk", x, a["wk"].astype(dt))
         v = jnp.einsum("bsd,dhk->bshk", x, a["wv"].astype(dt))
-    return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
+    return rope_in_one_pass(q, sin, cos), rope_in_one_pass(k, sin, cos), v
 
 
 def _attn_block(x, layer, cfg: LlamaConfig, sin, cos, segment_ids,

@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LLAMA_TINY, LlamaConfig
@@ -61,6 +62,53 @@ def test_loss_and_grads():
     assert abs(float(loss) - np.log(cfg.vocab_size)) < 1.5
     gnorm = jnp.sqrt(sum(jnp.sum(g**2) for g in jax.tree.leaves(grads)))
     assert bool(jnp.isfinite(gnorm)) and float(gnorm) > 0
+
+
+# (heads, positions, dtype, taken by the product)
+ROPE_CASES = {
+    "q_heads": (4, 32, jnp.bfloat16, True),
+    "one_kv_head": (1, 24, jnp.bfloat16, True),
+    "float32_rounds_on_an_mxu": (2, 16, jnp.float32, False),
+    "a_decode_row": (4, 1, jnp.bfloat16, False),
+}
+
+
+@pytest.mark.parametrize("case", ROPE_CASES)
+def test_rope_in_one_pass_is_apply_rope(case):
+    """The rotation as a product's epilogue is apply_rope's arithmetic,
+    forward and backward (the same products and one sum each; a compiler
+    that contracts one product of a sum into a fused multiply-add may
+    choose the other one, which shows in the last bit of a few elements
+    in ten thousand); dtype and length decide which runs."""
+    H, S, dtype, by_product = ROPE_CASES[case]
+    from ray_tpu.util import metrics
+
+    def traced():              # the registry's count of each form's traces
+        return {dict(tags)["form"]: n
+                for _, tags, n, _ in llama._rope_traces()._samples()}
+
+    before = traced()
+    kx, kg = jax.random.split(jax.random.key(3))
+    x = jax.random.normal(kx, (2, S, H, 128), dtype)
+    g = jax.random.normal(kg, x.shape, dtype)
+    pos = jnp.broadcast_to(jnp.arange(S) + 1000, (2, S))
+    sin, cos = llama.rope_table(LlamaConfig(dim=128 * H, n_heads=H), pos)
+
+    def both(fn):
+        y, vjp = jax.vjp(lambda x: fn(x, sin, cos), x)
+        return y, vjp(g)[0]
+
+    want = jax.jit(lambda: both(llama.apply_rope))()
+    assert traced() == before
+    got = jax.jit(lambda: both(llama.rope_in_one_pass))()
+    form = "product" if by_product else "concat"
+    assert traced() == {**before, form: before.get(form, 0) + 1}
+    assert metrics.registry().get("raytpu_rope_traces_total") is not None
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.mean(a != b) <= (1e-3 if by_product else 0)
+        np.testing.assert_allclose(a, b, rtol=2 ** -7, atol=2 ** -9)
 
 
 def test_ragged_step_matches_forward():

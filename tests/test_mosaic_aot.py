@@ -40,6 +40,59 @@ def test_flash_forward_and_backward(v5e, B, S, H, KVH):
         argnums=(0, 1, 2)), q, kv, kv)
 
 
+def test_attention_scope_moves_nothing_but_products(v5e):
+    """One layer's attention of ``internlm2_1b8-pretrain_4k`` (norm,
+    projections, RoPE, the flash kernels, the output projection; forward
+    under ``jax.checkpoint`` and its gradient): every instruction of the
+    compiled program whose result holds 16 MB or more is a product's
+    output fusion (RoPE rides one: ``llama.rope_in_one_pass``), a kernel
+    or a norm's reduction.  A ``transpose``, ``copy`` or ``convert`` of
+    that size, or a loop fusion (RoPE's halves, its ``concatenate``, a
+    float32 copy of dq), is a pass over q, k or their cotangents that
+    PR 52 took out: 75 ms of the cell's 1606 ms step."""
+    from ray_tpu.models import llama
+
+    B, S, H, KVH, D = 4, 4096, 16, 8, 128
+    cfg = llama.LlamaConfig(vocab_size=128, dim=H * D, n_layers=1,
+                            n_heads=H, n_kv_heads=KVH, mlp_dim=128,
+                            max_seq_len=S, dtype=jnp.bfloat16)
+    mesh = _one(v5e)
+    x, sin = _on(mesh, (_sds(B, S, H * D),
+                        _sds(B, S, D // 2, dtype=jnp.float32)))
+    layer = _on(mesh, {
+        "ln_attn": _sds(H * D),
+        "attn": {"wq": _sds(H * D, H, D), "wk": _sds(H * D, KVH, D),
+                 "wv": _sds(H * D, KVH, D), "wo": _sds(H, D, H * D)}})
+
+    def attention(x, layer, sin, cos):
+        normed = llama.rms_norm(x, layer["ln_attn"], cfg.norm_eps)
+        return x + llama._attn_block(normed, layer, cfg, sin, cos, None)[0]
+
+    def both_ways(x, layer, sin, cos, ct):
+        y, vjp = jax.vjp(
+            lambda x, layer: jax.checkpoint(attention)(x, layer, sin, cos),
+            x, layer)
+        return y, vjp(ct)
+
+    text = _compile(both_ways, x, layer, sin, sin, x).as_text()
+    entry = text[text.index("ENTRY"):]
+    assert entry.count("flash_fwd") >= 2 and "flash_bwd_dkv" in entry
+    moved = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) "
+                     r"(fusion|copy|convert|transpose|concatenate)\(", line)
+        if (m is None or "kind=kOutput" in line
+                or re.search(r'op_name="[^"]*/reduce_sum"', line)):
+            continue
+        name, result, op = m.groups()
+        sizes = [np.prod([int(d) for d in dims.split(",")])
+                 * (4 if dtype == "f32" else 2)
+                 for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", result)]
+        if max(sizes, default=0) >= 16 * 2**20:
+            moved.append((name, op, result.split("{")[0]))
+    assert moved == [], moved
+
+
 @pytest.mark.parametrize("kv_int8", [False, True])
 def test_paged_decode_kernels(v5e, kv_int8):
     from ray_tpu.ops import paged_attention as pa
